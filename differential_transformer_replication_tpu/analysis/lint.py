@@ -44,8 +44,8 @@ How a file is analyzed:
    the lambda bound at each call site); ``<fn>.defvjp(fwd, bwd)``
    (the VJP pair executes wherever the primal does); lambdas passed
    as call arguments; and functions whose parameter is handed to a
-   tracing transform inside their body (``utils/compat.shard_map``'s
-   ``f`` — so every wrapped body is discovered through the wrapper).
+   tracing transform inside their body (a ``shard_map`` wrapper's
+   ``fn`` — so every wrapped body is discovered through the wrapper).
 7. **Axis environments.** ``shard_map``/``pmap`` bodies *bind* mesh
    axis names (``pmap`` binds its literal ``axis_name``; ``shard_map``
    binds the wildcard ``*`` — the mesh's axes are runtime values).
@@ -594,9 +594,9 @@ def _resolve_call(
 def _alias_transform_last(mod: _Mod, name: Optional[str]) -> Optional[str]:
     """The tracing transform's SHORT name when ``name`` — as written, or
     resolved through the module's import aliases — names one; None
-    otherwise. The alias path accepts jax-rooted resolutions and this
-    repo's compat re-exports (``from utils.compat import shard_map as
-    _shard_map`` must still read as shard_map)."""
+    otherwise. The alias path accepts jax-rooted resolutions (``from
+    jax import shard_map as _shard_map`` must still read as
+    shard_map)."""
     if not name:
         return None
     resolved = _call_dotted_resolved(mod, name)
@@ -609,7 +609,7 @@ def _alias_transform_last(mod: _Mod, name: Optional[str]) -> Optional[str]:
             "jax", "lax", "jnp", "pjit", "functools"
         ):
             return last
-        if cand is not name and ("jax" in parts or "compat" in parts):
+        if cand is not name and "jax" in parts:
             return last
     return None
 

@@ -1,7 +1,8 @@
 """ctypes bridge to the native data-pipeline library (native/src/).
 
-Compiles ``data_native.cpp`` with g++ on first use (cached by source
-mtime under ``native/build/``) and exposes:
+Compiles ``data_native.cpp`` with g++ on first use (cached under
+``native/build/`` by a hash of the source, so a copied tree can never
+load a binary built from another version of it) and exposes:
 
   - ``permute_indices(n, seed, start, count)`` — a window of the seeded
     O(1)-memory Feistel permutation of [0, n),
@@ -16,6 +17,7 @@ framework behavior never depends on the native build succeeding.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -27,14 +29,20 @@ import numpy as np
 _REPO_ROOT = Path(__file__).resolve().parents[2]
 _SRC = _REPO_ROOT / "native" / "src" / "data_native.cpp"
 _BUILD_DIR = _REPO_ROOT / "native" / "build"
-_LIB_PATH = _BUILD_DIR / "libdata_native.so"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
-def _compile() -> bool:
+def _lib_path() -> Path:
+    """The library for THIS source: the name carries the source's hash
+    (mtimes do not survive a copy of the tree in order)."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _BUILD_DIR / f"libdata_native.{digest}.so"
+
+
+def _compile(lib_path: Path) -> bool:
     _BUILD_DIR.mkdir(parents=True, exist_ok=True)
     # compile to a process-unique temp path and rename atomically: the
     # threading lock is per-process, and concurrent jobs on one checkout
@@ -46,7 +54,7 @@ def _compile() -> bool:
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _LIB_PATH)
+        os.replace(tmp, lib_path)
         return True
     except (OSError, subprocess.SubprocessError):
         tmp.unlink(missing_ok=True)
@@ -54,20 +62,18 @@ def _compile() -> bool:
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    """Compile (if stale) and load the shared library; None on failure."""
+    """Compile (if not built for this source yet) and load the shared
+    library; None on failure."""
     global _lib, _load_failed
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
         try:
-            stale = (
-                not _LIB_PATH.exists()
-                or os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)
-            )
-            if stale and not _compile():
+            lib_path = _lib_path()
+            if not lib_path.exists() and not _compile(lib_path):
                 _load_failed = True
                 return None
-            lib = ctypes.CDLL(str(_LIB_PATH))
+            lib = ctypes.CDLL(str(lib_path))
             lib.permute_indices.argtypes = [
                 ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64,
                 ctypes.c_uint64, ctypes.POINTER(ctypes.c_int64),

@@ -117,7 +117,9 @@ class DeviceProfileSampler:
                          stitched device lane); NOOP-safe,
     ``flops_per_step`` / ``hbm_bytes_per_step`` / ``peak_flops``
                          estimates feeding :func:`xprof.derived_metrics`
-                         (``device_mfu``); None = those fields omitted,
+                         (``device_mfu``; the peak comes from
+                         :func:`xprof.device_peaks` for the device the
+                         loop runs on); None = those fields omitted,
     ``start_fn`` / ``stop_fn`` / ``block_fn``
                          the profiler seam — default to jax.profiler
                          (imported lazily, so scheduler tests run
@@ -136,7 +138,7 @@ class DeviceProfileSampler:
         keep: int = 2,
         flops_per_step: Optional[float] = None,
         hbm_bytes_per_step: Optional[float] = None,
-        peak_flops: float = xprof.TPU_V5E_BF16_PEAK_FLOPS,
+        peak_flops: Optional[float] = None,
         start_fn: Optional[Callable[[str], None]] = None,
         stop_fn: Optional[Callable[[], None]] = None,
         block_fn: Optional[Callable[[object], None]] = None,

@@ -60,9 +60,36 @@ KERNEL_BUCKETS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
                      "collective-broadcast")),
 )
 
-# TPU v5e bf16 peak, the MFU denominator bench.py uses; callers on
-# other hardware pass their own peak to derived_metrics.
-TPU_V5E_BF16_PEAK_FLOPS = 197e12
+# Published per-chip peaks, keyed by the ``device_kind`` string JAX
+# reports — the one table bench.py's MFU and the continuous ``device_mfu``
+# gauge divide by. Source: Google Cloud documentation, "TPU v5e" (system
+# architecture): 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at
+# 819 GB/s per chip. A device that is not here is an error
+# (:func:`device_peaks`), never a default.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "int8_ops_per_s": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+    },
+}
+
+
+def device_peaks(device_kind: str) -> Dict[str, float]:
+    """The published peaks of ``device_kind`` (``jax.devices()[0]
+    .device_kind``). Raises ``KeyError`` naming the kind for a device
+    the table does not hold: a utilization against another chip's peak
+    is a wrong number, not an estimate."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r} in "
+            f"obs/xprof.py:DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)}); "
+            "add the chip with its source before reporting a "
+            "utilization on it"
+        ) from None
 
 
 # -- minimal protobuf wire reader -----------------------------------------
@@ -357,12 +384,13 @@ def derived_metrics(
     busy_ms_per_step: float,
     flops_per_step: Optional[float] = None,
     hbm_bytes_per_step: Optional[float] = None,
-    peak_flops: float = TPU_V5E_BF16_PEAK_FLOPS,
+    peak_flops: Optional[float] = None,
 ) -> dict:
     """MFU / HBM-bandwidth estimates from the device-busy time.
 
     ``mfu`` divides the caller's model-FLOPs estimate (bench.py's
-    6*N*D convention for training) by busy time and hardware peak —
+    6*N*D convention for training) by busy time and the device's peak
+    (``device_peaks(kind)["bf16_flops_per_s"]``; omitted without one) —
     the same accounting as the bench JSON's ``mfu_6nd``, so continuous
     samples and bench rounds are directly comparable.
     ``hbm_gbps`` is the achieved bandwidth implied by the caller's
@@ -373,7 +401,7 @@ def derived_metrics(
     busy_s = busy_ms_per_step / 1e3
     if busy_s <= 0:
         return out
-    if flops_per_step:
+    if flops_per_step and peak_flops:
         out["mfu"] = flops_per_step / busy_s / peak_flops
     if hbm_bytes_per_step:
         out["hbm_gbps"] = hbm_bytes_per_step / busy_s / 1e9

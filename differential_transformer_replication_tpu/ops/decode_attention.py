@@ -17,12 +17,17 @@ This module is the fused alternative:
   applies the lambda-weighted combine coefficients
   (models/decode.py:``_layer_coeffs`` — control S=1, diff S=2, ndiff S=N)
   in-kernel, and writes only the ``(B, H, dv)`` output. Per-stream
-  attention maps and fp32 scores never reach HBM.
+  attention maps and fp32 scores never reach HBM. The paged and the
+  multi-query (speculative verify) entry points are the same kernel
+  with page-table index maps and L > 1 query rows per program.
 - int8 KV: :func:`quantize_kv` stores K/V rows as int8 with one fp32
-  scale per (stream,) slot/head/token vector; the kernel dequantizes
-  INSIDE the tile loads, so the HBM stream is genuinely half the bf16
-  bytes (plus a ~4/d scale overhead). :func:`dequantize_kv` is the XLA
-  twin used by the un-fused path and the parity oracles.
+  scale per (stream,) slot/head/token vector; the kernel feeds the int8
+  tiles to the MXU (the cast to the compute dtype is exact) and applies
+  the per-token scales to the score / probability rows, so the HBM
+  stream is genuinely half the bf16 bytes (plus a ~4/d scale overhead)
+  and nothing is rounded to the compute dtype on the way.
+  :func:`dequantize_kv` is the XLA twin used by the un-fused path and
+  the parity oracles.
 - :func:`decode_attention_reference` — the plain-XLA twin (same masking
   and fp32 softmax), used when ``decode_attention_impl == "xla"`` and by
   tests/tools/decode_attn_sweep.py as the parity baseline.
@@ -36,7 +41,7 @@ non-negative, which reduces to ``m <= pos`` (for ``pos >= M`` every slot
 holds a live key) — the same arithmetic ``_attn_chunk`` derives for its
 general chunk case, collapsed for L=1 (see models/decode.py).
 
-Kernel naming: the kernel body is ``_dattn_fwd_kernel`` so XLA op names
+Kernel naming: the kernel body is ``_dattn_kernel`` so XLA op names
 carry the ``_dattn_`` needle tools/profile_step.py buckets on.
 """
 
@@ -50,10 +55,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from differential_transformer_replication_tpu.utils.compat import (
-    CompilerParams as _CompilerParams,
-)
 
 from differential_transformer_replication_tpu.ops.flash import (
     auto_interpret,
@@ -99,28 +100,37 @@ def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def _dattn_fwd_kernel(
-    q_ref,  # (1, S, d) this row's per-stream queries (post-RoPE)
-    k_ref,  # (S, 1, block_k, d) stored dtype (float) or int8
-    v_ref,  # (1, block_k, dv)
-    pos_ref,  # (1, BH) int32 SMEM: absolute position per (b, h) program
-    c_ref,  # (S, H) float32 SMEM combine coefficients (_layer_coeffs)
-    *refs,  # [k_scale_ref (S, 1, block_k), v_scale_ref (1, block_k) if
-    #          quantized] then out_ref (1, dv) and scratch:
-    #          m (S, 1), l (S, 1), acc (S, dv) — all fp32
+def _dattn_kernel(
+    *refs,
+    # after the scalar-prefetch refs (the page table, consumed by the
+    # index maps):
+    #   q_ref    (1, L, S, d)   this (b, h)'s per-(row, stream) queries
+    #   k_ref    (S, 1, block_k, d)  stored dtype (float) or int8
+    #   v_ref    (1, block_k, dv)
+    #   pos_ref  (L, BH) int32 SMEM: absolute position per row, program
+    #   c_ref    (S, H) float32 SMEM combine coefficients (_layer_coeffs)
+    #   [ks_ref (S, 1, 1, block_k), vs_ref (1, 1, block_k) if quantized]
+    #   out_ref  (1, L, dv)
+    #   scratch  m (L*S, 1), l (L*S, 1), acc (L*S, dv) — all fp32
+    n_prefetch: int,
     n_heads: int,
     quantized: bool,
 ):
+    refs = refs[n_prefetch:]
+    q_ref, k_ref, v_ref, pos_ref, c_ref = refs[:5]
     if quantized:
-        ks_ref, vs_ref, out_ref, m_scr, l_scr, acc_scr = refs
+        ks_ref, vs_ref, out_ref, m_scr, l_scr, acc_scr = refs[5:]
     else:
-        out_ref, m_scr, l_scr, acc_scr = refs
-    S, d = q_ref.shape[1], q_ref.shape[2]
+        out_ref, m_scr, l_scr, acc_scr = refs[5:]
+    L, S, d = q_ref.shape[1:]
     block_k = k_ref.shape[2]
     bh = pl.program_id(0)  # read at top level (interpreter cannot lower
     j = pl.program_id(1)   # program_id inside when-bodies; see ops/flash.py)
     nk = pl.num_programs(1)
-    pos = pos_ref[0, bh]
+    # per-row positions; the tile-skip bound is the rows' max (static
+    # unroll over the tiny L keeps the SMEM reads scalar-indexed)
+    pos_l = [pos_ref[l, bh] for l in range(L)]
+    pos_max = functools.reduce(jnp.maximum, pos_l)
     scale = 1.0 / math.sqrt(d)
 
     @pl.when(j == 0)
@@ -129,55 +139,189 @@ def _dattn_fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # ring visibility collapses to col <= pos for a single decode row
-    # (module docstring); a tile entirely past pos is skipped outright
-    @pl.when(j * block_k <= pos)
+    # Ring visibility collapses to col <= pos for a decode row (module
+    # docstring); a tile entirely past every row's position is skipped
+    # outright. Rows and streams are a STATIC unroll of 2-D (1, .) x
+    # (block_k, .) matmuls: every row runs the same op sequence whatever
+    # L is (the greedy spec/non-spec bit-parity pin depends on that),
+    # and the MXU gets a free lhs dimension (a batched dot with no free
+    # lhs dim is not expressible in Mosaic).
+    @pl.when(j * block_k <= pos_max)
     def _():
-        q = q_ref[0]  # (S, d)
-        k_j = k_ref[:, 0]  # (S, block_k, d)
-        v_j = v_ref[0]  # (block_k, dv)
-        if quantized:
-            # dequant fused into the tile load: HBM carried int8 + one
-            # fp32 scale per row vector; VMEM sees compute-dtype tiles
-            k_j = (
-                k_j.astype(jnp.float32) * ks_ref[:, 0][:, :, None]
-            ).astype(q.dtype)
-            v_j = (
-                v_j.astype(jnp.float32) * vs_ref[0][:, None]
-            ).astype(q.dtype)
-        s = jax.lax.dot_general(
-            q, k_j,
-            dimension_numbers=(((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (S, block_k)
+        cdtype = q_ref.dtype
         cols = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1
         )
-        s = jnp.where(cols <= pos, s, NEG_INF)
-        m_prev = m_scr[:]  # (S, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)  # (S, block_k)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v_j.dtype), v_j,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (S, dv)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = m_new
+        v_j = v_ref[0]  # (block_k, dv)
+        if quantized:
+            # int8 -> compute dtype is exact; the per-token scales are
+            # applied on the (1, block_k) score / probability rows
+            # below, where they sit on lanes like the rows themselves
+            v_j = v_j.astype(jnp.float32).astype(cdtype)
+            v_sc = vs_ref[0]  # (1, block_k)
+        for s_i in range(S):
+            k_j = k_ref[s_i, 0]  # (block_k, d)
+            if quantized:
+                k_j = k_j.astype(jnp.float32).astype(cdtype)
+                k_sc = ks_ref[s_i, 0] * scale  # (1, block_k)
+            for l in range(L):
+                i = l * S + s_i
+                q = q_ref[0, l, s_i:s_i + 1, :]  # (1, d)
+                s = jax.lax.dot_general(
+                    q, k_j,
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # (1, block_k)
+                s = s * k_sc if quantized else s * scale
+                s = jnp.where(cols <= pos_l[l], s, NEG_INF)
+                m_prev = m_scr[i:i + 1]  # (1, 1)
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(s, axis=-1, keepdims=True)
+                )
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)  # (1, block_k)
+                l_scr[i:i + 1] = (
+                    l_scr[i:i + 1] * alpha
+                    + jnp.sum(p, axis=-1, keepdims=True)
+                )
+                if quantized:
+                    p = p * v_sc
+                pv = jax.lax.dot_general(
+                    p.astype(v_j.dtype), v_j,
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # (1, dv)
+                acc_scr[i:i + 1] = acc_scr[i:i + 1] * alpha + pv
+                m_scr[i:i + 1] = m_new
 
     @pl.when(j == nk - 1)
     def _():
-        # l >= 1 always (slot pos is visible to its own query); the floor
-        # only guards never-stepped degenerate rows
-        l_safe = jnp.maximum(l_scr[:], 1e-30)
-        o_s = acc_scr[:] / l_safe  # (S, dv) per-stream outputs
         h = jax.lax.rem(bh, jnp.int32(n_heads))
-        combined = o_s[0:1] * c_ref[0, h]
-        for s_i in range(1, S):
-            combined += o_s[s_i:s_i + 1] * c_ref[s_i, h]
-        out_ref[:] = combined.astype(out_ref.dtype)
+        for l in range(L):
+            combined = None
+            for s_i in range(S):
+                i = l * S + s_i
+                # l >= 1 always (a row's own position is visible to
+                # it); the floor only guards never-stepped rows
+                o_s = acc_scr[i:i + 1] / jnp.maximum(l_scr[i:i + 1], 1e-30)
+                term = o_s * c_ref[s_i, h]
+                combined = term if combined is None else combined + term
+            out_ref[0, l:l + 1, :] = combined.astype(out_ref.dtype)
+
+
+def _dattn_call(
+    qs: jnp.ndarray,  # (S, B, L, H, d)
+    k: jnp.ndarray,  # (S, R, H, M, d) slot pool | (S, P, H, ps, d) pages
+    v: jnp.ndarray,  # (R, H, M, dv) | (P, H, ps, dv)
+    pos: jnp.ndarray,  # (B, L) int32
+    coeffs: jnp.ndarray,  # (S, H)
+    k_scale: Optional[jnp.ndarray],  # k.shape[:-1] fp32 (int8 path)
+    v_scale: Optional[jnp.ndarray],  # v.shape[:-1] fp32
+    page_tables: Optional[jnp.ndarray],  # (B, pages_per_slot) | None
+    block_k: int,
+    interpret: Optional[bool],
+) -> jnp.ndarray:
+    """One ``pallas_call`` behind all four public entry points: grid
+    ``(B*H, tiles)``, L query rows per program, K/V tiles addressed
+    either directly (slot pool: row ``bh`` of the head-major pool, tile
+    ``j``) or through the scalar-prefetched page table (row
+    ``page_tables[b, j] * H + h``, the whole page). Returns
+    ``(B, L, H, dv)`` in the query dtype.
+
+    Mosaic wants the last two dims of every block either full or
+    (8, 128)-aligned, so single rows ride behind a singleton axis: the
+    output block is ``(1, L, dv)`` and the scale planes are viewed as
+    ``(.., 1, M)`` (zero-copy: M is already the minor axis)."""
+    S, B, L, H, d = qs.shape
+    rows, M = k.shape[1] * H, k.shape[3]
+    dv = v.shape[-1]
+    BH = B * H
+    if interpret is None:
+        interpret = auto_interpret()
+    quantized = k_scale is not None
+    if quantized != (v_scale is not None):
+        raise ValueError("k_scale and v_scale must be given together")
+
+    if page_tables is None:
+        prefetch = []
+        bk = pick_block(block_k or _DEFAULT_BLOCK_K, M)
+        n_tiles = M // bk
+
+        def kv_index(bh, j):
+            return bh, j
+    else:
+        prefetch = [jnp.asarray(page_tables, jnp.int32)]
+        bk = M  # one grid step streams one page
+        n_tiles = page_tables.shape[1]
+
+        def kv_index(bh, j, pt_ref):
+            return pt_ref[bh // H, j] * H + bh % H, 0
+
+    # (S, B, L, H, d) -> (BH, L, S, d): tiny, one token per row
+    q = qs.transpose(1, 3, 2, 0, 4).reshape(BH, L, S, d)
+    # zero-copy views: the pools are head-major (models/decode.py)
+    k = k.reshape(S, rows, M, d)
+    v = v.reshape(rows, M, dv)
+    # (L, BH): column b*H+h carries slot b's row positions
+    pos_bh = jnp.repeat(jnp.asarray(pos, jnp.int32).T, H, axis=1)
+
+    def fixed(*idx):
+        return lambda bh, j, *pt: idx
+
+    inputs = [q, k, v, pos_bh, coeffs.astype(jnp.float32)]
+    in_specs = [
+        pl.BlockSpec((1, L, S, d), lambda bh, j, *pt: (bh, 0, 0, 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((S, 1, bk, d),
+                     lambda *a: (0, *kv_index(*a), 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bk, dv), lambda *a: (*kv_index(*a), 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((L, BH), fixed(0, 0), memory_space=pltpu.SMEM),
+        pl.BlockSpec((S, H), fixed(0, 0), memory_space=pltpu.SMEM),
+    ]
+    if quantized:
+        inputs += [
+            k_scale.reshape(S, rows, 1, M).astype(jnp.float32),
+            v_scale.reshape(rows, 1, M).astype(jnp.float32),
+        ]
+
+        def scale_index(*a):
+            row, tile = kv_index(*a)
+            return row, 0, tile
+
+        in_specs += [
+            pl.BlockSpec((S, 1, 1, bk), lambda *a: (0, *scale_index(*a)),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, bk), scale_index,
+                         memory_space=pltpu.VMEM),
+        ]
+    out = pl.pallas_call(
+        functools.partial(
+            _dattn_kernel, n_prefetch=len(prefetch), n_heads=H,
+            quantized=quantized,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(BH, n_tiles),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, L, dv),
+                                   lambda bh, j, *pt: (bh, 0, 0),
+                                   memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((L * S, 1), jnp.float32),
+                pltpu.VMEM((L * S, 1), jnp.float32),
+                pltpu.VMEM((L * S, dv), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((BH, L, dv), qs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
+        interpret=interpret,
+    )(*prefetch, *inputs)
+    # (BH, L, dv) -> (B, L, H, dv)
+    return out.reshape(B, H, L, dv).transpose(0, 2, 1, 3)
 
 
 def decode_attention(
@@ -201,79 +345,11 @@ def decode_attention(
     cache at ``pos % M`` (the same update-then-attend order
     ``_attn_chunk`` uses). Returns ``(B, H, dv)`` in the query dtype.
     """
-    S, B, H, M, d = k_cache.shape
-    dv = v_cache.shape[-1]
-    BH = B * H
-    if interpret is None:
-        interpret = auto_interpret()
-    bk = pick_block(block_k or _DEFAULT_BLOCK_K, M)
-    nk = M // bk
-    quantized = k_scale is not None
-    if quantized != (v_scale is not None):
-        raise ValueError("k_scale and v_scale must be given together")
-
-    q = qs.transpose(1, 2, 0, 3).reshape(BH, S, d)  # tiny: one token/row
-    k = k_cache.reshape(S, BH, M, d)  # zero-copy: head-major layout
-    v = v_cache.reshape(BH, M, dv)
-    pos_bh = jnp.broadcast_to(
-        jnp.asarray(pos, jnp.int32)[:, None], (B, H)
-    ).reshape(1, BH)
-
-    inputs = [q, k, v, pos_bh, coeffs.astype(jnp.float32)]
-    in_specs = [
-        pl.BlockSpec((1, S, d), lambda bh, j: (bh, 0, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((S, 1, bk, d), lambda bh, j: (0, bh, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, dv), lambda bh, j: (bh, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, BH), lambda bh, j: (0, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((S, H), lambda bh, j: (0, 0),
-                     memory_space=pltpu.SMEM),
-    ]
-    if quantized:
-        inputs += [
-            k_scale.reshape(S, BH, M).astype(jnp.float32),
-            v_scale.reshape(BH, M).astype(jnp.float32),
-        ]
-        in_specs += [
-            pl.BlockSpec((S, 1, bk), lambda bh, j: (0, bh, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk), lambda bh, j: (bh, j),
-                         memory_space=pltpu.VMEM),
-        ]
-    out = pl.pallas_call(
-        functools.partial(
-            _dattn_fwd_kernel, n_heads=H, quantized=quantized
-        ),
-        grid=(BH, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, dv), lambda bh, j: (bh, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((BH, dv), qs.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((S, 1), jnp.float32),
-            pltpu.VMEM((S, 1), jnp.float32),
-            pltpu.VMEM((S, dv), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(*inputs)
-    return out.reshape(B, H, dv)
-
-
-def _dattn_paged_kernel(pt_ref, *args, n_heads: int, quantized: bool):
-    """Paged twin of :func:`_dattn_fwd_kernel`: identical math — the
-    page table did its work in the BlockSpec index maps (scalar
-    prefetch resolved which physical page each grid step streams), so
-    the kernel body sees the same (S, 1, page_size, d) tiles in
-    LOGICAL ring order and delegates wholesale. Keeping the ``_dattn_``
-    needle in the name preserves tools/profile_step.py's bucketing."""
-    del pt_ref  # consumed by the index maps
-    _dattn_fwd_kernel(*args, n_heads=n_heads, quantized=quantized)
+    return _dattn_call(
+        qs[:, :, None], k_cache, v_cache,
+        jnp.asarray(pos, jnp.int32)[:, None], coeffs, k_scale, v_scale,
+        None, block_k, interpret,
+    )[:, 0]
 
 
 def decode_attention_paged(
@@ -304,89 +380,18 @@ def decode_attention_paged(
     allocating/freeing/sharing/forking pages between calls compiles
     NOTHING new — the zero-recompile pin the serving engine keeps.
 
-    Hardware note: Mosaic wants the (ps, d) tile at or above the dtype
-    tiling floor — page sizes of 128+ (bf16) / 256+ (int8) keep the
-    loads aligned on real TPUs; CPU interpret mode (tests) takes any
-    divisor of block_size.
+    Hardware note: a page is a whole K/V block (its last two dims are
+    the array's own), so the chip's compiler takes every page size that
+    divides block_size — bf16 and int8, down to one token
+    (tests/test_tpu_compile.py; run on a v5e at 1..256 in PR 21). Small
+    pages cost grid steps, and pages under the dtype's sublane tile
+    (16 rows bf16, 32 int8) are presumably padded in HBM.
     """
-    S, P, H, ps, d = k_pages.shape
-    dv = v_pages.shape[-1]
-    B, pp = page_tables.shape
-    BH = B * H
-    if interpret is None:
-        interpret = auto_interpret()
-    quantized = k_scale is not None
-    if quantized != (v_scale is not None):
-        raise ValueError("k_scale and v_scale must be given together")
-
-    q = qs.transpose(1, 2, 0, 3).reshape(BH, S, d)
-    k = k_pages.reshape(S, P * H, ps, d)  # zero-copy: head-major pages
-    v = v_pages.reshape(P * H, ps, dv)
-    pos_bh = jnp.broadcast_to(
-        jnp.asarray(pos, jnp.int32)[:, None], (B, H)
-    ).reshape(1, BH)
-    pt = jnp.asarray(page_tables, jnp.int32)
-
-    def _k_map(bh, j, pt_ref):
-        return (0, pt_ref[bh // H, j] * H + bh % H, 0, 0)
-
-    def _v_map(bh, j, pt_ref):
-        return (pt_ref[bh // H, j] * H + bh % H, 0, 0)
-
-    inputs = [q, k, v, pos_bh, coeffs.astype(jnp.float32)]
-    in_specs = [
-        pl.BlockSpec((1, S, d), lambda bh, j, pt_ref: (bh, 0, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((S, 1, ps, d), _k_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, ps, dv), _v_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, BH), lambda bh, j, pt_ref: (0, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((S, H), lambda bh, j, pt_ref: (0, 0),
-                     memory_space=pltpu.SMEM),
-    ]
-    if quantized:
-        inputs += [
-            k_scale.reshape(S, P * H, ps).astype(jnp.float32),
-            v_scale.reshape(P * H, ps).astype(jnp.float32),
-        ]
-        in_specs += [
-            pl.BlockSpec(
-                (S, 1, ps),
-                lambda bh, j, pt_ref: (0, pt_ref[bh // H, j] * H
-                                       + bh % H, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, ps),
-                lambda bh, j, pt_ref: (pt_ref[bh // H, j] * H
-                                       + bh % H, 0),
-                memory_space=pltpu.VMEM,
-            ),
-        ]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(BH, pp),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, dv), lambda bh, j, pt_ref: (bh, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((S, 1), jnp.float32),
-            pltpu.VMEM((S, 1), jnp.float32),
-            pltpu.VMEM((S, dv), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _dattn_paged_kernel, n_heads=H, quantized=quantized
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((BH, dv), qs.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(pt, *inputs)
-    return out.reshape(B, H, dv)
+    return _dattn_call(
+        qs[:, :, None], k_pages, v_pages,
+        jnp.asarray(pos, jnp.int32)[:, None], coeffs, k_scale, v_scale,
+        page_tables, 0, interpret,
+    )[:, 0]
 
 
 def decode_attention_reference(
@@ -420,105 +425,9 @@ def decode_attention_reference(
 # the same page-table-resolved pages) with ROW-CAUSAL visibility
 # ``col <= pos[b, l]``, so row l sees the K/V rows 0..l wrote this very
 # step (update-then-attend order, positions pos..pos+l) and nothing a
-# later row wrote. L = 1 reduces to the single-query kernel above; the
-# hot L=1 path keeps its dedicated kernel untouched.
+# later row wrote. It is the same kernel: the single-query entry points
+# above are its L = 1 case.
 # ---------------------------------------------------------------------------
-
-
-def _dattn_mq_fwd_kernel(
-    q_ref,  # (1, S * L, d) this slot's per-(stream, row) queries
-    k_ref,  # (S, 1, block_k, d) stored dtype (float) or int8
-    v_ref,  # (1, block_k, dv)
-    pos_ref,  # (BH, L) int32 SMEM: absolute position per (b, h) row
-    c_ref,  # (S, H) float32 SMEM combine coefficients (_layer_coeffs)
-    *refs,  # [k_scale_ref (S, 1, block_k), v_scale_ref (1, block_k) if
-    #          quantized] then out_ref (1, L, dv) and scratch:
-    #          m (S, L), l (S, L), acc (S, L, dv) — all fp32
-    n_heads: int,
-    n_rows: int,
-    quantized: bool,
-):
-    if quantized:
-        ks_ref, vs_ref, out_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        out_ref, m_scr, l_scr, acc_scr = refs
-    L = n_rows
-    S, d = q_ref.shape[1] // L, q_ref.shape[2]
-    block_k = k_ref.shape[2]
-    bh = pl.program_id(0)
-    j = pl.program_id(1)
-    nk = pl.num_programs(1)
-    # per-row positions; the tile-skip bound is the rows' max (static
-    # unroll over the tiny L to keep SMEM reads scalar-indexed)
-    pos_l = [pos_ref[bh, l] for l in range(L)]
-    pos_max = pos_l[0]
-    for l in range(1, L):
-        pos_max = jnp.maximum(pos_max, pos_l[l])
-    scale = 1.0 / math.sqrt(d)
-
-    @pl.when(j == 0)
-    def _():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    # a tile entirely past every row's position is skipped outright;
-    # per-row visibility (col <= pos[l]) is masked below. The row loop
-    # is a STATIC unroll (L is tiny) running per row EXACTLY the op
-    # sequence of the single-query kernel above — a batched (S, L,
-    # block_k) dot would reassociate the d-reduction and break the
-    # bit-parity the greedy spec/non-spec pin depends on.
-    @pl.when(j * block_k <= pos_max)
-    def _():
-        q_all = q_ref[0].reshape(S, L, d)
-        k_j = k_ref[:, 0]  # (S, block_k, d)
-        v_j = v_ref[0]  # (block_k, dv)
-        if quantized:
-            k_j = (
-                k_j.astype(jnp.float32) * ks_ref[:, 0][:, :, None]
-            ).astype(q_all.dtype)
-            v_j = (
-                v_j.astype(jnp.float32) * vs_ref[0][:, None]
-            ).astype(q_all.dtype)
-        cols = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1
-        )
-        for l in range(L):
-            q = q_all[:, l]  # (S, d)
-            s = jax.lax.dot_general(
-                q, k_j,
-                dimension_numbers=(((1,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            ) * scale  # (S, block_k)
-            s = jnp.where(cols <= pos_l[l], s, NEG_INF)
-            m_prev = m_scr[:, l:l + 1]  # (S, 1)
-            m_new = jnp.maximum(
-                m_prev, jnp.max(s, axis=-1, keepdims=True)
-            )
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)  # (S, block_k)
-            l_scr[:, l:l + 1] = (
-                l_scr[:, l:l + 1] * alpha
-                + jnp.sum(p, axis=-1, keepdims=True)
-            )
-            pv = jax.lax.dot_general(
-                p.astype(v_j.dtype), v_j,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # (S, dv)
-            acc_scr[:, l] = acc_scr[:, l] * alpha + pv
-            m_scr[:, l:l + 1] = m_new
-
-    @pl.when(j == nk - 1)
-    def _():
-        h = jax.lax.rem(bh, jnp.int32(n_heads))
-        for l in range(L):
-            l_safe = jnp.maximum(l_scr[:, l:l + 1], 1e-30)
-            o_s = acc_scr[:, l] / l_safe  # (S, dv) per-stream outputs
-            combined = o_s[0:1] * c_ref[0, h]
-            for s_i in range(1, S):
-                combined += o_s[s_i:s_i + 1] * c_ref[s_i, h]
-            out_ref[0, l] = combined[0].astype(out_ref.dtype)
 
 
 def decode_attention_multi(
@@ -541,85 +450,10 @@ def decode_attention_multi(
     MORE batch rows than there are query slots (``R > B``: the spec
     engine's trash row rides at index B and is never attended).
     Returns ``(B, L, H, dv)`` in the query dtype."""
-    S, B, L, H, d = qs.shape
-    R, M = k_cache.shape[1], k_cache.shape[3]
-    dv = v_cache.shape[-1]
-    BH = B * H
-    if interpret is None:
-        interpret = auto_interpret()
-    bk = pick_block(block_k or _DEFAULT_BLOCK_K, M)
-    nk = M // bk
-    quantized = k_scale is not None
-    if quantized != (v_scale is not None):
-        raise ValueError("k_scale and v_scale must be given together")
-
-    # (S, B, L, H, d) -> (B, H, S, L, d) -> (BH, S*L, d): stream-major
-    # row packing, so the kernel's reshape to (S, L, d) is zero-copy
-    q = qs.transpose(1, 3, 0, 2, 4).reshape(BH, S * L, d)
-    k = k_cache.reshape(S, R * H, M, d)  # zero-copy: head-major layout
-    v = v_cache.reshape(R * H, M, dv)
-    pos_bh = jnp.repeat(
-        jnp.asarray(pos, jnp.int32), H, axis=0
-    )  # (B*H, L): row b*H+h carries slot b's positions
-
-    inputs = [q, k, v, pos_bh, coeffs.astype(jnp.float32)]
-    in_specs = [
-        pl.BlockSpec((1, S * L, d), lambda bh, j: (bh, 0, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((S, 1, bk, d), lambda bh, j: (0, bh, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, dv), lambda bh, j: (bh, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((BH, L), lambda bh, j: (0, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((S, H), lambda bh, j: (0, 0),
-                     memory_space=pltpu.SMEM),
-    ]
-    if quantized:
-        inputs += [
-            k_scale.reshape(S, R * H, M).astype(jnp.float32),
-            v_scale.reshape(R * H, M).astype(jnp.float32),
-        ]
-        in_specs += [
-            pl.BlockSpec((S, 1, bk), lambda bh, j: (0, bh, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk), lambda bh, j: (bh, j),
-                         memory_space=pltpu.VMEM),
-        ]
-    out = pl.pallas_call(
-        functools.partial(
-            _dattn_mq_fwd_kernel, n_heads=H, n_rows=L,
-            quantized=quantized,
-        ),
-        grid=(BH, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, L, dv), lambda bh, j: (bh, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((BH, L, dv), qs.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((S, L), jnp.float32),
-            pltpu.VMEM((S, L), jnp.float32),
-            pltpu.VMEM((S, L, dv), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(*inputs)
-    # (BH, L, dv) -> (B, L, H, dv)
-    return out.reshape(B, H, L, dv).transpose(0, 2, 1, 3)
-
-
-def _dattn_mq_paged_kernel(pt_ref, *args, n_heads: int, n_rows: int,
-                           quantized: bool):
-    """Paged twin of :func:`_dattn_mq_fwd_kernel`: the page table did
-    its work in the scalar-prefetch index maps (same maps as
-    :func:`decode_attention_paged`), so the body sees (S, 1, ps, d)
-    tiles in logical ring order and delegates wholesale. ``_dattn_``
-    needle kept for tools/profile_step.py bucketing."""
-    del pt_ref  # consumed by the index maps
-    _dattn_mq_fwd_kernel(*args, n_heads=n_heads, n_rows=n_rows,
-                         quantized=quantized)
+    return _dattn_call(
+        qs, k_cache, v_cache, jnp.asarray(pos, jnp.int32), coeffs,
+        k_scale, v_scale, None, block_k, interpret,
+    )
 
 
 def decode_attention_multi_paged(
@@ -640,85 +474,10 @@ def decode_attention_multi_paged(
     step streams one physical page, int8 dequant fused in the load)
     with row-causal ``col <= pos[b, l]`` visibility. Runtime int32
     tables ⇒ page churn between calls compiles nothing new."""
-    S, P, H, ps, d = k_pages.shape
-    dv = v_pages.shape[-1]
-    B, pp = page_tables.shape
-    L = qs.shape[2]
-    BH = B * H
-    if interpret is None:
-        interpret = auto_interpret()
-    quantized = k_scale is not None
-    if quantized != (v_scale is not None):
-        raise ValueError("k_scale and v_scale must be given together")
-
-    q = qs.transpose(1, 3, 0, 2, 4).reshape(BH, S * L, d)
-    k = k_pages.reshape(S, P * H, ps, d)  # zero-copy: head-major pages
-    v = v_pages.reshape(P * H, ps, dv)
-    pos_bh = jnp.repeat(jnp.asarray(pos, jnp.int32), H, axis=0)
-    pt = jnp.asarray(page_tables, jnp.int32)
-
-    def _k_map(bh, j, pt_ref):
-        return (0, pt_ref[bh // H, j] * H + bh % H, 0, 0)
-
-    def _v_map(bh, j, pt_ref):
-        return (pt_ref[bh // H, j] * H + bh % H, 0, 0)
-
-    inputs = [q, k, v, pos_bh, coeffs.astype(jnp.float32)]
-    in_specs = [
-        pl.BlockSpec((1, S * L, d), lambda bh, j, pt_ref: (bh, 0, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((S, 1, ps, d), _k_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, ps, dv), _v_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((BH, L), lambda bh, j, pt_ref: (0, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((S, H), lambda bh, j, pt_ref: (0, 0),
-                     memory_space=pltpu.SMEM),
-    ]
-    if quantized:
-        inputs += [
-            k_scale.reshape(S, P * H, ps).astype(jnp.float32),
-            v_scale.reshape(P * H, ps).astype(jnp.float32),
-        ]
-        in_specs += [
-            pl.BlockSpec(
-                (S, 1, ps),
-                lambda bh, j, pt_ref: (0, pt_ref[bh // H, j] * H
-                                       + bh % H, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, ps),
-                lambda bh, j, pt_ref: (pt_ref[bh // H, j] * H
-                                       + bh % H, 0),
-                memory_space=pltpu.VMEM,
-            ),
-        ]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(BH, pp),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, L, dv),
-                               lambda bh, j, pt_ref: (bh, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((S, L), jnp.float32),
-            pltpu.VMEM((S, L), jnp.float32),
-            pltpu.VMEM((S, L, dv), jnp.float32),
-        ],
+    return _dattn_call(
+        qs, k_pages, v_pages, jnp.asarray(pos, jnp.int32), coeffs,
+        k_scale, v_scale, page_tables, 0, interpret,
     )
-    out = pl.pallas_call(
-        functools.partial(
-            _dattn_mq_paged_kernel, n_heads=H, n_rows=L,
-            quantized=quantized,
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((BH, L, dv), qs.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(pt, *inputs)
-    return out.reshape(B, H, L, dv).transpose(0, 2, 1, 3)
 
 
 def decode_attention_multi_reference(

@@ -49,16 +49,13 @@ from __future__ import annotations
 
 import functools
 import math
+import warnings
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from differential_transformer_replication_tpu.utils.compat import (
-    CompilerParams as _CompilerParams,
-)
 
 from differential_transformer_replication_tpu.ops.streams import (
     NEG_INF,
@@ -118,17 +115,29 @@ _TUNED_BLOCKS = {
 _CONSERVATIVE_BLOCKS = (256, 512, 256, 256)
 
 
+def tuned_block_key(device_kind: str) -> Optional[str]:
+    """The ``_TUNED_BLOCKS`` key a ``device_kind`` string matches, or
+    None (a v5e reports ``"TPU v5 lite"``)."""
+    kind = device_kind.lower()
+    return next((key for key in _TUNED_BLOCKS if key in kind), None)
+
+
 def default_blocks() -> tuple:
     """(block_q, block_k, block_q_train, block_k_train) for the current
-    backend: tuned tiles on known TPU kinds, conservative ones elsewhere,
-    tuned for the interpreter (tile size is semantics-free there)."""
+    backend: tuned tiles on known TPU kinds, conservative ones (with a
+    warning that names the kind) on others, tuned for the interpreter
+    (tile size is semantics-free there)."""
     if jax.default_backend() != "tpu":
         return _TUNED_BLOCKS["v5 lite"]
-    kind = jax.devices()[0].device_kind.lower()
-    for key, blocks in _TUNED_BLOCKS.items():
-        if key in kind:
-            return blocks
-    return _CONSERVATIVE_BLOCKS
+    kind = jax.devices()[0].device_kind
+    key = tuned_block_key(kind)
+    if key is None:
+        warnings.warn(
+            "no tuned flash tiles for TPU kind %r; using the conservative "
+            "%r" % (kind, _CONSERVATIVE_BLOCKS)
+        )
+        return _CONSERVATIVE_BLOCKS
+    return _TUNED_BLOCKS[key]
 
 
 # ---------------------------------------------------------------------------
@@ -536,7 +545,7 @@ def _fwd_call(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
@@ -710,7 +719,7 @@ def _tiled_fwd_call(
             pltpu.VMEM((S, block_q), jnp.float32),
             pltpu.VMEM((S, block_q, dv), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -954,7 +963,7 @@ def _tiled_bwd_call(
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((BH, S, T, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((S, block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -995,7 +1004,7 @@ def _tiled_bwd_call(
             pltpu.VMEM((S, block_k, d), jnp.float32),
             pltpu.VMEM((block_k, dv_width), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -1354,7 +1363,7 @@ def _fused_bwd_call(
             jax.ShapeDtypeStruct((BH, S, T, d), q.dtype),
             jax.ShapeDtypeStruct((BH, T, dv_width), v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
         interpret=interpret,
@@ -1454,7 +1463,7 @@ def _bwd_call(
         out_specs=pl.BlockSpec((1, S, block_q, d), lambda b, i: (b, 0, i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((BH, S, T, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
@@ -1500,7 +1509,7 @@ def _bwd_call(
             jax.ShapeDtypeStruct((BH, S, T, d), q.dtype),
             jax.ShapeDtypeStruct((BH, T, dv_width), v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
@@ -1636,7 +1645,7 @@ def _chunk_fwd_call(q, k, v, offset, *, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((BH, S, T, dv), q.dtype),
             jax.ShapeDtypeStruct((BH, S, T), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
@@ -2006,7 +2015,7 @@ def _tm_fwd_call(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_TM_VMEM_LIMIT,
         ),
@@ -2154,7 +2163,7 @@ def _tm_bwd_call(qs, ks, v, g, lse, delta, coeffs, *, H: int, interpret: bool):
             + [jax.ShapeDtypeStruct((B, T, Hd), qs[0].dtype)] * S
             + [jax.ShapeDtypeStruct((B, T, Hdv), v.dtype)]
         ),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_TM_VMEM_LIMIT,
         ),
@@ -2387,7 +2396,7 @@ def _tm_fwd_call_packed(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_TM_VMEM_LIMIT,
         ),
@@ -2470,7 +2479,7 @@ def _tm_bwd_call_packed(
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[jax.ShapeDtypeStruct((B, T, W), proj.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_TM_VMEM_LIMIT,
         ),

@@ -49,10 +49,6 @@ from differential_transformer_replication_tpu.ops.flash import (
     auto_interpret,
     pick_block,
 )
-from differential_transformer_replication_tpu.utils.compat import (
-    CompilerParams as _CompilerParams,
-)
-
 _DEFAULT_BLOCK_M = 256
 _DEFAULT_BLOCK_F = 512
 
@@ -109,7 +105,7 @@ def _fwd_call(x2, wg, bg2, wx, bx2, *, block_m, block_f, interpret):
         in_specs=in_specs,
         out_shape=jax.ShapeDtypeStruct((M, F), x2.dtype),
         out_specs=h_spec,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
@@ -188,7 +184,7 @@ def _bwd_call(x2, wg, bg2, wx, bx2, gh, *, block_m, block_f, interpret):
             jax.ShapeDtypeStruct((1, F), jnp.float32),    # dbx
         ],
         out_specs=[h_spec, h_spec, w_spec, dwb_spec, w_spec, dwb_spec],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,

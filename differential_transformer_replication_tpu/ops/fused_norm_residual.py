@@ -49,10 +49,6 @@ from differential_transformer_replication_tpu.ops.flash import (
     auto_interpret,
     pick_block,
 )
-from differential_transformer_replication_tpu.utils.compat import (
-    CompilerParams as _CompilerParams,
-)
-
 _DEFAULT_BLOCK_M = 256
 
 
@@ -103,7 +99,7 @@ def _fwd_call(x2, d2, w2, b2, *, eps, has_delta, block_m, interpret):
         in_specs=in_specs,
         out_shape=out_shapes,
         out_specs=out_specs,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*inputs)
 
@@ -169,7 +165,7 @@ def _bwd_call(x2, w2, gn2, gx2, *, eps, block_m, interpret):
             jax.ShapeDtypeStruct((1, E), jnp.float32),
         ],
         out_specs=[row_spec, par_spec, par_spec],
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*inputs)
 

@@ -53,7 +53,6 @@ from differential_transformer_replication_tpu.train.step import (
     make_step_fn,
 )
 from differential_transformer_replication_tpu.utils import faults
-from differential_transformer_replication_tpu.utils.compat import shard_map
 
 
 def _attach_compile_counter(step, jitted, label: str):
@@ -72,6 +71,7 @@ def _attach_compile_counter(step, jitted, label: str):
     logged once at build so a drifted jax version is visible in the
     run log, not just as a changed metric baseline.
     """
+    step.jitted = jitted  # for lowering: what the step compiles to
     cache_size = getattr(jitted, "_cache_size", None)
     if cache_size is not None:
         step._cache_size = cache_size
@@ -208,7 +208,7 @@ def _make_overlap_train_step(cfg: TrainConfig, mesh: Mesh):
         # path — armed faults never change the jit signature mid-run
         batch_specs["poison"] = P()
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         raw,
         mesh=mesh,
         in_specs=(P(), batch_specs, P()),

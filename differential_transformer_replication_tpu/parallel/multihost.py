@@ -42,8 +42,7 @@ def initialize(
     No-op when running single-process with no explicit arguments — the
     common laptop/single-chip case needs no coordinator. On TPU pods all
     three arguments autodetect from the environment when left None."""
-    already = getattr(jax.distributed, "is_initialized", None)
-    if callable(already) and already():
+    if jax.distributed.is_initialized():
         return
     if (
         coordinator_address is None

@@ -81,7 +81,6 @@ from differential_transformer_replication_tpu.ops import causal_mask, rope_cos_s
 from differential_transformer_replication_tpu.parallel.sharding import spec_for
 from differential_transformer_replication_tpu.train.optim import make_optimizer
 from differential_transformer_replication_tpu.train.step import create_train_state
-from differential_transformer_replication_tpu.utils.compat import shard_map as _shard_map
 
 _DATA_AXES = ("data", "fsdp")
 _PIPE_AXIS = "pipeline"
@@ -329,7 +328,7 @@ def make_pipeline_loss(model_cfg: ModelConfig, mesh: Mesh):
     # (_unmatch_spec, jax 0.9) rejects a manual-subset axis_names; under
     # jit the auto axes partition correctly. Nested under the train-step
     # jit this inlines.
-    smapped_plain = jax.jit(_shard_map(
+    smapped_plain = jax.jit(jax.shard_map(
         lambda b, r, x, y: spmd(b, r, x, y, None),
         mesh=mesh,
         in_specs=data_specs,
@@ -337,7 +336,7 @@ def make_pipeline_loss(model_cfg: ModelConfig, mesh: Mesh):
         axis_names=manual_axes,
         check_vma=False,
     ))
-    smapped_dropout = jax.jit(_shard_map(
+    smapped_dropout = jax.jit(jax.shard_map(
         spmd,
         mesh=mesh,
         in_specs=data_specs + (P(),),

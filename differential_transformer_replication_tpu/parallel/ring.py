@@ -46,10 +46,6 @@ from differential_transformer_replication_tpu.ops.streams import (
     ndiff_coeffs,
     vanilla_coeffs,
 )
-from differential_transformer_replication_tpu.utils.compat import (
-    axis_size as _axis_size,
-    shard_map as _shard_map,
-)
 
 _BATCH_AXES = ("data", "fsdp")
 _SEQ_AXIS = "sequence"
@@ -79,7 +75,7 @@ def _ring_flash_body(
     the rng so shards decorrelate."""
     S, B, Tl, H, d = qs.shape
     dv = v.shape[-1]
-    p = _axis_size(_SEQ_AXIS)
+    p = jax.lax.axis_size(_SEQ_AXIS)
     my = jax.lax.axis_index(_SEQ_AXIS)
     interpret = auto_interpret()
     bq = pick_block(128, Tl)
@@ -141,7 +137,7 @@ def _ring_shard_body(
     dense path)."""
     S, B, Tl, H, d = qs.shape
     dv = v.shape[-1]
-    p = _axis_size(_SEQ_AXIS)
+    p = jax.lax.axis_size(_SEQ_AXIS)
     my = jax.lax.axis_index(_SEQ_AXIS)
     scale = 1.0 / math.sqrt(d)
     use_drop = dropout_rate > 0.0 and dropout_rng is not None
@@ -209,7 +205,7 @@ def sequence_shard_map(body, mesh: Mesh, qs, ks, v, coeffs, *, dropout_rng=None)
                 pos = pos * mesh.shape[ax] + jax.lax.axis_index(ax)
             return body(qs_l, ks_l, v_l, c_l, jax.random.fold_in(rng, pos))
 
-        inner = _shard_map(
+        inner = jax.shard_map(
             folded,
             mesh=mesh,
             in_specs=(qk_spec, qk_spec, v_spec, c_spec, P()),
@@ -218,7 +214,7 @@ def sequence_shard_map(body, mesh: Mesh, qs, ks, v, coeffs, *, dropout_rng=None)
         )
         return inner(qs, ks, v, coeffs, dropout_rng)
 
-    inner = _shard_map(
+    inner = jax.shard_map(
         lambda a, b, c, d: body(a, b, c, d, None),
         mesh=mesh,
         in_specs=(qk_spec, qk_spec, v_spec, c_spec),

@@ -38,7 +38,6 @@ from differential_transformer_replication_tpu.ops.streams import (
     ndiff_coeffs,
     vanilla_coeffs,
 )
-from differential_transformer_replication_tpu.utils.compat import shard_map as _shard_map
 
 _BATCH_AXES = ("data", "fsdp")
 _HEAD_AXIS = "tensor"
@@ -87,7 +86,7 @@ def shard_flash_multi_stream_attention(
                 dropout_rng=jax.random.fold_in(rng, pos),
             )
 
-        inner = _shard_map(
+        inner = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(qk_spec, qk_spec, v_spec, c_spec, P()),
@@ -99,7 +98,7 @@ def shard_flash_multi_stream_attention(
     def body(qs_l, ks_l, v_l, c_l):
         return multi_stream_flash_attention(qs_l, ks_l, v_l, c_l)
 
-    inner = _shard_map(
+    inner = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(qk_spec, qk_spec, v_spec, c_spec),

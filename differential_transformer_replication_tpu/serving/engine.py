@@ -539,20 +539,20 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
     # Donate the cache pool so XLA updates it in place instead of
     # allocating + copying a second full pool per chunk/step (the engine
     # always rebinds self.cache to the result, so the old buffers are
-    # dead). CPU has no donation support and would warn on every call.
-    donate = jax.default_backend() != "cpu"
+    # dead). Every backend donates, the CPU included, so a read of a
+    # donated pool fails in the tests and not first on the chip.
     if page_size > 0:
         return (
-            jax.jit(_prefill_paged, donate_argnums=(1,) if donate else ()),
-            jax.jit(_decode_paged, donate_argnums=(3,) if donate else ()),
+            jax.jit(_prefill_paged, donate_argnums=(1,)),
+            jax.jit(_decode_paged, donate_argnums=(3,)),
             jax.jit(_sample),
-            jax.jit(_page_copy, donate_argnums=(0,) if donate else ()),
+            jax.jit(_page_copy, donate_argnums=(0,)),
             jax.jit(_page_extract),  # cache NOT donated: it stays live
-            jax.jit(_page_inject, donate_argnums=(0,) if donate else ()),
+            jax.jit(_page_inject, donate_argnums=(0,)),
         )
     return (
-        jax.jit(_prefill, donate_argnums=(1,) if donate else ()),
-        jax.jit(_decode, donate_argnums=(4,) if donate else ()),
+        jax.jit(_prefill, donate_argnums=(1,)),
+        jax.jit(_decode, donate_argnums=(4,)),
         jax.jit(_sample),
         None,
         None,
@@ -817,7 +817,6 @@ def _build_spec_step_fns(cfg: ModelConfig, rope_len: int, draft_len: int,
             ))
         return jnp.concatenate(cols, axis=1)
 
-    donate = jax.default_backend() != "cpu"
     if page_size > 0:
 
         def _spec_step(params, ints, cache, page_tables, allowed,
@@ -836,9 +835,7 @@ def _build_spec_step_fns(cfg: ModelConfig, rope_len: int, draft_len: int,
             )
             return _pack_out(*out), new_cache
 
-        return jax.jit(
-            _spec_step, donate_argnums=(2,) if donate else ()
-        )
+        return jax.jit(_spec_step, donate_argnums=(2,))
 
     def _spec_step(params, ints, cache, allowed, pcounts):
         (tokens, pos, row_target, draft, dlen, counts, topks,
@@ -855,7 +852,7 @@ def _build_spec_step_fns(cfg: ModelConfig, rope_len: int, draft_len: int,
         )
         return _pack_out(*out), new_cache
 
-    return jax.jit(_spec_step, donate_argnums=(2,) if donate else ())
+    return jax.jit(_spec_step, donate_argnums=(2,))
 
 
 def _penalties_on(p) -> bool:

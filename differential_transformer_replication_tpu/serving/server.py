@@ -92,6 +92,11 @@ from differential_transformer_replication_tpu.serving.scheduler import (
     DeadlineExceededError,
     QueueFullError,
 )
+from differential_transformer_replication_tpu.utils.device import (
+    device_summary,
+    peak_memory_bytes,
+    setup_compile_cache,
+)
 
 
 class ShuttingDownError(RuntimeError):
@@ -945,6 +950,10 @@ def _make_handler(client: ServingClient, tokenizer=None, events=None,
                     "restarts": client.runner.restarts,
                     "last_step_s": client.runner.last_step_s,
                     "stats": client.stats,
+                    # which device this replica runs on, as JAX reports
+                    # it, and its memory high-water mark (None on CPU)
+                    "device": {**device_summary(),
+                               "peak_bytes_in_use": peak_memory_bytes()},
                 }
                 # compile-cache sizes, so fleet chaos tests can pin
                 # "zero added recompiles" on REMOTE replicas too
@@ -1604,6 +1613,7 @@ def main() -> None:
                         "tools/ckpt_doctor.py --adopt-legacy)")
     args = p.parse_args()
 
+    setup_compile_cache()
     meta = None
     if args.checkpoint:
         from differential_transformer_replication_tpu.train.checkpoint import (

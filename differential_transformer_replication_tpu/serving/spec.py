@@ -273,10 +273,9 @@ def _drafter_step_fns(cfg, rope_len: int, k: int):
         )
         return out, ok, cache
 
-    donate = jax.default_backend() != "cpu"
     return (
-        jax.jit(_prefill, donate_argnums=(1,) if donate else ()),
-        jax.jit(_propose, donate_argnums=(4,) if donate else ()),
+        jax.jit(_prefill, donate_argnums=(1,)),
+        jax.jit(_propose, donate_argnums=(4,)),
     )
 
 

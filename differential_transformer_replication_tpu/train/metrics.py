@@ -42,12 +42,9 @@ from differential_transformer_replication_tpu.config import TrainConfig
 def device_memory_mb() -> Optional[float]:
     """Allocated device memory in MB (the reference logs
     torch.cuda.memory_allocated/1024**2, train.py:293), or None when the
-    platform exposes no memory stats (CPU, some simulators) — callers
-    must OMIT the metric rather than log a misleading zero."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        return None
+    platform exposes no memory stats (the CPU backend returns None) —
+    callers must OMIT the metric rather than log a misleading zero."""
+    stats = jax.local_devices()[0].memory_stats()
     if not stats or "bytes_in_use" not in stats:
         return None
     return stats["bytes_in_use"] / 1024**2
@@ -97,16 +94,12 @@ class MetricLogger:
         """One identity record per logger lifetime (i.e. per process
         incarnation): joins records across supervisor relaunches. JSONL
         only — wandb carries the config natively via init."""
-        try:
-            device_kind = jax.local_devices()[0].device_kind
-        except Exception:
-            device_kind = "unknown"
         header = {
             "record": "run_header",
             "ts": round(time.time(), 3),
             "config_hash": config_hash(self.cfg),
             "jax_version": jax.__version__,
-            "device_kind": device_kind,
+            "device_kind": jax.local_devices()[0].device_kind,
             "device_count": jax.device_count(),
             "process_count": jax.process_count(),
             "model": self.cfg.resolved_model().model,

@@ -868,7 +868,7 @@ class TestGL401UnboundCollective:
 
     def test_negative_wrapper_idiom(self, tmp_path):
         # body reaches shard_map only through a wrapper's parameter —
-        # the compat.shard_map shape
+        # (a project-local shard_map wrapper)
         res = lint_src(tmp_path, SHARD_HEADER + (
             "def wrapper(fn, mesh):\n"
             "    return shard_map(fn, mesh=mesh, in_specs=None,\n"

@@ -18,6 +18,9 @@ import pytest
 
 REPO = Path(__file__).resolve().parents[1]
 FIXTURE = REPO / "tests" / "fixtures" / "synthetic_tpu.xplane.pb"
+# synthetic round archives in the shape a driver wraps bench.py's line
+# in ({"rc", "tail", "parsed"}); made-up values, not measurements
+BENCH_ROUNDS = REPO / "tests" / "fixtures" / "bench_rounds"
 
 from differential_transformer_replication_tpu.obs import xprof
 from differential_transformer_replication_tpu.obs.device_profile import (
@@ -218,6 +221,17 @@ class TestXprofParser:
         assert xprof.derived_metrics(0.0, flops_per_step=1e12) == {}
         # the 6*N*D convention shared with bench.py
         assert xprof.train_flops_per_step(100, 40, 10) == 6 * 60 * 10
+
+    def test_device_peaks_keyed_by_kind_unknown_is_error(self):
+        v5e = xprof.device_peaks("TPU v5 lite")
+        assert v5e["bf16_flops_per_s"] == 197e12
+        assert v5e["hbm_bytes_per_s"] == 819e9
+        # no default: a chip the table does not hold (or the CPU) raises
+        for kind in ("TPU v9", "cpu"):
+            with pytest.raises(KeyError, match="no published peaks"):
+                xprof.device_peaks(kind)
+        # and without a peak no utilization is derived
+        assert "mfu" not in xprof.derived_metrics(10.0, flops_per_step=1e12)
 
     def test_plane_to_chrome_events(self):
         planes = xprof.parse_xspace(FIXTURE.read_bytes())
@@ -485,8 +499,10 @@ class TestTrainerIntegration:
         for rec in dev:
             assert rec["process"] == "trainer"
             assert "bucket_ms" in rec and "busy_ms" in rec
-            # the trainer wires bench.py's 6*N*D estimate -> derived mfu
-            assert "mfu" in rec
+            # the trainer wires bench.py's 6*N*D estimate and the
+            # device's published peak -> derived mfu; the CPU backend
+            # has no peak, so a CPU run publishes no utilization
+            assert "mfu" not in rec
             assert os.path.exists(rec["trace_file"])
         # THE pin: continuous profiling adds zero retraces
         steps = [l for l in lines if "compile_events" in l]
@@ -625,11 +641,11 @@ def _run_gate(args, cwd=None):
 
 
 class TestPerfGate:
-    def test_committed_bench_history_passes_schema_pinned(self):
-        """The tier-1 CI smoke from the issue: the gate runs over the
-        committed BENCH_r0*.json rounds, passes, and the summary
-        schema is pinned."""
-        rounds = sorted(str(p) for p in REPO.glob("BENCH_r0*.json"))
+    def test_round_archive_history_passes_schema_pinned(self):
+        """The gate runs over a trajectory of driver-wrapped round
+        archives (the synthetic fixture rounds), passes, and the
+        summary schema is pinned."""
+        rounds = sorted(str(p) for p in BENCH_ROUNDS.glob("BENCH_r0*.json"))
         assert len(rounds) >= 3
         r = _run_gate([*rounds, "--key", "value", "--key", "mfu_6nd"])
         assert r.returncode == 0, r.stdout + r.stderr
@@ -722,10 +738,11 @@ class TestPerfGate:
 
 
 class TestBenchTrend:
-    def test_committed_trajectory_summary(self):
+    def test_round_archive_trajectory_summary(self):
+        rounds = sorted(str(p) for p in BENCH_ROUNDS.glob("BENCH_r0*.json"))
         r = subprocess.run(
             [sys.executable, str(REPO / "tools" / "bench_trend.py"),
-             "--ascii"],
+             "--ascii", *rounds],
             capture_output=True, text=True, timeout=120, cwd=str(REPO),
         )
         assert r.returncode == 0, r.stderr
@@ -738,8 +755,6 @@ class TestBenchTrend:
         assert {"median", "mad", "window_n"} <= set(
             doc["baseline"]["value"]
         )
-        # the r01-r05 predate-the-kernels caveat rides the output
-        assert "predate" in doc["note"]
         assert "bench_trend" in r.stderr  # --ascii sparklines
 
 

@@ -672,17 +672,24 @@ class TestToolGates:
             d.get("impl") for d in lines if d["case"] == "ffn_chain"
         }
 
-    def test_profile_step_json_line(self):
-        r = subprocess.run(
-            [sys.executable, str(REPO / "tools" / "profile_step.py"),
-             "--json", "--steps", "2", "--micro-batch", "2",
-             "--block-size", "16", "--n-embd", "32", "--n-head", "2",
-             "--n-layer", "2", "--vocab-size", "64", "--dtype", "float32"],
-            capture_output=True, text=True, cwd=str(REPO), timeout=580,
-            env=_cpu_env(),
+    def test_profile_step_json_line(self, capsys):
+        """The capture + report plumbing at toy size, in-process (the
+        command itself refuses to run without a TPU, below)."""
+        import importlib.util
+
+        spec = importlib.util.spec_from_file_location(
+            "profile_step", REPO / "tools" / "profile_step.py"
         )
-        assert r.returncode == 0, r.stderr[-2000:]
-        doc = json.loads(r.stdout.strip().splitlines()[-1])
+        tool = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(tool)
+        args = tool.build_parser().parse_args(
+            ["--json", "--steps", "2", "--micro-batch", "2",
+             "--block-size", "16", "--n-embd", "32", "--n-head", "2",
+             "--n-layer", "2", "--vocab-size", "64", "--dtype", "float32"]
+        )
+        out_dir, compiles = tool.capture(args)
+        tool.report(out_dir, args.steps, args.top, compiles, args.json)
+        doc = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
         assert doc["metric"] == "profile_step_breakdown"
         # the capture window ran inside the recompile sentinel — a
         # warmed-up tiny step compiles nothing inside the window
@@ -690,6 +697,25 @@ class TestToolGates:
         # CPU CI has no TPU plane: the breakdown degrades to an explicit
         # error field, never a crash (TPU runs carry groups_ms_per_step)
         assert ("groups_ms_per_step" in doc) or ("error" in doc)
+
+    @pytest.mark.parametrize("tool,argv", [
+        ("tools/profile_step.py", ["--json", "--steps", "1"]),
+        ("tools/ffn_sweep.py", []),
+        ("tools/decode_attn_sweep.py", []),
+        ("bench.py", []),
+    ])
+    def test_measurement_commands_refuse_to_time_the_cpu(self, tool, argv):
+        """No chip, no number: outside their --smoke gates the
+        measurement scripts exit instead of timing XLA:CPU or the
+        Pallas interpreter."""
+        r = subprocess.run(
+            [sys.executable, str(REPO / tool), *argv],
+            capture_output=True, text=True, cwd=str(REPO), timeout=120,
+            env=_cpu_env(),
+        )
+        assert r.returncode != 0
+        assert "JAX found no TPU" in r.stderr
+        assert r.stdout.strip() == ""
 
 
 def _cpu_env():

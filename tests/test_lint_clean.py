@@ -58,8 +58,8 @@ def test_engine_actually_analyzed_the_tree():
     _, doc = _lint_json()
     assert doc["files_scanned"] >= 60, doc["files_scanned"]
     # train/step.py + engine closures + models stack + the PR-11
-    # interprocedural expansion (Pallas kernels, shard_map bodies
-    # through the compat wrapper, defvjp pairs) exceed this by a lot;
+    # interprocedural expansion (Pallas kernels, shard_map bodies,
+    # defvjp pairs) exceed this by a lot;
     # the floor pins that the expansion never silently regresses
     assert doc["jit_regions"] >= 200, doc["jit_regions"]
     # GL1xx-GL6xx: 10 original + 9 sharding/pallas/concurrency rules

@@ -88,3 +88,20 @@ def test_native_reports_availability():
     # in this image g++ is baked in, so the native path should build;
     # if it ever can't, the numpy fallback keeps everything above passing
     assert isinstance(native_available(), bool)
+
+
+def test_built_library_is_keyed_by_source_hash(monkeypatch, tmp_path):
+    """The .so name carries the hash of the C++ source, so a copied tree
+    (mtimes out of order) never loads a binary of another version: an
+    edited source resolves to a different, not-yet-built path."""
+    import hashlib
+
+    digest = hashlib.sha256(native._SRC.read_bytes()).hexdigest()[:16]
+    assert native._lib_path().name == f"libdata_native.{digest}.so"
+    if native_available():
+        assert native._lib_path().exists()
+    edited = tmp_path / "data_native.cpp"
+    edited.write_bytes(native._SRC.read_bytes() + b"\n// edited\n")
+    monkeypatch.setattr(native, "_SRC", edited)
+    assert native._lib_path().name != f"libdata_native.{digest}.so"
+    assert not native._lib_path().exists()

@@ -1,0 +1,316 @@
+"""Compile the main path's kernels for a TPU v5e that is described, not
+attached (``topologies.get_topology_desc("tpu", "v5e:2x2")``).
+
+Interpret mode checks none of what the chip's compiler refuses: block
+shapes off the (8, 128) tiling, a matmul Mosaic cannot express, too much
+fast memory. These cases hand the real lowering recipe-width shapes, so a
+kernel that stops compiling for the chip fails here, at no chip time.
+Nothing runs — a compile that passes is not a chip run.
+
+The code under test asks ``auto_interpret()`` and still sees the CPU, so
+the fixture steers that one probe to the compiled path. The persistent
+compile cache is switched off around the module: such compiles would be
+written to it and could not be read back without a chip. libtpu admits
+one process at a time: under pytest-xdist the workers that lose its lock
+skip these cases (the tier-1 command runs them serially).
+"""
+
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from differential_transformer_replication_tpu.config import (
+    MeshConfig,
+    ModelConfig,
+    ServingConfig,
+    TrainConfig,
+)
+from differential_transformer_replication_tpu.models.common import (
+    apply_block_ffn,
+    flash_bh_fn,
+)
+from differential_transformer_replication_tpu.ops.rope import rope_cos_sin
+
+# the module, not the function ops/__init__.py re-exports under the name
+dattn = importlib.import_module(
+    "differential_transformer_replication_tpu.ops.decode_attention"
+)
+
+KERNEL_MODULES = (
+    "ops.flash", "ops.fused_ffn", "ops.fused_norm_residual",
+    "ops.decode_attention",
+)
+
+# the recipe's widths (8L/768d, T=512, vocab 12000); the batch is cut, a
+# kernel's tiling does not depend on it
+E, T, B = 768, 512, 1
+FAMILIES = {
+    # family: (streams, heads, d, dv, rope) — control doubles its heads
+    "control": (1, 8, 96, 96, True),
+    "diff": (2, 4, 96, 192, False),
+    "ndiff": (4, 4, 96, 192, True),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu here: nothing to ask
+        pytest.skip(f"no TPU compiler to describe a v5e to: {e!r}")
+
+
+@pytest.fixture(autouse=True)
+def tpu_lowering(monkeypatch):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    for name in KERNEL_MODULES:
+        mod = importlib.import_module(
+            f"differential_transformer_replication_tpu.{name}"
+        )
+        for attr in ("auto_interpret", "_auto_interpret"):
+            if hasattr(mod, attr):
+                monkeypatch.setattr(mod, attr, lambda: False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def compile_for(topo, fn, *shapes, sharding=None):
+    """Compile ``fn`` for the described chip; returns the compiled text."""
+    sharding = sharding or SingleDeviceSharding(topo.devices[0])
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        shapes,
+    )
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def sds(shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _attention_shapes(family):
+    S, H, d, dv, _ = FAMILIES[family]
+    return (sds((B, T, E)), sds((S, E, H, d), jnp.float32),
+            sds((S, E, H, d), jnp.float32), sds((E, H, dv), jnp.float32),
+            sds((S, H), jnp.float32))
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_flash_attention_fwd_bwd_compiles(topo, family):
+    """The head-major flash kernels (the path of dropout > 0 and of long
+    T), forward and backward, with each family's stream count."""
+    from differential_transformer_replication_tpu.ops.flash import (
+        multi_stream_flash_attention,
+    )
+
+    S, H, d, dv, _ = FAMILIES[family]
+
+    def loss(qs, ks, v, coeffs):
+        out = multi_stream_flash_attention(qs, ks, v, coeffs)
+        return out.astype(jnp.float32).sum()
+
+    text = compile_for(
+        topo, jax.grad(loss, argnums=(0, 1, 2, 3)),
+        sds((S, B, T, H, d)), sds((S, B, T, H, d)), sds((B, T, H, dv)),
+        sds((S, H), jnp.float32),
+    )
+    assert text.count("tpu_custom_call") >= 2  # forward and backward
+
+
+@pytest.mark.parametrize("family", [
+    "diff",  # the flagship, and what chip_smoke.py trains
+    pytest.param("control", marks=pytest.mark.slow),  # 10 s
+    pytest.param("ndiff", marks=pytest.mark.slow),  # 20 s
+])
+def test_model_attention_path_compiles(topo, family):
+    """The attention path the models take at T=512 without dropout (the
+    token-major kernels; packed for diff, RoPE'd for control/ndiff),
+    projections included, forward and backward."""
+    d, rope = FAMILIES[family][2], FAMILIES[family][4]
+    cos, sin = rope_cos_sin(d, T) if rope else (None, None)
+
+    def loss(x, wq, wk, wv, coeffs):
+        out = flash_bh_fn(x, wq, wk, wv, coeffs, dropout_rate=0.0, rng=None,
+                          cos=cos, sin=sin)()
+        return out.astype(jnp.float32).sum()
+
+    text = compile_for(topo, jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                       *_attention_shapes(family))
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_flash_attention_with_dropout_compiles(topo):
+    """Dropout > 0 takes the model off the token-major path, onto the
+    head-major kernels with in-kernel masks."""
+    def loss(x, wq, wk, wv, coeffs, rng):
+        out = flash_bh_fn(x, wq, wk, wv, coeffs, dropout_rate=0.1, rng=rng)()
+        return out.astype(jnp.float32).sum()
+
+    text = compile_for(topo, jax.grad(loss, argnums=(0, 1, 2, 3)),
+                       *_attention_shapes("diff"), sds((2,), jnp.uint32))
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_fused_ffn_and_add_norm_compile(topo):
+    """A block's FFN half on the fused path (ops/fused_norm_residual.py +
+    ops/fused_ffn.py), forward and backward."""
+    cfg = ModelConfig(model="diff", n_embd=E, ffn_impl="pallas", dropout=0.0)
+    blk = {
+        "ln2": {"w": sds((E,), jnp.float32), "b": sds((E,), jnp.float32)},
+        "ffn": {
+            name: {"w": sds(shape, jnp.float32),
+                   "b": sds(shape[1:], jnp.float32)}
+            for name, shape in (("gate", (E, 4 * E)), ("xform", (E, 4 * E)),
+                                ("out", (4 * E, E)))
+        },
+    }
+
+    def loss(x, attn_out, blk):
+        return apply_block_ffn(x, attn_out, blk, cfg).astype(jnp.float32).sum()
+
+    text = compile_for(topo, jax.grad(loss, argnums=(0, 1, 2)),
+                       sds((B, T, E)), sds((B, T, E)), blk)
+    assert text.count("tpu_custom_call") >= 4  # add+norm and SwiGLU, both ways
+
+
+# decode attention at the recipe's width over a 32-slot pool; pages of 8
+# are what the verify skill and serve_bench --smoke use, 1 is the least
+DECODE_CASES = [
+    pytest.param(rows, page, kv,
+                 id=f"L{rows}-{f'page{page}' if page else 'contiguous'}-{kv}")
+    for rows in (1, 5) for page in (0, 8) for kv in ("bf16", "int8")
+] + [pytest.param(1, 1, "int8", id="L1-page1-int8")]
+
+
+@pytest.mark.parametrize("rows,page,kv", DECODE_CASES)
+def test_decode_attention_compiles(topo, rows, page, kv):
+    """The decode kernel's four entry points (single query and the
+    speculative verify's L rows; slot pool and page table), bf16 and
+    int8 KV. Every page size that divides block_size is a whole block,
+    so the compiler takes it."""
+    S, H, d, dv, _ = FAMILIES["diff"]
+    # (a pool of one-token pages is large: fewer slots there)
+    slots, M, L = 32 if page != 1 else 4, T, rows
+    store = jnp.int8 if kv == "int8" else jnp.bfloat16
+    # the spec engine's slot pool carries one trash row past the slots
+    R = (slots * M // page + 1) if page else slots + (L > 1)
+    m = page or M
+    shapes = [sds((S, slots, L, H, d)), sds((S, R, H, m, d), store),
+              sds((R, H, m, dv), store), sds((slots, L), jnp.int32),
+              sds((S, H), jnp.float32)]
+    if kv == "int8":
+        shapes += [sds((S, R, H, m), jnp.float32), sds((R, H, m), jnp.float32)]
+    if page:
+        shapes.append(sds((slots, M // page), jnp.int32))
+
+    def fn(qs, k, v, pos, coeffs, *rest):
+        scales = dict(zip(("k_scale", "v_scale"), rest[:2])) if kv == "int8" else {}
+        if L == 1:  # the single-query entry points
+            qs, pos = qs[:, :, 0], pos[:, 0]
+        if page:
+            call = (dattn.decode_attention_paged if L == 1
+                    else dattn.decode_attention_multi_paged)
+            return call(qs, k, v, rest[-1], pos, coeffs, **scales)
+        call = dattn.decode_attention if L == 1 else dattn.decode_attention_multi
+        return call(qs, k, v, pos, coeffs, **scales)
+
+    text = compile_for(topo, fn, *shapes)
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_page_size_is_refused_in_words_when_it_does_not_fit():
+    """The kernel path takes every page size that divides block_size
+    (down to 1, above), so that is the one condition a page size is held
+    to — at engine build, before the first request, in words."""
+    model = ModelConfig(model="diff", block_size=512)
+    serving = ServingConfig(decode_attention_impl="pallas", kv_page_size=24)
+    with pytest.raises(ValueError, match=r"kv_page_size \(24\) must divide "
+                                         r"block_size \(512\)"):
+        serving.resolved_pool_pages(model)
+    for ok in (1, 8, 16, 128):
+        assert ServingConfig(
+            decode_attention_impl="pallas", kv_page_size=ok, num_slots=2,
+        ).resolved_pool_pages(model) == 2 * (512 // ok)
+
+
+def _recipe(family: str, **mesh) -> TrainConfig:
+    return TrainConfig(
+        model=ModelConfig(model=family, attention_impl="pallas",
+                          ffn_impl="pallas"),
+        mesh=MeshConfig(**mesh),
+        micro_batch_size=32 * max(1, mesh.get("data", 1)),
+    )
+
+
+def _abstract_step_args(cfg: TrainConfig):
+    from differential_transformer_replication_tpu.train.step import (
+        create_train_state,
+    )
+
+    state = jax.eval_shape(
+        lambda k: create_train_state(k, cfg), jax.random.PRNGKey(0)
+    )
+    tokens = sds((1, cfg.micro_batch_size, cfg.model.block_size), jnp.int32)
+    return state, {"x": tokens, "y": tokens}
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_whole_train_step_compiles_one_chip(topo, family):
+    """The whole jitted recipe step (8L/768d, T=512, micro-batch 32,
+    vocab 12000, bf16, Pallas attention and FFN): 30-60 s a family."""
+    from differential_transformer_replication_tpu.train.step import make_step_fn
+
+    cfg = _recipe(family)
+    state, batch = _abstract_step_args(cfg)
+    text = compile_for(topo, make_step_fn(cfg), state, batch)
+    # diff: 82 kernels in the compiled step (ISSUE 21; the same count ran
+    # on the chip in chip_smoke.py)
+    assert text.count("tpu_custom_call") >= 60
+
+
+@pytest.mark.slow
+def test_overlapped_dp_step_compiles_four_chips(topo):
+    """The sharded step ``--data-parallel 4`` reaches (parallel/dp_step.py)
+    compiled for the four described chips: kernels inside shard_map, the
+    bucketed gradient all-reduces, state replicated, batch split."""
+    from differential_transformer_replication_tpu.parallel import create_mesh
+    from differential_transformer_replication_tpu.parallel.dp_step import (
+        make_sharded_train_step,
+        overlap_eligible,
+    )
+
+    cfg = _recipe("diff", data=4)
+    assert overlap_eligible(cfg)
+    mesh = create_mesh(cfg.mesh, devices=topo.devices)
+    assert isinstance(mesh, Mesh) and mesh.devices.size == 4
+    state, batch = _abstract_step_args(cfg)
+    step = make_sharded_train_step(cfg, mesh, state)
+    repl = NamedSharding(mesh, P())
+    place = lambda tree, sh: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh), tree)
+    compiled = step.jitted.lower(
+        place(state, repl),
+        place(batch, NamedSharding(mesh, P(None, "data", None))), None,
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 60
+    assert "all-reduce" in text
+    x_sharding = compiled.input_shardings[0][1]["x"]
+    assert len(x_sharding.device_set) == 4
+    assert not x_sharding.is_fully_replicated

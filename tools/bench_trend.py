@@ -1,27 +1,25 @@
 #!/usr/bin/env python
-"""Render the committed bench trajectory as one machine-readable line.
+"""Render a bench trajectory as one machine-readable line.
 
-The driver archives every bench round at the repo root —
-``BENCH_r0*.json`` (single-chip train step: tokens/sec, vs_baseline,
-mfu_6nd, and the run's final loss in the stderr ``tail``) and
-``MULTICHIP_r0*.json`` (the 8-device dry-run result). This tool reads
-that history and prints ONE JSON summary line, so "are we still getting
-faster round over round?" is a jq expression instead of five file
-opens::
+A round archive is bench.py's JSON line wrapped by whoever ran it:
+``BENCH_r<NN>.json`` = ``{"rc", "tail", "parsed"}`` (single-chip train
+step: tokens/sec, vs_baseline, mfu_6nd, and the run's final loss in the
+stderr ``tail``), with ``MULTICHIP_r<NN>.json`` (the 8-virtual-device
+dry-run result) alongside. This tool reads such a history and prints
+ONE JSON summary line, so "are we still getting faster round over
+round?" is a jq expression instead of five file opens. No chip rounds
+are committed today (tests/fixtures/bench_rounds/ holds a synthetic
+trajectory for the tests)::
 
-    python tools/bench_trend.py                  # repo-root BENCH_r*/MULTICHIP_r*
-    python tools/bench_trend.py --ascii          # + sparklines on stderr
     python tools/bench_trend.py BENCH_r0*.json   # explicit round files
+    python tools/bench_trend.py --ascii BENCH_r0*.json  # + sparklines
+    python tools/bench_trend.py                  # repo-root BENCH_r*/MULTICHIP_r*
 
 Baseline math is IMPORTED from tools/perf_gate.py (median + MAD over
 the trailing window) so this trend view and the CI gate judge a
 trajectory identically — the summary's per-series ``baseline`` block is
 exactly what ``perf_gate.py --key`` would gate the next round against.
-
-Caveat carried in the output: rounds r01–r05 predate the PR 9–10 fused
-kernels (Pallas SwiGLU/norm, decode attention, int8 KV) — their numbers
-measure the pre-kernel hot path, so the next hardware round is expected
-to step, not drift. Stdlib only.
+Stdlib only.
 """
 
 from __future__ import annotations
@@ -37,12 +35,6 @@ from typing import List, Optional
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from perf_gate import baseline_stats  # noqa: E402  (shared gate math)
-
-PREDATE_NOTE = (
-    "rounds r01-r05 predate the PR 9-10 fused kernels "
-    "(Pallas SwiGLU/norm, decode attention, int8 KV): their numbers "
-    "measure the pre-kernel hot path"
-)
 
 _SPARK = "▁▂▃▄▅▆▇█"
 
@@ -188,7 +180,6 @@ def main() -> int:
             key: _baseline(series[key], args.window)
             for key in ("value", "vs_baseline", "mfu_6nd")
         },
-        "note": PREDATE_NOTE,
     }
     print(json.dumps(summary))
     if args.ascii:
@@ -197,7 +188,6 @@ def main() -> int:
             shown = [f"{v:g}" if v is not None else "-" for v in vals]
             print(f"[bench_trend] {key:14s} {sparkline(vals)}  "
                   f"({' '.join(shown)})", file=sys.stderr)
-        print(f"[bench_trend] NOTE: {PREDATE_NOTE}", file=sys.stderr)
     return 0
 
 

@@ -4,11 +4,12 @@
 Every perf surface in this repo already speaks one-line JSON —
 ``bench.py`` (tokens/sec, mfu_6nd), ``tools/serve_bench.py`` (tok/s,
 TTFT/ITL percentiles), the continuous profiler's ``device_profile``
-records (busy ms, per-bucket ms, mfu; obs/device_profile.py), and the
-committed ``BENCH_r0*.json`` round archives. This tool turns any such
-trajectory into a CI gate::
+records (busy ms, per-bucket ms, mfu; obs/device_profile.py), and
+driver-wrapped ``BENCH_r<NN>.json`` round archives. This tool turns any
+such trajectory into a CI gate::
 
-    # newest-last file list (the committed bench history):
+    # newest-last file list (a bench history; the repo commits none
+    # today — tests/fixtures/bench_rounds/ is a synthetic one):
     python tools/perf_gate.py BENCH_r0*.json --key value --key mfu_6nd
     # a serve_bench history file (--out appends one line per run):
     python tools/perf_gate.py serve_hist.jsonl --key value \

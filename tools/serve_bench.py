@@ -1665,6 +1665,12 @@ def main() -> None:
 
     import jax
 
+    from differential_transformer_replication_tpu.utils.device import (
+        start_measurement,
+    )
+
+    # the load runs in-process from here on: it times THIS device
+    start_measurement("serve_bench", smoke=args.smoke)
     from differential_transformer_replication_tpu.config import (
         ModelConfig,
         ServingConfig,

@@ -125,7 +125,11 @@ def main() -> None:
                         "kind, greedy parity ASSERTED for the exact "
                         "verify mode")
     args = p.parse_args()
+    from differential_transformer_replication_tpu.utils.device import (
+        start_measurement,
+    )
 
+    start_measurement("spec_sweep", smoke=args.smoke)
     if args.smoke:
         args.models = "control"
         args.draft_lens = "4"

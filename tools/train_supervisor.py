@@ -218,7 +218,9 @@ def probe_device_count(probe_cmd: Optional[List[str]] = None,
     it babysits is the thing that crashes). The default probe asks jax
     in the child's environment; ``--elastic-probe`` overrides it (and
     makes chaos tests deterministic). None on any failure — the caller
-    then relaunches with the mesh flags untouched."""
+    then relaunches with the mesh flags untouched. Probe only BETWEEN
+    children: a chip belongs to one process at a time, so beside a live
+    training child the probe would fail or hang."""
     cmd = probe_cmd or [
         sys.executable, "-c", "import jax; print(jax.device_count())"
     ]
