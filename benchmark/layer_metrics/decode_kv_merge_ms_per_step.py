@@ -1,0 +1,9 @@
+"""The decode program's time under kv_merge, read by ``lib/op_phases.py``
+from the trace's metadata; the declaration beside this file names the
+scopes."""
+
+from lib import op_phases
+
+
+def read(run):
+    return op_phases.read_declared(run, "decode_kv_merge_ms_per_step")

@@ -1,0 +1,67 @@
+"""The names of the Pallas kernels, one per ``pallas_call`` site.
+
+``pl.pallas_call(..., name=X)`` makes ``X`` the HLO instruction's own
+name (``%flash_fwd_tm_packed.3 = ... custom-call(...)``, under ``vmap``
+``%vmap_fused_add_norm_fwd_.1``), which is the name of the kernel's
+events in a profiler trace. Without it the instruction is named after
+whatever encloses the call (``%jvp__.N``, ``%transpose_jvp___.N``) and
+no reader can tell one kernel from another. ``obs/xprof.py`` builds its
+buckets from the families below, and the benchmark's readers
+(``benchmark/lib/xplane.py``, whose needles are frozen) match them as
+substrings, so the names keep to these rules:
+
+- every flash kernel holds ``flash``, forward kernels start
+  ``flash_fwd`` and backward kernels ``flash_bwd``;
+- every FFN kernel holds ``fused_ffn``;
+- every norm/residual kernel holds ``fused_add_norm`` and not
+  ``fused_ffn``;
+- the decode kernel holds ``_dattn_``;
+- no name holds a needle of another family.
+
+Standard library only, at the top of the package: ``ops/`` (which
+imports jax) and ``obs/xprof.py`` (which does not) both import it.
+"""
+
+# ops/flash.py
+FLASH_FWD = "flash_fwd"                      # one K/V block a row of Q blocks
+FLASH_FWD_TILED = "flash_fwd_tiled"          # K/V-tiled grid (long T)
+FLASH_FWD_CHUNK = "flash_fwd_chunk"          # a Q chunk at an offset (ring)
+FLASH_FWD_TM = "flash_fwd_tm"                # token-major
+FLASH_FWD_TM_PACKED = "flash_fwd_tm_packed"  # token-major, packed projections
+FLASH_BWD_DQ = "flash_bwd_dq"
+FLASH_BWD_DKV = "flash_bwd_dkv"
+FLASH_BWD_DQ_TILED = "flash_bwd_dq_tiled"
+FLASH_BWD_DKV_TILED = "flash_bwd_dkv_tiled"
+FLASH_BWD_FUSED = "flash_bwd_fused"          # dq, dk, dv in one kernel
+FLASH_BWD_TM = "flash_bwd_tm"
+FLASH_BWD_TM_PACKED = "flash_bwd_tm_packed"
+
+# ops/fused_ffn.py
+FUSED_FFN_FWD = "fused_ffn_fwd"
+FUSED_FFN_BWD = "fused_ffn_bwd"
+
+# ops/fused_norm_residual.py (with and without the residual input)
+FUSED_ADD_NORM_FWD = "fused_add_norm_fwd"
+FUSED_ADD_NORM_BWD = "fused_add_norm_bwd"
+
+# ops/decode_attention.py (all four entry points share one call)
+DECODE_ATTENTION = "decode_dattn_fwd"
+
+FLASH = (
+    FLASH_FWD, FLASH_FWD_TILED, FLASH_FWD_CHUNK, FLASH_FWD_TM,
+    FLASH_FWD_TM_PACKED, FLASH_BWD_DQ, FLASH_BWD_DKV, FLASH_BWD_DQ_TILED,
+    FLASH_BWD_DKV_TILED, FLASH_BWD_FUSED, FLASH_BWD_TM, FLASH_BWD_TM_PACKED,
+)
+FUSED_FFN = (FUSED_FFN_FWD, FUSED_FFN_BWD)
+FUSED_NORM = (FUSED_ADD_NORM_FWD, FUSED_ADD_NORM_BWD)
+DECODE = (DECODE_ATTENTION,)
+
+#: family -> its kernels' names; every ``pallas_call`` under ``ops/``
+#: passes one of these as ``name=``
+FAMILIES = {
+    "flash_attention": FLASH,
+    "fused_ffn": FUSED_FFN,
+    "fused_norm": FUSED_NORM,
+    "decode_attention": DECODE,
+}
+ALL = FLASH + FUSED_FFN + FUSED_NORM + DECODE

@@ -158,19 +158,22 @@ def apply_block_ffn(
     rate = cfg.dropout
     if use_fused_ffn(cfg, mesh):
         p = blk["ffn"]
-        x, normed = fused_add_norm(
-            x, attn_out, blk["ln2"]["w"], blk["ln2"]["b"]
-        )
-        h = fused_swiglu(
-            normed,
-            p["gate"]["w"], p["gate"]["b"],
-            p["xform"]["w"], p["xform"]["b"],
-        )
-        return x + dropout(linear(h, p["out"]), rate, rng)
-    x = x + attn_out
-    return x + apply_ffn(
-        apply_layer_norm(x, blk["ln2"]), blk["ffn"], rate, rng
-    )
+        with jax.named_scope("ffn_norm"):
+            x, normed = fused_add_norm(
+                x, attn_out, blk["ln2"]["w"], blk["ln2"]["b"]
+            )
+        with jax.named_scope("ffn"):
+            h = fused_swiglu(
+                normed,
+                p["gate"]["w"], p["gate"]["b"],
+                p["xform"]["w"], p["xform"]["b"],
+            )
+            return x + dropout(linear(h, p["out"]), rate, rng)
+    with jax.named_scope("ffn_norm"):
+        x = x + attn_out
+        normed = apply_layer_norm(x, blk["ln2"])
+    with jax.named_scope("ffn"):
+        return x + apply_ffn(normed, blk["ffn"], rate, rng)
 
 
 # jax.checkpoint policies selectable per run (ModelConfig.remat_policy):
@@ -276,11 +279,14 @@ def tail_and_loss(x, params: dict, cfg, targets, mesh=None):
     head backward skips XLA's fp32 transposed grad materialization, and
     the returned logits are an independent dense head application that
     training steps drop (DCE removes it when only the loss is consumed)."""
-    if targets is not None and cfg.loss_chunk:
-        return None, fused_tail_loss(
-            x, params, targets, cfg.loss_chunk, cfg, mesh
-        )
-    if targets is not None:
+    if targets is None:
+        with jax.named_scope("lm_head"):
+            return apply_tail(x, params, cfg, mesh), None
+    with jax.named_scope("lm_head_loss"):
+        if cfg.loss_chunk:
+            return None, fused_tail_loss(
+                x, params, targets, cfg.loss_chunk, cfg, mesh
+            )
         from differential_transformer_replication_tpu.ops.losses import (
             dense_linear_cross_entropy,
         )
@@ -289,7 +295,6 @@ def tail_and_loss(x, params: dict, cfg, targets, mesh=None):
         p = params["lm_head"]
         loss = dense_linear_cross_entropy(x_ln, p["w"], p.get("b"), targets)
         return linear(x_ln, p), loss
-    return apply_tail(x, params, cfg, mesh), None
 
 
 def split_rng(rng: Optional[jax.Array], n: int):

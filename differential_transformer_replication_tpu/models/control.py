@@ -104,7 +104,8 @@ def _attn(
 def embed(params: dict, idx: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """Token embedding only — RoPE is the position encoding
     (control.py:144, no position table)."""
-    return params["tok_emb"][idx].astype(jnp.dtype(cfg.compute_dtype))
+    with jax.named_scope("embed"):
+        return params["tok_emb"][idx].astype(jnp.dtype(cfg.compute_dtype))
 
 
 def block_forward(
@@ -123,11 +124,13 @@ def block_forward(
     model has no per-layer schedule, so it is unused here."""
     del layer_idx
     r_attn, r_ffn = common.split_rng(rng, 2)
-    a = _attn(
-        common.apply_pre_norm(x, blk["ln1"], cfg, mesh), blk["attn"],
-        cos, sin, mask, cfg.dropout, r_attn, cfg.attention_impl, mesh,
-        cfg.sequence_impl,
-    )
+    with jax.named_scope("attn_norm"):
+        h = common.apply_pre_norm(x, blk["ln1"], cfg, mesh)
+    with jax.named_scope("attn"):
+        a = _attn(
+            h, blk["attn"], cos, sin, mask, cfg.dropout, r_attn,
+            cfg.attention_impl, mesh, cfg.sequence_impl,
+        )
     # residual add + ln2 + SwiGLU + down-proj + residual, ffn_impl-
     # dispatched (fused kernels when "pallas"; models/common.py)
     return common.apply_block_ffn(x, a, blk, cfg, r_ffn, mesh)

@@ -336,7 +336,8 @@ def _attn_chunk(
     # chunk's own attention below reads exactly what later decode steps
     # will read.
     slot = jax.lax.rem(jnp.asarray(pos, jnp.int32), M)
-    new_cache = _write_chunk(layer_cache, ks, v, slot)
+    with jax.named_scope("kv_write"):
+        new_cache = _write_chunk(layer_cache, ks, v, slot)
     k_cache, v_cache = _dequant_layer(new_cache, x.dtype)
 
     scale = 1.0 / (cfg.head_size ** 0.5)
@@ -448,17 +449,21 @@ def forward_chunk(
 
     new_cache = []
     for li, blk in enumerate(params["blocks"], 1):  # 1-based (diff_transformer.py:161)
-        a, layer_cache = _attn_chunk(
-            common.apply_pre_norm(x, blk["ln1"], cfg), blk["attn"],
-            cache[li - 1], pos, li, cfg, cos, sin, window=window,
-        )
+        with jax.named_scope("attn_norm"):
+            h = common.apply_pre_norm(x, blk["ln1"], cfg)
+        with jax.named_scope("attn"):
+            a, layer_cache = _attn_chunk(
+                h, blk["attn"], cache[li - 1], pos, li, cfg, cos, sin,
+                window=window,
+            )
         # residual add + ln2 + SwiGLU + down-proj + residual — the same
         # ffn_impl dispatch as the training blocks (dropout-free here:
         # generation is eval-mode)
         x = common.apply_block_ffn(x, a, blk, cfg)
         new_cache.append(layer_cache)
-    x = common.apply_pre_norm(x, params["ln_f"], cfg)
-    logits = common.linear(x, params["lm_head"])
+    with jax.named_scope("lm_head"):
+        x = common.apply_pre_norm(x, params["ln_f"], cfg)
+        logits = common.linear(x, params["lm_head"])
     return logits, new_cache
 
 
@@ -537,7 +542,10 @@ def _pool_attn(
     if _uses_rope(cfg):
         qs = _rope_rows(qs, cos, sin)
         ks = _rope_rows(ks, cos, sin)
-    new_cache = _update_cache_rows(layer_cache, ks, v, pos, cfg.block_size)
+    with jax.named_scope("kv_write"):
+        new_cache = _update_cache_rows(
+            layer_cache, ks, v, pos, cfg.block_size
+        )
     coeffs = _layer_coeffs(cfg, p_attn, layer_idx)
     if cfg.decode_attention_impl == "pallas":
         out = decode_attention(
@@ -587,16 +595,20 @@ def forward_decode_pool(
         sin = sin_full[pos]
     new_cache = []
     for li, blk in enumerate(params["blocks"], 1):  # 1-based schedule
-        a, layer_cache = _pool_attn(
-            common.apply_pre_norm(x, blk["ln1"], cfg), blk["attn"],
-            cache[li - 1], pos, li, cfg, cos, sin,
-        )
+        with jax.named_scope("attn_norm"):
+            h = common.apply_pre_norm(x, blk["ln1"], cfg)
+        with jax.named_scope("attn"):
+            a, layer_cache = _pool_attn(
+                h, blk["attn"], cache[li - 1], pos, li, cfg, cos, sin,
+            )
         x = common.apply_block_ffn(x, a, blk, cfg)
         new_cache.append(layer_cache)
-    x = common.apply_pre_norm(x, params["ln_f"], cfg)
-    return common.linear(x, params["lm_head"]), new_cache
+    with jax.named_scope("lm_head"):
+        x = common.apply_pre_norm(x, params["ln_f"], cfg)
+        return common.linear(x, params["lm_head"]), new_cache
 
 
+@jax.named_scope("kv_merge")
 def merge_cache_update(active: jnp.ndarray, new_cache: list,
                        old_cache: list) -> list:
     """Masked cache merge over the pool-batch axis of every leaf: rows
@@ -794,7 +806,10 @@ def _pool_attn_paged(
     if _uses_rope(cfg):
         qs = _rope_rows(qs, cos, sin)
         ks = _rope_rows(ks, cos, sin)
-    new_cache = _update_pages_rows(layer_cache, ks, v, pos, write_pages, M)
+    with jax.named_scope("kv_write"):
+        new_cache = _update_pages_rows(
+            layer_cache, ks, v, pos, write_pages, M
+        )
     coeffs = _layer_coeffs(cfg, p_attn, layer_idx)
     if cfg.decode_attention_impl == "pallas":
         out = decode_attention_paged(
@@ -849,15 +864,18 @@ def forward_decode_pool_paged(
         sin = sin_full[pos]
     new_cache = []
     for li, blk in enumerate(params["blocks"], 1):  # 1-based schedule
-        a, layer_cache = _pool_attn_paged(
-            common.apply_pre_norm(x, blk["ln1"], cfg), blk["attn"],
-            cache[li - 1], pos, page_tables, write_pages, li, cfg,
-            cos, sin,
-        )
+        with jax.named_scope("attn_norm"):
+            h = common.apply_pre_norm(x, blk["ln1"], cfg)
+        with jax.named_scope("attn"):
+            a, layer_cache = _pool_attn_paged(
+                h, blk["attn"], cache[li - 1], pos, page_tables,
+                write_pages, li, cfg, cos, sin,
+            )
         x = common.apply_block_ffn(x, a, blk, cfg)
         new_cache.append(layer_cache)
-    x = common.apply_pre_norm(x, params["ln_f"], cfg)
-    return common.linear(x, params["lm_head"]), new_cache
+    with jax.named_scope("lm_head"):
+        x = common.apply_pre_norm(x, params["ln_f"], cfg)
+        return common.linear(x, params["lm_head"]), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -944,19 +962,20 @@ def _pool_attn_spec(
     S = qs.shape[0]
     ks_f = ks.reshape(S, B * L, cfg.n_head, -1)  # B, L adjacent: zero-copy
     v_f = v.reshape(B * L, cfg.n_head, -1)
-    if page_tables is None:
-        slot = jax.lax.rem(
-            jnp.asarray(pos, jnp.int32).reshape(-1), M
-        )
-        new_cache = _update_cache_rows_spec(
-            layer_cache, ks_f, v_f, slot, targets.reshape(-1)
-        )
-    else:
-        new_cache = _update_pages_rows(
-            layer_cache, ks_f, v_f,
-            jnp.asarray(pos, jnp.int32).reshape(-1),
-            targets.reshape(-1), M,
-        )
+    with jax.named_scope("kv_write"):
+        if page_tables is None:
+            slot = jax.lax.rem(
+                jnp.asarray(pos, jnp.int32).reshape(-1), M
+            )
+            new_cache = _update_cache_rows_spec(
+                layer_cache, ks_f, v_f, slot, targets.reshape(-1)
+            )
+        else:
+            new_cache = _update_pages_rows(
+                layer_cache, ks_f, v_f,
+                jnp.asarray(pos, jnp.int32).reshape(-1),
+                targets.reshape(-1), M,
+            )
     coeffs = _layer_coeffs(cfg, p_attn, layer_idx)
     if cfg.decode_attention_impl == "pallas":
         if page_tables is None:
@@ -1134,14 +1153,18 @@ def _forward_decode_spec_batched(params, tokens, pos, cache,
         sin = sin_full[pos]
     new_cache = []
     for li, blk in enumerate(params["blocks"], 1):  # 1-based schedule
-        a, layer_cache = _pool_attn_spec(
-            common.apply_pre_norm(x, blk["ln1"], cfg), blk["attn"],
-            cache[li - 1], pos, row_target, None, li, cfg, cos, sin,
-        )
+        with jax.named_scope("attn_norm"):
+            h = common.apply_pre_norm(x, blk["ln1"], cfg)
+        with jax.named_scope("attn"):
+            a, layer_cache = _pool_attn_spec(
+                h, blk["attn"], cache[li - 1], pos, row_target, None,
+                li, cfg, cos, sin,
+            )
         x = common.apply_block_ffn(x, a, blk, cfg)
         new_cache.append(layer_cache)
-    x = common.apply_pre_norm(x, params["ln_f"], cfg)
-    return common.linear(x, params["lm_head"]), new_cache
+    with jax.named_scope("lm_head"):
+        x = common.apply_pre_norm(x, params["ln_f"], cfg)
+        return common.linear(x, params["lm_head"]), new_cache
 
 
 def forward_decode_spec_paged(
@@ -1190,15 +1213,18 @@ def forward_decode_spec_paged(
         sin = sin_full[pos]
     new_cache = []
     for li, blk in enumerate(params["blocks"], 1):  # 1-based schedule
-        a, layer_cache = _pool_attn_spec(
-            common.apply_pre_norm(x, blk["ln1"], cfg), blk["attn"],
-            cache[li - 1], pos, write_pages, page_tables, li, cfg,
-            cos, sin,
-        )
+        with jax.named_scope("attn_norm"):
+            h = common.apply_pre_norm(x, blk["ln1"], cfg)
+        with jax.named_scope("attn"):
+            a, layer_cache = _pool_attn_spec(
+                h, blk["attn"], cache[li - 1], pos, write_pages,
+                page_tables, li, cfg, cos, sin,
+            )
         x = common.apply_block_ffn(x, a, blk, cfg)
         new_cache.append(layer_cache)
-    x = common.apply_pre_norm(x, params["ln_f"], cfg)
-    return common.linear(x, params["lm_head"]), new_cache
+    with jax.named_scope("lm_head"):
+        x = common.apply_pre_norm(x, params["ln_f"], cfg)
+        return common.linear(x, params["lm_head"]), new_cache
 
 
 @partial(

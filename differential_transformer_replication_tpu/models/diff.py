@@ -130,9 +130,10 @@ def embed(params: dict, idx: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
         # The reference raises (nn.Embedding index error) past block_size;
         # a JAX gather would silently clamp, so fail loudly instead.
         raise ValueError(f"sequence length {T} exceeds block_size {cfg.block_size}")
-    tok = params["tok_emb"][idx]
-    pos = params["pos_emb"][jnp.arange(T)]  # diff_transformer.py:158
-    return (tok + pos).astype(jnp.dtype(cfg.compute_dtype))
+    with jax.named_scope("embed"):
+        tok = params["tok_emb"][idx]
+        pos = params["pos_emb"][jnp.arange(T)]  # diff_transformer.py:158
+        return (tok + pos).astype(jnp.dtype(cfg.compute_dtype))
 
 
 def block_forward(
@@ -153,11 +154,13 @@ def block_forward(
     no RoPE."""
     del cos, sin
     r_attn, r_ffn = common.split_rng(rng, 2)
-    a = _attn(
-        common.apply_pre_norm(x, blk["ln1"], cfg, mesh), blk["attn"],
-        layer_idx, mask, cfg.dropout, r_attn, cfg.attention_impl, mesh,
-        cfg.sequence_impl, cfg,
-    )
+    with jax.named_scope("attn_norm"):
+        h = common.apply_pre_norm(x, blk["ln1"], cfg, mesh)
+    with jax.named_scope("attn"):
+        a = _attn(
+            h, blk["attn"], layer_idx, mask, cfg.dropout, r_attn,
+            cfg.attention_impl, mesh, cfg.sequence_impl, cfg,
+        )
     # residual add + ln2 + SwiGLU + down-proj + residual, ffn_impl-
     # dispatched (fused kernels when "pallas"; models/common.py)
     return common.apply_block_ffn(x, a, blk, cfg, r_ffn, mesh)

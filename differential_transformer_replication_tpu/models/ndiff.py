@@ -125,7 +125,8 @@ def _attn(
 
 def embed(params: dict, idx: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """Token embedding only — RoPE positions (Ndiff_transformer.py:188, 213)."""
-    return params["tok_emb"][idx].astype(jnp.dtype(cfg.compute_dtype))
+    with jax.named_scope("embed"):
+        return params["tok_emb"][idx].astype(jnp.dtype(cfg.compute_dtype))
 
 
 def block_forward(
@@ -143,11 +144,13 @@ def block_forward(
     ``layer_idx`` is 1-based (Ndiff_transformer.py:216) and may be static
     or traced (the pipeline-parallel layer scan)."""
     r_attn, r_ffn = common.split_rng(rng, 2)
-    a = _attn(
-        common.apply_pre_norm(x, blk["ln1"], cfg, mesh), blk["attn"],
-        layer_idx, cos, sin, mask, cfg.dropout, r_attn, cfg.attention_impl,
-        mesh, cfg.sequence_impl, cfg,
-    )
+    with jax.named_scope("attn_norm"):
+        h = common.apply_pre_norm(x, blk["ln1"], cfg, mesh)
+    with jax.named_scope("attn"):
+        a = _attn(
+            h, blk["attn"], layer_idx, cos, sin, mask, cfg.dropout, r_attn,
+            cfg.attention_impl, mesh, cfg.sequence_impl, cfg,
+        )
     # residual add + ln2 + SwiGLU + down-proj + residual, ffn_impl-
     # dispatched (fused kernels when "pallas"; models/common.py)
     return common.apply_block_ffn(x, a, blk, cfg, r_ffn, mesh)

@@ -74,7 +74,10 @@ from typing import Callable, Optional
 
 from differential_transformer_replication_tpu.obs import xprof
 from differential_transformer_replication_tpu.obs.registry import Registry
-from differential_transformer_replication_tpu.obs.spans import NOOP_TRACER
+from differential_transformer_replication_tpu.obs.spans import (
+    NOOP_TRACER,
+    annotate_spans,
+)
 
 _BUCKET_NAMES = tuple(name for name, _ in xprof.KERNEL_BUCKETS) + ("rest",)
 
@@ -262,6 +265,8 @@ class DeviceProfileSampler:
                       f"(continuing, counted): {e!r}", file=sys.stderr)
             return False
         self._active = True
+        # the loop's spans inside the window go into the capture too
+        annotate_spans(self._tracer, True)
         self._cap_dir = cap_dir
         self._cap_step = step
         self._t0 = time.perf_counter()
@@ -277,6 +282,7 @@ class DeviceProfileSampler:
         if not self._active:
             return
         self._active = False
+        annotate_spans(self._tracer, False)
         try:
             if sync is not None:
                 self._block(sync)
@@ -310,6 +316,7 @@ class DeviceProfileSampler:
         if not self._active:
             return
         self._active = False
+        annotate_spans(self._tracer, False)
         self._failures.inc()
         try:
             self._stop()

@@ -32,6 +32,12 @@ Design points:
 - **Crash-safe tail**: every tracer registers an ``atexit`` close, so
   a process that exits without reaching its explicit closer (SIGTERM
   drain paths close eagerly) still terminates a valid JSON document.
+- **On the device's clock while a capture is open**: with
+  ``tracer.annotate`` set (:func:`annotate_spans`; the capture windows
+  of obs/device_profile.py and utils/profiling.py flip it) every span
+  is also entered as a ``jax.profiler.TraceAnnotation``, so the
+  profiler's own trace shows the host spans beside the device's ops.
+  Off by default; jax is imported only then.
 """
 
 from __future__ import annotations
@@ -58,21 +64,28 @@ _NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: Optional[dict]):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._ann = None
 
     def __enter__(self):
+        if self._tracer.annotate:
+            import jax
+
+            self._ann = jax.profiler.TraceAnnotation(self._name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tracer._emit_complete(
-            self._name, self._t0, time.perf_counter(), self._args
-        )
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._tracer._emit_complete(self._name, self._t0, t1, self._args)
         return False
 
 
@@ -94,6 +107,8 @@ class SpanTracer:
         self._flush_every = max(1, flush_every)
         self._wrote_any = False
         self._closed = False
+        # set for the length of a jax.profiler capture (annotate_spans)
+        self.annotate = False
         # perf_counter has an arbitrary epoch; anchor it to wall clock
         # once so trace timestamps are meaningful across processes
         self._epoch = time.time() - time.perf_counter()
@@ -229,3 +244,11 @@ class _NoopTracer:
 
 
 NOOP_TRACER = _NoopTracer()
+
+
+def annotate_spans(tracer, on: bool) -> None:
+    """Flip ``tracer.annotate`` around a ``jax.profiler`` capture, so
+    that the spans recorded inside it also land in the profiler's own
+    trace. ``None`` and :data:`NOOP_TRACER` have nothing to flip."""
+    if tracer is not None and tracer is not NOOP_TRACER:
+        tracer.annotate = on

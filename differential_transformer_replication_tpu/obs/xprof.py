@@ -27,11 +27,14 @@ summarizes its busiest thread line; those numbers are plumbing-grade
 (events nest, so sums overcount) but keep the capture->parse->publish
 pipeline testable without hardware.
 
-Bucket attribution: XLA names Pallas programs after the kernel
-function, so substring membership against :data:`KERNEL_BUCKETS` is
-stable across jax versions. Order matters — the decode and fused-FFN
-kernels end in the flash needle ``_fwd_kernel`` and must match FIRST,
-and collectives are matched on their HLO op names.
+Bucket attribution: an "XLA Ops" event is named by its whole HLO
+instruction (``%flash_fwd_tm.3 = bf16[..] custom-call(%fusion.7), ...``)
+and only the instruction's OWN name, left of `` = ``, says what ran. A
+Pallas kernel's own name is the ``name=`` its ``pallas_call`` was given
+(``kernel_names.py``, the one table; without one the instruction is
+``%jvp__.N`` and says nothing), so the kernel buckets are built from
+that table and a kernel matches the bucket whose family holds its name.
+Collectives are matched on their HLO op names.
 """
 
 from __future__ import annotations
@@ -43,18 +46,20 @@ import re
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple, Union
 
-# Custom-kernel buckets for the grouped breakdown (see module
-# docstring on matching order). "collectives" covers the HLO
-# communication ops (DP all-reduce, tensor-parallel all-gather, ring
-# ppermute) so a sharded step's exposed-communication share is its own
-# line in the decomposition; everything unmatched is "rest".
+from differential_transformer_replication_tpu import kernel_names as _NAMES
+
+
+# Buckets of the grouped breakdown, by the instruction's own name. The
+# kernel buckets hold the names ``kernel_names.py`` gives the
+# ``pallas_call`` sites (the norm/residual kernels are booked with the
+# FFN they feed). "collectives" covers the HLO communication ops (DP
+# all-reduce, tensor-parallel all-gather, ring ppermute) so a sharded
+# step's exposed-communication share is its own line in the
+# decomposition; everything unmatched is "rest".
 KERNEL_BUCKETS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
-    ("decode_attention", ("_dattn_",)),
-    ("fused_ffn", ("_ffn_fwd", "_ffn_bwd", "_addnorm_",
-                   "fused_ffn", "fused_norm", "fused_add_norm",
-                   "_swiglu2", "_norm2", "_add_norm2")),
-    ("flash_attention", ("_fwd_kernel", "_bwd_dq", "_bwd_dkv", "flash",
-                         "_tm_", "tm_packed")),
+    ("decode_attention", _NAMES.DECODE),
+    ("fused_ffn", _NAMES.FUSED_FFN + _NAMES.FUSED_NORM),
+    ("flash_attention", _NAMES.FLASH),
     ("collectives", ("all-reduce", "all-gather", "reduce-scatter",
                      "all-to-all", "collective-permute",
                      "collective-broadcast")),
@@ -294,10 +299,13 @@ def _main_line(plane: XPlane, kind: str) -> Union[XLine, str]:
 
 
 def bucket_for(name: str) -> Optional[str]:
-    """First :data:`KERNEL_BUCKETS` bucket whose needles match, else
-    None (-> "rest" in the decomposition)."""
+    """The :data:`KERNEL_BUCKETS` bucket of an event, by the
+    instruction's own name (an op that READS a kernel's or a
+    collective's result is neither); None -> "rest" in the
+    decomposition."""
+    own = name.split(" = ", 1)[0]
     for bucket, needles in KERNEL_BUCKETS:
-        if any(n in name for n in needles):
+        if any(n in own for n in needles):
             return bucket
     return None
 

@@ -56,6 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from differential_transformer_replication_tpu import kernel_names
 from differential_transformer_replication_tpu.ops.flash import (
     auto_interpret,
     pick_block,
@@ -318,6 +319,7 @@ def _dattn_call(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
+        name=kernel_names.DECODE_ATTENTION,
         interpret=interpret,
     )(*prefetch, *inputs)
     # (BH, L, dv) -> (B, L, H, dv)

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from differential_transformer_replication_tpu import kernel_names
 from differential_transformer_replication_tpu.ops.streams import (
     NEG_INF,
     diff_coeffs,
@@ -548,6 +549,7 @@ def _fwd_call(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
+        name=kernel_names.FLASH_FWD,
         interpret=interpret,
     )(*inputs)
     if save_residuals:
@@ -722,6 +724,7 @@ def _tiled_fwd_call(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name=kernel_names.FLASH_FWD_TILED,
         interpret=interpret,
     )(*inputs)
     return results
@@ -966,6 +969,7 @@ def _tiled_bwd_call(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name=kernel_names.FLASH_BWD_DQ_TILED,
         interpret=interpret,
     )(q, k, v, do_s, lse, delta, offset, seed, c_arr)
 
@@ -1007,6 +1011,7 @@ def _tiled_bwd_call(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name=kernel_names.FLASH_BWD_DKV_TILED,
         interpret=interpret,
     )(q, k, v, do_s, lse, delta, offset, seed, c_arr)
     return dq, dk, dv
@@ -1366,6 +1371,7 @@ def _fused_bwd_call(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
+        name=kernel_names.FLASH_BWD_FUSED,
         interpret=interpret,
     )(q, k, v, g, lse, delta, seed, coeffs.astype(jnp.float32),
       causal_bias(T, 0))
@@ -1466,6 +1472,7 @@ def _bwd_call(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
+        name=kernel_names.FLASH_BWD_DQ,
         interpret=interpret,
     )(*dq_inputs)
 
@@ -1512,6 +1519,7 @@ def _bwd_call(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
+        name=kernel_names.FLASH_BWD_DKV,
         interpret=interpret,
     )(*dkv_inputs)
     return dq, dk, dv
@@ -1648,6 +1656,7 @@ def _chunk_fwd_call(q, k, v, offset, *, block_q, block_k, interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
+        name=kernel_names.FLASH_FWD_CHUNK,
         interpret=interpret,
     )(*inputs)
 
@@ -2019,6 +2028,7 @@ def _tm_fwd_call(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_TM_VMEM_LIMIT,
         ),
+        name=kernel_names.FLASH_FWD_TM,
         interpret=interpret,
     )(*qs, *ks, v, _tm_bias(T), coeffs.astype(jnp.float32))
     if save_residuals:
@@ -2167,6 +2177,7 @@ def _tm_bwd_call(qs, ks, v, g, lse, delta, coeffs, *, H: int, interpret: bool):
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_TM_VMEM_LIMIT,
         ),
+        name=kernel_names.FLASH_BWD_TM,
         interpret=interpret,
     )(*qs, *ks, v, g, lse, delta, coeffs.astype(jnp.float32), _tm_bias(T))
     dqs = tuple(results[:S])
@@ -2400,6 +2411,7 @@ def _tm_fwd_call_packed(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_TM_VMEM_LIMIT,
         ),
+        name=kernel_names.FLASH_FWD_TM_PACKED,
         interpret=interpret,
     )(*([proj] * (2 * S + 1)), _tm_bias(T),
       coeffs.astype(jnp.float32))
@@ -2483,6 +2495,7 @@ def _tm_bwd_call_packed(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_TM_VMEM_LIMIT,
         ),
+        name=kernel_names.FLASH_BWD_TM_PACKED,
         interpret=interpret,
     )(*([proj] * (2 * S + 1)), g, lse, delta,
       coeffs.astype(jnp.float32), _tm_bias(T))

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from differential_transformer_replication_tpu import kernel_names
 from differential_transformer_replication_tpu.ops.flash import (
     auto_interpret,
     pick_block,
@@ -108,6 +109,7 @@ def _fwd_call(x2, wg, bg2, wx, bx2, *, block_m, block_f, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
+        name=kernel_names.FUSED_FFN_FWD,
         interpret=interpret,
     )(*inputs)
 
@@ -187,6 +189,7 @@ def _bwd_call(x2, wg, bg2, wx, bx2, gh, *, block_m, block_f, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
+        name=kernel_names.FUSED_FFN_BWD,
         interpret=interpret,
     )(*inputs)
 

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from differential_transformer_replication_tpu import kernel_names
 from differential_transformer_replication_tpu.ops.flash import (
     auto_interpret,
     pick_block,
@@ -100,6 +101,7 @@ def _fwd_call(x2, d2, w2, b2, *, eps, has_delta, block_m, interpret):
         out_shape=out_shapes,
         out_specs=out_specs,
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        name=kernel_names.FUSED_ADD_NORM_FWD,
         interpret=interpret,
     )(*inputs)
 
@@ -166,6 +168,7 @@ def _bwd_call(x2, w2, gn2, gx2, *, eps, block_m, interpret):
         ],
         out_specs=[row_spec, par_spec, par_spec],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        name=kernel_names.FUSED_ADD_NORM_BWD,
         interpret=interpret,
     )(*inputs)
 

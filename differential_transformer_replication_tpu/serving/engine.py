@@ -450,6 +450,7 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
         ]
         return logits[0, -1].astype(jnp.float32), new_cache
 
+    @jax.named_scope("sampler")
     def _sample(ints, logits, allowed, counts_v):
         """Batched per-request sampling over (B, V) fp32 logits,
         through the structured-decoding logit pipeline
@@ -827,13 +828,14 @@ def _build_spec_step_fns(cfg: ModelConfig, rope_len: int, draft_len: int,
                 params, tokens, pos, cache, page_tables, write_pages,
                 cfg, rope_len=rope_len, batched=batched,
             )
-            out = _accept(
-                logits.astype(jnp.float32), draft, dlen, force_reject,
-                bases, counts, temps, topks,
-                pens[:, 0], pens[:, 1], pens[:, 2], allowed, pcounts,
-                tokens[:, 0],
-            )
-            return _pack_out(*out), new_cache
+            with jax.named_scope("sampler"):
+                out = _accept(
+                    logits.astype(jnp.float32), draft, dlen, force_reject,
+                    bases, counts, temps, topks,
+                    pens[:, 0], pens[:, 1], pens[:, 2], allowed, pcounts,
+                    tokens[:, 0],
+                )
+                return _pack_out(*out), new_cache
 
         return jax.jit(_spec_step, donate_argnums=(2,))
 
@@ -844,13 +846,14 @@ def _build_spec_step_fns(cfg: ModelConfig, rope_len: int, draft_len: int,
             params, tokens, pos, cache, cfg, row_target,
             rope_len=rope_len, batched=batched,
         )
-        out = _accept(
-            logits.astype(jnp.float32), draft, dlen, force_reject,
-            bases, counts, temps, topks,
-            pens[:, 0], pens[:, 1], pens[:, 2], allowed, pcounts,
-            tokens[:, 0],
-        )
-        return _pack_out(*out), new_cache
+        with jax.named_scope("sampler"):
+            out = _accept(
+                logits.astype(jnp.float32), draft, dlen, force_reject,
+                bases, counts, temps, topks,
+                pens[:, 0], pens[:, 1], pens[:, 2], allowed, pcounts,
+                tokens[:, 0],
+            )
+            return _pack_out(*out), new_cache
 
     return jax.jit(_spec_step, donate_argnums=(2,))
 
@@ -1573,7 +1576,7 @@ class ServingEngine:
             with self.tracer.span(
                 "prefill", iteration=iteration, chunks=len(chunks)
             ):
-                self._run_prefill(chunks, finished)
+                self._run_prefill(chunks, finished, iteration)
 
         if faults.serve_corrupt_at(iteration):
             self._corrupt_one_slot()
@@ -1626,14 +1629,27 @@ class ServingEngine:
             # rung, taken whenever no slot has a proposal this
             # iteration (drafter dry, all slots near their windows,
             # or a rebuilt drafter falling back)
-            B = self._rows
-            tokens = np.zeros((B,), np.int32)
-            pos = np.zeros((B,), np.int32)
-            mask = np.zeros((B,), bool)
-            for s in active:
-                tokens[s.index] = s.generated[-1]
-                pos[s.index] = s.prompt_len + len(s.generated) - 1
-                mask[s.index] = True
+            with self.tracer.span("decode_inputs", iteration=iteration):
+                B = self._rows
+                tokens = np.zeros((B,), np.int32)
+                pos = np.zeros((B,), np.int32)
+                mask = np.zeros((B,), bool)
+                for s in active:
+                    tokens[s.index] = s.generated[-1]
+                    pos[s.index] = s.prompt_len + len(s.generated) - 1
+                    mask[s.index] = True
+                if self._pages is not None:
+                    # page tables + per-row write pages ride the one
+                    # jitted step as runtime int32 arrays; inactive
+                    # rows write the trash page (masked-merge analog)
+                    M = self.cfg.block_size
+                    ps = self.serving.kv_page_size
+                    tables = self._pages.tables()
+                    write_pages = np.zeros((B,), np.int32)
+                    for s in active:
+                        write_pages[s.index] = tables[
+                            s.index, (pos[s.index] % M) // ps
+                        ]
             # the decode step is one batched op over every active slot;
             # its span carries the trace ids it advanced so a stitched
             # timeline shows which requests shared each iteration
@@ -1647,17 +1663,6 @@ class ServingEngine:
                     decode_args["trace_ids"] = tids
             with self.tracer.span("decode", **decode_args):
                 if self._pages is not None:
-                    # page tables + per-row write pages ride the one
-                    # jitted step as runtime int32 arrays; inactive
-                    # rows write the trash page (masked-merge analog)
-                    M = self.cfg.block_size
-                    ps = self.serving.kv_page_size
-                    tables = self._pages.tables()
-                    write_pages = np.zeros((B,), np.int32)
-                    for s in active:
-                        write_pages[s.index] = tables[
-                            s.index, (pos[s.index] % M) // ps
-                        ]
                     logits, self.cache = self._decode_fn(
                         self.params, jnp.asarray(tokens),
                         jnp.asarray(pos), self.cache,
@@ -1689,21 +1694,31 @@ class ServingEngine:
                            if self._quality else None),
                     )
 
-        if capturing:
-            # close the window (blocking on a cache leaf so the
-            # iteration's device work is inside it) and hand the trace
-            # to the off-loop parse worker
-            self._device_prof.end(sync=self.cache[0]["k"])
-        self.stats.inc("iterations")
-        self._step_hist.observe(time.perf_counter() - t_step)
-        self._update_gauges()
+        # the iteration's book-keeping; it lies AFTER the iteration's
+        # last stamped span, so it carries no ``iteration`` (readers
+        # take an iteration from its first to its last stamped span)
+        with self.tracer.span("step_tail"):
+            if capturing:
+                # close the window (blocking on a cache leaf so the
+                # iteration's device work is inside it) and hand the
+                # trace to the off-loop parse worker
+                self._device_prof.end(sync=self.cache[0]["k"])
+            self.stats.inc("iterations")
+            self._step_hist.observe(time.perf_counter() - t_step)
+            self._update_gauges()
         self._finished_prior = []
         return finished
 
-    def _run_prefill(self, chunks, finished: List[RequestOutput]) -> None:
+    def _run_prefill(self, chunks, finished: List[RequestOutput],
+                     iteration: int) -> None:
         """Execute one iteration's planned prefill chunks (see
         :meth:`Scheduler.plan`); extracted so the step's tracer span
-        brackets exactly the prefill device work."""
+        brackets exactly the prefill device work. Inside it, per chunk:
+        ``prefill_call`` (the chunk's transfer and the dispatch of its
+        program, which returns before the device is done) and, on the
+        chunk that completes a prompt, ``first_token`` (the sampler
+        call, whose read of the token BLOCKS on the device before the
+        next request's chunk can be dispatched, and the emit)."""
         for slot, start, size in chunks:
             if start == slot.cached_len:
                 # first chunk actually RUN = the request finally got a
@@ -1721,23 +1736,28 @@ class ServingEngine:
                         **(instant_args(slot.trace)
                            if slot.trace is not None else {}),
                     )
-            tokens = jnp.asarray(slot.prompt[start:start + size][None])
-            if self._pages is not None:
-                logits, self.cache = self._prefill_fn(
-                    self.params, self.cache,
-                    jnp.asarray(self._pages.table_row(slot.index)),
-                    tokens, np.int32(start),
-                )
-            else:
-                logits, self.cache = self._prefill_fn(
-                    self.params, self.cache, np.int32(slot.index),
-                    tokens, np.int32(start),
-                )
+            with self.tracer.span(
+                "prefill_call", iteration=iteration, size=size
+            ):
+                tokens = jnp.asarray(slot.prompt[start:start + size][None])
+                if self._pages is not None:
+                    logits, self.cache = self._prefill_fn(
+                        self.params, self.cache,
+                        jnp.asarray(self._pages.table_row(slot.index)),
+                        tokens, np.int32(start),
+                    )
+                else:
+                    logits, self.cache = self._prefill_fn(
+                        self.params, self.cache, np.int32(slot.index),
+                        tokens, np.int32(start),
+                    )
             slot.filled = start + size
             self.stats.inc("prefill_tokens", size)
-            if slot.filled == slot.prompt_len:
-                # prompt complete: the chunk's last-position logits give
-                # the first generated token (generate_cached's contract)
+            if slot.filled != slot.prompt_len:
+                continue
+            # prompt complete: the chunk's last-position logits give
+            # the first generated token (generate_cached's contract)
+            with self.tracer.span("first_token", iteration=iteration):
                 tok, ok, packed = self._sample_rows([slot], logits[None])
                 if not ok[0]:
                     raise EngineCrashError(

@@ -61,6 +61,7 @@ from differential_transformer_replication_tpu.obs.events import (
 from differential_transformer_replication_tpu.obs.registry import (
     CONTENT_TYPE as METRICS_CONTENT_TYPE,
 )
+from differential_transformer_replication_tpu.obs.spans import NOOP_TRACER
 from differential_transformer_replication_tpu.obs.trace import (
     from_payload as trace_from_payload,
 )
@@ -712,8 +713,51 @@ class EngineRunner:
             self._restarting = False
         return True
 
+    def _intake(self, cancels, incoming, commands, waiters: dict) -> None:
+        """What the loop took from the callers since its last look:
+        cancellations, new requests (each goes to ``engine.submit``),
+        and engine-thread commands."""
+        for pending in cancels:
+            if pending.rid is not None:
+                if self.engine.cancel(pending.rid):
+                    w = waiters.pop(pending.rid, None)
+                    if w is not None:
+                        self._settle(w, error=TimeoutError("cancelled"))
+            # rid None: either still in `incoming` (settled below) or
+            # it finished before the cancel landed — nothing to undo
+        for pending in incoming:
+            if pending.cancelled:
+                self._settle(
+                    pending,
+                    error=TimeoutError("cancelled before admission"),
+                )
+                continue
+            try:
+                # optional kwargs passed only when set, so plain
+                # test-double engines keep their narrow signatures
+                opt = {}
+                if pending.deadline is not None:
+                    opt["deadline"] = pending.deadline
+                if pending.trace is not None:
+                    opt["trace"] = pending.trace
+                pending.rid = self.engine.submit(
+                    pending.prompt, params=pending.params, **opt
+                )
+                waiters[pending.rid] = pending
+            except Exception as e:  # invalid request: fail the caller
+                self._settle(pending, error=e)
+        for thunk in commands:
+            # migration export/import thunks (run_on_engine): each
+            # captures its own exception and signals its caller
+            thunk()
+
     def _loop(self) -> None:
         waiters = self._waiters  # request_id -> _Pending (this thread's)
+        # the loop's own host time goes to the engine's tracer, around
+        # the engine's spans: ``intake`` before an iteration, ``deliver``
+        # after it; the wait for work is inside neither. getattr: test
+        # doubles keep their narrow surface.
+        tracer = getattr(self.engine, "tracer", NOOP_TRACER)
         while True:
             with self._cond:
                 while (
@@ -744,41 +788,8 @@ class EngineRunner:
                 for p in incoming:
                     self._settle(p, error=err)
                 return
-            for pending in cancels:
-                if pending.rid is not None:
-                    if self.engine.cancel(pending.rid):
-                        w = waiters.pop(pending.rid, None)
-                        if w is not None:
-                            self._settle(
-                                w, error=TimeoutError("cancelled")
-                            )
-                # rid None: either still in `incoming` (settled below) or
-                # it finished before the cancel landed — nothing to undo
-            for pending in incoming:
-                if pending.cancelled:
-                    self._settle(
-                        pending,
-                        error=TimeoutError("cancelled before admission"),
-                    )
-                    continue
-                try:
-                    # optional kwargs passed only when set, so plain
-                    # test-double engines keep their narrow signatures
-                    opt = {}
-                    if pending.deadline is not None:
-                        opt["deadline"] = pending.deadline
-                    if pending.trace is not None:
-                        opt["trace"] = pending.trace
-                    pending.rid = self.engine.submit(
-                        pending.prompt, params=pending.params, **opt
-                    )
-                    waiters[pending.rid] = pending
-                except Exception as e:  # invalid request: fail the caller
-                    self._settle(pending, error=e)
-            for thunk in commands:
-                # migration export/import thunks (run_on_engine): each
-                # captures its own exception and signals its caller
-                thunk()
+            with tracer.span("intake"):
+                self._intake(cancels, incoming, commands, waiters)
             try:
                 t0 = time.perf_counter()
                 # the watchdog state is read by status() from HTTP
@@ -810,16 +821,17 @@ class EngineRunner:
                 if not self._handle_engine_crash(e, waiters):
                     return
                 continue
-            self._deliver(outs, waiters)
-            progress = getattr(self.engine, "progress_snapshot", None)
-            if progress is not None:
-                entries = progress()
-                for ent in entries:
-                    p = waiters.get(ent.get("request_id"))
-                    if p is not None and p.journal_id is not None:
-                        ent["journal_id"] = p.journal_id
-                with self._cond:
-                    self._inflight = entries
+            with tracer.span("deliver"):
+                self._deliver(outs, waiters)
+                progress = getattr(self.engine, "progress_snapshot", None)
+                if progress is not None:
+                    entries = progress()
+                    for ent in entries:
+                        p = waiters.get(ent.get("request_id"))
+                        if p is not None and p.journal_id is not None:
+                            ent["journal_id"] = p.journal_id
+                    with self._cond:
+                        self._inflight = entries
             if stopping and not self.engine.has_work():
                 return
 

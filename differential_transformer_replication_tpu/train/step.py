@@ -159,7 +159,6 @@ def make_step_fn(cfg: TrainConfig, mesh=None, param_sync=None,
             # take different branches per device (grads are already
             # globally synced by param_sync's backward)
             loss = loss_sync(loss)
-        grad_norm = optax.global_norm(grads)
         # per-layer-group gradient norms ((L+2,): embed, blocks, head) —
         # the observability layer logs them next to the per-layer lambdas
         # every eval interval (obs/introspect.py). A handful of reduces
@@ -169,7 +168,12 @@ def make_step_fn(cfg: TrainConfig, mesh=None, param_sync=None,
             group_norms,
         )
 
-        gg = group_norms(grads)
+        # the norm passes over the gradients; the clip that uses the
+        # global norm is the optimizer chain's first link, inside
+        # "optimizer" below
+        with jax.named_scope("grad_norm_clip"):
+            grad_norm = optax.global_norm(grads)
+            gg = group_norms(grads)
         metrics = {
             "loss": loss,
             "learning_rate": schedule(state["step"]),
@@ -185,18 +189,20 @@ def make_step_fn(cfg: TrainConfig, mesh=None, param_sync=None,
             )
             return optax.apply_updates(state["params"], updates), opt_state
 
-        if cfg.anomaly_guard:
-            # skip the update on a bad step under lax.cond — one compiled
-            # program either way (compile count pinned, tests/test_faults
-            # .py); the step counter still advances so the lr schedule
-            # and the epoch-sampler fast-forward stay exact
-            params, opt_state, guard, extra = apply_guard(
-                cfg, state["guard"], loss, grad_norm, do_update,
-                state["params"], state["opt_state"],
-            )
-            metrics.update(extra)
-        else:
-            params, opt_state = do_update()
+        with jax.named_scope("optimizer"):
+            if cfg.anomaly_guard:
+                # skip the update on a bad step under lax.cond — one
+                # compiled program either way (compile count pinned,
+                # tests/test_faults.py); the step counter still advances
+                # so the lr schedule and the epoch-sampler fast-forward
+                # stay exact
+                params, opt_state, guard, extra = apply_guard(
+                    cfg, state["guard"], loss, grad_norm, do_update,
+                    state["params"], state["opt_state"],
+                )
+                metrics.update(extra)
+            else:
+                params, opt_state = do_update()
 
         new_state = {
             "params": params,

@@ -795,7 +795,8 @@ def train(cfg: TrainConfig) -> dict:
     # profile a short steady-state window past compile + warmup, relative
     # to wherever this run starts (fresh or resumed)
     profiler = ProfilerWindow(
-        cfg.profile_dir, start=int(jax.device_get(state["step"])) + 10
+        cfg.profile_dir, start=int(jax.device_get(state["step"])) + 10,
+        tracer=tracer,
     )
     # Preemption safety (SURVEY.md section 5.3 — the reference has none):
     # SIGTERM requests a graceful stop; the finally block below writes a

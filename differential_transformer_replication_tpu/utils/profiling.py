@@ -22,6 +22,8 @@ from typing import Iterator, Optional
 
 import jax
 
+from differential_transformer_replication_tpu.obs.spans import annotate_spans
+
 
 @contextlib.contextmanager
 def trace(logdir: str) -> Iterator[None]:
@@ -41,13 +43,17 @@ class ProfilerWindow:
     from a checkpoint past the window start (never calls stop without a
     matching start) and loops that end inside the window (``close()``
     finalizes the trace so it is never left running/unwritten).
+    ``tracer`` (obs/spans.py): the loop's host spans inside the window
+    are written into the capture too, on the device's clock.
     """
 
-    def __init__(self, logdir: Optional[str], start: int, n_steps: int = 5):
+    def __init__(self, logdir: Optional[str], start: int, n_steps: int = 5,
+                 tracer=None):
         self.logdir = logdir
         self.start = start
         self.stop = start + n_steps
         self.active = False
+        self._tracer = tracer
 
     def step(self, iter_num: int, sync=None) -> None:
         """Call once per loop iteration with the post-increment iteration
@@ -57,6 +63,7 @@ class ProfilerWindow:
             return
         if not self.active and iter_num == self.start:
             jax.profiler.start_trace(self.logdir)
+            annotate_spans(self._tracer, True)
             self.active = True
         elif self.active and iter_num >= self.stop:
             self._finalize(sync)
@@ -69,6 +76,7 @@ class ProfilerWindow:
     def _finalize(self, sync) -> None:
         if sync is not None:
             jax.block_until_ready(sync)
+        annotate_spans(self._tracer, False)
         jax.profiler.stop_trace()
         self.active = False
         print(f"Profiler trace written to {self.logdir}")

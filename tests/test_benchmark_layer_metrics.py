@@ -1,0 +1,628 @@
+"""The names and spans of ISSUE 24, and the benchmark readers that read
+them (``benchmark/layer_metrics/``), at no chip time:
+
+- every name of ``kernel_names.py`` falls in the intended bucket
+  under BOTH the program's ``obs/xprof.py`` and the benchmark's frozen
+  ``benchmark/lib/xplane.py``, and the needle sets of the new metrics
+  are disjoint over the table;
+- a hand-made xplane with named kernels, ``op_name`` paths in the event
+  metadata and ``bench:`` host spans, and a hand-made span record: each
+  of the new readers returns the value computed by hand, and a reader
+  with nothing to read returns None;
+- a real ``ServingEngine`` under its ``EngineRunner`` at toy size with a
+  recording tracer: the new spans' names, nesting and args, the
+  ``iteration`` rule (only spans between an iteration's ``schedule``
+  start and its ``emit`` end are stamped), what the readers the
+  benchmark already had read from it, and ``tracer=None`` sending every
+  site to ``NOOP_TRACER``.
+"""
+
+import sys
+import threading
+from pathlib import Path
+
+import jax
+import pytest
+
+REPO = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(REPO / "benchmark"))
+
+import selftest  # noqa: E402  (benchmark/selftest.py: the xplane writer)
+from lib import harness, op_phases, xplane  # noqa: E402
+from lib.spans import SpanRecorder  # noqa: E402
+
+from differential_transformer_replication_tpu.config import (  # noqa: E402
+    ModelConfig,
+    ServingConfig,
+)
+from differential_transformer_replication_tpu.models import init_model  # noqa: E402
+from differential_transformer_replication_tpu.obs import spans as obs_spans  # noqa: E402
+from differential_transformer_replication_tpu.obs import xprof  # noqa: E402
+from differential_transformer_replication_tpu import kernel_names  # noqa: E402
+from differential_transformer_replication_tpu.serving import (  # noqa: E402
+    SamplingParams,
+    ServingEngine,
+)
+from differential_transformer_replication_tpu.serving.server import (  # noqa: E402
+    EngineRunner,
+)
+
+TRAIN_CELL, SERVE_CELL = "train-diff-recipe", "serve-diff-recipe-chat"
+NEW_TRAIN = ("attn_kernel_ms_per_step", "attn_bwd_kernel_ms_per_step",
+             "ffn_kernel_ms_per_step", "norm_kernel_ms_per_step",
+             "flash_attention_roofline", "ffn_fwd_kernel_ms_per_step",
+             "ffn_bwd_kernel_ms_per_step", "fused_ffn_roofline")
+TRAIN_PHASES = ("xla_attn_ms_per_step", "xla_ffn_ms_per_step",
+                "xla_vocab_ms_per_step", "xla_optimizer_ms_per_step",
+                "xla_unscoped_ms_per_step")
+SERVE_PHASES = ("decode_attn_ms_per_step", "decode_kv_write_ms_per_step",
+                "decode_ffn_ms_per_step", "decode_kv_merge_ms_per_step",
+                "decode_rest_ms_per_step")
+NEW_SERVE = ("prefill_ms_per_iter", "prefill_calls_per_iter",
+             "first_token_sync_ms_per_iter", "between_iterations_ms",
+             "prefill_tokens_per_call")
+PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+
+
+def _needles(metric: str):
+    return harness.load_json("layer_metrics", metric + ".json")["source"]["needles"]
+
+
+def read(metric: str, run):
+    return harness._reader_for(metric)(run)
+
+
+# -- the table of names -------------------------------------------------------
+
+# (family of the table, the bucket of obs/xprof.py and benchmark/lib/xplane.py)
+BUCKET_OF_FAMILY = {"flash_attention": "flash_attention",
+                    "fused_ffn": "fused_ffn", "fused_norm": "fused_ffn",
+                    "decode_attention": "decode_attention"}
+NAMES = [(fam, name) for fam, names in kernel_names.FAMILIES.items()
+         for name in names]
+
+
+def _instruction(name: str, n: int = 3) -> str:
+    """The event's name on the chip: the whole HLO instruction."""
+    return (f"%{name}.{n} = bf16[8]{{0}} custom-call(bf16[8]{{0}} %p.1), "
+            'custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("family,name", NAMES, ids=[n for _, n in NAMES])
+def test_name_falls_in_its_bucket_under_both_readers(family, name):
+    want = BUCKET_OF_FAMILY[family]
+    for text in (_instruction(name), _instruction(f"vmap_{name}_", 1)):
+        assert xprof.bucket_for(text) == want
+        assert xplane.bucket_for(text) == want
+    # an op that READS the kernel's result is not the kernel
+    reader = f"%fusion.9 = f32[8]{{0}} fusion(bf16[8]{{0}} %{name}.3)"
+    assert xprof.bucket_for(reader) is None
+    assert xplane.bucket_for(reader) is None
+    if family == "flash_attention":
+        assert name.startswith(("flash_fwd", "flash_bwd"))
+
+
+def test_table_is_whole_and_the_metrics_needles_are_disjoint():
+    assert len(kernel_names.ALL) == len(set(kernel_names.ALL)) == 17
+    assert sorted(kernel_names.ALL) == sorted(n for _, n in NAMES)
+    sets = {
+        "attn": ["flash"],  # every needle-reader of the flash family
+        "ffn": _needles("ffn_kernel_ms_per_step"),
+        "norm": _needles("norm_kernel_ms_per_step"),
+    }
+    family_of = {"attn": "flash_attention", "ffn": "fused_ffn",
+                 "norm": "fused_norm"}
+    for key, needles in sets.items():
+        hit = {n for n in kernel_names.ALL if any(x in n for x in needles)}
+        assert hit == set(kernel_names.FAMILIES[family_of[key]]), key
+    bwd = {n for n in kernel_names.ALL
+           if any(x in n for x in _needles("attn_bwd_kernel_ms_per_step"))}
+    assert bwd == {n for n in kernel_names.FLASH if n.startswith("flash_bwd")}
+    assert all("_dattn_" in n for n in kernel_names.DECODE)
+    assert not any("_dattn_" in n for n in kernel_names.ALL
+                   if n not in kernel_names.DECODE)
+
+
+# -- a hand-made trace ----------------------------------------------------------
+# One chip, two traced steps, microseconds: the named kernels back to back,
+# then a fusion that READS a flash kernel (and is none), a gap of 100 us that
+# the host spends inside `prefill`: 30 under `prefill_call`, 70 under
+# `first_token` (the innermost span names a gap), and a last op.
+_CC = ', custom_call_target="tpu_custom_call"'
+_OPS = [
+    (f"%flash_fwd_tm_packed.1 = bf16[8] custom-call(bf16[8] %p){_CC}", 0, 30),
+    (f"%flash_bwd_tm_packed.2 = bf16[8] custom-call(bf16[8] %p){_CC}", 30, 60),
+    (f"%fused_ffn_fwd.3 = bf16[8] custom-call(bf16[8] %p){_CC}", 90, 20),
+    (f"%fused_ffn_bwd.4 = bf16[8] custom-call(bf16[8] %p){_CC}", 110, 40),
+    (f"%vmap_fused_add_norm_fwd_.5 = bf16[8] custom-call(bf16[8] %p){_CC}", 150, 10),
+    (f"%fused_add_norm_bwd.6 = bf16[8] custom-call(bf16[8] %p){_CC}", 160, 15),
+    ("%fusion.7 = f32[8] fusion(bf16[8] %flash_fwd_tm_packed.1)", 175, 25),
+    ("%fusion.8 = f32[8] fusion(f32[8] %p)", 300, 10),
+]
+_HOST = [("bench:prefill", 190, 115), ("bench:prefill_call", 195, 35),
+         ("bench:first_token", 230, 70), ("other", 0, 400)]
+TRACE_STEPS = 2
+
+
+@pytest.fixture(scope="module")
+def planes():
+    data = (selftest._ld(1, selftest._plane(
+                "/device:TPU:0", [("XLA Ops", _OPS)]))
+            + selftest._ld(1, selftest._plane(
+                "/host:CPU", [("engine", _HOST)])))
+    return xplane.parse_xspace(data)
+
+
+def _train_run(planes, peaks=PEAKS):
+    cell = harness.find_cell(harness.load_benchmark(), TRAIN_CELL)
+    run = harness.Run(cell, harness.Env([], peaks), planes=planes)
+    run.values.update(trace_steps=TRACE_STEPS, rows_per_chip=64, seq_len=512)
+    return run
+
+
+@pytest.mark.parametrize("metric,want_ms", [
+    ("attn_kernel_ms_per_step", (30 + 60) / 2 * 1e-3),
+    ("attn_bwd_kernel_ms_per_step", 60 / 2 * 1e-3),
+    ("ffn_kernel_ms_per_step", (20 + 40) / 2 * 1e-3),
+    ("norm_kernel_ms_per_step", (10 + 15) / 2 * 1e-3),
+    ("ffn_fwd_kernel_ms_per_step", 20 / 2 * 1e-3),
+    ("ffn_bwd_kernel_ms_per_step", 40 / 2 * 1e-3),
+    ("pallas_kernels_ms_per_step", 175 / 2 * 1e-3),  # the three above
+])
+def test_kernel_time_readers_on_the_named_trace(planes, metric, want_ms):
+    assert read(metric, _train_run(planes)) == pytest.approx(want_ms)
+
+
+def test_flash_attention_roofline_by_hand(planes, capsys):
+    # diff recipe: 8 layers, 4 heads, 2 streams of 96, values of 192;
+    # 64 rows of 512 tokens: 131,328 causal pairs a head
+    pairs = 512 * 513 // 2
+    flops = 8 * 64 * 4 * 2 * pairs * (3 * 2 * 96 + 3 * 192)
+    qk, vo = 2 * 4 * 512 * 96 * 2, 4 * 512 * 192 * 2
+    nbytes = 8 * 64 * (6 * qk + 6 * vo)
+    least = max(flops / 197e12, nbytes / 819e9)
+    assert least == nbytes / 819e9  # memory-bound at T=512
+    got = read("flash_attention_roofline", _train_run(planes))
+    assert got == pytest.approx(100 * least / 45e-6)
+    said = capsys.readouterr().out
+    assert "memory-bound" in said and f"{flops:.4g} operations" in said
+    # the control recipe needs the same: 8 heads of one stream of 96
+    cell = harness.find_cell(harness.load_benchmark(), "train-control-recipe")
+    run = _train_run(planes)
+    run.cell = cell
+    assert read("flash_attention_roofline", run) == pytest.approx(got)
+
+
+def test_fused_ffn_roofline_by_hand(planes, capsys):
+    # 8 layers, 64 * 512 tokens, 768 wide, hidden 3072: gate and xform
+    # forward, dWg and dWx backward, 2 operations a multiply-add
+    M, E, F = 64 * 512, 768, 3072
+    flops = 8 * 4 * 2 * M * E * F
+    nbytes = 8 * (2 * 2 * (M * E + 2 * E * F + M * F)
+                  + 2 * 2 * M * F + 4 * 2 * E * F)
+    least = flops / 197e12
+    assert least > nbytes / 819e9  # compute-bound
+    got = read("fused_ffn_roofline", _train_run(planes))
+    assert got == pytest.approx(100 * least / 30e-6)  # (20 + 40) us / 2 steps
+    said = capsys.readouterr().out
+    assert "compute-bound" in said and f"{flops:.4g} operations" in said
+
+
+def test_breakdown_names_kernels_and_gaps(planes):
+    summary = xplane.summary(planes, "bench:")
+    ops = dict(summary["breakdown"]["device_ops"])
+    assert not any(k.startswith("pallas:") for k in ops)
+    assert ops["flash_attention"] == pytest.approx(90e-6)
+    assert ops["fused_ffn"] == pytest.approx(85e-6)  # FFN and norm kernels
+    assert ops["fusion"] == pytest.approx(35e-6)
+    gaps = dict(summary["breakdown"]["idle_gaps"])
+    assert gaps["prefill_call"] == pytest.approx(30e-6)
+    assert gaps["first_token"] == pytest.approx(70e-6)
+    assert "prefill" not in gaps and "no-host-span" not in gaps
+
+
+# -- the phase scopes, from the trace's metadata ---------------------------------
+# A chip trace names an event by its instruction's text; the instruction's
+# op_name, scopes and all, is the `tf_op` stat of the event's METADATA, as a
+# string or as a reference to a stat_metadata entry whose name is the string.
+
+
+def _plane_with_paths(name, lines, paths, by_ref=()):
+    """``selftest._plane`` plus, on the metadata of every event named in
+    ``paths``, its op_name under ``tf_op`` (a reference for ``by_ref``),
+    and on every metadata a stat that is not the op_name."""
+    ld, vi = selftest._ld, selftest._vi
+    ids, body = {}, b""
+    for lname, events in lines:
+        evs = b""
+        for ename, start_us, dur_us in events:
+            mid = ids.setdefault(ename, len(ids) + 1)
+            evs += ld(4, vi(1, mid) + vi(2, start_us * 10**6)
+                      + vi(3, dur_us * 10**6))
+        body += ld(3, ld(2, lname.encode()) + vi(3, 1000) + evs)
+    stat_names, meta = {1: op_phases.OP_NAME_STAT, 2: "flops"}, b""
+    for ename, mid in ids.items():
+        stats = ld(5, vi(1, 2) + vi(3, 7))
+        path = paths.get(ename)
+        if path is not None and ename in by_ref:
+            sid = 10 + len(stat_names)
+            stat_names[sid] = path
+            stats += ld(5, vi(1, 1) + vi(7, sid))
+        elif path is not None:
+            stats += ld(5, vi(1, 1) + ld(5, path.encode()))
+        meta += ld(4, vi(1, mid) + ld(2, vi(1, mid) + ld(2, ename.encode())
+                                      + stats))
+    for sid, sname in stat_names.items():
+        meta += ld(5, vi(1, sid) + ld(2, vi(1, sid) + ld(2, sname.encode())))
+    return ld(2, name.encode()) + body + meta
+
+
+# two traced train steps, microseconds; a cond holds an op of its body and
+# an unscoped while holds an op of attention: time goes to the innermost
+_F = "%fusion.{} = f32[8] fusion(f32[8] %p)"
+_STEP_OPS = [
+    (_F.format(1), 0, 30, "jit(step)/jvp(attn)/dot_general"),
+    (f"%flash_fwd_tm.2 = bf16[8] custom-call(bf16[8] %p){_CC}", 30, 20,
+     "jit(step)/jvp(attn)/flash_fwd_tm/pallas_call"),
+    (_F.format(3), 50, 40, "jit(step)/transpose(jvp(ffn))/dot_general:"),
+    ("%cond.4 = f32[8] conditional(pred[] %p)", 100, 60,
+     "jit(step)/optimizer/cond"),
+    (_F.format(5), 110, 20, "jit(step)/optimizer/cond/branch_1_fun/mul"),
+    ("%copy.6 = f32[8] copy(f32[8] %p)", 160, 10, None),
+    (_F.format(7), 170, 20, "jit(step)/jvp(lm_head_loss)/reduce_sum"),
+    ("%gather.8 = f32[8] gather(f32[8] %p)", 190, 5, "jit(step)/embed/gather"),
+    (_F.format(9), 195, 5, "jit(step)/jvp(ffn_norm)/add"),
+    (_F.format(10), 200, 10, "jit(step)/grad_norm_clip/reduce_sum"),
+    ("%while.11 = f32[8] while(f32[8] %p)", 220, 40, None),
+    (_F.format(12), 230, 10, "jit(step)/while/body/attn_norm/mul"),
+]
+STEP_PHASE_US = {"xla_attn_ms_per_step": 30 + 10, "xla_ffn_ms_per_step": 40 + 5,
+                 "xla_vocab_ms_per_step": 20 + 5,
+                 "xla_optimizer_ms_per_step": 60 + 10,
+                 "xla_unscoped_ms_per_step": 10 + 30}
+# two executions of the decode program with a prefill program between them
+_D = "jit(_decode)/"
+_DECODE_OPS = [
+    (_F.format(1), 0, 20, _D + "attn/dot_general"),
+    ("%dynamic-update-slice.2 = f32[8] dynamic-update-slice(f32[8] %p)", 20,
+     30, _D + "attn/kv_write/dynamic_update_slice"),
+    (f"%vmap_fused_ffn_fwd_.3 = bf16[8] custom-call(bf16[8] %p){_CC}", 50, 20,
+     _D + "ffn/vmap(fused_ffn_fwd)/pallas_call"),
+    (_F.format(4), 70, 10, _D + "lm_head/dot_general"),
+    ("%select.7 = f32[8] select(pred[8] %p)", 80, 5, _D + "kv_merge/select_n"),
+    ("%copy.5 = f32[8] copy(f32[8] %p)", 85, 10, None),
+]
+_SERVE_OPS = (_DECODE_OPS
+              + [(_F.format(6), 100, 40, "jit(_prefill)/attn/dot_general")]
+              + [(n, a + 200, d, p) for n, a, d, p in _DECODE_OPS])
+_SERVE_MODS = [("jit__decode(1)", 0, 100), ("jit__prefill(2)", 100, 50),
+               ("jit__decode(1)", 200, 100)]
+DECODE_PHASE_US = {"decode_attn_ms_per_step": 20,
+                   "decode_kv_write_ms_per_step": 30,
+                   "decode_ffn_ms_per_step": 20,
+                   "decode_kv_merge_ms_per_step": 5,
+                   "decode_rest_ms_per_step": 10 + 10}
+
+
+def _traced_run(monkeypatch, tmp_path, cell_name, ops, mods=(), scoped=True):
+    """A run whose trace file lies where ``harness.Profile`` writes one."""
+    paths = {n: p for n, _, _, p in ops if scoped}
+    lines = [("XLA Ops", [(n, a, d) for n, a, d, _ in ops])]
+    if mods:
+        lines.append(("XLA Modules", list(mods)))
+    data = selftest._ld(1, _plane_with_paths(
+        "/device:TPU:0", lines, paths, by_ref={_F.format(3)}))
+    monkeypatch.setattr(harness, "OUT_DIR", str(tmp_path))
+    # an older trace of another cell whose name starts the same: not this one
+    for tag, blob in ((cell_name + "-dp4-5", b""), (cell_name + "-7", data)):
+        d = tmp_path / "trace" / tag / "plugins" / "profile" / "x"
+        d.mkdir(parents=True)
+        (d / "h.xplane.pb").write_bytes(blob)
+    cell = harness.find_cell(harness.load_benchmark(), cell_name)
+    run = harness.Run(cell, harness.Env([], PEAKS),
+                      planes=xplane.parse_xspace(data))
+    run.values.update(trace_steps=TRACE_STEPS)
+    return run
+
+
+def test_phase_of_takes_the_innermost_scope():
+    assert op_phases.phase_of("jit(step)/transpose(jvp(ffn))/dot_general:") == "ffn"
+    assert op_phases.phase_of("jit(_decode)/attn/kv_write/dus") == "kv_write"
+    assert op_phases.phase_of("jit(step)/jvp(jit(_attn))/mul") is None
+    assert op_phases.phase_of("") is None
+
+
+@pytest.mark.parametrize("metric", TRAIN_PHASES)
+def test_train_phase_readers_by_hand(monkeypatch, tmp_path, metric):
+    run = _traced_run(monkeypatch, tmp_path, TRAIN_CELL, _STEP_OPS)
+    assert read(metric, run) == pytest.approx(
+        STEP_PHASE_US[metric] / TRACE_STEPS * 1e-3)
+    if metric == TRAIN_PHASES[0]:
+        # the parts and the Pallas kernel add up to the union of the ops
+        parts = sum(read(m, run) for m in TRAIN_PHASES)
+        busy, _ = xplane.busy_and_window(run.planes)
+        assert parts + 20 / TRACE_STEPS * 1e-3 == pytest.approx(
+            busy * 1e3 / TRACE_STEPS)
+
+
+@pytest.mark.parametrize("metric", SERVE_PHASES)
+def test_decode_phase_readers_by_hand(monkeypatch, tmp_path, metric):
+    run = _traced_run(monkeypatch, tmp_path, SERVE_CELL, _SERVE_OPS,
+                      _SERVE_MODS)
+    assert read(metric, run) == pytest.approx(DECODE_PHASE_US[metric] * 1e-3)
+
+
+@pytest.mark.parametrize("metric", TRAIN_PHASES + SERVE_PHASES)
+def test_phase_reader_with_nothing_to_read_returns_none(
+        monkeypatch, tmp_path, metric):
+    """No trace, or a trace of a program without the scopes (the parent
+    under this PR's benchmark files): None, the unscoped share too."""
+    cell, ops, mods = ((TRAIN_CELL, _STEP_OPS, ()) if metric in TRAIN_PHASES
+                       else (SERVE_CELL, _SERVE_OPS, _SERVE_MODS))
+    run = _traced_run(monkeypatch, tmp_path, cell, ops, mods, scoped=False)
+    assert read(metric, run) is None
+    run.planes = None
+    assert read(metric, run) is None
+    entry = next(m for m in harness.load_benchmark()["per_layer"]
+                 if m["name"] == metric)
+    assert cell in entry["workloads"] and entry["source"] == "device_trace"
+    decl = harness.load_json("layer_metrics", metric + ".json")
+    assert (decl["unit"], decl["layer"], decl["moves"]) == (
+        entry["unit"], entry["layer"], entry["moves"])
+
+
+# -- a hand-made span record --------------------------------------------------
+# Two iterations, seconds. Iteration 0 prefills two chunks, one of which
+# completes a prompt; iteration 1 only decodes. What lies after `emit`
+# carries no stamp.
+def _recorded() -> SpanRecorder:
+    rec = SpanRecorder()
+    it0, it1 = {"iteration": 0}, {"iteration": 1}
+    rec.spans = [
+        ("schedule", 0.000, 0.001, it0),
+        ("prefill_call", 0.001, 0.003, dict(it0, size=16)),
+        ("prefill_call", 0.003, 0.006, dict(it0, size=8)),
+        ("first_token", 0.006, 0.010, it0),
+        ("prefill", 0.001, 0.011, dict(it0, chunks=2)),
+        ("decode_inputs", 0.011, 0.012, it0),
+        ("decode", 0.012, 0.013, dict(it0, active=3)),
+        ("sample", 0.013, 0.019, it0),
+        ("emit", 0.019, 0.020, it0),
+        ("step_tail", 0.020, 0.021, None),
+        ("deliver", 0.021, 0.023, None),
+        ("intake", 0.023, 0.024, None),
+        ("schedule", 0.024, 0.025, it1),
+        ("decode_inputs", 0.025, 0.026, it1),
+        ("decode", 0.026, 0.027, dict(it1, active=4)),
+        ("sample", 0.027, 0.039, it1),
+        ("emit", 0.039, 0.040, it1),
+        ("step_tail", 0.040, 0.0405, None),
+        ("deliver", 0.0405, 0.041, None),
+        # after the window: not counted
+        ("intake", 1.5, 1.6, None),
+    ]
+    return rec
+
+
+def _serve_run(rec):
+    cell = harness.find_cell(harness.load_benchmark(), SERVE_CELL)
+    run = harness.Run(cell, harness.Env([], None), spans=rec)
+    run.values["measured_window"] = (0.0, 1.0)
+    return run
+
+
+@pytest.mark.parametrize("metric,want", [
+    ("prefill_ms_per_iter", 10.0 / 2),
+    ("prefill_calls_per_iter", 2 / 2),
+    ("first_token_sync_ms_per_iter", 4.0 / 2),
+    ("between_iterations_ms", (1.0 + 2.0 + 1.0 + 0.5 + 0.5) / 2),
+    ("prefill_tokens_per_call", (16 + 8) / 2),
+    ("engine_iter_p50_ms", (20.0 + 16.0) / 2),  # schedule start to emit end
+])
+def test_span_readers_by_hand(metric, want):
+    assert read(metric, _serve_run(_recorded())) == pytest.approx(want)
+
+
+def _parent_like(rec: SpanRecorder) -> SpanRecorder:
+    """What the program recorded before this PR: no new span, no new arg."""
+    old = SpanRecorder()
+    old.spans = [s for s in rec.spans
+                 if s[0] in ("schedule", "prefill", "decode", "sample", "emit")]
+    return old
+
+
+@pytest.mark.parametrize("metric", NEW_TRAIN + NEW_SERVE)
+def test_reader_with_nothing_to_read_returns_none(metric):
+    """No trace, no spans, or a program that lacks the names and spans
+    (the parent under this PR's benchmark files): None, never 0 and
+    never an exception."""
+    bench = harness.load_benchmark()
+    if metric in NEW_TRAIN:
+        unnamed = xplane.parse_xspace(selftest._ld(1, selftest._plane(
+            "/device:TPU:0", [("XLA Ops", [
+                (f"%jvp__.3 = bf16[8] custom-call(bf16[8] %p){_CC}", 0, 20),
+                (f"%transpose_jvp___.4 = bf16[8] custom-call(bf16[8] %p){_CC}",
+                 20, 40)])])))
+        assert read(metric, _train_run(None)) is None
+        assert read(metric, _train_run(unnamed)) is None
+        cell = TRAIN_CELL
+    else:
+        assert read(metric, _serve_run(None)) is None
+        assert read(metric, _serve_run(SpanRecorder())) is None
+        got = read(metric, _serve_run(_parent_like(_recorded())))
+        if metric in ("prefill_ms_per_iter", "prefill_calls_per_iter"):
+            assert got is not None  # the parent has `prefill` and `chunks`
+        else:
+            assert got is None
+        cell = SERVE_CELL
+    entry = next(m for m in bench["per_layer"] if m["name"] == metric)
+    assert cell in entry["workloads"]
+    decl = harness.load_json("layer_metrics", metric + ".json")
+    assert (decl["unit"], decl["layer"], decl["moves"]) == (
+        entry["unit"], entry["layer"], entry["moves"])
+
+
+# -- the engine's spans, from a real engine under its runner ------------------
+
+STAMPED = {"schedule", "prefill", "prefill_call", "first_token",
+           "decode_inputs", "decode", "sample", "emit"}
+UNSTAMPED = {"step_tail", "intake", "deliver"}
+PROMPTS = [list(range(1, 1 + n)) for n in (5, 9, 20, 3)]  # 20 > one chunk
+NEW_TOKENS = 4
+
+
+def _toy():
+    cfg = ModelConfig(model="diff", vocab_size=61, n_embd=32, n_head=2,
+                      n_layer=2, block_size=32, dropout=0.0,
+                      compute_dtype="float32")
+    serving = ServingConfig(num_slots=4, prefill_chunk=8, prefill_budget=32)
+    return cfg, init_model(jax.random.PRNGKey(0), cfg), serving
+
+
+def _serve(tracer):
+    """The toy engine under its runner through PROMPTS; returns the
+    request ids in submission order."""
+    cfg, params, serving = _toy()
+    runner = EngineRunner(ServingEngine(params, cfg, serving, tracer=tracer))
+    try:
+        pend = [runner.submit(p, SamplingParams(max_new_tokens=NEW_TOKENS))
+                for p in PROMPTS]
+        for p in pend:
+            assert p.done.wait(120) and p.error is None, p.error
+        return [p.result.request_id for p in pend]
+    finally:
+        runner.drain(timeout=30)
+
+
+@pytest.fixture(scope="module")
+def served():
+    rec = SpanRecorder()
+    rids = _serve(rec)
+    return rec, rids
+
+
+def _inside(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_engine_span_names_args_and_the_iteration_rule(served):
+    rec, rids = served
+    by = {}
+    for s in rec.spans:
+        by.setdefault(s[0], []).append(s)
+    assert STAMPED | UNSTAMPED <= set(by)
+    for name in STAMPED:
+        assert all("iteration" in (s[3] or {}) for s in by[name]), name
+    for name in UNSTAMPED:
+        assert all("iteration" not in (s[3] or {}) for s in by[name]), name
+    # what the readers of PR 23 read keeps its names and args
+    assert all({"iteration", "active"} <= set(s[3]) for s in by["decode"])
+    admits = [a for n, _, a in rec.instants if n == "admit"]
+    assert sorted(a["rid"] for a in admits) == sorted(rids)
+    # prefill: its sub-spans inside it, one prefill_call a chunk; no arg
+    # that nothing reads
+    chunks = 0
+    for p in by["prefill"]:
+        args = p[3]
+        assert {"iteration", "chunks"} == set(args)
+        calls = [c for c in by["prefill_call"]
+                 if c[3]["iteration"] == args["iteration"]]
+        firsts = [f for f in by["first_token"]
+                  if f[3]["iteration"] == args["iteration"]]
+        assert len(calls) == args["chunks"]
+        assert all(_inside(s, p) for s in calls + firsts)
+        assert all({"iteration", "size"} == set(c[3]) for c in calls)
+        assert all({"iteration"} == set(f[3]) for f in firsts)
+        chunks += args["chunks"]
+    # every chunk the scheduler planned is one prefill_call, at most 8 long
+    assert chunks == len(by["prefill_call"]) >= 1 + 2 + 3 + 1
+    assert all(c[3]["size"] <= 8 for c in by["prefill_call"])
+    assert sum(c[3]["size"] for c in by["prefill_call"]) == sum(
+        map(len, PROMPTS))
+    assert len(by["first_token"]) == len(PROMPTS)
+    # every stamped span lies between its iteration's schedule start and
+    # the end of its emit (or of its prefill, where nothing decoded yet)
+    sched = {s[3]["iteration"]: s for s in by["schedule"]}
+    last = {}
+    for s in by["emit"] + by["prefill"]:
+        it = s[3]["iteration"]
+        last[it] = max(last.get(it, 0.0), s[2])
+    for name in STAMPED:
+        for s in by[name]:
+            it = s[3]["iteration"]
+            assert sched[it][1] <= s[1] and s[2] <= last[it], (name, it)
+    # decode_inputs ends before its decode starts
+    dec = {s[3]["iteration"]: s for s in by["decode"]}
+    for s in by["decode_inputs"]:
+        assert s[2] <= dec[s[3]["iteration"]][1]
+    assert not any(s[3] for name in UNSTAMPED for s in by[name])
+    # step_tail, deliver and intake lie outside every iteration
+    spans_of_iter = [(sched[it][1], end) for it, end in last.items()]
+    for name in UNSTAMPED:
+        for s in by[name]:
+            assert not any(a < s[2] and s[1] < b for a, b in spans_of_iter), name
+
+
+def test_readers_over_the_engines_own_spans(served):
+    rec, _ = served
+    run = _serve_run(rec)
+    t0 = min(s[1] for s in rec.spans) - 1.0
+    t1 = max(s[2] for s in rec.spans) + 1.0
+    run.values["measured_window"] = (t0, t1)
+    by = {}
+    for s in rec.spans:
+        by.setdefault(s[0], []).append(s)
+    iters = {s[3]["iteration"] for s in by["schedule"]}
+    ms = lambda spans: sum(b - a for _, a, b, _ in spans) * 1e3  # noqa: E731
+    n = len(iters)
+    assert read("prefill_ms_per_iter", run) == pytest.approx(ms(by["prefill"]) / n)
+    assert read("prefill_calls_per_iter", run) == pytest.approx(
+        len(by["prefill_call"]) / n)
+    assert read("first_token_sync_ms_per_iter", run) == pytest.approx(
+        ms(by["first_token"]) / n)
+    assert read("between_iterations_ms", run) == pytest.approx(
+        ms(by["step_tail"] + by["deliver"] + by["intake"]) / n)
+    assert read("prefill_tokens_per_call", run) == pytest.approx(
+        sum(map(len, PROMPTS)) / len(by["prefill_call"]))
+    # engine_iter_p50_ms reads what it read before the new spans: from an
+    # iteration's schedule start to the end of its emit (or prefill)
+    from lib.stats import percentile
+
+    first = {s[3]["iteration"]: s[1] for s in by["schedule"]}
+    last = {}
+    for s in by["emit"] + by["prefill"]:
+        last[s[3]["iteration"]] = max(last.get(s[3]["iteration"], 0.0), s[2])
+    want = percentile([(last[i] - first[i]) * 1e3 for i in first], 50)
+    assert read("engine_iter_p50_ms", run) == pytest.approx(want)
+    old = _serve_run(_parent_like(rec))
+    old.values["measured_window"] = (t0, t1)
+    assert read("engine_iter_p50_ms", old) == pytest.approx(want)
+    # queue_wait_p50_ms and slot_occupancy_pct, from what the cell's
+    # driver takes of the record (the admit instants' rid, decode's active)
+    run.values["decode_active_share"] = [s[3]["active"] / 4 for s in by["decode"]]
+    assert 0 < read("slot_occupancy_pct", run) <= 100
+    run.values["queue_wait_ms"] = [1.0, 3.0]
+    assert read("queue_wait_p50_ms", run) == pytest.approx(2.0)
+
+
+def test_tracing_off_sends_every_site_to_the_noop_tracer(monkeypatch):
+    seen = []
+    real = obs_spans._NoopTracer.span
+
+    def counting(self, name, **args):
+        seen.append((name, args, threading.current_thread().name))
+        return real(self, name, **args)
+
+    monkeypatch.setattr(obs_spans._NoopTracer, "span", counting)
+    cfg, params, serving = _toy()
+    engine = ServingEngine(params, cfg, serving, tracer=None)
+    assert engine.tracer is obs_spans.NOOP_TRACER and not engine._tracing
+    _serve(None)
+    names = {n for n, _, _ in seen}
+    assert STAMPED | UNSTAMPED <= names
+    # nothing was counted or listed for a span nobody records
+    for name, args, _ in seen:
+        if name == "prefill":
+            assert set(args) == {"iteration", "chunks"}
+        if name == "decode":
+            assert set(args) == {"iteration", "active"}

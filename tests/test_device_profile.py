@@ -91,13 +91,18 @@ def synthetic_tpu_space() -> bytes:
     """One TPU plane (XLA Ops line exercising every kernel bucket +
     collectives + rest, plus a decoy line) and one host plane (must
     NOT be picked while the TPU plane exists). Durations in ps."""
+    # event names as the chip writes them: the whole HLO instruction,
+    # operands included; the kernels under their kernel_names.py
+    # names (one under vmap). %convert.7 READS a kernel's result and a
+    # collective's and is neither: it is the "rest".
     meta = {
-        1: "%fusion.1 _dattn_fwd_kernel",
-        2: "_ffn_fwd_kernel.3",
-        3: "_addnorm_bwd_kernel",
-        4: "%custom-call q_chunk_fwd_kernel",
-        5: "%all-reduce.5",
-        6: "%convert.7",
+        1: "%decode_dattn_fwd.1 = bf16[8] custom-call(bf16[8] %p.1)",
+        2: "%fused_ffn_fwd.3 = bf16[8] custom-call(bf16[8] %p.2)",
+        3: "%vmap_fused_add_norm_bwd_.2 = bf16[8] custom-call(bf16[8] %p.3)",
+        4: "%flash_fwd_chunk.4 = bf16[8] custom-call(bf16[8] %p.4)",
+        5: "%all-reduce.5 = f32[8] all-reduce(f32[8] %fusion.9)",
+        6: "%convert.7 = f32[8] convert(bf16[8] %flash_fwd_chunk.4, "
+           "f32[8] %all-reduce.5)",
     }
     xla_ops = [
         (1, 0, 2_000_000_000),          # decode_attention 2 ms
@@ -151,19 +156,23 @@ class TestXprofParser:
             s["busy_ms_per_step"]
         )
         # every op name reaches the totals/counts tables
-        assert s["totals"]["%all-reduce.5"] == pytest.approx(0.5)
-        assert s["counts"]["%fusion.1 _dattn_fwd_kernel"] == 2
+        names = {n.split(" = ")[0]: n for n in s["totals"]}
+        assert s["totals"][names["%all-reduce.5"]] == pytest.approx(0.5)
+        assert s["counts"][names["%decode_dattn_fwd.1"]] == 2
         # per-step division
         s2 = xprof.summarize_plane(plane, kind, steps=2)
         assert s2["busy_ms_per_step"] == pytest.approx(FIXTURE_BUSY / 2)
 
-    def test_decode_and_ffn_match_before_flash(self):
-        """The fused kernels end in flash's "_fwd_kernel" needle —
-        bucket ORDER keeps their time out of flash_attention."""
-        assert xprof.bucket_for("_dattn_fwd_kernel") == "decode_attention"
-        assert xprof.bucket_for("_ffn_fwd_kernel") == "fused_ffn"
-        assert xprof.bucket_for("q_chunk_fwd_kernel") == "flash_attention"
-        assert xprof.bucket_for("%convert.7") is None
+    def test_bucket_is_decided_by_the_instructions_own_name(self):
+        """The buckets hold the names of kernel_names.py, matched
+        against the instruction's own name: under vmap the name is
+        wrapped, an unnamed kernel (what every kernel was before the
+        table) is "rest", and so is an op that only reads a kernel."""
+        assert xprof.bucket_for("%decode_dattn_fwd.4 = x") == "decode_attention"
+        assert xprof.bucket_for("%vmap_fused_ffn_fwd_.1 = x") == "fused_ffn"
+        assert xprof.bucket_for("%flash_bwd_tm_packed.9 = x") == "flash_attention"
+        assert xprof.bucket_for("%transpose_jvp___.3 = x") is None
+        assert xprof.bucket_for("%fusion.7 = f32[8] fusion(%flash_fwd.2)") is None
 
     def test_tf_proto_agreement(self):
         """The stdlib wire reader agrees field-for-field with

@@ -261,9 +261,15 @@ class TestSpanTracer:
             tracer.instant("marker", note="hi")
         tracer.counter("depth", queued=3, active=2)
 
+        # a thread id is reused as soon as its thread has ended: hold
+        # all three at a barrier until each has emitted its span, so
+        # that the three ids are those of three threads alive at once
+        emitted = threading.Barrier(3)
+
         def worker(i):
             with tracer.span("worker", idx=i):
                 time.sleep(0.001)
+            emitted.wait(timeout=30)
 
         threads = [
             threading.Thread(target=worker, args=(i,)) for i in range(3)
@@ -271,7 +277,8 @@ class TestSpanTracer:
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(timeout=60)
+            assert not t.is_alive()
         tracer.close()
         tracer.close()  # idempotent
 
@@ -315,6 +322,49 @@ class TestSpanTracer:
         NOOP_TRACER.counter("z", v=1)
         NOOP_TRACER.flush()
         NOOP_TRACER.close()
+
+    def test_annotate_enters_spans_as_profiler_annotations(
+        self, tmp_path, monkeypatch
+    ):
+        """While ``annotate`` is on (a capture window flips it through
+        ``annotate_spans``) every span is also entered as a
+        ``jax.profiler.TraceAnnotation`` of its name, and still
+        recorded; off, none is; NOOP_TRACER and None have no switch."""
+        from differential_transformer_replication_tpu.obs.spans import (
+            annotate_spans,
+        )
+
+        entered = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                entered.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                entered.append(("exit", self.name))
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+        path = str(tmp_path / "a.trace.json")
+        tracer = SpanTracer(path)
+        assert tracer.annotate is False
+        with tracer.span("before"):
+            pass
+        annotate_spans(tracer, True)
+        with tracer.span("outer", iteration=3):
+            # a window that closes inside a span leaves it whole
+            annotate_spans(tracer, False)
+            with tracer.span("inner"):
+                pass
+        tracer.close()
+        assert entered == [("enter", "outer"), ("exit", "outer")]
+        names = [e["name"] for e in json.load(open(path)) if e["ph"] == "X"]
+        assert names == ["before", "inner", "outer"]
+        annotate_spans(NOOP_TRACER, True)  # nothing to flip, nothing raised
+        annotate_spans(None, True)
+        assert not hasattr(NOOP_TRACER, "annotate")
 
 
 # -- serving instrumentation -------------------------------------------

@@ -70,3 +70,31 @@ def test_profiler_window_early_exit_finalizes(tmp_path):
     w.close(sync=jnp.ones(()))  # loop ended inside the window
     assert not w.active
     assert (tmp_path / "p2").exists()
+
+
+def test_profiler_window_capture_holds_the_tracers_spans(tmp_path):
+    """A real capture, nothing patched: the spans a SpanTracer records
+    while the window is open are in the capture's host plane under their
+    names (obs/spans.py:annotate_spans), those before and after it are
+    not. On the chip the same spans lie inside the device ops' window
+    (PERF.md section 6, PR 24)."""
+    from differential_transformer_replication_tpu.obs import xprof
+    from differential_transformer_replication_tpu.obs.spans import SpanTracer
+
+    tracer = SpanTracer(str(tmp_path / "host.trace.json"))
+    w = ProfilerWindow(str(tmp_path / "p"), start=2, n_steps=2, tracer=tracer)
+    x = jnp.ones((8, 8))
+    for i in range(1, 6):
+        with tracer.span(f"step_{i}"):
+            x = x + 1
+        w.step(i, sync=x)
+    tracer.close()
+    assert not w.active and tracer.annotate is False
+    with open(xprof.find_xplane_pb(str(tmp_path / "p")), "rb") as f:
+        planes = xprof.parse_xspace(f.read())
+    names = {p.event_name(ev.metadata_id) for p in planes
+             if p.name.startswith("/host:")
+             for line in p.lines for ev in line.events}
+    # the window opens after step 2's span closed and closes after step 4's
+    assert {"step_3", "step_4"} <= names
+    assert not {"step_1", "step_2", "step_5"} & names

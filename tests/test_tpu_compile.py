@@ -17,6 +17,7 @@ skip these cases (the tier-1 command runs them serially).
 
 import importlib
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 
@@ -35,6 +36,7 @@ from differential_transformer_replication_tpu.models.common import (
     apply_block_ffn,
     flash_bh_fn,
 )
+from differential_transformer_replication_tpu import kernel_names
 from differential_transformer_replication_tpu.ops.rope import rope_cos_sin
 
 # the module, not the function ops/__init__.py re-exports under the name
@@ -130,6 +132,10 @@ def test_flash_attention_fwd_bwd_compiles(topo, family):
         sds((S, H), jnp.float32),
     )
     assert text.count("tpu_custom_call") >= 2  # forward and backward
+    names = assert_kernels_named(text, "loss")
+    # called bare under jax.grad the instruction is ``jvp_<name>_``
+    assert any("flash_fwd" in n for n in names)
+    assert any("flash_bwd" in n for n in names)
 
 
 @pytest.mark.parametrize("family", [
@@ -152,6 +158,9 @@ def test_model_attention_path_compiles(topo, family):
     text = compile_for(topo, jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
                        *_attention_shapes(family))
     assert text.count("tpu_custom_call") >= 2
+    names = assert_kernels_named(text, "loss")
+    tail = "_tm" if rope else "_tm_packed"  # ``jvp_flash_fwd_tm_packed_``
+    assert all(re.search(rf"flash_(fwd|bwd){tail}_*$", n) for n in names), names
 
 
 def test_flash_attention_with_dropout_compiles(topo):
@@ -164,6 +173,7 @@ def test_flash_attention_with_dropout_compiles(topo):
     text = compile_for(topo, jax.grad(loss, argnums=(0, 1, 2, 3)),
                        *_attention_shapes("diff"), sds((2,), jnp.uint32))
     assert text.count("tpu_custom_call") >= 2
+    assert not any("_tm" in n for n in assert_kernels_named(text, "loss"))
 
 
 def test_fused_ffn_and_add_norm_compile(topo):
@@ -186,6 +196,9 @@ def test_fused_ffn_and_add_norm_compile(topo):
     text = compile_for(topo, jax.grad(loss, argnums=(0, 1, 2)),
                        sds((B, T, E)), sds((B, T, E)), blk)
     assert text.count("tpu_custom_call") >= 4  # add+norm and SwiGLU, both ways
+    assert assert_kernels_named(text, "loss") == set(
+        kernel_names.FUSED_FFN + kernel_names.FUSED_NORM)
+    assert {"ffn_norm", "ffn"} <= scopes_in(text)
 
 
 # decode attention at the recipe's width over a 32-slot pool; pages of 8
@@ -231,6 +244,7 @@ def test_decode_attention_compiles(topo, rows, page, kv):
 
     text = compile_for(topo, fn, *shapes)
     assert text.count("tpu_custom_call") == 1
+    assert assert_kernels_named(text, "fn") == {kernel_names.DECODE_ATTENTION}
 
 
 def test_page_size_is_refused_in_words_when_it_does_not_fit():
@@ -248,10 +262,10 @@ def test_page_size_is_refused_in_words_when_it_does_not_fit():
         ).resolved_pool_pages(model) == 2 * (512 // ok)
 
 
-def _recipe(family: str, **mesh) -> TrainConfig:
+def _recipe(family: str, n_layer: int = 8, **mesh) -> TrainConfig:
     return TrainConfig(
-        model=ModelConfig(model=family, attention_impl="pallas",
-                          ffn_impl="pallas"),
+        model=ModelConfig(model=family, n_layer=n_layer,
+                          attention_impl="pallas", ffn_impl="pallas"),
         mesh=MeshConfig(**mesh),
         micro_batch_size=32 * max(1, mesh.get("data", 1)),
     )
@@ -269,19 +283,142 @@ def _abstract_step_args(cfg: TrainConfig):
     return state, {"x": tokens, "y": tokens}
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_whole_train_step_compiles_one_chip(topo, family):
-    """The whole jitted recipe step (8L/768d, T=512, micro-batch 32,
-    vocab 12000, bf16, Pallas attention and FFN): 30-60 s a family."""
+@pytest.fixture(scope="module")
+def step_text(topo):
+    """``step_text(family, n_layer=8)``: the compiled text of the whole
+    jitted recipe step (8L/768d, T=512, micro-batch 32, vocab 12000, bf16,
+    Pallas attention and FFN) for one described chip, 30-60 s a family at
+    the recipe's depth, compiled once for every test of this module that
+    reads it."""
     from differential_transformer_replication_tpu.train.step import make_step_fn
 
-    cfg = _recipe(family)
-    state, batch = _abstract_step_args(cfg)
-    text = compile_for(topo, make_step_fn(cfg), state, batch)
+    texts = {}
+
+    def get(family: str, n_layer: int = 8) -> str:
+        if (family, n_layer) not in texts:
+            cfg = _recipe(family, n_layer)
+            state, batch = _abstract_step_args(cfg)
+            texts[family, n_layer] = compile_for(
+                topo, make_step_fn(cfg), state, batch)
+        return texts[family, n_layer]
+
+    return get
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_whole_train_step_compiles_one_chip(step_text, family):
     # diff: 82 kernels in the compiled step (ISSUE 21; the same count ran
     # on the chip in chip_smoke.py)
-    assert text.count("tpu_custom_call") >= 60
+    assert step_text(family).count("tpu_custom_call") >= 60
+
+
+# -- names (ISSUE 24): what a profiler trace of the chip will call things --
+
+
+def kernel_instruction_names(text: str) -> list:
+    """The own names (number stripped) of the text's Pallas kernels."""
+    names = []
+    for line in text.split("\n"):
+        if 'custom_call_target="tpu_custom_call"' in line:
+            own = line.split(" = ", 1)[0].split("%")[-1]
+            names.append(re.sub(r"\.\d+$", "", own))
+    return names
+
+
+def assert_kernels_named(text: str, jit_name: str) -> set:
+    names = kernel_instruction_names(text)
+    assert names, "no Pallas kernel in the compiled program"
+    for own in names:
+        assert own not in ("jvp__", "transpose_jvp___", "vmap__", jit_name), own
+        assert any(n in own for n in kernel_names.ALL), (
+            f"the kernel instruction %{own} holds no name of "
+            "kernel_names.py: its pallas_call passes no name=")
+    return set(names)
+
+
+def scopes_in(text: str) -> set:
+    """Every path component of every ``op_name`` of the text, with the
+    autodiff wrappers taken off (``transpose(jvp(attn))`` -> ``attn``)."""
+    out = set()
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        for part in path.split("/"):
+            out.add(re.sub(r"^(?:\w+\()+|\)+$", "", part))
+    return out
+
+
+@pytest.mark.parametrize("family,n_layer", [
+    # the names do not depend on the depth: one layer of the flagship in
+    # the quick tier, every family at the recipe's depth in the slow one
+    pytest.param("diff", 1, id="diff-1layer"),
+] + [pytest.param(f, 8, marks=pytest.mark.slow, id=f) for f in sorted(FAMILIES)])
+def test_train_step_kernels_and_phases_carry_names(step_text, family, n_layer):
+    """Every Pallas kernel of the compiled recipe step is named from the
+    table (attention, FFN and norm apart, forward and backward apart),
+    and the model's and the step's phases reach ``op_name``."""
+    text = step_text(family, n_layer)
+    names = assert_kernels_named(text, "step")
+    # T=512 without dropout: the token-major kernels, packed where the
+    # family has no RoPE
+    flash = ({kernel_names.FLASH_FWD_TM_PACKED, kernel_names.FLASH_BWD_TM_PACKED}
+             if family == "diff"
+             else {kernel_names.FLASH_FWD_TM, kernel_names.FLASH_BWD_TM})
+    assert names == flash | set(kernel_names.FUSED_FFN + kernel_names.FUSED_NORM)
+    assert {"embed", "attn_norm", "attn", "ffn_norm", "ffn", "lm_head_loss",
+            "grad_norm_clip", "optimizer"} <= scopes_in(text)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_decode_program_keeps_its_name_and_names_its_kernels(topo, impl):
+    """The engine's decode program at the recipe's widths (two layers:
+    the names do not depend on the depth) over a small pool, on the path
+    the serve cell takes (``xla``: forward_chunk under
+    vmap, so the fused norm/FFN kernels are ``vmap_<name>_``) and on the
+    fused decode-attention path; its module stays ``jit__decode`` (the
+    benchmark finds the program by that)."""
+    from differential_transformer_replication_tpu.models import init_model
+    from differential_transformer_replication_tpu.models.decode import init_cache
+    from differential_transformer_replication_tpu.serving.engine import (
+        _build_step_fns,
+    )
+
+    slots = 8
+    cfg = ModelConfig(model="diff", n_layer=2, ffn_impl="pallas",
+                      decode_attention_impl=impl)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = place(jax.eval_shape(lambda k: init_model(k, cfg),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_cache(cfg, slots)))
+    ints = place(sds((slots,), jnp.int32))
+    decode = _build_step_fns(cfg, cfg.block_size)[1]
+    text = decode.lower(params, ints, ints, place(sds((slots,), jnp.bool_)),
+                        cache).compile().as_text()
+    assert text.startswith("HloModule jit__decode")
+    names = assert_kernels_named(text, "_decode")
+    assert any(kernel_names.FUSED_FFN_FWD in n for n in names)
+    assert any(kernel_names.FUSED_ADD_NORM_FWD in n for n in names)
+    assert (kernel_names.DECODE_ATTENTION in names) == (impl == "pallas")
+    assert {"attn_norm", "attn", "kv_write", "ffn_norm", "ffn", "lm_head",
+            "kv_merge"} <= scopes_in(text)
+
+
+def test_sampler_is_scoped(topo):
+    """The engine's jitted sampler (a narrow vocabulary: the sort over
+    12,000 takes the compiler 23 s and the scope does not depend on it)."""
+    from differential_transformer_replication_tpu.serving.engine import (
+        _build_step_fns,
+    )
+
+    slots, V = 8, 512
+    sample = _build_step_fns(ModelConfig(model="diff", vocab_size=V), 512)[2]
+    text = compile_for(
+        topo, sample, sds((slots, 8), jnp.int32), sds((slots, V), jnp.float32),
+        sds((slots, V), jnp.bool_), sds((slots, V), jnp.int32))
+    assert text.startswith("HloModule jit__sample")
+    assert "sampler" in scopes_in(text)
 
 
 @pytest.mark.slow
@@ -311,6 +448,7 @@ def test_overlapped_dp_step_compiles_four_chips(topo):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 60
     assert "all-reduce" in text
+    assert_kernels_named(text, "step")  # the names survive shard_map
     x_sharding = compiled.input_shardings[0][1]["x"]
     assert len(x_sharding.device_set) == 4
     assert not x_sharding.is_fully_replicated

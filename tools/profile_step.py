@@ -16,7 +16,8 @@ so before/after MFU deltas are diffable in CI instead of eyeballed from
 text. The fused Pallas kernels get their own buckets
 (obs/xprof.py:KERNEL_BUCKETS): ``flash_attention`` (ops/flash.py),
 ``fused_ffn`` (ops/fused_ffn.py + ops/fused_norm_residual.py),
-``decode_attention`` (ops/decode_attention.py ``_dattn_*`` kernels) and
+``decode_attention`` (ops/decode_attention.py), by the names of
+kernel_names.py, and
 ``collectives`` (HLO communication ops). Without a TPU the command
 exits: a breakdown of the host plane is not a device profile (the
 capture/report functions still run anywhere, for the tests).
