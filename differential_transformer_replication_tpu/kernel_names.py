@@ -16,6 +16,8 @@ substrings, so the names keep to these rules:
 - every norm/residual kernel holds ``fused_add_norm`` and not
   ``fused_ffn``;
 - the decode kernel holds ``_dattn_``;
+- the cache-write kernel holds none of these (the benchmark's reader
+  books it under ``pallas``, ``obs/xprof.py`` under ``kv_write``);
 - no name holds a needle of another family.
 
 Standard library only, at the top of the package: ``ops/`` (which
@@ -47,6 +49,9 @@ FUSED_ADD_NORM_BWD = "fused_add_norm_bwd"
 # ops/decode_attention.py (all four entry points share one call)
 DECODE_ATTENTION = "decode_dattn_fwd"
 
+# ops/kv_write.py (one call a cache leaf: K, V and the int8 scale planes)
+KV_ROW_WRITE = "kv_row_write"
+
 FLASH = (
     FLASH_FWD, FLASH_FWD_TILED, FLASH_FWD_CHUNK, FLASH_FWD_TM,
     FLASH_FWD_TM_PACKED, FLASH_BWD_DQ, FLASH_BWD_DKV, FLASH_BWD_DQ_TILED,
@@ -55,6 +60,7 @@ FLASH = (
 FUSED_FFN = (FUSED_FFN_FWD, FUSED_FFN_BWD)
 FUSED_NORM = (FUSED_ADD_NORM_FWD, FUSED_ADD_NORM_BWD)
 DECODE = (DECODE_ATTENTION,)
+KV_WRITE = (KV_ROW_WRITE,)
 
 #: family -> its kernels' names; every ``pallas_call`` under ``ops/``
 #: passes one of these as ``name=``
@@ -63,5 +69,6 @@ FAMILIES = {
     "fused_ffn": FUSED_FFN,
     "fused_norm": FUSED_NORM,
     "decode_attention": DECODE,
+    "kv_write": KV_WRITE,
 }
-ALL = FLASH + FUSED_FFN + FUSED_NORM + DECODE
+ALL = FLASH + FUSED_FFN + FUSED_NORM + DECODE + KV_WRITE

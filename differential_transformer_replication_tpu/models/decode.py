@@ -69,6 +69,7 @@ from differential_transformer_replication_tpu.ops.decode_attention import (
     dequantize_kv,
     quantize_kv,
 )
+from differential_transformer_replication_tpu.ops.kv_write import write_rows
 from differential_transformer_replication_tpu.ops.lambdas import OUTPUT_SCALE
 from differential_transformer_replication_tpu.ops.streams import (
     NEG_INF,
@@ -305,20 +306,15 @@ def _layer_coeffs(cfg: ModelConfig, p_attn: dict, layer_idx: int) -> jnp.ndarray
     return ndiff_coeffs(lams, ndiff_signs(cfg.n_terms))
 
 
-def _attn_chunk(
+def _chunk_qkv(
     x: jnp.ndarray,  # (B, L, E) normed input chunk
     p_attn: dict,
-    layer_cache: dict,
-    pos,  # scalar int: absolute position of the chunk start
-    layer_idx: int,
     cfg: ModelConfig,
     cos: jnp.ndarray,  # (L, d/2) tables pre-sliced at [pos, pos+L)
     sin: jnp.ndarray,
-    window: int = 0,  # visibility clip; 0/None = the cache size M
-) -> Tuple[jnp.ndarray, dict]:
-    B, L, E = x.shape
-    M = cfg.block_size
-    W = int(window) if window else M
+):
+    """The chunk's queries, keys (S, B, L, H, d) and values
+    (B, L, H, dv), rotated at their absolute positions."""
     wq, wk = _stacked_wq(p_attn)
     qs = jnp.einsum("ble,sehd->sblhd", x, wq.astype(x.dtype))
     ks = jnp.einsum("ble,sehd->sblhd", x, wk.astype(x.dtype))
@@ -326,19 +322,24 @@ def _attn_chunk(
     if _uses_rope(cfg):
         qs = apply_rope(qs, cos, sin)
         ks = apply_rope(ks, cos, sin)
+    return qs, ks, v
 
-    # RING cache: slot = pos mod M, so positions past block_size roll over
-    # the oldest entries instead of clamping. Keys are rotated at their
-    # ABSOLUTE position; RoPE scores depend only on (q_pos - k_pos), so
-    # the rolled window needs no re-rotating (sliding-window attention —
-    # see the module docstring for how this relates to the reference's
-    # crop semantics). The write quantizes on the int8 path, so the
-    # chunk's own attention below reads exactly what later decode steps
-    # will read.
-    slot = jax.lax.rem(jnp.asarray(pos, jnp.int32), M)
-    with jax.named_scope("kv_write"):
-        new_cache = _write_chunk(layer_cache, ks, v, slot)
-    k_cache, v_cache = _dequant_layer(new_cache, x.dtype)
+
+def _chunk_attend(
+    qs: jnp.ndarray,  # (S, B, L, H, d)
+    p_attn: dict,
+    new_cache: dict,  # the layer's cache AFTER the chunk's write
+    pos,  # scalar int: absolute position of the chunk start
+    layer_idx: int,
+    cfg: ModelConfig,
+    window: int = 0,  # visibility clip; 0/None = the cache size M
+) -> jnp.ndarray:
+    """Attend the chunk's rows over the ring that already holds their
+    own K/V (update-then-attend), combine the streams, project out."""
+    _, B, L = qs.shape[:3]
+    M = cfg.block_size
+    W = int(window) if window else M
+    k_cache, v_cache = _dequant_layer(new_cache, qs.dtype)
 
     scale = 1.0 / (cfg.head_size ** 0.5)
     scores = (
@@ -365,13 +366,66 @@ def _attn_chunk(
 
     coeffs = _layer_coeffs(cfg, p_attn, layer_idx)  # (S, H)
     combined = jnp.einsum("sh,sbhlm->bhlm", coeffs, probs)
-    out = jnp.einsum("bhlm,bhme->blhe", combined.astype(v.dtype), v_cache)
+    out = jnp.einsum("bhlm,bhme->blhe", combined.astype(qs.dtype), v_cache)
     out = out.reshape(B, L, -1)  # concat heads
     if cfg.model in ("diff", "ndiff"):
         out = common.apply_group_norm(out, p_attn["gn"], cfg)
         out = out * OUTPUT_SCALE  # constant 0.2 (diff_transformer.py:91)
-    out = common.linear(out, p_attn["out"])
+    return common.linear(out, p_attn["out"])
+
+
+def _attn_chunk(
+    x: jnp.ndarray,  # (B, L, E) normed input chunk
+    p_attn: dict,
+    layer_cache: dict,
+    pos,  # scalar int: absolute position of the chunk start
+    layer_idx: int,
+    cfg: ModelConfig,
+    cos: jnp.ndarray,
+    sin: jnp.ndarray,
+    window: int = 0,
+) -> Tuple[jnp.ndarray, dict]:
+    qs, ks, v = _chunk_qkv(x, p_attn, cfg, cos, sin)
+    # RING cache: slot = pos mod M, so positions past block_size roll over
+    # the oldest entries instead of clamping. Keys are rotated at their
+    # ABSOLUTE position; RoPE scores depend only on (q_pos - k_pos), so
+    # the rolled window needs no re-rotating (sliding-window attention —
+    # see the module docstring for how this relates to the reference's
+    # crop semantics). The write quantizes on the int8 path, so the
+    # chunk's own attention reads exactly what later decode steps will
+    # read.
+    slot = jax.lax.rem(jnp.asarray(pos, jnp.int32), cfg.block_size)
+    with jax.named_scope("kv_write"):
+        new_cache = _write_chunk(layer_cache, ks, v, slot)
+    out = _chunk_attend(qs, p_attn, new_cache, pos, layer_idx, cfg, window)
     return out, new_cache
+
+
+def _embed_chunk(params: dict, tokens: jnp.ndarray, pos,
+                 cfg: ModelConfig, rope_len: int):
+    """The chunk's input rows (B, L, E) and its RoPE tables sliced at
+    [pos, pos+L) (None for the diff family, which adds its learned
+    absolute positions here instead, diff_transformer.py:158)."""
+    L, M = tokens.shape[1], cfg.block_size
+    compute = jnp.dtype(cfg.compute_dtype)
+    x = params["tok_emb"][tokens].astype(compute)
+    if cfg.model == "diff":
+        x = x + jax.lax.dynamic_slice_in_dim(
+            params["pos_emb"], pos, L, axis=0
+        ).astype(compute)
+        return x, None, None
+    cos_full, sin_full = rope_cos_sin(cfg.head_size, max(int(rope_len), M))
+    return (
+        x,
+        jax.lax.dynamic_slice_in_dim(cos_full, pos, L, axis=0),
+        jax.lax.dynamic_slice_in_dim(sin_full, pos, L, axis=0),
+    )
+
+
+@jax.named_scope("lm_head")
+def _lm_head(params: dict, x: jnp.ndarray, cfg: ModelConfig):
+    x = common.apply_pre_norm(x, params["ln_f"], cfg)
+    return common.linear(x, params["lm_head"])
 
 
 def forward_chunk(
@@ -433,20 +487,7 @@ def forward_chunk(
                 f"chunk [{pos}, {pos + L}) wraps the ring boundary (slot "
                 f"{pos % M} + {L} > {M}): split it at the boundary"
             )
-    compute = jnp.dtype(cfg.compute_dtype)
-    x = params["tok_emb"][tokens].astype(compute)
-    if cfg.model == "diff":  # learned absolute positions (diff_transformer.py:158)
-        x = x + jax.lax.dynamic_slice_in_dim(
-            params["pos_emb"], pos, L, axis=0
-        ).astype(compute)
-        cos = sin = None
-    else:
-        cos_full, sin_full = rope_cos_sin(
-            cfg.head_size, max(int(rope_len), M)
-        )
-        cos = jax.lax.dynamic_slice_in_dim(cos_full, pos, L, axis=0)
-        sin = jax.lax.dynamic_slice_in_dim(sin_full, pos, L, axis=0)
-
+    x, cos, sin = _embed_chunk(params, tokens, pos, cfg, rope_len)
     new_cache = []
     for li, blk in enumerate(params["blocks"], 1):  # 1-based (diff_transformer.py:161)
         with jax.named_scope("attn_norm"):
@@ -461,18 +502,18 @@ def forward_chunk(
         # generation is eval-mode)
         x = common.apply_block_ffn(x, a, blk, cfg)
         new_cache.append(layer_cache)
-    with jax.named_scope("lm_head"):
-        x = common.apply_pre_norm(x, params["ln_f"], cfg)
-        logits = common.linear(x, params["lm_head"])
-    return logits, new_cache
+    return _lm_head(params, x, cfg), new_cache
 
 
 # ---------------------------------------------------------------------------
-# Pool-native batched decode (decode_attention_impl == "pallas"): the
-# whole slot pool advances one token in ONE call — no vmap over rows —
-# with each row at its own absolute position and attention running
-# through the fused Pallas kernel (ops/decode_attention.py). The XLA
-# baseline keeps the per-row vmapped forward_chunk path untouched.
+# The decode step over the whole slot pool: every row advances one token
+# at its own absolute position, and the step's cache traffic is the rows
+# it writes. Two programs share one write (``_update_cache_rows``):
+# ``forward_decode_rows`` (decode_attention_impl == "xla") keeps every
+# row a length-1 ``forward_chunk`` under vmap and lifts only the write
+# out of the vmap; ``forward_decode_pool`` ("pallas") runs the pool in
+# one batch with the fused decode-attention kernel
+# (ops/decode_attention.py).
 # ---------------------------------------------------------------------------
 
 
@@ -493,32 +534,37 @@ def _rope_rows(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray):
     )
 
 
-def _update_cache_rows(layer_cache: dict, ks: jnp.ndarray, v: jnp.ndarray,
-                       pos: jnp.ndarray, M: int) -> dict:
-    """Scatter each row's new K/V — ks (S, B, H, d), v (B, H, dv) — into
-    its own ring slot ``pos[b] % M`` (one XLA scatter per leaf; row/slot
-    pairs are unique so the update order is immaterial)."""
+@jax.named_scope("kv_merge")
+def _write_targets(pos: jnp.ndarray, active, M: int) -> jnp.ndarray:
+    """(B,) int32: the ring position each row's K/V goes to,
+    ``pos[b] % M``, or -1 for a row that is not ``active`` and keeps
+    what its ring holds (a free slot, a slot in mid-prefill, a verify
+    row past its slot's draft). This B-sized select is all that a step
+    decides about keeping: the pool itself is never selected over."""
     slot = jax.lax.rem(jnp.asarray(pos, jnp.int32), M)
-    b_idx = jnp.arange(slot.shape[0])
-    out = dict(layer_cache)
+    return slot if active is None else jnp.where(active, slot, -1)
+
+
+def _update_cache_rows(layer_cache: dict, ks: jnp.ndarray, v: jnp.ndarray,
+                       targets: jnp.ndarray) -> dict:
+    """Write each row's new K/V — ks (S, B, H, d), v (B, H, dv) — into
+    its own ring at ``targets[b]`` (:func:`_write_targets`), in place in
+    the donated pool, one ``ops/kv_write.py`` kernel a leaf: the float
+    and the int8 leaves and the scale planes take the same route,
+    addressed through ``KV_CACHE_BATCH_AXIS``. The int8 path quantizes
+    first, so the step's own attention (and every later step) reads
+    exactly what the cache holds."""
     if "k_scale" in layer_cache:
         kq, ksc = quantize_kv(ks)
         vq, vsc = quantize_kv(v)
-        out["k"] = layer_cache["k"].at[:, b_idx, :, slot].set(
-            kq.transpose(1, 0, 2, 3)
-        )
-        out["k_scale"] = layer_cache["k_scale"].at[:, b_idx, :, slot].set(
-            ksc.transpose(1, 0, 2)
-        )
-        out["v"] = layer_cache["v"].at[b_idx, :, slot].set(vq)
-        out["v_scale"] = layer_cache["v_scale"].at[b_idx, :, slot].set(vsc)
+        rows = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc}
     else:
-        dt = layer_cache["k"].dtype
-        out["k"] = layer_cache["k"].at[:, b_idx, :, slot].set(
-            ks.astype(dt).transpose(1, 0, 2, 3)
-        )
-        out["v"] = layer_cache["v"].at[b_idx, :, slot].set(v.astype(dt))
-    return out
+        rows = {"k": ks, "v": v}
+    return {
+        key: write_rows(leaf, rows[key].astype(leaf.dtype), targets,
+                        KV_CACHE_BATCH_AXIS[key])
+        for key, leaf in layer_cache.items()
+    }
 
 
 def _pool_attn(
@@ -526,6 +572,7 @@ def _pool_attn(
     p_attn: dict,
     layer_cache: dict,
     pos: jnp.ndarray,  # (B,) int32 absolute positions
+    targets: jnp.ndarray,  # (B,) int32 ring position written, -1: none
     layer_idx: int,
     cfg: ModelConfig,
     cos,  # (B, d/2) per-row RoPE tables (None for the diff family)
@@ -543,9 +590,7 @@ def _pool_attn(
         qs = _rope_rows(qs, cos, sin)
         ks = _rope_rows(ks, cos, sin)
     with jax.named_scope("kv_write"):
-        new_cache = _update_cache_rows(
-            layer_cache, ks, v, pos, cfg.block_size
-        )
+        new_cache = _update_cache_rows(layer_cache, ks, v, targets)
     coeffs = _layer_coeffs(cfg, p_attn, layer_idx)
     if cfg.decode_attention_impl == "pallas":
         out = decode_attention(
@@ -570,19 +615,23 @@ def forward_decode_pool(
     cache: list,
     cfg: ModelConfig,
     rope_len: int = 0,
+    active=None,  # (B,) bool: rows whose K/V is written; None = all
 ) -> Tuple[jnp.ndarray, list]:
     """Advance the WHOLE slot pool by one token: returns ((B, V) logits,
     updated cache). The batched counterpart of a length-1
     :func:`forward_chunk` per row — same ring semantics, same
     update-then-attend order, every row at its own position — minus the
     vmap, so the fused decode kernel sees the full pool in one
-    ``(B*H,)``-grid call per layer. Host-side admission guards
-    (serving/engine.py submit, generate_cached's checks) own the
-    concrete-position validity rules; everything here is traced."""
-    B = tokens.shape[0]
+    ``(B*H,)``-grid call per layer. Rows that are not ``active`` run the
+    same math on whatever their slot holds (static shapes are the point)
+    and leave their ring as it is; their logits mean nothing. Host-side
+    admission guards (serving/engine.py submit, generate_cached's
+    checks) own the concrete-position validity rules; everything here
+    is traced."""
     M = cfg.block_size
     compute = jnp.dtype(cfg.compute_dtype)
     pos = jnp.asarray(pos, jnp.int32)
+    targets = _write_targets(pos, active, M)
     x = params["tok_emb"][tokens].astype(compute)  # (B, E)
     cos = sin = None
     if cfg.model == "diff":
@@ -599,31 +648,73 @@ def forward_decode_pool(
             h = common.apply_pre_norm(x, blk["ln1"], cfg)
         with jax.named_scope("attn"):
             a, layer_cache = _pool_attn(
-                h, blk["attn"], cache[li - 1], pos, li, cfg, cos, sin,
+                h, blk["attn"], cache[li - 1], pos, targets, li, cfg,
+                cos, sin,
             )
         x = common.apply_block_ffn(x, a, blk, cfg)
         new_cache.append(layer_cache)
-    with jax.named_scope("lm_head"):
-        x = common.apply_pre_norm(x, params["ln_f"], cfg)
-        return common.linear(x, params["lm_head"]), new_cache
+    return _lm_head(params, x, cfg), new_cache
 
 
-@jax.named_scope("kv_merge")
-def merge_cache_update(active: jnp.ndarray, new_cache: list,
-                       old_cache: list) -> list:
-    """Masked cache merge over the pool-batch axis of every leaf: rows
-    where ``active`` keep the update, others keep their old buffers —
-    how the engine's batched decode step discards the garbage writes of
-    inactive/mid-prefill slots (serving/engine.py)."""
-    merged = []
-    for nc, oc in zip(new_cache, old_cache):
-        layer = {}
-        for key in nc:
-            axis = KV_CACHE_BATCH_AXIS[key]
-            shape = (1,) * axis + (-1,) + (1,) * (nc[key].ndim - axis - 1)
-            layer[key] = jnp.where(active.reshape(shape), nc[key], oc[key])
-        merged.append(layer)
-    return merged
+def forward_decode_rows(
+    params: dict,
+    tokens: jnp.ndarray,  # (B,) current token per slot row
+    pos,  # (B,) int32 absolute position per row (runtime array)
+    cache: list,
+    cfg: ModelConfig,
+    rope_len: int = 0,
+    active=None,  # (B,) bool: rows whose K/V is written; None = all
+) -> Tuple[jnp.ndarray, list]:
+    """The XLA decode step: ((B, V) fp32 logits, updated cache). Every
+    row is a length-1 :func:`forward_chunk` at its own position, under
+    ``vmap`` over the rows — the same einsums at the same shapes, which
+    is what keeps a served token bit-identical to ``generate_cached``'s
+    and a speculative sub-step bit-identical to a plain step. Only the
+    write is lifted out of the vmap: a vmapped ``dynamic_update_slice``
+    hands back a NEW pool, which the chip fills through a copy of every
+    ring (ops/kv_write.py), so between the two vmapped halves of a
+    layer the rows' K/V go into the donated pool in place. ``active``
+    as in :func:`forward_decode_pool`."""
+    pos = jnp.asarray(pos, jnp.int32)
+    targets = _write_targets(pos, active, cfg.block_size)
+    x, cos, sin = jax.vmap(
+        lambda t, p: _embed_chunk(params, t[None, None], p, cfg, rope_len)
+    )(tokens, pos)  # x (B, 1, 1, E): a batch-1 chunk of one token a row
+    new_cache = []
+    for li, blk in enumerate(params["blocks"], 1):  # 1-based schedule
+
+        def _qkv(x, cos, sin):
+            with jax.named_scope("attn_norm"):
+                h = common.apply_pre_norm(x, blk["ln1"], cfg)
+            with jax.named_scope("attn"):
+                return _chunk_qkv(h, blk["attn"], cfg, cos, sin)
+
+        def _attend_ffn(x, qs, p, ring):
+            # re-add the batch-1 axis forward_chunk's layout has
+            ring = {
+                key: jnp.expand_dims(leaf, KV_CACHE_BATCH_AXIS[key])
+                for key, leaf in ring.items()
+            }
+            with jax.named_scope("attn"):
+                a = _chunk_attend(qs, blk["attn"], ring, p, li, cfg)
+            return common.apply_block_ffn(x, a, blk, cfg)
+
+        qs, ks, v = jax.vmap(_qkv)(x, cos, sin)
+        with jax.named_scope("attn"), jax.named_scope("kv_write"):
+            layer_cache = _update_cache_rows(
+                cache[li - 1],
+                ks[:, :, 0, 0].swapaxes(0, 1),  # (B, S, 1, 1, H, d) rows
+                v[:, 0, 0],  # (B, 1, 1, H, dv) rows
+                targets,
+            )
+        x = jax.vmap(
+            _attend_ffn,
+            in_axes=(0, 0, 0, {key: KV_CACHE_BATCH_AXIS[key]
+                               for key in layer_cache}),
+        )(x, qs, pos, layer_cache)
+        new_cache.append(layer_cache)
+    logits = jax.vmap(lambda x: _lm_head(params, x, cfg))(x)
+    return logits[:, 0, -1].astype(jnp.float32), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -753,8 +844,8 @@ def _update_pages_rows(layer_cache: dict, ks: jnp.ndarray,
     """Scatter each row's new K/V — ks (S, B, H, d), v (B, H, dv) —
     into physical page ``write_pages[b]`` at in-page offset
     ``(pos[b] % M) % page_size``. The engine redirects inactive rows
-    to the trash page, which replaces the contiguous path's masked
-    merge (models/decode.py:merge_cache_update)."""
+    to the trash page, where the contiguous path gives them no write
+    target (:func:`_write_targets`)."""
     ps = layer_cache["v"].shape[-2]
     off = jax.lax.rem(
         jax.lax.rem(jnp.asarray(pos, jnp.int32), M), ps
@@ -1015,64 +1106,22 @@ def _pool_attn_spec(
     return common.linear(out, p_attn["out"]), new_cache
 
 
-def _spec_row_axes(cfg: ModelConfig) -> list:
-    """Per-layer cache vmap axes (the engine's ``row_axes`` twin)."""
-    keys = (
-        ("k", "v", "k_scale", "v_scale")
-        if kv_store_dtype(cfg) == "int8" else ("k", "v")
-    )
-    return [
-        {key: KV_CACHE_BATCH_AXIS[key] for key in keys}
-    ] * cfg.n_layer
-
-
-def _one_row_exact(params, token, pos, cache_row, cfg: ModelConfig,
-                   rope_len: int):
-    """One vmap lane of the engine's XLA decode step (serving/engine.py
-    ``_build_step_fns._one_row``, duplicated here so the EXACT verify
-    mode is bit-identical to it by construction): re-add the batch-1
-    axis forward_chunk expects, advance one token, strip it again."""
-    cache_b = [
-        {key: (c[key][:, None] if KV_CACHE_BATCH_AXIS[key]
-               else c[key][None])
-         for key in c}
-        for c in cache_row
-    ]
-    logits, new_cache = forward_chunk(
-        params, token[None, None], pos, cache_b, cfg, rope_len=rope_len
-    )
-    new_row = [
-        {key: (c[key][:, 0] if KV_CACHE_BATCH_AXIS[key] else c[key][0])
-         for key in c}
-        for c in new_cache
-    ]
-    return logits[0, -1].astype(jnp.float32), new_row
-
-
 def _exact_row_step(params, tokens_r, pos_r, valid_r, cache,
                     cfg: ModelConfig, rope_len: int):
-    """One EXACT verify sub-step over the full contiguous pool: run
-    the engine's own L=1 decode program (vmapped forward_chunk for the
-    XLA impl, the pool-native fused path for pallas) and discard
-    invalid rows' writes with the same masked merge the engine uses.
+    """One EXACT verify sub-step over the full contiguous pool: the
+    engine's own L=1 decode program (:func:`forward_decode_rows` for the
+    XLA impl, the pool-native fused path for pallas), with ``valid_r``
+    as its write mask, so invalid rows leave their rings as they are.
     Because every op runs at exactly the L=1 step's shapes, the
     sub-step is bit-identical to a plain engine iteration — at ANY
     model size (batched multi-row matmuls reassociate their reductions
     once the contraction is large enough; per-lane/M-preserving shapes
     cannot)."""
-    if cfg.decode_attention_impl == "pallas":
-        logits, new_cache = forward_decode_pool(
-            params, tokens_r, pos_r, cache, cfg, rope_len=rope_len
-        )
-        logits = logits.astype(jnp.float32)
-    else:
-        axes = _spec_row_axes(cfg)
-        logits, new_cache = jax.vmap(
-            lambda t, p, c: _one_row_exact(params, t, p, c, cfg,
-                                           rope_len),
-            in_axes=(0, 0, axes), out_axes=(0, axes),
-        )(tokens_r, pos_r, cache)
-    return logits, merge_cache_update(valid_r, new_cache, cache)
+    step = (forward_decode_pool if cfg.decode_attention_impl == "pallas"
+            else forward_decode_rows)
+    logits, cache = step(params, tokens_r, pos_r, cache, cfg,
+                         rope_len=rope_len, active=valid_r)
+    return logits.astype(jnp.float32), cache
 
 
 def forward_decode_spec(

@@ -58,6 +58,7 @@ from differential_transformer_replication_tpu import kernel_names as _NAMES
 # decomposition; everything unmatched is "rest".
 KERNEL_BUCKETS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("decode_attention", _NAMES.DECODE),
+    ("kv_write", _NAMES.KV_WRITE),
     ("fused_ffn", _NAMES.FUSED_FFN + _NAMES.FUSED_NORM),
     ("flash_attention", _NAMES.FLASH),
     ("collectives", ("all-reduce", "all-gather", "reduce-scatter",

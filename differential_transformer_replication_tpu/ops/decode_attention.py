@@ -61,6 +61,9 @@ from differential_transformer_replication_tpu.ops.flash import (
     auto_interpret,
     pick_block,
 )
+from differential_transformer_replication_tpu.ops.kv_write import (
+    position_on_lanes,
+)
 from differential_transformer_replication_tpu.ops.streams import NEG_INF
 
 # K tile length streamed per grid step; clipped to a divisor of the cache
@@ -106,8 +109,9 @@ def _dattn_kernel(
     # after the scalar-prefetch refs (the page table, consumed by the
     # index maps):
     #   q_ref    (1, L, S, d)   this (b, h)'s per-(row, stream) queries
-    #   k_ref    (S, 1, block_k, d)  stored dtype (float) or int8
-    #   v_ref    (1, block_k, dv)
+    #   k_ref    (S, 1, block_k, d)  stored dtype (float) or int8;
+    #            (S, 1, d, block_k) with ``k_on_lanes``
+    #   v_ref    (1, block_k, dv); (1, dv, block_k) with ``v_on_lanes``
     #   pos_ref  (L, BH) int32 SMEM: absolute position per row, program
     #   c_ref    (S, H) float32 SMEM combine coefficients (_layer_coeffs)
     #   [ks_ref (S, 1, 1, block_k), vs_ref (1, 1, block_k) if quantized]
@@ -116,6 +120,8 @@ def _dattn_kernel(
     n_prefetch: int,
     n_heads: int,
     quantized: bool,
+    k_on_lanes: bool = False,
+    v_on_lanes: bool = False,
 ):
     refs = refs[n_prefetch:]
     q_ref, k_ref, v_ref, pos_ref, c_ref = refs[:5]
@@ -124,7 +130,7 @@ def _dattn_kernel(
     else:
         out_ref, m_scr, l_scr, acc_scr = refs[5:]
     L, S, d = q_ref.shape[1:]
-    block_k = k_ref.shape[2]
+    block_k = k_ref.shape[3 if k_on_lanes else 2]
     bh = pl.program_id(0)  # read at top level (interpreter cannot lower
     j = pl.program_id(1)   # program_id inside when-bodies; see ops/flash.py)
     nk = pl.num_programs(1)
@@ -153,7 +159,7 @@ def _dattn_kernel(
         cols = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1
         )
-        v_j = v_ref[0]  # (block_k, dv)
+        v_j = v_ref[0]  # (block_k, dv) | (dv, block_k)
         if quantized:
             # int8 -> compute dtype is exact; the per-token scales are
             # applied on the (1, block_k) score / probability rows
@@ -161,7 +167,7 @@ def _dattn_kernel(
             v_j = v_j.astype(jnp.float32).astype(cdtype)
             v_sc = vs_ref[0]  # (1, block_k)
         for s_i in range(S):
-            k_j = k_ref[s_i, 0]  # (block_k, d)
+            k_j = k_ref[s_i, 0]  # (block_k, d) | (d, block_k)
             if quantized:
                 k_j = k_j.astype(jnp.float32).astype(cdtype)
                 k_sc = ks_ref[s_i, 0] * scale  # (1, block_k)
@@ -170,7 +176,9 @@ def _dattn_kernel(
                 q = q_ref[0, l, s_i:s_i + 1, :]  # (1, d)
                 s = jax.lax.dot_general(
                     q, k_j,
-                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    dimension_numbers=(
+                        ((1,), (0 if k_on_lanes else 1,)), ((), ())
+                    ),
                     preferred_element_type=jnp.float32,
                 )  # (1, block_k)
                 s = s * k_sc if quantized else s * scale
@@ -189,7 +197,9 @@ def _dattn_kernel(
                     p = p * v_sc
                 pv = jax.lax.dot_general(
                     p.astype(v_j.dtype), v_j,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    dimension_numbers=(
+                        ((1,), (1 if v_on_lanes else 0,)), ((), ())
+                    ),
                     preferred_element_type=jnp.float32,
                 )  # (1, dv)
                 acc_scr[i:i + 1] = acc_scr[i:i + 1] * alpha + pv
@@ -232,7 +242,14 @@ def _dattn_call(
     Mosaic wants the last two dims of every block either full or
     (8, 128)-aligned, so single rows ride behind a singleton axis: the
     output block is ``(1, L, dv)`` and the scale planes are viewed as
-    ``(.., 1, M)`` (zero-copy: M is already the minor axis)."""
+    ``(.., 1, M)`` (zero-copy: M is already the minor axis).
+
+    The slot pool is read in the layout the chip holds it in
+    (ops/kv_write.py:``position_on_lanes``): where the chip puts the
+    ring on the lanes (the recipe's 512 x 96 and 512 x 192), K and V
+    ride in as their ``(.., d, M)`` views and the two matmuls contract
+    the other axis of the tile; a row-major view there would have XLA
+    copy the whole pool into another layout for every call."""
     S, B, L, H, d = qs.shape
     rows, M = k.shape[1] * H, k.shape[3]
     dv = v.shape[-1]
@@ -243,10 +260,14 @@ def _dattn_call(
     if quantized != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be given together")
 
+    k_on_lanes = v_on_lanes = False
     if page_tables is None:
         prefetch = []
         bk = pick_block(block_k or _DEFAULT_BLOCK_K, M)
         n_tiles = M // bk
+        lane_tiles = bk == M or bk % 128 == 0
+        k_on_lanes = lane_tiles and position_on_lanes(M, d)
+        v_on_lanes = lane_tiles and position_on_lanes(M, dv)
 
         def kv_index(bh, j):
             return bh, j
@@ -261,8 +282,10 @@ def _dattn_call(
     # (S, B, L, H, d) -> (BH, L, S, d): tiny, one token per row
     q = qs.transpose(1, 3, 2, 0, 4).reshape(BH, L, S, d)
     # zero-copy views: the pools are head-major (models/decode.py)
-    k = k.reshape(S, rows, M, d)
-    v = v.reshape(rows, M, dv)
+    k = (jnp.swapaxes(k, -1, -2).reshape(S, rows, d, M) if k_on_lanes
+         else k.reshape(S, rows, M, d))
+    v = (jnp.swapaxes(v, -1, -2).reshape(rows, dv, M) if v_on_lanes
+         else v.reshape(rows, M, dv))
     # (L, BH): column b*H+h carries slot b's row positions
     pos_bh = jnp.repeat(jnp.asarray(pos, jnp.int32).T, H, axis=1)
 
@@ -273,11 +296,17 @@ def _dattn_call(
     in_specs = [
         pl.BlockSpec((1, L, S, d), lambda bh, j, *pt: (bh, 0, 0, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((S, 1, bk, d),
-                     lambda *a: (0, *kv_index(*a), 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, dv), lambda *a: (*kv_index(*a), 0),
-                     memory_space=pltpu.VMEM),
+        (pl.BlockSpec((S, 1, d, bk),
+                      lambda *a: (0, kv_index(*a)[0], 0, kv_index(*a)[1]),
+                      memory_space=pltpu.VMEM) if k_on_lanes else
+         pl.BlockSpec((S, 1, bk, d),
+                      lambda *a: (0, *kv_index(*a), 0),
+                      memory_space=pltpu.VMEM)),
+        (pl.BlockSpec((1, dv, bk),
+                      lambda *a: (kv_index(*a)[0], 0, kv_index(*a)[1]),
+                      memory_space=pltpu.VMEM) if v_on_lanes else
+         pl.BlockSpec((1, bk, dv), lambda *a: (*kv_index(*a), 0),
+                      memory_space=pltpu.VMEM)),
         pl.BlockSpec((L, BH), fixed(0, 0), memory_space=pltpu.SMEM),
         pl.BlockSpec((S, H), fixed(0, 0), memory_space=pltpu.SMEM),
     ]
@@ -300,7 +329,8 @@ def _dattn_call(
     out = pl.pallas_call(
         functools.partial(
             _dattn_kernel, n_prefetch=len(prefetch), n_heads=H,
-            quantized=quantized,
+            quantized=quantized, k_on_lanes=k_on_lanes,
+            v_on_lanes=v_on_lanes,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),

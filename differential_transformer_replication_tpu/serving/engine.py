@@ -30,7 +30,8 @@ recompilation as requests come and go:
 
 - the decode step is one jitted call over the FULL pool — per-slot
   positions/tokens/active-mask are runtime arrays (inactive rows compute
-  garbage that a masked cache-merge discards);
+  garbage and write nothing: the mask goes into the cache write, and the
+  step's cache traffic is the rows it writes, in place in the pool);
 - prefill chunks come from a power-of-two ladder, so at most
   log2(prefill_chunk)+1 prefill shapes ever compile;
 - sampling is one jitted batched kernel with per-row temperature/top-k
@@ -39,8 +40,9 @@ recompilation as requests come and go:
   default paths are bit-identical to ``sample_token`` — pinned by
   tests/test_serving.py.
 
-Mixed per-slot positions ride a ``jax.vmap`` over ``forward_chunk``
-(each row carries its own ``pos`` scalar, exactly the traced-position
+Mixed per-slot positions ride a ``jax.vmap`` over the rows, each a
+length-1 ``forward_chunk`` (models/decode.py:``forward_decode_rows``:
+each row carries its own ``pos`` scalar, exactly the traced-position
 path the chunked decoder already supports); ``forward_chunk``'s
 concrete-position validity guards are enforced host-side at submit
 instead. Per-request determinism: the key for the t-th generated token
@@ -76,13 +78,13 @@ from differential_transformer_replication_tpu.models.decode import (
     forward_chunk,
     forward_decode_pool,
     forward_decode_pool_paged,
+    forward_decode_rows,
     forward_decode_spec,
     forward_decode_spec_paged,
     gather_slot_cache,
     init_cache,
     init_cache_paged,
     kv_store_dtype,
-    merge_cache_update,
     quality_vector,
     scatter_slot_cache,
 )
@@ -296,38 +298,6 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
     token column; False compiles the EXACT pre-telemetry closure, so
     telemetry-off output is bit-identical by construction.
     """
-    # cache leaves depend on the KV dtype (int8 adds the scale planes);
-    # slicing/scatter/vmap specs derive from the shared axis table so
-    # every leaf is handled uniformly (models/decode.py)
-    cache_keys = (
-        ("k", "v", "k_scale", "v_scale")
-        if kv_store_dtype(cfg) == "int8" else ("k", "v")
-    )
-    row_axes = [
-        {key: KV_CACHE_BATCH_AXIS[key] for key in cache_keys}
-    ] * cfg.n_layer
-
-    def _row_expand(c, key):
-        # one pool row -> the batch-1 layout forward_chunk expects
-        return c[key][:, None] if KV_CACHE_BATCH_AXIS[key] else c[key][None]
-
-    def _row_squeeze(c, key):
-        return c[key][:, 0] if KV_CACHE_BATCH_AXIS[key] else c[key][0]
-
-    def _one_row(params, token, pos, cache_row):
-        # cache_row: per-layer per-slot cache leaves (batch axis sliced
-        # away by the vmap); re-add the batch axis forward_chunk expects.
-        cache_b = [
-            {key: _row_expand(c, key) for key in c} for c in cache_row
-        ]
-        logits, new_cache = forward_chunk(
-            params, token[None, None], pos, cache_b, cfg, rope_len=rope_len
-        )
-        new_row = [
-            {key: _row_squeeze(c, key) for key in c} for c in new_cache
-        ]
-        return logits[0, -1].astype(jnp.float32), new_row
-
     if page_size > 0:
 
         def _decode_paged(params, tokens, pos, cache, page_tables,
@@ -336,8 +306,8 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
             THROUGH the page tables (models/decode.py
             ``forward_decode_pool_paged``): both attention impls
             dispatch inside; inactive rows' writes are redirected to
-            the trash page by ``write_pages`` (the paged replacement
-            for the contiguous path's masked merge)."""
+            the trash page by ``write_pages`` (the contiguous path
+            gives them no write target instead)."""
             logits, new_cache = forward_decode_pool_paged(
                 params, tokens, pos, cache, page_tables, write_pages,
                 cfg, rope_len=rope_len,
@@ -392,38 +362,25 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
                 out.append(layer)
             return out
 
-    if cfg.decode_attention_impl == "pallas":
+    def _decode(params, tokens, pos, active, cache):
+        """One batched length-1 step over the WHOLE slot pool:
+        models/decode.py ``forward_decode_rows`` (every row a length-1
+        forward_chunk under vmap) or, with ``decode_attention_impl:
+        pallas``, ``forward_decode_pool`` (one batch, the fused
+        decode-attention kernel over every row in one (B*H,)-grid call
+        per layer).
 
-        def _decode(params, tokens, pos, active, cache):
-            """One batched length-1 step over the WHOLE slot pool via the
-            pool-native fused path (models/decode.py
-            ``forward_decode_pool``): the Pallas decode-attention kernel
-            sees every row in one (B*H,)-grid call per layer instead of
-            a vmap over rows. Masked-merge semantics identical to the
-            XLA variant below."""
-            logits, new_cache = forward_decode_pool(
-                params, tokens, pos, cache, cfg, rope_len=rope_len
-            )
-            return (
-                logits.astype(jnp.float32),
-                merge_cache_update(active, new_cache, cache),
-            )
-
-    else:
-
-        def _decode(params, tokens, pos, active, cache):
-            """One batched length-1 step over the WHOLE slot pool.
-
-            tokens/pos/active: (B,) runtime arrays. Inactive rows run the
-            same math on garbage inputs (static shapes are the point); the
-            masked merge below discards their cache writes so a mid-prefill
-            or free slot is never corrupted by the fused step.
-            """
-            logits, new_cache = jax.vmap(
-                _one_row, in_axes=(None, 0, 0, row_axes),
-                out_axes=(0, row_axes),
-            )(params, tokens, pos, cache)
-            return logits, merge_cache_update(active, new_cache, cache)
+        tokens/pos/active: (B,) runtime arrays. Inactive rows run the
+        same math on garbage inputs (static shapes are the point); the
+        write takes ``active`` as its mask, so a mid-prefill or free
+        slot's ring is left as it is, and the step's cache traffic is
+        the rows it writes, in place in the donated pool.
+        """
+        step = (forward_decode_pool if cfg.decode_attention_impl == "pallas"
+                else forward_decode_rows)
+        logits, new_cache = step(params, tokens, pos, cache, cfg,
+                                 rope_len=rope_len, active=active)
+        return logits.astype(jnp.float32), new_cache
 
     def _prefill(params, cache, slot, tokens, pos):
         """One prompt chunk for one slot, in place in the pool.
@@ -537,11 +494,14 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
             cols.append(jax.lax.bitcast_convert_type(qv, jnp.int32))
         return jnp.concatenate(cols, axis=1)
 
-    # Donate the cache pool so XLA updates it in place instead of
-    # allocating + copying a second full pool per chunk/step (the engine
-    # always rebinds self.cache to the result, so the old buffers are
-    # dead). Every backend donates, the CPU included, so a read of a
-    # donated pool fails in the tests and not first on the chip.
+    # Donate the cache pool: the engine always rebinds self.cache to the
+    # result, so the old buffers are dead. Donation only ALLOWS an update
+    # in place; a program gets one when nothing reads the old pool after
+    # the write and the write takes the pool in the layout the chip holds
+    # it in (ops/kv_write.py; tests/test_tpu_compile.py pins that the
+    # decode program aliases every leaf and allocates no second pool).
+    # Every backend donates, the CPU included, so a read of a donated
+    # pool fails in the tests and not first on the chip.
     if page_size > 0:
         return (
             jax.jit(_prefill_paged, donate_argnums=(1,)),
@@ -1641,7 +1601,8 @@ class ServingEngine:
                 if self._pages is not None:
                     # page tables + per-row write pages ride the one
                     # jitted step as runtime int32 arrays; inactive
-                    # rows write the trash page (masked-merge analog)
+                    # rows write the trash page (the contiguous path
+                    # gives them no write target)
                     M = self.cfg.block_size
                     ps = self.serving.kv_page_size
                     tables = self._pages.tables()

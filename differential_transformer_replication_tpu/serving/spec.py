@@ -206,9 +206,9 @@ def _drafter_step_fns(cfg, rope_len: int, k: int):
     fusing the whole-pool forwards, the greedy argmaxes AND the
     finite-logits reduction: a poisoned drafter pool surfaces as a
     typed flag through exactly the guard the engine's sampler uses.
-    Per-slot round caps ride as a runtime array — slots drop out of
-    the masked merge as their caps fill, so varying caps recompile
-    nothing."""
+    Per-slot round caps ride as a runtime array — a slot whose cap is
+    full is no longer ``active`` and its rounds write nothing, so
+    varying caps recompile nothing."""
     import jax
     import jax.numpy as jnp
 
@@ -216,7 +216,6 @@ def _drafter_step_fns(cfg, rope_len: int, k: int):
         KV_CACHE_BATCH_AXIS,
         forward_chunk,
         forward_decode_pool,
-        merge_cache_update,
     )
 
     def _prefill(params, cache, slot, tokens, pos):
@@ -249,16 +248,15 @@ def _drafter_step_fns(cfg, rope_len: int, k: int):
         def body(r, carry):
             cache, cur_tok, cur_pos, out, ok = carry
             active = r < caps
-            logits, new_cache = forward_decode_pool(
+            logits, cache = forward_decode_pool(
                 params, cur_tok, cur_pos, cache, cfg,
-                rope_len=rope_len,
+                rope_len=rope_len, active=active,
             )
             lf = logits.astype(jnp.float32)
             nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
             ok = ok & jnp.where(
                 active, jnp.isfinite(lf).all(axis=-1), True
             )
-            cache = merge_cache_update(active, new_cache, cache)
             out = out.at[:, r].set(jnp.where(active, nxt, 0))
             cur_tok = jnp.where(active, nxt, cur_tok)
             cur_pos = cur_pos + active.astype(jnp.int32)

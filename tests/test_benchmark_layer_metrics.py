@@ -77,7 +77,11 @@ def read(metric: str, run):
 # (family of the table, the bucket of obs/xprof.py and benchmark/lib/xplane.py)
 BUCKET_OF_FAMILY = {"flash_attention": "flash_attention",
                     "fused_ffn": "fused_ffn", "fused_norm": "fused_ffn",
-                    "decode_attention": "decode_attention"}
+                    "decode_attention": "decode_attention",
+                    "kv_write": "kv_write"}
+# the benchmark's needles are frozen (PR 23): a kernel named after them
+# is one more Pallas kernel to that reader, by the call's target
+FROZEN_READER = {"kv_write": "pallas"}
 NAMES = [(fam, name) for fam, names in kernel_names.FAMILIES.items()
          for name in names]
 
@@ -93,7 +97,7 @@ def test_name_falls_in_its_bucket_under_both_readers(family, name):
     want = BUCKET_OF_FAMILY[family]
     for text in (_instruction(name), _instruction(f"vmap_{name}_", 1)):
         assert xprof.bucket_for(text) == want
-        assert xplane.bucket_for(text) == want
+        assert xplane.bucket_for(text) == FROZEN_READER.get(family, want)
     # an op that READS the kernel's result is not the kernel
     reader = f"%fusion.9 = f32[8]{{0}} fusion(bf16[8]{{0}} %{name}.3)"
     assert xprof.bucket_for(reader) is None
@@ -103,7 +107,7 @@ def test_name_falls_in_its_bucket_under_both_readers(family, name):
 
 
 def test_table_is_whole_and_the_metrics_needles_are_disjoint():
-    assert len(kernel_names.ALL) == len(set(kernel_names.ALL)) == 17
+    assert len(kernel_names.ALL) == len(set(kernel_names.ALL)) == 18
     assert sorted(kernel_names.ALL) == sorted(n for _, n in NAMES)
     sets = {
         "attn": ["flash"],  # every needle-reader of the flash family

@@ -112,13 +112,17 @@ def _rand_case(S, B, H, M, d, dv, kv_dtype, seed=0):
 
 @pytest.mark.parametrize("kind", FAMILIES)
 @pytest.mark.parametrize("kv", ["float", "int8"])
-def test_kernel_matches_xla_reference(kind, kv):
+@pytest.mark.parametrize("M", [32, 128], ids=["ring-on-rows", "ring-on-lanes"])
+def test_kernel_matches_xla_reference(kind, kv, M):
     """The fused kernel and the materialized-softmax twin agree to fp32
     tile-accumulation noise on identical inputs — per family (S=1/2/N
-    combine), staggered per-row positions, both KV dtypes."""
+    combine), staggered per-row positions, both KV dtypes, and both views
+    of the pool: a ring of 128 over heads of 16 or 32 is one the chip
+    holds with the ring on the lanes (ops/kv_write.py), which the kernel
+    reads as (d, M) tiles."""
     S = {"control": 1, "diff": 2, "ndiff": 4}[kind]
     qs, k, v, pos, coeffs, scales = _rand_case(
-        S, B=5, H=2, M=32, d=16, dv=16 if kind == "control" else 32,
+        S, B=5, H=2, M=M, d=16, dv=16 if kind == "control" else 32,
         kv_dtype=kv,
     )
     if scales is None:

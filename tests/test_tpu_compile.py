@@ -46,7 +46,7 @@ dattn = importlib.import_module(
 
 KERNEL_MODULES = (
     "ops.flash", "ops.fused_ffn", "ops.fused_norm_residual",
-    "ops.decode_attention",
+    "ops.decode_attention", "ops.kv_write",
 )
 
 # the recipe's widths (8L/768d, T=512, vocab 12000); the batch is cut, a
@@ -376,15 +376,29 @@ def test_decode_program_keeps_its_name_and_names_its_kernels(topo, impl):
     vmap, so the fused norm/FFN kernels are ``vmap_<name>_``) and on the
     fused decode-attention path; its module stays ``jit__decode`` (the
     benchmark finds the program by that)."""
+    text = _compile_decode(topo, impl, slots=8)[0].as_text()
+    assert text.startswith("HloModule jit__decode")
+    names = assert_kernels_named(text, "_decode")
+    assert any(kernel_names.FUSED_FFN_FWD in n for n in names)
+    assert any(kernel_names.FUSED_ADD_NORM_FWD in n for n in names)
+    assert (kernel_names.DECODE_ATTENTION in names) == (impl == "pallas")
+    assert kernel_names.KV_ROW_WRITE in names
+    assert {"attn_norm", "attn", "kv_write", "ffn_norm", "ffn", "lm_head",
+            "kv_merge"} <= scopes_in(text)
+
+
+def _compile_decode(topo, impl, slots, kv="auto"):
+    """The engine's decode program of the diff recipe's widths, two
+    layers deep, compiled for the described chip: (compiled, the
+    abstract cache it was lowered with)."""
     from differential_transformer_replication_tpu.models import init_model
     from differential_transformer_replication_tpu.models.decode import init_cache
     from differential_transformer_replication_tpu.serving.engine import (
         _build_step_fns,
     )
 
-    slots = 8
     cfg = ModelConfig(model="diff", n_layer=2, ffn_impl="pallas",
-                      decode_attention_impl=impl)
+                      decode_attention_impl=impl, kv_cache_dtype=kv)
     one_chip = SingleDeviceSharding(topo.devices[0])
     place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
@@ -394,15 +408,79 @@ def test_decode_program_keeps_its_name_and_names_its_kernels(topo, impl):
     cache = place(jax.eval_shape(lambda: init_cache(cfg, slots)))
     ints = place(sds((slots,), jnp.int32))
     decode = _build_step_fns(cfg, cfg.block_size)[1]
-    text = decode.lower(params, ints, ints, place(sds((slots,), jnp.bool_)),
-                        cache).compile().as_text()
-    assert text.startswith("HloModule jit__decode")
-    names = assert_kernels_named(text, "_decode")
-    assert any(kernel_names.FUSED_FFN_FWD in n for n in names)
-    assert any(kernel_names.FUSED_ADD_NORM_FWD in n for n in names)
-    assert (kernel_names.DECODE_ATTENTION in names) == (impl == "pallas")
-    assert {"attn_norm", "attn", "kv_write", "ffn_norm", "ffn", "lm_head",
-            "kv_merge"} <= scopes_in(text)
+    return decode.lower(params, ints, ints, place(sds((slots,), jnp.bool_)),
+                        cache).compile(), cache
+
+
+_HLO_DTYPES = {"bfloat16": "bf16", "int8": "s8", "float32": "f32"}
+
+
+@pytest.mark.parametrize("impl,kv", [("xla", "auto"), ("pallas", "auto"),
+                                     ("xla", "int8"), ("pallas", "int8")])
+def test_decode_program_updates_the_pool_in_place(topo, impl, kv):
+    """What the chip's compiler makes of the decode step at the serve
+    cell's pool (256 slots; two layers): every cache leaf is aliased
+    input to output; nothing but the write kernel produces a buffer the
+    size of a K or V leaf (until PR 25: a ``copy`` of every ring into the
+    layout XLA's scatter wants, a second copy back, and a select between
+    the new pool and the old one), and the program's temporaries are
+    under a quarter of one layer's pool (they held a whole second pool).
+    The pool's layout on the chip follows from its shape (the ring on
+    the lanes for the recipe's 512 x 96 and 512 x 192), so this is what
+    keeps a later change — to the write, to what reads the pool after
+    it, or to the shapes — from quietly bringing the copies back."""
+    compiled, cache = _compile_decode(topo, impl, slots=256, kv=kv)
+    text = compiled.as_text()
+    leaves = jax.tree_util.tree_leaves(cache)
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    head = text.split("\n", 1)[0]
+    aliased = re.findall(r"\{(\d+)\}: \((\d+), \{\}", head.split(
+        "input_output_alias={", 1)[1].split("entry_computation_layout", 1)[0])
+    # output 0 is the logits; outputs 1.. are the cache leaves, each fed
+    # by a parameter of its own
+    assert sorted(int(o) for o, _ in aliased) == list(
+        range(1, len(leaves) + 1))
+    assert len({p for _, p in aliased}) == len(leaves)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_bytes
+    layer_bytes = pool_bytes // 2
+    assert mem.temp_size_in_bytes < layer_bytes // 4, mem.temp_size_in_bytes
+
+    # K and V (the scale planes are 1/96 of them and ride along)
+    big = {(_HLO_DTYPES[layer[key].dtype.name], layer[key].size)
+           for layer in cache for key in ("k", "v")}
+    # an instruction INSIDE a fusion is no buffer; the fusion's own
+    # result, in the computation that calls it, is
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    harmless = {"parameter", "bitcast", "get-tuple-element", "tuple"}
+    if kv == "int8":
+        # a 100 MB int8 leaf fits the chip's 128 MiB of fast memory, and
+        # XLA's memory-space assignment prefetches it there for the XLA
+        # attention and writes it back, asynchronously: a move between
+        # memories, in the pool's own layout
+        harmless |= {"copy-start", "copy-done", "slice-start", "slice-done"}
+    offenders, inside = [], False
+    for line in text.split("\n"):
+        head_of = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head_of:
+            inside = head_of.group(1) in fused
+        m = re.match(
+            r"\s+(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]+)\]\S* ([\w\-]+)\(",
+            line)
+        if inside or not m:
+            continue
+        own, dtype, dims, opcode = m.groups()
+        size = 1
+        for n in dims.split(","):
+            size *= int(n)
+        if (dtype, size) not in big or opcode in harmless:
+            continue
+        if opcode == "custom-call" and (
+                own.startswith(kernel_names.KV_ROW_WRITE)
+                or (kv == "int8" and "ConcatBitcast" in line)):
+            continue
+        offenders.append(f"%{own} = {dtype}[{dims}] {opcode}")
+    assert not offenders, offenders
 
 
 def test_sampler_is_scoped(topo):
