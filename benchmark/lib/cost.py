@@ -19,18 +19,29 @@ def least_seconds(cost: dict, peaks: dict) -> tuple:
 
 
 def _attn_sizes(model: dict) -> tuple:
+    """``(streams, heads, q/k width, value width)`` of the two families
+    counted here, `control` and `diff`. Another family's attention has
+    other shapes: its roofline metrics are files of their own with their
+    own need functions (as ``layer_metrics/flash_attention_roofline.py``
+    has), and a share printed from the wrong shape would be worse than
+    none."""
     E, H = model["n_embd"], model["n_head"]
     if model["model"] == "control":
         H *= model.get("control_head_multiplier", 1)
         return 1, H, E // H, E // H
-    d = E // (2 * H)
-    return 2, H, d, 2 * d  # streams, heads, q/k width, value width
+    if model["model"] == "diff":
+        d = E // (2 * H)
+        return 2, H, d, 2 * d
+    raise ValueError(
+        f"benchmark/lib/cost.py counts the `control` and `diff` families, "
+        f"not {model['model']!r}")
 
 
 def non_embedding_params(model: dict) -> int:
-    """Parameters in the 6*N*D numerator: everything but the token table
-    and, for `diff`, the learned position table (the definition of the
-    program's ``obs/xprof.py:embedding_param_count``, copied)."""
+    """Parameters in the 6*N*D numerator of a `control` or a `diff` model:
+    everything but the token table and, for `diff`, the learned position
+    table (the definition of the program's
+    ``obs/xprof.py:embedding_param_count``, copied)."""
     E, V, L = model["n_embd"], model["vocab_size"], model["n_layer"]
     S, H, d, dv = _attn_sizes(model)
     attn = 2 * S * E * H * d + E * H * dv + (H * dv * E + E)
@@ -42,21 +53,22 @@ def non_embedding_params(model: dict) -> int:
 
 
 def train_step_6nd(model: dict, v: dict) -> dict:
-    """One optimizer step on one chip (``v["rows_per_chip"]`` sequences of
-    ``v["seq_len"]`` tokens) by the 6*N*D rule: forward 2, backward 4, per
-    parameter and token; attention's T*T products and recomputation are
-    not counted."""
+    """One optimizer step of a `control` or a `diff` model on one chip
+    (``v["rows_per_chip"]`` sequences of ``v["seq_len"]`` tokens) by the
+    6*N*D rule: forward 2, backward 4, per parameter and token;
+    attention's T*T products and recomputation are not counted."""
     tokens = v["rows_per_chip"] * v["seq_len"]
     return {"flops": 6.0 * non_embedding_params(model) * tokens, "bytes": 0.0}
 
 
 def decode_step(model: dict, v: dict) -> dict:
-    """One decode step that advances ``v["decode_rows"]`` sequences by a
-    token (means over the traced steps): the non-embedding weights read
-    once in bf16 and the K/V of the ``v["decode_live_positions"]`` cached
-    positions of those sequences read once (all layers); 2 operations a weight and row plus attention over the live
-    positions. Not what a path reads that streams whole rings or float32
-    weights."""
+    """One decode step of a `control` or a `diff` model that advances
+    ``v["decode_rows"]`` sequences by a token (means over the traced
+    steps): the non-embedding weights read once in bf16 and the K/V of the
+    ``v["decode_live_positions"]`` cached positions of those sequences
+    read once (all layers); 2 operations a weight and row plus attention
+    over the live positions. Not what a path reads that streams whole
+    rings or float32 weights."""
     S, H, d, dv = _attn_sizes(model)
     L = model["n_layer"]
     n = non_embedding_params(model)

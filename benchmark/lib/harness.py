@@ -21,6 +21,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
+from . import program
+
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 OUT_DIR = os.path.join(BENCH_DIR, "out")
@@ -95,12 +97,30 @@ def find_cell(bench: dict, name: str) -> Cell:
     cfg_entry = next(c for c in bench["configs"] if c["name"] == entry["config"])
     with open(os.path.join(ROOT, cfg_entry["file"])) as f:
         config = json.load(f)
+    config["file"] = cfg_entry["file"]  # for the messages that name it
+    program.check_config(config)
     traffic = load_json("traffic", entry["traffic"] + ".json")
     e2e = [m["name"] for m in bench["end_to_end"]
            if name in m.get("workloads", [name])]
     layer = [m for m in bench["per_layer"]
              if name in m.get("workloads", [name]) and m["moves"] in e2e]
     return Cell(name, entry["chips"], config, traffic, e2e, layer)
+
+
+def need(config: dict, block: str, who: str):
+    """``config[a][b]`` for the block ``"a.b"``. A configuration states
+    the paths it has: one that can only be served has no ``train`` and no
+    ``correct.train``; asked for a path it lacks, the run ends with a
+    sentence that names the block."""
+    node = config
+    for key in block.split("."):
+        if not isinstance(node, dict) or key not in node:
+            raise SystemExit(
+                f"benchmark: {program.config_file(config)} has no "
+                f"`{block}` block, which {who} needs: the configuration "
+                "does not state that path")
+        node = node[key]
+    return node
 
 
 @dataclass

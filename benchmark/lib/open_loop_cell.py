@@ -225,6 +225,7 @@ def run(cell: harness.Cell, env: harness.Env, args, t_start: float,
         break_engine=None) -> str:
     reference = harness.load_reference(cell.config)
     traffic, config = cell.traffic, cell.config
+    limits = harness.need(config, "correct.serve", "the open_loop driver")
     model = config["model"]
     vocab, width = model["vocab_size"], model["block_size"]
     sampling = traffic["sampling"]
@@ -300,7 +301,7 @@ def run(cell: harness.Cell, env: harness.Env, args, t_start: float,
         say(f"reference: {int(mask.sum())} served tokens of {len(sample)} "
             f"requests in {time.perf_counter() - t_ref:.1f} s")
         rows_cmp.append(("served_token_gap", float(gaps[mask].max()),
-                         config["correct"]["serve"]["token_gap"]))
+                         limits["token_gap"]))
         rows_cmp += served_rows(sample, vocab)
     else:
         rows_cmp.append(("requests_finished_in_window", 0.0, -1.0))

@@ -7,18 +7,13 @@ by the benchmark. Nothing here measures or judges.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
-
-_MODEL_KEYS = ("model", "vocab_size", "n_embd", "n_head", "n_layer",
-               "block_size", "dropout", "compute_dtype", "param_dtype",
-               "attention_impl", "ffn_impl")
-_TRAIN_KEYS = ("learning_rate", "min_lr", "weight_decay", "beta1", "beta2",
-               "warmup_iters", "max_iters", "grad_clip", "grad_acc_steps")
 
 
 def setup_compile_cache() -> None:
@@ -36,39 +31,87 @@ def setup_compile_cache() -> None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
-def model_config(config: dict, **overrides):
-    from differential_transformer_replication_tpu.config import ModelConfig
+def config_file(config: dict) -> str:
+    """The configuration's file, for a message (``harness.find_cell`` puts
+    BENCHMARK.json's ``file`` into the dict it loads)."""
+    return config.get("file") or f"benchmark/configs/{config.get('name')}.json"
 
-    fields = {k: config["model"][k] for k in _MODEL_KEYS if k in config["model"]}
-    fields.update(overrides)
-    return ModelConfig(**fields)
+
+def _declared(cls, block: dict, config: dict, name: str):
+    """``cls(**block)``, the configuration's ``name`` block whole. A key
+    that is no field the program declares ends the run here, naming the
+    key and the file: dropped, the reference (which reads the raw block)
+    and the program would run different models without a word."""
+    fields = {f.name for f in dataclasses.fields(cls)}
+    unknown = sorted(set(block) - fields)
+    if unknown:
+        raise SystemExit(
+            f"benchmark: {config_file(config)}: `{name}` key "
+            f"{', '.join(map(repr, unknown))} is no field of the program's "
+            f"{cls.__name__} (config.py)")
+    try:
+        return cls(**block)
+    except ValueError as e:  # the dataclass's own check of a value
+        raise SystemExit(f"benchmark: {config_file(config)}: `{name}`: {e}")
+
+
+def _trainer(config: dict, **fields):
+    """The program's ``TrainConfig`` around the configuration's ``model``
+    block. One key of that block is no model field but the trainer-level
+    switch ``TrainConfig.resolved_model`` applies
+    (``control_head_multiplier``; 1 where the file does not state it), and
+    the trainer's vocabulary is the model block's."""
+    from differential_transformer_replication_tpu.config import (
+        ModelConfig,
+        TrainConfig,
+    )
+
+    block = dict(config["model"])
+    multiplier = block.pop("control_head_multiplier", 1)
+    model = _declared(ModelConfig, block, config, "model")
+    return _declared(TrainConfig, dict(
+        fields, model=model, vocab_size=model.vocab_size,
+        control_head_multiplier=multiplier), config, "train")
+
+
+def model_config(config: dict):
+    """Every key of the ``model`` block, as the program's ``ModelConfig``
+    (before the trainer-level switches: see :func:`served_model`)."""
+    return _trainer(config).model
 
 
 def train_config(config: dict, rows: int, chips: int):
     """``rows`` is the global batch of one step (the trainer's
-    ``micro_batch_size`` before the data-parallel split)."""
-    from differential_transformer_replication_tpu.config import (
-        MeshConfig,
-        TrainConfig,
-    )
+    ``micro_batch_size`` before the data-parallel split). Every key of the
+    ``train`` block reaches ``TrainConfig``; what the cell itself sets
+    (the batch, the mesh, the sampler) a configuration may not state."""
+    from differential_transformer_replication_tpu.config import MeshConfig
 
-    train = config["train"]
-    return TrainConfig(
-        model=model_config(config),
-        mesh=MeshConfig(data=chips),
-        vocab_size=config["model"]["vocab_size"],
-        control_head_multiplier=config["model"].get(
-            "control_head_multiplier", 1),
-        micro_batch_size=rows,
-        sampler="replacement",
-        **{k: train[k] for k in _TRAIN_KEYS if k in train},
-    )
+    fixed = dict(mesh=MeshConfig(data=chips), micro_batch_size=rows,
+                 sampler="replacement")
+    clash = sorted(set(config["train"]) & (set(fixed) | {
+        "model", "vocab_size", "control_head_multiplier"}))
+    if clash:
+        raise SystemExit(
+            f"benchmark: {config_file(config)}: `train` key "
+            f"{', '.join(map(repr, clash))} is set by the cell or by the "
+            "`model` block, not by the `train` block")
+    return _trainer(config, **fixed, **config["train"])
 
 
 def served_model(config: dict):
     """The model configuration a server gets from a checkpoint of this
-    recipe (the trainer's head doubling for `control` applied)."""
-    return train_config(config, 1, 1).resolved_model()
+    recipe (the trainer's head doubling for `control` applied). It needs
+    no ``train`` block: a configuration that can only be served has none."""
+    return _trainer(config).resolved_model()
+
+
+def check_config(config: dict) -> None:
+    """Both blocks against the program's declarations, before anything is
+    built: every entry point passes here (``harness.find_cell``)."""
+    served_model(config)
+    if "train" in config:
+        train_config(config, 1, 1)
 
 
 def check_layout(params, config: dict) -> None:

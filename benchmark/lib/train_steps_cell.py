@@ -46,6 +46,7 @@ class Trainer:
         self.model = model = config["model"]
         self.rows = traffic["rows_per_chip"] * cell.chips
         self.seq = model["block_size"]
+        harness.need(config, "train", "the train_steps driver")
         self.tcfg = program.train_config(config, self.rows, cell.chips)
         mesh = program.make_mesh(self.tcfg) if cell.chips > 1 else None
         self.repl = None
@@ -85,7 +86,7 @@ class Trainer:
         optimizer got it (Adam's first moment after one step is
         (1 - beta1) times it), the leaf norms of the parameters' change
         after the last. Also returns the rows the steps saw."""
-        beta1 = self.cell.config["train"]["beta1"]
+        beta1 = self.tcfg.beta1
         norms = jax.jit(lambda t: jax.tree_util.tree_map(
             lambda a: jnp.sqrt(jnp.sum(jnp.square(a))), t))
         sub_norms = jax.jit(lambda a, b: jax.tree_util.tree_map(
@@ -132,6 +133,7 @@ def reference_readings(cell: harness.Cell, seed: int, rows: np.ndarray,
 def run(cell: harness.Cell, env: harness.Env, args, t_start: float,
         break_step=None) -> str:
     traffic, config = cell.traffic, cell.config
+    limits = harness.need(config, "correct.train", "the train_steps driver")
     chips = cell.chips
     per_window = traffic["steps_per_window"]
     spans = SpanRecorder()
@@ -205,7 +207,7 @@ def run(cell: harness.Cell, env: harness.Env, args, t_start: float,
     ref = reference_readings(cell, args.seed, seen["rows"])
     say(f"reference: {len(ref['losses'])} float32 steps in "
         f"{time.perf_counter() - t_ref:.1f} s")
-    rows_cmp = check.train_rows(seen, ref, config["correct"]["train"])
+    rows_cmp = check.train_rows(seen, ref, limits)
     rows_cmp.append(("nonfinite_losses", float(failed), 0.0))
     rows_cmp.append(("compilations_in_window", float(compiled_in_window), 0.0))
     correct = check.judge(rows_cmp, cell.name)

@@ -31,12 +31,14 @@ Interval = Tuple[float, float]  # seconds on the trace's clock
 #
 # Buckets by instruction name, first match wins (the decode and fused-FFN
 # kernels end in the flash needle ``_fwd_kernel`` and must be tried before
-# it). A Pallas kernel carries its function's name only where the program
-# gives ``pallas_call`` one that reaches the HLO; on today's installation
-# the instructions are ``%jvp__.N`` / ``%transpose_jvp___.N`` with
-# ``custom_call_target="tpu_custom_call"`` and no kernel name (my chip run,
-# PR 23), so they all fall into ``pallas`` (forward and backward apart in
-# the breakdown) until the program names them.
+# it). A Pallas kernel carries a name only where the program gives
+# ``pallas_call`` one that reaches the HLO. Since PR 24 the program names
+# every kernel (``kernel_names.py``: ``%flash_bwd_tm_packed.7``, under vmap
+# ``%vmap_fused_ffn_fwd_.1``), and the needles below catch those names; a
+# kernel left without one is ``%jvp__.N`` / ``%transpose_jvp___.N`` with
+# ``custom_call_target="tpu_custom_call"`` (every kernel until PR 24; my
+# chip run, PR 23) and falls into ``pallas``, as does a named kernel no
+# needle matches (``kv_row_write``, PR 25).
 KERNEL_BUCKETS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("decode_attention", ("_dattn_",)),
     ("fused_ffn", ("_ffn_fwd", "_ffn_bwd", "_addnorm_", "fused_ffn",

@@ -9,7 +9,10 @@ count, and it is the only place where the harness runs without a chip.
 ``--rehearse`` skips the harness's look for a chip and drives the rest of a
 run (one train cell, one serve cell, traced and untraced) at a tiny size
 under ``JAX_PLATFORMS=cpu``; its result lines carry ``"rehearsal": true``
-and the CPU's name as the device, and no number of them is a device's.
+and the CPU's name as the device, and no number of them is a device's. It
+also serves a configuration that states no ``train`` block and a
+``ModelConfig`` field the shipped files do not (the key has to arrive in
+the engine), and requires one with a misspelt key to exit naming it.
 ``--control`` puts the reference at float8 in the program's place and
 requires `correct`'s numbers to fail; ``--broken`` breaks the timed path
 underneath (a step that returns its state unchanged; a decode step whose
@@ -213,8 +216,8 @@ def rehearsal_env(chips: int) -> harness.Env:
     return harness.Env(jax.devices()[:chips], None, rehearsal=True)
 
 
-def drive(name: str, seed: int, seconds: float, trace: int, **hooks) -> dict:
-    cell = tiny_cell(name)
+def drive(cell: harness.Cell, seed: int, seconds: float, trace: int,
+          **hooks) -> dict:
     args = types.SimpleNamespace(seed=seed, seconds=seconds, trace=trace)
     line = harness.driver(cell.traffic["kind"]).run(
         cell, rehearsal_env(cell.chips), args, harness.process_start(),
@@ -240,10 +243,56 @@ def cells_by_kind() -> dict:
 def rehearse() -> None:
     for kind, name in cells_by_kind().items():
         for trace in (0, 1):
-            out = drive(name, 2**31 + 17 + trace, 2.0, trace)
+            out = drive(tiny_cell(name), 2**31 + 17 + trace, 2.0, trace)
             assert out["correct"] is True, f"{name} trace={trace}: not correct"
             assert out["failed"] == 0 and out["attempted"] > 0
             assert out["metrics"], f"{name} trace={trace}: no metric reported"
+    if "open_loop" in cells_by_kind():
+        rehearse_serve_only()
+
+
+def serve_only_cell(**model_keys) -> harness.Cell:
+    """A configuration that can only be served, made as ``tiny_cell``
+    makes its cells: the shipped file in memory, ``TINY_MODEL`` over it,
+    the ``train`` block and its limits taken out, ``model_keys`` stated."""
+    cell = tiny_cell(cells_by_kind()["open_loop"])
+    del cell.config["train"]
+    cell.config["correct"].pop("train", None)
+    cell.config["model"].update(model_keys)
+    return cell
+
+
+def refused(cell: harness.Cell, word: str) -> None:
+    """The run of ``cell`` has to exit with a sentence that holds ``word``."""
+    try:
+        drive(cell, 7, 1.0, 0)
+    except SystemExit as e:
+        assert word in str(e), f"the exit does not name {word}: {e}"
+        print(f"refused, as it has to be: {e}")
+    else:
+        raise AssertionError(f"a configuration that had to exit on {word} ran")
+
+
+def rehearse_serve_only() -> None:
+    """The ``model`` block reaches the program whole, and a configuration
+    states the paths it has."""
+    import jax.numpy as jnp
+
+    seen = {}
+
+    def look(engine):
+        seen["k"] = engine.cache[0]["k"].dtype
+
+    # a ModelConfig field that no shipped file states: it has to arrive
+    out = drive(serve_only_cell(kv_cache_dtype="int8"), 2**31 + 23, 2.0, 0,
+                break_engine=look)
+    assert seen["k"] == jnp.int8, f"kv_cache_dtype was dropped: {seen['k']}"
+    assert out["correct"] is True and out["failed"] == 0 < out["attempted"]
+    refused(serve_only_cell(kv_cache_dtpye="int8"), "kv_cache_dtpye")
+    # the train driver, asked for a path the configuration does not state
+    cell = tiny_cell(cells_by_kind()["train_steps"])
+    del cell.config["train"]
+    refused(cell, "`train`")
 
 
 def broken() -> None:
@@ -261,7 +310,7 @@ def broken() -> None:
             return state, metrics
         return same_state
 
-    out = drive(kinds["train_steps"], 5, 1.0, 0, break_step=stuck)
+    out = drive(tiny_cell(kinds["train_steps"]), 5, 1.0, 0, break_step=stuck)
     assert out["correct"] is False, "a step that leaves its state unchanged passed"
 
     def shifted(engine):
@@ -274,7 +323,8 @@ def broken() -> None:
         engine._decode_fn = decode
 
     if "open_loop" in kinds:
-        out = drive(kinds["open_loop"], 6, 2.0, 0, break_engine=shifted)
+        out = drive(tiny_cell(kinds["open_loop"]), 6, 2.0, 0,
+                    break_engine=shifted)
         assert out["correct"] is False, "shifted decode logits passed"
 
 
@@ -289,33 +339,35 @@ def control() -> None:
 
     bench = harness.load_benchmark()
     for cfg_entry in bench["configs"]:
-        config = harness.load_json("configs", cfg_entry["name"] + ".json")
+        with open(os.path.join(harness.ROOT, cfg_entry["file"])) as f:
+            config = json.load(f)
         reference = harness.load_reference(config)
         model = dict(config["model"], block_size=128)
         params = reference.make_params(11, model)
         rng = np.random.default_rng(11)
         toks = jnp.asarray(rng.integers(0, model["vocab_size"], (3, 4, 129)))
         x, y = toks[..., :-1], toks[..., 1:]
-        readings = {}
-        for quant in (None, "fp8"):
-            got = jax.device_get(reference.make_train_steps(
-                model, config["train"], 4, quant)(params, x, y))
-            readings[quant] = {
-                "losses": [float(v) for v in got["losses"]],
-                "first_grad_norms": [float(v) for v in jax.tree_util.tree_leaves(got["first_grad_norms"])],
-                "delta_norms": [float(v) for v in jax.tree_util.tree_leaves(got["delta_norms"])]}
-        rows = check.train_rows(readings["fp8"], readings[None],
-                                config["correct"]["train"])
-        assert not check.judge(rows, cfg_entry["name"] + " float8 control, train"), \
-            "the float8 control passed the training limits"
-        if "serve" not in config["correct"]:
-            continue  # no serving cell of this configuration yet
-        gaps = np.asarray(reference.make_token_gaps(model, "fp8")(
-            params, x[0], y[0]))
-        rows = [("served_token_gap", float(gaps.max()),
-                 config["correct"]["serve"]["token_gap"])]
-        assert not check.judge(rows, cfg_entry["name"] + " float8 control, serve"), \
-            "the float8 control passed the serving limit"
+        limits = config["correct"]  # a configuration states the paths it has
+        if "train" in limits:
+            readings = {}
+            for quant in (None, "fp8"):
+                got = jax.device_get(reference.make_train_steps(
+                    model, config["train"], 4, quant)(params, x, y))
+                readings[quant] = {
+                    "losses": [float(v) for v in got["losses"]],
+                    "first_grad_norms": [float(v) for v in jax.tree_util.tree_leaves(got["first_grad_norms"])],
+                    "delta_norms": [float(v) for v in jax.tree_util.tree_leaves(got["delta_norms"])]}
+            rows = check.train_rows(readings["fp8"], readings[None],
+                                    limits["train"])
+            assert not check.judge(rows, cfg_entry["name"] + " float8 control, train"), \
+                "the float8 control passed the training limits"
+        if "serve" in limits:
+            gaps = np.asarray(reference.make_token_gaps(model, "fp8")(
+                params, x[0], y[0]))
+            rows = [("served_token_gap", float(gaps.max()),
+                     limits["serve"]["token_gap"])]
+            assert not check.judge(rows, cfg_entry["name"] + " float8 control, serve"), \
+                "the float8 control passed the serving limit"
 
 
 def main() -> int:
