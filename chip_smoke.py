@@ -454,7 +454,95 @@ def multichip_phase(work: Path, size: dict, require_device) -> dict:
             "one_losses": one_losses, "tpu_custom_call": kernels}
 
 
-CHILD_PHASES = {"train": train_phases, "multichip": multichip_phase}
+# bf16 logits of one toy model through two backends of its state-space
+# mixers: the kernels keep the recurrence in float32 as the XLA path does,
+# so what differs is the order of a few float32 sums
+JAMBA_LOGIT_TOL = 0.05
+
+
+def jamba_phase(work: Path, size: dict, require_device) -> dict:
+    """A toy model of the ``jamba`` family (Mamba mixers with a recurrent
+    state a slot beside a multi-query attention layer) through the engine,
+    once a ``ssm_impl``: prefill in ladder chunks, decoding through the
+    slot pool with slots reused, one decode program, and the ``pallas``
+    path's logits against the ``xla`` path's at the same tokens."""
+    del size
+    device = require_device("chip_smoke jamba")
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from differential_transformer_replication_tpu.config import (
+        ModelConfig,
+        ServingConfig,
+    )
+    from differential_transformer_replication_tpu.models import init_model
+    from differential_transformer_replication_tpu.models.decode import (
+        forward_chunk,
+        forward_decode_rows,
+        init_cache,
+    )
+    from differential_transformer_replication_tpu.serving.engine import (
+        ServingEngine,
+    )
+
+    cfg = ModelConfig(
+        model="jamba", vocab_size=2048, n_embd=256, n_head=4, kv_heads=1,
+        n_layer=4, block_size=256, ffn_hidden=512,
+        tie_embeddings=True, attn_layer_period=4, attn_layer_offset=1,
+        param_dtype="bfloat16")
+    params = init_model(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 2048, size=int(n)).tolist()
+               for n in rng.integers(8, 120, size=12)]
+    served = {}
+    for impl in ("xla", "pallas"):
+        eng = ServingEngine(params, cfg.replace(ssm_impl=impl), ServingConfig(
+            num_slots=4, prefill_chunk=32, prefill_budget=128))
+        outs = eng.generate(prompts, max_new_tokens=16, temperature=0.0)
+        check(all(len(o.tokens) == 16 and o.finish_reason == "length"
+                  for o in outs), f"jamba {impl}: a request came back short")
+        stats = eng.compile_stats()
+        check(stats["decode"] == 1 and stats["state_reset"] == 1,
+              f"jamba {impl}: compile stats {stats}")
+        check(eng.stats["state_resets"] == len(prompts),
+              f"jamba {impl}: {eng.stats['state_resets']} resets")
+        served[impl] = [list(o.tokens) for o in outs]
+        say("jamba", f"{impl}: {len(prompts)} requests over 4 slots, "
+                     f"compiles {json.dumps(stats)}")
+    same = sum(a == b for a, b in zip(served["xla"], served["pallas"]))
+    say("jamba", f"{same} of {len(prompts)} greedy replies identical "
+                 "between the two paths (a bf16 tie may differ)")
+    # the same tokens through both paths: prefill 64 + 32, then 8 steps
+    idx = jnp.asarray(rng.integers(0, 2048, size=(4, 104)))
+    logits = {}
+    for impl in ("xla", "pallas"):
+        c = cfg.replace(ssm_impl=impl)
+        cache, outs, pos = init_cache(c, 4), [], 0
+        for n in (64, 32):
+            lg, cache = jax.jit(
+                lambda t, cache, pos=pos, c=c: forward_chunk(
+                    params, t, pos, cache, c))(idx[:, pos:pos + n], cache)
+            outs.append(lg)
+            pos += n
+        step = jax.jit(lambda t, p, cache, c=c: forward_decode_rows(
+            params, t, p, cache, c))
+        for t in range(pos, idx.shape[1]):
+            lg, cache = step(idx[:, t], jnp.full((4,), t), cache)
+            outs.append(lg[:, None])
+        logits[impl] = np.asarray(jnp.concatenate(outs, 1), np.float32)
+    worst = float(np.abs(logits["xla"] - logits["pallas"]).max())
+    say("jamba", f"worst logit difference pallas vs xla {worst:.4f} "
+                 f"(tolerance {JAMBA_LOGIT_TOL}, logits' std "
+                 f"{logits['xla'].std():.2f})")
+    check(np.isfinite(worst) and worst <= JAMBA_LOGIT_TOL,
+          "the two ssm_impl paths disagree")
+    return {"device": device, "identical_replies": same,
+            "worst_logit_gap": worst}
+
+
+CHILD_PHASES = {"train": train_phases, "multichip": multichip_phase,
+                "jamba": jamba_phase}
 
 
 # ---------------------------------------------------------------------------
@@ -693,6 +781,7 @@ def main(argv=None, *, run_child=run_child, serve_phase=serve_phase,
             device = run_child("multichip", work)["device"]
         else:
             device = run_child("train", work)["device"]
+            run_child("jamba", work)
             serve_phase(work, size, device)
     except SmokeFailure as e:
         print(f"[smoke] FAILED: {e}", flush=True)

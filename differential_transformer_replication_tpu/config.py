@@ -18,15 +18,28 @@ import dataclasses
 from dataclasses import dataclass, field
 from typing import Any, Optional, Tuple
 
-MODEL_KINDS = ("control", "diff", "ndiff")
+MODEL_KINDS = ("control", "diff", "ndiff", "jamba")
+
+# Fields only the ``jamba`` family reads. Another family given one of them
+# at a value other than its default is refused by name: a field that is
+# silently ignored lets a configuration file describe a model the program
+# does not run.
+JAMBA_FIELDS = (
+    "ffn_hidden", "kv_heads", "norm_eps", "tie_embeddings",
+    "attn_layer_period", "attn_layer_offset", "mamba_d_state",
+    "mamba_d_conv", "mamba_expand", "mamba_dt_rank", "ssm_state_dtype",
+    "ssm_impl",
+)
 
 
 @dataclass(frozen=True)
 class ModelConfig:
-    """Hyperparameters shared by all three model families.
+    """Hyperparameters shared by the model families.
 
     Mirrors the constructor surface of the reference models
-    (control.py:114, diff_transformer.py:129, Ndiff_transformer.py:183).
+    (control.py:114, diff_transformer.py:129, Ndiff_transformer.py:183);
+    the ``jamba`` family (models/jamba.py: Mamba-1 mixers beside a few
+    grouped-query attention layers) adds the block of fields at the end.
     """
 
     model: str = "control"  # one of MODEL_KINDS (train.py:205-230 switch)
@@ -109,10 +122,47 @@ class ModelConfig:
     # flash is on). forward() then returns (None, loss) when targets are
     # given. None = dense loss (the reference's shape, control.py:153-159).
     loss_chunk: Optional[int] = None
+    # -- the ``jamba`` family's fields (JAMBA_FIELDS; models/jamba.py) ------
+    # Hidden width of the gated MLP; 0 = the reference families' 4 * n_embd.
+    ffn_hidden: int = 0
+    # K/V heads shared by n_head // kv_heads query heads each (1 =
+    # multi-query); 0 = n_head, one K/V head a query head.
+    kv_heads: int = 0
+    # eps of the family's RMSNorm (a scale only; the reference families
+    # keep LayerNorm at 1e-5); 0 = 1e-6. The family also fixes the
+    # position scheme: none of any kind (no RoPE, no table).
+    norm_eps: float = 0.0
+    # The head reuses the token table (logits = x E^T), no lm_head leaf.
+    tie_embeddings: bool = False
+    # Layer i (0-based) mixes tokens by attention iff
+    # i % attn_layer_period == attn_layer_offset, else by a Mamba block
+    # (transformers' JambaConfig.layers_block_type).
+    attn_layer_period: int = 8
+    attn_layer_offset: int = 4
+    # Mamba-1 sizes: d_inner = mamba_expand * n_embd channels, each with a
+    # state of mamba_d_state values and a causal convolution of
+    # mamba_d_conv taps; mamba_dt_rank 0 = ceil(n_embd / 16).
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    # Storage dtype of a sequence's recurrent state in the decode cache
+    # (models/decode.py:init_cache). The recurrence itself always runs in
+    # float32, whatever compute_dtype. Only float32 is taken: a narrower
+    # state rounds an accumulator that lives for thousands of steps, and
+    # none has been tested or measured.
+    ssm_state_dtype: str = "float32"
+    # Selective-scan backend, selected like attention_impl/ffn_impl: "xla"
+    # (a lax.scan over time; differentiable) or "pallas" (ops/ssm.py:
+    # ssm_scan_fwd keeps the state on the chip over a chunk, and
+    # ssm_state_update advances the active slots of the decode pool in
+    # place; forward only).
+    ssm_impl: str = "xla"
 
     def __post_init__(self):
         if self.model not in MODEL_KINDS:
             raise ValueError(f"model must be one of {MODEL_KINDS}, got {self.model!r}")
+        self._check_jamba_fields()
         if self.attention_impl not in ("xla", "pallas"):
             raise ValueError(
                 "attention_impl must be 'xla' or 'pallas', got "
@@ -152,6 +202,85 @@ class ModelConfig:
                 "train.py:79, would crash at Ndiff_transformer.py:119)"
             )
 
+    def _check_jamba_fields(self):
+        if self.model != "jamba":
+            for f in dataclasses.fields(self):
+                if f.name in JAMBA_FIELDS and getattr(self, f.name) != f.default:
+                    raise ValueError(
+                        f"{f.name} is a field of the jamba family; model "
+                        f"{self.model!r} does not read it"
+                    )
+            return
+        for name in ("attention_impl", "ffn_impl", "decode_attention_impl"):
+            if getattr(self, name) != "xla":
+                raise ValueError(
+                    f"the jamba family runs {name}='xla' only, got "
+                    f"{getattr(self, name)!r}: the Pallas attention and FFN "
+                    "kernels take as many K/V heads as query heads, "
+                    "LayerNorm and biased projections"
+                )
+        if self.ssm_impl not in ("xla", "pallas"):
+            raise ValueError(
+                f"ssm_impl must be 'xla' or 'pallas', got {self.ssm_impl!r}"
+            )
+        if self.ssm_state_dtype != "float32":
+            raise ValueError(
+                "ssm_state_dtype must be 'float32' (no narrower recurrent "
+                f"state is tested or measured), got {self.ssm_state_dtype!r}"
+            )
+        if self.dropout:
+            raise ValueError("the jamba family has no dropout")
+        if self.n_embd % self.n_head or self.n_head % self.n_kv_head:
+            raise ValueError(
+                f"n_embd ({self.n_embd}) must divide by n_head "
+                f"({self.n_head}) and n_head by kv_heads ({self.n_kv_head})"
+            )
+        if not 0 <= self.attn_layer_offset < self.attn_layer_period:
+            raise ValueError(
+                f"attn_layer_offset ({self.attn_layer_offset}) must lie in "
+                f"[0, attn_layer_period = {self.attn_layer_period})"
+            )
+        for name in ("mamba_d_state", "mamba_d_conv", "mamba_expand"):
+            if getattr(self, name) < 1:
+                raise ValueError(f"{name} must be >= 1, got {getattr(self, name)}")
+        if self.mamba_d_conv < 2:
+            raise ValueError("mamba_d_conv must be >= 2 (a carried window)")
+
+    @property
+    def ffn_width(self) -> int:
+        """Hidden width of the block's gated MLP."""
+        return self.ffn_hidden or 4 * self.n_embd
+
+    @property
+    def n_kv_head(self) -> int:
+        return self.kv_heads or self.n_head
+
+    @property
+    def resolved_norm_eps(self) -> float:
+        """eps of the jamba family's RMSNorm."""
+        return self.norm_eps or 1e-6
+
+    @property
+    def d_inner(self) -> int:
+        """Channels of a Mamba mixer."""
+        return self.mamba_expand * self.n_embd
+
+    @property
+    def dt_rank(self) -> int:
+        return self.mamba_dt_rank or -(-self.n_embd // 16)
+
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """``"attention"`` or ``"mamba"`` for every layer, 0-based. The
+        reference families attend in every layer."""
+        if self.model != "jamba":
+            return ("attention",) * self.n_layer
+        return tuple(
+            "attention"
+            if i % self.attn_layer_period == self.attn_layer_offset
+            else "mamba"
+            for i in range(self.n_layer)
+        )
+
     @property
     def head_size(self) -> int:
         """Per-head query/key width.
@@ -160,7 +289,7 @@ class ModelConfig:
         it because each head carries a doubled value
         (diff_transformer.py:111, Ndiff_transformer.py:164).
         """
-        if self.model == "control":
+        if self.model in ("control", "jamba"):
             return self.n_embd // self.n_head
         return self.n_embd // (self.n_head * 2)
 
@@ -168,7 +297,7 @@ class ModelConfig:
     def value_size(self) -> int:
         """Per-head value width: doubled for differential variants
         (diff_transformer.py:30, Ndiff_transformer.py:59)."""
-        if self.model == "control":
+        if self.model in ("control", "jamba"):
             return self.head_size
         return self.head_size * 2
 
@@ -516,7 +645,10 @@ class ServingConfig:
 
     def resolved_max_seq_len(self, model: "ModelConfig") -> int:
         """Hard cap on prompt + generated length for this model family."""
-        if model.model == "diff":
+        if model.model in ("diff", "jamba"):
+            # a learned table cannot roll; jamba's attention layers carry
+            # no position at all, so a rolled ring would turn them into
+            # sliding-window layers without a word
             return model.block_size
         return max(self.max_seq_len, model.block_size)
 

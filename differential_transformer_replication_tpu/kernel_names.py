@@ -18,6 +18,7 @@ substrings, so the names keep to these rules:
 - the decode kernel holds ``_dattn_``;
 - the cache-write kernel holds none of these (the benchmark's reader
   books it under ``pallas``, ``obs/xprof.py`` under ``kv_write``);
+- the state-space kernels start ``ssm_`` (``pallas`` there, ``ssm`` here);
 - no name holds a needle of another family.
 
 Standard library only, at the top of the package: ``ops/`` (which
@@ -52,6 +53,11 @@ DECODE_ATTENTION = "decode_dattn_fwd"
 # ops/kv_write.py (one call a cache leaf: K, V and the int8 scale planes)
 KV_ROW_WRITE = "kv_row_write"
 
+# ops/ssm.py (the jamba family's Mamba mixers: the chunk's scan, and the
+# decode step's one-token update of the active slots, in place in the pool)
+SSM_SCAN_FWD = "ssm_scan_fwd"
+SSM_STATE_UPDATE = "ssm_state_update"
+
 FLASH = (
     FLASH_FWD, FLASH_FWD_TILED, FLASH_FWD_CHUNK, FLASH_FWD_TM,
     FLASH_FWD_TM_PACKED, FLASH_BWD_DQ, FLASH_BWD_DKV, FLASH_BWD_DQ_TILED,
@@ -61,6 +67,7 @@ FUSED_FFN = (FUSED_FFN_FWD, FUSED_FFN_BWD)
 FUSED_NORM = (FUSED_ADD_NORM_FWD, FUSED_ADD_NORM_BWD)
 DECODE = (DECODE_ATTENTION,)
 KV_WRITE = (KV_ROW_WRITE,)
+SSM = (SSM_SCAN_FWD, SSM_STATE_UPDATE)
 
 #: family -> its kernels' names; every ``pallas_call`` under ``ops/``
 #: passes one of these as ``name=``
@@ -70,5 +77,6 @@ FAMILIES = {
     "fused_norm": FUSED_NORM,
     "decode_attention": DECODE,
     "kv_write": KV_WRITE,
+    "ssm": SSM,
 }
-ALL = FLASH + FUSED_FFN + FUSED_NORM + DECODE + KV_WRITE
+ALL = FLASH + FUSED_FFN + FUSED_NORM + DECODE + KV_WRITE + SSM

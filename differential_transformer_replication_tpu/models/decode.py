@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 from differential_transformer_replication_tpu.config import ModelConfig
 from differential_transformer_replication_tpu.models.generate import sample_token
-from differential_transformer_replication_tpu.models import common
+from differential_transformer_replication_tpu.models import common, jamba
 from differential_transformer_replication_tpu.ops import (
     apply_rope,
     diff_lambda,
@@ -80,7 +80,15 @@ from differential_transformer_replication_tpu.ops.streams import (
 
 
 def _n_streams(cfg: ModelConfig) -> int:
-    return {"control": 1, "diff": 2, "ndiff": cfg.n_terms}[cfg.model]
+    return {"control": 1, "diff": 2, "ndiff": cfg.n_terms,
+            "jamba": 1}[cfg.model]
+
+
+def _cannot_roll(cfg: ModelConfig) -> bool:
+    """Families whose cache cannot run past ``block_size``: diff's learned
+    position table, and jamba, whose attention layers carry no position
+    (a rolled ring would make them sliding-window layers)."""
+    return cfg.model in ("diff", "jamba")
 
 
 def _uses_rope(cfg: ModelConfig) -> bool:
@@ -90,7 +98,18 @@ def _uses_rope(cfg: ModelConfig) -> bool:
 # Pool-batch axis of each cache leaf: K (and its scales) carry the
 # stream axis first, V does not. The single source of truth for every
 # per-slot slice/scatter/merge over the cache pytree (serving/engine.py).
-KV_CACHE_BATCH_AXIS = {"k": 1, "v": 0, "k_scale": 1, "v_scale": 0}
+# ``ssm`` and ``conv`` are a Mamba layer's leaves (the jamba family): a
+# slot's recurrent state, not a ring over positions.
+KV_CACHE_BATCH_AXIS = {"k": 1, "v": 0, "k_scale": 1, "v_scale": 0,
+                       "ssm": 0, "conv": 0}
+STATE_LEAVES = ("ssm", "conv")
+
+
+def has_recurrent_state(cfg: ModelConfig) -> bool:
+    """Whether a sequence's cache holds state that every token overwrites
+    (a Mamba layer's): such a slot has to be zeroed before a new sequence
+    enters it, where a ring is simply masked by positions."""
+    return "mamba" in cfg.layer_kinds()
 
 
 def kv_store_dtype(cfg: ModelConfig) -> str:
@@ -210,12 +229,21 @@ def init_cache(cfg: ModelConfig, batch_size: int) -> list:
     ``cfg.kv_cache_dtype == "int8"`` stores symmetric per-head-scale
     int8 values plus fp32 scales (``k_scale`` (S, B, H, M) / ``v_scale``
     (B, H, M)) — about half the bf16 bytes per slot; otherwise the
-    resolved float dtype (:func:`kv_store_dtype`)."""
+    resolved float dtype (:func:`kv_store_dtype`).
+
+    The ``jamba`` family's layers are of two kinds: an attention layer
+    gets rings with ``kv_heads`` heads, a Mamba layer ``{ssm (B, N, Di)
+    in ssm_state_dtype, conv (B, K-1, Di)}``, zeros being a sequence's
+    start."""
     S = _n_streams(cfg)
-    H, d, dv, M = cfg.n_head, cfg.head_size, cfg.value_size, cfg.block_size
+    H, d, dv, M = cfg.n_kv_head, cfg.head_size, cfg.value_size, cfg.block_size
     store = kv_store_dtype(cfg)
     cache = []
-    for _ in range(cfg.n_layer):
+    for kind in cfg.layer_kinds():
+        if kind == "mamba":
+            conv, ssm = jamba.zero_state(cfg, batch_size)
+            cache.append({"ssm": ssm, "conv": conv})
+            continue
         if store == "int8":
             layer = {
                 "k": jnp.zeros((S, batch_size, H, M, d), jnp.int8),
@@ -354,13 +382,7 @@ def _chunk_attend(
     # future rows and unwritten (zero) slots. W < M (an explicit
     # ``window``) clips visibility tighter than the cache — used by the
     # append-oracle test to validate the ring arithmetic.
-    rows = pos + jnp.arange(L)[:, None]
-    slots = jnp.arange(M)[None, :]
-    last = pos + L - 1
-    held = last - jax.lax.rem(
-        jnp.asarray(last, jnp.int32) - slots, jnp.asarray(M, jnp.int32)
-    )
-    visible = (held <= rows) & (held >= 0) & (held > rows - W)
+    visible = _ring_visible(pos, L, M, W)
     scores = jnp.where(visible[None, None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)  # per-stream, fp32
 
@@ -372,6 +394,18 @@ def _chunk_attend(
         out = common.apply_group_norm(out, p_attn["gn"], cfg)
         out = out * OUTPUT_SCALE  # constant 0.2 (diff_transformer.py:91)
     return common.linear(out, p_attn["out"])
+
+
+def _ring_visible(pos, L: int, M: int, W: int) -> jnp.ndarray:
+    """(L, M) bool: which ring slots row ``l`` of a chunk at ``pos`` may
+    see, after the chunk's own write (:func:`_chunk_attend`)."""
+    rows = pos + jnp.arange(L)[:, None]
+    slots = jnp.arange(M)[None, :]
+    last = pos + L - 1
+    held = last - jax.lax.rem(
+        jnp.asarray(last, jnp.int32) - slots, jnp.asarray(M, jnp.int32)
+    )
+    return (held <= rows) & (held >= 0) & (held > rows - W)
 
 
 def _attn_chunk(
@@ -436,9 +470,17 @@ def forward_chunk(
     cfg: ModelConfig,
     rope_len: int = 0,
     window: int = 0,
+    valid=None,
 ) -> Tuple[jnp.ndarray, list]:
     """Process a chunk against the cache. Returns ((B, L, V) logits,
     updated cache). Prefill = one big chunk at pos=0; decode = L=1.
+
+    ``valid`` (the jamba family only; a runtime scalar, 1 <= valid <= L)
+    says that only the chunk's first ``valid`` tokens are the sequence's
+    and the rest padding up to a compiled shape: the cache comes back as
+    after ``valid`` tokens, and the logits are those of the LAST REAL
+    token alone, (B, 1, V). A prompt's tail then costs one program, not
+    one a binary digit of its length (each of which reads every weight).
 
     The cache is a RING over ``block_size`` slots, so RoPE families
     (control/ndiff) may run ``pos`` past block_size indefinitely — the
@@ -461,6 +503,12 @@ def forward_chunk(
     B, L = tokens.shape
     M = cfg.block_size
     if isinstance(pos, int):
+        if cfg.model == "jamba" and pos + L > M:
+            raise ValueError(
+                f"chunk [{pos}, {pos + L}) exceeds block_size {M}: the jamba "
+                "family's attention layers carry no position, so a rolled "
+                "ring would silently become sliding-window attention"
+            )
         if cfg.model == "diff" and pos + L > M:
             raise ValueError(
                 f"chunk [{pos}, {pos + L}) exceeds block_size {M}: the diff "
@@ -468,7 +516,7 @@ def forward_chunk(
                 "slide would re-embed every cached position); use "
                 "models.generate for its sliding-window behavior"
             )
-        if cfg.model != "diff" and pos + L > max(int(rope_len), M):
+        if not _cannot_roll(cfg) and pos + L > max(int(rope_len), M):
             raise ValueError(
                 f"chunk [{pos}, {pos + L}) exceeds the RoPE table length "
                 f"{max(int(rope_len), M)}: pass rope_len >= the final "
@@ -487,6 +535,14 @@ def forward_chunk(
                 f"chunk [{pos}, {pos + L}) wraps the ring boundary (slot "
                 f"{pos % M} + {L} > {M}): split it at the boundary"
             )
+    if cfg.model == "jamba":
+        return _forward_chunk_jamba(params, tokens, pos, cache, cfg, window,
+                                    valid)
+    if valid is not None:
+        raise ValueError(
+            f"forward_chunk(valid=...) pads a chunk of the jamba family "
+            f"only; the {cfg.model!r} family runs whole chunks"
+        )
     x, cos, sin = _embed_chunk(params, tokens, pos, cfg, rope_len)
     new_cache = []
     for li, blk in enumerate(params["blocks"], 1):  # 1-based (diff_transformer.py:161)
@@ -628,6 +684,8 @@ def forward_decode_pool(
     admission guards (serving/engine.py submit, generate_cached's
     checks) own the concrete-position validity rules; everything here
     is traced."""
+    if cfg.model == "jamba":
+        return _forward_decode_jamba(params, tokens, pos, cache, cfg, active)
     M = cfg.block_size
     compute = jnp.dtype(cfg.compute_dtype)
     pos = jnp.asarray(pos, jnp.int32)
@@ -674,7 +732,10 @@ def forward_decode_rows(
     hands back a NEW pool, which the chip fills through a copy of every
     ring (ops/kv_write.py), so between the two vmapped halves of a
     layer the rows' K/V go into the donated pool in place. ``active``
-    as in :func:`forward_decode_pool`."""
+    as in :func:`forward_decode_pool`. The ``jamba`` family has one
+    decode program, batched over the rows (:func:`_forward_decode_jamba`)."""
+    if cfg.model == "jamba":
+        return _forward_decode_jamba(params, tokens, pos, cache, cfg, active)
     pos = jnp.asarray(pos, jnp.int32)
     targets = _write_targets(pos, active, cfg.block_size)
     x, cos, sin = jax.vmap(
@@ -715,6 +776,116 @@ def forward_decode_rows(
         new_cache.append(layer_cache)
     logits = jax.vmap(lambda x: _lm_head(params, x, cfg))(x)
     return logits[:, 0, -1].astype(jnp.float32), new_cache
+
+
+# ---------------------------------------------------------------------------
+# The jamba family (models/jamba.py): layers of two kinds in one stack. An
+# attention layer keeps K/V rings like the others (``kv_heads`` heads, each
+# shared by a group of query heads, no position information); a Mamba layer
+# keeps a recurrent state a slot, which a prefill chunk carries on from
+# where the last chunk left it and a decode step overwrites for the active
+# slots, in place in the donated pool. Nothing masks a state by position:
+# a slot that takes a new sequence has to be zeroed first
+# (:func:`reset_slot_state`; serving/engine.py does so on admission).
+# ---------------------------------------------------------------------------
+
+
+def _forward_chunk_jamba(params: dict, tokens: jnp.ndarray, pos,
+                         cache: list, cfg: ModelConfig, window: int = 0,
+                         valid=None):
+    """:func:`forward_chunk` for the jamba family: every Mamba layer's
+    ``conv`` and ``ssm`` enter as the state before the chunk and leave as
+    the state after it, so a prompt may arrive in any chunks. With
+    ``valid`` the Mamba layers stop their state there
+    (``jamba.mixer_chunk``); the attention layers write the padding's keys
+    and values into ring positions past the sequence's end, which no query
+    sees (a query sees no later position) and which the tokens that come
+    to stand there overwrite before they attend."""
+    L, M = tokens.shape[1], cfg.block_size
+    slot = jax.lax.rem(jnp.asarray(pos, jnp.int32), M)
+    visible = _ring_visible(pos, L, M, int(window) or M)
+    x = jamba.embed(params, tokens, cfg)
+    new_cache = []
+    for blk, layer_cache in zip(params["blocks"], cache):
+        if "mamba" in blk:
+            with jax.named_scope("ssm"):
+                h = jamba.norm(x, blk["ln1"], cfg)
+                a, conv, ssm = jamba.mixer_chunk(
+                    h, blk["mamba"], cfg, layer_cache["conv"],
+                    layer_cache["ssm"], valid)
+            new_cache.append({"ssm": ssm, "conv": conv})
+        else:
+            with jax.named_scope("attn_norm"):
+                h = jamba.norm(x, blk["ln1"], cfg)
+            with jax.named_scope("attn"):
+                q, k, v = jamba.qkv(h, blk["attn"])
+                with jax.named_scope("kv_write"):
+                    layer_cache = _write_chunk(layer_cache, k[None], v, slot)
+                k_c, v_c = _dequant_layer(layer_cache, q.dtype)
+                a = jamba.attend(q, k_c[0], v_c, visible) @ blk["attn"][
+                    "out"]["w"].astype(q.dtype)
+            new_cache.append(layer_cache)
+        x = jamba.ffn(x + a, blk, cfg)
+    if valid is not None:
+        x = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
+    with jax.named_scope("lm_head"):
+        return jamba.lm_head(params, x, cfg), new_cache
+
+
+def _forward_decode_jamba(params: dict, tokens: jnp.ndarray, pos,
+                          cache: list, cfg: ModelConfig, active=None):
+    """The jamba family's decode step over the whole slot pool, one batch:
+    ``((B, V) logits, updated cache)``. The attention layers write their
+    row into the ring in place (``ops/kv_write.py``) and read the pool;
+    the Mamba layers advance the active slots' states (``ops/ssm.py``). A
+    row that is not ``active`` leaves every leaf of its slot as it is."""
+    B, M = tokens.shape[0], cfg.block_size
+    pos = jnp.asarray(pos, jnp.int32)
+    targets = _write_targets(pos, active, M)
+    live = jnp.ones((B,), bool) if active is None else active
+    # pos < M always (the family cannot roll): slot m holds a live key iff
+    # m <= pos
+    visible = jnp.arange(M)[None, None, :] <= pos[:, None, None]
+    x = jamba.embed(params, tokens, cfg)  # (B, E)
+    new_cache = []
+    for blk, layer_cache in zip(params["blocks"], cache):
+        if "mamba" in blk:
+            with jax.named_scope("ssm"):
+                h = jamba.norm(x, blk["ln1"], cfg)
+                a, conv, ssm = jamba.mixer_step(
+                    h, blk["mamba"], cfg, layer_cache["conv"],
+                    layer_cache["ssm"], live)
+            new_cache.append({"ssm": ssm, "conv": conv})
+        else:
+            with jax.named_scope("attn_norm"):
+                h = jamba.norm(x, blk["ln1"], cfg)
+            with jax.named_scope("attn"):
+                q, k, v = jamba.qkv(h, blk["attn"])
+                with jax.named_scope("kv_write"):
+                    layer_cache = _update_cache_rows(
+                        layer_cache, k[None], v, targets)
+                k_c, v_c = _dequant_layer(layer_cache, q.dtype)
+                a = jamba.attend(q[:, None], k_c[0], v_c, visible)[:, 0] @ blk[
+                    "attn"]["out"]["w"].astype(q.dtype)
+            new_cache.append(layer_cache)
+        x = jamba.ffn(x + a, blk, cfg)
+    with jax.named_scope("lm_head"):
+        return jamba.lm_head(params, x, cfg), new_cache
+
+
+def reset_slot_state(cache: list, slot) -> list:
+    """``cache`` with slot ``slot``'s recurrent state (every Mamba layer's
+    ``ssm`` and ``conv``) zeroed, in place under a jit that donates the
+    pool; rings are left as they are (positions mask them). ``slot`` is a
+    runtime scalar."""
+    return [
+        {key: (jax.lax.dynamic_update_slice_in_dim(
+                   leaf, jnp.zeros((1,) + leaf.shape[1:], leaf.dtype),
+                   slot, axis=0)
+               if key in STATE_LEAVES else leaf)
+         for key, leaf in layer.items()}
+        for layer in cache
+    ]
 
 
 # ---------------------------------------------------------------------------
@@ -1308,6 +1479,12 @@ def generate_cached(
     for longer runs."""
     B, T0 = idx.shape
     M = cfg.block_size
+    if cfg.model == "jamba" and T0 + max_new_tokens > M:
+        raise ValueError(
+            f"prompt ({T0}) + max_new_tokens ({max_new_tokens}) exceeds "
+            f"block_size ({M}): the jamba family's cache cannot roll (its "
+            "attention layers carry no position)"
+        )
     if cfg.model == "diff" and T0 + max_new_tokens > M:
         raise ValueError(
             f"prompt ({T0}) + max_new_tokens ({max_new_tokens}) exceeds "

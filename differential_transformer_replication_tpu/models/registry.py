@@ -2,7 +2,8 @@
 
 The reference selects models by commenting code blocks in and out
 (train.py:205-230); here it is a first-class dispatch on
-``ModelConfig.model`` covering the same three families.
+``ModelConfig.model`` covering the same three families, and ``jamba``
+(models/jamba.py), whose layers are of two kinds.
 """
 
 from __future__ import annotations
@@ -13,9 +14,10 @@ import jax
 import jax.numpy as jnp
 
 from differential_transformer_replication_tpu.config import ModelConfig
-from differential_transformer_replication_tpu.models import control, diff, ndiff
+from differential_transformer_replication_tpu.models import control, diff, jamba, ndiff
 
-_MODULES = {"control": control, "diff": diff, "ndiff": ndiff}
+_MODULES = {"control": control, "diff": diff, "ndiff": ndiff,
+            "jamba": jamba}
 
 
 def init_model(key: jax.Array, cfg: ModelConfig) -> dict:

@@ -38,7 +38,7 @@ from differential_transformer_replication_tpu.ops.lambdas import (
 
 
 def _layer_lambdas(params: dict, cfg: ModelConfig) -> Optional[jnp.ndarray]:
-    if cfg.model == "control":
+    if cfg.model in ("control", "jamba"):  # one softmax: no lambdas
         return None
     blocks = params["blocks"]
     if cfg.model == "diff":

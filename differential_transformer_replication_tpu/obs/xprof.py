@@ -59,6 +59,7 @@ from differential_transformer_replication_tpu import kernel_names as _NAMES
 KERNEL_BUCKETS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("decode_attention", _NAMES.DECODE),
     ("kv_write", _NAMES.KV_WRITE),
+    ("ssm", _NAMES.SSM),
     ("fused_ffn", _NAMES.FUSED_FFN + _NAMES.FUSED_NORM),
     ("flash_attention", _NAMES.FLASH),
     ("collectives", ("all-reduce", "all-gather", "reduce-scatter",

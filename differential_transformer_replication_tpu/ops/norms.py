@@ -13,6 +13,7 @@ mean/var over the full concatenated ``num_heads * 2*head_size`` dimension
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -32,3 +33,12 @@ def group_layer_norm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray, eps
     concatenated head outputs (diff_transformer.py:15-20). Kept as a named
     alias so call sites document which reference module they replicate."""
     return layer_norm(x, weight, bias, eps=eps)
+
+
+def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
+    """RMSNorm over the last axis: ``x / sqrt(mean(x^2) + eps) * weight``,
+    no mean subtracted and no bias (the ``jamba`` family's norm, also on
+    the Mamba mixer's ``dt``, ``B`` and ``C``). Computed in float32."""
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(ms + eps) * weight).astype(x.dtype)

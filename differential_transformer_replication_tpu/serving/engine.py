@@ -33,7 +33,9 @@ recompilation as requests come and go:
   garbage and write nothing: the mask goes into the cache write, and the
   step's cache traffic is the rows it writes, in place in the pool);
 - prefill chunks come from a power-of-two ladder, so at most
-  log2(prefill_chunk)+1 prefill shapes ever compile;
+  log2(prefill_chunk)+1 prefill shapes ever compile (a family with a
+  recurrent state runs a prompt's tail once, padded to the ladder's next
+  shape, where the others run it digit by digit);
 - sampling is one jitted batched kernel with per-row temperature/top-k
   ARRAYS (models/generate.py:``sample_token`` bakes them into the trace
   as statics; rows here must differ without recompiling). The greedy and
@@ -81,9 +83,12 @@ from differential_transformer_replication_tpu.models.decode import (
     forward_decode_rows,
     forward_decode_spec,
     forward_decode_spec_paged,
+    has_recurrent_state,
     gather_slot_cache,
     init_cache,
     init_cache_paged,
+    reset_slot_state,
+    STATE_LEAVES,
     kv_store_dtype,
     quality_vector,
     scatter_slot_cache,
@@ -135,6 +140,7 @@ from differential_transformer_replication_tpu.serving.scheduler import (
     FREE,
     Scheduler,
     Slot,
+    padded_chunk,
 )
 from differential_transformer_replication_tpu.utils import faults
 
@@ -175,6 +181,11 @@ _STAT_SPEC = {
     "engine_restarts": (
         "serving_engine_restarts_total",
         "Slot-pool rebuilds after a crashed engine step.",
+    ),
+    "state_resets": (
+        "serving_state_resets_total",
+        "Slots whose recurrent state was zeroed on admission (families "
+        "with Mamba layers; a K/V ring needs none).",
     ),
     "page_shed": (
         "serving_requests_page_shed_total",
@@ -382,12 +393,16 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
                                  rope_len=rope_len, active=active)
         return logits.astype(jnp.float32), new_cache
 
-    def _prefill(params, cache, slot, tokens, pos):
+    def _prefill(params, cache, slot, tokens, pos, valid=None):
         """One prompt chunk for one slot, in place in the pool.
 
         tokens: (1, L) with L from the power-of-two ladder; slot/pos are
         runtime scalars (dynamic gather/scatter on the pool's batch
-        axis), so only L distinguishes compiles.
+        axis), so only L distinguishes compiles. ``valid`` (a runtime
+        scalar too; a family with a recurrent state always passes it) is
+        how many of the L tokens are the prompt's: a tail that is no
+        power of two runs once, padded to the next shape of the ladder
+        (``forward_chunk``), and not once a binary digit.
         """
         row = [
             {key: (c[key][:, slot][:, None]
@@ -396,7 +411,7 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
             for c in cache
         ]
         logits, new_row = forward_chunk(
-            params, tokens, pos, row, cfg, rope_len=rope_len
+            params, tokens, pos, row, cfg, rope_len=rope_len, valid=valid
         )
         new_cache = [
             {key: (c[key].at[:, slot].set(nr[key][:, 0])
@@ -457,17 +472,36 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
         )
         V = logits.shape[-1]
         kth = jnp.clip(top_k - 1, 0, V - 1)
-        sorted_desc = -jnp.sort(-proc, axis=-1)
-        thresh = jnp.take_along_axis(sorted_desc, kth[:, None], axis=-1)
+
+        def _kth_largest(_):
+            sorted_desc = -jnp.sort(-proc, axis=-1)
+            return sorted_desc, jnp.take_along_axis(
+                sorted_desc, kth[:, None], axis=-1)
+
+        if quality:
+            # the telemetry tail reads the sort's head, so the sort runs
+            sorted_desc, thresh = _kth_largest(None)
+        else:
+            # Only a row with top_k > 0 reads the threshold, and only a
+            # row with a temperature reads the draw: a batch without one
+            # (all greedy) skips the vocabulary's sort and the noise, the
+            # same tokens bit for bit. At 256 x 65,536 logits the sort
+            # alone was 22 ms of a 42 ms iteration (my chip run, PR 28).
+            thresh = jax.lax.cond(
+                jnp.any(top_k > 0), lambda _: _kth_largest(None)[1],
+                lambda _: jnp.zeros((proc.shape[0], 1), proc.dtype), None)
         masked = jnp.where(
             (top_k > 0)[:, None] & (proc < thresh), -jnp.inf, proc
         )
         greedy = jnp.argmax(masked, axis=-1)
         safe_t = jnp.where(temperature > 0, temperature, 1.0)[:, None]
         scaled = masked / safe_t
-        drawn = jax.vmap(lambda k, lg: jax.random.categorical(k, lg))(
-            keys, scaled
-        )
+        drawn = jax.lax.cond(
+            jnp.any(temperature > 0),
+            lambda _: jax.vmap(
+                lambda k, lg: jax.random.categorical(k, lg))(
+                    keys, scaled).astype(greedy.dtype),
+            lambda _: jnp.zeros(greedy.shape, greedy.dtype), None)
         tokens = jnp.where(temperature <= 0, greedy, drawn).astype(jnp.int32)
         lp = jax.nn.log_softmax(scaled, axis=-1)
         chosen = jnp.take_along_axis(lp, tokens[:, None], axis=-1)
@@ -519,6 +553,44 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
         None,
         None,
     )
+
+
+# One slot's recurrent state zeroed in place in the donated pool
+# (models/decode.py:reset_slot_state). Model-independent: the slot is a
+# runtime scalar and the leaves are told apart by name, so it compiles once
+# a pool shape.
+_reset_state_fn = jax.jit(reset_slot_state, donate_argnums=(0,))
+
+
+def _refuse_for_recurrent_state(cfg: ModelConfig,
+                                serving: ServingConfig) -> None:
+    """The engine features that address a sequence's state BY POSITION,
+    each refused by name for a family whose layers hold a recurrent state
+    (``jamba``): a K/V ring can be cut, shared, rolled back or shipped at
+    any position, a Mamba state is overwritten every token and what it was
+    at an earlier position is gone."""
+    lacks = ("the {} family keeps a recurrent state a Mamba layer, and {} "
+             "needs a snapshot of that state at a position, which the "
+             "engine does not take")
+    asked = (
+        ("the host tier (host_tier_bytes; preemption and resume)",
+         serving.host_tier_bytes > 0),
+        ("speculation (spec_mode; rejected drafts roll the cache back)",
+         serving.spec_enabled()),
+        ("paging (kv_page_size > 0; with it the prefix cache, whose hits "
+         "resume a sequence at the shared prefix's end)",
+         serving.paged()),
+    )
+    for what, on in asked:
+        if on:
+            raise ValueError(lacks.format(cfg.model, what))
+    if cfg.kv_cache_dtype == "int8":
+        raise ValueError(
+            f"kv_cache_dtype='int8' is not available for the {cfg.model} "
+            "family: its attention layers' grouped-query decode path reads "
+            "float rings, and a quantized recurrent state does not exist "
+            "yet (ssm_state_dtype is float32)"
+        )
 
 
 # fold_in salt distinguishing a draft position's ACCEPT-draw key from
@@ -853,6 +925,12 @@ class ServingEngine:
         if self.serving.kv_cache_dtype:
             cfg = cfg.replace(kv_cache_dtype=self.serving.kv_cache_dtype)
         self.cfg = cfg
+        # a sequence's state is overwritten every token in some layers:
+        # a slot is zeroed on admission (_run_prefill), and what needs the
+        # state at a position refuses here
+        self._recurrent = has_recurrent_state(cfg)
+        if self._recurrent:
+            _refuse_for_recurrent_state(cfg, self.serving)
         self.max_total = self.serving.resolved_max_seq_len(cfg)
         # Paged KV cache (serving/pages.py): device KV lives in fixed
         # pages mapped through per-slot page tables; admission keys on
@@ -982,6 +1060,7 @@ class ServingEngine:
             on_preempt=(
                 self._preempt_slot if self._tier is not None else None
             ),
+            pad_limit=self.cfg.block_size if self._recurrent else 0,
         )
         self._next_id = 0
         self._base_keys: dict = {}  # request_id -> np (2,) uint32 PRNG base
@@ -1063,6 +1142,14 @@ class ServingEngine:
         # KV state (int8 roughly halves it vs bf16 — the dashboards'
         # capacity-win signal) and the active storage dtype as a labeled
         # identity gauge
+        self.registry.gauge(
+            "serving_state_pool_bytes",
+            "HBM bytes of the pool's recurrent state (every Mamba "
+            "layer's ssm and conv leaves); 0 for a family of K/V rings.",
+        ).set(
+            sum(leaf.nbytes for layer in self.cache
+                for key, leaf in layer.items() if key in STATE_LEAVES)
+        )
         self.registry.gauge(
             "serving_kv_cache_bytes_per_slot",
             "HBM bytes of pooled KV-cache state per slot "
@@ -1321,13 +1408,17 @@ class ServingEngine:
         req = Request.make(rid, prompt, params, **kw)
         M = self.cfg.block_size
         p = np.asarray(req.prompt, np.int32)
-        if self.cfg.model == "diff":
+        if self.cfg.model in ("diff", "jamba"):
             if p.shape[0] + req.params.max_new_tokens > M:
                 raise ValueError(
                     f"prompt ({p.shape[0]}) + max_new_tokens "
                     f"({req.params.max_new_tokens}) exceeds block_size ({M}) "
-                    "and the diff family's learned absolute position table "
-                    "cannot roll with a KV cache (models/decode.py)"
+                    + ("and the diff family's learned absolute position "
+                       "table cannot roll with a KV cache (models/decode.py)"
+                       if self.cfg.model == "diff" else
+                       "and the jamba family's cache cannot roll: its "
+                       "attention layers carry no position "
+                       "(models/decode.py)")
                 )
         else:
             if p.shape[0] > M:
@@ -1663,7 +1754,8 @@ class ServingEngine:
                 # close the window (blocking on a cache leaf so the
                 # iteration's device work is inside it) and hand the
                 # trace to the off-loop parse worker
-                self._device_prof.end(sync=self.cache[0]["k"])
+                self._device_prof.end(
+                    sync=next(iter(self.cache[0].values())))
             self.stats.inc("iterations")
             self._step_hist.observe(time.perf_counter() - t_step)
             self._update_gauges()
@@ -1697,20 +1789,32 @@ class ServingEngine:
                         **(instant_args(slot.trace)
                            if slot.trace is not None else {}),
                     )
+                if self._recurrent:
+                    self._reset_slot_state(slot, iteration)
             with self.tracer.span(
                 "prefill_call", iteration=iteration, size=size
             ):
-                tokens = jnp.asarray(slot.prompt[start:start + size][None])
-                if self._pages is not None:
+                chunk = slot.prompt[start:start + size][None]
+                if self._recurrent:
+                    # the ladder's shape that holds the chunk; what is
+                    # past ``size`` is padding (Scheduler.plan)
+                    tokens = np.zeros((1, padded_chunk(size)), np.int32)
+                    tokens[:, :size] = chunk
+                    logits, self.cache = self._prefill_fn(
+                        self.params, self.cache, np.int32(slot.index),
+                        jnp.asarray(tokens), np.int32(start),
+                        np.int32(size),
+                    )
+                elif self._pages is not None:
                     logits, self.cache = self._prefill_fn(
                         self.params, self.cache,
                         jnp.asarray(self._pages.table_row(slot.index)),
-                        tokens, np.int32(start),
+                        jnp.asarray(chunk), np.int32(start),
                     )
                 else:
                     logits, self.cache = self._prefill_fn(
                         self.params, self.cache, np.int32(slot.index),
-                        tokens, np.int32(start),
+                        jnp.asarray(chunk), np.int32(start),
                     )
             slot.filled = start + size
             self.stats.inc("prefill_tokens", size)
@@ -1732,6 +1836,16 @@ class ServingEngine:
                     q=(self._quality_echo(packed[0])
                        if self._quality else None),
                 )
+
+    def _reset_slot_state(self, slot: Slot, iteration: int) -> None:
+        """Zero the recurrent state the slot's last sequence left behind,
+        before the new one's first chunk (a ring needs none: positions
+        mask it). Dispatched like a prefill chunk, in place in the
+        donated pool; retirement, cancellation and a deadline leave the
+        state where it is, and a slot nobody holds is never read."""
+        with self.tracer.span("state_reset", iteration=iteration, slots=1):
+            self.cache = _reset_state_fn(self.cache, np.int32(slot.index))
+        self.stats.inc("state_resets")
 
     # -- speculative decoding (serving/spec.py) ------------------------
 
@@ -2273,6 +2387,8 @@ class ServingEngine:
             "decode": self._decode_fn._cache_size(),
             "sample": self._sample_fn._cache_size(),
         }
+        if self._recurrent:
+            out["state_reset"] = _reset_state_fn._cache_size()
         if self._copy_fn is not None:
             out["page_copy"] = self._copy_fn._cache_size()
         if self._extract_fn is not None:
@@ -2557,6 +2673,15 @@ class ServingEngine:
             None,
         )
 
+    def _refuse_migration(self) -> None:
+        if self._recurrent:
+            raise MigrateExportError(
+                f"live migration is not available for the {self.cfg.model} "
+                "family: the wire image ships K/V pages by position, and a "
+                "Mamba layer's recurrent state has no page to ship (it "
+                "needs a snapshot of the state) — fall back to replay"
+            )
+
     def export_slot_state(self, request_id: int,
                           dedup_pages: int = 0) -> bytes:
         """Capture one ACTIVE slot's full decode state as a wire image
@@ -2568,6 +2693,7 @@ class ServingEngine:
         copies device-locally. Raises the typed
         :class:`MigrateExportError` when there is nothing exportable
         (contiguous layout, request queued/prefilling/finished)."""
+        self._refuse_migration()
         if self._pages is None or self._extract_fn is None:
             raise MigrateExportError(
                 "live migration needs the paged KV layout "
@@ -2675,6 +2801,7 @@ class ServingEngine:
         :class:`MigratePayloadError` (corrupt/torn) or
         :class:`MigrateExportError` (geometry mismatch, dedup miss,
         contiguous layout) — both typed, both leave the engine clean."""
+        self._refuse_migration()
         if self._pages is None or self._inject_fn is None:
             raise MigrateExportError(
                 "live migration needs the paged KV layout "
@@ -3408,6 +3535,7 @@ class ServingEngine:
             on_preempt=(
                 self._preempt_slot if self._tier is not None else None
             ),
+            pad_limit=self.cfg.block_size if self._recurrent else 0,
         )
         self.scheduler.queue.extend(preserved)
         self.stats.inc("engine_restarts")

@@ -152,12 +152,24 @@ def _pow2_chunk(n: int, cap: int) -> int:
     return 1 << (m.bit_length() - 1)
 
 
+def padded_chunk(n: int) -> int:
+    """The ladder's shape that holds a chunk of n tokens: the smallest
+    power of two >= n (n >= 1)."""
+    return 1 << (n - 1).bit_length()
+
+
 class Scheduler:
     """FCFS queue + slot pool bookkeeping (see module docstring)."""
 
     def __init__(self, serving: ServingConfig, on_retire=None,
-                 on_preempt=None):
+                 on_preempt=None, pad_limit: int = 0):
         self.serving = serving
+        # > 0: the engine's prefill program takes a chunk padded to the
+        # next power of two (a family with a recurrent state,
+        # models/decode.py:forward_chunk ``valid``), so a prompt's tail
+        # is planned as ONE chunk whose padded end may not pass this
+        # position (the ring's size); 0: whole power-of-two chunks only
+        self.pad_limit = pad_limit
         # retirement hook: called with the slot BEFORE it resets, on
         # EVERY retire path (finish, deadline, cancel) — how the paged
         # engine returns KV pages / inserts prompts into the radix
@@ -306,7 +318,9 @@ class Scheduler:
 
         Returns ``[(slot, start, length), ...]`` chunks (FCFS by
         admission order, budget-capped); the engine executes them in
-        order and flips a slot to ACTIVE when its prompt completes.
+        order and flips a slot to ACTIVE when its prompt completes. A
+        length is a power of two, but for a prompt's tail under
+        ``pad_limit``, which is its real length (the engine pads it).
 
         Admission is priority-aware: each round picks the queued
         request with the best (effective rank, queue position) — aging
@@ -401,10 +415,19 @@ class Scheduler:
         for slot in pending:
             start = slot.filled
             while budget > 0 and start < slot.prompt_len:
+                left = slot.prompt_len - start
                 size = _pow2_chunk(
-                    min(slot.prompt_len - start, budget),
-                    self.serving.prefill_chunk,
+                    min(left, budget), self.serving.prefill_chunk,
                 )
+                if size < left <= self.serving.prefill_chunk:
+                    # the tail in one padded chunk where the budget and
+                    # the ring hold its padded shape (the budget counts
+                    # what the device runs), else digit by digit
+                    run = padded_chunk(left)
+                    if run <= budget and start + run <= self.pad_limit:
+                        chunks.append((slot, start, left))
+                        budget -= run
+                        break
                 chunks.append((slot, start, size))
                 start += size
                 budget -= size

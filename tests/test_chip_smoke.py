@@ -50,7 +50,7 @@ def test_last_line_has_exactly_the_contract_keys(capsys):
         [], capsys, run_child=run_child,
         serve_phase=lambda work, size, device: phases.append("serve"),
     )
-    assert rc == 0 and phases == ["train", "serve"]
+    assert rc == 0 and phases == ["train", "jamba", "serve"]
     last = json.loads(lines[-1])
     assert last == {"ok": True, "device": V5E}
     assert list(last) == ["ok", "device"]

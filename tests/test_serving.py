@@ -99,6 +99,37 @@ def test_batched_greedy_bit_identical_to_generate_cached(kind):
     assert all(s.state == FREE for s in eng.scheduler.slots)
 
 
+@pytest.mark.parametrize("mixed", [False, True])
+def test_a_greedy_batch_skips_the_sort_and_serves_the_same(mixed):
+    """The sampler sorts the vocabulary only for a batch in which some row
+    has a top-k, and draws only where some row has a temperature (a
+    ``lax.cond`` each): a greedy request gets the same tokens and log
+    probabilities alone (both skipped) and beside a sampling request (both
+    run), and the sampling request the same tokens alone and beside it."""
+    cfg, params = _setup("control")
+    greedy_p, drawn_p = _prompts([7, 5], cfg.vocab_size, seed=8)
+
+    def run(*which):
+        eng = ServingEngine(params, cfg, ServingConfig(num_slots=2))
+        ids = {}
+        if "greedy" in which:
+            ids[eng.submit(greedy_p, temperature=0.0, max_new_tokens=6,
+                           logprobs=2)] = "greedy"
+        if "drawn" in which:
+            ids[eng.submit(drawn_p, temperature=0.9, top_k=4, seed=5,
+                           max_new_tokens=6)] = "drawn"
+        return {ids[o.request_id]: o for o in eng.run()}
+
+    both = run("greedy", "drawn")
+    name = "drawn" if mixed else "greedy"
+    alone = run(name)[name]
+    assert alone.tokens == both[name].tokens
+    if not mixed:
+        assert alone.tokens == _ref_greedy(params, cfg, greedy_p, 6)
+        assert alone.token_logprobs == both[name].token_logprobs
+        assert alone.top_logprobs == both[name].top_logprobs
+
+
 @pytest.mark.slow
 def test_long_prompt_crop_and_rolling_decode_parity():
     """RoPE families crop prompts > block_size to the last block_size ids
@@ -294,6 +325,28 @@ class TestScheduler:
         sizes = [c[2] for c in s.plan()]
         assert sizes == [8, 4, 1]
         assert all(sz & (sz - 1) == 0 for sz in sizes)
+
+    @pytest.mark.parametrize("length, budget, limit, want", [
+        (5, 64, 64, [(0, 5)]),               # the tail in one padded chunk
+        (21, 64, 64, [(0, 8), (8, 8), (16, 5)]),  # whole chunks, then the tail
+        (16, 64, 64, [(0, 8), (8, 8)]),      # nothing to pad
+        (13, 12, 64, [(0, 8), (8, 4)]),      # the padded shape (16) is over budget
+        (13, 64, 12, [(0, 8), (8, 4), (12, 1)]),  # ... or past the ring's end
+        (13, 64, 0, [(0, 8), (8, 4), (12, 1)]),   # a family that takes none
+    ])
+    def test_a_tail_is_one_padded_chunk_where_it_fits(self, length, budget,
+                                                      limit, want):
+        s = Scheduler(ServingConfig(num_slots=1, prefill_chunk=8,
+                                    prefill_budget=budget), pad_limit=limit)
+        self._submit(s, [length])
+        assert [(start, size) for _, start, size in s.plan()] == want
+
+    def test_a_padded_tail_is_charged_its_padded_shape(self):
+        s = Scheduler(ServingConfig(num_slots=2, prefill_chunk=8,
+                                    prefill_budget=12), pad_limit=64)
+        self._submit(s, [5, 5])  # 8 of the budget, then 4 are left
+        assert [(c[0].index, c[1], c[2]) for c in s.plan()] == [
+            (0, 0, 5), (1, 0, 4)]
 
     def test_retire_frees_slot_for_next_request(self):
         s = self._sched(num_slots=1, prefill_chunk=8, prefill_budget=8)

@@ -46,7 +46,7 @@ dattn = importlib.import_module(
 
 KERNEL_MODULES = (
     "ops.flash", "ops.fused_ffn", "ops.fused_norm_residual",
-    "ops.decode_attention", "ops.kv_write",
+    "ops.decode_attention", "ops.kv_write", "ops.ssm",
 )
 
 # the recipe's widths (8L/768d, T=512, vocab 12000); the batch is cut, a
@@ -483,6 +483,108 @@ def test_decode_program_updates_the_pool_in_place(topo, impl, kv):
     assert not offenders, offenders
 
 
+# -- the jamba family at the published widths (AI21-Jamba2-3B) ----------------
+
+JAMBA = dict(model="jamba", vocab_size=65536, n_embd=2560, n_head=20,
+             kv_heads=1, block_size=2048, ffn_hidden=8192,
+             tie_embeddings=True, mamba_dt_rank=160,
+             param_dtype="bfloat16", ssm_impl="pallas")
+
+
+@pytest.mark.parametrize("L", [1, 8, 512])
+def test_ssm_scan_kernel_compiles_at_published_widths(topo, L):
+    """One sequence's chunk of the prefill ladder through 5120 channels
+    of 16 states (a chunk shorter than a time block is padded to one)."""
+    from differential_transformer_replication_tpu.ops import ssm
+
+    Di, N = 5120, 16
+    text = compile_for(
+        topo, ssm.selective_scan_pallas, sds((1, L, Di)),
+        sds((1, L, Di), jnp.float32), sds((Di, N), jnp.float32),
+        sds((1, L, N), jnp.float32), sds((1, L, N), jnp.float32),
+        sds((Di,), jnp.float32), sds((1, N, Di), jnp.float32))
+    assert kernel_instruction_names(text) == [kernel_names.SSM_SCAN_FWD]
+
+
+def _compile_jamba(topo, slots, n_layer=4):
+    """The engine's programs for a jamba stack of the published widths,
+    ``n_layer`` deep with attention in layer 1 (every kind of layer is
+    there): ``(decode, prefill, reset, the abstract cache)``."""
+    from differential_transformer_replication_tpu.models import init_model
+    from differential_transformer_replication_tpu.models.decode import init_cache
+    from differential_transformer_replication_tpu.serving import engine
+
+    cfg = ModelConfig(**JAMBA, n_layer=n_layer, attn_layer_period=n_layer,
+                      attn_layer_offset=1)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = place(jax.eval_shape(lambda k: init_model(k, cfg),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_cache(cfg, slots)))
+    ints, scalar = place(sds((slots,), jnp.int32)), place(sds((), jnp.int32))
+    prefill, decode = engine._build_step_fns(cfg, cfg.block_size)[:2]
+    return (
+        decode.lower(params, ints, ints, place(sds((slots,), jnp.bool_)),
+                     cache).compile(),
+        # the engine always gives this family `valid` (a padded tail)
+        prefill.lower(params, cache, scalar, place(sds((1, 64), jnp.int32)),
+                      scalar, scalar).compile(),
+        engine._reset_state_fn.lower(cache, scalar).compile(),
+        cache,
+    )
+
+
+def test_jamba_programs_update_the_state_pool_in_place(topo):
+    """What the chip's compiler makes of the jamba family's three
+    programs at the serve cell's pool (256 slots, published widths, four
+    layers): every cache leaf is aliased input to output in all three;
+    the decode program names both of its kernels and all of its phases,
+    and nothing in it but the state kernel produces a buffer the size of
+    a layer's recurrent state (84 MB; an XLA select over the pool would,
+    and a copy into another layout twice)."""
+    decode, prefill, reset, cache = _compile_jamba(topo, slots=256)
+    leaves = jax.tree_util.tree_leaves(cache)
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    for compiled in (decode, prefill, reset):
+        assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
+    text = decode.as_text()
+    assert text.startswith("HloModule jit__decode")
+    names = assert_kernels_named(text, "_decode")
+    assert names == {kernel_names.SSM_STATE_UPDATE, kernel_names.KV_ROW_WRITE}
+    assert {"ssm", "ssm_conv", "ssm_state", "attn_norm", "attn", "kv_write",
+            "ffn_norm", "ffn", "lm_head", "kv_merge"} <= scopes_in(text)
+    assert {"ssm", "ssm_conv", "ssm_scan"} <= scopes_in(prefill.as_text())
+    assert assert_kernels_named(prefill.as_text(), "_prefill") == {
+        kernel_names.SSM_SCAN_FWD}
+    state = 256 * 16 * 5120
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    offenders, inside = [], False
+    for line in text.split("\n"):
+        head_of = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head_of:
+            inside = head_of.group(1) in fused
+        m = re.match(
+            r"\s+(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]+)\]\S* ([\w\-]+)\(",
+            line)
+        if inside or not m:
+            continue
+        own, dtype, dims, opcode = m.groups()
+        size = 1
+        for n in dims.split(","):
+            size *= int(n)
+        if (dtype, size) != ("f32", state) or opcode in (
+                "parameter", "bitcast", "get-tuple-element", "tuple"):
+            continue
+        if opcode == "custom-call" and own.startswith(
+                kernel_names.SSM_STATE_UPDATE):
+            continue
+        offenders.append(f"%{own} = {dtype}[{dims}] {opcode}")
+    assert not offenders, offenders
+    assert decode.memory_analysis().temp_size_in_bytes < state * 4
+
+
 def test_sampler_is_scoped(topo):
     """The engine's jitted sampler (a narrow vocabulary: the sort over
     12,000 takes the compiler 23 s and the scope does not depend on it)."""
@@ -497,6 +599,10 @@ def test_sampler_is_scoped(topo):
         sds((slots, V), jnp.bool_), sds((slots, V), jnp.int32))
     assert text.startswith("HloModule jit__sample")
     assert "sampler" in scopes_in(text)
+    # the sort and the draw each sit in a branch a greedy batch skips
+    assert len(re.findall(r" conditional\(", text)) == 2
+    entry = text[text.index("ENTRY "):]
+    assert " sort(" not in entry and " sort(" in text
 
 
 @pytest.mark.slow

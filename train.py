@@ -27,7 +27,8 @@ def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description=__doc__)
     m = ModelConfig()
     t = TrainConfig()
-    p.add_argument("--model", choices=("control", "diff", "ndiff"), default=m.model)
+    p.add_argument("--model", choices=("control", "diff", "ndiff", "jamba"),
+                   default=m.model)
     p.add_argument("--n-embd", type=int, default=m.n_embd)
     p.add_argument("--n-head", type=int, default=m.n_head)
     p.add_argument("--n-layer", type=int, default=m.n_layer)
@@ -53,6 +54,26 @@ def build_parser() -> argparse.ArgumentParser:
                             "everything"),
                    help="what jax.checkpoint may save per block under "
                         "--remat (sweep with tools/ffn_sweep.py)")
+    # the jamba family's fields (config.py:JAMBA_FIELDS); --model jamba sets
+    # RMSNorm and no position information, which is all the family runs
+    p.add_argument("--ffn-hidden", type=int, default=m.ffn_hidden,
+                   help="jamba: hidden width of the gated MLP (0 = 4 * n_embd)")
+    p.add_argument("--kv-heads", type=int, default=m.kv_heads,
+                   help="jamba: K/V heads shared by groups of query heads "
+                        "(0 = one a query head, 1 = multi-query)")
+    p.add_argument("--tie-embeddings", action="store_true",
+                   help="jamba: the head reuses the token table")
+    p.add_argument("--attn-layer-period", type=int, default=m.attn_layer_period)
+    p.add_argument("--attn-layer-offset", type=int, default=m.attn_layer_offset,
+                   help="jamba: layer i attends iff i %% period == offset, "
+                        "else it is a Mamba block")
+    p.add_argument("--mamba-d-state", type=int, default=m.mamba_d_state)
+    p.add_argument("--mamba-d-conv", type=int, default=m.mamba_d_conv)
+    p.add_argument("--mamba-expand", type=int, default=m.mamba_expand)
+    p.add_argument("--mamba-dt-rank", type=int, default=m.mamba_dt_rank)
+    p.add_argument("--ssm-impl", choices=("xla", "pallas"), default=m.ssm_impl,
+                   help="jamba: selective-scan backend; training needs xla "
+                        "(the Pallas scan is forward only)")
     p.add_argument("--no-dp-overlap", action="store_true",
                    help="disable the bucketed backward-overlapped DP "
                         "gradient all-reduce (parallel/dp_step.py)")
@@ -215,7 +236,19 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def config_from_args(args: argparse.Namespace) -> TrainConfig:
+    jamba = {}
+    if args.model == "jamba":
+        jamba = dict(
+            ffn_hidden=args.ffn_hidden,
+            kv_heads=args.kv_heads, tie_embeddings=args.tie_embeddings,
+            attn_layer_period=args.attn_layer_period,
+            attn_layer_offset=args.attn_layer_offset,
+            mamba_d_state=args.mamba_d_state, mamba_d_conv=args.mamba_d_conv,
+            mamba_expand=args.mamba_expand, mamba_dt_rank=args.mamba_dt_rank,
+            ssm_impl=args.ssm_impl,
+        )
     model = ModelConfig(
+        **jamba,
         model=args.model,
         vocab_size=args.vocab_size,
         n_embd=args.n_embd,
