@@ -353,6 +353,18 @@ def _chunk_qkv(
     return qs, ks, v
 
 
+def _attn_out(heads: jnp.ndarray, p_attn: dict,
+              cfg: ModelConfig) -> jnp.ndarray:
+    """The attention half's tail on the concatenated heads (..., H*dv):
+    the diff families' group norm and constant scale, then the output
+    projection. It is about no ring, so the decode step runs it once
+    over the pool's rows."""
+    if cfg.model in ("diff", "ndiff"):
+        heads = common.apply_group_norm(heads, p_attn["gn"], cfg)
+        heads = heads * OUTPUT_SCALE  # constant 0.2 (diff_transformer.py:91)
+    return common.linear(heads, p_attn["out"])
+
+
 def _chunk_attend(
     qs: jnp.ndarray,  # (S, B, L, H, d)
     p_attn: dict,
@@ -363,7 +375,8 @@ def _chunk_attend(
     window: int = 0,  # visibility clip; 0/None = the cache size M
 ) -> jnp.ndarray:
     """Attend the chunk's rows over the ring that already holds their
-    own K/V (update-then-attend), combine the streams, project out."""
+    own K/V (update-then-attend) and combine the streams: the
+    concatenated heads (B, L, H*dv), before :func:`_attn_out`."""
     _, B, L = qs.shape[:3]
     M = cfg.block_size
     W = int(window) if window else M
@@ -389,11 +402,7 @@ def _chunk_attend(
     coeffs = _layer_coeffs(cfg, p_attn, layer_idx)  # (S, H)
     combined = jnp.einsum("sh,sbhlm->bhlm", coeffs, probs)
     out = jnp.einsum("bhlm,bhme->blhe", combined.astype(qs.dtype), v_cache)
-    out = out.reshape(B, L, -1)  # concat heads
-    if cfg.model in ("diff", "ndiff"):
-        out = common.apply_group_norm(out, p_attn["gn"], cfg)
-        out = out * OUTPUT_SCALE  # constant 0.2 (diff_transformer.py:91)
-    return common.linear(out, p_attn["out"])
+    return out.reshape(B, L, -1)  # concat heads
 
 
 def _ring_visible(pos, L: int, M: int, W: int) -> jnp.ndarray:
@@ -431,8 +440,8 @@ def _attn_chunk(
     slot = jax.lax.rem(jnp.asarray(pos, jnp.int32), cfg.block_size)
     with jax.named_scope("kv_write"):
         new_cache = _write_chunk(layer_cache, ks, v, slot)
-    out = _chunk_attend(qs, p_attn, new_cache, pos, layer_idx, cfg, window)
-    return out, new_cache
+    heads = _chunk_attend(qs, p_attn, new_cache, pos, layer_idx, cfg, window)
+    return _attn_out(heads, p_attn, cfg), new_cache
 
 
 def _embed_chunk(params: dict, tokens: jnp.ndarray, pos,
@@ -564,11 +573,13 @@ def forward_chunk(
 # ---------------------------------------------------------------------------
 # The decode step over the whole slot pool: every row advances one token
 # at its own absolute position, and the step's cache traffic is the rows
-# it writes. Two programs share one write (``_update_cache_rows``):
-# ``forward_decode_rows`` (decode_attention_impl == "xla") keeps every
-# row a length-1 ``forward_chunk`` under vmap and lifts only the write
-# out of the vmap; ``forward_decode_pool`` ("pallas") runs the pool in
-# one batch with the fused decode-attention kernel
+# it writes. Two programs share one write (``_update_cache_rows``) and
+# run everything that is about no ring (the norms, the attention's output
+# projection, the FFN half, the head) once over the rows, M = B: they
+# differ in the attention call alone. ``forward_decode_rows``
+# (decode_attention_impl == "xla") keeps a row's Q/K/V and its attend a
+# length-1 ``forward_chunk``'s, under vmap; ``forward_decode_pool``
+# ("pallas") hands the pool to the fused decode-attention kernel
 # (ops/decode_attention.py).
 # ---------------------------------------------------------------------------
 
@@ -658,10 +669,7 @@ def _pool_attn(
         k_eff, v_eff = _dequant_layer(new_cache, x.dtype)
         out = decode_attention_reference(qs, k_eff, v_eff, pos, coeffs)
     out = out.reshape(B, -1)  # concat heads
-    if cfg.model in ("diff", "ndiff"):
-        out = common.apply_group_norm(out, p_attn["gn"], cfg)
-        out = out * OUTPUT_SCALE
-    return common.linear(out, p_attn["out"]), new_cache
+    return _attn_out(out, p_attn, cfg), new_cache
 
 
 def forward_decode_pool(
@@ -724,16 +732,27 @@ def forward_decode_rows(
     active=None,  # (B,) bool: rows whose K/V is written; None = all
 ) -> Tuple[jnp.ndarray, list]:
     """The XLA decode step: ((B, V) fp32 logits, updated cache). Every
-    row is a length-1 :func:`forward_chunk` at its own position, under
-    ``vmap`` over the rows — the same einsums at the same shapes, which
-    is what keeps a served token bit-identical to ``generate_cached``'s
-    and a speculative sub-step bit-identical to a plain step. Only the
-    write is lifted out of the vmap: a vmapped ``dynamic_update_slice``
+    row is a length-1 :func:`forward_chunk` at its own position, and what
+    is about the row's own ring runs as one, under ``vmap`` over the
+    rows: its Q/K/V at its position (:func:`_chunk_qkv`) and its attend
+    over its ring (:func:`_chunk_attend`). Everything else runs ONCE over
+    the rows, ``(B, 1, 1, E)`` taken as M = B by the functions' own
+    ``reshape(-1, E)``: the write (a vmapped ``dynamic_update_slice``
     hands back a NEW pool, which the chip fills through a copy of every
-    ring (ops/kv_write.py), so between the two vmapped halves of a
-    layer the rows' K/V go into the donated pool in place. ``active``
-    as in :func:`forward_decode_pool`. The ``jamba`` family has one
-    decode program, batched over the rows (:func:`_forward_decode_jamba`)."""
+    ring, so the rows' K/V go into the donated pool in place,
+    ops/kv_write.py), and the norms, the attention's tail
+    (:func:`_attn_out`), the FFN half and the head. Under the vmap a
+    Pallas kernel gets the rows prepended to its grid, one row a grid
+    step: the fused FFN kernel streamed its weights 256 times a layer
+    for 256 rows (PR 29), so no kernel stays under it. A row's math is a
+    length-1 chunk's; its matmuls run at M = B, as
+    :func:`forward_decode_pool`'s do, so a served token's logits equal
+    ``generate_cached``'s up to the reassociation of a reduction
+    (tests/test_decode_rows.py states the tolerance), and a speculative
+    EXACT sub-step, which IS this program, equals a plain step bit for
+    bit. ``active`` as in :func:`forward_decode_pool`. The ``jamba``
+    family has one decode program, batched over the rows
+    (:func:`_forward_decode_jamba`)."""
     if cfg.model == "jamba":
         return _forward_decode_jamba(params, tokens, pos, cache, cfg, active)
     pos = jnp.asarray(pos, jnp.int32)
@@ -741,40 +760,37 @@ def forward_decode_rows(
     x, cos, sin = jax.vmap(
         lambda t, p: _embed_chunk(params, t[None, None], p, cfg, rope_len)
     )(tokens, pos)  # x (B, 1, 1, E): a batch-1 chunk of one token a row
+    ring_axes = {key: KV_CACHE_BATCH_AXIS[key] for key in cache[0]}
     new_cache = []
     for li, blk in enumerate(params["blocks"], 1):  # 1-based schedule
 
-        def _qkv(x, cos, sin):
-            with jax.named_scope("attn_norm"):
-                h = common.apply_pre_norm(x, blk["ln1"], cfg)
-            with jax.named_scope("attn"):
-                return _chunk_qkv(h, blk["attn"], cfg, cos, sin)
-
-        def _attend_ffn(x, qs, p, ring):
+        def _attend(qs, p, ring):
             # re-add the batch-1 axis forward_chunk's layout has
             ring = {
                 key: jnp.expand_dims(leaf, KV_CACHE_BATCH_AXIS[key])
                 for key, leaf in ring.items()
             }
-            with jax.named_scope("attn"):
-                a = _chunk_attend(qs, blk["attn"], ring, p, li, cfg)
-            return common.apply_block_ffn(x, a, blk, cfg)
+            return _chunk_attend(qs, blk["attn"], ring, p, li, cfg)
 
-        qs, ks, v = jax.vmap(_qkv)(x, cos, sin)
-        with jax.named_scope("attn"), jax.named_scope("kv_write"):
-            layer_cache = _update_cache_rows(
-                cache[li - 1],
-                ks[:, :, 0, 0].swapaxes(0, 1),  # (B, S, 1, 1, H, d) rows
-                v[:, 0, 0],  # (B, 1, 1, H, dv) rows
-                targets,
-            )
-        x = jax.vmap(
-            _attend_ffn,
-            in_axes=(0, 0, 0, {key: KV_CACHE_BATCH_AXIS[key]
-                               for key in layer_cache}),
-        )(x, qs, pos, layer_cache)
+        with jax.named_scope("attn_norm"):
+            h = common.apply_pre_norm(x, blk["ln1"], cfg)
+        with jax.named_scope("attn"):
+            qs, ks, v = jax.vmap(
+                lambda h, cos, sin: _chunk_qkv(h, blk["attn"], cfg, cos, sin)
+            )(h, cos, sin)
+            with jax.named_scope("kv_write"):
+                layer_cache = _update_cache_rows(
+                    cache[li - 1],
+                    ks[:, :, 0, 0].swapaxes(0, 1),  # (B, S, 1, 1, H, d) rows
+                    v[:, 0, 0],  # (B, 1, 1, H, dv) rows
+                    targets,
+                )
+            heads = jax.vmap(_attend, in_axes=(0, 0, ring_axes))(
+                qs, pos, layer_cache)
+            a = _attn_out(heads, blk["attn"], cfg)
+        x = common.apply_block_ffn(x, a, blk, cfg)
         new_cache.append(layer_cache)
-    logits = jax.vmap(lambda x: _lm_head(params, x, cfg))(x)
+    logits = _lm_head(params, x, cfg)  # (B, 1, 1, V)
     return logits[:, 0, -1].astype(jnp.float32), new_cache
 
 
@@ -1088,10 +1104,7 @@ def _pool_attn_paged(
         k_eff, v_eff = _dequant_layer(view, x.dtype)
         out = decode_attention_reference(qs, k_eff, v_eff, pos, coeffs)
     out = out.reshape(B, -1)  # concat heads
-    if cfg.model in ("diff", "ndiff"):
-        out = common.apply_group_norm(out, p_attn["gn"], cfg)
-        out = out * OUTPUT_SCALE
-    return common.linear(out, p_attn["out"]), new_cache
+    return _attn_out(out, p_attn, cfg), new_cache
 
 
 def forward_decode_pool_paged(
@@ -1271,10 +1284,7 @@ def _pool_attn_spec(
         out = decode_attention_multi_reference(qs, k_eff, v_eff, pos,
                                                coeffs)
     out = out.reshape(B, L, -1)  # concat heads
-    if cfg.model in ("diff", "ndiff"):
-        out = common.apply_group_norm(out, p_attn["gn"], cfg)
-        out = out * OUTPUT_SCALE
-    return common.linear(out, p_attn["out"]), new_cache
+    return _attn_out(out, p_attn, cfg), new_cache
 
 
 def _exact_row_step(params, tokens_r, pos_r, valid_r, cache,
@@ -1283,11 +1293,11 @@ def _exact_row_step(params, tokens_r, pos_r, valid_r, cache,
     engine's own L=1 decode program (:func:`forward_decode_rows` for the
     XLA impl, the pool-native fused path for pallas), with ``valid_r``
     as its write mask, so invalid rows leave their rings as they are.
-    Because every op runs at exactly the L=1 step's shapes, the
-    sub-step is bit-identical to a plain engine iteration — at ANY
-    model size (batched multi-row matmuls reassociate their reductions
-    once the contraction is large enough; per-lane/M-preserving shapes
-    cannot)."""
+    Because the sub-step IS the plain step's program (every op at the
+    shapes it has there, its matmuls at M = the pool's rows), it is
+    bit-identical to a plain engine iteration — at ANY model size (the
+    L-row batched verify's matmuls run at M = B * L and reassociate
+    their reductions once the contraction is large enough)."""
     step = (forward_decode_pool if cfg.decode_attention_impl == "pallas"
             else forward_decode_rows)
     logits, cache = step(params, tokens_r, pos_r, cache, cfg,

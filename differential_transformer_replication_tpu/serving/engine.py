@@ -42,10 +42,12 @@ recompilation as requests come and go:
   default paths are bit-identical to ``sample_token`` — pinned by
   tests/test_serving.py.
 
-Mixed per-slot positions ride a ``jax.vmap`` over the rows, each a
-length-1 ``forward_chunk`` (models/decode.py:``forward_decode_rows``:
-each row carries its own ``pos`` scalar, exactly the traced-position
-path the chunked decoder already supports); ``forward_chunk``'s
+Mixed per-slot positions ride a ``jax.vmap`` over the rows for what is
+about a row's own ring, its Q/K/V and its attend, each a length-1
+``forward_chunk``'s (models/decode.py:``forward_decode_rows``: each row
+carries its own ``pos`` scalar, exactly the traced-position path the
+chunked decoder already supports); the norms, the FFN half and the head
+run once over the rows as one batch. ``forward_chunk``'s
 concrete-position validity guards are enforced host-side at submit
 instead. Per-request determinism: the key for the t-th generated token
 is ``fold_in(PRNGKey(seed), t)``, a pure function of the request — not
@@ -375,11 +377,11 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
 
     def _decode(params, tokens, pos, active, cache):
         """One batched length-1 step over the WHOLE slot pool:
-        models/decode.py ``forward_decode_rows`` (every row a length-1
-        forward_chunk under vmap) or, with ``decode_attention_impl:
-        pallas``, ``forward_decode_pool`` (one batch, the fused
-        decode-attention kernel over every row in one (B*H,)-grid call
-        per layer).
+        models/decode.py ``forward_decode_rows`` (a row's Q/K/V and
+        attend a length-1 forward_chunk's under vmap, everything else
+        one batch over the rows) or, with ``decode_attention_impl:
+        pallas``, ``forward_decode_pool`` (the fused decode-attention
+        kernel over every row in one (B*H,)-grid call per layer).
 
         tokens/pos/active: (B,) runtime arrays. Inactive rows run the
         same math on garbage inputs (static shapes are the point); the
