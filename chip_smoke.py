@@ -479,7 +479,7 @@ def jamba_phase(work: Path, size: dict, require_device) -> dict:
     from differential_transformer_replication_tpu.models import init_model
     from differential_transformer_replication_tpu.models.decode import (
         forward_chunk,
-        forward_decode_rows,
+        forward_decode_pool,
         init_cache,
     )
     from differential_transformer_replication_tpu.serving.engine import (
@@ -525,7 +525,7 @@ def jamba_phase(work: Path, size: dict, require_device) -> dict:
                     params, t, pos, cache, c))(idx[:, pos:pos + n], cache)
             outs.append(lg)
             pos += n
-        step = jax.jit(lambda t, p, cache, c=c: forward_decode_rows(
+        step = jax.jit(lambda t, p, cache, c=c: forward_decode_pool(
             params, t, p, cache, c))
         for t in range(pos, idx.shape[1]):
             lg, cache = step(idx[:, t], jnp.full((4,), t), cache)

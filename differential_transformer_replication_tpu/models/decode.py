@@ -60,12 +60,9 @@ from differential_transformer_replication_tpu.ops import (
     rope_cos_sin,
 )
 from differential_transformer_replication_tpu.ops.decode_attention import (
-    decode_attention,
     decode_attention_multi,
     decode_attention_multi_paged,
     decode_attention_multi_reference,
-    decode_attention_paged,
-    decode_attention_reference,
     dequantize_kv,
     quantize_kv,
 )
@@ -571,230 +568,6 @@ def forward_chunk(
 
 
 # ---------------------------------------------------------------------------
-# The decode step over the whole slot pool: every row advances one token
-# at its own absolute position, and the step's cache traffic is the rows
-# it writes. Two programs share one write (``_update_cache_rows``) and
-# run everything that is about no ring (the norms, the attention's output
-# projection, the FFN half, the head) once over the rows, M = B: they
-# differ in the attention call alone. ``forward_decode_rows``
-# (decode_attention_impl == "xla") keeps a row's Q/K/V and its attend a
-# length-1 ``forward_chunk``'s, under vmap; ``forward_decode_pool``
-# ("pallas") hands the pool to the fused decode-attention kernel
-# (ops/decode_attention.py).
-# ---------------------------------------------------------------------------
-
-
-def _rope_rows(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray):
-    """Rotate single-token streams at PER-ROW positions: x (S, B, H, d),
-    cos/sin (B, d/2) gathered at each row's own position. Same fp32
-    even/odd-lane formula as ops/rope.py:apply_rope (which slices one
-    shared [0, T) table and so cannot express per-row positions)."""
-    xf = x.astype(jnp.float32)
-    x_even = xf[..., 0::2]
-    x_odd = xf[..., 1::2]
-    c = cos[None, :, None, :]  # broadcast over (S, ..., H, ...)
-    s = sin[None, :, None, :]
-    rot_even = x_even * c - x_odd * s
-    rot_odd = x_even * s + x_odd * c
-    return jnp.stack([rot_even, rot_odd], axis=-1).reshape(x.shape).astype(
-        x.dtype
-    )
-
-
-@jax.named_scope("kv_merge")
-def _write_targets(pos: jnp.ndarray, active, M: int) -> jnp.ndarray:
-    """(B,) int32: the ring position each row's K/V goes to,
-    ``pos[b] % M``, or -1 for a row that is not ``active`` and keeps
-    what its ring holds (a free slot, a slot in mid-prefill, a verify
-    row past its slot's draft). This B-sized select is all that a step
-    decides about keeping: the pool itself is never selected over."""
-    slot = jax.lax.rem(jnp.asarray(pos, jnp.int32), M)
-    return slot if active is None else jnp.where(active, slot, -1)
-
-
-def _update_cache_rows(layer_cache: dict, ks: jnp.ndarray, v: jnp.ndarray,
-                       targets: jnp.ndarray) -> dict:
-    """Write each row's new K/V — ks (S, B, H, d), v (B, H, dv) — into
-    its own ring at ``targets[b]`` (:func:`_write_targets`), in place in
-    the donated pool, one ``ops/kv_write.py`` kernel a leaf: the float
-    and the int8 leaves and the scale planes take the same route,
-    addressed through ``KV_CACHE_BATCH_AXIS``. The int8 path quantizes
-    first, so the step's own attention (and every later step) reads
-    exactly what the cache holds."""
-    if "k_scale" in layer_cache:
-        kq, ksc = quantize_kv(ks)
-        vq, vsc = quantize_kv(v)
-        rows = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc}
-    else:
-        rows = {"k": ks, "v": v}
-    return {
-        key: write_rows(leaf, rows[key].astype(leaf.dtype), targets,
-                        KV_CACHE_BATCH_AXIS[key])
-        for key, leaf in layer_cache.items()
-    }
-
-
-def _pool_attn(
-    x: jnp.ndarray,  # (B, E) normed single-token inputs, one per slot
-    p_attn: dict,
-    layer_cache: dict,
-    pos: jnp.ndarray,  # (B,) int32 absolute positions
-    targets: jnp.ndarray,  # (B,) int32 ring position written, -1: none
-    layer_idx: int,
-    cfg: ModelConfig,
-    cos,  # (B, d/2) per-row RoPE tables (None for the diff family)
-    sin,
-):
-    """The batched L=1 twin of :func:`_attn_chunk`: update-then-attend
-    over every slot row at once, attention dispatched on
-    ``cfg.decode_attention_impl``."""
-    B = x.shape[0]
-    wq, wk = _stacked_wq(p_attn)
-    qs = jnp.einsum("be,sehd->sbhd", x, wq.astype(x.dtype))
-    ks = jnp.einsum("be,sehd->sbhd", x, wk.astype(x.dtype))
-    v = jnp.einsum("be,ehd->bhd", x, p_attn["wv"].astype(x.dtype))
-    if _uses_rope(cfg):
-        qs = _rope_rows(qs, cos, sin)
-        ks = _rope_rows(ks, cos, sin)
-    with jax.named_scope("kv_write"):
-        new_cache = _update_cache_rows(layer_cache, ks, v, targets)
-    coeffs = _layer_coeffs(cfg, p_attn, layer_idx)
-    if cfg.decode_attention_impl == "pallas":
-        out = decode_attention(
-            qs, new_cache["k"], new_cache["v"], pos, coeffs,
-            k_scale=new_cache.get("k_scale"),
-            v_scale=new_cache.get("v_scale"),
-        )
-    else:
-        k_eff, v_eff = _dequant_layer(new_cache, x.dtype)
-        out = decode_attention_reference(qs, k_eff, v_eff, pos, coeffs)
-    out = out.reshape(B, -1)  # concat heads
-    return _attn_out(out, p_attn, cfg), new_cache
-
-
-def forward_decode_pool(
-    params: dict,
-    tokens: jnp.ndarray,  # (B,) current token per slot row
-    pos,  # (B,) int32 absolute position per row (runtime array)
-    cache: list,
-    cfg: ModelConfig,
-    rope_len: int = 0,
-    active=None,  # (B,) bool: rows whose K/V is written; None = all
-) -> Tuple[jnp.ndarray, list]:
-    """Advance the WHOLE slot pool by one token: returns ((B, V) logits,
-    updated cache). The batched counterpart of a length-1
-    :func:`forward_chunk` per row — same ring semantics, same
-    update-then-attend order, every row at its own position — minus the
-    vmap, so the fused decode kernel sees the full pool in one
-    ``(B*H,)``-grid call per layer. Rows that are not ``active`` run the
-    same math on whatever their slot holds (static shapes are the point)
-    and leave their ring as it is; their logits mean nothing. Host-side
-    admission guards (serving/engine.py submit, generate_cached's
-    checks) own the concrete-position validity rules; everything here
-    is traced."""
-    if cfg.model == "jamba":
-        return _forward_decode_jamba(params, tokens, pos, cache, cfg, active)
-    M = cfg.block_size
-    compute = jnp.dtype(cfg.compute_dtype)
-    pos = jnp.asarray(pos, jnp.int32)
-    targets = _write_targets(pos, active, M)
-    x = params["tok_emb"][tokens].astype(compute)  # (B, E)
-    cos = sin = None
-    if cfg.model == "diff":
-        x = x + params["pos_emb"][pos].astype(compute)
-    else:
-        cos_full, sin_full = rope_cos_sin(
-            cfg.head_size, max(int(rope_len), M)
-        )
-        cos = cos_full[pos]  # (B, d/2) at each row's own position
-        sin = sin_full[pos]
-    new_cache = []
-    for li, blk in enumerate(params["blocks"], 1):  # 1-based schedule
-        with jax.named_scope("attn_norm"):
-            h = common.apply_pre_norm(x, blk["ln1"], cfg)
-        with jax.named_scope("attn"):
-            a, layer_cache = _pool_attn(
-                h, blk["attn"], cache[li - 1], pos, targets, li, cfg,
-                cos, sin,
-            )
-        x = common.apply_block_ffn(x, a, blk, cfg)
-        new_cache.append(layer_cache)
-    return _lm_head(params, x, cfg), new_cache
-
-
-def forward_decode_rows(
-    params: dict,
-    tokens: jnp.ndarray,  # (B,) current token per slot row
-    pos,  # (B,) int32 absolute position per row (runtime array)
-    cache: list,
-    cfg: ModelConfig,
-    rope_len: int = 0,
-    active=None,  # (B,) bool: rows whose K/V is written; None = all
-) -> Tuple[jnp.ndarray, list]:
-    """The XLA decode step: ((B, V) fp32 logits, updated cache). Every
-    row is a length-1 :func:`forward_chunk` at its own position, and what
-    is about the row's own ring runs as one, under ``vmap`` over the
-    rows: its Q/K/V at its position (:func:`_chunk_qkv`) and its attend
-    over its ring (:func:`_chunk_attend`). Everything else runs ONCE over
-    the rows, ``(B, 1, 1, E)`` taken as M = B by the functions' own
-    ``reshape(-1, E)``: the write (a vmapped ``dynamic_update_slice``
-    hands back a NEW pool, which the chip fills through a copy of every
-    ring, so the rows' K/V go into the donated pool in place,
-    ops/kv_write.py), and the norms, the attention's tail
-    (:func:`_attn_out`), the FFN half and the head. Under the vmap a
-    Pallas kernel gets the rows prepended to its grid, one row a grid
-    step: the fused FFN kernel streamed its weights 256 times a layer
-    for 256 rows (PR 29), so no kernel stays under it. A row's math is a
-    length-1 chunk's; its matmuls run at M = B, as
-    :func:`forward_decode_pool`'s do, so a served token's logits equal
-    ``generate_cached``'s up to the reassociation of a reduction
-    (tests/test_decode_rows.py states the tolerance), and a speculative
-    EXACT sub-step, which IS this program, equals a plain step bit for
-    bit. ``active`` as in :func:`forward_decode_pool`. The ``jamba``
-    family has one decode program, batched over the rows
-    (:func:`_forward_decode_jamba`)."""
-    if cfg.model == "jamba":
-        return _forward_decode_jamba(params, tokens, pos, cache, cfg, active)
-    pos = jnp.asarray(pos, jnp.int32)
-    targets = _write_targets(pos, active, cfg.block_size)
-    x, cos, sin = jax.vmap(
-        lambda t, p: _embed_chunk(params, t[None, None], p, cfg, rope_len)
-    )(tokens, pos)  # x (B, 1, 1, E): a batch-1 chunk of one token a row
-    ring_axes = {key: KV_CACHE_BATCH_AXIS[key] for key in cache[0]}
-    new_cache = []
-    for li, blk in enumerate(params["blocks"], 1):  # 1-based schedule
-
-        def _attend(qs, p, ring):
-            # re-add the batch-1 axis forward_chunk's layout has
-            ring = {
-                key: jnp.expand_dims(leaf, KV_CACHE_BATCH_AXIS[key])
-                for key, leaf in ring.items()
-            }
-            return _chunk_attend(qs, blk["attn"], ring, p, li, cfg)
-
-        with jax.named_scope("attn_norm"):
-            h = common.apply_pre_norm(x, blk["ln1"], cfg)
-        with jax.named_scope("attn"):
-            qs, ks, v = jax.vmap(
-                lambda h, cos, sin: _chunk_qkv(h, blk["attn"], cfg, cos, sin)
-            )(h, cos, sin)
-            with jax.named_scope("kv_write"):
-                layer_cache = _update_cache_rows(
-                    cache[li - 1],
-                    ks[:, :, 0, 0].swapaxes(0, 1),  # (B, S, 1, 1, H, d) rows
-                    v[:, 0, 0],  # (B, 1, 1, H, dv) rows
-                    targets,
-                )
-            heads = jax.vmap(_attend, in_axes=(0, 0, ring_axes))(
-                qs, pos, layer_cache)
-            a = _attn_out(heads, blk["attn"], cfg)
-        x = common.apply_block_ffn(x, a, blk, cfg)
-        new_cache.append(layer_cache)
-    logits = _lm_head(params, x, cfg)  # (B, 1, 1, V)
-    return logits[:, 0, -1].astype(jnp.float32), new_cache
-
-
-# ---------------------------------------------------------------------------
 # The jamba family (models/jamba.py): layers of two kinds in one stack. An
 # attention layer keeps K/V rings like the others (``kv_heads`` heads, each
 # shared by a group of query heads, no position information); a Mamba layer
@@ -878,8 +651,9 @@ def _forward_decode_jamba(params: dict, tokens: jnp.ndarray, pos,
             with jax.named_scope("attn"):
                 q, k, v = jamba.qkv(h, blk["attn"])
                 with jax.named_scope("kv_write"):
-                    layer_cache = _update_cache_rows(
-                        layer_cache, k[None], v, targets)
+                    layer_cache = _write_ring(
+                        layer_cache, _store_rows(layer_cache, k[None], v),
+                        targets)
                 k_c, v_c = _dequant_layer(layer_cache, q.dtype)
                 a = jamba.attend(q[:, None], k_c[0], v_c, visible)[:, 0] @ blk[
                     "attn"]["out"]["w"].astype(q.dtype)
@@ -1025,436 +799,321 @@ def _gather_pool_view(leaf: jnp.ndarray, page_tables: jnp.ndarray,
     return g.reshape(shape)
 
 
-def _update_pages_rows(layer_cache: dict, ks: jnp.ndarray,
-                       v: jnp.ndarray, pos: jnp.ndarray,
-                       write_pages: jnp.ndarray, M: int) -> dict:
-    """Scatter each row's new K/V — ks (S, B, H, d), v (B, H, dv) —
-    into physical page ``write_pages[b]`` at in-page offset
-    ``(pos[b] % M) % page_size``. The engine redirects inactive rows
-    to the trash page, where the contiguous path gives them no write
-    target (:func:`_write_targets`)."""
-    ps = layer_cache["v"].shape[-2]
-    off = jax.lax.rem(
-        jax.lax.rem(jnp.asarray(pos, jnp.int32), M), ps
-    )
-    wp = jnp.asarray(write_pages, jnp.int32)
-    out = dict(layer_cache)
+# ---------------------------------------------------------------------------
+# The decode step. ONE program (:func:`_decode_step`) advances N rows by a
+# token each, every row at its own absolute position: the engine's step
+# over its slots (tokens (B,), N = B) and speculation's batched verify
+# (tokens (B, L), N = B * L: row (b, l) is slot b's last emitted token,
+# l = 0, or its l-th draft token, seen by the rows after it and by none
+# before: update-then-attend, all rows written first, then each row's mask
+# ``col <= pos[b, l]``). What differs between the pool's two layouts, and
+# between one row a slot and L, is bound once a step as a pair of
+# functions (:func:`_pool_seam`): ``write``, where a row's K/V lands, and
+# ``attend``, what a row reads. A row that must leave no trace (a free
+# slot, a slot in mid-prefill, a verify row past its slot's draft) is
+# turned away by the WRITE, never masked afterwards: the slot pool's step
+# gives it no target (:func:`_write_targets`), the paged pool sends it to
+# the reserved trash page, the slot pool's batched verify to a trash row
+# past the slots. So the program's shapes never depend on which rows are
+# live, and mixed traffic compiles nothing new.
+# ---------------------------------------------------------------------------
+
+
+@jax.named_scope("kv_merge")
+def _write_targets(pos: jnp.ndarray, active, M: int) -> jnp.ndarray:
+    """(B,) int32: the ring position each row's K/V goes to,
+    ``pos[b] % M``, or -1 for a row that is not ``active`` and keeps
+    what its ring holds (a free slot, a slot in mid-prefill, a verify
+    row past its slot's draft). This B-sized select is all that a step
+    decides about keeping: the pool itself is never selected over."""
+    slot = jax.lax.rem(jnp.asarray(pos, jnp.int32), M)
+    return slot if active is None else jnp.where(active, slot, -1)
+
+
+def _store_rows(layer_cache: dict, ks: jnp.ndarray, v: jnp.ndarray) -> dict:
+    """The rows' new K/V — ks (S, N, H, d), v (N, H, dv) — as the pool
+    stores them, one array a leaf of the layer. The int8 path quantizes
+    here, once for every ``write``, so the step's own attention (and
+    every later step) reads exactly what the cache holds."""
     if "k_scale" in layer_cache:
         kq, ksc = quantize_kv(ks)
         vq, vsc = quantize_kv(v)
-        out["k"] = layer_cache["k"].at[:, wp, :, off].set(
-            kq.transpose(1, 0, 2, 3)
-        )
-        out["k_scale"] = layer_cache["k_scale"].at[:, wp, :, off].set(
-            ksc.transpose(1, 0, 2)
-        )
-        out["v"] = layer_cache["v"].at[wp, :, off].set(vq)
-        out["v_scale"] = layer_cache["v_scale"].at[wp, :, off].set(vsc)
+        rows = {"k": kq, "v": vq, "k_scale": ksc, "v_scale": vsc}
     else:
-        dt = layer_cache["k"].dtype
-        out["k"] = layer_cache["k"].at[:, wp, :, off].set(
-            ks.astype(dt).transpose(1, 0, 2, 3)
-        )
-        out["v"] = layer_cache["v"].at[wp, :, off].set(v.astype(dt))
+        rows = {"k": ks, "v": v}
+    return {key: rows[key].astype(leaf.dtype)
+            for key, leaf in layer_cache.items()}
+
+
+def _write_ring(layer_cache: dict, rows: dict, targets: jnp.ndarray) -> dict:
+    """``write`` for one row a slot of the slot pool: row b goes into its
+    own ring at ``targets[b]`` (:func:`_write_targets`), in place in the
+    donated pool, one ``ops/kv_write.py`` kernel a leaf: the float and
+    the int8 leaves and the scale planes take the same route, addressed
+    through ``KV_CACHE_BATCH_AXIS``."""
+    return {
+        key: write_rows(leaf, rows[key], targets, KV_CACHE_BATCH_AXIS[key])
+        for key, leaf in layer_cache.items()
+    }
+
+
+def _write_scatter(layer_cache: dict, rows: dict, where: jnp.ndarray,
+                   pos: jnp.ndarray) -> dict:
+    """``write`` by an XLA scatter: row n goes to entry ``where[n]`` of
+    the pool's batch axis, at that entry's token ``pos[n] %`` its length.
+    For a paged pool the entry is a physical page (the engine names the
+    trash page for a row that must not land; the page size divides
+    ``block_size``, so this is the in-page offset of ``pos % M``); for
+    the slot pool under a batched verify it is a cache row (the trash row
+    likewise; several rows of a slot then land in one ring, which the
+    kernel of :func:`_write_ring` does not do). Collisions inside the
+    trash entry are harmless: it is write-only garbage."""
+    token = jax.lax.rem(pos, layer_cache["v"].shape[-2])
+    out = {}
+    for key, leaf in layer_cache.items():
+        axis = KV_CACHE_BATCH_AXIS[key]
+        at = (slice(None),) * axis + (where, slice(None), token)
+        out[key] = leaf.at[at].set(jnp.moveaxis(rows[key], axis, 0))
     return out
 
 
-def _pool_attn_paged(
-    x: jnp.ndarray,  # (B, E) normed single-token inputs, one per slot
-    p_attn: dict,
-    layer_cache: dict,  # paged leaves (page axis where the slot axis was)
-    pos: jnp.ndarray,  # (B,) int32 absolute positions
-    page_tables: jnp.ndarray,  # (B, pages_per_slot) int32
-    write_pages: jnp.ndarray,  # (B,) int32 physical page per row's write
-    layer_idx: int,
-    cfg: ModelConfig,
-    cos,
-    sin,
-):
-    """The paged twin of :func:`_pool_attn`: write each row's K/V into
-    its physical page (update-then-attend), then attend through the
-    page table — the fused kernel loads pages directly; the XLA path
-    gathers the contiguous view first."""
-    B = x.shape[0]
-    M = cfg.block_size
-    wq, wk = _stacked_wq(p_attn)
-    qs = jnp.einsum("be,sehd->sbhd", x, wq.astype(x.dtype))
-    ks = jnp.einsum("be,sehd->sbhd", x, wk.astype(x.dtype))
-    v = jnp.einsum("be,ehd->bhd", x, p_attn["wv"].astype(x.dtype))
-    if _uses_rope(cfg):
-        qs = _rope_rows(qs, cos, sin)
-        ks = _rope_rows(ks, cos, sin)
-    with jax.named_scope("kv_write"):
-        new_cache = _update_pages_rows(
-            layer_cache, ks, v, pos, write_pages, M
-        )
+def _attend_own_ring(cfg: ModelConfig, qs, pos, layer_cache, p_attn,
+                     layer_idx):
+    """``attend`` for one row a slot of the slot pool, in XLA: each row
+    over its own ring, a length-1 :func:`forward_chunk`'s attend
+    (:func:`_chunk_attend`) under ``vmap``. The serve cells measure this
+    one."""
+
+    def one(q, at, ring):
+        # re-add the batch-1 axis forward_chunk's layout has
+        ring = {key: jnp.expand_dims(leaf, KV_CACHE_BATCH_AXIS[key])
+                for key, leaf in ring.items()}
+        return _chunk_attend(q, p_attn, ring, at, layer_idx, cfg)
+
+    ring_axes = {key: KV_CACHE_BATCH_AXIS[key] for key in layer_cache}
+    return jax.vmap(one, in_axes=(0, 0, ring_axes))(qs, pos, layer_cache)
+
+
+def _attend_pool(cfg: ModelConfig, shape: tuple, page_tables, fused: bool,
+                 qs, pos, layer_cache, p_attn, layer_idx):
+    """``attend`` over the pool as a whole, slot b's L rows with
+    row-causal visibility (L = 1 for the plain step): the fused
+    multi-query kernel of ops/decode_attention.py, which streams a ring
+    (or, through the page table, its pages) once for its L rows and
+    dequantizes int8 in the load, or its XLA oracle over a float view of
+    the rings: the pages gathered through the table, or the slot pool
+    short of its trash row, which is never attended."""
+    B, L = (*shape, 1)[:2]  # the plain step is a block of one row a slot
+    q = qs[:, :, 0, 0].swapaxes(0, 1)  # (N, S, 1, 1, H, d) -> (S, N, H, d)
+    q = q.reshape(q.shape[:1] + (B, L) + q.shape[2:])
+    pos = pos.reshape(B, L)
     coeffs = _layer_coeffs(cfg, p_attn, layer_idx)
-    if cfg.decode_attention_impl == "pallas":
-        out = decode_attention_paged(
-            qs, new_cache["k"], new_cache["v"], page_tables, pos, coeffs,
-            k_scale=new_cache.get("k_scale"),
-            v_scale=new_cache.get("v_scale"),
-        )
+    if fused:
+        scales = {"k_scale": layer_cache.get("k_scale"),
+                  "v_scale": layer_cache.get("v_scale")}
+        if page_tables is None:
+            return decode_attention_multi(
+                q, layer_cache["k"], layer_cache["v"], pos, coeffs, **scales)
+        return decode_attention_multi_paged(
+            q, layer_cache["k"], layer_cache["v"], page_tables, pos, coeffs,
+            **scales)
+    if page_tables is None:
+        view = {key: jax.lax.slice_in_dim(leaf, 0, B,
+                                          axis=KV_CACHE_BATCH_AXIS[key])
+                for key, leaf in layer_cache.items()}
     else:
-        view = {
-            key: _gather_pool_view(new_cache[key], page_tables,
-                                   KV_CACHE_BATCH_AXIS[key])
-            for key in new_cache
-        }
-        k_eff, v_eff = _dequant_layer(view, x.dtype)
-        out = decode_attention_reference(qs, k_eff, v_eff, pos, coeffs)
-    out = out.reshape(B, -1)  # concat heads
-    return _attn_out(out, p_attn, cfg), new_cache
+        view = {key: _gather_pool_view(leaf, page_tables,
+                                       KV_CACHE_BATCH_AXIS[key])
+                for key, leaf in layer_cache.items()}
+    k_eff, v_eff = _dequant_layer(view, q.dtype)
+    return decode_attention_multi_reference(q, k_eff, v_eff, pos, coeffs)
 
 
-def forward_decode_pool_paged(
-    params: dict,
-    tokens: jnp.ndarray,  # (B,) current token per slot row
-    pos,  # (B,) int32 absolute position per row
-    cache: list,  # paged cache (init_cache_paged)
-    page_tables: jnp.ndarray,  # (B, pages_per_slot) int32
-    write_pages: jnp.ndarray,  # (B,) int32; trash page for inactive rows
-    cfg: ModelConfig,
-    rope_len: int = 0,
-) -> Tuple[jnp.ndarray, list]:
-    """Advance the whole slot pool by one token THROUGH the page
-    tables: the paged counterpart of :func:`forward_decode_pool`, same
-    ring semantics and update-then-attend order, with the physical
-    placement of every KV row resolved from runtime int32 tables — so
-    pages can be allocated, freed, shared and forked between calls
-    with ZERO recompiles (pinned by tests/test_pages.py)."""
-    B = tokens.shape[0]
-    M = cfg.block_size
-    compute = jnp.dtype(cfg.compute_dtype)
-    pos = jnp.asarray(pos, jnp.int32)
-    x = params["tok_emb"][tokens].astype(compute)  # (B, E)
-    cos = sin = None
-    if cfg.model == "diff":
-        x = x + params["pos_emb"][pos].astype(compute)
+def _pool_seam(cfg: ModelConfig, shape: tuple, pos: jnp.ndarray, active,
+               where, page_tables):
+    """The step's ``(write, attend)``, chosen here and nowhere else, from
+    what the caller passes (page tables or none; tokens ``(B,)`` or
+    ``(B, L)``) and ``cfg.decode_attention_impl``:
+
+    ======================  ========================  ====================
+    pool, rows a slot       write                     attend (xla | pallas)
+    ======================  ========================  ====================
+    slots, 1                ``_write_ring``           own ring | pool
+    slots, L (trash row)    ``_write_scatter``        pool     | pool
+    pages, 1 or L           ``_write_scatter``        pool     | pool
+    ======================  ========================  ====================
+
+    ``write(layer_cache, rows) -> layer_cache`` takes what
+    :func:`_store_rows` made; ``attend(qs, pos, layer_cache, p_attn,
+    layer_idx)`` takes the rows' queries as :func:`_chunk_qkv` leaves
+    them under the rows' vmap, (N, S, 1, 1, H, d), and returns the
+    concatenated heads a row. A change to how a row is written or read
+    (another kernel, another layout) is a change to one of the four
+    functions above or a new line here."""
+    own_ring = page_tables is None and len(shape) == 1
+    if own_ring:
+        write = partial(_write_ring,
+                        targets=_write_targets(pos, active, cfg.block_size))
     else:
-        cos_full, sin_full = rope_cos_sin(
-            cfg.head_size, max(int(rope_len), M)
-        )
-        cos = cos_full[pos]
-        sin = sin_full[pos]
+        write = partial(_write_scatter, pos=pos,
+                        where=jnp.asarray(where, jnp.int32).reshape(-1))
+    fused = cfg.decode_attention_impl == "pallas"
+    if own_ring and not fused:
+        return write, partial(_attend_own_ring, cfg)
+    return write, partial(_attend_pool, cfg, shape, page_tables, fused)
+
+
+def _decode_step(params: dict, tokens: jnp.ndarray, pos, cache: list,
+                 cfg: ModelConfig, rope_len: int, active=None, where=None,
+                 page_tables=None) -> Tuple[jnp.ndarray, list]:
+    """The K/V families' one decode program: embed, the layers, the head,
+    for tokens ``(B,)`` or ``(B, L)`` with ``pos`` alike: N = B * L rows,
+    each a length-1 :func:`forward_chunk` at its own position, against
+    the pool :func:`_pool_seam` binds. Returns fp32 logits
+    ``tokens.shape + (V,)`` and the updated cache.
+
+    The rule of this loop (PR 29 measured it): only what is about a row's
+    own position may sit under a ``vmap`` over the rows, which is its
+    embedding, its Q/K/V rotated at its position (:func:`_chunk_qkv`)
+    and, where ``attend`` is the row's own ring, that attend. Everything
+    else runs ONCE over the N rows, ``(N, 1, 1, E)`` taken as M = N by
+    the functions' own ``reshape(-1, E)``: the write, the norms, the
+    attention's tail (:func:`_attn_out`), the FFN half and the head.
+    Under a vmap a Pallas kernel gets the rows prepended to its grid, one
+    row a grid step (the fused FFN kernel streamed its weights 256 times
+    a layer for 256 rows), and a vmapped ``dynamic_update_slice`` hands
+    back a NEW pool, which the chip fills through a copy of every ring.
+    A row's math is a length-1 chunk's and its matmuls run at M = N, so a
+    served token's logits equal a chunk's up to the reassociation of a
+    reduction (tests/test_decode_rows.py states the tolerance). Rows that
+    write nothing run the same math on whatever their slot holds (static
+    shapes are the point); their logits mean nothing. The engine's
+    admission guards own the concrete-position validity rules
+    (serving/engine.py submit, ``generate_cached``'s checks); everything
+    here is traced."""
+    shape = tokens.shape
+    pos = jnp.asarray(pos, jnp.int32).reshape(-1)
+    write, attend = _pool_seam(cfg, shape, pos, active, where, page_tables)
+    with jax.named_scope("embed"):
+        x, cos, sin = jax.vmap(
+            lambda t, p: _embed_chunk(params, t[None, None], p, cfg, rope_len)
+        )(tokens.reshape(-1), pos)  # x (N, 1, 1, E): a batch-1 chunk a row
     new_cache = []
     for li, blk in enumerate(params["blocks"], 1):  # 1-based schedule
         with jax.named_scope("attn_norm"):
             h = common.apply_pre_norm(x, blk["ln1"], cfg)
         with jax.named_scope("attn"):
-            a, layer_cache = _pool_attn_paged(
-                h, blk["attn"], cache[li - 1], pos, page_tables,
-                write_pages, li, cfg, cos, sin,
-            )
+            qs, ks, v = jax.vmap(
+                lambda h, cos, sin: _chunk_qkv(h, blk["attn"], cfg, cos, sin)
+            )(h, cos, sin)
+            with jax.named_scope("kv_write"):
+                layer_cache = write(cache[li - 1], _store_rows(
+                    cache[li - 1],
+                    ks[:, :, 0, 0].swapaxes(0, 1),  # (N, S, 1, 1, H, d) rows
+                    v[:, 0, 0],  # (N, 1, 1, H, dv) rows
+                ))
+            heads = attend(qs, pos, layer_cache, blk["attn"], li)
+            a = _attn_out(heads.reshape(x.shape[:-1] + (-1,)), blk["attn"],
+                          cfg)
         x = common.apply_block_ffn(x, a, blk, cfg)
         new_cache.append(layer_cache)
-    with jax.named_scope("lm_head"):
-        x = common.apply_pre_norm(x, params["ln_f"], cfg)
-        return common.linear(x, params["lm_head"]), new_cache
+    logits = _lm_head(params, x, cfg)[:, 0, -1].astype(jnp.float32)
+    return logits.reshape(shape + logits.shape[-1:]), new_cache
 
 
-# ---------------------------------------------------------------------------
-# Speculative multi-row decode (serving/spec.py): the verify step runs
-# L = k + 1 rows per slot through the pool in ONE call — the slot's last
-# emitted token plus its k draft tokens, each row at its own absolute
-# position with row-causal visibility (update-then-attend: all L rows'
-# K/V are written first, then each row's mask ``col <= pos[b, l]`` shows
-# it exactly the rows before it). Rows past a slot's draft length (and
-# every row of an inactive slot) are WRITE-REDIRECTED instead of masked:
-# the contiguous pool carries one extra TRASH ROW at batch index
-# ``num_slots`` (``row_target`` names each row's destination), the paged
-# pool redirects to the trash page through ``write_pages`` — either way
-# the jitted step needs no shape change as per-slot draft lengths vary,
-# so mixed spec/non-spec traffic compiles NOTHING new.
-# ---------------------------------------------------------------------------
-
-
-def _update_cache_rows_spec(layer_cache: dict, ks: jnp.ndarray,
-                            v: jnp.ndarray, slot: jnp.ndarray,
-                            row: jnp.ndarray) -> dict:
-    """Scatter N flattened verify rows' K/V — ks (S, N, H, d),
-    v (N, H, dv) — into cache batch row ``row[n]`` at ring slot
-    ``slot[n]``. The multi-row twin of :func:`_update_cache_rows` with
-    an EXPLICIT batch-row index: valid rows name their own slot row,
-    invalid rows the trash row (collisions inside the trash row are
-    harmless — it is write-only garbage)."""
-    out = dict(layer_cache)
-    if "k_scale" in layer_cache:
-        kq, ksc = quantize_kv(ks)
-        vq, vsc = quantize_kv(v)
-        out["k"] = layer_cache["k"].at[:, row, :, slot].set(
-            kq.transpose(1, 0, 2, 3)
-        )
-        out["k_scale"] = layer_cache["k_scale"].at[:, row, :, slot].set(
-            ksc.transpose(1, 0, 2)
-        )
-        out["v"] = layer_cache["v"].at[row, :, slot].set(vq)
-        out["v_scale"] = layer_cache["v_scale"].at[row, :, slot].set(vsc)
-    else:
-        dt = layer_cache["k"].dtype
-        out["k"] = layer_cache["k"].at[:, row, :, slot].set(
-            ks.astype(dt).transpose(1, 0, 2, 3)
-        )
-        out["v"] = layer_cache["v"].at[row, :, slot].set(v.astype(dt))
-    return out
-
-
-def _pool_attn_spec(
-    x: jnp.ndarray,  # (B, L, E) normed per-row inputs
-    p_attn: dict,
-    layer_cache: dict,  # contiguous (R >= B rows) OR paged leaves
-    pos: jnp.ndarray,  # (B, L) int32 absolute positions
-    targets: jnp.ndarray,  # (B, L) int32: cache row (contiguous) or
-    #                        physical write page (paged) per verify row
-    page_tables,  # (B, pages_per_slot) int32, or None on the
-    #               contiguous path
-    layer_idx: int,
+def forward_decode_pool(
+    params: dict,
+    tokens: jnp.ndarray,  # (B,) current token per slot row
+    pos,  # (B,) int32 absolute position per row (runtime array)
+    cache: list,  # init_cache's pool, or init_cache_paged's with tables
     cfg: ModelConfig,
-    cos,  # (B, L, d/2) per-row RoPE tables (None for the diff family)
-    sin,
-):
-    """The L-row twin of :func:`_pool_attn` / :func:`_pool_attn_paged`:
-    write all L rows' K/V (flattened, write-redirected), then attend
-    every row with row-causal visibility through
-    ops/decode_attention.py's multi-query kernel (or its XLA twin)."""
-    B, L, E = x.shape
-    M = cfg.block_size
-    wq, wk = _stacked_wq(p_attn)
-    qs = jnp.einsum("ble,sehd->sblhd", x, wq.astype(x.dtype))
-    ks = jnp.einsum("ble,sehd->sblhd", x, wk.astype(x.dtype))
-    v = jnp.einsum("ble,ehd->blhd", x, p_attn["wv"].astype(x.dtype))
-    if _uses_rope(cfg):
-        S = qs.shape[0]
-        d = qs.shape[-1]
-        cos_f = cos.reshape(B * L, -1)
-        sin_f = sin.reshape(B * L, -1)
-        qs = _rope_rows(
-            qs.reshape(S, B * L, cfg.n_head, d), cos_f, sin_f
-        ).reshape(qs.shape)
-        ks = _rope_rows(
-            ks.reshape(S, B * L, cfg.n_head, d), cos_f, sin_f
-        ).reshape(ks.shape)
-    S = qs.shape[0]
-    ks_f = ks.reshape(S, B * L, cfg.n_head, -1)  # B, L adjacent: zero-copy
-    v_f = v.reshape(B * L, cfg.n_head, -1)
-    with jax.named_scope("kv_write"):
-        if page_tables is None:
-            slot = jax.lax.rem(
-                jnp.asarray(pos, jnp.int32).reshape(-1), M
-            )
-            new_cache = _update_cache_rows_spec(
-                layer_cache, ks_f, v_f, slot, targets.reshape(-1)
-            )
-        else:
-            new_cache = _update_pages_rows(
-                layer_cache, ks_f, v_f,
-                jnp.asarray(pos, jnp.int32).reshape(-1),
-                targets.reshape(-1), M,
-            )
-    coeffs = _layer_coeffs(cfg, p_attn, layer_idx)
-    if cfg.decode_attention_impl == "pallas":
-        if page_tables is None:
-            out = decode_attention_multi(
-                qs, new_cache["k"], new_cache["v"], pos, coeffs,
-                k_scale=new_cache.get("k_scale"),
-                v_scale=new_cache.get("v_scale"),
-            )
-        else:
-            out = decode_attention_multi_paged(
-                qs, new_cache["k"], new_cache["v"], page_tables, pos,
-                coeffs,
-                k_scale=new_cache.get("k_scale"),
-                v_scale=new_cache.get("v_scale"),
-            )
-    else:
-        if page_tables is None:
-            # the trash row (batch rows >= B) is never attended
-            view = {
-                key: (c_val[:, :B] if KV_CACHE_BATCH_AXIS[key]
-                      else c_val[:B])
-                for key, c_val in new_cache.items()
-            }
-        else:
-            view = {
-                key: _gather_pool_view(new_cache[key], page_tables,
-                                       KV_CACHE_BATCH_AXIS[key])
-                for key in new_cache
-            }
-        k_eff, v_eff = _dequant_layer(view, x.dtype)
-        out = decode_attention_multi_reference(qs, k_eff, v_eff, pos,
-                                               coeffs)
-    out = out.reshape(B, L, -1)  # concat heads
-    return _attn_out(out, p_attn, cfg), new_cache
-
-
-def _exact_row_step(params, tokens_r, pos_r, valid_r, cache,
-                    cfg: ModelConfig, rope_len: int):
-    """One EXACT verify sub-step over the full contiguous pool: the
-    engine's own L=1 decode program (:func:`forward_decode_rows` for the
-    XLA impl, the pool-native fused path for pallas), with ``valid_r``
-    as its write mask, so invalid rows leave their rings as they are.
-    Because the sub-step IS the plain step's program (every op at the
-    shapes it has there, its matmuls at M = the pool's rows), it is
-    bit-identical to a plain engine iteration — at ANY model size (the
-    L-row batched verify's matmuls run at M = B * L and reassociate
-    their reductions once the contraction is large enough)."""
-    step = (forward_decode_pool if cfg.decode_attention_impl == "pallas"
-            else forward_decode_rows)
-    logits, cache = step(params, tokens_r, pos_r, cache, cfg,
-                         rope_len=rope_len, active=valid_r)
-    return logits.astype(jnp.float32), cache
+    rope_len: int = 0,
+    active=None,  # (B,) bool: rows whose K/V is written; None = all
+    page_tables=None,  # (B, pages_per_slot) int32: the pool is paged
+    write_pages=None,  # (B,) int32 physical page a row's write goes to
+) -> Tuple[jnp.ndarray, list]:
+    """Advance the WHOLE pool by one token: ``((B, V) logits, updated
+    cache)``. THE L = 1 entry point: the engine's step, every EXACT
+    verify sub-step, the model drafter's rounds and ``generate_cached``'s
+    loop all run this, on either layout and either
+    ``decode_attention_impl`` (:func:`_pool_seam`). On the slot pool a
+    row that is not ``active`` leaves its ring as it is. With
+    ``page_tables`` the physical place of every K/V row comes from
+    runtime int32 tables, so pages can be allocated, freed, shared and
+    forked between calls with ZERO recompiles (tests/test_pages.py), and
+    the engine names the trash page in ``write_pages`` for a row that
+    must not land. The ``jamba`` family has its own loop, two kinds of
+    layer, on the slot pool (:func:`_forward_decode_jamba`)."""
+    if cfg.model == "jamba":
+        return _forward_decode_jamba(params, tokens, pos, cache, cfg, active)
+    return _decode_step(params, tokens, pos, cache, cfg, rope_len,
+                        active=active, where=write_pages,
+                        page_tables=page_tables)
 
 
 def forward_decode_spec(
     params: dict,
     tokens: jnp.ndarray,  # (B, L) per-row tokens (row 0 = last emitted)
     pos,  # (B, L) int32 absolute position per row
-    cache: list,  # contiguous cache with R >= B batch rows
+    cache: list,  # slot pool with R > B rows (a trash row), or paged
     cfg: ModelConfig,
-    row_target: jnp.ndarray,  # (B, L) int32 cache row per verify row
+    targets: jnp.ndarray,  # (B, L) int32: the cache row (slot pool) or
+    #                        physical page (paged) each row is written to
     rope_len: int = 0,
     batched: bool = False,
+    page_tables=None,  # (B, pages_per_slot) int32: the pool is paged
 ) -> Tuple[jnp.ndarray, list]:
-    """Advance the whole slot pool by an L-row verify block: returns
-    ``((B, L, V) logits, updated cache)``. Row (b, 0) reruns the slot's
-    last emitted token exactly like :func:`forward_decode_pool`; rows
-    1..L-1 carry its draft tokens at pos+1.. with row-causal
-    visibility. ``row_target`` redirects rows past a slot's draft
-    length (and inactive slots' rows) to the pool's trash row (batch
-    index B), so the rejected suffix never lands in live cache state —
-    the ring/page cursors "roll back" for free because visibility
-    derives purely from position arithmetic.
+    """Advance the whole pool by an L-row verify block (serving/spec.py):
+    ``((B, L, V) logits, updated cache)``. THE verify entry point. Row
+    (b, 0) reruns the slot's last emitted token exactly like
+    :func:`forward_decode_pool`; rows 1..L-1 carry its draft tokens at
+    pos+1.. with row-causal visibility. ``targets`` sends rows past a
+    slot's draft length (and inactive slots' rows) to the trash row
+    (batch index >= B) or the trash page, so the rejected suffix never
+    lands in live cache state: the ring/page cursors "roll back" for
+    free because visibility derives purely from position arithmetic, and
+    draft lengths, page churn and COW forks between calls compile
+    nothing new.
 
     Two verify formulations (``ServingConfig.spec_verify``):
 
     - ``batched=False`` (EXACT, the serving default): a static unroll
-      of L engine-native L=1 sub-steps inside one jitted program.
-      Every matmul keeps the plain decode step's shapes, so greedy
-      spec output is bit-identical to non-spec decoding at ANY model
-      size — the property the parity pins rely on.
-    - ``batched=True``: all L rows in ONE pass — one fused multi-query
-      attention call per layer (ops/decode_attention.py
-      ``decode_attention_multi``: every row's ring streamed once,
-      row-causal masks, int8 dequant fused) and (B, L)-batched
-      projections/FFN. This is the bandwidth-optimal TPU formulation
-      (the KV stream and weight reads amortize over the L rows);
-      large-contraction XLA matmuls may reassociate their reductions
-      vs the 1-row step, so greedy ties can resolve differently at
-      scale (bit-identical at the pinned test sizes; the sampled
-      distribution is unchanged either way).
+      of L :func:`forward_decode_pool` sub-steps inside one jitted
+      program, on the slot pool over all R rows with the valid rows as
+      the write mask. Every op keeps the plain decode step's shapes, so
+      greedy spec output is bit-identical to non-spec decoding at ANY
+      model size — the property the parity pins rely on.
+    - ``batched=True``: all L rows in ONE :func:`_decode_step` — one
+      fused multi-query attention call per layer (every ring or page
+      streamed once for its L rows) and N = B * L rows through the
+      projections and the FFN. This is the bandwidth-optimal TPU
+      formulation (the KV stream and weight reads amortize over the L
+      rows); large-contraction XLA matmuls may reassociate their
+      reductions vs the 1-row step, so greedy ties can resolve
+      differently at scale (bit-identical at the pinned test sizes; the
+      sampled distribution is unchanged either way).
     """
-    B, L = tokens.shape
     pos = jnp.asarray(pos, jnp.int32)
+    targets = jnp.asarray(targets, jnp.int32)
     if batched:
-        return _forward_decode_spec_batched(
-            params, tokens, pos, cache, cfg, row_target, rope_len
-        )
-    R = cache[0]["v"].shape[0]
-    padn = R - B
-    valid = jnp.asarray(row_target, jnp.int32) < B
+        return _decode_step(params, tokens, pos, cache, cfg, rope_len,
+                            where=targets, page_tables=page_tables)
+    B, L = tokens.shape
+    paged = page_tables is not None
+    spare = 0 if paged else cache[0]["v"].shape[0] - B
+
+    def column(x, l):
+        return jnp.pad(x[:, l], (0, spare))
+
     rows = []
     for l in range(L):
-        t_r, p_r, v_r = tokens[:, l], pos[:, l], valid[:, l]
-        if padn:
-            t_r = jnp.concatenate([t_r, jnp.zeros((padn,), t_r.dtype)])
-            p_r = jnp.concatenate([p_r, jnp.zeros((padn,), p_r.dtype)])
-            v_r = jnp.concatenate([v_r, jnp.zeros((padn,), bool)])
-        lg, cache = _exact_row_step(params, t_r, p_r, v_r, cache, cfg,
-                                    rope_len)
-        rows.append(lg[:B])
+        how = (dict(page_tables=page_tables, write_pages=targets[:, l])
+               if paged else dict(active=column(targets < B, l)))
+        logits, cache = forward_decode_pool(
+            params, column(tokens, l), column(pos, l), cache, cfg,
+            rope_len=rope_len, **how)
+        rows.append(logits[:B])
     return jnp.stack(rows, axis=1), cache
-
-
-def _forward_decode_spec_batched(params, tokens, pos, cache,
-                                 cfg: ModelConfig, row_target,
-                                 rope_len: int):
-    B, L = tokens.shape
-    M = cfg.block_size
-    compute = jnp.dtype(cfg.compute_dtype)
-    x = params["tok_emb"][tokens].astype(compute)  # (B, L, E)
-    cos = sin = None
-    if cfg.model == "diff":
-        x = x + params["pos_emb"][pos].astype(compute)
-    else:
-        cos_full, sin_full = rope_cos_sin(
-            cfg.head_size, max(int(rope_len), M)
-        )
-        cos = cos_full[pos]  # (B, L, d/2)
-        sin = sin_full[pos]
-    new_cache = []
-    for li, blk in enumerate(params["blocks"], 1):  # 1-based schedule
-        with jax.named_scope("attn_norm"):
-            h = common.apply_pre_norm(x, blk["ln1"], cfg)
-        with jax.named_scope("attn"):
-            a, layer_cache = _pool_attn_spec(
-                h, blk["attn"], cache[li - 1], pos, row_target, None,
-                li, cfg, cos, sin,
-            )
-        x = common.apply_block_ffn(x, a, blk, cfg)
-        new_cache.append(layer_cache)
-    with jax.named_scope("lm_head"):
-        x = common.apply_pre_norm(x, params["ln_f"], cfg)
-        return common.linear(x, params["lm_head"]), new_cache
-
-
-def forward_decode_spec_paged(
-    params: dict,
-    tokens: jnp.ndarray,  # (B, L) per-row tokens
-    pos,  # (B, L) int32 absolute position per row
-    cache: list,  # paged cache (init_cache_paged)
-    page_tables: jnp.ndarray,  # (B, pages_per_slot) int32
-    write_pages: jnp.ndarray,  # (B, L) int32; trash page for invalid rows
-    cfg: ModelConfig,
-    rope_len: int = 0,
-    batched: bool = False,
-) -> Tuple[jnp.ndarray, list]:
-    """Paged twin of :func:`forward_decode_spec`: every verify row's
-    K/V lands in the physical page ``write_pages[b, l]`` names (the
-    trash page for rows past the slot's draft length), and each row
-    attends THROUGH the same runtime page tables as the L=1 step — so
-    draft lengths, page churn and COW forks between calls compile
-    nothing new. EXACT mode unrolls L ``forward_decode_pool_paged``
-    sub-steps (bit-identical to the engine's paged L=1 step at any
-    size); batched mode streams each slot's pages ONCE for all L rows
-    through the scalar-prefetch multi-query kernel
-    (``decode_attention_multi_paged``)."""
-    B, L = tokens.shape
-    pos = jnp.asarray(pos, jnp.int32)
-    if not batched:
-        rows = []
-        for l in range(L):
-            lg, cache = forward_decode_pool_paged(
-                params, tokens[:, l], pos[:, l], cache, page_tables,
-                write_pages[:, l], cfg, rope_len=rope_len,
-            )
-            rows.append(lg.astype(jnp.float32))
-        return jnp.stack(rows, axis=1), cache
-    M = cfg.block_size
-    compute = jnp.dtype(cfg.compute_dtype)
-    x = params["tok_emb"][tokens].astype(compute)  # (B, L, E)
-    cos = sin = None
-    if cfg.model == "diff":
-        x = x + params["pos_emb"][pos].astype(compute)
-    else:
-        cos_full, sin_full = rope_cos_sin(
-            cfg.head_size, max(int(rope_len), M)
-        )
-        cos = cos_full[pos]
-        sin = sin_full[pos]
-    new_cache = []
-    for li, blk in enumerate(params["blocks"], 1):  # 1-based schedule
-        with jax.named_scope("attn_norm"):
-            h = common.apply_pre_norm(x, blk["ln1"], cfg)
-        with jax.named_scope("attn"):
-            a, layer_cache = _pool_attn_spec(
-                h, blk["attn"], cache[li - 1], pos, write_pages,
-                page_tables, li, cfg, cos, sin,
-            )
-        x = common.apply_block_ffn(x, a, blk, cfg)
-        new_cache.append(layer_cache)
-    with jax.named_scope("lm_head"):
-        x = common.apply_pre_norm(x, params["ln_f"], cfg)
-        return common.linear(x, params["lm_head"]), new_cache
 
 
 @partial(
@@ -1527,20 +1186,12 @@ def generate_cached(
         cache, samples, rng = carry
         rng, key = jax.random.split(rng)
         prev = samples[:, i - 1]
-        if cfg.decode_attention_impl == "pallas":
-            # fused pool step: all B rows share the position here, but
-            # the kernel path is the same one the serving engine runs
-            # with per-row positions
-            last, cache = forward_decode_pool(
-                params, prev, jnp.full((B,), Tc + i - 1, jnp.int32),
-                cache, cfg, rope_len=total,
-            )
-        else:
-            logits, cache = forward_chunk(
-                params, prev[:, None], Tc + i - 1, cache, cfg,
-                rope_len=total,
-            )
-            last = logits[:, -1, :]
+        # all B rows share the position here, but the step is the one the
+        # serving engine runs with per-row positions
+        last, cache = forward_decode_pool(
+            params, prev, jnp.full((B,), Tc + i - 1, jnp.int32),
+            cache, cfg, rope_len=total,
+        )
         nxt = sample_token(
             key, last.astype(jnp.float32), temperature, top_k
         ).astype(samples.dtype)

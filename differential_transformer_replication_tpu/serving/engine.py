@@ -42,12 +42,16 @@ recompilation as requests come and go:
   default paths are bit-identical to ``sample_token`` — pinned by
   tests/test_serving.py.
 
-Mixed per-slot positions ride a ``jax.vmap`` over the rows for what is
-about a row's own ring, its Q/K/V and its attend, each a length-1
-``forward_chunk``'s (models/decode.py:``forward_decode_rows``: each row
+The decode step is models/decode.py:``forward_decode_pool``, the one
+L = 1 entry point for both pool layouts and both attention impls (a
+verify block is ``forward_decode_spec``, which unrolls or batches the
+same program): this module never chooses between step functions. Mixed
+per-slot positions ride a ``jax.vmap`` over the rows for what is about a
+row's own position, its Q/K/V and (on the slot pool under XLA) its
+attend over its own ring, each a length-1 ``forward_chunk``'s (each row
 carries its own ``pos`` scalar, exactly the traced-position path the
-chunked decoder already supports); the norms, the FFN half and the head
-run once over the rows as one batch. ``forward_chunk``'s
+chunked decoder already supports); the write, the norms, the FFN half
+and the head run once over the rows as one batch. ``forward_chunk``'s
 concrete-position validity guards are enforced host-side at submit
 instead. Per-request determinism: the key for the t-th generated token
 is ``fold_in(PRNGKey(seed), t)``, a pure function of the request — not
@@ -81,10 +85,7 @@ from differential_transformer_replication_tpu.models.decode import (
     copy_cache_pages,
     forward_chunk,
     forward_decode_pool,
-    forward_decode_pool_paged,
-    forward_decode_rows,
     forward_decode_spec,
-    forward_decode_spec_paged,
     has_recurrent_state,
     gather_slot_cache,
     init_cache,
@@ -303,7 +304,10 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
     runtime int32 arrays, so page allocation/free/share/fork between
     calls compiles nothing new — the same zero-recompile pin as the
     contiguous path. ``page_copy`` is the COW-fork device copy (None on
-    the contiguous path).
+    the contiguous path). Either decode closure is
+    ``forward_decode_pool`` with the arguments its layout has; the
+    layout's write and the attention impl are bound inside it
+    (models/decode.py:``_pool_seam``), nowhere in this module.
 
     ``quality`` (a static, like lp_k) appends the in-jit quality
     telemetry tail (models/decode.py:``quality_vector``) to the
@@ -317,13 +321,13 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
                           write_pages):
             """One batched length-1 step over the whole slot pool
             THROUGH the page tables (models/decode.py
-            ``forward_decode_pool_paged``): both attention impls
-            dispatch inside; inactive rows' writes are redirected to
-            the trash page by ``write_pages`` (the contiguous path
-            gives them no write target instead)."""
-            logits, new_cache = forward_decode_pool_paged(
-                params, tokens, pos, cache, page_tables, write_pages,
-                cfg, rope_len=rope_len,
+            ``forward_decode_pool``, as ``_decode`` below): inactive
+            rows' writes are redirected to the trash page by
+            ``write_pages`` (the contiguous path gives them no write
+            target instead)."""
+            logits, new_cache = forward_decode_pool(
+                params, tokens, pos, cache, cfg, rope_len=rope_len,
+                page_tables=page_tables, write_pages=write_pages,
             )
             return logits.astype(jnp.float32), new_cache
 
@@ -377,11 +381,12 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
 
     def _decode(params, tokens, pos, active, cache):
         """One batched length-1 step over the WHOLE slot pool:
-        models/decode.py ``forward_decode_rows`` (a row's Q/K/V and
-        attend a length-1 forward_chunk's under vmap, everything else
-        one batch over the rows) or, with ``decode_attention_impl:
-        pallas``, ``forward_decode_pool`` (the fused decode-attention
-        kernel over every row in one (B*H,)-grid call per layer).
+        models/decode.py ``forward_decode_pool``, the one L = 1 entry
+        point (a row's Q/K/V a length-1 forward_chunk's under vmap,
+        everything else one batch over the rows). Which attention a row
+        runs (its own ring under that vmap or, with
+        ``decode_attention_impl: pallas``, the fused kernel over every
+        row in one (B*H,)-grid call per layer) is bound there, not here.
 
         tokens/pos/active: (B,) runtime arrays. Inactive rows run the
         same math on garbage inputs (static shapes are the point); the
@@ -389,10 +394,8 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
         slot's ring is left as it is, and the step's cache traffic is
         the rows it writes, in place in the donated pool.
         """
-        step = (forward_decode_pool if cfg.decode_attention_impl == "pallas"
-                else forward_decode_rows)
-        logits, new_cache = step(params, tokens, pos, cache, cfg,
-                                 rope_len=rope_len, active=active)
+        logits, new_cache = forward_decode_pool(
+            params, tokens, pos, cache, cfg, rope_len=rope_len, active=active)
         return logits.astype(jnp.float32), new_cache
 
     def _prefill(params, cache, slot, tokens, pos, valid=None):
@@ -608,14 +611,16 @@ def _build_spec_step_fns(cfg: ModelConfig, rope_len: int, draft_len: int,
                          page_size: int = 0, num_pages: int = 0,
                          lp_k: int = 5, quality: bool = False):
     """ONE fused jitted verify step for (cfg, rope_len, k rung): the
-    L = k+1-row pool forward (models/decode.py:``forward_decode_spec``
-    or its paged twin), the per-row sampling transforms, and the
+    L = k+1-row pool forward (models/decode.py:``forward_decode_spec``,
+    on either pool layout), the per-row sampling transforms, and the
     accept/reject decision — all under ``lax`` ops with k static.
     Per-slot draft lengths, the reject-storm fault flag, page tables
     and write targets ride as RUNTIME arrays, so mixed spec/non-spec
     traffic and varying per-request draft lengths compile NOTHING
     beyond this one rung (the engine's k ladder is {0 = the plain
-    decode step, spec_draft_len = this}).
+    decode step, spec_draft_len = this}). ``page_size`` and
+    ``num_pages`` only key the cache, as in ``_build_step_fns``: the
+    closure learns its layout from whether it is passed page tables.
 
     Acceptance semantics (Leviathan et al. 2023, one-hot drafter):
     greedy rows (temperature <= 0) accept draft j iff it equals row
@@ -852,33 +857,15 @@ def _build_spec_step_fns(cfg: ModelConfig, rope_len: int, draft_len: int,
             ))
         return jnp.concatenate(cols, axis=1)
 
-    if page_size > 0:
-
-        def _spec_step(params, ints, cache, page_tables, allowed,
-                       pcounts):
-            (tokens, pos, write_pages, draft, dlen, counts, topks,
-             bases, temps, force_reject, pens) = _unpack(ints)
-            logits, new_cache = forward_decode_spec_paged(
-                params, tokens, pos, cache, page_tables, write_pages,
-                cfg, rope_len=rope_len, batched=batched,
-            )
-            with jax.named_scope("sampler"):
-                out = _accept(
-                    logits.astype(jnp.float32), draft, dlen, force_reject,
-                    bases, counts, temps, topks,
-                    pens[:, 0], pens[:, 1], pens[:, 2], allowed, pcounts,
-                    tokens[:, 0],
-                )
-                return _pack_out(*out), new_cache
-
-        return jax.jit(_spec_step, donate_argnums=(2,))
-
-    def _spec_step(params, ints, cache, allowed, pcounts):
-        (tokens, pos, row_target, draft, dlen, counts, topks,
+    def _spec_step(params, ints, cache, allowed, pcounts, page_tables=None):
+        """``targets`` are cache rows on the contiguous pool and, with
+        ``page_tables`` (the paged engine passes them last), physical
+        write pages."""
+        (tokens, pos, targets, draft, dlen, counts, topks,
          bases, temps, force_reject, pens) = _unpack(ints)
         logits, new_cache = forward_decode_spec(
-            params, tokens, pos, cache, cfg, row_target,
-            rope_len=rope_len, batched=batched,
+            params, tokens, pos, cache, cfg, targets,
+            rope_len=rope_len, batched=batched, page_tables=page_tables,
         )
         with jax.named_scope("sampler"):
             out = _accept(
@@ -2037,16 +2024,11 @@ class ServingEngine:
             if tids:
                 decode_args["trace_ids"] = tids
         with self.tracer.span("decode", **decode_args):
-            if self._pages is not None:
-                out, self.cache = spec_fn(
-                    self.params, jnp.asarray(ints), self.cache,
-                    jnp.asarray(tables), allowed3, pcounts,
-                )
-            else:
-                out, self.cache = spec_fn(
-                    self.params, jnp.asarray(ints), self.cache,
-                    allowed3, pcounts,
-                )
+            out, self.cache = spec_fn(
+                self.params, jnp.asarray(ints), self.cache, allowed3,
+                pcounts,
+                None if self._pages is None else jnp.asarray(tables),
+            )
         # one transfer for all three host-consumed outputs
         out = np.asarray(out)
         toks = out[:, :L]

@@ -1,24 +1,35 @@
-"""The XLA decode step runs what is about no ring once over the pool's
-rows (PR 29).
+"""One decode step (PR 30), and what runs under the rows' vmap (PR 29).
 
-Until PR 29 ``forward_decode_rows`` kept a whole layer a length-1
-``forward_chunk`` a row under ``jax.vmap``. With ``ffn_impl: pallas``
-the vmap prepends the rows to each kernel's grid: at the recipe's widths
-and 256 rows the fused FFN kernel ran at ``grid=(256, 6, 1)``, a
-one-row matmul a grid step, 12,288 steps a decode step. Now only the
-row's own ring stays under the vmap (``_chunk_qkv``, ``_chunk_attend``);
-the norms, the attention's output projection, the FFN half and the head
-take ``(B, 1, 1, E)`` through their own ``reshape(-1, E)`` as M = B.
+``models/decode.py:_decode_step`` is the K/V families' one decode
+program: ``forward_decode_pool`` (the L = 1 entry point) and
+``forward_decode_spec`` (the verify entry point) bind a pool layout and
+an attention to it through ``_pool_seam``'s ``write`` and ``attend``.
+Until PR 29 the XLA step kept a whole layer a length-1 ``forward_chunk``
+a row under ``jax.vmap``. With ``ffn_impl: pallas`` the vmap prepends the
+rows to each kernel's grid: at the recipe's widths and 256 rows the fused
+FFN kernel ran at ``grid=(256, 6, 1)``, a one-row matmul a grid step,
+12,288 steps a decode step. Now only what is about a row's own position
+stays under the vmap (``_chunk_qkv``, and ``_chunk_attend`` where a row
+reads its own ring); the norms, the attention's output projection, the
+FFN half and the head take ``(N, 1, 1, E)`` through their own
+``reshape(-1, E)`` as M = N.
 
 The first test pins that in the jaxpr, abstractly, at the serve cell's
 size. The others hold the step to a length-1 ``forward_chunk`` a row, each
 run as a program of its OWN at B = 1 (the oracle of
 tests/test_decode_write.py vmaps the rows instead, and XLA batches a
 vmapped matmul against shared weights into one of M = B: it stays equal
-bit for bit, before and after this PR). Against a real M = 1 program
+bit for bit, before and after PR 29). Against a real M = 1 program
 float32 reassociates in the last bits, at any width, as it did before the
-lift; bfloat16 rounds to the same values but for an odd last place.
+lift; bfloat16 rounds to the same values but for an odd last place. The
+seam's cases run every binding of ``write`` and ``attend`` (family x pool
+layout x rows a slot x attention) against that oracle, and the last test
+pins that the step stays written once.
 """
+
+import ast
+from functools import lru_cache
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -35,8 +46,12 @@ from differential_transformer_replication_tpu.models import init_model
 from differential_transformer_replication_tpu.models.decode import (
     KV_CACHE_BATCH_AXIS,
     forward_chunk,
+    forward_decode_pool,
     forward_decode_spec,
+    gather_slot_cache,
     init_cache,
+    init_cache_paged,
+    scatter_slot_cache,
 )
 from differential_transformer_replication_tpu.serving.engine import (
     _build_step_fns,
@@ -205,3 +220,206 @@ def test_exact_verify_sub_step_is_a_plain_step_bit_for_bit(ffn, dtype):
         for key in want:
             np.testing.assert_array_equal(_f32(have[key]), _f32(want[key]),
                                           err_msg=key)
+
+
+# ---------------------------------------------------------------------------
+# The seam: every binding of ``write`` and ``attend`` against the oracle
+# ---------------------------------------------------------------------------
+
+DEPTH = 3  # rows a slot in a verify block: the last token and two drafts
+PAGE = 8
+# a slot's rows start here; slot 0's cross a page boundary (6, 7 | 8)
+STARTS = [6, 9, 28, 0, 17]
+# draft tokens a slot verifies; -1: the slot does not run at all (free, or
+# in mid-prefill) and must keep its ring. The plain step runs row 0 alone.
+DRAFTED = [2, -1, 1, 2, 0]
+
+
+def _seam_cfg(family, attention="xla"):
+    return ModelConfig(
+        model=family, vocab_size=61, n_embd=64, n_head=2, n_layer=2,
+        block_size=32, dropout=0.0, n_terms=3, compute_dtype="float32",
+        decode_attention_impl=attention,
+    )
+
+
+@lru_cache(maxsize=None)
+def _seam_params(family):
+    return init_model(jax.random.PRNGKey(0), _seam_cfg(family))
+
+
+def _seam_inputs(cfg):
+    rng = np.random.default_rng(11)
+    tokens = rng.integers(0, cfg.vocab_size, (len(STARTS), DEPTH))
+    pos = np.asarray(STARTS)[:, None] + np.arange(DEPTH)
+    return tokens.astype(np.int32), pos.astype(np.int32)
+
+
+@lru_cache(maxsize=None)
+def _chunk_a_row(family):
+    """The oracle: slot b's rows one after another, each a length-1
+    ``forward_chunk`` of its own (B = 1) on the slot's ring. ``[b][l]``
+    is ``(row l's logits, the slot's ring after rows 0..l)``."""
+    cfg, params = _seam_cfg(family), _seam_params(family)
+    tokens, pos = _seam_inputs(cfg)
+    pool = _random_pool(cfg, len(STARTS), 13)
+    chunk = jax.jit(lambda t, at, row: forward_chunk(
+        params, t, at, row, cfg, rope_len=cfg.block_size))
+    out = []
+    for b in range(len(STARTS)):
+        ring, rows = _slot(pool, b), []
+        for l in range(DEPTH):
+            lg, ring = chunk(tokens[b, l][None, None], pos[b, l], ring)
+            rows.append((np.asarray(lg[0, -1], np.float32), ring))
+        out.append(rows)
+    return out
+
+
+def _paged(cfg, pool, tables):
+    """``pool``'s rings laid out in pages: slot b's logical page j is
+    physical page ``tables[b, j]``; page 0 is the trash page."""
+    paged = init_cache_paged(cfg, 1 + tables.size, PAGE)
+    for b in range(tables.shape[0]):
+        paged = scatter_slot_cache(paged, _slot(pool, b), tables[b])
+    return paged
+
+
+def _with_trash_row(cfg, pool):
+    """``pool`` and one more row past its slots, which holds anything."""
+    extra = _random_pool(cfg, 1, 17)
+    return [{key: jnp.concatenate([c[key], e[key]],
+                                  axis=KV_CACHE_BATCH_AXIS[key])
+             for key in c} for c, e in zip(pool, extra)]
+
+
+@pytest.mark.parametrize("attention", ["xla", "pallas"])
+@pytest.mark.parametrize("rows", ["step", "exact", "batched"])
+@pytest.mark.parametrize("layout", ["slots", "pages"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_every_binding_of_the_seam_matches_a_length_1_chunk_a_row(
+        family, layout, rows, attention):
+    """``step``: the plain L = 1 step; ``exact`` and ``batched``: a verify
+    block of ``DEPTH`` rows a slot in either formulation. A row that runs
+    equals the oracle's (greedy token, logits, the K/V it wrote); a row
+    that does not leaves no trace (its slot's ring bit for bit). An exact
+    verify is, besides, the plain step a row at a time, bit for bit."""
+    cfg, params = _seam_cfg(family, attention), _seam_params(family)
+    slots, M = len(STARTS), cfg.block_size
+    tokens, pos = _seam_inputs(cfg)
+    depth = 1 if rows == "step" else DEPTH
+    tokens, pos = tokens[:, :depth], pos[:, :depth]
+    runs = np.arange(depth)[None, :] <= np.asarray(DRAFTED)[:, None]
+    before = _random_pool(cfg, slots, 13)
+
+    if layout == "pages":
+        # physical pages in no order, so that a gather by the table shows
+        tables = 1 + np.random.default_rng(5).permutation(
+            slots * (M // PAGE)).reshape(slots, M // PAGE).astype(np.int32)
+        pool, trash = _paged(cfg, before, tables), 0
+        where = np.where(
+            runs, np.take_along_axis(tables, pos % M // PAGE, axis=1), 0)
+        paging = dict(page_tables=jnp.asarray(tables))
+    else:
+        # a verify's slot pool carries a trash row past the slots
+        trash = int(depth > 1)
+        pool = _with_trash_row(cfg, before) if trash else before
+        where = np.where(runs, np.arange(slots)[:, None], slots)
+        paging = {}
+    where = jnp.asarray(where, jnp.int32)
+
+    def ring_of(pool, b):
+        if layout == "pages":
+            return gather_slot_cache(pool, tables[b])
+        return _slot(pool, b)
+
+    @jax.jit
+    def step(tokens, pos, pool, how):
+        return forward_decode_pool(params, tokens, pos, pool, cfg,
+                                   rope_len=M, **paging, **how)
+
+    def column(l):
+        """What the L = 1 entry point takes for column ``l`` of a block:
+        the slot pool's trash row is a row that does not run."""
+        if layout == "pages":
+            how = dict(write_pages=where[:, l])
+        else:
+            how = dict(active=jnp.pad(jnp.asarray(runs[:, l]), (0, trash)))
+        return (jnp.pad(tokens[:, l], (0, trash)),
+                jnp.pad(pos[:, l], (0, trash)), how)
+
+    if rows == "step":
+        t, at, how = column(0)
+        logits, after = step(t, at, pool, how)
+        logits = logits[:, None]
+    else:
+        logits, after = jax.jit(
+            lambda t, at, pool, tgt: forward_decode_spec(
+                params, t, at, pool, cfg, tgt, rope_len=M,
+                batched=rows == "batched", **paging)
+        )(tokens, pos, pool, where)
+    assert logits.shape == (slots, depth, cfg.vocab_size)
+    assert logits.dtype == jnp.float32
+
+    tol = TOLERANCE["float32"]
+    for b, oracle in enumerate(_chunk_a_row(family)):
+        ran = min(DRAFTED[b] + 1, depth)
+        want_ring = oracle[ran - 1][1] if ran else _slot(before, b)
+        for have, want in zip(ring_of(after, b), want_ring):
+            for key in want:
+                # a slot that did not run keeps its ring bit for bit
+                np.testing.assert_allclose(
+                    _f32(have[key]), _f32(want[key]), rtol=0,
+                    atol=tol if ran else 0, err_msg=key)
+        for l in range(ran):
+            have, want = np.asarray(logits[b, l]), oracle[l][0]
+            assert int(np.argmax(have)) == int(np.argmax(want))
+            np.testing.assert_allclose(have, want, rtol=0, atol=tol)
+
+    if rows == "exact":
+        for l in range(depth):
+            t, at, how = column(l)
+            lg, pool = step(t, at, pool, how)
+            np.testing.assert_array_equal(np.asarray(logits[:, l]),
+                                          np.asarray(lg[:slots]))
+        for have, want in zip(after, pool):
+            for key in want:
+                np.testing.assert_array_equal(
+                    _f32(have[key]), _f32(want[key]), err_msg=key)
+
+
+PACKAGE = Path(__file__).resolve().parents[1] / (
+    "differential_transformer_replication_tpu")
+
+
+def _function_of(tree, node):
+    """The name of the innermost function that holds ``node``."""
+    holders = [fn for fn in ast.walk(tree)
+               if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef))
+               and fn.lineno <= node.lineno <= fn.end_lineno]
+    return max(holders, key=lambda fn: fn.lineno).name if holders else None
+
+
+def test_the_decode_step_stays_written_once():
+    """The fork PR 30 closed cannot grow back unseen: in the package,
+    outside ``config.py`` (which validates the option), ONE comparison
+    reads ``decode_attention_impl``, in ``models/decode.py:_pool_seam``;
+    and ``models/decode.py`` walks ``enumerate(params["blocks"], 1)`` in
+    two functions, ``forward_chunk`` and the one decode step (the jamba
+    family's loops zip its two kinds of layer and are not counted)."""
+    compares, loops = [], []
+    for path in sorted(PACKAGE.rglob("*.py")):
+        if path.name == "config.py":
+            continue
+        tree = ast.parse(path.read_text())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Compare) and any(
+                    isinstance(side, ast.Attribute)
+                    and side.attr == "decode_attention_impl"
+                    for side in [node.left, *node.comparators]):
+                compares.append((path.name, _function_of(tree, node)))
+            if (path.name == "decode.py" and isinstance(node, ast.For)
+                    and ast.unparse(node.iter).startswith(
+                        "enumerate(params['blocks']")):
+                loops.append(_function_of(tree, node))
+    assert compares == [("decode.py", "_pool_seam")]
+    assert sorted(loops) == ["_decode_step", "forward_chunk"]

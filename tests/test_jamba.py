@@ -227,7 +227,7 @@ def test_ladder_prefill_then_pool_decode_matches_the_full_forward(
     cache = [{k: (v.at[2].set(0.5) if k in decode.STATE_LEAVES else v)
               for k, v in layer.items()} for layer in cache]
     active = jnp.asarray([True, True, False])
-    step = jax.jit(lambda t, p, c: decode.forward_decode_rows(
+    step = jax.jit(lambda t, p, c: decode.forward_decode_pool(
         params, t, p, c, cfg, active=active))
     for t in range(pos, idx.shape[1]):
         lg, cache = step(idx[:, t], jnp.full((3,), t), cache)
@@ -269,7 +269,7 @@ def test_a_padded_tail_chunk_is_the_tail(params, tokens, full_logits, impl):
                                            rtol=1e-5)
     outs = []
     for t in range(85, 100):
-        step, cache = decode.forward_decode_rows(
+        step, cache = decode.forward_decode_pool(
             params, tokens[:, t], jnp.full((2,), t), cache, cfg)
         outs.append(step[:, None])
     np.testing.assert_allclose(jnp.concatenate(outs, 1), full_logits[:, 85:],
