@@ -19,6 +19,11 @@ made from a seed:
   path within the bf16 tolerance below.
 - *sync*: windows of ten train steps closed by ``jax.block_until_ready``
   and by a scalar read-back, timed side by side.
+- *kv_write*: the decode step's K/V write kernel (``ops/kv_write.py``)
+  alone, compiled, at the serve cells' leaf shapes, over the patterns of
+  slots that write and slots that keep their row, against NumPy bit for
+  bit. A kept slot's grid step leans on what the chip's pipeline keeps in
+  VMEM between steps, which the interpreter the tests run cannot show.
 - *serve*: ``python -m ...serving.server --checkpoint <the one train wrote>``
   once per decode path (XLA and the Pallas decode kernel; contiguous and
   paged; bf16 and int8 KV; the multi-query kernel under ``--spec-mode``).
@@ -68,6 +73,7 @@ FULL = {
     # serve: at least one prompt >= 256 tokens; chunk sizes 128/64/16
     "prompt_lens": (320, 64, 16), "new_tokens": 64, "num_slots": 8,
     "page_size": 8,  # smallest the verify skill and serve_bench use
+    "kv_slots": 256,  # kv_write: the serve cells' pool
     # --multichip: the batch is per optimizer step, before the DP split
     "dp": 4, "dp_steps": 8,
 }
@@ -541,8 +547,107 @@ def jamba_phase(work: Path, size: dict, require_device) -> dict:
             "worst_logit_gap": worst}
 
 
+def kv_write_leaves(slots: int) -> list:
+    """(name, leaf shape, pool axis, dtype) of the pools the serve cells
+    hold: the diff recipe's K and V (ring on the lanes), an int8 K with
+    its scale plane, and the jamba cell's K (a head of 128: ring on the
+    sublanes)."""
+    return [
+        ("diff-k", (2, slots, 4, 512, 96), 1, "bfloat16"),
+        ("diff-v", (slots, 4, 512, 192), 0, "bfloat16"),
+        ("diff-k-int8", (2, slots, 4, 512, 96), 1, "int8"),
+        ("diff-k-scale", (2, slots, 4, 512), 1, "float32"),
+        ("jamba-k", (1, slots, 1, 2048, 128), 1, "bfloat16"),
+    ]
+
+
+def kv_write_patterns(slots: int, M: int, rng) -> list:
+    """(name, targets) over ``slots`` slots of a ring of ``M``: -1 keeps
+    a slot's row. Every place a kept grid step can stand in: nowhere,
+    everywhere, before the first writer, between writers, behind the
+    last; and the chat cell's one slot in eight."""
+    def at(writers):
+        targets = [-1] * slots
+        for b in writers:
+            targets[b] = int(rng.integers(0, M))
+        return targets
+
+    same = [-1] * slots
+    same[slots // 3], same[2 * slots // 3] = M // 2 + 1, M // 2 + 2
+    return [
+        ("none", at([])),
+        ("last-only", at([slots - 1])),
+        ("first-only", at([0])),
+        ("kept-then-writers", at(range(slots // 3, slots, 2))),
+        ("all", at(range(slots))),
+        ("same-block", same),
+        ("one-in-eight", at(sorted(rng.choice(
+            slots, size=max(1, slots // 8), replace=False).tolist()))),
+    ]
+
+
+def kv_write_phase(work: Path, size: dict, require_device) -> dict:
+    """``write_rows`` compiled, a leaf and a pattern at a time, on the
+    pool that the call before it gave back (donated, as the engine's
+    decode program donates it): the result is the leaf's own buffer, and
+    holds NumPy's answer to the bit."""
+    del work
+    device = require_device("chip_smoke kv_write")
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from differential_transformer_replication_tpu.ops.kv_write import (
+        write_rows,
+    )
+
+    rng = np.random.default_rng(SEED)
+    on_chip = device["platform"] == "tpu"
+
+    def draw(shape, dtype):
+        if dtype == "int8":
+            return rng.integers(-127, 128, shape, dtype=np.int8)
+        return rng.standard_normal(shape, dtype=np.float32)
+
+    def bits(a):
+        a = np.asarray(a)
+        return a.view({1: np.uint8, 2: np.uint16, 4: np.uint32}[a.itemsize])
+
+    checked = {}
+    for name, shape, axis, dtype in kv_write_leaves(size["kv_slots"]):
+        M = shape[axis + 2]
+        write = jax.jit(lambda l, r, t, axis=axis: write_rows(l, r, t, axis),
+                        donate_argnums=0)
+        leaf = jnp.asarray(draw(shape, dtype), dtype)
+        want = np.array(leaf)
+        for pattern, targets in kv_write_patterns(shape[axis], M, rng):
+            rows = jnp.asarray(
+                draw(shape[:axis + 2] + shape[axis + 3:], dtype), dtype)
+            rows_np = np.asarray(rows)
+            for b, t in enumerate(targets):
+                if t >= 0:
+                    lead = (slice(None),) * axis + (b,)
+                    want[lead + (slice(None), t)] = rows_np[lead]
+            given, held = leaf, leaf.unsafe_buffer_pointer()
+            leaf = write(given, rows, jnp.asarray(targets, jnp.int32))
+            leaf.block_until_ready()
+            check(given.is_deleted(),
+                  f"{name} {pattern}: the donated leaf is still alive")
+            # the CPU's interpreter builds its result elsewhere
+            check(not on_chip or leaf.unsafe_buffer_pointer() == held,
+                  f"{name} {pattern}: the result is not the donated buffer")
+            wrong = int((bits(leaf) != bits(want)).sum())
+            check(wrong == 0, f"{name} {pattern}: {wrong} values differ from "
+                              f"NumPy's ({sum(t >= 0 for t in targets)} of "
+                              f"{len(targets)} slots write)")
+            checked.setdefault(name, []).append(pattern)
+        say("kv_write", f"{name} {'x'.join(map(str, shape))} {dtype}: bit for "
+                        f"bit over {', '.join(checked[name])}")
+    return {"device": device, "checked": checked}
+
+
 CHILD_PHASES = {"train": train_phases, "multichip": multichip_phase,
-                "jamba": jamba_phase}
+                "jamba": jamba_phase, "kv_write": kv_write_phase}
 
 
 # ---------------------------------------------------------------------------
@@ -782,6 +887,7 @@ def main(argv=None, *, run_child=run_child, serve_phase=serve_phase,
         else:
             device = run_child("train", work)["device"]
             run_child("jamba", work)
+            run_child("kv_write", work)
             serve_phase(work, size, device)
     except SmokeFailure as e:
         print(f"[smoke] FAILED: {e}", flush=True)

@@ -850,7 +850,12 @@ def _write_ring(layer_cache: dict, rows: dict, targets: jnp.ndarray) -> dict:
     own ring at ``targets[b]`` (:func:`_write_targets`), in place in the
     donated pool, one ``ops/kv_write.py`` kernel a leaf: the float and
     the int8 leaves and the scale planes take the same route, addressed
-    through ``KV_CACHE_BATCH_AXIS``."""
+    through ``KV_CACHE_BATCH_AXIS``. A row with no target costs the
+    kernel no traffic: its grid step points at the block of its
+    ``owner`` (``ops/kv_write.py:slot_owners``, made from ``targets``
+    inside ``write_rows``: the nearest slot before it that writes),
+    which the chip neither fetches nor writes back a second time, so a
+    leaf's write moves a block for each ACTIVE row, not for each slot."""
     return {
         key: write_rows(leaf, rows[key], targets, KV_CACHE_BATCH_AXIS[key])
         for key, leaf in layer_cache.items()

@@ -50,7 +50,7 @@ def test_last_line_has_exactly_the_contract_keys(capsys):
         [], capsys, run_child=run_child,
         serve_phase=lambda work, size, device: phases.append("serve"),
     )
-    assert rc == 0 and phases == ["train", "jamba", "serve"]
+    assert rc == 0 and phases == ["train", "jamba", "kv_write", "serve"]
     last = json.loads(lines[-1])
     assert last == {"ok": True, "device": V5E}
     assert list(last) == ["ok", "device"]
@@ -72,6 +72,39 @@ def test_a_failed_phase_is_a_nonzero_exit_and_no_result(capsys, failing):
     assert rc == 1
     assert not any('"ok"' in line for line in lines)
     assert "FAILED" in lines[-1]
+
+
+def test_kv_write_phase_rehearsed_at_six_slots(tmp_path, capsys):
+    """The phase's own control flow, through the interpreter: every
+    leaf kind and every pattern of writing slots against NumPy, on the
+    pool the call before gave back. What it is FOR (a kept grid step on
+    the chip's pipeline) only the chip shows."""
+    cpu = {"platform": "cpu", "kind": "cpu", "count": 1}
+    got = chip_smoke.kv_write_phase(
+        tmp_path, dict(chip_smoke.FULL, kv_slots=6), lambda what: cpu)
+    leaves = chip_smoke.kv_write_leaves(6)
+    assert got["device"] == cpu
+    assert sorted(got["checked"]) == sorted(name for name, *_ in leaves)
+    assert all(len(patterns) == 7 for patterns in got["checked"].values())
+    assert {shape[axis] for _, shape, axis, _ in leaves} == {6}
+    assert capsys.readouterr().out.count("bit for bit") == len(leaves)
+
+
+def test_kv_write_patterns_cover_every_place_a_kept_step_can_stand():
+    import numpy as np
+
+    got = dict(chip_smoke.kv_write_patterns(
+        256, 512, np.random.default_rng(0)))
+    writers = {k: [b for b, t in enumerate(v) if t >= 0]
+               for k, v in got.items()}
+    assert all(len(v) == 256 and max(v) < 512 for v in got.values())
+    assert writers["none"] == [] and writers["all"] == list(range(256))
+    assert writers["last-only"] == [255] and writers["first-only"] == [0]
+    assert writers["kept-then-writers"][0] == 85
+    assert len(writers["one-in-eight"]) == 32
+    a, b = writers["same-block"]
+    assert a != b and (got["same-block"][a] // 128
+                       == got["same-block"][b] // 128)
 
 
 def test_multichip_runs_that_phase_alone_and_reports_four(capsys):

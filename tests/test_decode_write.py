@@ -23,7 +23,10 @@ from differential_transformer_replication_tpu.models.decode import (
     forward_decode_pool,
     init_cache,
 )
-from differential_transformer_replication_tpu.ops.kv_write import write_rows
+from differential_transformer_replication_tpu.ops.kv_write import (
+    slot_owners,
+    write_rows,
+)
 from differential_transformer_replication_tpu.serving.engine import (
     _build_step_fns,
 )
@@ -156,11 +159,29 @@ LEAVES = [
 ]
 
 
+# ring positions of five slots; -1 keeps the slot's row, M is the leaf's
+# ring length. The kernel's grid step of a kept slot points at its
+# owner's block and moves nothing (ops/kv_write.py), so the patterns are
+# the places a kept step can stand in: nowhere, everywhere, before the
+# first writer, between writers, behind the last
+TARGETS = {
+    "mixed": lambda M: [3, -1, M - 1, 0, -1],
+    "none": lambda M: [-1, -1, -1, -1, -1],
+    "last-only": lambda M: [-1, -1, -1, -1, 5],
+    "first-only": lambda M: [M - 2, -1, -1, -1, -1],
+    "kept-then-writers": lambda M: [-1, -1, 7, -1, M - 1],
+    "all": lambda M: [0, M - 1, 9, M // 2, 1],
+    # one 128-block (and one sublane tile) index, different slots
+    "same-block": lambda M: [-1, 2, -1, 5, -1],
+}
+
+
+@pytest.mark.parametrize("pattern", sorted(TARGETS))
 @pytest.mark.parametrize("shape,axis,dtype", LEAVES,
                          ids=[f"{'x'.join(map(str, s))}-{jnp.dtype(d).name}"
                               for s, _, d in LEAVES])
 def test_write_rows_puts_one_position_a_slot_and_keeps_the_rest(
-        shape, axis, dtype):
+        shape, axis, dtype, pattern):
     rng = np.random.default_rng(0)
 
     def draw(shp):
@@ -171,7 +192,7 @@ def test_write_rows_puts_one_position_a_slot_and_keeps_the_rest(
     M = shape[axis + 2]
     leaf = draw(shape)
     rows = draw(shape[:axis + 2] + shape[axis + 3:])
-    targets = [3, -1, M - 1, 0, -1]
+    targets = TARGETS[pattern](M)
     want = np.array(leaf.astype(jnp.float32))
     for b, t in enumerate(targets):
         if t >= 0:
@@ -182,3 +203,25 @@ def test_write_rows_puts_one_position_a_slot_and_keeps_the_rest(
         leaf, rows, jnp.asarray(targets, jnp.int32))
     assert got.dtype == leaf.dtype and got.shape == leaf.shape
     np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)), want)
+
+
+@pytest.mark.parametrize("targets,owners", [
+    # a writer owns itself; a kept slot rides on the writer before it
+    ([3, -1, 31, 0, -1], [0, 0, 2, 3, 3]),
+    # before the first writer: the first writer, whose fetch is needed
+    ([-1, -1, 7, -1, 31], [2, 2, 2, 2, 4]),
+    ([-1, -1, -1, -1, 5], [4, 4, 4, 4, 4]),
+    ([30, -1, -1, -1, -1], [0, 0, 0, 0, 0]),
+    # nobody writes: one block, slot 0's, read once and put back
+    ([-1, -1, -1, -1, -1], [0, 0, 0, 0, 0]),
+    # everybody writes: the identity, the kernel as it was
+    ([0, 31, 9, 16, 1], [0, 1, 2, 3, 4]),
+    ([4], [0]),
+    ([-1], [0]),
+], ids=["mixed", "kept-then-writers", "last-only", "first-only", "none",
+        "all", "one-writes", "one-keeps"])
+def test_slot_owners_names_the_nearest_writer_at_or_before_a_slot(
+        targets, owners):
+    got = jax.jit(slot_owners)(jnp.asarray(targets, jnp.int32))
+    assert got.dtype == jnp.int32
+    assert np.asarray(got).tolist() == owners
