@@ -18,7 +18,7 @@ import dataclasses
 from dataclasses import dataclass, field
 from typing import Any, Optional, Tuple
 
-MODEL_KINDS = ("control", "diff", "ndiff", "jamba")
+MODEL_KINDS = ("control", "diff", "ndiff", "jamba", "kimi_linear")
 
 # Fields only the ``jamba`` family reads. Another family given one of them
 # at a value other than its default is refused by name: a field that is
@@ -30,6 +30,16 @@ JAMBA_FIELDS = (
     "mamba_d_conv", "mamba_expand", "mamba_dt_rank", "ssm_state_dtype",
     "ssm_impl",
 )
+# Fields only the ``kimi_linear`` family reads (``ffn_hidden`` and
+# ``norm_eps`` it shares with ``jamba``), refused the same way.
+KIMI_LINEAR_FIELDS = (
+    "ffn_hidden", "norm_eps", "kda_layers", "full_attn_layers",
+    "kda_head_dim", "kda_conv", "kv_lora_rank",
+    "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "num_experts",
+    "experts_per_token", "moe_hidden", "first_dense_layers",
+    "routed_scaling", "held_experts",
+)
+FAMILY_FIELDS = {"jamba": JAMBA_FIELDS, "kimi_linear": KIMI_LINEAR_FIELDS}
 
 
 @dataclass(frozen=True)
@@ -158,11 +168,49 @@ class ModelConfig:
     # ssm_state_update advances the active slots of the decode pool in
     # place; forward only).
     ssm_impl: str = "xla"
+    # -- the ``kimi_linear`` family's fields (KIMI_LINEAR_FIELDS;
+    # models/kimi_linear.py) ------------------------------------------------
+    # The published layer lists, numbered from 1: a layer mixes tokens by
+    # KDA (a gated delta rule with a recurrent state a head) or by MLA
+    # (attention over a latent cache, no position information). Together
+    # they name every layer once.
+    kda_layers: Tuple[int, ...] = ()
+    full_attn_layers: Tuple[int, ...] = ()
+    # KDA: n_head heads, keys and values kda_head_dim wide, a causal
+    # depthwise convolution of kda_conv taps on q, k and v.
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    # MLA: the cache holds kv_lora_rank + qk_rope_head_dim values a
+    # position; a head's key is qk_nope_head_dim values widened from the
+    # latent beside the qk_rope_head_dim shared ones (not rotated).
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    # Experts: layers past the first ``first_dense_layers`` replace the
+    # dense MLP (ffn_hidden wide) by ``num_experts`` routed SwiGLU experts
+    # of ``moe_hidden``, ``experts_per_token`` a token by a sigmoid router
+    # with a correction bias, weights renormalised and scaled by
+    # ``routed_scaling``, plus one shared expert. ``held_experts`` is the
+    # half-open range of experts whose weights this program holds (an
+    # expert-parallel share; (0, 0) = all): the router ranks all
+    # ``num_experts``, only the held ones' terms are added.
+    num_experts: int = 0
+    experts_per_token: int = 8
+    moe_hidden: int = 1024
+    first_dense_layers: int = 1
+    routed_scaling: float = 1.0
+    held_experts: Tuple[int, int] = (0, 0)
 
     def __post_init__(self):
         if self.model not in MODEL_KINDS:
             raise ValueError(f"model must be one of {MODEL_KINDS}, got {self.model!r}")
+        for name in ("kda_layers", "full_attn_layers", "held_experts"):
+            # a configuration file gives lists; the config is a jit key
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+        self._check_family_fields()
         self._check_jamba_fields()
+        self._check_kimi_linear_fields()
         if self.attention_impl not in ("xla", "pallas"):
             raise ValueError(
                 "attention_impl must be 'xla' or 'pallas', got "
@@ -202,14 +250,65 @@ class ModelConfig:
                 "train.py:79, would crash at Ndiff_transformer.py:119)"
             )
 
+    def _check_family_fields(self):
+        mine = FAMILY_FIELDS.get(self.model, ())
+        for f in dataclasses.fields(self):
+            owners = [fam for fam, names in FAMILY_FIELDS.items()
+                      if f.name in names]
+            if (owners and f.name not in mine
+                    and getattr(self, f.name) != f.default):
+                raise ValueError(
+                    f"{f.name} is a field of the {' and '.join(owners)} "
+                    f"famil{'ies' if len(owners) > 1 else 'y'}; model "
+                    f"{self.model!r} does not read it"
+                )
+
+    def _check_kimi_linear_fields(self):
+        if self.model != "kimi_linear":
+            return
+        for name in ("attention_impl", "ffn_impl", "decode_attention_impl"):
+            if getattr(self, name) != "xla":
+                raise ValueError(
+                    f"the kimi_linear family runs {name}='xla' only, got "
+                    f"{getattr(self, name)!r}: the Pallas attention and FFN "
+                    "kernels know neither a latent cache nor experts"
+                )
+        if self.dropout:
+            raise ValueError("the kimi_linear family has no dropout")
+        layers = sorted(self.kda_layers + self.full_attn_layers)
+        if layers != list(range(1, self.n_layer + 1)):
+            raise ValueError(
+                "kda_layers and full_attn_layers (numbered from 1) must "
+                f"name each of the {self.n_layer} layers once, got "
+                f"{self.kda_layers} and {self.full_attn_layers}"
+            )
+        for name in ("kda_head_dim", "kv_lora_rank", "qk_nope_head_dim",
+                     "qk_rope_head_dim", "v_head_dim", "moe_hidden",
+                     "experts_per_token"):
+            if getattr(self, name) < 1:
+                raise ValueError(f"{name} must be >= 1, got {getattr(self, name)}")
+        if self.kda_conv < 2:
+            raise ValueError("kda_conv must be >= 2 (a carried window)")
+        if not 0 <= self.first_dense_layers <= self.n_layer:
+            raise ValueError(
+                f"first_dense_layers ({self.first_dense_layers}) must lie in "
+                f"[0, n_layer = {self.n_layer}]"
+            )
+        if self.first_dense_layers < self.n_layer:
+            lo, hi = self.held_expert_range
+            if self.num_experts < self.experts_per_token:
+                raise ValueError(
+                    f"num_experts ({self.num_experts}) must be at least "
+                    f"experts_per_token ({self.experts_per_token})"
+                )
+            if not 0 <= lo < hi <= self.num_experts:
+                raise ValueError(
+                    f"held_experts {self.held_experts} must be a non-empty "
+                    f"range within [0, num_experts = {self.num_experts}]"
+                )
+
     def _check_jamba_fields(self):
         if self.model != "jamba":
-            for f in dataclasses.fields(self):
-                if f.name in JAMBA_FIELDS and getattr(self, f.name) != f.default:
-                    raise ValueError(
-                        f"{f.name} is a field of the jamba family; model "
-                        f"{self.model!r} does not read it"
-                    )
             return
         for name in ("attention_impl", "ffn_impl", "decode_attention_impl"):
             if getattr(self, name) != "xla":
@@ -257,7 +356,7 @@ class ModelConfig:
 
     @property
     def resolved_norm_eps(self) -> float:
-        """eps of the jamba family's RMSNorm."""
+        """eps of the jamba and kimi_linear families' RMSNorm."""
         return self.norm_eps or 1e-6
 
     @property
@@ -269,9 +368,34 @@ class ModelConfig:
     def dt_rank(self) -> int:
         return self.mamba_dt_rank or -(-self.n_embd // 16)
 
+    @property
+    def cannot_roll(self) -> bool:
+        """Whether a sequence's cache cannot run past ``block_size``: diff's
+        learned position table cannot roll, and jamba's and kimi_linear's
+        attention layers carry no position at all, so a rolled ring would
+        turn them into sliding-window layers without a word."""
+        return self.model in ("diff", "jamba", "kimi_linear")
+
+    @property
+    def held_expert_range(self) -> Tuple[int, int]:
+        """``[lo, hi)``: the experts whose weights this program holds."""
+        lo, hi = self.held_experts
+        return (lo, hi) if hi else (0, self.num_experts)
+
+    def mlp_kinds(self) -> Tuple[str, ...]:
+        """``"dense"`` or ``"moe"`` for every layer, 0-based."""
+        if self.model != "kimi_linear":
+            return ("dense",) * self.n_layer
+        return tuple("dense" if i < self.first_dense_layers else "moe"
+                     for i in range(self.n_layer))
+
     def layer_kinds(self) -> Tuple[str, ...]:
-        """``"attention"`` or ``"mamba"`` for every layer, 0-based. The
-        reference families attend in every layer."""
+        """The mixer of every layer, 0-based: ``"attention"``, ``"mamba"``
+        (jamba), ``"kda"`` or ``"mla"`` (kimi_linear). The reference
+        families attend in every layer."""
+        if self.model == "kimi_linear":
+            return tuple("kda" if i in self.kda_layers else "mla"
+                         for i in range(1, self.n_layer + 1))
         if self.model != "jamba":
             return ("attention",) * self.n_layer
         return tuple(
@@ -289,7 +413,7 @@ class ModelConfig:
         it because each head carries a doubled value
         (diff_transformer.py:111, Ndiff_transformer.py:164).
         """
-        if self.model in ("control", "jamba"):
+        if self.model in ("control", "jamba", "kimi_linear"):
             return self.n_embd // self.n_head
         return self.n_embd // (self.n_head * 2)
 
@@ -297,7 +421,7 @@ class ModelConfig:
     def value_size(self) -> int:
         """Per-head value width: doubled for differential variants
         (diff_transformer.py:30, Ndiff_transformer.py:59)."""
-        if self.model in ("control", "jamba"):
+        if self.model in ("control", "jamba", "kimi_linear"):
             return self.head_size
         return self.head_size * 2
 
@@ -645,10 +769,7 @@ class ServingConfig:
 
     def resolved_max_seq_len(self, model: "ModelConfig") -> int:
         """Hard cap on prompt + generated length for this model family."""
-        if model.model in ("diff", "jamba"):
-            # a learned table cannot roll; jamba's attention layers carry
-            # no position at all, so a rolled ring would turn them into
-            # sliding-window layers without a word
+        if model.cannot_roll:
             return model.block_size
         return max(self.max_seq_len, model.block_size)
 

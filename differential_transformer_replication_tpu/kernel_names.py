@@ -19,6 +19,9 @@ substrings, so the names keep to these rules:
 - the cache-write kernel holds none of these (the benchmark's reader
   books it under ``pallas``, ``obs/xprof.py`` under ``kv_write``);
 - the state-space kernels start ``ssm_`` (``pallas`` there, ``ssm`` here);
+- the delta-rule kernel starts ``kda_`` (``pallas`` there, ``kda`` here);
+- the experts' grouped product starts ``moe_`` (``pallas`` there, ``moe``
+  here);
 - no name holds a needle of another family.
 
 Standard library only, at the top of the package: ``ops/`` (which
@@ -58,6 +61,14 @@ KV_ROW_WRITE = "kv_row_write"
 SSM_SCAN_FWD = "ssm_scan_fwd"
 SSM_STATE_UPDATE = "ssm_state_update"
 
+# ops/kda.py (the kimi_linear family's KDA mixers: the decode step's
+# one-token update of the active slots' states, in place in the pool)
+KDA_STATE_UPDATE = "kda_state_update"
+
+# ops/moe.py (the kimi_linear family's routed experts: one call a grouped
+# product, a row tile of one expert a grid step)
+MOE_GROUPED_MATMUL = "moe_grouped_matmul"
+
 FLASH = (
     FLASH_FWD, FLASH_FWD_TILED, FLASH_FWD_CHUNK, FLASH_FWD_TM,
     FLASH_FWD_TM_PACKED, FLASH_BWD_DQ, FLASH_BWD_DKV, FLASH_BWD_DQ_TILED,
@@ -68,6 +79,8 @@ FUSED_NORM = (FUSED_ADD_NORM_FWD, FUSED_ADD_NORM_BWD)
 DECODE = (DECODE_ATTENTION,)
 KV_WRITE = (KV_ROW_WRITE,)
 SSM = (SSM_SCAN_FWD, SSM_STATE_UPDATE)
+KDA = (KDA_STATE_UPDATE,)
+MOE = (MOE_GROUPED_MATMUL,)
 
 #: family -> its kernels' names; every ``pallas_call`` under ``ops/``
 #: passes one of these as ``name=``
@@ -78,5 +91,7 @@ FAMILIES = {
     "decode_attention": DECODE,
     "kv_write": KV_WRITE,
     "ssm": SSM,
+    "kda": KDA,
+    "moe": MOE,
 }
-ALL = FLASH + FUSED_FFN + FUSED_NORM + DECODE + KV_WRITE + SSM
+ALL = FLASH + FUSED_FFN + FUSED_NORM + DECODE + KV_WRITE + SSM + KDA + MOE

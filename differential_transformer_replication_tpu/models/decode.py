@@ -50,7 +50,11 @@ import jax.numpy as jnp
 
 from differential_transformer_replication_tpu.config import ModelConfig
 from differential_transformer_replication_tpu.models.generate import sample_token
-from differential_transformer_replication_tpu.models import common, jamba
+from differential_transformer_replication_tpu.models import (
+    common,
+    jamba,
+    kimi_linear,
+)
 from differential_transformer_replication_tpu.ops import (
     apply_rope,
     diff_lambda,
@@ -78,14 +82,7 @@ from differential_transformer_replication_tpu.ops.streams import (
 
 def _n_streams(cfg: ModelConfig) -> int:
     return {"control": 1, "diff": 2, "ndiff": cfg.n_terms,
-            "jamba": 1}[cfg.model]
-
-
-def _cannot_roll(cfg: ModelConfig) -> bool:
-    """Families whose cache cannot run past ``block_size``: diff's learned
-    position table, and jamba, whose attention layers carry no position
-    (a rolled ring would make them sliding-window layers)."""
-    return cfg.model in ("diff", "jamba")
+            "jamba": 1, "kimi_linear": 1}[cfg.model]
 
 
 def _uses_rope(cfg: ModelConfig) -> bool:
@@ -95,18 +92,23 @@ def _uses_rope(cfg: ModelConfig) -> bool:
 # Pool-batch axis of each cache leaf: K (and its scales) carry the
 # stream axis first, V does not. The single source of truth for every
 # per-slot slice/scatter/merge over the cache pytree (serving/engine.py).
-# ``ssm`` and ``conv`` are a Mamba layer's leaves (the jamba family): a
-# slot's recurrent state, not a ring over positions.
+# ``ssm`` and ``conv`` are a Mamba layer's leaves (the jamba family),
+# ``kda`` and ``conv`` a KDA layer's (kimi_linear): a slot's recurrent
+# state, not a ring over positions. ``latent`` is an MLA layer's ring of
+# latents (B, 1, M, rank + rope): one "head" that every query head reads.
 KV_CACHE_BATCH_AXIS = {"k": 1, "v": 0, "k_scale": 1, "v_scale": 0,
-                       "ssm": 0, "conv": 0}
-STATE_LEAVES = ("ssm", "conv")
+                       "ssm": 0, "conv": 0, "kda": 0, "latent": 0}
+STATE_LEAVES = ("ssm", "conv", "kda")
+# the families whose layers are of several kinds (:func:`_hybrid_chunk`)
+HYBRID = ("jamba", "kimi_linear")
 
 
 def has_recurrent_state(cfg: ModelConfig) -> bool:
     """Whether a sequence's cache holds state that every token overwrites
-    (a Mamba layer's): such a slot has to be zeroed before a new sequence
-    enters it, where a ring is simply masked by positions."""
-    return "mamba" in cfg.layer_kinds()
+    (a Mamba or a KDA layer's): such a slot has to be zeroed before a new
+    sequence enters it, where a ring is simply masked by positions. Told
+    by what the layers keep, not by the family's name."""
+    return any(kind in ("mamba", "kda") for kind in cfg.layer_kinds())
 
 
 def kv_store_dtype(cfg: ModelConfig) -> str:
@@ -231,7 +233,9 @@ def init_cache(cfg: ModelConfig, batch_size: int) -> list:
     The ``jamba`` family's layers are of two kinds: an attention layer
     gets rings with ``kv_heads`` heads, a Mamba layer ``{ssm (B, N, Di)
     in ssm_state_dtype, conv (B, K-1, Di)}``, zeros being a sequence's
-    start."""
+    start. The ``kimi_linear`` family's: a KDA layer ``{kda (B, H, d, d)
+    float32, conv (B, K-1, 3 H d)}``, an MLA layer ``{latent (B, 1, M,
+    rank + rope)}``, the ring of what it caches a position."""
     S = _n_streams(cfg)
     H, d, dv, M = cfg.n_kv_head, cfg.head_size, cfg.value_size, cfg.block_size
     store = kv_store_dtype(cfg)
@@ -240,6 +244,15 @@ def init_cache(cfg: ModelConfig, batch_size: int) -> list:
         if kind == "mamba":
             conv, ssm = jamba.zero_state(cfg, batch_size)
             cache.append({"ssm": ssm, "conv": conv})
+            continue
+        if kind == "kda":
+            conv, state = kimi_linear.kda_zero_state(cfg, batch_size)
+            cache.append({"kda": state, "conv": conv})
+            continue
+        if kind == "mla":
+            cache.append({"latent": jnp.zeros(
+                (batch_size, 1, M, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+                jnp.dtype(cfg.compute_dtype))})
             continue
         if store == "int8":
             layer = {
@@ -481,7 +494,7 @@ def forward_chunk(
     """Process a chunk against the cache. Returns ((B, L, V) logits,
     updated cache). Prefill = one big chunk at pos=0; decode = L=1.
 
-    ``valid`` (the jamba family only; a runtime scalar, 1 <= valid <= L)
+    ``valid`` (the hybrid families only; a runtime scalar, 1 <= valid <= L)
     says that only the chunk's first ``valid`` tokens are the sequence's
     and the rest padding up to a compiled shape: the cache comes back as
     after ``valid`` tokens, and the logits are those of the LAST REAL
@@ -509,11 +522,12 @@ def forward_chunk(
     B, L = tokens.shape
     M = cfg.block_size
     if isinstance(pos, int):
-        if cfg.model == "jamba" and pos + L > M:
+        if cfg.model in HYBRID and pos + L > M:
             raise ValueError(
-                f"chunk [{pos}, {pos + L}) exceeds block_size {M}: the jamba "
-                "family's attention layers carry no position, so a rolled "
-                "ring would silently become sliding-window attention"
+                f"chunk [{pos}, {pos + L}) exceeds block_size {M}: the "
+                f"{cfg.model} family's attention layers carry no position, "
+                "so a rolled ring would silently become sliding-window "
+                "attention"
             )
         if cfg.model == "diff" and pos + L > M:
             raise ValueError(
@@ -522,7 +536,7 @@ def forward_chunk(
                 "slide would re-embed every cached position); use "
                 "models.generate for its sliding-window behavior"
             )
-        if not _cannot_roll(cfg) and pos + L > max(int(rope_len), M):
+        if not cfg.cannot_roll and pos + L > max(int(rope_len), M):
             raise ValueError(
                 f"chunk [{pos}, {pos + L}) exceeds the RoPE table length "
                 f"{max(int(rope_len), M)}: pass rope_len >= the final "
@@ -541,13 +555,13 @@ def forward_chunk(
                 f"chunk [{pos}, {pos + L}) wraps the ring boundary (slot "
                 f"{pos % M} + {L} > {M}): split it at the boundary"
             )
-    if cfg.model == "jamba":
-        return _forward_chunk_jamba(params, tokens, pos, cache, cfg, window,
-                                    valid)
+    if cfg.model in HYBRID:
+        return _hybrid_chunk(params, tokens, pos, cache, cfg, window, valid)
     if valid is not None:
         raise ValueError(
-            f"forward_chunk(valid=...) pads a chunk of the jamba family "
-            f"only; the {cfg.model!r} family runs whole chunks"
+            f"forward_chunk(valid=...) pads a chunk of the jamba and "
+            f"kimi_linear families only; the {cfg.model!r} family runs "
+            "whole chunks"
         )
     x, cos, sin = _embed_chunk(params, tokens, pos, cfg, rope_len)
     new_cache = []
@@ -568,106 +582,181 @@ def forward_chunk(
 
 
 # ---------------------------------------------------------------------------
-# The jamba family (models/jamba.py): layers of two kinds in one stack. An
-# attention layer keeps K/V rings like the others (``kv_heads`` heads, each
-# shared by a group of query heads, no position information); a Mamba layer
-# keeps a recurrent state a slot, which a prefill chunk carries on from
-# where the last chunk left it and a decode step overwrites for the active
-# slots, in place in the donated pool. Nothing masks a state by position:
-# a slot that takes a new sequence has to be zeroed first
-# (:func:`reset_slot_state`; serving/engine.py does so on admission).
+# The hybrid families (models/jamba.py, models/kimi_linear.py): layers of
+# several kinds in one stack, ONE loop over (mixer kind, MLP kind) a layer
+# for a prefill chunk and one for the decode step. An attention layer keeps
+# K/V rings like the other families' (``kv_heads`` heads, each shared by a
+# group of query heads, no position information) and an MLA layer a ring of
+# latents; a Mamba or a KDA layer keeps a recurrent state a slot, which a
+# prefill chunk carries on from where the last chunk left it and a decode
+# step overwrites for the active slots, in place in the donated pool.
+# Nothing masks a state by position: a slot that takes a new sequence has
+# to be zeroed first (:func:`reset_slot_state`; serving/engine.py does so
+# on admission). A layer's kinds are read off its leaves.
 # ---------------------------------------------------------------------------
 
 
-def _forward_chunk_jamba(params: dict, tokens: jnp.ndarray, pos,
-                         cache: list, cfg: ModelConfig, window: int = 0,
-                         valid=None):
-    """:func:`forward_chunk` for the jamba family: every Mamba layer's
-    ``conv`` and ``ssm`` enter as the state before the chunk and leave as
-    the state after it, so a prompt may arrive in any chunks. With
-    ``valid`` the Mamba layers stop their state there
-    (``jamba.mixer_chunk``); the attention layers write the padding's keys
-    and values into ring positions past the sequence's end, which no query
-    sees (a query sees no later position) and which the tokens that come
-    to stand there overwrite before they attend."""
+def _mixer_chunk(x, blk: dict, layer_cache: dict, cfg: ModelConfig, slot,
+                 visible, valid):
+    """A layer's mixer over a chunk ``x`` (B, L, E): ``(its output, the
+    layer's cache after the chunk)``."""
+    if "mamba" in blk:
+        with jax.named_scope("ssm"):
+            h = jamba.norm(x, blk["ln1"], cfg)
+            a, conv, ssm = jamba.mixer_chunk(
+                h, blk["mamba"], cfg, layer_cache["conv"],
+                layer_cache["ssm"], valid)
+        return a, {"ssm": ssm, "conv": conv}
+    if "kda" in blk:
+        with jax.named_scope("kda"):
+            h = jamba.norm(x, blk["ln1"], cfg)
+            a, conv, state = kimi_linear.kda_chunk(
+                h, blk["kda"], cfg, layer_cache["conv"], layer_cache["kda"],
+                valid)
+        return a, {"kda": state, "conv": conv}
+    if "mla" in blk:
+        with jax.named_scope("mla"):
+            h = jamba.norm(x, blk["ln1"], cfg)
+            with jax.named_scope("mla_latent_write"):
+                rows = kimi_linear.mla_latent(h, blk["mla"], cfg)
+                latent = jax.lax.dynamic_update_slice(
+                    layer_cache["latent"],
+                    rows[:, None].astype(layer_cache["latent"].dtype),
+                    (0, 0, slot, 0))
+            with jax.named_scope("mla_attend"):
+                a = kimi_linear.mla_attend(h, blk["mla"], latent[:, 0],
+                                           visible)
+        return a, {"latent": latent}
+    with jax.named_scope("attn_norm"):
+        h = jamba.norm(x, blk["ln1"], cfg)
+    with jax.named_scope("attn"):
+        q, k, v = jamba.qkv(h, blk["attn"])
+        with jax.named_scope("kv_write"):
+            layer_cache = _write_chunk(layer_cache, k[None], v, slot)
+        k_c, v_c = _dequant_layer(layer_cache, q.dtype)
+        a = jamba.attend(q, k_c[0], v_c, visible) @ blk["attn"][
+            "out"]["w"].astype(q.dtype)
+    return a, layer_cache
+
+
+def _mlp(x, blk: dict, cfg: ModelConfig, live=None):
+    """A layer's MLP of either kind on the residual ``x``: ``(x + y, the
+    held experts' load or None)``."""
+    if "moe" in blk:
+        return kimi_linear.moe(x, blk, cfg, live)
+    return jamba.ffn(x, blk, cfg), None
+
+
+def _hybrid_chunk(params: dict, tokens: jnp.ndarray, pos, cache: list,
+                  cfg: ModelConfig, window: int = 0, valid=None):
+    """:func:`forward_chunk` for the hybrid families: every recurrent
+    layer's state enters as the state before the chunk and leaves as the
+    state after it, so a prompt may arrive in any chunks. With ``valid``
+    the recurrent layers stop their state there (``jamba.mixer_chunk``,
+    ``kimi_linear.kda_chunk``); the attention layers write the padding's
+    keys and values (latents) into ring positions past the sequence's
+    end, which no query sees (a query sees no later position) and which
+    the tokens that come to stand there overwrite before they attend."""
     L, M = tokens.shape[1], cfg.block_size
     slot = jax.lax.rem(jnp.asarray(pos, jnp.int32), M)
     visible = _ring_visible(pos, L, M, int(window) or M)
     x = jamba.embed(params, tokens, cfg)
     new_cache = []
     for blk, layer_cache in zip(params["blocks"], cache):
-        if "mamba" in blk:
-            with jax.named_scope("ssm"):
-                h = jamba.norm(x, blk["ln1"], cfg)
-                a, conv, ssm = jamba.mixer_chunk(
-                    h, blk["mamba"], cfg, layer_cache["conv"],
-                    layer_cache["ssm"], valid)
-            new_cache.append({"ssm": ssm, "conv": conv})
-        else:
-            with jax.named_scope("attn_norm"):
-                h = jamba.norm(x, blk["ln1"], cfg)
-            with jax.named_scope("attn"):
-                q, k, v = jamba.qkv(h, blk["attn"])
-                with jax.named_scope("kv_write"):
-                    layer_cache = _write_chunk(layer_cache, k[None], v, slot)
-                k_c, v_c = _dequant_layer(layer_cache, q.dtype)
-                a = jamba.attend(q, k_c[0], v_c, visible) @ blk["attn"][
-                    "out"]["w"].astype(q.dtype)
-            new_cache.append(layer_cache)
-        x = jamba.ffn(x + a, blk, cfg)
+        a, layer_cache = _mixer_chunk(x, blk, layer_cache, cfg, slot,
+                                      visible, valid)
+        new_cache.append(layer_cache)
+        x, _ = _mlp(x + a, blk, cfg)
     if valid is not None:
         x = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
     with jax.named_scope("lm_head"):
         return jamba.lm_head(params, x, cfg), new_cache
 
 
-def _forward_decode_jamba(params: dict, tokens: jnp.ndarray, pos,
-                          cache: list, cfg: ModelConfig, active=None):
-    """The jamba family's decode step over the whole slot pool, one batch:
-    ``((B, V) logits, updated cache)``. The attention layers write their
-    row into the ring in place (``ops/kv_write.py``) and read the pool;
-    the Mamba layers advance the active slots' states (``ops/ssm.py``). A
-    row that is not ``active`` leaves every leaf of its slot as it is."""
+def _mixer_step(x, blk: dict, layer_cache: dict, cfg: ModelConfig, live,
+                targets, visible):
+    """A layer's mixer for one token a slot, ``x`` (B, E): ``(its output,
+    the layer's cache after the step)``."""
+    if "mamba" in blk:
+        with jax.named_scope("ssm"):
+            h = jamba.norm(x, blk["ln1"], cfg)
+            a, conv, ssm = jamba.mixer_step(
+                h, blk["mamba"], cfg, layer_cache["conv"],
+                layer_cache["ssm"], live)
+        return a, {"ssm": ssm, "conv": conv}
+    if "kda" in blk:
+        with jax.named_scope("kda"):
+            h = jamba.norm(x, blk["ln1"], cfg)
+            a, conv, state = kimi_linear.kda_step(
+                h, blk["kda"], cfg, layer_cache["conv"], layer_cache["kda"],
+                live)
+        return a, {"kda": state, "conv": conv}
+    if "mla" in blk:
+        with jax.named_scope("mla"):
+            h = jamba.norm(x, blk["ln1"], cfg)
+            with jax.named_scope("mla_latent_write"):
+                rows = kimi_linear.mla_latent(h, blk["mla"], cfg)
+                layer_cache = _write_ring(
+                    layer_cache,
+                    {"latent": rows[:, None].astype(
+                        layer_cache["latent"].dtype)}, targets)
+            with jax.named_scope("mla_attend"):
+                a = kimi_linear.mla_attend(
+                    h[:, None], blk["mla"], layer_cache["latent"][:, 0],
+                    visible)[:, 0]
+        return a, layer_cache
+    with jax.named_scope("attn_norm"):
+        h = jamba.norm(x, blk["ln1"], cfg)
+    with jax.named_scope("attn"):
+        q, k, v = jamba.qkv(h, blk["attn"])
+        with jax.named_scope("kv_write"):
+            layer_cache = _write_ring(
+                layer_cache, _store_rows(layer_cache, k[None], v), targets)
+        k_c, v_c = _dequant_layer(layer_cache, q.dtype)
+        a = jamba.attend(q[:, None], k_c[0], v_c, visible)[:, 0] @ blk[
+            "attn"]["out"]["w"].astype(q.dtype)
+    return a, layer_cache
+
+
+def _hybrid_decode(params: dict, tokens: jnp.ndarray, pos, cache: list,
+                   cfg: ModelConfig, active=None):
+    """The hybrid families' decode step over the whole slot pool, one
+    batch: ``((B, V) logits, updated cache, expert load)``. The attention
+    layers write their row into the ring in place (``ops/kv_write.py``)
+    and read the pool; the recurrent layers advance the active slots'
+    states (``ops/ssm.py``, ``ops/kda.py``). A row that is not ``active``
+    leaves every leaf of its slot as it is and meets no expert. ``load``
+    (3,) int32, summed over the expert layers: the (row, expert)
+    assignments that fell on held experts, the largest count on one
+    expert, and the held experts that got a row at all (whose weights the
+    step had to read); None for a family without experts."""
     B, M = tokens.shape[0], cfg.block_size
     pos = jnp.asarray(pos, jnp.int32)
     targets = _write_targets(pos, active, M)
     live = jnp.ones((B,), bool) if active is None else active
-    # pos < M always (the family cannot roll): slot m holds a live key iff
-    # m <= pos
+    # pos < M always (the families cannot roll): slot m holds a live key
+    # iff m <= pos
     visible = jnp.arange(M)[None, None, :] <= pos[:, None, None]
     x = jamba.embed(params, tokens, cfg)  # (B, E)
-    new_cache = []
+    new_cache, loads = [], []
     for blk, layer_cache in zip(params["blocks"], cache):
-        if "mamba" in blk:
-            with jax.named_scope("ssm"):
-                h = jamba.norm(x, blk["ln1"], cfg)
-                a, conv, ssm = jamba.mixer_step(
-                    h, blk["mamba"], cfg, layer_cache["conv"],
-                    layer_cache["ssm"], live)
-            new_cache.append({"ssm": ssm, "conv": conv})
-        else:
-            with jax.named_scope("attn_norm"):
-                h = jamba.norm(x, blk["ln1"], cfg)
-            with jax.named_scope("attn"):
-                q, k, v = jamba.qkv(h, blk["attn"])
-                with jax.named_scope("kv_write"):
-                    layer_cache = _write_ring(
-                        layer_cache, _store_rows(layer_cache, k[None], v),
-                        targets)
-                k_c, v_c = _dequant_layer(layer_cache, q.dtype)
-                a = jamba.attend(q[:, None], k_c[0], v_c, visible)[:, 0] @ blk[
-                    "attn"]["out"]["w"].astype(q.dtype)
-            new_cache.append(layer_cache)
-        x = jamba.ffn(x + a, blk, cfg)
+        a, layer_cache = _mixer_step(x, blk, layer_cache, cfg, live,
+                                     targets, visible)
+        new_cache.append(layer_cache)
+        x, load = _mlp(x + a, blk, cfg, live)
+        if load is not None:
+            loads.append(jnp.stack([jnp.sum(load), jnp.max(load),
+                                    jnp.sum(load > 0)]))
     with jax.named_scope("lm_head"):
-        return jamba.lm_head(params, x, cfg), new_cache
+        return (jamba.lm_head(params, x, cfg), new_cache,
+                sum(loads) if loads else None)
 
 
 def reset_slot_state(cache: list, slot) -> list:
-    """``cache`` with slot ``slot``'s recurrent state (every Mamba layer's
-    ``ssm`` and ``conv``) zeroed, in place under a jit that donates the
-    pool; rings are left as they are (positions mask them). ``slot`` is a
-    runtime scalar."""
+    """``cache`` with slot ``slot``'s recurrent state (every Mamba or KDA
+    layer's ``STATE_LEAVES``) zeroed, in place under a jit that donates
+    the pool; rings are left as they are (positions mask them). ``slot``
+    is a runtime scalar."""
     return [
         {key: (jax.lax.dynamic_update_slice_in_dim(
                    leaf, jnp.zeros((1,) + leaf.shape[1:], leaf.dtype),
@@ -1047,10 +1136,13 @@ def forward_decode_pool(
     runtime int32 tables, so pages can be allocated, freed, shared and
     forked between calls with ZERO recompiles (tests/test_pages.py), and
     the engine names the trash page in ``write_pages`` for a row that
-    must not land. The ``jamba`` family has its own loop, two kinds of
-    layer, on the slot pool (:func:`_forward_decode_jamba`)."""
-    if cfg.model == "jamba":
-        return _forward_decode_jamba(params, tokens, pos, cache, cfg, active)
+    must not land. The hybrid families have their own loop, several
+    kinds of layer, on the slot pool (:func:`_hybrid_decode`); a
+    configuration with experts (``num_experts``) gains that loop's
+    ``load`` as a third item."""
+    if cfg.model in HYBRID:
+        out = _hybrid_decode(params, tokens, pos, cache, cfg, active)
+        return out if cfg.num_experts else out[:2]
     return _decode_step(params, tokens, pos, cache, cfg, rope_len,
                         active=active, where=write_pages,
                         page_tables=page_tables)
@@ -1153,11 +1245,11 @@ def generate_cached(
     for longer runs."""
     B, T0 = idx.shape
     M = cfg.block_size
-    if cfg.model == "jamba" and T0 + max_new_tokens > M:
+    if cfg.model in HYBRID and T0 + max_new_tokens > M:
         raise ValueError(
             f"prompt ({T0}) + max_new_tokens ({max_new_tokens}) exceeds "
-            f"block_size ({M}): the jamba family's cache cannot roll (its "
-            "attention layers carry no position)"
+            f"block_size ({M}): the {cfg.model} family's cache cannot roll "
+            "(its attention layers carry no position)"
         )
     if cfg.model == "diff" and T0 + max_new_tokens > M:
         raise ValueError(
@@ -1193,7 +1285,7 @@ def generate_cached(
         prev = samples[:, i - 1]
         # all B rows share the position here, but the step is the one the
         # serving engine runs with per-row positions
-        last, cache = forward_decode_pool(
+        last, cache, *_ = forward_decode_pool(
             params, prev, jnp.full((B,), Tc + i - 1, jnp.int32),
             cache, cfg, rope_len=total,
         )

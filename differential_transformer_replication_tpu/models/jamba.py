@@ -124,10 +124,14 @@ def ffn(x: jnp.ndarray, blk: dict, cfg: ModelConfig) -> jnp.ndarray:
     with jax.named_scope("ffn_norm"):
         h = norm(x, blk["ln2"], cfg)
     with jax.named_scope("ffn"):
-        p = blk["ffn"]
-        g = jax.nn.silu(h @ p["gate"]["w"].astype(h.dtype))
-        return x + (g * (h @ p["xform"]["w"].astype(h.dtype))) @ p["out"][
-            "w"].astype(h.dtype)
+        return x + gated_mlp(h, blk["ffn"])
+
+
+def gated_mlp(h: jnp.ndarray, p: dict) -> jnp.ndarray:
+    """``W_out(silu(W_gate h) * W_xform h)``, no bias."""
+    g = jax.nn.silu(h @ p["gate"]["w"].astype(h.dtype))
+    return (g * (h @ p["xform"]["w"].astype(h.dtype))) @ p["out"][
+        "w"].astype(h.dtype)
 
 
 # -- the Mamba mixer -----------------------------------------------------------

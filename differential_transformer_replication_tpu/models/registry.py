@@ -3,7 +3,8 @@
 The reference selects models by commenting code blocks in and out
 (train.py:205-230); here it is a first-class dispatch on
 ``ModelConfig.model`` covering the same three families, and ``jamba``
-(models/jamba.py), whose layers are of two kinds.
+(models/jamba.py) and ``kimi_linear`` (models/kimi_linear.py), whose
+layers are of several kinds.
 """
 
 from __future__ import annotations
@@ -14,10 +15,16 @@ import jax
 import jax.numpy as jnp
 
 from differential_transformer_replication_tpu.config import ModelConfig
-from differential_transformer_replication_tpu.models import control, diff, jamba, ndiff
+from differential_transformer_replication_tpu.models import (
+    control,
+    diff,
+    jamba,
+    kimi_linear,
+    ndiff,
+)
 
 _MODULES = {"control": control, "diff": diff, "ndiff": ndiff,
-            "jamba": jamba}
+            "jamba": jamba, "kimi_linear": kimi_linear}
 
 
 def init_model(key: jax.Array, cfg: ModelConfig) -> dict:

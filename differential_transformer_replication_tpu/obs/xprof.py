@@ -60,6 +60,8 @@ KERNEL_BUCKETS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("decode_attention", _NAMES.DECODE),
     ("kv_write", _NAMES.KV_WRITE),
     ("ssm", _NAMES.SSM),
+    ("kda", _NAMES.KDA),
+    ("moe", _NAMES.MOE),
     ("fused_ffn", _NAMES.FUSED_FFN + _NAMES.FUSED_NORM),
     ("flash_attention", _NAMES.FLASH),
     ("collectives", ("all-reduce", "all-gather", "reduce-scatter",

@@ -188,7 +188,14 @@ _STAT_SPEC = {
     "state_resets": (
         "serving_state_resets_total",
         "Slots whose recurrent state was zeroed on admission (families "
-        "with Mamba layers; a K/V ring needs none).",
+        "with Mamba or KDA layers; a K/V ring needs none).",
+    ),
+    "moe_held": (
+        "serving_moe_held_assignments_total",
+        "(row, expert) assignments of decode steps that fell on an expert "
+        "this replica holds, over the expert layers (families with routed "
+        "experts; the rest of a row's experts_per_token lie on the chips "
+        "that hold the other experts).",
     ),
     "page_shed": (
         "serving_requests_page_shed_total",
@@ -392,11 +399,13 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
         same math on garbage inputs (static shapes are the point); the
         write takes ``active`` as its mask, so a mid-prefill or free
         slot's ring is left as it is, and the step's cache traffic is
-        the rows it writes, in place in the donated pool.
+        the rows it writes, in place in the donated pool. A family with
+        routed experts (``num_experts``) returns a third item, the step's
+        expert load (models/decode.py:``_hybrid_decode``).
         """
-        logits, new_cache = forward_decode_pool(
+        logits, new_cache, *load = forward_decode_pool(
             params, tokens, pos, cache, cfg, rope_len=rope_len, active=active)
-        return logits.astype(jnp.float32), new_cache
+        return (logits.astype(jnp.float32), new_cache, *load)
 
     def _prefill(params, cache, slot, tokens, pos, valid=None):
         """One prompt chunk for one slot, in place in the pool.
@@ -571,12 +580,13 @@ def _refuse_for_recurrent_state(cfg: ModelConfig,
                                 serving: ServingConfig) -> None:
     """The engine features that address a sequence's state BY POSITION,
     each refused by name for a family whose layers hold a recurrent state
-    (``jamba``): a K/V ring can be cut, shared, rolled back or shipped at
-    any position, a Mamba state is overwritten every token and what it was
-    at an earlier position is gone."""
-    lacks = ("the {} family keeps a recurrent state a Mamba layer, and {} "
-             "needs a snapshot of that state at a position, which the "
-             "engine does not take")
+    (``jamba``'s Mamba layers, ``kimi_linear``'s KDA layers): a K/V ring
+    can be cut, shared, rolled back or shipped at any position, a
+    recurrent state is overwritten every token and what it was at an
+    earlier position is gone."""
+    lacks = ("the {} family keeps a recurrent state a Mamba or KDA layer, "
+             "and {} needs a snapshot of that state at a position, which "
+             "the engine does not take")
     asked = (
         ("the host tier (host_tier_bytes; preemption and resume)",
          serving.host_tier_bytes > 0),
@@ -592,9 +602,9 @@ def _refuse_for_recurrent_state(cfg: ModelConfig,
     if cfg.kv_cache_dtype == "int8":
         raise ValueError(
             f"kv_cache_dtype='int8' is not available for the {cfg.model} "
-            "family: its attention layers' grouped-query decode path reads "
-            "float rings, and a quantized recurrent state does not exist "
-            "yet (ssm_state_dtype is float32)"
+            "family: its attention layers' decode path reads float rings "
+            "(grouped-query K/V, or MLA's latents), and a quantized "
+            "recurrent state does not exist yet (the state is float32)"
         )
 
 
@@ -1133,11 +1143,14 @@ class ServingEngine:
         # identity gauge
         self.registry.gauge(
             "serving_state_pool_bytes",
-            "HBM bytes of the pool's recurrent state (every Mamba "
-            "layer's ssm and conv leaves); 0 for a family of K/V rings.",
+            "HBM bytes of the pool's state that is no K/V ring: every "
+            "Mamba or KDA layer's recurrent state and convolution window, "
+            "and every MLA layer's ring of latents; 0 for a family of K/V "
+            "rings.",
         ).set(
             sum(leaf.nbytes for layer in self.cache
-                for key, leaf in layer.items() if key in STATE_LEAVES)
+                for key, leaf in layer.items()
+                if key in STATE_LEAVES or key == "latent")
         )
         self.registry.gauge(
             "serving_kv_cache_bytes_per_slot",
@@ -1397,7 +1410,7 @@ class ServingEngine:
         req = Request.make(rid, prompt, params, **kw)
         M = self.cfg.block_size
         p = np.asarray(req.prompt, np.int32)
-        if self.cfg.model in ("diff", "jamba"):
+        if self.cfg.cannot_roll:
             if p.shape[0] + req.params.max_new_tokens > M:
                 raise ValueError(
                     f"prompt ({p.shape[0]}) + max_new_tokens "
@@ -1405,8 +1418,8 @@ class ServingEngine:
                     + ("and the diff family's learned absolute position "
                        "table cannot roll with a KV cache (models/decode.py)"
                        if self.cfg.model == "diff" else
-                       "and the jamba family's cache cannot roll: its "
-                       "attention layers carry no position "
+                       f"and the {self.cfg.model} family's cache cannot "
+                       "roll: its attention layers carry no position "
                        "(models/decode.py)")
                 )
         else:
@@ -1702,6 +1715,11 @@ class ServingEngine:
                 ]
                 if tids:
                     decode_args["trace_ids"] = tids
+            load = ()
+            if self.cfg.num_experts:
+                # filled in below, once the tokens' read has waited for
+                # the step: the span keeps the dict it was handed
+                decode_args["moe"] = expert_load = {}
             with self.tracer.span("decode", **decode_args):
                 if self._pages is not None:
                     logits, self.cache = self._decode_fn(
@@ -1710,12 +1728,19 @@ class ServingEngine:
                         jnp.asarray(tables), jnp.asarray(write_pages),
                     )
                 else:
-                    logits, self.cache = self._decode_fn(
+                    logits, self.cache, *load = self._decode_fn(
                         self.params, jnp.asarray(tokens),
                         jnp.asarray(pos), jnp.asarray(mask), self.cache,
                     )
             with self.tracer.span("sample", iteration=iteration):
                 sampled, ok, packed = self._sample_all_slots(logits)
+                if load:
+                    # the step has finished (its tokens were just read):
+                    # twelve bytes that are there, no second wait
+                    held, top, hit = (int(v) for v in np.asarray(load[0]))
+                    expert_load.update(held=held, max_expert=top,
+                                       experts_hit=hit)
+                    self.stats.inc("moe_held", held)
             bad = [s for s in active if not ok[s.index]]
             if bad:
                 raise EngineCrashError(
@@ -2662,8 +2687,8 @@ class ServingEngine:
             raise MigrateExportError(
                 f"live migration is not available for the {self.cfg.model} "
                 "family: the wire image ships K/V pages by position, and a "
-                "Mamba layer's recurrent state has no page to ship (it "
-                "needs a snapshot of the state) — fall back to replay"
+                "Mamba or KDA layer's recurrent state has no page to ship "
+                "(it needs a snapshot of the state) — fall back to replay"
             )
 
     def export_slot_state(self, request_id: int,

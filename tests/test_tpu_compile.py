@@ -46,7 +46,7 @@ dattn = importlib.import_module(
 
 KERNEL_MODULES = (
     "ops.flash", "ops.fused_ffn", "ops.fused_norm_residual",
-    "ops.decode_attention", "ops.kv_write", "ops.ssm",
+    "ops.decode_attention", "ops.kv_write", "ops.ssm", "ops.kda", "ops.moe",
 )
 
 # the recipe's widths (8L/768d, T=512, vocab 12000); the batch is cut, a
@@ -583,6 +583,71 @@ def test_jamba_programs_update_the_state_pool_in_place(topo):
         offenders.append(f"%{own} = {dtype}[{dims}] {opcode}")
     assert not offenders, offenders
     assert decode.memory_analysis().temp_size_in_bytes < state * 4
+
+
+# -- the kimi_linear family at the published widths (Kimi-Linear, a share) ----
+
+KIMI = dict(model="kimi_linear", vocab_size=163840, n_embd=2304, n_head=32,
+            n_layer=5, block_size=4096, ffn_hidden=9216, norm_eps=1e-5,
+            kda_layers=[1, 2, 3, 5], full_attn_layers=[4], num_experts=256,
+            experts_per_token=8, moe_hidden=1024, routed_scaling=2.446,
+            held_experts=[0, 128], param_dtype="bfloat16")
+
+
+def test_kimi_linear_programs_update_the_pool_in_place(topo):
+    """What the chip's compiler makes of the kimi_linear family's three
+    programs at the serve cell's own size (256 slots, the five layers of
+    the share at published widths): every cache leaf is aliased input to
+    output in all three; the decode program names its three kernels (the
+    state update, the latent's write, the experts' grouped product) and
+    all of its scopes, and its temporaries stay far under the latent ring
+    (1.2 GB) and a KDA layer's state pool (0.54 GB): neither is copied,
+    selected over or laid out anew. The chip lays the ring of latents
+    (4096 x 576) out with the positions on the lanes, which is what
+    ``ops/kv_write.py:position_on_lanes`` says of it."""
+    from differential_transformer_replication_tpu.models import init_model
+    from differential_transformer_replication_tpu.models.decode import init_cache
+    from differential_transformer_replication_tpu.serving import engine
+
+    cfg, slots = ModelConfig(**KIMI), 256
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = place(jax.eval_shape(lambda k: init_model(k, cfg),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_cache(cfg, slots)))
+    ints, scalar = place(sds((slots,), jnp.int32)), place(sds((), jnp.int32))
+    prefill, decode = engine._build_step_fns(cfg, cfg.block_size)[:2]
+    programs = {
+        "decode": decode.lower(params, ints, ints,
+                               place(sds((slots,), jnp.bool_)), cache).compile(),
+        "prefill": prefill.lower(params, cache, scalar,
+                                 place(sds((1, 256), jnp.int32)), scalar,
+                                 scalar).compile(),
+        "reset": engine._reset_state_fn.lower(cache, scalar).compile(),
+    }
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(cache))
+    assert pool_bytes == 256 * (4 * (32 * 128 * 128 * 4 + 3 * 12288 * 2)
+                                + 4096 * 576 * 2)
+    for name, compiled in programs.items():
+        assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes, name
+    text = programs["decode"].as_text()
+    assert text.startswith("HloModule jit__decode")
+    assert assert_kernels_named(text, "_decode") == {
+        kernel_names.KDA_STATE_UPDATE, kernel_names.KV_ROW_WRITE,
+        kernel_names.MOE_GROUPED_MATMUL}
+    assert {"kda", "kda_conv", "kda_state", "mla", "mla_latent_write",
+            "mla_attend", "moe", "moe_router", "moe_experts", "moe_shared",
+            "ffn_norm", "ffn", "lm_head", "kv_merge"} <= scopes_in(text)
+    assert {"kda", "kda_conv", "kda_chunk", "mla", "mla_latent_write",
+            "mla_attend", "moe_experts"} <= scopes_in(
+                programs["prefill"].as_text())
+    assert assert_kernels_named(programs["prefill"].as_text(), "_prefill") == {
+        kernel_names.MOE_GROUPED_MATMUL}
+    assert programs["decode"].memory_analysis().temp_size_in_bytes < 0.4e9
+    assert programs["prefill"].memory_analysis().temp_size_in_bytes < 0.4e9
 
 
 def test_sampler_is_scoped(topo):
