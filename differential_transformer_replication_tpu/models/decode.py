@@ -47,6 +47,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from differential_transformer_replication_tpu.config import ModelConfig
 from differential_transformer_replication_tpu.models.generate import sample_token
@@ -70,7 +71,10 @@ from differential_transformer_replication_tpu.ops.decode_attention import (
     dequantize_kv,
     quantize_kv,
 )
-from differential_transformer_replication_tpu.ops.kv_write import write_rows
+from differential_transformer_replication_tpu.ops.kv_write import (
+    position_on_lanes,
+    write_rows,
+)
 from differential_transformer_replication_tpu.ops.lambdas import OUTPUT_SCALE
 from differential_transformer_replication_tpu.ops.streams import (
     NEG_INF,
@@ -971,12 +975,66 @@ def _write_scatter(layer_cache: dict, rows: dict, where: jnp.ndarray,
     return out
 
 
-def _attend_own_ring(cfg: ModelConfig, qs, pos, layer_cache, p_attn,
+# Rows of the slot pool that the own-ring attend covers in one block: it
+# runs the blocks up to the one holding the highest ACTIVE row and no
+# further (the scheduler admits into the lowest free slot, so the live
+# slots crowd the low indices). 32 read on the chip (PERF.md section 6,
+# PR 33): a block of 16 costs a full pool 4% of its step, 64 reads twice
+# the rows at the chat cell's occupancy.
+ATTEND_BLOCK_ROWS = 32
+
+
+def _fused_attend(cfg: ModelConfig) -> bool:
+    """The one read of ``decode_attention_impl`` outside ``config.py``."""
+    return cfg.decode_attention_impl == "pallas"
+
+
+def own_ring_attend(cfg: ModelConfig, paged: bool) -> bool:
+    """Whether the L = 1 step over this pool attends each row over its
+    own ring in XLA (:func:`_attend_own_ring`, the first line of
+    :func:`_pool_seam`'s table), the attend that :func:`attend_rows`
+    bounds: the engine asks, to know what its ``decode`` span may say."""
+    return not paged and cfg.model not in HYBRID and not _fused_attend(cfg)
+
+
+def attend_rows(active) -> "int | jnp.ndarray":
+    """Rows of the pool that :func:`_attend_own_ring` reads for the mask
+    ``active`` (B,): whole blocks of ``ATTEND_BLOCK_ROWS`` up to the one
+    that holds the highest active row, 0 with none active, never more
+    than B. THE rule, stated once: the decode program calls it on the
+    traced mask, the engine on the NumPy mask it built (the ``decode``
+    span's ``attend_rows``), and both read the same number."""
+    B = active.shape[0]
+    R = min(ATTEND_BLOCK_ROWS, B)
+    xp = np if isinstance(active, np.ndarray) else jnp
+    span = xp.max(xp.where(active, xp.arange(1, B + 1), 0))  # highest + 1
+    rows = xp.minimum((span + R - 1) // R * R, B)
+    return int(rows) if xp is np else rows
+
+
+def _attend_own_ring(cfg: ModelConfig, active, qs, pos, layer_cache, p_attn,
                      layer_idx):
     """``attend`` for one row a slot of the slot pool, in XLA: each row
     over its own ring, a length-1 :func:`forward_chunk`'s attend
     (:func:`_chunk_attend`) under ``vmap``. The serve cells measure this
-    one."""
+    one.
+
+    With a mask (the engine's step) the rows go through that ``vmap`` a
+    block of ``ATTEND_BLOCK_ROWS`` at a time, in a loop that stops after
+    the block of the highest active row (:func:`attend_rows`): the rings
+    past it are not read, and their rows come out as zeros. The trip
+    count is traced, so one program serves every mask. A row that is
+    read has exactly the math of the whole-pool ``vmap``, which
+    ``active=None`` keeps (no loop).
+
+    The loop takes a K or V leaf as the chip holds it (ring on the
+    lanes: ``ops/kv_write.py:position_on_lanes``), read row-major, and
+    swaps a block's two last axes back. The compiler lays a loop's body
+    out before it sees the caller: handed the leaf as it is, the body
+    expects it row-major and every block goes through a copy in VMEM
+    first (3.1 ms a step for 2.1 at one block, 16.2 for 8.5 at a full
+    pool: my chip run, PR 33); handed the view, the slice fuses into the
+    score and value fusions, which read the pool where it lies."""
 
     def one(q, at, ring):
         # re-add the batch-1 axis forward_chunk's layout has
@@ -985,7 +1043,36 @@ def _attend_own_ring(cfg: ModelConfig, qs, pos, layer_cache, p_attn,
         return _chunk_attend(q, p_attn, ring, at, layer_idx, cfg)
 
     ring_axes = {key: KV_CACHE_BATCH_AXIS[key] for key in layer_cache}
-    return jax.vmap(one, in_axes=(0, 0, ring_axes))(qs, pos, layer_cache)
+    rows_attend = jax.vmap(one, in_axes=(0, 0, ring_axes))
+    if active is None:
+        return rows_attend(qs, pos, layer_cache)
+
+    B = pos.shape[0]
+    R = min(ATTEND_BLOCK_ROWS, B)
+    # K and V: (.., B, H, M, features); a scale plane has no feature axis
+    swapped = {key for key, leaf in layer_cache.items()
+               if leaf.ndim == ring_axes[key] + 4
+               and position_on_lanes(*leaf.shape[-2:])}
+
+    def chip_view(rows: dict) -> dict:  # its own inverse
+        return {key: jnp.swapaxes(leaf, -1, -2) if key in swapped else leaf
+                for key, leaf in rows.items()}
+
+    pool = chip_view(layer_cache)
+    take = jax.lax.dynamic_slice_in_dim
+
+    def block(i, out):
+        # the last block of a pool that R does not divide starts early
+        # and recomputes rows the block before it wrote: the same values
+        start = jnp.minimum(i * R, B - R)
+        rings = chip_view({key: take(leaf, start, R, axis=ring_axes[key])
+                           for key, leaf in pool.items()})
+        heads = rows_attend(take(qs, start, R), take(pos, start, R), rings)
+        return jax.lax.dynamic_update_slice_in_dim(out, heads, start, 0)
+
+    out = jax.eval_shape(rows_attend, qs, pos, layer_cache)
+    return jax.lax.fori_loop(0, (attend_rows(active) + R - 1) // R, block,
+                             jnp.zeros(out.shape, out.dtype))
 
 
 def _attend_pool(cfg: ModelConfig, shape: tuple, page_tables, fused: bool,
@@ -1037,6 +1124,12 @@ def _pool_seam(cfg: ModelConfig, shape: tuple, pos: jnp.ndarray, active,
     pages, 1 or L           ``_write_scatter``        pool     | pool
     ======================  ========================  ====================
 
+    ``active`` (the rows that write; None = all) bounds both halves of
+    the first line: ``_write_ring`` moves a block only for a row that
+    writes, and the XLA own-ring attend reads the pool only up to the
+    block of the highest active row (:func:`attend_rows`; with None it
+    reads every ring). The other attends read the pool whole.
+
     ``write(layer_cache, rows) -> layer_cache`` takes what
     :func:`_store_rows` made; ``attend(qs, pos, layer_cache, p_attn,
     layer_idx)`` takes the rows' queries as :func:`_chunk_qkv` leaves
@@ -1051,9 +1144,9 @@ def _pool_seam(cfg: ModelConfig, shape: tuple, pos: jnp.ndarray, active,
     else:
         write = partial(_write_scatter, pos=pos,
                         where=jnp.asarray(where, jnp.int32).reshape(-1))
-    fused = cfg.decode_attention_impl == "pallas"
+    fused = _fused_attend(cfg)
     if own_ring and not fused:
-        return write, partial(_attend_own_ring, cfg)
+        return write, partial(_attend_own_ring, cfg, active)
     return write, partial(_attend_pool, cfg, shape, page_tables, fused)
 
 
@@ -1080,8 +1173,12 @@ def _decode_step(params: dict, tokens: jnp.ndarray, pos, cache: list,
     A row's math is a length-1 chunk's and its matmuls run at M = N, so a
     served token's logits equal a chunk's up to the reassociation of a
     reduction (tests/test_decode_rows.py states the tolerance). Rows that
-    write nothing run the same math on whatever their slot holds (static
-    shapes are the point); their logits mean nothing. The engine's
+    write nothing run the same projections, FFN and head on whatever
+    their slot holds (static shapes are the point) and their logits mean
+    nothing; whether their attention runs at all is the bound
+    ``attend``'s business (the XLA own-ring attend skips the blocks of
+    rows past the highest active one and hands those rows zeros, so no
+    caller may read an inactive row's logits). The engine's
     admission guards own the concrete-position validity rules
     (serving/engine.py submit, ``generate_cached``'s checks); everything
     here is traced."""
@@ -1131,7 +1228,9 @@ def forward_decode_pool(
     verify sub-step, the model drafter's rounds and ``generate_cached``'s
     loop all run this, on either layout and either
     ``decode_attention_impl`` (:func:`_pool_seam`). On the slot pool a
-    row that is not ``active`` leaves its ring as it is. With
+    row that is not ``active`` leaves its ring as it is and its logits
+    mean nothing (the XLA attention stops at the highest active row:
+    :func:`attend_rows`). With
     ``page_tables`` the physical place of every K/V row comes from
     runtime int32 tables, so pages can be allocated, freed, shared and
     forked between calls with ZERO recompiles (tests/test_pages.py), and

@@ -82,6 +82,7 @@ from differential_transformer_replication_tpu.config import (
 from differential_transformer_replication_tpu.models.decode import (
     KV_CACHE_BATCH_AXIS,
     apply_logit_pipeline,
+    attend_rows,
     copy_cache_pages,
     forward_chunk,
     forward_decode_pool,
@@ -93,6 +94,7 @@ from differential_transformer_replication_tpu.models.decode import (
     reset_slot_state,
     STATE_LEAVES,
     kv_store_dtype,
+    own_ring_attend,
     quality_vector,
     scatter_slot_cache,
 )
@@ -164,6 +166,15 @@ _STAT_SPEC = {
     "decode_tokens": (
         "serving_decode_tokens_total",
         "Tokens generated by batched decode steps.",
+    ),
+    "decode_attend_rows": (
+        "serving_decode_attend_rows_total",
+        "Rows of the slot pool the XLA decode attention read, summed over "
+        "decode steps: whole blocks up to the highest active slot "
+        "(models/decode.py attend_rows); over iterations x num_slots it "
+        "is the share of the pool a step reads. 0 on the paths that "
+        "attend the pool another way (pages, the fused kernel, the "
+        "hybrid families).",
     ),
     "completed": (
         "serving_requests_completed_total",
@@ -396,10 +407,15 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
         row in one (B*H,)-grid call per layer) is bound there, not here.
 
         tokens/pos/active: (B,) runtime arrays. Inactive rows run the
-        same math on garbage inputs (static shapes are the point); the
-        write takes ``active`` as its mask, so a mid-prefill or free
-        slot's ring is left as it is, and the step's cache traffic is
-        the rows it writes, in place in the donated pool. A family with
+        projections, the FFN and the head on garbage inputs (static
+        shapes are the point) and their logits mean nothing; the write
+        takes ``active`` as its mask, so a mid-prefill or free slot's
+        ring is left as it is and the step's cache traffic is the rows
+        it writes, in place in the donated pool, and the XLA attention
+        takes it as its bound: it reads the pool in blocks of rows up to
+        the highest active one (models/decode.py ``attend_rows``, which
+        the ``decode`` span carries), the trip count a runtime value of
+        this ONE program. A family with
         routed experts (``num_experts``) returns a third item, the step's
         expert load (models/decode.py:``_hybrid_decode``).
         """
@@ -981,6 +997,9 @@ class ServingEngine:
         self._rows = self.serving.num_slots + (
             1 if self._spec_k and not self._paged else 0
         )
+        # whether the L = 1 step's attention stops at the highest active
+        # slot (models/decode.py attend_rows): then its span says so
+        self._own_ring_attend = own_ring_attend(cfg, paged=self._paged)
         self._drafter = None
         self._spec_fn = None
         self._spec_window = cfg.block_size
@@ -1708,6 +1727,10 @@ class ServingEngine:
             # its span carries the trace ids it advanced so a stitched
             # timeline shows which requests shared each iteration
             decode_args = {"iteration": iteration, "active": len(active)}
+            if self._own_ring_attend:
+                # what the step's attention reads of the pool: the rule
+                # the program runs on this mask, read on the host
+                decode_args["attend_rows"] = attend_rows(mask)
             if self._tracing:
                 tids = [
                     s.trace.trace_id for s in active
@@ -1752,6 +1775,9 @@ class ServingEngine:
             with self.tracer.span("emit", iteration=iteration):
                 now = time.perf_counter()
                 self.stats.inc("decode_tokens", len(active))
+                if self._own_ring_attend:
+                    self.stats.inc("decode_attend_rows",
+                                   decode_args["attend_rows"])
                 for s in active:
                     self._emit(
                         s, int(sampled[s.index]), now, finished,

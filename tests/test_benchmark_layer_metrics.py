@@ -468,6 +468,39 @@ def test_reader_with_nothing_to_read_returns_none(metric):
         entry["unit"], entry["layer"], entry["moves"])
 
 
+@pytest.mark.parametrize("rows, want", [
+    ((32, 64), 48.0),      # the mean over the window's decode spans
+    ((None, 32), 32.0),    # a span without the argument is no step of it
+    ((None, None), None),  # the parent: `active` alone
+    (None, None),          # no record at all
+], ids=["both", "one", "parent", "no-spans"])
+def test_decode_attn_rows_read_per_step_by_hand(rows, want):
+    """PR 33: the decode spans' ``attend_rows`` (models/decode.py
+    ``attend_rows`` of the engine's mask), a step; None, never 0 and
+    never an exception, for a program that records none."""
+    metric = "decode_attn_rows_read_per_step"
+    rec = None
+    if rows is not None:
+        rec = _recorded()
+        given = iter(rows)
+        rec.spans = [
+            (n, a, b, dict(args, attend_rows=r) if n == "decode" and (
+                r := next(given)) is not None else args)
+            for n, a, b, args in rec.spans]
+        # a step after the window is not counted
+        rec.spans.append(("decode", 1.5, 1.6, {"active": 1,
+                                               "attend_rows": 256}))
+    got = read(metric, _serve_run(rec))
+    assert got == want and (want is None or isinstance(got, float))
+    entry = next(m for m in harness.load_benchmark()["per_layer"]
+                 if m["name"] == metric)
+    assert entry["workloads"] == [SERVE_CELL]
+    decl = harness.load_json("layer_metrics", metric + ".json")
+    assert (decl["unit"], decl["layer"], decl["moves"]) == (
+        entry["unit"], entry["layer"], entry["moves"]) == (
+        "rows", "decode step and kernel", "itl_mean_ms")
+
+
 # -- the engine's spans, from a real engine under its runner ------------------
 
 STAMPED = {"schedule", "prefill", "prefill_call", "first_token",
@@ -631,4 +664,6 @@ def test_tracing_off_sends_every_site_to_the_noop_tracer(monkeypatch):
         if name == "prefill":
             assert set(args) == {"iteration", "chunks"}
         if name == "decode":
-            assert set(args) == {"iteration", "active"}
+            # `attend_rows` (PR 33) is one number, which the engine's
+            # counter `decode_attend_rows` takes whether traced or not
+            assert set(args) == {"iteration", "active", "attend_rows"}

@@ -28,6 +28,7 @@ pins that the step stays written once.
 """
 
 import ast
+import contextlib
 from functools import lru_cache
 from pathlib import Path
 
@@ -36,7 +37,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from differential_transformer_replication_tpu.config import ModelConfig
+from differential_transformer_replication_tpu.config import (
+    ModelConfig,
+    ServingConfig,
+)
 from differential_transformer_replication_tpu.kernel_names import (
     FUSED_ADD_NORM_FWD,
     FUSED_FFN_FWD,
@@ -44,7 +48,9 @@ from differential_transformer_replication_tpu.kernel_names import (
 )
 from differential_transformer_replication_tpu.models import init_model
 from differential_transformer_replication_tpu.models.decode import (
+    ATTEND_BLOCK_ROWS,
     KV_CACHE_BATCH_AXIS,
+    attend_rows,
     forward_chunk,
     forward_decode_pool,
     forward_decode_spec,
@@ -54,6 +60,7 @@ from differential_transformer_replication_tpu.models.decode import (
     scatter_slot_cache,
 )
 from differential_transformer_replication_tpu.serving.engine import (
+    ServingEngine,
     _build_step_fns,
 )
 
@@ -387,6 +394,150 @@ def test_every_binding_of_the_seam_matches_a_length_1_chunk_a_row(
                     _f32(have[key]), _f32(want[key]), err_msg=key)
 
 
+# ---------------------------------------------------------------------------
+# The XLA own-ring attend stops at the highest active row (PR 33)
+# ---------------------------------------------------------------------------
+
+# two whole blocks of ATTEND_BLOCK_ROWS and six rows more: the last block
+# starts early and overlaps the one before it
+POOL_ROWS = 2 * ATTEND_BLOCK_ROWS + 6
+# mask: (the active rows, the rows the attend reads for them)
+MASKS = {
+    "none": ([], 0),
+    "first": ([0], ATTEND_BLOCK_ROWS),
+    "last": ([POOL_ROWS - 1], POOL_ROWS),
+    # ends in the middle of the second block
+    "scattered": ([1, 5, ATTEND_BLOCK_ROWS + 1, ATTEND_BLOCK_ROWS + 8],
+                  2 * ATTEND_BLOCK_ROWS),
+    "all": (list(range(POOL_ROWS)), POOL_ROWS),
+}
+ROPE_LEN = 128
+# An int8 store rounds a row's K/V on its way in. The two programs'
+# float32 differs in its last place (a block's matmuls run at M = 32, the
+# pool's at M = 70: reassociation, as TOLERANCE says), so a value on a
+# rounding boundary can land one int8 step apart, and the logits that
+# read it move: one row of the 420 here reads 6.3e-5 (ndiff, all rows).
+INT8_LOGITS = 5e-4
+
+
+@lru_cache(maxsize=None)
+def _bounded(family, store):
+    """``(cfg, params, the engine's decode program, the whole-pool step,
+    tokens, positions)``: the two programs differ in ``active`` alone,
+    the mask against None."""
+    cfg = ModelConfig(
+        model=family, vocab_size=67, n_embd=64, n_head=2, n_layer=2,
+        block_size=32, dropout=0.0, n_terms=3, compute_dtype="float32",
+        kv_cache_dtype=store,
+    )
+    params = init_model(jax.random.PRNGKey(0), cfg)
+    whole = jax.jit(lambda t, at, pool: forward_decode_pool(
+        params, t, at, pool, cfg, rope_len=ROPE_LEN))
+    rng = np.random.default_rng(21)
+    tokens = rng.integers(0, cfg.vocab_size, POOL_ROWS).astype(np.int32)
+    # control's rings have rolled (RoPE: positions past the block); diff's
+    # learned positions end at the block
+    pos = rng.integers(*((40, 100) if family == "control" else (0, 32)),
+                       POOL_ROWS).astype(np.int32)
+    return (cfg, params, _build_step_fns(cfg, ROPE_LEN)[1], whole,
+            jnp.asarray(tokens), jnp.asarray(pos))
+
+
+@pytest.mark.parametrize("mask", sorted(MASKS))
+@pytest.mark.parametrize("store", ["auto", "int8"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_own_ring_attend_stops_at_the_highest_active_row(family, store,
+                                                             mask):
+    """The engine's step (a mask) against the same step with
+    ``active=None`` (the whole-pool ``vmap``, no loop): every ACTIVE row
+    has its logits and leaves its ring as there; the rule the program
+    runs and the one the engine reads on the host agree on the rows
+    read; the rows past them come out finite and the same whatever
+    their rings hold (they were not read); and ONE program serves every
+    mask."""
+    cfg, params, decode, whole, tokens, pos = _bounded(family, store)
+    rows, read = MASKS[mask]
+    active = np.zeros(POOL_ROWS, bool)
+    active[rows] = True
+    assert attend_rows(active) == read
+    assert int(jax.jit(attend_rows)(jnp.asarray(active))) == read
+
+    logits, pool = decode(params, tokens, pos, jnp.asarray(active),
+                          _random_pool(cfg, POOL_ROWS, 23))
+    want_logits, want_pool = whole(tokens, pos,
+                                   _random_pool(cfg, POOL_ROWS, 23))
+    assert decode._cache_size() == 1
+    assert np.isfinite(np.asarray(logits)).all()
+    tol = TOLERANCE["float32"]
+    np.testing.assert_allclose(np.asarray(logits)[rows],
+                               np.asarray(want_logits)[rows], rtol=0,
+                               atol=INT8_LOGITS if store == "int8" else tol)
+    before = _random_pool(cfg, POOL_ROWS, 23)
+    for have, want, kept in zip(pool, want_pool, before):
+        for key in want:
+            axis = KV_CACHE_BATCH_AXIS[key]
+
+            def take(leaf, idx):
+                return np.take(_f32(leaf), idx, axis)
+
+            np.testing.assert_allclose(
+                take(have[key], rows), take(want[key], rows), rtol=0,
+                atol=1 if have[key].dtype == jnp.int8 else tol, err_msg=key)
+            idle = np.flatnonzero(~active)
+            np.testing.assert_array_equal(take(have[key], idle),
+                                          take(kept[key], idle), key)
+    if read < POOL_ROWS:
+        other, _ = decode(params, tokens, pos, jnp.asarray(active),
+                          _random_pool(cfg, POOL_ROWS, 29))
+        np.testing.assert_array_equal(np.asarray(other)[read:],
+                                      np.asarray(logits)[read:])
+        assert not np.array_equal(np.asarray(want_logits)[read:],
+                                  np.asarray(logits)[read:])
+
+
+class _Spans:
+    """The tracer's interface, keeping the spans the engine hands it."""
+    path, annotate = None, False
+
+    def __init__(self):
+        self.spans = []
+
+    def span(self, name, **args):
+        self.spans.append((name, args))
+        return contextlib.nullcontext()
+
+    def __getattr__(self, name):  # instant, counter, complete, flush, close
+        return lambda *a, **k: None
+
+
+@pytest.mark.parametrize("model, serving, read", [
+    ({}, dict(num_slots=40), ATTEND_BLOCK_ROWS),  # three requests: one block
+    ({}, dict(num_slots=4), 4),  # a pool smaller than a block: itself
+    (dict(decode_attention_impl="pallas"), dict(num_slots=40), None),
+    ({}, dict(num_slots=40, kv_page_size=8), None),
+], ids=["xla", "xla-small-pool", "pallas", "paged"])
+def test_the_decode_span_says_what_the_attend_read(model, serving, read):
+    """The engine puts the rule's value for the mask it built on the
+    ``decode`` span and sums it in ``decode_attend_rows``; a step whose
+    attention is bound another way (the fused kernel, pages) says
+    nothing, for it reads the pool another way."""
+    cfg = ModelConfig(model="diff", vocab_size=67, n_embd=64, n_head=2,
+                      n_layer=2, block_size=32, dropout=0.0,
+                      compute_dtype="float32", **model)
+    spans = _Spans()
+    eng = ServingEngine(
+        init_model(jax.random.PRNGKey(0), cfg), cfg,
+        ServingConfig(prefill_chunk=8, **serving),
+        tracer=spans)
+    eng.generate([[1, 2, 3], [4, 5], [6, 7, 8, 9]], max_new_tokens=4,
+                 temperature=0.0)
+    steps = [a for n, a in spans.spans if n == "decode"]
+    assert steps and all(0 < a["active"] <= 3 for a in steps)
+    assert [a.get("attend_rows") for a in steps] == [read] * len(steps)
+    assert eng.stats["decode_attend_rows"] == (read or 0) * len(steps)
+    assert "serving_decode_attend_rows_total" in eng.registry.render()
+
+
 PACKAGE = Path(__file__).resolve().parents[1] / (
     "differential_transformer_replication_tpu")
 
@@ -402,7 +553,9 @@ def _function_of(tree, node):
 def test_the_decode_step_stays_written_once():
     """The fork PR 30 closed cannot grow back unseen: in the package,
     outside ``config.py`` (which validates the option), ONE comparison
-    reads ``decode_attention_impl``, in ``models/decode.py:_pool_seam``;
+    reads ``decode_attention_impl``, in ``models/decode.py:_fused_attend``
+    (``_pool_seam`` binds by it, and through ``own_ring_attend`` the
+    engine learns what its ``decode`` span may say of the attention);
     and ``models/decode.py`` walks ``enumerate(params["blocks"], 1)`` in
     two functions, ``forward_chunk`` and the one decode step (the jamba
     family's loops zip its two kinds of layer and are not counted)."""
@@ -421,5 +574,5 @@ def test_the_decode_step_stays_written_once():
                     and ast.unparse(node.iter).startswith(
                         "enumerate(params['blocks']")):
                 loops.append(_function_of(tree, node))
-    assert compares == [("decode.py", "_pool_seam")]
+    assert compares == [("decode.py", "_fused_attend")]
     assert sorted(loops) == ["_decode_step", "forward_chunk"]

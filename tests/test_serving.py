@@ -348,6 +348,30 @@ class TestScheduler:
         assert [(c[0].index, c[1], c[2]) for c in s.plan()] == [
             (0, 0, 5), (1, 0, 4)]
 
+    @pytest.mark.parametrize("freed", [0, 2, 4])
+    def test_admission_takes_the_lowest_free_slot(self, freed):
+        """What the decode attention's bound leans on (models/decode.py
+        ``attend_rows``: it reads the pool up to the highest active
+        slot): with slots 0..4 of 8 held and one of them freed, the next
+        request goes into the freed slot, not past the held ones, so the
+        live slots keep to the low indices."""
+        s = self._sched(num_slots=8, prefill_chunk=8, prefill_budget=64)
+        self._submit(s, [4] * 5)
+        s.plan()
+        assert [sl.index for sl in s.slots if sl.state != FREE] == [
+            0, 1, 2, 3, 4]
+        s.retire(s.slots[freed])
+        assert s.slots[freed].state == FREE
+        from differential_transformer_replication_tpu.serving.request import (
+            Request,
+        )
+
+        s.submit(Request.make(99, [1] * 4), np.ones(4, np.int32), 0.0)
+        s.plan()
+        assert s.slots[freed].request.request_id == 99
+        assert [sl.index for sl in s.slots if sl.state != FREE] == [
+            0, 1, 2, 3, 4]
+
     def test_retire_frees_slot_for_next_request(self):
         s = self._sched(num_slots=1, prefill_chunk=8, prefill_budget=8)
         self._submit(s, [4, 4])

@@ -37,6 +37,9 @@ from differential_transformer_replication_tpu.models.common import (
     flash_bh_fn,
 )
 from differential_transformer_replication_tpu import kernel_names
+from differential_transformer_replication_tpu.models.decode import (
+    ATTEND_BLOCK_ROWS,
+)
 from differential_transformer_replication_tpu.ops.rope import rope_cos_sin
 
 # the module, not the function ops/__init__.py re-exports under the name
@@ -428,7 +431,9 @@ def test_decode_program_updates_the_pool_in_place(topo, impl, kv):
     The pool's layout on the chip follows from its shape (the ring on
     the lanes for the recipe's 512 x 96 and 512 x 192), so this is what
     keeps a later change — to the write, to what reads the pool after
-    it, or to the shapes — from quietly bringing the copies back."""
+    it, or to the shapes — from quietly bringing the copies back. The
+    XLA attention reads the pool a block of rows at a time inside a
+    ``while`` (PR 33), whose body is held to the same."""
     compiled, cache = _compile_decode(topo, impl, slots=256, kv=kv)
     text = compiled.as_text()
     leaves = jax.tree_util.tree_leaves(cache)
@@ -449,6 +454,14 @@ def test_decode_program_updates_the_pool_in_place(topo, impl, kv):
     # K and V (the scale planes are 1/96 of them and ride along)
     big = {(_HLO_DTYPES[layer[key].dtype.name], layer[key].size)
            for layer in cache for key in ("k", "v")}
+    if impl == "xla":
+        # nor one the size of a BLOCK of their rows (PR 33): the attend's
+        # loop slices a block of the pool where the score and value
+        # fusions read it. Handed the leaf in another layout than the
+        # chip's, the loop's body copies every block through VMEM first
+        # (models/decode.py:_attend_own_ring)
+        big |= {(dtype, size * ATTEND_BLOCK_ROWS // 256)
+                for dtype, size in big}
     # an instruction INSIDE a fusion is no buffer; the fusion's own
     # result, in the computation that calls it, is
     fused = set(re.findall(r"calls=%([\w.\-]+)", text))
