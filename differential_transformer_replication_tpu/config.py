@@ -18,7 +18,7 @@ import dataclasses
 from dataclasses import dataclass, field
 from typing import Any, Optional, Tuple
 
-MODEL_KINDS = ("control", "diff", "ndiff", "jamba", "kimi_linear")
+MODEL_KINDS = ("control", "diff", "ndiff", "jamba", "kimi_linear", "afmoe")
 
 # Fields only the ``jamba`` family reads. Another family given one of them
 # at a value other than its default is refused by name: a field that is
@@ -39,7 +39,20 @@ KIMI_LINEAR_FIELDS = (
     "experts_per_token", "moe_hidden", "first_dense_layers",
     "routed_scaling", "held_experts",
 )
-FAMILY_FIELDS = {"jamba": JAMBA_FIELDS, "kimi_linear": KIMI_LINEAR_FIELDS}
+# Fields only the ``afmoe`` family reads (the MLP's width, the K/V heads and
+# the norm's eps it shares with ``jamba``, the experts' fields with
+# ``kimi_linear``), refused the same way.
+AFMOE_FIELDS = (
+    "ffn_hidden", "kv_heads", "norm_eps", "head_dim", "layer_types",
+    "sliding_window", "sliding_ring", "rope_theta", "num_experts",
+    "experts_per_token", "moe_hidden", "first_dense_layers",
+    "routed_scaling", "held_experts",
+)
+FAMILY_FIELDS = {"jamba": JAMBA_FIELDS, "kimi_linear": KIMI_LINEAR_FIELDS,
+                 "afmoe": AFMOE_FIELDS}
+# ``layer_types`` as the published config.json spells them, and the mixer
+# kind ``ModelConfig.layer_kinds`` gives each
+AFMOE_LAYER_TYPES = {"sliding_attention": "window", "full_attention": "full"}
 
 
 @dataclass(frozen=True)
@@ -201,16 +214,37 @@ class ModelConfig:
     first_dense_layers: int = 1
     routed_scaling: float = 1.0
     held_experts: Tuple[int, int] = (0, 0)
+    # -- the ``afmoe`` family's fields (AFMOE_FIELDS; models/afmoe.py) ------
+    # The published ``layer_types`` list, a name a layer: a
+    # ``"sliding_attention"`` layer rotates q and k at their absolute
+    # position (``rope_theta``, dimension i paired with i + d/2) and sees
+    # the last ``sliding_window`` positions, itself among them; a
+    # ``"full_attention"`` layer carries no position and sees every
+    # earlier one. ``head_dim`` is a head's width (0 = n_embd // n_head;
+    # the published heads are wider than that). A sequence's cache holds
+    # rings of two lengths: a full layer's is ``block_size`` long (it
+    # cannot roll, so it bounds the sequence), a sliding layer's
+    # ``sliding_ring`` (0 = min(block_size, 2 * sliding_window)); what it
+    # holds past the window is the longest chunk of tokens that may be
+    # written at once at a rolled position (:meth:`ring_slack`).
+    head_dim: int = 0
+    layer_types: Tuple[str, ...] = ()
+    sliding_window: int = 0
+    sliding_ring: int = 0
+    rope_theta: float = 10000.0
 
     def __post_init__(self):
         if self.model not in MODEL_KINDS:
             raise ValueError(f"model must be one of {MODEL_KINDS}, got {self.model!r}")
-        for name in ("kda_layers", "full_attn_layers", "held_experts"):
+        for name in ("kda_layers", "full_attn_layers", "held_experts",
+                     "layer_types"):
             # a configuration file gives lists; the config is a jit key
             object.__setattr__(self, name, tuple(getattr(self, name)))
         self._check_family_fields()
         self._check_jamba_fields()
         self._check_kimi_linear_fields()
+        self._check_afmoe_fields()
+        self._check_expert_fields()
         if self.attention_impl not in ("xla", "pallas"):
             raise ValueError(
                 "attention_impl must be 'xla' or 'pallas', got "
@@ -283,12 +317,20 @@ class ModelConfig:
                 f"{self.kda_layers} and {self.full_attn_layers}"
             )
         for name in ("kda_head_dim", "kv_lora_rank", "qk_nope_head_dim",
-                     "qk_rope_head_dim", "v_head_dim", "moe_hidden",
-                     "experts_per_token"):
+                     "qk_rope_head_dim", "v_head_dim"):
             if getattr(self, name) < 1:
                 raise ValueError(f"{name} must be >= 1, got {getattr(self, name)}")
         if self.kda_conv < 2:
             raise ValueError("kda_conv must be >= 2 (a carried window)")
+
+    def _check_expert_fields(self):
+        """The fields of a family whose later layers hold routed experts
+        (kimi_linear, afmoe)."""
+        if self.model not in ("kimi_linear", "afmoe"):
+            return
+        for name in ("moe_hidden", "experts_per_token"):
+            if getattr(self, name) < 1:
+                raise ValueError(f"{name} must be >= 1, got {getattr(self, name)}")
         if not 0 <= self.first_dense_layers <= self.n_layer:
             raise ValueError(
                 f"first_dense_layers ({self.first_dense_layers}) must lie in "
@@ -305,6 +347,51 @@ class ModelConfig:
                 raise ValueError(
                     f"held_experts {self.held_experts} must be a non-empty "
                     f"range within [0, num_experts = {self.num_experts}]"
+                )
+
+    def _check_afmoe_fields(self):
+        if self.model != "afmoe":
+            return
+        for name in ("attention_impl", "ffn_impl", "decode_attention_impl"):
+            if getattr(self, name) != "xla":
+                raise ValueError(
+                    f"the afmoe family takes {name}='xla' only, got "
+                    f"{getattr(self, name)!r}: the kernels that 'pallas' "
+                    "selects (ops/flash.py, ops/fused_ffn.py, "
+                    "ops/decode_attention.py) know neither a window, grouped "
+                    "K/V heads, a gated output nor experts. The option "
+                    "chooses nothing for this family: its decode step reads "
+                    "the rings through its own kernel whatever it says "
+                    "(ops/ring_attention.py, ring_gqa_decode_fwd), its "
+                    "prefill attention and projections are XLA's"
+                )
+        if self.dropout:
+            raise ValueError("the afmoe family has no dropout")
+        if len(self.layer_types) != self.n_layer or any(
+                t not in AFMOE_LAYER_TYPES for t in self.layer_types):
+            raise ValueError(
+                f"layer_types must name each of the {self.n_layer} layers "
+                f"as one of {sorted(AFMOE_LAYER_TYPES)}, got "
+                f"{self.layer_types}"
+            )
+        if self.n_head % self.n_kv_head:
+            raise ValueError(
+                f"n_head ({self.n_head}) must divide by kv_heads "
+                f"({self.n_kv_head})"
+            )
+        if self.head_size % 2:
+            raise ValueError(
+                f"head_dim ({self.head_size}) must be even: the sliding "
+                "layers rotate dimension i with i + head_dim / 2"
+            )
+        if "window" in self.layer_kinds():
+            W, R = self.sliding_window, self.ring_len("window")
+            if not 1 <= W <= R <= self.block_size:
+                raise ValueError(
+                    f"sliding_window ({W}) <= sliding_ring ({R}) <= "
+                    f"block_size ({self.block_size}) must hold, all >= 1: a "
+                    "sliding layer's ring holds its window, and a sequence "
+                    "ends where the full layers' ring does"
                 )
 
     def _check_jamba_fields(self):
@@ -356,7 +443,7 @@ class ModelConfig:
 
     @property
     def resolved_norm_eps(self) -> float:
-        """eps of the jamba and kimi_linear families' RMSNorm."""
+        """eps of the jamba, kimi_linear and afmoe families' RMSNorm."""
         return self.norm_eps or 1e-6
 
     @property
@@ -370,11 +457,38 @@ class ModelConfig:
 
     @property
     def cannot_roll(self) -> bool:
-        """Whether a sequence's cache cannot run past ``block_size``: diff's
-        learned position table cannot roll, and jamba's and kimi_linear's
-        attention layers carry no position at all, so a rolled ring would
-        turn them into sliding-window layers without a word."""
-        return self.model in ("diff", "jamba", "kimi_linear")
+        """Whether a sequence cannot run past ``block_size``, because SOME
+        layer's ring cannot roll: diff's learned position table cannot, and
+        jamba's and kimi_linear's attention layers and afmoe's full layers
+        carry no position at all, so a rolled ring would turn them into
+        sliding-window layers without a word. afmoe's sliding layers roll
+        inside that bound, in rings of their own length
+        (:meth:`ring_len`)."""
+        return self.model in ("diff", "jamba", "kimi_linear", "afmoe")
+
+    def ring_len(self, kind: str) -> int:
+        """Positions the ring of a layer of mixer ``kind`` holds a slot
+        (:meth:`layer_kinds`): ``block_size``, but for a sliding layer."""
+        if kind != "window":
+            return self.block_size
+        return self.sliding_ring or min(self.block_size,
+                                        2 * self.sliding_window)
+
+    def ring_window(self, kind: str) -> int:
+        """Positions a query of a layer of ``kind`` sees, itself among
+        them: the whole ring, but for a sliding layer."""
+        return self.sliding_window if kind == "window" else self.block_size
+
+    @property
+    def ring_slack(self) -> int:
+        """The longest chunk that may be written at once into a sliding
+        layer's ring at a rolled position: what the ring holds past the
+        window, so that the chunk's writes evict only positions none of
+        its rows may see (``last - ring < row - window`` for every row).
+        ``block_size`` for a model without sliding layers."""
+        if "window" not in self.layer_kinds():
+            return self.block_size
+        return max(1, self.ring_len("window") - self.sliding_window)
 
     @property
     def held_expert_range(self) -> Tuple[int, int]:
@@ -383,16 +497,21 @@ class ModelConfig:
         return (lo, hi) if hi else (0, self.num_experts)
 
     def mlp_kinds(self) -> Tuple[str, ...]:
-        """``"dense"`` or ``"moe"`` for every layer, 0-based."""
-        if self.model != "kimi_linear":
+        """``"dense"`` or ``"moe"`` for every layer, 0-based: a model with
+        experts holds them in the layers past the first
+        ``first_dense_layers``."""
+        if not self.num_experts:
             return ("dense",) * self.n_layer
         return tuple("dense" if i < self.first_dense_layers else "moe"
                      for i in range(self.n_layer))
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """The mixer of every layer, 0-based: ``"attention"``, ``"mamba"``
-        (jamba), ``"kda"`` or ``"mla"`` (kimi_linear). The reference
-        families attend in every layer."""
+        (jamba), ``"kda"`` or ``"mla"`` (kimi_linear), ``"window"`` or
+        ``"full"`` (afmoe). The reference families attend in every
+        layer."""
+        if self.model == "afmoe":
+            return tuple(AFMOE_LAYER_TYPES[t] for t in self.layer_types)
         if self.model == "kimi_linear":
             return tuple("kda" if i in self.kda_layers else "mla"
                          for i in range(1, self.n_layer + 1))
@@ -413,7 +532,9 @@ class ModelConfig:
         it because each head carries a doubled value
         (diff_transformer.py:111, Ndiff_transformer.py:164).
         """
-        if self.model in ("control", "jamba", "kimi_linear"):
+        if self.head_dim:
+            return self.head_dim
+        if self.model in ("control", "jamba", "kimi_linear", "afmoe"):
             return self.n_embd // self.n_head
         return self.n_embd // (self.n_head * 2)
 
@@ -421,7 +542,7 @@ class ModelConfig:
     def value_size(self) -> int:
         """Per-head value width: doubled for differential variants
         (diff_transformer.py:30, Ndiff_transformer.py:59)."""
-        if self.model in ("control", "jamba", "kimi_linear"):
+        if self.model in ("control", "jamba", "kimi_linear", "afmoe"):
             return self.head_size
         return self.head_size * 2
 

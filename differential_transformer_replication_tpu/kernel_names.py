@@ -53,6 +53,10 @@ FUSED_ADD_NORM_BWD = "fused_add_norm_bwd"
 # ops/decode_attention.py (all four entry points share one call)
 DECODE_ATTENTION = "decode_dattn_fwd"
 
+# ops/ring_attention.py (the afmoe family's decode step: a row's live ring
+# blocks of every K/V head a grid step, grouped query heads)
+RING_GQA_DECODE = "ring_gqa_decode_fwd"
+
 # ops/kv_write.py (one call a cache leaf: K, V and the int8 scale planes)
 KV_ROW_WRITE = "kv_row_write"
 
@@ -69,6 +73,7 @@ KDA_STATE_UPDATE = "kda_state_update"
 # product, a row tile of one expert a grid step)
 MOE_GROUPED_MATMUL = "moe_grouped_matmul"
 
+RING = (RING_GQA_DECODE,)
 FLASH = (
     FLASH_FWD, FLASH_FWD_TILED, FLASH_FWD_CHUNK, FLASH_FWD_TM,
     FLASH_FWD_TM_PACKED, FLASH_BWD_DQ, FLASH_BWD_DKV, FLASH_BWD_DQ_TILED,
@@ -93,5 +98,7 @@ FAMILIES = {
     "ssm": SSM,
     "kda": KDA,
     "moe": MOE,
+    "ring_attention": RING,
 }
-ALL = FLASH + FUSED_FFN + FUSED_NORM + DECODE + KV_WRITE + SSM + KDA + MOE
+ALL = (FLASH + FUSED_FFN + FUSED_NORM + DECODE + KV_WRITE + SSM + KDA + MOE
+       + RING)

@@ -42,8 +42,9 @@ at the input instead. Generation is eval-mode: no dropout anywhere.
 
 from __future__ import annotations
 
+import math
 from functools import partial
-from typing import Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +53,7 @@ import numpy as np
 from differential_transformer_replication_tpu.config import ModelConfig
 from differential_transformer_replication_tpu.models.generate import sample_token
 from differential_transformer_replication_tpu.models import (
+    afmoe,
     common,
     jamba,
     kimi_linear,
@@ -76,6 +78,9 @@ from differential_transformer_replication_tpu.ops.kv_write import (
     write_rows,
 )
 from differential_transformer_replication_tpu.ops.lambdas import OUTPUT_SCALE
+from differential_transformer_replication_tpu.ops.ring_attention import (
+    ring_decode_attention,
+)
 from differential_transformer_replication_tpu.ops.streams import (
     NEG_INF,
     diff_coeffs,
@@ -85,8 +90,7 @@ from differential_transformer_replication_tpu.ops.streams import (
 
 
 def _n_streams(cfg: ModelConfig) -> int:
-    return {"control": 1, "diff": 2, "ndiff": cfg.n_terms,
-            "jamba": 1, "kimi_linear": 1}[cfg.model]
+    return {"control": 1, "diff": 2, "ndiff": cfg.n_terms}.get(cfg.model, 1)
 
 
 def _uses_rope(cfg: ModelConfig) -> bool:
@@ -103,8 +107,12 @@ def _uses_rope(cfg: ModelConfig) -> bool:
 KV_CACHE_BATCH_AXIS = {"k": 1, "v": 0, "k_scale": 1, "v_scale": 0,
                        "ssm": 0, "conv": 0, "kda": 0, "latent": 0}
 STATE_LEAVES = ("ssm", "conv", "kda")
-# the families whose layers are of several kinds (:func:`_hybrid_chunk`)
-HYBRID = ("jamba", "kimi_linear")
+# the families whose layers are of several kinds (:func:`_hybrid_chunk`),
+# each with the module that holds its ``embed``
+HYBRID = {"jamba": jamba, "kimi_linear": kimi_linear, "afmoe": afmoe}
+# Ring positions that a prefill chunk's blocked attention
+# (:func:`_attend_ring_blocked`) reads at a time
+ATTEND_KEY_BLOCK = 1024
 
 
 def has_recurrent_state(cfg: ModelConfig) -> bool:
@@ -239,12 +247,16 @@ def init_cache(cfg: ModelConfig, batch_size: int) -> list:
     in ssm_state_dtype, conv (B, K-1, Di)}``, zeros being a sequence's
     start. The ``kimi_linear`` family's: a KDA layer ``{kda (B, H, d, d)
     float32, conv (B, K-1, 3 H d)}``, an MLA layer ``{latent (B, 1, M,
-    rank + rope)}``, the ring of what it caches a position."""
+    rank + rope)}``, the ring of what it caches a position. The ``afmoe``
+    family's rings are of two lengths in one slot (``cfg.ring_len``): a
+    full layer's ``block_size`` long, a sliding layer's
+    ``sliding_ring``."""
     S = _n_streams(cfg)
-    H, d, dv, M = cfg.n_kv_head, cfg.head_size, cfg.value_size, cfg.block_size
+    H, d, dv = cfg.n_kv_head, cfg.head_size, cfg.value_size
     store = kv_store_dtype(cfg)
     cache = []
     for kind in cfg.layer_kinds():
+        M = cfg.ring_len(kind)
         if kind == "mamba":
             conv, ssm = jamba.zero_state(cfg, batch_size)
             cache.append({"ssm": ssm, "conv": conv})
@@ -419,11 +431,13 @@ def _chunk_attend(
     return out.reshape(B, L, -1)  # concat heads
 
 
-def _ring_visible(pos, L: int, M: int, W: int) -> jnp.ndarray:
-    """(L, M) bool: which ring slots row ``l`` of a chunk at ``pos`` may
-    see, after the chunk's own write (:func:`_chunk_attend`)."""
+def _ring_visible(pos, L: int, M: int, W: int, first=0,
+                  count: int = 0) -> jnp.ndarray:
+    """(L, M) bool: which slots of a ring of M row ``l`` of a chunk at
+    ``pos`` may see, after the chunk's own write (:func:`_chunk_attend`);
+    with ``count``, of the ``count`` slots from ``first`` on."""
     rows = pos + jnp.arange(L)[:, None]
-    slots = jnp.arange(M)[None, :]
+    slots = first + jnp.arange(count or M)[None, :]
     last = pos + L - 1
     held = last - jax.lax.rem(
         jnp.asarray(last, jnp.int32) - slots, jnp.asarray(M, jnp.int32)
@@ -529,9 +543,19 @@ def forward_chunk(
         if cfg.model in HYBRID and pos + L > M:
             raise ValueError(
                 f"chunk [{pos}, {pos + L}) exceeds block_size {M}: the "
-                f"{cfg.model} family's attention layers carry no position, "
-                "so a rolled ring would silently become sliding-window "
-                "attention"
+                f"{cfg.model} family's "
+                f"{'full ' if 'full' in cfg.layer_kinds() else ''}attention "
+                "layers carry no position, so a rolled ring would silently become "
+                "sliding-window attention"
+            )
+        if ("window" in cfg.layer_kinds() and L > cfg.ring_slack
+                and pos + L > cfg.ring_len("window")):
+            raise ValueError(
+                f"chunk [{pos}, {pos + L}) of {L} tokens rolls the sliding "
+                f"layers' ring of {cfg.ring_len('window')} and is longer "
+                f"than its slack past the window of {cfg.sliding_window} "
+                f"({cfg.ring_slack}): its writes would evict keys that its "
+                "earlier rows still see; feed it in chunks of the slack"
             )
         if cfg.model == "diff" and pos + L > M:
             raise ValueError(
@@ -563,9 +587,9 @@ def forward_chunk(
         return _hybrid_chunk(params, tokens, pos, cache, cfg, window, valid)
     if valid is not None:
         raise ValueError(
-            f"forward_chunk(valid=...) pads a chunk of the jamba and "
-            f"kimi_linear families only; the {cfg.model!r} family runs "
-            "whole chunks"
+            f"forward_chunk(valid=...) pads a chunk of the jamba, "
+            f"kimi_linear and afmoe families only; the {cfg.model!r} family "
+            "runs whole chunks"
         )
     x, cos, sin = _embed_chunk(params, tokens, pos, cfg, rope_len)
     new_cache = []
@@ -586,22 +610,128 @@ def forward_chunk(
 
 
 # ---------------------------------------------------------------------------
-# The hybrid families (models/jamba.py, models/kimi_linear.py): layers of
-# several kinds in one stack, ONE loop over (mixer kind, MLP kind) a layer
-# for a prefill chunk and one for the decode step. An attention layer keeps
-# K/V rings like the other families' (``kv_heads`` heads, each shared by a
-# group of query heads, no position information) and an MLA layer a ring of
-# latents; a Mamba or a KDA layer keeps a recurrent state a slot, which a
-# prefill chunk carries on from where the last chunk left it and a decode
-# step overwrites for the active slots, in place in the donated pool.
-# Nothing masks a state by position: a slot that takes a new sequence has
-# to be zeroed first (:func:`reset_slot_state`; serving/engine.py does so
-# on admission). A layer's kinds are read off its leaves.
+# The hybrid families (models/jamba.py, models/kimi_linear.py,
+# models/afmoe.py): layers of several kinds in one stack, ONE loop over
+# (mixer kind, MLP kind) a layer for a prefill chunk and one for the decode
+# step. An attention layer keeps K/V rings like the other families'
+# (``kv_heads`` heads, each shared by a group of query heads) and an MLA
+# layer a ring of latents; a Mamba or a KDA layer keeps a recurrent state a
+# slot, which a prefill chunk carries on from where the last chunk left it
+# and a decode step overwrites for the active slots, in place in the
+# donated pool. Nothing masks a state by position: a slot that takes a new
+# sequence has to be zeroed first (:func:`reset_slot_state`;
+# serving/engine.py does so on admission). A layer's kinds are read off
+# its leaves, but an ``afmoe`` layer's attention kind, which
+# ``cfg.layer_kinds`` tells. A slot's rings may be of several lengths
+# (``cfg.ring_len``: afmoe's sliding layers keep a shorter ring than its
+# full layers, and roll in it); where a row's K/V lands and what a row
+# sees is worked out once a ring length (:class:`_Ring`), not once a layer.
 # ---------------------------------------------------------------------------
 
 
-def _mixer_chunk(x, blk: dict, layer_cache: dict, cfg: ModelConfig, slot,
-                 visible, valid):
+#: The attention kinds that read a ring in blocks and mask each block from
+#: ``_Ring.window`` (:func:`_attend_ring_blocked`, ``ring_decode_attention``;
+#: ``models/afmoe.py``'s gated attention): their rings get no ``visible``.
+BLOCKED_KINDS = ("window", "full")
+
+
+class _Ring(NamedTuple):
+    """Where the rows of a chunk or a step stand in the rings of one
+    length: ``at``, where their K/V goes (a chunk: the first row's ring
+    position; a step: a target a row, -1 for none); ``visible``, what each
+    row sees of the ring after the write ((L, M) a chunk, (B, 1, M) a
+    step; None for the ``BLOCKED_KINDS``); ``window``, the positions a
+    row sees, itself among them."""
+    at: jnp.ndarray
+    visible: Optional[jnp.ndarray]
+    window: int
+
+
+def _rings(cfg: ModelConfig, one) -> dict:
+    """``{mixer kind: one(ring length, window, blocked)}`` for the kinds
+    of ``cfg``'s layers that keep a ring, ``one`` called once a length
+    (a family's rings are all read in blocks or none is)."""
+    by_len, out = {}, {}
+    for kind in cfg.layer_kinds():
+        if kind in ("mamba", "kda") or kind in out:
+            continue
+        M = cfg.ring_len(kind)
+        if M not in by_len:
+            by_len[M] = one(M, cfg.ring_window(kind), kind in BLOCKED_KINDS)
+        out[kind] = by_len[M]
+    return out
+
+
+def _write_chunk_wrapping(layer_cache: dict, ks: jnp.ndarray, v: jnp.ndarray,
+                          slot) -> dict:
+    """:func:`_write_chunk` for a ring that rolls under multi-token chunks
+    (afmoe's sliding layers): the chunk's rows may run over the ring's end
+    and go on at its start, which one ``dynamic_update_slice`` cannot
+    write. The chunk is laid at the head of a ring of zeros, turned by
+    ``slot``, and selected over what the ring held: dense passes over one
+    slot's ring (10 MB at the published sizes), no scatter."""
+    out = {}
+    for key, rows in (("k", ks.transpose(0, 1, 3, 2, 4)),
+                      ("v", v.transpose(0, 2, 1, 3))):
+        ring = layer_cache[key]
+        R, L = ring.shape[-2], rows.shape[-2]
+        laid = jnp.pad(rows.astype(ring.dtype),
+                       [(0, 0)] * (rows.ndim - 2) + [(0, R - L), (0, 0)])
+        fresh = jax.lax.rem(jnp.arange(R) - slot + R, R) < L
+        out[key] = jnp.where(fresh[:, None],
+                             jnp.roll(laid, slot, axis=-2), ring)
+    return out
+
+
+def _attend_ring_blocked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                         pos, window: int) -> jnp.ndarray:
+    """A chunk's queries ``q`` (B, L, H, d), the first at absolute
+    position ``pos``, over rings ``k``, ``v`` (B, KV, M, d) that already
+    hold the chunk's own keys; every K/V head serves H / KV query heads;
+    returns (B, L, H * d). ``jamba.attend`` under :func:`_ring_visible`,
+    but the ring is read ``ATTEND_KEY_BLOCK`` positions at a time, with a
+    running softmax (float32), and only as far as it has been written: the
+    trip count is traced (``ceil((pos + L) / block)``, the whole ring once
+    it has rolled), so one program serves every position. A chunk of
+    1,024 at a ring's start then writes and re-reads float32 scores of
+    1,024 x 1,024 a head, not of 1,024 x 8,192: 0.39 ms a layer there and
+    1.9 ms at position 6,144 of the full ring, where a Pallas flash kernel
+    over the same blocks (tiles of 128 chunk positions x 512 ring
+    positions) took 0.50 and 2.66 and was taken out again (my chip run,
+    PR 36: PERF.md section 6)."""
+    B, L, H, d = q.shape
+    KV, M = k.shape[1], k.shape[2]
+    KB = math.gcd(M, ATTEND_KEY_BLOCK)
+    f32 = jnp.float32
+    qg = q.reshape(B, L, KV, H // KV, d)
+    take = jax.lax.dynamic_slice_in_dim
+
+    def block(j, carry):
+        top, total, acc = carry
+        scores = jnp.einsum("blkgd,bkmd->bkglm", qg, take(k, j * KB, KB, 2)
+                            ).astype(f32) / math.sqrt(d)
+        vis = _ring_visible(pos, L, M, window, j * KB, KB)[None, None, None]
+        new_top = jnp.maximum(
+            top, jnp.max(jnp.where(vis, scores, NEG_INF), axis=-1))
+        probs = jnp.where(vis, jnp.exp(scores - new_top[..., None]), 0.0)
+        keep = jnp.exp(top - new_top)
+        acc = acc * keep[..., None] + jnp.einsum(
+            "bkglm,bkmd->bkgld", probs.astype(q.dtype), take(v, j * KB, KB, 2)
+        ).astype(f32)
+        return new_top, total * keep + jnp.sum(probs, axis=-1), acc
+
+    stat = jnp.full((B, KV, H // KV, L), NEG_INF, f32)
+    blocks = jnp.minimum((jnp.asarray(pos, jnp.int32) + L - 1) // KB + 1,
+                         M // KB)
+    _, total, acc = jax.lax.fori_loop(
+        0, blocks, block,
+        (stat, jnp.zeros_like(stat), jnp.zeros(stat.shape + (d,), f32)))
+    out = (acc / total[..., None]).astype(q.dtype)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, L, H * d)
+
+
+def _mixer_chunk(x, blk: dict, layer_cache: dict, cfg: ModelConfig, pos,
+                 kind: str, ring: Optional[_Ring], valid):
     """A layer's mixer over a chunk ``x`` (B, L, E): ``(its output, the
     layer's cache after the chunk)``."""
     if "mamba" in blk:
@@ -626,19 +756,34 @@ def _mixer_chunk(x, blk: dict, layer_cache: dict, cfg: ModelConfig, slot,
                 latent = jax.lax.dynamic_update_slice(
                     layer_cache["latent"],
                     rows[:, None].astype(layer_cache["latent"].dtype),
-                    (0, 0, slot, 0))
+                    (0, 0, ring.at, 0))
             with jax.named_scope("mla_attend"):
                 a = kimi_linear.mla_attend(h, blk["mla"], latent[:, 0],
-                                           visible)
+                                           ring.visible)
         return a, {"latent": latent}
     with jax.named_scope("attn_norm"):
         h = jamba.norm(x, blk["ln1"], cfg)
+    if kind in BLOCKED_KINDS:
+        with jax.named_scope("attn"):
+            q, k, v, g = afmoe.qkvg(h, blk["attn"], cfg,
+                                    pos + jnp.arange(x.shape[1]), kind)
+            with jax.named_scope("kv_write"):
+                write = (_write_chunk_wrapping if kind == "window"
+                         else _write_chunk)
+                layer_cache = write(layer_cache, k[None], v, ring.at)
+            with jax.named_scope("attn_" + kind):
+                o = _attend_ring_blocked(
+                    q, layer_cache["k"][0].astype(q.dtype),
+                    layer_cache["v"].astype(q.dtype), pos, ring.window)
+            a = afmoe.gate_out(o, g, blk["attn"])
+        with jax.named_scope("attn_norm"):
+            return jamba.norm(a, blk["ln1_post"], cfg), layer_cache
     with jax.named_scope("attn"):
         q, k, v = jamba.qkv(h, blk["attn"])
         with jax.named_scope("kv_write"):
-            layer_cache = _write_chunk(layer_cache, k[None], v, slot)
+            layer_cache = _write_chunk(layer_cache, k[None], v, ring.at)
         k_c, v_c = _dequant_layer(layer_cache, q.dtype)
-        a = jamba.attend(q, k_c[0], v_c, visible) @ blk["attn"][
+        a = jamba.attend(q, k_c[0], v_c, ring.visible) @ blk["attn"][
             "out"]["w"].astype(q.dtype)
     return a, layer_cache
 
@@ -646,9 +791,16 @@ def _mixer_chunk(x, blk: dict, layer_cache: dict, cfg: ModelConfig, slot,
 def _mlp(x, blk: dict, cfg: ModelConfig, live=None):
     """A layer's MLP of either kind on the residual ``x``: ``(x + y, the
     held experts' load or None)``."""
+    if "ln2_post" in blk:  # afmoe: either kind between two norms
+        return afmoe.mlp(x, blk, cfg, live)
     if "moe" in blk:
         return kimi_linear.moe(x, blk, cfg, live)
     return jamba.ffn(x, blk, cfg), None
+
+
+def _embed(params: dict, tokens: jnp.ndarray, cfg: ModelConfig):
+    """The family's own ``embed`` (afmoe scales the token table)."""
+    return HYBRID[cfg.model].embed(params, tokens, cfg)
 
 
 def _hybrid_chunk(params: dict, tokens: jnp.ndarray, pos, cache: list,
@@ -660,15 +812,24 @@ def _hybrid_chunk(params: dict, tokens: jnp.ndarray, pos, cache: list,
     ``kimi_linear.kda_chunk``); the attention layers write the padding's
     keys and values (latents) into ring positions past the sequence's
     end, which no query sees (a query sees no later position) and which
-    the tokens that come to stand there overwrite before they attend."""
-    L, M = tokens.shape[1], cfg.block_size
-    slot = jax.lax.rem(jnp.asarray(pos, jnp.int32), M)
-    visible = _ring_visible(pos, L, M, int(window) or M)
-    x = jamba.embed(params, tokens, cfg)
+    the tokens that come to stand there overwrite before they attend. In
+    a ring that rolls (afmoe's sliding layers) the chunk, padding
+    included, evicts the positions a ring length before its own, which
+    lie outside the window of each of its rows and of every later row as
+    long as the chunk is no longer than the ring's slack past the window
+    (``cfg.ring_slack``: serving/engine.py holds ``prefill_chunk`` to it,
+    :func:`forward_chunk` a concrete chunk)."""
+    L = tokens.shape[1]
+    rings = _rings(cfg, lambda M, W, blocked: _Ring(
+        jax.lax.rem(jnp.asarray(pos, jnp.int32), M),
+        None if blocked else _ring_visible(pos, L, M, int(window) or W),
+        int(window) or W))
+    x = _embed(params, tokens, cfg)
     new_cache = []
-    for blk, layer_cache in zip(params["blocks"], cache):
-        a, layer_cache = _mixer_chunk(x, blk, layer_cache, cfg, slot,
-                                      visible, valid)
+    for blk, layer_cache, kind in zip(params["blocks"], cache,
+                                      cfg.layer_kinds()):
+        a, layer_cache = _mixer_chunk(x, blk, layer_cache, cfg, pos, kind,
+                                      rings.get(kind), valid)
         new_cache.append(layer_cache)
         x, _ = _mlp(x + a, blk, cfg)
     if valid is not None:
@@ -678,7 +839,7 @@ def _hybrid_chunk(params: dict, tokens: jnp.ndarray, pos, cache: list,
 
 
 def _mixer_step(x, blk: dict, layer_cache: dict, cfg: ModelConfig, live,
-                targets, visible):
+                pos, kind: str, ring: Optional[_Ring]):
     """A layer's mixer for one token a slot, ``x`` (B, E): ``(its output,
     the layer's cache after the step)``."""
     if "mamba" in blk:
@@ -703,23 +864,34 @@ def _mixer_step(x, blk: dict, layer_cache: dict, cfg: ModelConfig, live,
                 layer_cache = _write_ring(
                     layer_cache,
                     {"latent": rows[:, None].astype(
-                        layer_cache["latent"].dtype)}, targets)
+                        layer_cache["latent"].dtype)}, ring.at)
             with jax.named_scope("mla_attend"):
                 a = kimi_linear.mla_attend(
                     h[:, None], blk["mla"], layer_cache["latent"][:, 0],
-                    visible)[:, 0]
+                    ring.visible)[:, 0]
         return a, layer_cache
+    gated = kind in BLOCKED_KINDS
     with jax.named_scope("attn_norm"):
         h = jamba.norm(x, blk["ln1"], cfg)
     with jax.named_scope("attn"):
-        q, k, v = jamba.qkv(h, blk["attn"])
+        if gated:
+            q, k, v, g = afmoe.qkvg(h, blk["attn"], cfg, pos, kind)
+        else:
+            q, k, v = jamba.qkv(h, blk["attn"])
         with jax.named_scope("kv_write"):
             layer_cache = _write_ring(
-                layer_cache, _store_rows(layer_cache, k[None], v), targets)
+                layer_cache, _store_rows(layer_cache, k[None], v), ring.at)
         k_c, v_c = _dequant_layer(layer_cache, q.dtype)
-        a = jamba.attend(q[:, None], k_c[0], v_c, visible)[:, 0] @ blk[
-            "attn"]["out"]["w"].astype(q.dtype)
-    return a, layer_cache
+        if not gated:
+            a = jamba.attend(q[:, None], k_c[0], v_c, ring.visible)[:, 0] @ blk[
+                "attn"]["out"]["w"].astype(q.dtype)
+            return a, layer_cache
+        with jax.named_scope("attn_" + kind):
+            # a row's live ring blocks alone, not every slot's ring whole
+            o = ring_decode_attention(q, k_c[0], v_c, pos, live, ring.window)
+        a = afmoe.gate_out(o, g, blk["attn"])
+    with jax.named_scope("attn_norm"):
+        return jamba.norm(a, blk["ln1_post"], cfg), layer_cache
 
 
 def _hybrid_decode(params: dict, tokens: jnp.ndarray, pos, cache: list,
@@ -727,25 +899,34 @@ def _hybrid_decode(params: dict, tokens: jnp.ndarray, pos, cache: list,
     """The hybrid families' decode step over the whole slot pool, one
     batch: ``((B, V) logits, updated cache, expert load)``. The attention
     layers write their row into the ring in place (``ops/kv_write.py``)
-    and read the pool; the recurrent layers advance the active slots'
-    states (``ops/ssm.py``, ``ops/kda.py``). A row that is not ``active``
+    and read the pool (afmoe's a row's live ring blocks alone:
+    ``ops/ring_attention.py``); the recurrent layers advance the active
+    slots' states (``ops/ssm.py``, ``ops/kda.py``). A row that is not ``active``
     leaves every leaf of its slot as it is and meets no expert. ``load``
     (3,) int32, summed over the expert layers: the (row, expert)
     assignments that fell on held experts, the largest count on one
     expert, and the held experts that got a row at all (whose weights the
     step had to read); None for a family without experts."""
-    B, M = tokens.shape[0], cfg.block_size
+    B = tokens.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
-    targets = _write_targets(pos, active, M)
     live = jnp.ones((B,), bool) if active is None else active
-    # pos < M always (the families cannot roll): slot m holds a live key
-    # iff m <= pos
-    visible = jnp.arange(M)[None, None, :] <= pos[:, None, None]
-    x = jamba.embed(params, tokens, cfg)  # (B, E)
+
+    def ring(M: int, W: int, blocked: bool) -> _Ring:
+        # a ring that is not read in blocks cannot roll (jamba's and
+        # kimi_linear's: pos < M always), so slot m holds a live key iff
+        # m <= pos; the blocked kinds' rows find what they see in
+        # ops/ring_attention.py
+        visible = (None if blocked else
+                   jnp.arange(M)[None, None, :] <= pos[:, None, None])
+        return _Ring(_write_targets(pos, active, M), visible, W)
+
+    rings = _rings(cfg, ring)
+    x = _embed(params, tokens, cfg)  # (B, E)
     new_cache, loads = [], []
-    for blk, layer_cache in zip(params["blocks"], cache):
-        a, layer_cache = _mixer_step(x, blk, layer_cache, cfg, live,
-                                     targets, visible)
+    for blk, layer_cache, kind in zip(params["blocks"], cache,
+                                      cfg.layer_kinds()):
+        a, layer_cache = _mixer_step(x, blk, layer_cache, cfg, live, pos,
+                                     kind, rings.get(kind))
         new_cache.append(layer_cache)
         x, load = _mlp(x + a, blk, cfg, live)
         if load is not None:
@@ -754,6 +935,22 @@ def _hybrid_decode(params: dict, tokens: jnp.ndarray, pos, cache: list,
     with jax.named_scope("lm_head"):
         return (jamba.lm_head(params, x, cfg), new_cache,
                 sum(loads) if loads else None)
+
+
+def live_kv(pos: np.ndarray, active: np.ndarray, window: int) -> dict:
+    """What a decode step's rows hold of their rings, from the positions
+    and the mask the engine built (NumPy: no device read), summed over the
+    ACTIVE rows: ``live_window``, the positions a sliding layer's ring
+    holds live, ``min(pos + 1, window)`` a row; ``live_full``, a full
+    layer's, ``pos + 1``; ``rolled``, the rows whose position has passed
+    the window (``pos >= window``), for which a sliding layer reads less
+    than a full one. The ``decode`` span's ``kv`` argument (a family with
+    sliding layers); the rule stands here, beside the program that reads
+    the rings."""
+    at = pos[active].astype(np.int64)
+    return {"live_window": int(np.minimum(at + 1, window).sum()),
+            "live_full": int((at + 1).sum()),
+            "rolled": int((at >= window).sum())}
 
 
 def reset_slot_state(cache: list, slot) -> list:
@@ -1348,7 +1545,7 @@ def generate_cached(
         raise ValueError(
             f"prompt ({T0}) + max_new_tokens ({max_new_tokens}) exceeds "
             f"block_size ({M}): the {cfg.model} family's cache cannot roll "
-            "(its attention layers carry no position)"
+            "(its attention layers, afmoe's full ones, carry no position)"
         )
     if cfg.model == "diff" and T0 + max_new_tokens > M:
         raise ValueError(
@@ -1369,7 +1566,13 @@ def generate_cached(
         Tc = T0
     total = Tc + max_new_tokens
     cache = init_cache(cfg, B)
-    logits, cache = forward_chunk(params, idx_cond, 0, cache, cfg, rope_len=total)
+    # a prompt that rolls a model's sliding rings goes in chunks of the
+    # rings' slack (forward_chunk refuses a longer one there)
+    rolls = "window" in cfg.layer_kinds() and Tc > cfg.ring_len("window")
+    step = cfg.ring_slack if rolls else Tc
+    for at in range(0, Tc, step):
+        logits, cache = forward_chunk(params, idx_cond[:, at:at + step], at,
+                                      cache, cfg, rope_len=total)
     samples = jnp.zeros((B, max_new_tokens), idx.dtype)
 
     rng, key0 = jax.random.split(rng)

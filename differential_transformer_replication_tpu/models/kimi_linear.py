@@ -229,15 +229,14 @@ def mla_attend(h: jnp.ndarray, p: dict, latent: jnp.ndarray,
 # -- the experts ---------------------------------------------------------------
 
 
-def moe(x: jnp.ndarray, blk: dict, cfg: ModelConfig,
-        live: Optional[jnp.ndarray] = None):
-    """The block's second half on the residual ``x`` (.., E) with a
-    layer of experts: returns ``(x + y, load (G,) int32)``, ``load`` the
+def moe_mlp(h: jnp.ndarray, p: dict, cfg: ModelConfig,
+            live: Optional[jnp.ndarray] = None):
+    """A layer of experts on the normed rows ``h`` (.., E), ``p`` the
+    layer's ``moe`` leaves: ``(y (.., E), load (G,) int32)``, the held
+    experts' weighted sum beside the shared expert's output, and the
     assignments that fell on each held expert from the rows that are
-    ``live`` (.., bool; None = all)."""
-    with jax.named_scope("ffn_norm"):
-        h = norm(x, blk["ln2"], cfg)
-    p = blk["moe"]
+    ``live`` (.., bool; None = all). The ``afmoe`` family's layer is this
+    one too (models/afmoe.py)."""
     with jax.named_scope("moe"):
         rows = h.reshape(-1, h.shape[-1])
         with jax.named_scope("moe_router"):
@@ -251,7 +250,19 @@ def moe(x: jnp.ndarray, blk: dict, cfg: ModelConfig,
                 None if live is None else live.reshape(-1))
         with jax.named_scope("moe_shared"):
             y = y + gated_mlp(rows, p["shared"])
-        return x + y.reshape(x.shape), load
+        return y.reshape(h.shape), load
+
+
+def moe(x: jnp.ndarray, blk: dict, cfg: ModelConfig,
+        live: Optional[jnp.ndarray] = None):
+    """The block's second half on the residual ``x`` (.., E) with a
+    layer of experts: returns ``(x + y, load (G,) int32)``
+    (:func:`moe_mlp`)."""
+    with jax.named_scope("ffn_norm"):
+        h = norm(x, blk["ln2"], cfg)
+    y, load = moe_mlp(h, blk["moe"], cfg, live)
+    with jax.named_scope("moe"):
+        return x + y, load
 
 
 # -- the model -----------------------------------------------------------------

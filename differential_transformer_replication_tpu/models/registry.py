@@ -3,8 +3,8 @@
 The reference selects models by commenting code blocks in and out
 (train.py:205-230); here it is a first-class dispatch on
 ``ModelConfig.model`` covering the same three families, and ``jamba``
-(models/jamba.py) and ``kimi_linear`` (models/kimi_linear.py), whose
-layers are of several kinds.
+(models/jamba.py), ``kimi_linear`` (models/kimi_linear.py) and ``afmoe``
+(models/afmoe.py), whose layers are of several kinds.
 """
 
 from __future__ import annotations
@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from differential_transformer_replication_tpu.config import ModelConfig
 from differential_transformer_replication_tpu.models import (
+    afmoe,
     control,
     diff,
     jamba,
@@ -24,7 +25,7 @@ from differential_transformer_replication_tpu.models import (
 )
 
 _MODULES = {"control": control, "diff": diff, "ndiff": ndiff,
-            "jamba": jamba, "kimi_linear": kimi_linear}
+            "jamba": jamba, "kimi_linear": kimi_linear, "afmoe": afmoe}
 
 
 def init_model(key: jax.Array, cfg: ModelConfig) -> dict:

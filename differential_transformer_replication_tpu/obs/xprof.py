@@ -62,6 +62,7 @@ KERNEL_BUCKETS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("ssm", _NAMES.SSM),
     ("kda", _NAMES.KDA),
     ("moe", _NAMES.MOE),
+    ("ring_attention", _NAMES.RING),
     ("fused_ffn", _NAMES.FUSED_FFN + _NAMES.FUSED_NORM),
     ("flash_attention", _NAMES.FLASH),
     ("collectives", ("all-reduce", "all-gather", "reduce-scatter",

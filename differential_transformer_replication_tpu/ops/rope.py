@@ -74,3 +74,22 @@ def apply_rope(
     rot_odd = x_even * s + x_odd * c
     out = jnp.stack([rot_even, rot_odd], axis=-1).reshape(x.shape)
     return out.astype(orig_dtype)
+
+
+def apply_rope_half(x: jnp.ndarray, pos: jnp.ndarray,
+                    theta: float = 10000.0) -> jnp.ndarray:
+    """Rotate ``x`` (..., d) at the absolute positions ``pos``, which
+    broadcast against ``x``'s leading axes, in the ``rotate_half`` pairing
+    (dimension i turns with i + d/2, as ``transformers`` rotates; the
+    reference families' :func:`apply_rope` pairs 2i with 2i + 1): the
+    ``afmoe`` family's sliding layers. The angles come from the positions
+    themselves, in float32, so no table bounds a sequence; the result is
+    cast back to ``x``'s dtype."""
+    d = x.shape[-1]
+    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angles = jnp.asarray(pos, jnp.float32)[..., None] * freqs
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., : d // 2], xf[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)

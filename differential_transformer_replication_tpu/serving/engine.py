@@ -87,7 +87,9 @@ from differential_transformer_replication_tpu.models.decode import (
     forward_chunk,
     forward_decode_pool,
     forward_decode_spec,
+    HYBRID,
     has_recurrent_state,
+    live_kv,
     gather_slot_cache,
     init_cache,
     init_cache_paged,
@@ -175,6 +177,14 @@ _STAT_SPEC = {
         "is the share of the pool a step reads. 0 on the paths that "
         "attend the pool another way (pages, the fused kernel, the "
         "hybrid families).",
+    ),
+    "decode_live_kv": (
+        "serving_decode_live_kv_positions_total",
+        "Ring positions that hold a live key and value for the rows of "
+        "decode steps, summed over the layers (a family whose slots hold "
+        "rings of two lengths: min(pos + 1, sliding_window) a sliding "
+        "layer, pos + 1 a full one; models/decode.py live_kv). 0 for "
+        "the other families.",
     ),
     "completed": (
         "serving_requests_completed_total",
@@ -624,6 +634,49 @@ def _refuse_for_recurrent_state(cfg: ModelConfig,
         )
 
 
+def _refuse_for_two_ring_lengths(cfg: ModelConfig,
+                                 serving: ServingConfig) -> None:
+    """The engine features that assume ONE ring length a slot, each
+    refused by name for a family whose slot holds rings of two (``afmoe``:
+    a sliding layer's ring is shorter than a full layer's and rolls):
+    ``serving/pages.py`` keeps one page table a slot for every layer, and
+    a page, a rolled-back draft or a stashed slot means the same ring
+    positions in every layer."""
+    lacks = ("the {} family keeps rings of two lengths a slot (a sliding "
+             "layer's of {}, a full layer's of {}), and {}")
+    asked = (
+        ("the host tier (host_tier_bytes) stashes and restores a slot as "
+         "pages of one page table", serving.host_tier_bytes > 0),
+        ("speculation (spec_mode) verifies several rows a slot in one "
+         "step, whose writes into a rolled sliding ring would evict keys "
+         "that the step's earlier rows still see, and whose rejected rows "
+         "cannot be rolled back there", serving.spec_enabled()),
+        ("paging (kv_page_size > 0; with it the prefix cache) maps every "
+         "layer's ring through ONE page table a slot, block_size long",
+         serving.paged()),
+    )
+    for what, on in asked:
+        if on:
+            raise ValueError(lacks.format(
+                cfg.model, cfg.ring_len("window"), cfg.block_size, what))
+    if cfg.kv_cache_dtype == "int8":
+        raise ValueError(
+            f"kv_cache_dtype='int8' is not available for the {cfg.model} "
+            "family: its prefill writes a chunk into a rolling ring by a "
+            "select over the float ring, and its grouped-query decode path "
+            "reads float rings"
+        )
+    if serving.prefill_chunk > cfg.ring_slack:
+        raise ValueError(
+            f"prefill_chunk ({serving.prefill_chunk}) exceeds what the "
+            f"{cfg.model} family's sliding rings hold past their window "
+            f"(sliding_ring {cfg.ring_len('window')} - sliding_window "
+            f"{cfg.sliding_window} = {cfg.ring_slack}): a longer chunk "
+            "written at a rolled position would evict keys that its "
+            "earlier rows still see (models/decode.py)"
+        )
+
+
 # fold_in salt distinguishing a draft position's ACCEPT-draw key from
 # its token key: the t-th token's decisions stay a pure function of
 # (request seed, t) — never of slot, batch composition, or how many
@@ -946,6 +999,16 @@ class ServingEngine:
         self._recurrent = has_recurrent_state(cfg)
         if self._recurrent:
             _refuse_for_recurrent_state(cfg, self.serving)
+        # a slot holds rings of two lengths (afmoe's sliding and full
+        # layers): what assumes one refuses here, and the decode span
+        # says what the rows hold of each (models/decode.py live_kv)
+        self._window_layers = cfg.layer_kinds().count("window")
+        if self._window_layers:
+            _refuse_for_two_ring_lengths(cfg, self.serving)
+        # the hybrid families' prefill program takes a chunk padded to
+        # the ladder's next shape (forward_chunk ``valid``): a prompt's
+        # tail is one program, not one a binary digit of its length
+        self._pads_tail = cfg.model in HYBRID
         self.max_total = self.serving.resolved_max_seq_len(cfg)
         # Paged KV cache (serving/pages.py): device KV lives in fixed
         # pages mapped through per-slot page tables; admission keys on
@@ -1078,7 +1141,7 @@ class ServingEngine:
             on_preempt=(
                 self._preempt_slot if self._tier is not None else None
             ),
-            pad_limit=self.cfg.block_size if self._recurrent else 0,
+            pad_limit=self.cfg.block_size if self._pads_tail else 0,
         )
         self._next_id = 0
         self._base_keys: dict = {}  # request_id -> np (2,) uint32 PRNG base
@@ -1162,14 +1225,17 @@ class ServingEngine:
         # identity gauge
         self.registry.gauge(
             "serving_state_pool_bytes",
-            "HBM bytes of the pool's state that is no K/V ring: every "
-            "Mamba or KDA layer's recurrent state and convolution window, "
-            "and every MLA layer's ring of latents; 0 for a family of K/V "
-            "rings.",
+            "HBM bytes of the pool's state that is no K/V ring of "
+            "block_size positions: every Mamba or KDA layer's recurrent "
+            "state and convolution window, every MLA layer's ring of "
+            "latents, and, where a slot holds K/V rings of two lengths "
+            "(afmoe), the rings of both; 0 for a family of K/V rings of "
+            "one length.",
         ).set(
             sum(leaf.nbytes for layer in self.cache
                 for key, leaf in layer.items()
-                if key in STATE_LEAVES or key == "latent")
+                if key in STATE_LEAVES or key == "latent"
+                or self._window_layers)
         )
         self.registry.gauge(
             "serving_kv_cache_bytes_per_slot",
@@ -1438,8 +1504,8 @@ class ServingEngine:
                        "table cannot roll with a KV cache (models/decode.py)"
                        if self.cfg.model == "diff" else
                        f"and the {self.cfg.model} family's cache cannot "
-                       "roll: its attention layers carry no position "
-                       "(models/decode.py)")
+                       "roll: its attention layers (afmoe's full ones) "
+                       "carry no position (models/decode.py)")
                 )
         else:
             if p.shape[0] > M:
@@ -1738,6 +1804,11 @@ class ServingEngine:
                 ]
                 if tids:
                     decode_args["trace_ids"] = tids
+            if self._window_layers:
+                # what the rows hold of their rings of either length:
+                # from the positions just built, no device read
+                decode_args["kv"] = live_kv(pos, mask,
+                                            self.cfg.sliding_window)
             load = ()
             if self.cfg.num_experts:
                 # filled in below, once the tokens' read has waited for
@@ -1778,6 +1849,13 @@ class ServingEngine:
                 if self._own_ring_attend:
                     self.stats.inc("decode_attend_rows",
                                    decode_args["attend_rows"])
+                if self._window_layers:
+                    kv = decode_args["kv"]
+                    self.stats.inc(
+                        "decode_live_kv",
+                        self._window_layers * kv["live_window"]
+                        + (self.cfg.n_layer - self._window_layers)
+                        * kv["live_full"])
                 for s in active:
                     self._emit(
                         s, int(sampled[s.index]), now, finished,
@@ -1835,7 +1913,7 @@ class ServingEngine:
                 "prefill_call", iteration=iteration, size=size
             ):
                 chunk = slot.prompt[start:start + size][None]
-                if self._recurrent:
+                if self._pads_tail:
                     # the ladder's shape that holds the chunk; what is
                     # past ``size`` is padding (Scheduler.plan)
                     tokens = np.zeros((1, padded_chunk(size)), np.int32)
@@ -2716,6 +2794,13 @@ class ServingEngine:
                 "Mamba or KDA layer's recurrent state has no page to ship "
                 "(it needs a snapshot of the state) — fall back to replay"
             )
+        if self._window_layers:
+            raise MigrateExportError(
+                f"live migration is not available for the {self.cfg.model} "
+                "family: the wire image ships K/V pages of ONE ring length "
+                "a slot, and this family's slots hold rings of two lengths "
+                "— fall back to replay"
+            )
 
     def export_slot_state(self, request_id: int,
                           dedup_pages: int = 0) -> bytes:
@@ -3570,7 +3655,7 @@ class ServingEngine:
             on_preempt=(
                 self._preempt_slot if self._tier is not None else None
             ),
-            pad_limit=self.cfg.block_size if self._recurrent else 0,
+            pad_limit=self.cfg.block_size if self._pads_tail else 0,
         )
         self.scheduler.queue.extend(preserved)
         self.stats.inc("engine_restarts")

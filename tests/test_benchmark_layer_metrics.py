@@ -79,11 +79,11 @@ BUCKET_OF_FAMILY = {"flash_attention": "flash_attention",
                     "fused_ffn": "fused_ffn", "fused_norm": "fused_ffn",
                     "decode_attention": "decode_attention",
                     "kv_write": "kv_write", "ssm": "ssm", "kda": "kda",
-                    "moe": "moe"}
+                    "moe": "moe", "ring_attention": "ring_attention"}
 # the benchmark's needles are frozen (PR 23): a kernel named after them
 # is one more Pallas kernel to that reader, by the call's target
 FROZEN_READER = {"kv_write": "pallas", "ssm": "pallas", "kda": "pallas",
-                 "moe": "pallas"}
+                 "moe": "pallas", "ring_attention": "pallas"}
 NAMES = [(fam, name) for fam, names in kernel_names.FAMILIES.items()
          for name in names]
 
@@ -109,7 +109,7 @@ def test_name_falls_in_its_bucket_under_both_readers(family, name):
 
 
 def test_table_is_whole_and_the_metrics_needles_are_disjoint():
-    assert len(kernel_names.ALL) == len(set(kernel_names.ALL)) == 22
+    assert len(kernel_names.ALL) == len(set(kernel_names.ALL)) == 23
     assert sorted(kernel_names.ALL) == sorted(n for _, n in NAMES)
     sets = {
         "attn": ["flash"],  # every needle-reader of the flash family

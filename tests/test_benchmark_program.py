@@ -32,6 +32,11 @@ _cases = _load(BENCH_DIR / "tests" / "test_benchmark_program.py",
                "benchmark_program_cases")
 globals().update({name: fn for name, fn in vars(_cases).items()
                   if name.startswith("test_")})
+# PR 36: the afmoe configuration's cases, a file of their own beside them
+_afmoe_cases = _load(BENCH_DIR / "tests" / "test_benchmark_afmoe.py",
+                     "benchmark_afmoe_cases")
+globals().update({name: fn for name, fn in vars(_afmoe_cases).items()
+                  if name.startswith("test_") or name == "planted_afmoe"})
 
 from lib import (  # noqa: E402
     harness,

@@ -50,6 +50,7 @@ dattn = importlib.import_module(
 KERNEL_MODULES = (
     "ops.flash", "ops.fused_ffn", "ops.fused_norm_residual",
     "ops.decode_attention", "ops.kv_write", "ops.ssm", "ops.kda", "ops.moe",
+    "ops.ring_attention",
 )
 
 # the recipe's widths (8L/768d, T=512, vocab 12000); the batch is cut, a
@@ -661,6 +662,72 @@ def test_kimi_linear_programs_update_the_pool_in_place(topo):
         kernel_names.MOE_GROUPED_MATMUL}
     assert programs["decode"].memory_analysis().temp_size_in_bytes < 0.4e9
     assert programs["prefill"].memory_analysis().temp_size_in_bytes < 0.4e9
+
+
+# -- the afmoe family at the published widths (Trinity-Large, a share) --------
+
+AFMOE = dict(model="afmoe", vocab_size=25024, n_embd=3072, n_head=48,
+             kv_heads=8, head_dim=128, n_layer=5, block_size=8192,
+             ffn_hidden=12288, norm_eps=1e-5,
+             layer_types=["sliding_attention", "sliding_attention",
+                          "full_attention", "sliding_attention",
+                          "sliding_attention"],
+             sliding_window=4096, sliding_ring=5120, num_experts=256,
+             experts_per_token=4, moe_hidden=3072, routed_scaling=2.448,
+             held_experts=[0, 16], param_dtype="bfloat16")
+
+
+def test_afmoe_programs_update_the_pool_of_two_ring_lengths_in_place(topo):
+    """What the chip's compiler makes of the afmoe family's two programs
+    at the serve cell's own size (64 slots of four rings of 5,120 and one
+    of 8,192 positions, the five layers of the share at published widths):
+    every cache leaf is aliased input to output; the decode program names
+    its kernels (the row write into rings of both lengths, the experts'
+    grouped product) and its scopes, the ring reads of either kind among
+    them; and the temporaries of both stay far under the pool (7.5 GB)
+    and under what the chip has left beside pool and weights (3.5 GB): no
+    ring is copied or laid out anew, and a prefill chunk's scores exist a
+    block of 1,024 ring positions at a time."""
+    from differential_transformer_replication_tpu.models import init_model
+    from differential_transformer_replication_tpu.models.decode import init_cache
+    from differential_transformer_replication_tpu.serving import engine
+
+    cfg, slots = ModelConfig(**AFMOE), 64
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = place(jax.eval_shape(lambda k: init_model(k, cfg),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_cache(cfg, slots)))
+    ints, scalar = place(sds((slots,), jnp.int32)), place(sds((), jnp.int32))
+    prefill, decode = engine._build_step_fns(cfg, cfg.block_size)[:2]
+    programs = {
+        "decode": decode.lower(params, ints, ints,
+                               place(sds((slots,), jnp.bool_)), cache).compile(),
+        "prefill": prefill.lower(params, cache, scalar,
+                                 place(sds((1, 1024), jnp.int32)), scalar,
+                                 scalar).compile(),
+    }
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(cache))
+    assert pool_bytes == 64 * 4096 * (4 * 5120 + 8192)
+    for name, compiled in programs.items():
+        assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes, name
+    text = programs["decode"].as_text()
+    assert text.startswith("HloModule jit__decode")
+    assert assert_kernels_named(text, "_decode") == {
+        kernel_names.KV_ROW_WRITE, kernel_names.MOE_GROUPED_MATMUL,
+        kernel_names.RING_GQA_DECODE}
+    assert {"attn_norm", "attn", "attn_window", "attn_full", "attn_gate",
+            "kv_write", "moe", "moe_router", "moe_experts", "moe_shared",
+            "ffn_norm", "ffn", "lm_head", "kv_merge"} <= scopes_in(text)
+    assert {"attn", "attn_window", "attn_full", "attn_gate", "kv_write",
+            "moe_experts"} <= scopes_in(programs["prefill"].as_text())
+    assert assert_kernels_named(programs["prefill"].as_text(), "_prefill") == {
+        kernel_names.MOE_GROUPED_MATMUL}
+    assert programs["decode"].memory_analysis().temp_size_in_bytes < 1.0e9
+    assert programs["prefill"].memory_analysis().temp_size_in_bytes < 2.0e9
 
 
 def test_sampler_is_scoped(topo):
