@@ -321,13 +321,24 @@ def flash_bh_fn(
     (VERDICT r2 item 5 — it was diff-only, leaving the control half of
     every PPL-gap experiment slower by construction).
 
-    Projects straight into the kernel's (B*H, S, T, d) layout — einsum
-    ``"bte,sehd->bhstd"`` + free reshape — instead of transposing the
+    Projects straight into the kernel's layout instead of transposing the
     stacked (S, B, T, H, d) arrays the dense path builds (XLA does not
-    eliminate those copies; profiled ~0.5-1 ms at recipe scale). RoPE
-    families rotate in the bh layout itself (``headed=False``: tables
-    broadcast over the fused batch*head axis), so no layout round-trip
-    sneaks back in."""
+    eliminate those copies; profiled ~0.5-1 ms at recipe scale): one
+    packed token-major product where the token-major kernels cover the
+    shape, a product a stream where only the packing does not fit, the
+    (B*H, S, T, d) layout (einsum ``"bte,sehd->bhstd"`` + free reshape)
+    under dropout or a long T.
+
+    A family that passes tables rotates on EVERY branch the same way, and
+    not as the dense reference does: the columns of Wq and Wk are
+    re-ordered inside each head (``ops/rope.py:half_split``) and the two
+    contiguous halves of q and k turn, on the tile in VMEM in the
+    token-major kernels (``ops/flash.py:_tm_turn``), in HBM on the
+    head-major branch. The reference's stride of two along the lanes cost
+    the control recipe a sixth of its step in gathers and float32
+    transposes (PERF.md section 6, PR 37). Parameters, checkpoints and the
+    decode ring keep the published order; a family without tables takes
+    the branches as they were."""
 
     def _fn():
         from differential_transformer_replication_tpu.ops.flash import (
@@ -336,33 +347,48 @@ def flash_bh_fn(
             tm_packed_ok,
             use_tm,
         )
-        from differential_transformer_replication_tpu.ops.rope import apply_rope
+        from differential_transformer_replication_tpu.ops.rope import (
+            apply_rope_halves,
+            half_split,
+        )
 
         B, T, E = x.shape
         S, _, H, d = wq.shape
         dv = wv.shape[-1]
         rate_live = dropout_rate if rng is not None else 0.0
+        rope = None if cos is None else (cos, sin)
+        wq_p, wk_p = wq, wk
+        if rope is not None:
+            # the rotation's pair (2i, 2i + 1) moves to (i, i + d/2), two
+            # contiguous halves: q . k does not care where a feature
+            # stands as long as both agree (ops/rope.py). The gradient
+            # comes back through half_split's transpose, so in the
+            # published order.
+            wq_p, wk_p = half_split(wq), half_split(wk)
         # Ineligible shapes (exotic dv/d offset ratios, narrow lane
-        # widths — see tm_packed_ok) fall through to the per-array tm
-        # path instead of tripping the kernel's spec assert at trace time.
-        if use_tm(S, T, rate_live) and cos is None and tm_packed_ok(S, H, d, dv):
-            # PACKED token-major fast path (no-RoPE families): ONE fused
-            # projection matmul x @ [Wq..|Wk..|Wv]; the kernel reads
-            # column windows of its output and the backward emits one
-            # packed dproj — zero copies on either side
+        # widths, four rotated streams — see tm_packed_ok) fall through
+        # to the per-array tm path instead of tripping the kernel's spec
+        # assert at trace time or the chip's VMEM at compile time.
+        if use_tm(S, T, rate_live) and tm_packed_ok(
+                S, H, d, dv, rope=rope is not None):
+            # PACKED token-major fast path: ONE fused projection matmul
+            # x @ [Wq..|Wk..|Wv]; the kernel reads column windows of its
+            # output (and turns the q/k tiles of a RoPE family in VMEM)
+            # and the backward emits one packed dproj — zero copies on
+            # either side
             from differential_transformer_replication_tpu.ops.flash import (
                 multi_stream_flash_attention_tm_packed,
             )
 
             wcat = jnp.concatenate(
-                [wq[s].reshape(E, H * d) for s in range(S)]
-                + [wk[s].reshape(E, H * d) for s in range(S)]
+                [wq_p[s].reshape(E, H * d) for s in range(S)]
+                + [wk_p[s].reshape(E, H * d) for s in range(S)]
                 + [wv.reshape(E, H * dv)],
                 axis=1,
             ).astype(x.dtype)
             proj = x @ wcat  # (B, T, 2*S*H*d + H*dv)
             return multi_stream_flash_attention_tm_packed(
-                proj, coeffs, B, H, S, d, dv
+                proj, coeffs, B, H, S, d, dv, rope=rope
             )
         if use_tm(S, T, rate_live):
             # TOKEN-MAJOR fast path (ops/flash.py tm kernels): each
@@ -372,8 +398,8 @@ def flash_bh_fn(
             # the out-projection contiguous (round-4 profile: ~660 MB/step
             # of HBM transpose copies + a 4.5 ms strided stat reduce on
             # the head-major path at recipe scale)
-            wq_c = wq.astype(x.dtype)
-            wk_c = wk.astype(x.dtype)
+            wq_c = wq_p.astype(x.dtype)
+            wk_c = wk_p.astype(x.dtype)
             qs = tuple(
                 (x @ wq_c[s].reshape(E, H * d)).reshape(B, T, H, d)
                 for s in range(S)
@@ -385,22 +411,21 @@ def flash_bh_fn(
             v_tm = (x @ wv.astype(x.dtype).reshape(E, H * dv)).reshape(
                 B, T, H, dv
             )
-            if cos is not None:
-                qs = tuple(apply_rope(q, cos, sin, headed=True) for q in qs)
-                ks = tuple(apply_rope(k, cos, sin, headed=True) for k in ks)
-            return multi_stream_flash_attention_tm(qs, ks, v_tm, coeffs, B, H)
-        q_r = jnp.einsum("bte,sehd->bhstd", x, wq.astype(x.dtype)).reshape(
+            return multi_stream_flash_attention_tm(
+                qs, ks, v_tm, coeffs, B, H, rope=rope
+            )
+        q_r = jnp.einsum("bte,sehd->bhstd", x, wq_p.astype(x.dtype)).reshape(
             B * H, S, T, d
         )
-        k_r = jnp.einsum("bte,sehd->bhstd", x, wk.astype(x.dtype)).reshape(
+        k_r = jnp.einsum("bte,sehd->bhstd", x, wk_p.astype(x.dtype)).reshape(
             B * H, S, T, d
         )
         v_r = jnp.einsum("bte,ehd->bhtd", x, wv.astype(x.dtype)).reshape(
             B * H, T, dv
         )
-        if cos is not None:
-            q_r = apply_rope(q_r, cos, sin, headed=False)
-            k_r = apply_rope(k_r, cos, sin, headed=False)
+        if rope is not None:
+            q_r = apply_rope_halves(q_r, cos, sin)
+            k_r = apply_rope_halves(k_r, cos, sin)
         out = multi_stream_flash_attention_bh(
             q_r, k_r, v_r, coeffs, B, H,
             dropout_rate=dropout_rate, dropout_rng=rng,

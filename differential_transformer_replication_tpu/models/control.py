@@ -90,7 +90,8 @@ def _attn(
         ),
         impl=impl, mesh=mesh, dropout_rate=dropout_rate, rng=r_att,
         seq_impl=seq_impl,
-        # kernel-native-layout fast path (RoPE applied in the bh layout)
+        # kernel-native-layout fast path; it rotates for itself, halves
+        # of re-ordered projections (the q, k above are the dense path's)
         flash_fn=common.flash_bh_fn(
             x, p["wq"][None], p["wk"][None], p["wv"], coeffs,
             dropout_rate=dropout_rate, rng=r_att, cos=cos, sin=sin,

@@ -1887,18 +1887,65 @@ def _tm_bias(T: int) -> jnp.ndarray:
     return causal_bias(T, 0).astype(jnp.bfloat16)
 
 
+def tm_rope_table(cos: jnp.ndarray, sin: jnp.ndarray, T: int) -> jnp.ndarray:
+    """The tm kernels' rotation operand ``(2, T, d)`` float32 from the
+    tables of ``ops/rope.py:rope_cos_sin`` (``(>=T, d/2)``): row 0 is
+    ``[cos | cos]``, row 1 ``[-sin | sin]``, so that a head's features in
+    ``half_split``'s order turn as ``x * t[0] + swap(x) * t[1]``
+    (:func:`_tm_turn`), dimension i with i + d/2."""
+    c, s = cos[:T].astype(jnp.float32), sin[:T].astype(jnp.float32)
+    return jnp.stack([jnp.concatenate([c, c], -1),
+                      jnp.concatenate([-s, s], -1)])
+
+
+def _tm_half_swap(d: int, dtype) -> jnp.ndarray:
+    """The ``(d, d)`` 0/1 matrix P with ``(x @ P)[:, j] = x[:, (j + d/2)
+    % d]``: a head's two halves trade places on the MXU, exactly (one
+    product a column). Slicing the halves and concatenating them swapped
+    goes through the lane-rotate unit instead, which the softmax's row
+    reductions already keep busy: 2.6 ms a recipe step slower (PERF.md
+    section 6, PR 37)."""
+    h = d // 2
+    row = jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (d, d), 1)
+    return (row == jnp.where(col < h, col + h, col - h)).astype(dtype)
+
+
+def _tm_turn(x, rot_ref, swap, back: bool = False):
+    """Rotate one head's ``(rows, d)`` tile in VMEM by the rows' angles
+    (``rot_ref`` (2, rows, d), :func:`tm_rope_table`; ``swap``,
+    :func:`_tm_half_swap`, in the dtype the tile is stored in): float32
+    inside, cast back, as ``ops/rope.py`` rotates in HBM. ``back`` turns
+    by the transposed rotation, which takes a gradient of the rotated
+    tile to one of the tile as loaded; a float32 gradient rounds to the
+    stored dtype first, as it does on its way to a rotation in HBM."""
+    x = x.astype(swap.dtype)
+    swapped = jax.lax.dot_general(
+        x, swap, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.HIGHEST
+                   if x.dtype == jnp.float32 else None),
+    )
+    side = swapped * rot_ref[1]
+    main = x.astype(jnp.float32) * rot_ref[0]
+    return (main - side if back else main + side).astype(x.dtype)
+
+
 def _tm_fwd_kernel(
     *refs,
     S: int,
     H: int,
     save_residuals: bool,
+    rope: bool = False,
 ):
     """Single-pass (full-T) forward over token-major refs, one program
     per (batch row, q block), all H heads in-program.
 
     refs: q_0..q_{S-1} (bq, H*d) | k_0..k_{S-1} (T, H*d) | v (T, H*dv) |
-    bias (bq, T) bf16 | c (BH, S) SMEM | out (bq, H*dv)
-    [| oall (H, S, bq, dv), lse (bq, H*S) when save_residuals].
+    bias (bq, T) bf16 | c (BH, S) SMEM [| rot_q (2, bq, d), rot_k (2, T, d)
+    float32 when ``rope``: the q and k tiles turn as loaded, :func:`_tm_turn`]
+    | out (bq, H*dv) [| oall (H, S, bq, dv), lse (bq, H*S) when
+    save_residuals].
 
     The head dim rides FLATTENED into the lane dim (one lane slice per
     head) because Mosaic rejects sublane-strided stores of converted
@@ -1916,6 +1963,9 @@ def _tm_fwd_kernel(
     k_refs, refs = refs[:S], refs[S:]
     v_ref, bias_ref, c_ref, *outs = refs
     d = q_refs[0].shape[-1] // H
+    if rope:
+        rot_q, rot_k, *outs = outs
+        swap = _tm_half_swap(d, q_refs[0].dtype)
     dv = v_ref.shape[-1] // H
     b = pl.program_id(0)
     scale = 1.0 / math.sqrt(d)
@@ -1930,6 +1980,9 @@ def _tm_fwd_kernel(
         for s_i in range(S):
             q_h = q_refs[s_i][:, h * d : (h + 1) * d]  # (bq, d)
             k_h = k_refs[s_i][:, h * d : (h + 1) * d]  # (T, d)
+            if rope:
+                q_h = _tm_turn(q_h, rot_q, swap)
+                k_h = _tm_turn(k_h, rot_k, swap)
             sm = jax.lax.dot_general(
                 q_h, k_h,
                 dimension_numbers=(((1,), (1,)), ((), ())),
@@ -1960,12 +2013,27 @@ def _tm_fwd_kernel(
         lse_ref[...] = jnp.concatenate(lse_cols, axis=1)  # (bq, H*S) f32
 
 
+def _tm_rot_specs(rot, block_q: int):
+    """(specs, operands) of the rotation table for a forward call: the q
+    block's rows and all of K's; none without a table."""
+    if rot is None:
+        return [], ()
+    _, T, d = rot.shape
+    return [
+        pl.BlockSpec((2, block_q, d), lambda b, i: (0, i, 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((2, T, d), lambda b, i: (0, 0, 0),
+                     memory_space=pltpu.VMEM),
+    ], (rot, rot)
+
+
 def _tm_fwd_call(
-    qs, ks, v, coeffs, *, H: int, block_q: int, save_residuals: bool,
+    qs, ks, v, coeffs, rot, *, H: int, block_q: int, save_residuals: bool,
     interpret: bool
 ):
     """qs/ks: tuples of S (B, T, H*d) arrays (raw projection outputs);
-    v (B, T, H*dv); coeffs (B*H, S) fp32; ``H`` static. Returns
+    v (B, T, H*dv); coeffs (B*H, S) fp32; ``rot`` the rotation table
+    (:func:`tm_rope_table`) or None; ``H`` static. Returns
     (out (B, T, H*dv) [, oall (B, H, S, T, dv), lse (B, T, H*S)])."""
     S = len(qs)
     B, T, Hd = qs[0].shape
@@ -1993,6 +2061,8 @@ def _tm_fwd_call(
         pl.BlockSpec((BH, S), lambda b, i: (0, 0),
                      memory_space=pltpu.SMEM),
     ]
+    rot_specs, rot_args = _tm_rot_specs(rot, block_q)
+    in_specs += rot_specs
     out_shapes = [jax.ShapeDtypeStruct((B, T, H * dv), qs[0].dtype)]
     out_specs = [
         pl.BlockSpec(
@@ -2018,7 +2088,8 @@ def _tm_fwd_call(
         ]
     results = pl.pallas_call(
         functools.partial(
-            _tm_fwd_kernel, S=S, H=H, save_residuals=save_residuals
+            _tm_fwd_kernel, S=S, H=H, save_residuals=save_residuals,
+            rope=rot is not None,
         ),
         grid=(B, nq),
         in_specs=in_specs,
@@ -2030,7 +2101,7 @@ def _tm_fwd_call(
         ),
         name=kernel_names.FLASH_FWD_TM,
         interpret=interpret,
-    )(*qs, *ks, v, _tm_bias(T), coeffs.astype(jnp.float32))
+    )(*qs, *ks, v, _tm_bias(T), coeffs.astype(jnp.float32), *rot_args)
     if save_residuals:
         return results
     return results[0], None, None
@@ -2038,7 +2109,7 @@ def _tm_fwd_call(
 
 def _tm_bwd_columns(
     q_refs, k_refs, v_ref, g_ref, lse_ref, delta_ref, c_ref, bias,
-    *, S: int, H: int, s_list: tuple, out_dtype,
+    *, S: int, H: int, s_list: tuple, out_dtype, rot_ref=None,
 ):
     """The factored whole-T backward math shared by the per-array and
     packed tm kernels: per (head, listed stream) gradient column groups.
@@ -2046,11 +2117,22 @@ def _tm_bwd_columns(
     h-ordered lists of (T, d) columns for stream s_list[j]; dv_cols is
     the h-ordered list of (T, dv) columns (dV summed over the listed
     streams). g V^T runs once per head and is scaled per stream; each
-    stream's softmax recompute (the exp floor) happens exactly once."""
+    stream's softmax recompute (the exp floor) happens exactly once.
+    With ``rot_ref`` (2, T, d) the q and k tiles turn as loaded, as the
+    forward's did, and dq and dk turn back before the cast, so the
+    gradients are those of the tiles in HBM (:func:`_tm_turn`)."""
     d = q_refs[0].shape[-1] // H
     dv = v_ref.shape[-1] // H
     b = pl.program_id(0)
     scale = 1.0 / math.sqrt(d)
+
+    if rot_ref is not None:
+        swap = _tm_half_swap(d, out_dtype)
+
+    def store(grad):  # a float32 (T, d) gradient of a head's q or k tile
+        if rot_ref is None:
+            return grad.astype(out_dtype)
+        return _tm_turn(grad, rot_ref, swap, back=True)
 
     dq_cols = [[] for _ in s_list]
     dk_cols = [[] for _ in s_list]
@@ -2070,6 +2152,9 @@ def _tm_bwd_columns(
             delta_h = delta_ref[:, col : col + 1]  # (T, 1) f32
             q_h = q_refs[j][:, h * d : (h + 1) * d]  # (T, d)
             k_h = k_refs[j][:, h * d : (h + 1) * d]
+            if rot_ref is not None:
+                q_h = _tm_turn(q_h, rot_ref, swap)
+                k_h = _tm_turn(k_h, rot_ref, swap)
             sm = jax.lax.dot_general(
                 q_h, k_h,
                 dimension_numbers=(((1,), (1,)), ((), ())),
@@ -2079,22 +2164,22 @@ def _tm_bwd_columns(
             c_sh = c_ref[b * H + h, s_idx]
             ds = (p * (gv * c_sh - delta_h)).astype(q_h.dtype)
             dq_cols[j].append(
-                (
+                store(
                     jax.lax.dot_general(
                         ds, k_h,
                         dimension_numbers=(((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32,
                     ) * scale
-                ).astype(out_dtype)
+                )
             )
             dk_cols[j].append(
-                (
+                store(
                     jax.lax.dot_general(
                         ds, q_h,
                         dimension_numbers=(((0,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32,
                     ) * scale
-                ).astype(out_dtype)
+                )
             )
             pc = p * c_sh
             dv_h = pc if dv_h is None else dv_h + pc
@@ -2108,7 +2193,16 @@ def _tm_bwd_columns(
     return dq_cols, dk_cols, dv_cols
 
 
-def _tm_bwd_kernel(*refs, S: int, H: int, s_list: tuple):
+def _tm_bwd_rot_specs(rot):
+    """(specs, operands) of the rotation table for a whole-T backward
+    call; none without a table."""
+    if rot is None:
+        return [], ()
+    return [pl.BlockSpec(rot.shape, lambda b: (0, 0, 0),
+                         memory_space=pltpu.VMEM)], (rot,)
+
+
+def _tm_bwd_kernel(*refs, S: int, H: int, s_list: tuple, rope: bool = False):
     """Whole-T backward for the streams in ``s_list`` over token-major
     refs, one program per batch row — the factored math of
     :func:`_bwd_fused_kernel` (see :func:`_tm_bwd_columns`); outputs are
@@ -2117,16 +2211,20 @@ def _tm_bwd_kernel(*refs, S: int, H: int, s_list: tuple):
 
     refs: q_s (T, H*d) per listed stream | k_s likewise | v (T, H*dv) |
     g (T, H*dv) | lse (T, H*S) | delta (T, H*S) | c (BH, S) SMEM |
-    bias (T, T) bf16 | dq_s per stream | dk_s per stream | dv (T, H*dv)."""
+    bias (T, T) bf16 [| rot (2, T, d) when ``rope``] | dq_s per stream |
+    dk_s per stream | dv (T, H*dv)."""
     ns = len(s_list)
     q_refs, refs = refs[:ns], refs[ns:]
     k_refs, refs = refs[:ns], refs[ns:]
     (v_ref, g_ref, lse_ref, delta_ref, c_ref, bias_ref, *outs) = refs
+    rot_ref = None
+    if rope:
+        rot_ref, *outs = outs
     dq_refs, dk_refs, dv_ref = outs[:ns], outs[ns : 2 * ns], outs[2 * ns]
     dq_cols, dk_cols, dv_cols = _tm_bwd_columns(
         q_refs, k_refs, v_ref, g_ref, lse_ref, delta_ref, c_ref,
         bias_ref[...].astype(jnp.float32),
-        S=S, H=H, s_list=s_list, out_dtype=dq_refs[0].dtype,
+        S=S, H=H, s_list=s_list, out_dtype=dq_refs[0].dtype, rot_ref=rot_ref,
     )
     for j in range(ns):
         dq_refs[j][...] = jnp.concatenate(dq_cols[j], axis=1)
@@ -2134,7 +2232,8 @@ def _tm_bwd_kernel(*refs, S: int, H: int, s_list: tuple):
     dv_ref[...] = jnp.concatenate(dv_cols, axis=1)
 
 
-def _tm_bwd_call(qs, ks, v, g, lse, delta, coeffs, *, H: int, interpret: bool):
+def _tm_bwd_call(qs, ks, v, g, lse, delta, coeffs, rot, *, H: int,
+                 interpret: bool):
     """qs/ks/v/g: flat (B, T, H*width); lse/delta: (B, T, H*S) fp32.
     All streams in ONE pallas call (the g V^T matmul then runs once per
     head): the call raises the kernel's scoped-VMEM budget via
@@ -2155,9 +2254,11 @@ def _tm_bwd_call(qs, ks, v, g, lse, delta, coeffs, *, H: int, interpret: bool):
     stspec = pl.BlockSpec(
         (None, T, H * S), lambda b: (b, 0, 0), memory_space=pltpu.VMEM
     )
+    rot_specs, rot_args = _tm_bwd_rot_specs(rot)
     results = pl.pallas_call(
         functools.partial(
-            _tm_bwd_kernel, S=S, H=H, s_list=tuple(range(S))
+            _tm_bwd_kernel, S=S, H=H, s_list=tuple(range(S)),
+            rope=rot is not None,
         ),
         grid=(B,),
         in_specs=[qspec] * S + [qspec] * S + [
@@ -2166,7 +2267,7 @@ def _tm_bwd_call(qs, ks, v, g, lse, delta, coeffs, *, H: int, interpret: bool):
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((T, T), lambda b: (0, 0),
                          memory_space=pltpu.VMEM),
-        ],
+        ] + rot_specs,
         out_specs=[qspec] * S + [qspec] * S + [vspec],
         out_shape=(
             [jax.ShapeDtypeStruct((B, T, Hd), qs[0].dtype)] * S
@@ -2179,7 +2280,8 @@ def _tm_bwd_call(qs, ks, v, g, lse, delta, coeffs, *, H: int, interpret: bool):
         ),
         name=kernel_names.FLASH_BWD_TM,
         interpret=interpret,
-    )(*qs, *ks, v, g, lse, delta, coeffs.astype(jnp.float32), _tm_bias(T))
+    )(*qs, *ks, v, g, lse, delta, coeffs.astype(jnp.float32), _tm_bias(T),
+      *rot_args)
     dqs = tuple(results[:S])
     dks = tuple(results[S : 2 * S])
     return dqs, dks, results[2 * S]
@@ -2212,27 +2314,27 @@ def _tm_train_block_q(S: int) -> int:
     return min(_TM_TRAIN_BLOCK_Q, 256) if S >= 3 else _TM_TRAIN_BLOCK_Q
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _flash_tm(qs, ks, v, coeffs, blocks, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_tm(qs, ks, v, coeffs, rot, blocks, interpret):
     H = coeffs.shape[0] // qs[0].shape[0]
     out, _, _ = _tm_fwd_call(
-        qs, ks, v, coeffs,
+        qs, ks, v, coeffs, rot,
         H=H, block_q=blocks[0], save_residuals=False, interpret=interpret,
     )
     return out
 
 
-def _flash_tm_fwd(qs, ks, v, coeffs, blocks, interpret):
+def _flash_tm_fwd(qs, ks, v, coeffs, rot, blocks, interpret):
     H = coeffs.shape[0] // qs[0].shape[0]
     out, o_all, lse = _tm_fwd_call(
-        qs, ks, v, coeffs,
+        qs, ks, v, coeffs, rot,
         H=H, block_q=blocks[2], save_residuals=True, interpret=interpret,
     )
-    return out, (qs, ks, v, coeffs, o_all, lse)
+    return out, (qs, ks, v, coeffs, rot, o_all, lse)
 
 
 def _flash_tm_bwd(blocks, interpret, res, g):
-    qs, ks, v, coeffs, o_all, lse = res
+    qs, ks, v, coeffs, rot, o_all, lse = res
     B, H, S, T, dv = o_all.shape
     g32 = g.astype(jnp.float32).reshape(B, T, H, dv)
     # base[b,t,h,s] = <g_t, O_s,t>; delta_s = c_s * base; dcoeffs = sum_t
@@ -2245,10 +2347,11 @@ def _flash_tm_bwd(blocks, interpret, res, g):
         base * coeffs.astype(jnp.float32).reshape(B, 1, H, S)
     ).reshape(B, T, H * S)
     dqs, dks, dv_grad = _tm_bwd_call(
-        qs, ks, v, g.astype(qs[0].dtype), lse, delta, coeffs,
+        qs, ks, v, g.astype(qs[0].dtype), lse, delta, coeffs, rot,
         H=H, interpret=interpret,
     )
-    return dqs, dks, dv_grad, dcoeffs.astype(coeffs.dtype)
+    # the angles are positions, not parameters: no gradient to the table
+    return dqs, dks, dv_grad, dcoeffs.astype(coeffs.dtype), None
 
 
 _flash_tm.defvjp(_flash_tm_fwd, _flash_tm_bwd)
@@ -2257,6 +2360,7 @@ _flash_tm.defvjp(_flash_tm_fwd, _flash_tm_bwd)
 def multi_stream_flash_attention_tm(
     qs, ks, v: jnp.ndarray, coeffs: jnp.ndarray, B: int, H: int,
     *,
+    rope=None,
     block_q: Optional[int] = None,
     block_q_train: Optional[int] = None,
     interpret: Optional[bool] = None,
@@ -2265,7 +2369,10 @@ def multi_stream_flash_attention_tm(
     arrays (each the RESHAPED output of its own projection matmul — no
     transpose anywhere), ``v`` is ``(B, T, H, dv)``; returns
     ``(B, T, H, dv)``. The kernels run on the flat ``(B, T, H*width)``
-    forms (all reshapes here are free row-major bitcasts). Callers must
+    forms (all reshapes here are free row-major bitcasts). ``rope``: the
+    ``(cos, sin)`` tables of a family that rotates, whose q and k then
+    arrive UNROTATED with every head's features in
+    ``ops/rope.py:half_split``'s order and turn in VMEM. Callers must
     check :func:`use_tm` first; ineligible configs belong on
     :func:`multi_stream_flash_attention_bh`."""
     if interpret is None:
@@ -2297,7 +2404,8 @@ def multi_stream_flash_attention_tm(
         tuple(q.reshape(B, T, H * d) for q in qs),
         tuple(k.reshape(B, T, H * d) for k in ks),
         v.reshape(B, T, H * dv),
-        c_r, blocks, interpret,
+        c_r, None if rope is None else tm_rope_table(*rope, T),
+        blocks, interpret,
     )
     return out.reshape(B, T, H, dv)
 
@@ -2309,13 +2417,21 @@ def multi_stream_flash_attention_tm(
 # logical operand with window-offset index maps (zero copies), and the
 # backward emits ONE packed dproj in the same column order, which is
 # exactly the operand the projection's own dx/dW matmuls need — no
-# gradient concat materializes either. RoPE families cannot use this
-# (rotating the q/k windows would need slice+concat copies); they stay on
-# the per-array entry above.
+# gradient concat materializes either. RoPE families ride it too: their
+# q/k windows turn on the tile in VMEM (:func:`_tm_turn`), since rotating
+# them in HBM would need slice+concat copies of the packed array.
 # ---------------------------------------------------------------------------
 
 
-def tm_packed_ok(S: int, H: int, d: int, dv: int) -> bool:
+# The packed whole-T backward holds the (T, W) dproj block beside every
+# window of proj; turning the tiles adds the table and float32 copies of a
+# head's q, k, dq and dk. At S = 4 (W = 3840 at recipe widths) that is
+# 30.0 MB of scoped VMEM inside the recipe step, 2 over _TM_VMEM_LIMIT;
+# S <= 3 compiles at micro-batch 32 and 64 (described v5e, PR 37).
+_TM_PACKED_ROPE_MAX_S = 3
+
+
+def tm_packed_ok(S: int, H: int, d: int, dv: int, rope: bool = False) -> bool:
     """Shape eligibility for the packed tm kernels: the fused (B, T, W)
     projection is windowed with H*d- and H*dv-wide column blocks, so the
     V window offset 2*S*H*d must be a whole number of H*dv blocks (holds
@@ -2323,9 +2439,13 @@ def tm_packed_ok(S: int, H: int, d: int, dv: int) -> bool:
     ratios miss it), and both window widths must be 128-lane multiples —
     a BlockSpec block narrower than the array's last dim must divide
     into lanes (Mosaic lowering rule; narrow test-scale models miss it).
+    With ``rope`` (the kernels turn q and k) S may not pass
+    ``_TM_PACKED_ROPE_MAX_S``.
     Callers route ineligible shapes to the per-array tm path, whose
     blocks span each array's full last dim and are always legal."""
     Hd, Hdv = H * d, H * dv
+    if rope and S > _TM_PACKED_ROPE_MAX_S:
+        return False
     return (2 * S * Hd) % Hdv == 0 and Hd % 128 == 0 and Hdv % 128 == 0
 
 
@@ -2361,7 +2481,7 @@ def _tm_packed_specs(S, H, d, dv, T, block_q):
 
 
 def _tm_fwd_call_packed(
-    proj, coeffs, *, S, H, d, dv, block_q, save_residuals, interpret
+    proj, coeffs, rot, *, S, H, d, dv, block_q, save_residuals, interpret
 ):
     """Packed twin of :func:`_tm_fwd_call`: same kernel body, operands
     windowed out of ``proj`` (B, T, W)."""
@@ -2376,6 +2496,8 @@ def _tm_fwd_call_packed(
         pl.BlockSpec((BH, S), lambda b, i: (0, 0),
                      memory_space=pltpu.SMEM),
     ]
+    rot_specs, rot_args = _tm_rot_specs(rot, block_q)
+    in_specs += rot_specs
     out_shapes = [jax.ShapeDtypeStruct((B, T, H * dv), proj.dtype)]
     out_specs = [
         pl.BlockSpec(
@@ -2401,7 +2523,8 @@ def _tm_fwd_call_packed(
         ]
     results = pl.pallas_call(
         functools.partial(
-            _tm_fwd_kernel, S=S, H=H, save_residuals=save_residuals
+            _tm_fwd_kernel, S=S, H=H, save_residuals=save_residuals,
+            rope=rot is not None,
         ),
         grid=(B, nq),
         in_specs=in_specs,
@@ -2414,23 +2537,25 @@ def _tm_fwd_call_packed(
         name=kernel_names.FLASH_FWD_TM_PACKED,
         interpret=interpret,
     )(*([proj] * (2 * S + 1)), _tm_bias(T),
-      coeffs.astype(jnp.float32))
+      coeffs.astype(jnp.float32), *rot_args)
     if save_residuals:
         return results
     return results[0], None, None
 
 
-def _tm_bwd_kernel_packed(*refs, S: int, H: int):
+def _tm_bwd_kernel_packed(*refs, S: int, H: int, rope: bool = False):
     """Packed twin of :func:`_tm_bwd_kernel` (all streams; same shared
     math, :func:`_tm_bwd_columns`): the per-stream dq/dk and dv column
     groups store as ONE (T, W) ref in the packed projection order."""
     q_refs, refs = refs[:S], refs[S:]
     k_refs, refs = refs[:S], refs[S:]
-    (v_ref, g_ref, lse_ref, delta_ref, c_ref, bias_ref, dproj_ref) = refs
+    (v_ref, g_ref, lse_ref, delta_ref, c_ref, bias_ref, *rest) = refs
+    rot_ref, dproj_ref = rest if rope else (None, *rest)
     dq_cols, dk_cols, dv_cols = _tm_bwd_columns(
         q_refs, k_refs, v_ref, g_ref, lse_ref, delta_ref, c_ref,
         bias_ref[...].astype(jnp.float32),
         S=S, H=H, s_list=tuple(range(S)), out_dtype=dproj_ref.dtype,
+        rot_ref=rot_ref,
     )
     cols = (
         [c for s_i in range(S) for c in dq_cols[s_i]]
@@ -2441,7 +2566,7 @@ def _tm_bwd_kernel_packed(*refs, S: int, H: int):
 
 
 def _tm_bwd_call_packed(
-    proj, g, lse, delta, coeffs, *, S, H, d, dv, interpret
+    proj, g, lse, delta, coeffs, rot, *, S, H, d, dv, interpret
 ):
     """Returns dproj (B, T, W) — the single packed gradient the fused
     projection matmul's own backward consumes directly."""
@@ -2473,8 +2598,10 @@ def _tm_bwd_call_packed(
     pvspec = pl.BlockSpec(
         (None, T, Hdv), lambda b: (b, 0, vcol), memory_space=pltpu.VMEM
     )
+    rot_specs, rot_args = _tm_bwd_rot_specs(rot)
     results = pl.pallas_call(
-        functools.partial(_tm_bwd_kernel_packed, S=S, H=H),
+        functools.partial(_tm_bwd_kernel_packed, S=S, H=H,
+                          rope=rot is not None),
         grid=(B,),
         in_specs=qspecs + kspecs + [
             pvspec,
@@ -2485,7 +2612,7 @@ def _tm_bwd_call_packed(
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((T, T), lambda b: (0, 0),
                          memory_space=pltpu.VMEM),
-        ],
+        ] + rot_specs,
         out_specs=[
             pl.BlockSpec((None, T, W), lambda b: (b, 0, 0),
                          memory_space=pltpu.VMEM),
@@ -2498,29 +2625,29 @@ def _tm_bwd_call_packed(
         name=kernel_names.FLASH_BWD_TM_PACKED,
         interpret=interpret,
     )(*([proj] * (2 * S + 1)), g, lse, delta,
-      coeffs.astype(jnp.float32), _tm_bias(T))
+      coeffs.astype(jnp.float32), _tm_bias(T), *rot_args)
     return results[0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
-def _flash_tm_packed(proj, coeffs, S, H, d, dv, blocks, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_tm_packed(proj, coeffs, rot, S, H, d, dv, blocks, interpret):
     out, _, _ = _tm_fwd_call_packed(
-        proj, coeffs, S=S, H=H, d=d, dv=dv,
+        proj, coeffs, rot, S=S, H=H, d=d, dv=dv,
         block_q=blocks[0], save_residuals=False, interpret=interpret,
     )
     return out
 
 
-def _flash_tm_packed_fwd(proj, coeffs, S, H, d, dv, blocks, interpret):
+def _flash_tm_packed_fwd(proj, coeffs, rot, S, H, d, dv, blocks, interpret):
     out, o_all, lse = _tm_fwd_call_packed(
-        proj, coeffs, S=S, H=H, d=d, dv=dv,
+        proj, coeffs, rot, S=S, H=H, d=d, dv=dv,
         block_q=blocks[2], save_residuals=True, interpret=interpret,
     )
-    return out, (proj, coeffs, o_all, lse)
+    return out, (proj, coeffs, rot, o_all, lse)
 
 
 def _flash_tm_packed_bwd(S, H, d, dv, blocks, interpret, res, g):
-    proj, coeffs, o_all, lse = res
+    proj, coeffs, rot, o_all, lse = res
     B, _, _, T, _ = o_all.shape
     g32 = g.astype(jnp.float32).reshape(B, T, H, dv)
     base = jnp.einsum("bthd,bhstd->bths", g32, o_all.astype(jnp.float32))
@@ -2529,10 +2656,10 @@ def _flash_tm_packed_bwd(S, H, d, dv, blocks, interpret, res, g):
         base * coeffs.astype(jnp.float32).reshape(B, 1, H, S)
     ).reshape(B, T, H * S)
     dproj = _tm_bwd_call_packed(
-        proj, g.astype(proj.dtype), lse, delta, coeffs,
+        proj, g.astype(proj.dtype), lse, delta, coeffs, rot,
         S=S, H=H, d=d, dv=dv, interpret=interpret,
     )
-    return dproj, dcoeffs.astype(coeffs.dtype)
+    return dproj, dcoeffs.astype(coeffs.dtype), None
 
 
 _flash_tm_packed.defvjp(_flash_tm_packed_fwd, _flash_tm_packed_bwd)
@@ -2543,13 +2670,15 @@ def multi_stream_flash_attention_tm_packed(
     coeffs: jnp.ndarray,  # (S, H) float32
     B: int, H: int, S: int, d: int, dv: int,
     *,
+    rope=None,
     block_q: Optional[int] = None,
     block_q_train: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Packed-projection token-major entry (see the section comment):
     ``proj`` is the raw output of ONE fused projection matmul; returns
-    (B, T, H, dv). No-RoPE families only; callers check use_tm."""
+    (B, T, H, dv). ``rope`` as :func:`multi_stream_flash_attention_tm`
+    takes it; callers check use_tm."""
     if interpret is None:
         interpret = _auto_interpret()
     T = proj.shape[1]
@@ -2573,7 +2702,10 @@ def multi_stream_flash_attention_tm_packed(
     c_r = jnp.broadcast_to(
         coeffs.astype(jnp.float32).T[None], (B, H, S)
     ).reshape(B * H, S)
-    out = _flash_tm_packed(proj, c_r, S, H, d, dv, blocks, interpret)
+    out = _flash_tm_packed(
+        proj, c_r, None if rope is None else tm_rope_table(*rope, T),
+        S, H, d, dv, blocks, interpret,
+    )
     return out.reshape(B, T, H, dv)
 
 

@@ -12,6 +12,22 @@ Parity notes:
     matching the reference's explicit upcast (control.py:17,22),
   - the table is truncated to the actual sequence length at apply time
     (control.py:18).
+
+Two pairings of one rotation. :func:`apply_rope` pairs feature ``2i``
+with ``2i + 1`` as the reference does: the dense reference, the prefill
+chunk and the decode step use it (layout rule in its docstring), and
+parameters, checkpoints and the K ring keep that published order. A stride
+of two along the LANES is the one thing the chip's layout cannot slice (the
+compiler turns it into a gather that puts the feature first and float32
+transposes of the whole activation: a sixth of the control recipe's step,
+PERF.md section 6, PR 37). So the kernel path
+(``models/common.py:flash_bh_fn``) re-orders the columns of ``Wq`` and
+``Wk`` inside each head by :func:`half_split` before projecting, which
+puts the pair at ``(i, i + d/2)``, and turns the two contiguous halves:
+the token-major kernels on the tile in VMEM (``ops/flash.py:_tm_turn``,
+from these tables), the head-major branch in HBM with
+:func:`apply_rope_halves`. The same angles, the same float32 arithmetic,
+the same cast back, and ``q . k`` is the same sum in another order.
 """
 
 from __future__ import annotations
@@ -76,6 +92,38 @@ def apply_rope(
     return out.astype(orig_dtype)
 
 
+def half_split(w: jnp.ndarray) -> jnp.ndarray:
+    """Re-order the last axis ``[0, 1, 2, ...]`` to ``[0, 2, ..., d-2, 1,
+    3, ..., d-1]``: the reference's pair ``(2i, 2i + 1)`` moves to ``(i,
+    i + d/2)``. Written as a transpose of the ``(d/2, 2)`` view, which the
+    chip does in place of the gather that indexing would be."""
+    d = w.shape[-1]
+    return jnp.swapaxes(w.reshape(*w.shape[:-1], d // 2, 2), -1, -2).reshape(
+        w.shape
+    )
+
+
+def _turn_halves(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray):
+    """Dimension i of ``x`` (..., d) turns with i + d/2 by the angle whose
+    ``cos``/``sin`` (float32) broadcast against a half of ``x``: float32
+    inside, cast back to ``x``'s dtype."""
+    h = x.shape[-1] // 2
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :h], xf[..., h:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def apply_rope_halves(
+    x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray
+) -> jnp.ndarray:
+    """:func:`apply_rope` for an ``x`` (..., T, d) whose features stand in
+    :func:`half_split`'s order: the same tables, truncated to T the
+    same way, turn dimension i with i + d/2."""
+    seq_len = x.shape[-2]
+    return _turn_halves(x, cos[:seq_len], sin[:seq_len])
+
+
 def apply_rope_half(x: jnp.ndarray, pos: jnp.ndarray,
                     theta: float = 10000.0) -> jnp.ndarray:
     """Rotate ``x`` (..., d) at the absolute positions ``pos``, which
@@ -88,8 +136,4 @@ def apply_rope_half(x: jnp.ndarray, pos: jnp.ndarray,
     d = x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = jnp.asarray(pos, jnp.float32)[..., None] * freqs
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    xf = x.astype(jnp.float32)
-    a, b = xf[..., : d // 2], xf[..., d // 2:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
+    return _turn_halves(x, jnp.cos(angles), jnp.sin(angles))

@@ -15,6 +15,7 @@ from differential_transformer_replication_tpu.models import (
 )
 from differential_transformer_replication_tpu.models.decode import (
     forward_chunk,
+    forward_decode_pool,
     generate_cached,
     init_cache,
 )
@@ -55,6 +56,33 @@ def test_incremental_decode_matches_full_forward(kind):
         ref_full, _ = model_forward(params, seq[:, : t + 1], cfg)
         np.testing.assert_allclose(
             logits[:, -1], ref_full[:, -1], rtol=1e-4, atol=1e-4,
+            err_msg=f"divergence at position {t}",
+        )
+
+
+@pytest.mark.parametrize("kind", ["control", "ndiff"])
+def test_served_rope_model_matches_the_kernel_path_forward(kind):
+    """The full forward at ``attention_impl='pallas'`` rotates contiguous
+    halves of projections whose weights' columns it re-ordered
+    (models/common.py:flash_bh_fn); the prefill chunk and the decode step
+    rotate pairs 2i, 2i + 1 and keep the K ring in the published order.
+    One checkpoint must read the same through both: prefill 8 tokens, then
+    decode 6 on the pool, against the kernel path over all 14 (causal, so
+    its row t is the prefix's last)."""
+    import dataclasses
+
+    cfg = _cfg(kind)
+    params = init_model(jax.random.PRNGKey(0), cfg)
+    seq = jax.random.randint(jax.random.PRNGKey(2), (2, 14), 0, cfg.vocab_size)
+    ref, _ = model_forward(
+        params, seq, dataclasses.replace(cfg, attention_impl="pallas"))
+    logits, cache = forward_chunk(params, seq[:, :8], 0, init_cache(cfg, 2), cfg)
+    np.testing.assert_allclose(logits, ref[:, :8], rtol=1e-4, atol=1e-4)
+    for t in range(8, 14):
+        logits, cache = forward_decode_pool(
+            params, seq[:, t], jnp.full((2,), t, jnp.int32), cache, cfg)
+        np.testing.assert_allclose(
+            logits, ref[:, t], rtol=1e-4, atol=1e-4,
             err_msg=f"divergence at position {t}",
         )
 

@@ -399,10 +399,11 @@ class TestTokenMajor:
         np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
 
     def test_bh_fn_tm_with_rope_matches_dense(self):
-        """The tm branch with LIVE RoPE tables — the path every
-        recipe-scale control run takes (control.py:94 passes cos/sin,
-        S=1, T<=512): rotation in the (B, T, H, d) headed layout must
-        match rotating the dense path's projections."""
+        """The tm branch with LIVE RoPE tables (control.py passes cos/sin,
+        S=1, T<=512; this narrow shape takes the per-array kernels): the
+        kernels' rotation of re-ordered halves in VMEM must match rotating
+        the dense path's projections. tests/test_rope_kernel_path.py holds
+        the gradients and the other branches."""
         from differential_transformer_replication_tpu.models import common
         from differential_transformer_replication_tpu.ops.rope import (
             apply_rope,

@@ -16,6 +16,7 @@ skip these cases (the tier-1 command runs them serially).
 """
 
 import importlib
+import math
 import os
 import re
 
@@ -142,15 +143,7 @@ def test_flash_attention_fwd_bwd_compiles(topo, family):
     assert any("flash_bwd" in n for n in names)
 
 
-@pytest.mark.parametrize("family", [
-    "diff",  # the flagship, and what chip_smoke.py trains
-    pytest.param("control", marks=pytest.mark.slow),  # 10 s
-    pytest.param("ndiff", marks=pytest.mark.slow),  # 20 s
-])
-def test_model_attention_path_compiles(topo, family):
-    """The attention path the models take at T=512 without dropout (the
-    token-major kernels; packed for diff, RoPE'd for control/ndiff),
-    projections included, forward and backward."""
+def _attention_half_text(topo, family, batch=B):
     d, rope = FAMILIES[family][2], FAMILIES[family][4]
     cos, sin = rope_cos_sin(d, T) if rope else (None, None)
 
@@ -159,12 +152,63 @@ def test_model_attention_path_compiles(topo, family):
                           cos=cos, sin=sin)()
         return out.astype(jnp.float32).sum()
 
-    text = compile_for(topo, jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
-                       *_attention_shapes(family))
+    _, *weights = _attention_shapes(family)
+    return compile_for(topo, jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                       sds((batch, T, E)), *weights)
+
+
+@pytest.mark.parametrize("family", [
+    "diff",  # the flagship, and what chip_smoke.py trains
+    pytest.param("control", marks=pytest.mark.slow),  # 10 s
+    pytest.param("ndiff", marks=pytest.mark.slow),  # 20 s
+])
+def test_model_attention_path_compiles(topo, family):
+    """The attention path the models take at T=512 without dropout (the
+    token-major kernels: control and ndiff hand them the RoPE tables and
+    they turn q and k in VMEM; packed but for ndiff, whose four rotated
+    streams pass the packed backward's VMEM), projections included,
+    forward and backward."""
+    text = _attention_half_text(topo, family)
     assert text.count("tpu_custom_call") >= 2
     names = assert_kernels_named(text, "loss")
-    tail = "_tm" if rope else "_tm_packed"  # ``jvp_flash_fwd_tm_packed_``
+    # ``jvp_flash_fwd_tm_packed_``
+    tail = "_tm" if family == "ndiff" else "_tm_packed"
     assert all(re.search(rf"flash_(fwd|bwd){tail}_*$", n) for n in names), names
+
+
+def _layout_ops(text):
+    """``(op, dtype, dims)`` of every gather, transpose and copy
+    instruction of the compiled text."""
+    return [
+        (op, dtype, [int(n) for n in shape.split(",")])
+        for dtype, shape, op in re.findall(
+            r"= (\w+)\[([\d,]+)\]\S* (gather|transpose|copy)\(", text)
+    ]
+
+
+@pytest.mark.parametrize("family", [
+    "control",  # the cell train-control-recipe
+    pytest.param("ndiff", marks=pytest.mark.slow),
+    "diff",
+])
+def test_rotation_leaves_no_layout_op_on_an_activation(topo, family):
+    """ISSUE 37: ``apply_rope``'s stride of two along the lanes compiled,
+    a layer, to 4 gathers that put the FEATURE first, 16 float32
+    transposes and a dozen copies of ``(rows, T, 8, 48)`` arrays: 8.7 GB
+    of HBM traffic where the products need 2. With the weights' columns
+    re-ordered and the halves turned in VMEM, the attention half of a
+    layer, forward and backward, holds NO gather, NO transpose, and no
+    copy of an activation (an array with a T axis as large as half of one
+    head's q): what is left copies weights and the backward's per-row
+    statistics. diff, which rotates nothing, is held to the same, so its
+    packed projection is never copied."""
+    rows = 8
+    ops = _layout_ops(_attention_half_text(topo, family, batch=rows))
+    assert ops, "the pattern no longer finds the weights' copies"
+    assert not [o for o in ops if o[0] != "copy"], ops
+    d = FAMILIES[family][2]
+    assert not [o for o in ops
+                if T in o[2] and math.prod(o[2]) >= rows * T * d // 2], ops
 
 
 def test_flash_attention_with_dropout_compiles(topo):
@@ -362,11 +406,11 @@ def test_train_step_kernels_and_phases_carry_names(step_text, family, n_layer):
     and the model's and the step's phases reach ``op_name``."""
     text = step_text(family, n_layer)
     names = assert_kernels_named(text, "step")
-    # T=512 without dropout: the token-major kernels, packed where the
-    # family has no RoPE
-    flash = ({kernel_names.FLASH_FWD_TM_PACKED, kernel_names.FLASH_BWD_TM_PACKED}
-             if family == "diff"
-             else {kernel_names.FLASH_FWD_TM, kernel_names.FLASH_BWD_TM})
+    # T=512 without dropout: the token-major kernels, packed up to three
+    # rotated streams
+    flash = ({kernel_names.FLASH_FWD_TM, kernel_names.FLASH_BWD_TM}
+             if family == "ndiff" else
+             {kernel_names.FLASH_FWD_TM_PACKED, kernel_names.FLASH_BWD_TM_PACKED})
     assert names == flash | set(kernel_names.FUSED_FFN + kernel_names.FUSED_NORM)
     assert {"embed", "attn_norm", "attn", "ffn_norm", "ffn", "lm_head_loss",
             "grad_norm_clip", "optimizer"} <= scopes_in(text)
