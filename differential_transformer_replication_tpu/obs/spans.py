@@ -142,15 +142,6 @@ class SpanTracer:
             **({"args": args} if args else {}),
         })
 
-    def counter(self, name: str, **values) -> None:
-        """A counter track sample (``"ph": "C"``) — queue depth, slot
-        occupancy — rendered as a stacked area chart by the viewer."""
-        self._append({
-            "name": name, "ph": "C",
-            "ts": self._ts(time.perf_counter()),
-            "pid": self.pid, "tid": 0, "args": values,
-        })
-
     def complete(self, name: str, t0: float, t1: float, **args) -> None:
         """One complete event over an ALREADY-MEASURED
         ``perf_counter`` interval — for spans whose start was recorded
@@ -228,9 +219,6 @@ class _NoopTracer:
         return _NOOP_SPAN
 
     def instant(self, name: str, **args) -> None:
-        pass
-
-    def counter(self, name: str, **values) -> None:
         pass
 
     def complete(self, name: str, t0: float, t1: float, **args) -> None:

@@ -507,9 +507,13 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
         f = jax.lax.bitcast_convert_type(ints[:, 4:8], jnp.float32)
         temperature = f[:, 0]
         keys = jax.vmap(jax.random.fold_in)(bases, counts)
-        proc = apply_logit_pipeline(
-            logits, allowed, counts_v, f[:, 1], f[:, 2], f[:, 3]
-        )
+        # The sampler's parts each under a scope of its own (metadata
+        # only: README, "What a profile calls things"), so a trace says
+        # what every row pays for what few rows asked for.
+        with jax.named_scope("logit_pipeline"):
+            proc = apply_logit_pipeline(
+                logits, allowed, counts_v, f[:, 1], f[:, 2], f[:, 3]
+            )
         V = logits.shape[-1]
         kth = jnp.clip(top_k - 1, 0, V - 1)
 
@@ -518,35 +522,43 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
             return sorted_desc, jnp.take_along_axis(
                 sorted_desc, kth[:, None], axis=-1)
 
-        if quality:
-            # the telemetry tail reads the sort's head, so the sort runs
-            sorted_desc, thresh = _kth_largest(None)
-        else:
-            # Only a row with top_k > 0 reads the threshold, and only a
-            # row with a temperature reads the draw: a batch without one
-            # (all greedy) skips the vocabulary's sort and the noise, the
-            # same tokens bit for bit. At 256 x 65,536 logits the sort
-            # alone was 22 ms of a 42 ms iteration (my chip run, PR 28).
-            thresh = jax.lax.cond(
-                jnp.any(top_k > 0), lambda _: _kth_largest(None)[1],
-                lambda _: jnp.zeros((proc.shape[0], 1), proc.dtype), None)
-        masked = jnp.where(
-            (top_k > 0)[:, None] & (proc < thresh), -jnp.inf, proc
-        )
-        greedy = jnp.argmax(masked, axis=-1)
-        safe_t = jnp.where(temperature > 0, temperature, 1.0)[:, None]
-        scaled = masked / safe_t
-        drawn = jax.lax.cond(
-            jnp.any(temperature > 0),
-            lambda _: jax.vmap(
-                lambda k, lg: jax.random.categorical(k, lg))(
-                    keys, scaled).astype(greedy.dtype),
-            lambda _: jnp.zeros(greedy.shape, greedy.dtype), None)
-        tokens = jnp.where(temperature <= 0, greedy, drawn).astype(jnp.int32)
-        lp = jax.nn.log_softmax(scaled, axis=-1)
-        chosen = jnp.take_along_axis(lp, tokens[:, None], axis=-1)
-        top_lp, top_ids = jax.lax.top_k(lp, lp_k)
-        ok = jnp.isfinite(logits).all(axis=-1)
+        with jax.named_scope("sampler_topk"):
+            if quality:
+                # the telemetry tail reads the sort's head, so the sort
+                # runs
+                sorted_desc, thresh = _kth_largest(None)
+            else:
+                # Only a row with top_k > 0 reads the threshold, and only
+                # a row with a temperature reads the draw: a batch without
+                # one (all greedy) skips the vocabulary's sort and the
+                # noise, the same tokens bit for bit. At 256 x 65,536
+                # logits the sort alone was 22 ms of a 42 ms iteration (my
+                # chip run, PR 28).
+                thresh = jax.lax.cond(
+                    jnp.any(top_k > 0), lambda _: _kth_largest(None)[1],
+                    lambda _: jnp.zeros((proc.shape[0], 1), proc.dtype),
+                    None)
+            masked = jnp.where(
+                (top_k > 0)[:, None] & (proc < thresh), -jnp.inf, proc
+            )
+        with jax.named_scope("sampler_draw"):
+            greedy = jnp.argmax(masked, axis=-1)
+            safe_t = jnp.where(temperature > 0, temperature, 1.0)[:, None]
+            scaled = masked / safe_t
+            drawn = jax.lax.cond(
+                jnp.any(temperature > 0),
+                lambda _: jax.vmap(
+                    lambda k, lg: jax.random.categorical(k, lg))(
+                        keys, scaled).astype(greedy.dtype),
+                lambda _: jnp.zeros(greedy.shape, greedy.dtype), None)
+            tokens = jnp.where(
+                temperature <= 0, greedy, drawn).astype(jnp.int32)
+        with jax.named_scope("sampler_logprobs"):
+            lp = jax.nn.log_softmax(scaled, axis=-1)
+            chosen = jnp.take_along_axis(lp, tokens[:, None], axis=-1)
+            top_lp, top_ids = jax.lax.top_k(lp, lp_k)
+        with jax.named_scope("sampler_finite"):
+            ok = jnp.isfinite(logits).all(axis=-1)
         cols = [
             tokens[:, None],
             ok.astype(jnp.int32)[:, None],
@@ -561,10 +573,11 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
             # margin reuses sorted_desc's head — the sort already paid
             # for the top-k threshold — so the tail adds no second
             # full-vocab top_k to the fused sampler
-            qv = quality_vector(
-                lp, proc, tokens, ints[:, 8],
-                top2=sorted_desc[:, :2] if V >= 2 else None,
-            )
+            with jax.named_scope("sampler_quality"):
+                qv = quality_vector(
+                    lp, proc, tokens, ints[:, 8],
+                    top2=sorted_desc[:, :2] if V >= 2 else None,
+                )
             cols.append(jax.lax.bitcast_convert_type(qv, jnp.int32))
         return jnp.concatenate(cols, axis=1)
 
@@ -747,33 +760,38 @@ def _build_spec_step_fns(cfg: ModelConfig, rope_len: int, draft_len: int,
         # through drafts 0..j-1, built host-side (all-ones when
         # unconstrained — the pipeline's where passes raw logits
         # through bit-identically).
-        if k > 0:
-            oh = jax.nn.one_hot(draft, V, dtype=jnp.int32)
-            prefix = jnp.concatenate(
-                [jnp.zeros((B, 1, V), jnp.int32),
-                 jnp.cumsum(oh, axis=1)], axis=1,
-            )
-        else:
-            prefix = jnp.zeros((B, L, V), jnp.int32)
-        counts3 = pcounts[:, None] + prefix
-        proc = apply_logit_pipeline(
-            logits.reshape(B * L, V), allowed.reshape(B * L, V),
-            counts3.reshape(B * L, V),
-            jnp.repeat(rep, L), jnp.repeat(pres, L),
-            jnp.repeat(freq, L),
-        ).reshape(B, L, V)
+        # The parts the L=1 sampler has carry its scopes' names
+        # (``_build_step_fns._sample``); the accept test itself lies
+        # under ``sampler`` alone.
+        with jax.named_scope("logit_pipeline"):
+            if k > 0:
+                oh = jax.nn.one_hot(draft, V, dtype=jnp.int32)
+                prefix = jnp.concatenate(
+                    [jnp.zeros((B, 1, V), jnp.int32),
+                     jnp.cumsum(oh, axis=1)], axis=1,
+                )
+            else:
+                prefix = jnp.zeros((B, L, V), jnp.int32)
+            counts3 = pcounts[:, None] + prefix
+            proc = apply_logit_pipeline(
+                logits.reshape(B * L, V), allowed.reshape(B * L, V),
+                counts3.reshape(B * L, V),
+                jnp.repeat(rep, L), jnp.repeat(pres, L),
+                jnp.repeat(freq, L),
+            ).reshape(B, L, V)
         if sampled:
-            kth = jnp.clip(topks - 1, 0, V - 1)
-            sorted_desc = -jnp.sort(-proc, axis=-1)
-            thresh = jnp.take_along_axis(
-                sorted_desc,
-                jnp.broadcast_to(kth[:, None, None], (B, L, 1)),
-                axis=-1,
-            )
-            masked = jnp.where(
-                (topks > 0)[:, None, None] & (proc < thresh),
-                -jnp.inf, proc,
-            )
+            with jax.named_scope("sampler_topk"):
+                kth = jnp.clip(topks - 1, 0, V - 1)
+                sorted_desc = -jnp.sort(-proc, axis=-1)
+                thresh = jnp.take_along_axis(
+                    sorted_desc,
+                    jnp.broadcast_to(kth[:, None, None], (B, L, 1)),
+                    axis=-1,
+                )
+                masked = jnp.where(
+                    (topks > 0)[:, None, None] & (proc < thresh),
+                    -jnp.inf, proc,
+                )
         else:
             masked = proc  # greedy: the mask cannot move an argmax
         safe_t = jnp.where(temps > 0, temps, 1.0)
@@ -815,26 +833,28 @@ def _build_spec_step_fns(cfg: ModelConfig, rope_len: int, draft_len: int,
         # distribution (one-hot drafter => target with the rejected
         # token removed, renormalized); greedy argmax is unchanged by
         # that mask (the rejected token was not the argmax)
-        row_logits = jnp.take_along_axis(
-            masked, a[:, None, None], axis=1
-        )[:, 0]  # (B, V)
-        if sampled:
-            rejected = a < dlen
-            corr_logits = jnp.where(
-                rejected[:, None]
-                & (jnp.arange(V)[None, :] == d_rej[:, None]),
-                -jnp.inf, row_logits,
-            )
-            corr_keys = jax.vmap(jax.random.fold_in)(bases, counts + a)
-            drawn = jax.vmap(
-                lambda kk, lg: jax.random.categorical(kk, lg)
-            )(corr_keys, corr_logits / safe_t[:, None])
-            corr = jnp.where(
-                temps <= 0, jnp.argmax(corr_logits, axis=-1), drawn
-            ).astype(jnp.int32)
-        else:
-            del d_rej
-            corr = jnp.argmax(row_logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sampler_draw"):
+            row_logits = jnp.take_along_axis(
+                masked, a[:, None, None], axis=1
+            )[:, 0]  # (B, V)
+            if sampled:
+                rejected = a < dlen
+                corr_logits = jnp.where(
+                    rejected[:, None]
+                    & (jnp.arange(V)[None, :] == d_rej[:, None]),
+                    -jnp.inf, row_logits,
+                )
+                corr_keys = jax.vmap(jax.random.fold_in)(
+                    bases, counts + a)
+                drawn = jax.vmap(
+                    lambda kk, lg: jax.random.categorical(kk, lg)
+                )(corr_keys, corr_logits / safe_t[:, None])
+                corr = jnp.where(
+                    temps <= 0, jnp.argmax(corr_logits, axis=-1), drawn
+                ).astype(jnp.int32)
+            else:
+                del d_rej
+                corr = jnp.argmax(row_logits, axis=-1).astype(jnp.int32)
         jL = jnp.arange(L)[None, :]
         draft_pad = (
             jnp.pad(draft, ((0, 0), (0, 1))) if k > 0
@@ -846,20 +866,22 @@ def _build_spec_step_fns(cfg: ModelConfig, rope_len: int, draft_len: int,
         ).astype(jnp.int32)
         # finite guard over each slot's USED rows only (rows past its
         # draft length computed garbage by design)
-        finite_rows = jnp.isfinite(logits).all(axis=-1)
-        ok = (finite_rows | (jL > dlen[:, None])).all(axis=1)
+        with jax.named_scope("sampler_finite"):
+            finite_rows = jnp.isfinite(logits).all(axis=-1)
+            ok = (finite_rows | (jL > dlen[:, None])).all(axis=1)
         # per-row logprob echo over the distribution each row's token
         # came from (processed + top-k'd + temperature-scaled, same
         # surface as the L=1 sampler). The greedy rung skips the top-k
         # masking (it cannot move an argmax), so its echo ignores
         # top_k — logprob comparisons on the greedy rung hold with
         # top_k off (documented in the README runbook).
-        scaled = masked / safe_t[:, None, None]
-        lp = jax.nn.log_softmax(scaled, axis=-1)
-        chosen_lp = jnp.take_along_axis(
-            lp, tokens_out[..., None], axis=-1
-        )[..., 0]  # (B, L)
-        top_lp, top_ids = jax.lax.top_k(lp, lp_k)  # (B, L, lp_k)
+        with jax.named_scope("sampler_logprobs"):
+            scaled = masked / safe_t[:, None, None]
+            lp = jax.nn.log_softmax(scaled, axis=-1)
+            chosen_lp = jnp.take_along_axis(
+                lp, tokens_out[..., None], axis=-1
+            )[..., 0]  # (B, L)
+            top_lp, top_ids = jax.lax.top_k(lp, lp_k)  # (B, L, lp_k)
         qv = None
         if quality:
             # per-row quality tail over the SAME surfaces as the L=1
@@ -874,11 +896,12 @@ def _build_spec_step_fns(cfg: ModelConfig, rope_len: int, draft_len: int,
             # the sampled rung's top-k threshold sort already ranks
             # proc — reuse its head for the margin (greedy rungs have
             # no sort on hand and fall back to top_k inside)
-            qv = quality_vector(
-                lp, proc, tokens_out, prev_chain,
-                top2=(sorted_desc[..., :2]
-                      if sampled and V >= 2 else None),
-            )
+            with jax.named_scope("sampler_quality"):
+                qv = quality_vector(
+                    lp, proc, tokens_out, prev_chain,
+                    top2=(sorted_desc[..., :2]
+                          if sampled and V >= 2 else None),
+                )
         return (tokens_out, (a + 1).astype(jnp.int32), ok,
                 chosen_lp, top_ids, top_lp, qv)
 
@@ -1814,24 +1837,37 @@ class ServingEngine:
                 # filled in below, once the tokens' read has waited for
                 # the step: the span keeps the dict it was handed
                 decode_args["moe"] = expert_load = {}
+            operands = ((tokens, pos, mask) if self._pages is None
+                        else (tokens, pos, tables, write_pages))
             with self.tracer.span("decode", **decode_args):
-                if self._pages is not None:
-                    logits, self.cache = self._decode_fn(
-                        self.params, jnp.asarray(tokens),
-                        jnp.asarray(pos), self.cache,
-                        jnp.asarray(tables), jnp.asarray(write_pages),
-                    )
-                else:
-                    logits, self.cache, *load = self._decode_fn(
-                        self.params, jnp.asarray(tokens),
-                        jnp.asarray(pos), jnp.asarray(mask), self.cache,
-                    )
+                # the host's share of the step, taken apart: the
+                # operands' transfers, one each, then the call, which
+                # returns before the device is done
+                with self.tracer.span(
+                    "decode_h2d", **self._h2d_args(iteration, operands)
+                ):
+                    operands = [jnp.asarray(a) for a in operands]
+                with self.tracer.span("decode_dispatch",
+                                      iteration=iteration):
+                    if self._pages is not None:
+                        logits, self.cache = self._decode_fn(
+                            self.params, *operands[:2], self.cache,
+                            *operands[2:],
+                        )
+                    else:
+                        logits, self.cache, *load = self._decode_fn(
+                            self.params, *operands, self.cache,
+                        )
             with self.tracer.span("sample", iteration=iteration):
-                sampled, ok, packed = self._sample_all_slots(logits)
+                sampled, ok, packed = self._sample_all_slots(
+                    logits, iteration)
                 if load:
                     # the step has finished (its tokens were just read):
                     # twelve bytes that are there, no second wait
-                    held, top, hit = (int(v) for v in np.asarray(load[0]))
+                    with self.tracer.span("load_read",
+                                          iteration=iteration):
+                        held, top, hit = (
+                            int(v) for v in np.asarray(load[0]))
                     expert_load.update(held=held, max_expert=top,
                                        experts_hit=hit)
                     self.stats.inc("moe_held", held)
@@ -1903,7 +1939,7 @@ class ServingEngine:
                 if self._tracing:
                     self.tracer.instant(
                         "admit", rid=slot.request.request_id,
-                        slot=slot.index, cached=slot.cached_len,
+                        cached=slot.cached_len,
                         **(instant_args(slot.trace)
                            if slot.trace is not None else {}),
                     )
@@ -1941,7 +1977,8 @@ class ServingEngine:
             # prompt complete: the chunk's last-position logits give
             # the first generated token (generate_cached's contract)
             with self.tracer.span("first_token", iteration=iteration):
-                tok, ok, packed = self._sample_rows([slot], logits[None])
+                tok, ok, packed = self._sample_rows(
+                    [slot], logits[None], iteration)
                 if not ok[0]:
                     raise EngineCrashError(
                         f"non-finite logits prefilling slot {slot.index} "
@@ -2048,118 +2085,131 @@ class ServingEngine:
         each slot's draft length to the trash row/page, run the jitted
         step (multi-row forward + fused accept/reject), then emit each
         slot's accepted prefix + corrected token host-side."""
-        B = self.serving.num_slots
-        k = self._spec_k
-        L = k + 1
-        M = self.cfg.block_size
-        c = 3 * L + k
-        # ONE packed int operand (see _build_spec_step_fns._unpack):
-        # tokens | positions | write targets | draft | dlen | counts |
-        # topks | PRNG base (bitcast) | temperature (bitcast) |
-        # force-reject | penalties (bitcast) — a single host->device
-        # conversion per step
-        ints = np.zeros((B, c + 10), np.int32)
-        tok_blk = ints[:, 0:L]
-        pos_blk = ints[:, L:2 * L]
-        targets = ints[:, 2 * L:3 * L]
-        draft = ints[:, 3 * L:c]
-        bases = ints[:, c + 3:c + 5].view(np.uint32)
-        temps = ints[:, c + 5].view(np.float32)
-        temps[:] = 1.0
-        pens = ints[:, c + 7:c + 10].view(np.float32)
-        pens[:, 0] = 1.0  # repetition penalty (1 = off)
-        need_mask = need_counts = False
-        if self._pages is not None:
-            tables = self._pages.tables()
-            ps = self.serving.kv_page_size
-            # targets default to the trash page 0
-        else:
-            targets[:] = B  # default: the trash row (cache batch B)
-        for s in active:
-            d = proposals.get(s.index, [])
-            dl = len(d)
-            p0 = s.prompt_len + len(s.generated) - 1
-            prm = s.request.params
-            row = ints[s.index]
-            tok_blk[s.index, 0] = s.generated[-1]
-            pos_blk[s.index, :] = p0  # clamp invalid rows' gathers
-            for j, t in enumerate(d):
-                tok_blk[s.index, j + 1] = t
-                draft[s.index, j] = t
-            pos_blk[s.index, :dl + 1] = p0 + np.arange(dl + 1)
-            row[c] = dl  # dlen
-            # counts: key-chain position, replay-offset like the L=1
-            # sampler's column 0 (serving/migrate.py key_offset)
-            row[c + 1] = prm.key_offset + len(s.generated)
-            row[c + 2] = prm.top_k or 0  # topks
-            bases[s.index] = self._base_keys[s.request.request_id]
-            temps[s.index] = prm.temperature
-            pens[s.index, 0] = prm.repetition_penalty
-            pens[s.index, 1] = prm.presence_penalty
-            pens[s.index, 2] = prm.frequency_penalty
-            if self._slot_fsm(s) is not None:
-                need_mask = True
-            if _penalties_on(prm):
-                need_counts = True
+        with self.tracer.span("decode_inputs", iteration=iteration):
+            B = self.serving.num_slots
+            k = self._spec_k
+            L = k + 1
+            M = self.cfg.block_size
+            c = 3 * L + k
+            # ONE packed int operand (see _build_spec_step_fns._unpack):
+            # tokens | positions | write targets | draft | dlen | counts |
+            # topks | PRNG base (bitcast) | temperature (bitcast) |
+            # force-reject | penalties (bitcast) — a single host->device
+            # conversion per step
+            ints = np.zeros((B, c + 10), np.int32)
+            tok_blk = ints[:, 0:L]
+            pos_blk = ints[:, L:2 * L]
+            targets = ints[:, 2 * L:3 * L]
+            draft = ints[:, 3 * L:c]
+            bases = ints[:, c + 3:c + 5].view(np.uint32)
+            temps = ints[:, c + 5].view(np.float32)
+            temps[:] = 1.0
+            pens = ints[:, c + 7:c + 10].view(np.float32)
+            pens[:, 0] = 1.0  # repetition penalty (1 = off)
+            need_mask = need_counts = False
             if self._pages is not None:
-                for j in range(dl + 1):
-                    targets[s.index, j] = tables[
-                        s.index, (int(pos_blk[s.index, j]) % M) // ps
-                    ]
+                tables = self._pages.tables()
+                ps = self.serving.kv_page_size
+                # targets default to the trash page 0
             else:
-                targets[s.index, :dl + 1] = s.index
-        dlen = ints[:, c]
-        ints[0, c + 6] = int(faults.spec_reject_storm_at(iteration))
-        # the verify pipeline's mask/histogram operands: per verify
-        # row j, the FSM row for the state reached through drafts
-        # 0..j-1 (walked host-side — table lookups, no device work)
-        # and the PRE-BLOCK histogram (the kernel adds the in-block
-        # draft cumsum itself). Inert cached constants when no active
-        # slot engages the pipeline — the zero-recompile contract's
-        # operand side.
-        V = self.cfg.vocab_size
-        allowed3, pcounts = self._inert_ops(("spec", B), (B, L))
-        if need_mask:
-            am = np.ones((B, L, V), bool)
+                targets[:] = B  # default: the trash row (cache batch B)
             for s in active:
-                fsm = self._slot_fsm(s)
-                if fsm is None:
-                    continue
-                st = s.fsm_state
-                am[s.index, 0] = fsm.allowed_row(st)
-                for j, t in enumerate(proposals.get(s.index, [])):
-                    st = fsm.advance(st, int(t))
-                    am[s.index, j + 1] = fsm.allowed_row(st)
-            allowed3 = jnp.asarray(am)
-        if need_counts:
-            cm = np.zeros((B, V), np.int32)
-            for s in active:
-                if _penalties_on(s.request.params):
-                    cm[s.index] = self._slot_counts(s)
-            pcounts = jnp.asarray(cm)
+                d = proposals.get(s.index, [])
+                dl = len(d)
+                p0 = s.prompt_len + len(s.generated) - 1
+                prm = s.request.params
+                row = ints[s.index]
+                tok_blk[s.index, 0] = s.generated[-1]
+                pos_blk[s.index, :] = p0  # clamp invalid rows' gathers
+                for j, t in enumerate(d):
+                    tok_blk[s.index, j + 1] = t
+                    draft[s.index, j] = t
+                pos_blk[s.index, :dl + 1] = p0 + np.arange(dl + 1)
+                row[c] = dl  # dlen
+                # counts: key-chain position, replay-offset like the L=1
+                # sampler's column 0 (serving/migrate.py key_offset)
+                row[c + 1] = prm.key_offset + len(s.generated)
+                row[c + 2] = prm.top_k or 0  # topks
+                bases[s.index] = self._base_keys[s.request.request_id]
+                temps[s.index] = prm.temperature
+                pens[s.index, 0] = prm.repetition_penalty
+                pens[s.index, 1] = prm.presence_penalty
+                pens[s.index, 2] = prm.frequency_penalty
+                if self._slot_fsm(s) is not None:
+                    need_mask = True
+                if _penalties_on(prm):
+                    need_counts = True
+                if self._pages is not None:
+                    for j in range(dl + 1):
+                        targets[s.index, j] = tables[
+                            s.index, (int(pos_blk[s.index, j]) % M) // ps
+                        ]
+                else:
+                    targets[s.index, :dl + 1] = s.index
+            dlen = ints[:, c]
+            ints[0, c + 6] = int(faults.spec_reject_storm_at(iteration))
+            # the verify pipeline's mask/histogram operands: per verify
+            # row j, the FSM row for the state reached through drafts
+            # 0..j-1 (walked host-side — table lookups, no device work)
+            # and the PRE-BLOCK histogram (the kernel adds the in-block
+            # draft cumsum itself). Inert cached constants when no active
+            # slot engages the pipeline — the zero-recompile contract's
+            # operand side.
+            V = self.cfg.vocab_size
+            am = cm = None
+            if need_mask:
+                am = np.ones((B, L, V), bool)
+                for s in active:
+                    fsm = self._slot_fsm(s)
+                    if fsm is None:
+                        continue
+                    st = s.fsm_state
+                    am[s.index, 0] = fsm.allowed_row(st)
+                    for j, t in enumerate(proposals.get(s.index, [])):
+                        st = fsm.advance(st, int(t))
+                        am[s.index, j + 1] = fsm.allowed_row(st)
+            if need_counts:
+                cm = np.zeros((B, V), np.int32)
+                for s in active:
+                    if _penalties_on(s.request.params):
+                        cm[s.index] = self._slot_counts(s)
         # accept-variant pick: all-greedy steps run the threefry-free
         # specialization (bit-identical on greedy rows)
         spec_fn = self._spec_fn[
             any(s.request.params.temperature > 0 for s in active)
         ]
-        decode_args = {
-            "iteration": iteration, "active": len(active),
-            "drafted": int(dlen.sum()),
-        }
+        decode_args = {"iteration": iteration, "active": len(active)}
         if self._tracing:
             tids = [
                 s.trace.trace_id for s in active if s.trace is not None
             ]
             if tids:
                 decode_args["trace_ids"] = tids
+        operands = [a for a in (
+            ints, am, cm, None if self._pages is None else tables,
+        ) if a is not None]
         with self.tracer.span("decode", **decode_args):
-            out, self.cache = spec_fn(
-                self.params, jnp.asarray(ints), self.cache, allowed3,
-                pcounts,
-                None if self._pages is None else jnp.asarray(tables),
-            )
-        # one transfer for all three host-consumed outputs
-        out = np.asarray(out)
+            with self.tracer.span(
+                "decode_h2d", **self._h2d_args(iteration, operands)
+            ):
+                allowed3, pcounts = self._inert_ops(("spec", B), (B, L))
+                if am is not None:
+                    allowed3 = jnp.asarray(am)
+                if cm is not None:
+                    pcounts = jnp.asarray(cm)
+                ints_d = jnp.asarray(ints)
+                tables_d = (None if self._pages is None
+                            else jnp.asarray(tables))
+            with self.tracer.span("decode_dispatch", iteration=iteration):
+                out, self.cache = spec_fn(
+                    self.params, ints_d, self.cache, allowed3, pcounts,
+                    tables_d,
+                )
+        # one transfer for all three host-consumed outputs: the wait for
+        # the step, the copy back and the thread's wake-up
+        with self.tracer.span("token_read", iteration=iteration,
+                              path="decode"):
+            out = np.asarray(out)
         toks = out[:, :L]
         n_emit = out[:, L]
         ok = out[:, L + 1].astype(bool)
@@ -3179,13 +3229,47 @@ class ServingEngine:
             self._inert[key] = ops
         return ops
 
+    def _h2d_args(self, iteration: int, operands) -> dict:
+        """A ``decode_h2d`` span's args: how many host arrays the step's
+        operands are, one transfer each, and their bytes (summed only
+        for a tracer that records them)."""
+        args = {"iteration": iteration}
+        if self._tracing:
+            args.update(arrays=len(operands),
+                        bytes=sum(a.nbytes for a in operands))
+        return args
+
+    def _sampler_use(self, rows) -> dict:
+        """What the (row index, slot) assignment asks of the sampler
+        beyond an argmax, as counts of rows: with an FSM, with a penalty
+        on, with ``logprobs > 0``, with a temperature, and with any of
+        the four. The ``sample_operands`` span's args, so a trace sets
+        what the sampler costs EVERY row beside the rows that wanted it;
+        a pass of its own over the rows, made only for a tracer that
+        records."""
+        masked = penalized = logprobs = tempered = asking = 0
+        for _, s in rows:
+            p = s.request.params
+            uses = (self._slot_fsm(s) is not None, _penalties_on(p),
+                    p.logprobs > 0, p.temperature > 0)
+            masked += uses[0]
+            penalized += uses[1]
+            logprobs += uses[2]
+            tempered += uses[3]
+            asking += any(uses)
+        return {"masked": masked, "penalized": penalized,
+                "logprobs": logprobs, "tempered": tempered,
+                "asking": asking}
+
     def _sample_operands(self, rows, B):
         """Packed (B, 8) int32 sampler operand plus the pipeline's
-        allowed/counts arrays for a (row index, slot) assignment (see
-        _build_step_fns._sample for the column layout; quality
+        allowed/counts HOST arrays for a (row index, slot) assignment
+        (see _build_step_fns._sample for the column layout; quality
         telemetry widens it by one previous-token column). Rows not
         named keep inert defaults (temp 1, penalties off, mask
-        all-ones, no previous token)."""
+        all-ones, no previous token); a mask or histogram no row needs
+        is None, for which the caller passes ``_inert_ops``'s cached
+        device constant."""
         ints = np.zeros((B, 9 if self._quality else 8), np.int32)
         f = ints[:, 4:8].view(np.float32)
         f[:, 0] = 1.0  # temperature
@@ -3220,48 +3304,64 @@ class ServingEngine:
                 need_mask = True
             if _penalties_on(p):
                 need_counts = True
-        allowed, counts = self._inert_ops(B, (B,))
+        am = cm = None
         if need_mask:
             am = np.ones((B, self.cfg.vocab_size), bool)
             for i, s in rows:
                 fsm = self._slot_fsm(s)
                 if fsm is not None:
                     am[i] = fsm.allowed_row(s.fsm_state)
-            allowed = jnp.asarray(am)
         if need_counts:
             cm = np.zeros((B, self.cfg.vocab_size), np.int32)
             for i, s in rows:
                 if _penalties_on(s.request.params):
                     cm[i] = self._slot_counts(s)
-            counts = jnp.asarray(cm)
-        return ints, allowed, counts
+        return ints, am, cm
 
-    def _sample_rows(self, slots: List[Slot], logits):
+    def _sample(self, rows, B: int, logits, iteration: int, path: str):
+        """One sampler call over a (row index, slot) assignment of B
+        rows, the host's share of it in three spans inside the caller's
+        ``sample`` / ``first_token``: ``sample_operands`` (the packed
+        rows, a mask or histogram where a row needs one),
+        ``sample_dispatch`` (their transfers and the call, which
+        returns before the device is done) and ``token_read`` (the
+        BLOCKING read: the wait for the device, the copy back, the
+        thread's wake-up). ``path`` says which caller: ``decode`` or
+        ``prefill``. Returns (tokens, finite-ok, packed echo rows) — the
+        packed layout is _build_step_fns._sample's output contract."""
+        args = {"iteration": iteration, "path": path}
+        use = self._sampler_use(rows) if self._tracing else {}
+        with self.tracer.span("sample_operands", rows=B,
+                              active=len(rows), **args, **use):
+            ints, mask, hist = self._sample_operands(rows, B)
+        with self.tracer.span("sample_dispatch", **args):
+            allowed, counts = self._inert_ops(B, (B,))
+            if mask is not None:
+                allowed = jnp.asarray(mask)
+            if hist is not None:
+                counts = jnp.asarray(hist)
+            out = self._sample_fn(
+                jnp.asarray(ints), logits, allowed, counts
+            )
+        with self.tracer.span("token_read", **args):
+            out = np.asarray(out)
+        return out[:, 0], out[:, 1].astype(bool), out
+
+    def _sample_rows(self, slots: List[Slot], logits, iteration: int):
         """Sample one token for each given slot from (n, V) logits
-        through the logit pipeline; returns (tokens, finite-ok,
-        packed echo rows) — the packed layout is
-        _build_step_fns._sample's output contract."""
-        ints, allowed, counts = self._sample_operands(
-            list(enumerate(slots)), len(slots)
-        )
-        out = np.asarray(self._sample_fn(
-            jnp.asarray(ints), logits, allowed, counts
-        ))
-        return out[:, 0], out[:, 1].astype(bool), out
+        through the logit pipeline (a completed prompt's first
+        token)."""
+        return self._sample(list(enumerate(slots)), len(slots), logits,
+                            iteration, "prefill")
 
-    def _sample_all_slots(self, logits):
+    def _sample_all_slots(self, logits, iteration: int):
         """Full-pool variant with inert defaults on non-active rows, so
-        the decode-path sampler always sees the same (B, V) shape.
-        Returns (tokens, finite-ok, packed); only ACTIVE rows mean
-        anything (inactive rows compute garbage by design)."""
-        ints, allowed, counts = self._sample_operands(
+        the decode-path sampler always sees the same (B, V) shape; only
+        ACTIVE rows mean anything (inactive rows compute garbage by
+        design)."""
+        return self._sample(
             [(s.index, s) for s in self.scheduler.active_slots()],
-            self._rows,
-        )
-        out = np.asarray(self._sample_fn(
-            jnp.asarray(ints), logits, allowed, counts
-        ))
-        return out[:, 0], out[:, 1].astype(bool), out
+            self._rows, logits, iteration, "decode")
 
     def _lp_echo(self, s: Slot, row: np.ndarray):
         """Decode one sampler echo row into the (chosen logprob,
@@ -3502,8 +3602,7 @@ class ServingEngine:
             )
             self.tracer.complete(
                 "request", slot.submit_time, out.finish_time,
-                rid=out.request_id, reason=reason,
-                tokens=len(out.tokens), **sargs,
+                rid=out.request_id, reason=reason, **sargs,
             )
         del self._base_keys[slot.request.request_id]
         self._drop_constraint(slot.request.request_id)

@@ -713,10 +713,12 @@ class EngineRunner:
             self._restarting = False
         return True
 
-    def _intake(self, cancels, incoming, commands, waiters: dict) -> None:
+    def _intake(self, cancels, incoming, commands, waiters: dict) -> int:
         """What the loop took from the callers since its last look:
         cancellations, new requests (each goes to ``engine.submit``),
-        and engine-thread commands."""
+        and engine-thread commands. Returns the requests it handed
+        ``engine.submit``."""
+        handed = 0
         for pending in cancels:
             if pending.rid is not None:
                 if self.engine.cancel(pending.rid):
@@ -740,6 +742,7 @@ class EngineRunner:
                     opt["deadline"] = pending.deadline
                 if pending.trace is not None:
                     opt["trace"] = pending.trace
+                handed += 1
                 pending.rid = self.engine.submit(
                     pending.prompt, params=pending.params, **opt
                 )
@@ -750,6 +753,7 @@ class EngineRunner:
             # migration export/import thunks (run_on_engine): each
             # captures its own exception and signals its caller
             thunk()
+        return handed
 
     def _loop(self) -> None:
         waiters = self._waiters  # request_id -> _Pending (this thread's)
@@ -788,8 +792,13 @@ class EngineRunner:
                 for p in incoming:
                     self._settle(p, error=err)
                 return
-            with tracer.span("intake"):
-                self._intake(cancels, incoming, commands, waiters)
+            # a span's keyword args are copied when it is made, so the
+            # count rides in a dict the span keeps and is filled in
+            # before the span closes (as the ``decode`` span's ``moe``)
+            submitted = {"requests": 0}
+            with tracer.span("intake", submitted=submitted):
+                submitted["requests"] = self._intake(
+                    cancels, incoming, commands, waiters)
             try:
                 t0 = time.perf_counter()
                 # the watchdog state is read by status() from HTTP

@@ -14,9 +14,17 @@ them (``benchmark/layer_metrics/``), at no chip time:
   ``iteration`` rule (only spans between an iteration's ``schedule``
   start and its ``emit`` end are stamped), what the readers the
   benchmark already had read from it, and ``tracer=None`` sending every
-  site to ``NOOP_TRACER``.
+  site to ``NOOP_TRACER``;
+- ISSUE 38: the sub-spans inside ``decode``, ``sample`` and
+  ``first_token`` (their nesting, their cover of the parent, ``path``,
+  ``rows`` and the counts of what the rows asked of the sampler, on the
+  plain and on the speculative path), ``intake``'s count of requests, the
+  served tokens and ``engine.stats`` with and without a tracer, and the
+  scopes inside the sampler program; the readers' cases are
+  ``benchmark/tests/test_benchmark_host_share.py``'s, imported below.
 """
 
+import importlib.util
 import sys
 import threading
 from pathlib import Path
@@ -30,6 +38,18 @@ sys.path.insert(0, str(REPO / "benchmark"))
 import selftest  # noqa: E402  (benchmark/selftest.py: the xplane writer)
 from lib import harness, op_phases, xplane  # noqa: E402
 from lib.spans import SpanRecorder  # noqa: E402
+
+# PR 38: the cases of the sub-spans' and the sampler scopes' readers live
+# with the benchmark (benchmark/tests/); the tier-1 command collects
+# ``tests/`` only, so they are imported here and run under their own
+# names, as tests/test_benchmark_program.py does with its own
+_spec = importlib.util.spec_from_file_location(
+    "benchmark_host_share_cases",
+    REPO / "benchmark" / "tests" / "test_benchmark_host_share.py")
+_host_share_cases = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_host_share_cases)
+globals().update({name: fn for name, fn in vars(_host_share_cases).items()
+                  if name.startswith("test_")})
 
 from differential_transformer_replication_tpu.config import (  # noqa: E402
     ModelConfig,
@@ -233,36 +253,6 @@ def test_breakdown_names_kernels_and_gaps(planes):
 # string or as a reference to a stat_metadata entry whose name is the string.
 
 
-def _plane_with_paths(name, lines, paths, by_ref=()):
-    """``selftest._plane`` plus, on the metadata of every event named in
-    ``paths``, its op_name under ``tf_op`` (a reference for ``by_ref``),
-    and on every metadata a stat that is not the op_name."""
-    ld, vi = selftest._ld, selftest._vi
-    ids, body = {}, b""
-    for lname, events in lines:
-        evs = b""
-        for ename, start_us, dur_us in events:
-            mid = ids.setdefault(ename, len(ids) + 1)
-            evs += ld(4, vi(1, mid) + vi(2, start_us * 10**6)
-                      + vi(3, dur_us * 10**6))
-        body += ld(3, ld(2, lname.encode()) + vi(3, 1000) + evs)
-    stat_names, meta = {1: op_phases.OP_NAME_STAT, 2: "flops"}, b""
-    for ename, mid in ids.items():
-        stats = ld(5, vi(1, 2) + vi(3, 7))
-        path = paths.get(ename)
-        if path is not None and ename in by_ref:
-            sid = 10 + len(stat_names)
-            stat_names[sid] = path
-            stats += ld(5, vi(1, 1) + vi(7, sid))
-        elif path is not None:
-            stats += ld(5, vi(1, 1) + ld(5, path.encode()))
-        meta += ld(4, vi(1, mid) + ld(2, vi(1, mid) + ld(2, ename.encode())
-                                      + stats))
-    for sid, sname in stat_names.items():
-        meta += ld(5, vi(1, sid) + ld(2, vi(1, sid) + ld(2, sname.encode())))
-    return ld(2, name.encode()) + body + meta
-
-
 # two traced train steps, microseconds; a cond holds an op of its body and
 # an unscoped while holds an op of attention: time goes to the innermost
 _F = "%fusion.{} = f32[8] fusion(f32[8] %p)"
@@ -316,7 +306,7 @@ def _traced_run(monkeypatch, tmp_path, cell_name, ops, mods=(), scoped=True):
     lines = [("XLA Ops", [(n, a, d) for n, a, d, _ in ops])]
     if mods:
         lines.append(("XLA Modules", list(mods)))
-    data = selftest._ld(1, _plane_with_paths(
+    data = selftest._ld(1, _host_share_cases.plane_with_paths(
         "/device:TPU:0", lines, paths, by_ref={_F.format(3)}))
     monkeypatch.setattr(harness, "OUT_DIR", str(tmp_path))
     # an older trace of another cell whose name starts the same: not this one
@@ -506,6 +496,11 @@ def test_decode_attn_rows_read_per_step_by_hand(rows, want):
 STAMPED = {"schedule", "prefill", "prefill_call", "first_token",
            "decode_inputs", "decode", "sample", "emit"}
 UNSTAMPED = {"step_tail", "intake", "deliver"}
+# ISSUE 38: the parts of `decode`, `sample` and `first_token`, stamped
+# like their parents
+SUB_SPANS = {"decode_h2d", "decode_dispatch", "sample_operands",
+             "sample_dispatch", "token_read"}
+USE = ("masked", "penalized", "logprobs", "tempered", "asking")
 PROMPTS = [list(range(1, 1 + n)) for n in (5, 9, 20, 3)]  # 20 > one chunk
 NEW_TOKENS = 4
 
@@ -572,6 +567,15 @@ def test_engine_span_names_args_and_the_iteration_rule(served):
         assert all(_inside(s, p) for s in calls + firsts)
         assert all({"iteration", "size"} == set(c[3]) for c in calls)
         assert all({"iteration"} == set(f[3]) for f in firsts)
+        # the sampler call of a completed prompt, taken apart
+        for f in firsts:
+            parts = [c for n in SUB_SPANS - {"decode_h2d", "decode_dispatch"}
+                     for c in by[n] if _inside(c, f)]
+            assert sorted(c[0] for c in parts) == [
+                "sample_dispatch", "sample_operands", "token_read"]
+            assert all(c[3]["path"] == "prefill"
+                       and c[3]["iteration"] == args["iteration"]
+                       for c in parts)
         chunks += args["chunks"]
     # every chunk the scheduler planned is one prefill_call, at most 8 long
     assert chunks == len(by["prefill_call"]) >= 1 + 2 + 3 + 1
@@ -586,7 +590,7 @@ def test_engine_span_names_args_and_the_iteration_rule(served):
     for s in by["emit"] + by["prefill"]:
         it = s[3]["iteration"]
         last[it] = max(last.get(it, 0.0), s[2])
-    for name in STAMPED:
+    for name in STAMPED | SUB_SPANS:
         for s in by[name]:
             it = s[3]["iteration"]
             assert sched[it][1] <= s[1] and s[2] <= last[it], (name, it)
@@ -594,7 +598,12 @@ def test_engine_span_names_args_and_the_iteration_rule(served):
     dec = {s[3]["iteration"]: s for s in by["decode"]}
     for s in by["decode_inputs"]:
         assert s[2] <= dec[s[3]["iteration"]][1]
-    assert not any(s[3] for name in UNSTAMPED for s in by[name])
+    assert not any(s[3] for name in ("step_tail", "deliver")
+                   for s in by[name])
+    # intake: the requests it handed engine.submit, and nothing else
+    assert all(set(s[3]) == {"submitted"} for s in by["intake"])
+    assert sum(s[3]["submitted"]["requests"] for s in by["intake"]) == len(
+        PROMPTS)
     # step_tail, deliver and intake lie outside every iteration
     spans_of_iter = [(sched[it][1], end) for it, end in last.items()]
     for name in UNSTAMPED:
@@ -658,7 +667,7 @@ def test_tracing_off_sends_every_site_to_the_noop_tracer(monkeypatch):
     assert engine.tracer is obs_spans.NOOP_TRACER and not engine._tracing
     _serve(None)
     names = {n for n, _, _ in seen}
-    assert STAMPED | UNSTAMPED <= names
+    assert STAMPED | UNSTAMPED | SUB_SPANS <= names
     # nothing was counted or listed for a span nobody records
     for name, args, _ in seen:
         if name == "prefill":
@@ -667,3 +676,209 @@ def test_tracing_off_sends_every_site_to_the_noop_tracer(monkeypatch):
             # `attend_rows` (PR 33) is one number, which the engine's
             # counter `decode_attend_rows` takes whether traced or not
             assert set(args) == {"iteration", "active", "attend_rows"}
+        # ISSUE 38: the bytes and the counts of use cost a loop each
+        if name == "decode_h2d":
+            assert set(args) == {"iteration"}
+        if name == "sample_operands":
+            assert set(args) == {"iteration", "path", "rows", "active"}
+
+
+# -- ISSUE 38: the host's share of an iteration, taken apart --------------------
+
+
+def _by_name(rec):
+    by = {}
+    for s in rec.spans:
+        by.setdefault(s[0], []).append(s)
+    return by
+
+
+def _children(by, parent, names):
+    return [c for n in names for c in by.get(n, ()) if _inside(c, parent)]
+
+
+def _cover(by, parent_name, names) -> float:
+    """The share of the parents' time that their sub-spans cover, summed
+    over the record."""
+    whole = sum(p[2] - p[1] for p in by[parent_name])
+    parts = sum(c[2] - c[1] for p in by[parent_name]
+                for c in _children(by, p, names))
+    return parts / whole
+
+
+def test_sub_spans_lie_inside_their_parents_and_cover_them(served):
+    rec, _ = served
+    by = _by_name(rec)
+    assert SUB_SPANS <= set(by)
+    for d in by["decode"]:
+        parts = _children(by, d, ("decode_h2d", "decode_dispatch"))
+        assert [c[0] for c in sorted(parts, key=lambda c: c[1])] == [
+            "decode_h2d", "decode_dispatch"]
+        assert all(c[3]["iteration"] == d[3]["iteration"] for c in parts)
+        h2d = next(c for c in parts if c[0] == "decode_h2d")
+        # tokens, pos (int32) and mask (bool) of the pool's rows
+        rows = 4  # the toy's slots
+        assert set(h2d[3]) == {"iteration", "arrays", "bytes"}
+        assert (h2d[3]["arrays"], h2d[3]["bytes"]) == (3, rows * (4 + 4 + 1))
+    for smp in by["sample"]:
+        parts = _children(by, smp, SUB_SPANS)
+        assert [c[0] for c in sorted(parts, key=lambda c: c[1])] == [
+            "sample_operands", "sample_dispatch", "token_read"]
+        assert all(c[3]["iteration"] == smp[3]["iteration"]
+                   and c[3]["path"] == "decode" for c in parts)
+    # every sub-span has a parent of the right name
+    for name, parents in (("decode_h2d", ("decode",)),
+                          ("decode_dispatch", ("decode",)),
+                          ("sample_operands", ("sample", "first_token")),
+                          ("sample_dispatch", ("sample", "first_token")),
+                          ("token_read", ("sample", "first_token"))):
+        for c in by[name]:
+            held = [p for n in parents for p in by[n] if _inside(c, p)]
+            assert len(held) == 1, (name, c)
+            assert held[0][3]["iteration"] == c[3]["iteration"]
+            if "decode" not in parents:  # the sampler's say which call
+                assert c[3]["path"] == (
+                    "prefill" if held[0][0] == "first_token" else "decode")
+    assert _cover(by, "decode", ("decode_h2d", "decode_dispatch")) >= 0.9
+    assert _cover(by, "sample", SUB_SPANS) >= 0.9
+    # the default request draws at temperature 1 and asks nothing else
+    for c in by["sample_operands"]:
+        assert set(c[3]) == {"iteration", "path", "rows", "active", *USE}
+        assert [c[3][k] for k in USE] == [
+            0, 0, 0, c[3]["active"], c[3]["active"]]
+        assert c[3]["rows"] == (1 if c[3]["path"] == "prefill" else 4)
+
+
+MIXED = [  # one row of each kind, and what it asks of the sampler
+    (dict(temperature=0.0), ()),
+    (dict(temperature=0.8, top_k=5, seed=3), ("tempered",)),
+    (dict(temperature=0.0, logprobs=2), ("logprobs",)),
+    (dict(temperature=0.0, repetition_penalty=1.3), ("penalized",)),
+]
+
+
+def _drive(tracer, serving=None, prompts=None, params=None):
+    """The toy engine stepped by hand (no runner: every request is
+    submitted before the first iteration, so every decode step holds all
+    of them); returns (outputs in submission order, the engine's stats)."""
+    cfg, weights, toy_serving = _toy()
+    engine = ServingEngine(weights, cfg, serving or toy_serving,
+                           tracer=tracer)
+    prompts = prompts or [list(range(1 + i, 6 + i)) for i in range(4)]
+    params = params or [SamplingParams(max_new_tokens=NEW_TOKENS, **kw)
+                        for kw, _ in MIXED]
+    outs = engine.generate(prompts, params=params)
+    return outs, dict(engine.stats)
+
+
+def test_sample_operands_counts_what_the_rows_asked_for():
+    rec = SpanRecorder()
+    _drive(rec)
+    ops = _by_name(rec)["sample_operands"]
+    first = [c[3] for c in ops if c[3]["path"] == "prefill"]
+    assert len(first) == len(MIXED)
+    for args, (_, asks) in zip(first, MIXED):
+        assert (args["rows"], args["active"]) == (1, 1)
+        assert {k for k in USE if args[k]} == set(asks) | (
+            {"asking"} if asks else set())
+    steps = [c[3] for c in ops if c[3]["path"] == "decode"]
+    assert len(steps) == NEW_TOKENS - 1
+    for args in steps:  # all four rows in every decode step
+        assert (args["rows"], args["active"]) == (4, 4)
+        assert [args[k] for k in USE] == [0, 1, 1, 1, 3]
+
+
+def test_served_tokens_and_stats_do_not_depend_on_the_tracer():
+    """The hoisted transfers, the shared sampler helper and the counts
+    made for a recording tracer change nothing that is served."""
+    untraced, stats = _drive(None)
+    traced, stats_traced = _drive(SpanRecorder())
+    assert [o.tokens for o in traced] == [o.tokens for o in untraced]
+    assert [o.finish_reason for o in traced] == [
+        o.finish_reason for o in untraced]
+    assert traced[2].token_logprobs == untraced[2].token_logprobs
+    assert stats_traced == stats and stats["decode_tokens"] > 0
+
+
+def test_speculative_path_reads_its_tokens_inside_token_read():
+    """``_decode_spec``: the operands' build under ``decode_inputs``, the
+    transfers and the call inside ``decode``, and the blocking read, which
+    no span held, in a ``token_read`` of path ``decode`` between its
+    ``decode`` and its ``emit``."""
+    _, _, toy_serving = _toy()
+    import dataclasses
+
+    serving = dataclasses.replace(toy_serving, spec_mode="ngram",
+                                  spec_draft_len=2)
+    rec = SpanRecorder()
+    # a prompt that repeats itself: the n-gram drafter has proposals
+    outs, stats = _drive(rec, serving, prompts=[[7, 8, 9] * 4],
+                         params=[SamplingParams(max_new_tokens=8)])
+    assert stats["spec_proposed"] > 0
+    by = _by_name(rec)
+    sampled = {s[3]["iteration"] for s in by.get("sample", ())}
+    spec = [d for d in by["decode"] if d[3]["iteration"] not in sampled]
+    assert spec, "no speculative step ran"
+    for d in spec:
+        it = d[3]["iteration"]
+        mine = {n: [c for c in by.get(n, ()) if c[3]["iteration"] == it]
+                for n in ("decode_inputs", "decode_h2d", "decode_dispatch",
+                          "token_read", "sample", "emit")}
+        assert not mine["sample"]  # the verify step samples on the device
+        (inputs,), (h2d,), (call,), (read_,), (emit,) = (
+            mine[n] for n in ("decode_inputs", "decode_h2d",
+                              "decode_dispatch", "token_read", "emit"))
+        assert inputs[2] <= d[1]
+        assert _inside(h2d, d) and _inside(call, d) and h2d[2] <= call[1]
+        assert d[2] <= read_[1] and read_[2] <= emit[1]
+        assert read_[3] == {"iteration": it, "path": "decode"}
+        assert h2d[3]["arrays"] == 1  # the one packed operand
+    # the untraced engine serves the same tokens
+    same, _ = _drive(None, serving, prompts=[[7, 8, 9] * 4],
+                     params=[SamplingParams(max_new_tokens=8)])
+    assert [o.tokens for o in same] == [o.tokens for o in outs]
+
+
+SAMPLER_SCOPES = {"sampler", "logit_pipeline", "sampler_topk",
+                  "sampler_draw", "sampler_logprobs", "sampler_finite"}
+
+
+def _scopes_in(text: str) -> set:
+    """Every path component of every ``op_name`` of a compiled text."""
+    import re
+
+    return {part for path in re.findall(r'op_name="([^"]*)"', text)
+            for part in path.split("/")}
+
+
+@pytest.mark.parametrize("quality", [False, True], ids=["plain", "quality"])
+@pytest.mark.parametrize("program", ["sample", "spec_verify"])
+def test_sampler_programs_carry_the_scope_names(program, quality):
+    """Metadata only: the parts of the sampler program, and of the
+    speculative verify's sampler half where it has them, reach
+    ``op_name``, which is what ``benchmark/lib/scopes.py`` reads of a chip
+    trace (a compile on the CPU is enough: no kernel is involved)."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    cfg, weights, serving = _toy()
+    serving = dataclasses.replace(
+        serving, quality_telemetry=quality,
+        **(dict(spec_mode="ngram", spec_draft_len=2)
+           if program == "spec_verify" else {}))
+    engine = ServingEngine(weights, cfg, serving)
+    B, V, S = engine._rows, cfg.vocab_size, jax.ShapeDtypeStruct
+    if program == "sample":
+        lowered = engine._sample_fn.lower(
+            S((B, 9 if quality else 8), jnp.int32), S((B, V), jnp.float32),
+            S((B, V), jnp.bool_), S((B, V), jnp.int32))
+        want = SAMPLER_SCOPES
+    else:
+        B, L = serving.num_slots, 3
+        lowered = engine._spec_fn[True].lower(
+            engine.params, S((B, 3 * L + 2 + 10), jnp.int32), engine.cache,
+            S((B, L, V), jnp.bool_), S((B, V), jnp.int32), None)
+        want = SAMPLER_SCOPES
+    got = _scopes_in(lowered.compile().as_text())
+    assert want | ({"sampler_quality"} if quality else set()) <= got
