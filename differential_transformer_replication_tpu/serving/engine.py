@@ -313,6 +313,13 @@ class EngineCrashError(RuntimeError):
     retriable = True
 
 
+# What a row asks of the sampler program beyond an argmax and the
+# finiteness check, one bit each in column 8 of its packed operand
+# (``_build_step_fns._sample``): the host sets them for the rows a call
+# names (``ServingEngine._row_asks``), the program takes its arms by them.
+ASK_MASK, ASK_PENALTY, ASK_LOGPROBS, ASK_TEMPERATURE = 1, 2, 4, 8
+
+
 @lru_cache(maxsize=None)
 def _build_step_fns(cfg: ModelConfig, rope_len: int,
                     page_size: int = 0, num_pages: int = 0,
@@ -466,24 +473,45 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
     def _sample(ints, logits, allowed, counts_v):
         """Batched per-request sampling over (B, V) fp32 logits,
         through the structured-decoding logit pipeline
-        (models/decode.py:``apply_logit_pipeline``).
+        (models/decode.py:``apply_logit_pipeline``), computing per call
+        only what a row of it ASKED for.
 
-        Every per-row scalar rides ONE packed (B, 8) int32 operand
+        Every per-row scalar rides ONE packed (B, 9) int32 operand
         (one host->device conversion per call): token count | top_k |
         PRNG base (2 cols, bitcast uint32) | temperature | repetition
-        | presence | frequency penalties (bitcast f32); with
-        ``quality`` on, one extra column carries the previous emitted
-        token (-1 = none) for the repetition flag. ``allowed``
-        (B, V) bool is the per-row constraint-FSM mask row and
-        ``counts_v`` (B, V) int32 the generated-token histogram — both
-        runtime arrays (the engine passes cached all-ones/zeros
-        constants when no active row needs the pipeline), so mixed
-        constrained/unconstrained traffic never recompiles. The t-th
+        | presence | frequency penalties (bitcast f32) | what the row
+        asks (the ``ASK_*`` bits: an FSM mask, a penalty, ``logprobs >
+        0``, a temperature); with ``quality`` on, one extra column
+        carries the previous emitted token (-1 = none) for the
+        repetition flag. A row the call does not name (an empty slot of
+        the pool) asks NOTHING: its bits are 0, its temperature and
+        top_k 0. ``allowed`` (B, V) bool is the per-row constraint-FSM
+        mask row and ``counts_v`` (B, V) int32 the generated-token
+        histogram — both runtime arrays (the engine passes cached
+        all-ones/zeros constants when no active row needs the
+        pipeline), and so are the bits: mixed traffic never recompiles,
+        it takes another arm of this one program. The t-th
         token's key is fold_in(base, t); temperature/top-k semantics
         match sample_token row-for-row (<=0 temp = greedy, top_k <= 0
         = off, mask-below-kth-PROCESSED-logit otherwise). Rows with
         the pipeline inert are BIT-IDENTICAL to the pre-pipeline
         sampler (the pipeline's ``where`` passes raw logits through).
+
+        The arms, a ``lax.cond`` each on runtime values of the operand,
+        every one returning small arrays only (a branch that handed the
+        processed logits on would copy them: 168 MB at 256 x 163,840):
+        no bit set in the whole batch -> the PLAIN arm, one argmax over
+        the raw logits and the finiteness check, zeros in the echo
+        columns. Its tokens are the full arm's bit for bit: with the
+        pipeline inert the processed logits ARE the logits, a top-k
+        never masks the largest, and a row without a temperature takes
+        the argmax. Some bit set -> the full arm, every row through the
+        pipeline, and inside it the vocabulary's sort only where some
+        row has a top_k, the draw only where some row has a
+        temperature, the log-softmax and its top-k only where some row
+        has ``ASK_LOGPROBS`` (zeros else). With ``quality`` on the
+        telemetry tail reads the sort and the log-softmax of every
+        call, so that build is the full arm alone with both run.
 
         Output is ONE packed (B, 3 + 2*lp_k) int32 array: token |
         finite-ok | chosen-token logprob (bitcast f32) | top-lp_k ids
@@ -494,92 +522,127 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
         distribution actually sampled from — processed logits after
         top-k, divided by the greedy-safe temperature. The finiteness
         flag is over the RAW logits (before the intentional -inf
-        masking): a corrupt KV slot or numerically diverged model
-        yields NaN logits, and serving a garbage argmax over them
-        would be a silent wrong answer — the engine turns a non-finite
-        ACTIVE row into a typed :class:`EngineCrashError` instead
-        (inactive rows compute garbage by design and are ignored
-        host-side).
+        masking), in either arm: a corrupt KV slot or numerically
+        diverged model yields NaN logits, and serving a garbage argmax
+        over them would be a silent wrong answer — the engine turns a
+        non-finite ACTIVE row into a typed :class:`EngineCrashError`
+        instead (inactive rows compute garbage by design and are
+        ignored host-side).
         """
-        counts = ints[:, 0]
-        top_k = ints[:, 1]
-        bases = jax.lax.bitcast_convert_type(ints[:, 2:4], jnp.uint32)
-        f = jax.lax.bitcast_convert_type(ints[:, 4:8], jnp.float32)
-        temperature = f[:, 0]
-        keys = jax.vmap(jax.random.fold_in)(bases, counts)
-        # The sampler's parts each under a scope of its own (metadata
-        # only: README, "What a profile calls things"), so a trace says
-        # what every row pays for what few rows asked for.
-        with jax.named_scope("logit_pipeline"):
-            proc = apply_logit_pipeline(
-                logits, allowed, counts_v, f[:, 1], f[:, 2], f[:, 3]
-            )
-        V = logits.shape[-1]
-        kth = jnp.clip(top_k - 1, 0, V - 1)
+        asks = ints[:, 8]
+        B, V = logits.shape
 
-        def _kth_largest(_):
-            sorted_desc = -jnp.sort(-proc, axis=-1)
-            return sorted_desc, jnp.take_along_axis(
-                sorted_desc, kth[:, None], axis=-1)
+        def _finite():
+            with jax.named_scope("sampler_finite"):
+                return jnp.isfinite(logits).all(axis=-1)
 
-        with jax.named_scope("sampler_topk"):
-            if quality:
-                # the telemetry tail reads the sort's head, so the sort
-                # runs
-                sorted_desc, thresh = _kth_largest(None)
-            else:
-                # Only a row with top_k > 0 reads the threshold, and only
-                # a row with a temperature reads the draw: a batch without
-                # one (all greedy) skips the vocabulary's sort and the
-                # noise, the same tokens bit for bit. At 256 x 65,536
-                # logits the sort alone was 22 ms of a 42 ms iteration (my
-                # chip run, PR 28).
-                thresh = jax.lax.cond(
-                    jnp.any(top_k > 0), lambda _: _kth_largest(None)[1],
-                    lambda _: jnp.zeros((proc.shape[0], 1), proc.dtype),
-                    None)
-            masked = jnp.where(
-                (top_k > 0)[:, None] & (proc < thresh), -jnp.inf, proc
-            )
-        with jax.named_scope("sampler_draw"):
-            greedy = jnp.argmax(masked, axis=-1)
-            safe_t = jnp.where(temperature > 0, temperature, 1.0)[:, None]
-            scaled = masked / safe_t
-            drawn = jax.lax.cond(
-                jnp.any(temperature > 0),
-                lambda _: jax.vmap(
-                    lambda k, lg: jax.random.categorical(k, lg))(
-                        keys, scaled).astype(greedy.dtype),
-                lambda _: jnp.zeros(greedy.shape, greedy.dtype), None)
-            tokens = jnp.where(
-                temperature <= 0, greedy, drawn).astype(jnp.int32)
-        with jax.named_scope("sampler_logprobs"):
-            lp = jax.nn.log_softmax(scaled, axis=-1)
-            chosen = jnp.take_along_axis(lp, tokens[:, None], axis=-1)
-            top_lp, top_ids = jax.lax.top_k(lp, lp_k)
-        with jax.named_scope("sampler_finite"):
-            ok = jnp.isfinite(logits).all(axis=-1)
-        cols = [
-            tokens[:, None],
-            ok.astype(jnp.int32)[:, None],
-            jax.lax.bitcast_convert_type(chosen, jnp.int32),
-            top_ids.astype(jnp.int32),
-            jax.lax.bitcast_convert_type(top_lp, jnp.int32),
-        ]
-        if quality:
-            # the telemetry tail rides the SAME packed transfer: the
-            # sampled distribution's entropy, the processed-logit
-            # margin, and the repeat-of-previous flag per row. The
-            # margin reuses sorted_desc's head — the sort already paid
-            # for the top-k threshold — so the tail adds no second
-            # full-vocab top_k to the fused sampler
-            with jax.named_scope("sampler_quality"):
-                qv = quality_vector(
-                    lp, proc, tokens, ints[:, 8],
-                    top2=sorted_desc[:, :2] if V >= 2 else None,
+        def _packed(tokens, ok, chosen, top_ids, top_lp):
+            return [
+                tokens.astype(jnp.int32)[:, None],
+                ok.astype(jnp.int32)[:, None],
+                jax.lax.bitcast_convert_type(chosen, jnp.int32),
+                top_ids.astype(jnp.int32),
+                jax.lax.bitcast_convert_type(top_lp, jnp.int32),
+            ]
+
+        def _no_echo(_):
+            return (jnp.zeros((B, 1), jnp.float32),
+                    jnp.zeros((B, lp_k), jnp.int32),
+                    jnp.zeros((B, lp_k), jnp.float32))
+
+        def _plain(_):
+            with jax.named_scope("sampler_draw"):
+                tokens = jnp.argmax(logits, axis=-1)
+            return jnp.concatenate(
+                _packed(tokens, _finite(), *_no_echo(None)), axis=1)
+
+        def _full(_):
+            counts = ints[:, 0]
+            top_k = ints[:, 1]
+            bases = jax.lax.bitcast_convert_type(ints[:, 2:4], jnp.uint32)
+            f = jax.lax.bitcast_convert_type(ints[:, 4:8], jnp.float32)
+            temperature = f[:, 0]
+            keys = jax.vmap(jax.random.fold_in)(bases, counts)
+            # The sampler's parts each under a scope of its own (metadata
+            # only: README, "What a profile calls things"), so a trace
+            # says which parts a call ran and what each cost.
+            with jax.named_scope("logit_pipeline"):
+                proc = apply_logit_pipeline(
+                    logits, allowed, counts_v, f[:, 1], f[:, 2], f[:, 3]
                 )
-            cols.append(jax.lax.bitcast_convert_type(qv, jnp.int32))
-        return jnp.concatenate(cols, axis=1)
+            kth = jnp.clip(top_k - 1, 0, V - 1)
+
+            def _kth_largest(_):
+                sorted_desc = -jnp.sort(-proc, axis=-1)
+                return sorted_desc, jnp.take_along_axis(
+                    sorted_desc, kth[:, None], axis=-1)
+
+            with jax.named_scope("sampler_topk"):
+                if quality:
+                    # the telemetry tail reads the sort's head, so the
+                    # sort runs
+                    sorted_desc, thresh = _kth_largest(None)
+                else:
+                    # Only a row with top_k > 0 reads the threshold, and
+                    # only a row with a temperature reads the draw: a
+                    # batch without one skips the vocabulary's sort and
+                    # the noise, the same tokens bit for bit. At 256 x
+                    # 65,536 logits the sort alone was 22 ms of a 42 ms
+                    # iteration (my chip run, PR 28). An empty slot's
+                    # row has neither (``_sample_operands``).
+                    thresh = jax.lax.cond(
+                        jnp.any(top_k > 0), lambda _: _kth_largest(None)[1],
+                        lambda _: jnp.zeros((B, 1), proc.dtype), None)
+                masked = jnp.where(
+                    (top_k > 0)[:, None] & (proc < thresh), -jnp.inf, proc
+                )
+            with jax.named_scope("sampler_draw"):
+                greedy = jnp.argmax(masked, axis=-1)
+                safe_t = jnp.where(
+                    temperature > 0, temperature, 1.0)[:, None]
+                scaled = masked / safe_t
+                drawn = jax.lax.cond(
+                    jnp.any(temperature > 0),
+                    lambda _: jax.vmap(
+                        lambda k, lg: jax.random.categorical(k, lg))(
+                            keys, scaled).astype(greedy.dtype),
+                    lambda _: jnp.zeros(greedy.shape, greedy.dtype), None)
+                tokens = jnp.where(
+                    temperature <= 0, greedy, drawn).astype(jnp.int32)
+
+            def _echo(_):
+                lp = jax.nn.log_softmax(scaled, axis=-1)
+                top_lp, top_ids = jax.lax.top_k(lp, lp_k)
+                return lp, (
+                    jnp.take_along_axis(lp, tokens[:, None], axis=-1),
+                    top_ids.astype(jnp.int32), top_lp)
+
+            with jax.named_scope("sampler_logprobs"):
+                if quality:
+                    lp, echo = _echo(None)  # the tail reads ``lp``
+                else:
+                    echo = jax.lax.cond(
+                        jnp.any((asks & ASK_LOGPROBS) != 0),
+                        lambda _: _echo(None)[1], _no_echo, None)
+            cols = _packed(tokens, _finite(), *echo)
+            if quality:
+                # the telemetry tail rides the SAME packed transfer: the
+                # sampled distribution's entropy, the processed-logit
+                # margin, and the repeat-of-previous flag per row. The
+                # margin reuses sorted_desc's head — the sort already
+                # paid for the top-k threshold — so the tail adds no
+                # second full-vocab top_k to the fused sampler
+                with jax.named_scope("sampler_quality"):
+                    qv = quality_vector(
+                        lp, proc, tokens, ints[:, 9],
+                        top2=sorted_desc[:, :2] if V >= 2 else None,
+                    )
+                cols.append(jax.lax.bitcast_convert_type(qv, jnp.int32))
+            return jnp.concatenate(cols, axis=1)
+
+        if quality:
+            return _full(None)
+        return jax.lax.cond(jnp.any(asks != 0), _full, _plain, None)
 
     # Donate the cache pool: the engine always rebinds self.cache to the
     # result, so the old buffers are dead. Donation only ALLOWS an update
@@ -3239,44 +3302,53 @@ class ServingEngine:
                         bytes=sum(a.nbytes for a in operands))
         return args
 
+    def _row_asks(self, s: Slot) -> int:
+        """What the slot's request asks of the sampler beyond an argmax,
+        as ``ASK_*`` bits: an FSM mask, a penalty on, ``logprobs > 0``, a
+        temperature. The ONE rule: ``_sample_operands`` writes it into
+        the operand the program takes its arms by, ``_sampler_use``
+        counts it for a trace. (A top-k has no bit: the row that reads
+        it has a temperature or ``logprobs``, and a greedy row's argmax
+        is the same with it and without.)"""
+        p = s.request.params
+        return ((ASK_MASK if self._slot_fsm(s) is not None else 0)
+                | (ASK_PENALTY if _penalties_on(p) else 0)
+                | (ASK_LOGPROBS if p.logprobs > 0 else 0)
+                | (ASK_TEMPERATURE if p.temperature > 0 else 0))
+
     def _sampler_use(self, rows) -> dict:
         """What the (row index, slot) assignment asks of the sampler
         beyond an argmax, as counts of rows: with an FSM, with a penalty
         on, with ``logprobs > 0``, with a temperature, and with any of
-        the four. The ``sample_operands`` span's args, so a trace sets
-        what the sampler costs EVERY row beside the rows that wanted it;
-        a pass of its own over the rows, made only for a tracer that
-        records."""
-        masked = penalized = logprobs = tempered = asking = 0
-        for _, s in rows:
-            p = s.request.params
-            uses = (self._slot_fsm(s) is not None, _penalties_on(p),
-                    p.logprobs > 0, p.temperature > 0)
-            masked += uses[0]
-            penalized += uses[1]
-            logprobs += uses[2]
-            tempered += uses[3]
-            asking += any(uses)
-        return {"masked": masked, "penalized": penalized,
-                "logprobs": logprobs, "tempered": tempered,
-                "asking": asking}
+        the four; and ``plain``, 1 where no row asks anything, which is
+        the call that takes the program's plain arm (the telemetry build
+        has none). The ``sample_operands`` span's args, so a trace sets
+        what the sampler costs beside the rows that wanted it; a pass of
+        its own over the rows, made only for a tracer that records."""
+        asks = [self._row_asks(s) for _, s in rows]
+        use = {name: sum(1 for a in asks if a & bit) for name, bit in (
+            ("masked", ASK_MASK), ("penalized", ASK_PENALTY),
+            ("logprobs", ASK_LOGPROBS), ("tempered", ASK_TEMPERATURE))}
+        use["asking"] = sum(1 for a in asks if a)
+        use["plain"] = int(not use["asking"] and not self._quality)
+        return use
 
     def _sample_operands(self, rows, B):
-        """Packed (B, 8) int32 sampler operand plus the pipeline's
+        """Packed (B, 9) int32 sampler operand plus the pipeline's
         allowed/counts HOST arrays for a (row index, slot) assignment
         (see _build_step_fns._sample for the column layout; quality
-        telemetry widens it by one previous-token column). Rows not
-        named keep inert defaults (temp 1, penalties off, mask
-        all-ones, no previous token); a mask or histogram no row needs
-        is None, for which the caller passes ``_inert_ops``'s cached
-        device constant."""
-        ints = np.zeros((B, 9 if self._quality else 8), np.int32)
+        telemetry widens it by one previous-token column). A row not
+        named (an empty slot of the pool) asks the program for NOTHING:
+        no ``ASK_*`` bit, temperature and top_k 0 (a greedy row), so no
+        predicate of the program is true for its sake; beside that the
+        inert defaults (penalties off, mask all-ones, no previous
+        token). A mask or histogram no row needs is None, for which the
+        caller passes ``_inert_ops``'s cached device constant."""
+        ints = np.zeros((B, 10 if self._quality else 9), np.int32)
         f = ints[:, 4:8].view(np.float32)
-        f[:, 0] = 1.0  # temperature
         f[:, 1] = 1.0  # repetition penalty (1 = off)
         if self._quality:
-            ints[:, 8] = -1  # no previous token (repeat flag stays 0)
-        need_mask = need_counts = False
+            ints[:, 9] = -1  # no previous token (repeat flag stays 0)
         for i, s in rows:
             p = s.request.params
             # key-chain position: a replayed continuation (key_offset >
@@ -3292,26 +3364,24 @@ class ServingEngine:
             f[i, 1] = p.repetition_penalty
             f[i, 2] = p.presence_penalty
             f[i, 3] = p.frequency_penalty
+            ints[i, 8] = self._row_asks(s)
             if self._quality:
                 # the token the sampled one would repeat: the last
                 # emitted, or (first sample, at prefill completion)
                 # the last prompt token
                 if s.generated:
-                    ints[i, 8] = s.generated[-1]
+                    ints[i, 9] = s.generated[-1]
                 elif s.prompt_len:
-                    ints[i, 8] = int(s.prompt[s.prompt_len - 1])
-            if self._slot_fsm(s) is not None:
-                need_mask = True
-            if _penalties_on(p):
-                need_counts = True
+                    ints[i, 9] = int(s.prompt[s.prompt_len - 1])
+        asked = int(np.bitwise_or.reduce(ints[:, 8]))  # by any row
         am = cm = None
-        if need_mask:
+        if asked & ASK_MASK:
             am = np.ones((B, self.cfg.vocab_size), bool)
             for i, s in rows:
                 fsm = self._slot_fsm(s)
                 if fsm is not None:
                     am[i] = fsm.allowed_row(s.fsm_state)
-        if need_counts:
+        if asked & ASK_PENALTY:
             cm = np.zeros((B, self.cfg.vocab_size), np.int32)
             for i, s in rows:
                 if _penalties_on(s.request.params):

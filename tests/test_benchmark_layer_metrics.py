@@ -743,9 +743,11 @@ def test_sub_spans_lie_inside_their_parents_and_cover_them(served):
     assert _cover(by, "sample", SUB_SPANS) >= 0.9
     # the default request draws at temperature 1 and asks nothing else
     for c in by["sample_operands"]:
-        assert set(c[3]) == {"iteration", "path", "rows", "active", *USE}
+        assert set(c[3]) == {"iteration", "path", "rows", "active", *USE,
+                             "plain"}
         assert [c[3][k] for k in USE] == [
             0, 0, 0, c[3]["active"], c[3]["active"]]
+        assert c[3]["plain"] == 0  # every row draws: the full arm
         assert c[3]["rows"] == (1 if c[3]["path"] == "prefill" else 4)
 
 
@@ -781,11 +783,59 @@ def test_sample_operands_counts_what_the_rows_asked_for():
         assert (args["rows"], args["active"]) == (1, 1)
         assert {k for k in USE if args[k]} == set(asks) | (
             {"asking"} if asks else set())
+        # a call takes the plain arm where none of its rows asks
+        assert args["plain"] == (0 if asks else 1)
     steps = [c[3] for c in ops if c[3]["path"] == "decode"]
     assert len(steps) == NEW_TOKENS - 1
     for args in steps:  # all four rows in every decode step
         assert (args["rows"], args["active"]) == (4, 4)
         assert [args[k] for k in USE] == [0, 1, 1, 1, 3]
+        assert args["plain"] == 0
+
+
+def test_plain_calls_reader_by_hand_and_with_nothing_to_read(monkeypatch,
+                                                             tmp_path):
+    """ISSUE 39: ``sampler_plain_calls_pct`` is the share of the window's
+    ``sample_operands`` spans whose ``plain`` is 1; a program from before
+    the argument (the hand-built run as it stands: PR 38's), a run
+    without a record and a span after the window give it nothing."""
+    read = harness._reader_for("sampler_plain_calls_pct")
+    run = _host_share_cases.hand_built_run(monkeypatch, tmp_path)
+    assert read(run) is None
+    # the first decode step asks, the completed prompt and the second do not
+    calls = iter((0, 1, 1))
+    run.spans.spans = [
+        (n, a, b, dict(args, plain=next(calls)) if n == "sample_operands"
+         else args) for n, a, b, args in run.spans.spans]
+    run.spans.spans.append(("sample_operands", 1.5, 1.6, {"plain": 0}))
+    got = read(run)
+    assert got == pytest.approx(100.0 * 2 / 3) and isinstance(got, float)
+    run.spans = None
+    assert read(run) is None
+    entry = next(m for m in harness.load_benchmark()["per_layer"]
+                 if m["name"] == "sampler_plain_calls_pct")
+    assert entry == harness.load_benchmark()["per_layer"][-1]
+    assert entry["workloads"] == list(_host_share_cases.SERVE_CELLS)
+    decl = harness.load_json("layer_metrics", "sampler_plain_calls_pct.json")
+    assert (decl["unit"], decl["layer"], decl["moves"]) == (
+        entry["unit"], entry["layer"], entry["moves"]) == (
+            "%", "decode step and kernel", "itl_mean_ms")
+    assert entry["source"] == "program_counter"
+
+
+def test_the_toy_engines_plain_calls_are_what_the_reader_counts():
+    """The engine's own record through the reader: of MIXED's four first
+    tokens one is a plain call, and no decode step is (three rows ask)."""
+    rec = SpanRecorder()
+    _drive(rec)
+    run = harness.Run(harness.find_cell(harness.load_benchmark(),
+                                        _host_share_cases.SERVE_CELLS[0]),
+                      harness.Env([], None), spans=rec, planes=None)
+    run.values["measured_window"] = (0.0, float("inf"))
+    calls = len(_by_name(rec)["sample_operands"])
+    assert calls == len(MIXED) + NEW_TOKENS - 1
+    assert harness._reader_for("sampler_plain_calls_pct")(run) == (
+        pytest.approx(100.0 / calls))
 
 
 def test_served_tokens_and_stats_do_not_depend_on_the_tracer():
@@ -871,7 +921,7 @@ def test_sampler_programs_carry_the_scope_names(program, quality):
     B, V, S = engine._rows, cfg.vocab_size, jax.ShapeDtypeStruct
     if program == "sample":
         lowered = engine._sample_fn.lower(
-            S((B, 9 if quality else 8), jnp.int32), S((B, V), jnp.float32),
+            S((B, 10 if quality else 9), jnp.int32), S((B, V), jnp.float32),
             S((B, V), jnp.bool_), S((B, V), jnp.int32))
         want = SAMPLER_SCOPES
     else:

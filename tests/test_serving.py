@@ -9,6 +9,7 @@ composition), EOS retirement, scheduler budget/pool invariants, and
 jit-stability (no recompilation as requests come and go).
 """
 
+import contextlib
 import json
 import os
 import subprocess
@@ -99,18 +100,20 @@ def test_batched_greedy_bit_identical_to_generate_cached(kind):
     assert all(s.state == FREE for s in eng.scheduler.slots)
 
 
+@pytest.mark.parametrize("num_slots", [2, 5], ids=["full_pool", "empty_slots"])
 @pytest.mark.parametrize("mixed", [False, True])
-def test_a_greedy_batch_skips_the_sort_and_serves_the_same(mixed):
+def test_a_greedy_batch_skips_the_sort_and_serves_the_same(mixed, num_slots):
     """The sampler sorts the vocabulary only for a batch in which some row
     has a top-k, and draws only where some row has a temperature (a
     ``lax.cond`` each): a greedy request gets the same tokens and log
     probabilities alone (both skipped) and beside a sampling request (both
-    run), and the sampling request the same tokens alone and beside it."""
+    run), and the sampling request the same tokens alone and beside it;
+    in a pool whose other slots are empty as in one that is full."""
     cfg, params = _setup("control")
     greedy_p, drawn_p = _prompts([7, 5], cfg.vocab_size, seed=8)
 
     def run(*which):
-        eng = ServingEngine(params, cfg, ServingConfig(num_slots=2))
+        eng = ServingEngine(params, cfg, ServingConfig(num_slots=num_slots))
         ids = {}
         if "greedy" in which:
             ids[eng.submit(greedy_p, temperature=0.0, max_new_tokens=6,
@@ -128,6 +131,279 @@ def test_a_greedy_batch_skips_the_sort_and_serves_the_same(mixed):
         assert alone.tokens == _ref_greedy(params, cfg, greedy_p, 6)
         assert alone.token_logprobs == both[name].token_logprobs
         assert alone.top_logprobs == both[name].top_logprobs
+
+
+class _SpanArgs:
+    """The tracer's interface, keeping every span's name and arguments
+    (what a recording tracer is handed; nothing is timed)."""
+
+    def __init__(self):
+        self.spans = []
+
+    def span(self, name, **args):
+        self.spans.append((name, args))
+        return contextlib.nullcontext()
+
+    def instant(self, name, **args):
+        pass
+
+    def complete(self, name, t0, t1, **args):
+        pass
+
+    def flush(self):
+        pass
+
+    def close(self):
+        pass
+
+    def sampler_calls(self):
+        return [args for name, args in self.spans
+                if name == "sample_operands"]
+
+
+# the 61 tokens as one character each, so a regex over a-f is an FSM over
+# six of them
+_LETTERS = [chr(ord("a") + i) if i < 26 else "" for i in range(61)]
+
+# what a request may ask of the sampler beyond an argmax, one of each
+_ASKERS = {
+    "logprobs": dict(temperature=0.0, logprobs=2),
+    "drawn": dict(temperature=0.9, top_k=4, seed=5),
+    "fsm": dict(temperature=0.0, regex="[a-f]{6}"),
+    "penalty": dict(temperature=0.0, repetition_penalty=1.7),
+}
+
+
+def _echo(out):
+    return out.tokens, out.token_logprobs, out.top_logprobs
+
+
+def test_a_plain_batch_in_a_pool_with_empty_slots_takes_the_plain_arm():
+    """Two greedy requests that ask nothing else in a pool of six: every
+    sampler call, the pool-wide ones with their four empty rows too, is
+    reported ``plain``, and the tokens are ``generate_cached``'s."""
+    cfg, params = _setup("control")
+    prompts = _prompts([7, 5], cfg.vocab_size, seed=8)
+    rec = _SpanArgs()
+    eng = ServingEngine(params, cfg, ServingConfig(num_slots=6), tracer=rec)
+    outs = eng.generate(prompts, max_new_tokens=6, temperature=0.0)
+    for p, o in zip(prompts, outs):
+        assert o.tokens == _ref_greedy(params, cfg, p, 6)
+    calls = rec.sampler_calls()
+    assert {c["path"] for c in calls} == {"prefill", "decode"}
+    assert all(c["rows"] == 6 and c["active"] == 2
+               for c in calls if c["path"] == "decode")
+    assert all(c["plain"] == 1 and c["asking"] == 0 for c in calls)
+
+
+def test_an_empty_slot_asks_the_sampler_for_nothing():
+    """The packed operand of a pool-wide call: a row the call does not
+    name is false under every predicate the program reads (no ``ASK_*``
+    bit, no temperature, no top-k), whatever the named rows ask."""
+    from differential_transformer_replication_tpu.serving import engine as E
+
+    cfg, params = _setup("control")
+    eng = ServingEngine(params, cfg, ServingConfig(num_slots=4))
+    eng.submit(_prompts([5], cfg.vocab_size)[0], max_new_tokens=4,
+               temperature=0.7, top_k=3, logprobs=1, presence_penalty=0.5)
+    eng.step()  # the prompt is in: slot 0 is active, three are empty
+    (slot,) = eng.scheduler.active_slots()
+    ints, mask, hist = eng._sample_operands([(slot.index, slot)], 4)
+    assert ints.shape == (4, 9) and mask is None and hist is not None
+    named, rest = ints[slot.index], np.delete(ints, slot.index, axis=0)
+    assert named[8] == (E.ASK_PENALTY | E.ASK_LOGPROBS | E.ASK_TEMPERATURE)
+    assert named[1] == 3 and named[4:5].view(np.float32)[0] > 0
+    assert not rest[:, 8].any() and not rest[:, 1].any()
+    assert (rest[:, 4:5].view(np.float32) <= 0).all()
+
+
+@pytest.mark.parametrize("mixed", [False, True], ids=["plain", "asker"])
+@pytest.mark.parametrize("asker", list(_ASKERS))
+def test_a_plain_request_and_one_that_asks_serve_the_same_alone_and_together(
+        asker, mixed):
+    """A request that asks nothing serves the same tokens alone (every
+    call the plain arm) and beside one with log probabilities, with a
+    temperature and a top-k, with an FSM or with a penalty (the full
+    arm); and each of those the same tokens and the same echo alone and
+    beside it."""
+    cfg, params = _setup("control")
+    plain_p, asker_p = _prompts([7, 5], cfg.vocab_size, seed=8)
+
+    def run(*which):
+        rec = _SpanArgs()
+        eng = ServingEngine(params, cfg, ServingConfig(num_slots=4),
+                            vocab=_LETTERS, tracer=rec)
+        ids = {}
+        if "plain" in which:
+            ids[eng.submit(plain_p, temperature=0.0,
+                           max_new_tokens=6)] = "plain"
+        if "asker" in which:
+            ids[eng.submit(asker_p, max_new_tokens=6,
+                           **_ASKERS[asker])] = "asker"
+        outs = {ids[o.request_id]: o for o in eng.run()}
+        return outs, rec.sampler_calls(), eng._sample_fn._cache_size()
+
+    both, calls, programs = run("plain", "asker")
+    # the asker's rows make their calls the full arm; the plain request's
+    # first token is a call of its own
+    assert {c["plain"] for c in calls if c["path"] == "decode"} == {0}
+    assert sorted(c["plain"] for c in calls if c["path"] == "prefill") == [
+        0, 1]
+    name = "asker" if mixed else "plain"
+    alone, calls, programs_after = run(name)
+    assert {c["plain"] for c in calls} == {0 if mixed else 1}
+    # another mix of what is asked is another arm, not another program
+    assert programs_after == programs
+    assert _echo(alone[name]) == _echo(both[name])
+    if not mixed:
+        assert alone[name].tokens == _ref_greedy(params, cfg, plain_p, 6)
+        assert alone[name].token_logprobs is None
+    if asker == "logprobs":
+        assert len(both["asker"].token_logprobs) == 6
+        assert all(lp < 0 for lp in both["asker"].token_logprobs)
+    if asker == "fsm":
+        assert set(both["asker"].tokens) <= {
+            _LETTERS.index(c) for c in "abcdef"}
+
+
+def _ref_sampled(params, cfg, prompt, n, seed, temperature, top_k=None):
+    """The single-request contract a sampled chain is held to: token t
+    drawn by ``sample_token`` with the key ``fold_in(PRNGKey(seed), t)``
+    (models/generate.py)."""
+    from differential_transformer_replication_tpu.models.decode import (
+        forward_chunk,
+        init_cache,
+    )
+    from differential_transformer_replication_tpu.models.generate import (
+        sample_token,
+    )
+
+    base = jax.random.PRNGKey(seed)
+    cache = init_cache(cfg, 1)
+    logits, cache = forward_chunk(
+        params, jnp.asarray(prompt, jnp.int32)[None], 0, cache, cfg,
+        rope_len=cfg.block_size,
+    )
+    toks = []
+    for t in range(n):
+        key = jax.random.fold_in(base, t)
+        tok = int(sample_token(
+            key, logits[:, -1].astype(jnp.float32), temperature, top_k
+        )[0])
+        toks.append(tok)
+        if t < n - 1:
+            logits, cache = forward_chunk(
+                params, jnp.asarray([[tok]], jnp.int32), len(prompt) + t,
+                cache, cfg, rope_len=cfg.block_size,
+            )
+    return toks
+
+
+def test_a_temperature_without_logprobs_draws_the_same_and_asks_no_echo():
+    """What most deployments send: a temperature and nothing else. The
+    tokens are ``sample_token``'s chain (what the sampler served before
+    its arms), no call asks for the echo, and a request that does ask
+    for it draws the same tokens."""
+    cfg, params = _setup("control")
+    prompt = _prompts([5], cfg.vocab_size, seed=4)[0]
+
+    def run(**kw):
+        rec = _SpanArgs()
+        eng = ServingEngine(params, cfg, ServingConfig(num_slots=3),
+                            tracer=rec)
+        out = eng.generate([prompt], temperature=0.8, seed=11,
+                           max_new_tokens=6, **kw)[0]
+        return out, rec.sampler_calls()
+
+    out, calls = run()
+    assert out.tokens == _ref_sampled(params, cfg, prompt, 6, 11, 0.8)
+    assert out.token_logprobs is None
+    assert all(c["tempered"] == 1 and c["logprobs"] == 0 and c["plain"] == 0
+               for c in calls)
+    echoed, calls = run(logprobs=1)
+    assert echoed.tokens == out.tokens
+    assert all(c["logprobs"] == 1 for c in calls)
+    assert all(lp < 0 for lp in echoed.token_logprobs)
+
+
+@pytest.mark.parametrize("row", ["active", "empty"])
+def test_nan_logits_on_an_active_row_raise_through_the_plain_arm(row):
+    """The finiteness flag is part of the plain arm's result: NaN logits
+    on an active row of a batch that asks nothing still raise the typed
+    ``EngineCrashError``; on an empty slot's row they are nobody's and
+    the tokens are served."""
+    from differential_transformer_replication_tpu.serving import (
+        EngineCrashError,
+    )
+
+    cfg, params = _setup("control")
+    prompt = _prompts([6], cfg.vocab_size, seed=9)[0]
+    rec = _SpanArgs()
+    eng = ServingEngine(params, cfg, ServingConfig(num_slots=3), tracer=rec)
+    eng.submit(prompt, max_new_tokens=5, temperature=0.0)
+    eng.step()  # the prompt and its first token
+    (slot,) = eng.scheduler.active_slots()
+    poisoned = slot.index if row == "active" else (slot.index + 1) % 3
+    decode = eng._decode_fn
+
+    def corrupt(*args):
+        logits, *rest = decode(*args)
+        return (logits.at[poisoned].set(jnp.nan), *rest)
+
+    eng._decode_fn = corrupt
+    if row == "active":
+        with pytest.raises(EngineCrashError, match="non-finite"):
+            eng.step()
+    else:
+        (out,) = eng.run()
+        assert out.tokens == _ref_greedy(params, cfg, prompt, 5)
+    assert all(c["plain"] == 1 for c in rec.sampler_calls())
+
+
+@pytest.mark.parametrize("shape", [(6, 61), (16, 1000)])
+def test_the_plain_arm_is_the_full_arm_for_rows_that_ask_nothing(shape):
+    """The jitted sampler itself on random logits (a NaN, an infinity
+    and a tie among them): for operands that ask nothing the plain arm's
+    token and finite columns equal the full arm's, which the same rows
+    take when one more row of the call asks for its log probabilities;
+    the plain arm's echo columns are zeros, the asking row's are not."""
+    from differential_transformer_replication_tpu.serving import engine as E
+
+    B, V = shape
+    sample = E._build_step_fns(_cfg("control", V), 32)[2]
+    rng = np.random.default_rng(B)
+    logits = rng.normal(size=(B, V)).astype(np.float32)
+    logits[1, 3] = np.nan
+    logits[2, 5] = np.inf
+    logits[3, [7, 2]] = logits[3].max() + 1.0  # a tie: the lower index
+    ints = np.zeros((B, 9), np.int32)
+    ints[:, 4:8].view(np.float32)[:, 1] = 1.0  # repetition penalty off
+    ints[:-1, 1] = rng.integers(0, 4, size=B - 1)  # inert on a greedy row
+    ones, zeros = jnp.ones((B, V), bool), jnp.zeros((B, V), jnp.int32)
+    plain = np.asarray(sample(jnp.asarray(ints), jnp.asarray(logits),
+                              ones, zeros))
+    programs = sample._cache_size()
+    asking = ints.copy()
+    asking[-1, 8] = E.ASK_LOGPROBS
+    full = np.asarray(sample(jnp.asarray(asking), jnp.asarray(logits),
+                             ones, zeros))
+    assert (plain[:, :2] == full[:, :2]).all()
+    assert plain[3, 0] == 2 and plain[2, 0] == 5
+    assert plain[:, 1].tolist() == [1, 0, 0] + [1] * (B - 3)
+    assert not plain[:, 2:].any()
+    assert full[-1, 2:3].view(np.float32)[0] < 0
+    # a temperature alone: the full arm draws, and leaves the echo out
+    drawn = ints.copy()
+    drawn[:, 4:5].view(np.float32)[:] = 0.8
+    drawn[:, 8] = E.ASK_TEMPERATURE
+    out = np.asarray(sample(jnp.asarray(drawn), jnp.asarray(logits),
+                            ones, zeros))
+    assert not out[:, 2:].any()
+    drawn[0, 8] |= E.ASK_LOGPROBS
+    echoed = np.asarray(sample(jnp.asarray(drawn), jnp.asarray(logits),
+                               ones, zeros))
+    assert (echoed[:, 0] == out[:, 0]).all() and echoed[:, 2:].any()
+    assert sample._cache_size() == programs  # one, whatever was asked
 
 
 @pytest.mark.slow
@@ -184,40 +460,13 @@ def test_sampled_chain_matches_sample_token_reference():
     """The engine's batched sampler must be bit-identical, token for
     token, to the single-request sample_token contract with the same
     fold_in key chain (models/generate.py)."""
-    from differential_transformer_replication_tpu.models.decode import (
-        forward_chunk,
-        init_cache,
-    )
-    from differential_transformer_replication_tpu.models.generate import (
-        sample_token,
-    )
-
     cfg, params = _setup("control")
     prompt = _prompts([5], cfg.vocab_size, seed=4)[0]
     eng = ServingEngine(params, cfg, ServingConfig(num_slots=2))
     out = eng.generate(
         [prompt], temperature=1.0, top_k=5, seed=11, max_new_tokens=6
     )[0]
-
-    base = jax.random.PRNGKey(11)
-    cache = init_cache(cfg, 1)
-    logits, cache = forward_chunk(
-        params, jnp.asarray(prompt, jnp.int32)[None], 0, cache, cfg,
-        rope_len=cfg.block_size,
-    )
-    toks = []
-    for t in range(6):
-        key = jax.random.fold_in(base, t)
-        tok = int(sample_token(
-            key, logits[:, -1].astype(jnp.float32), 1.0, 5
-        )[0])
-        toks.append(tok)
-        if t < 5:
-            logits, cache = forward_chunk(
-                params, jnp.asarray([[tok]], jnp.int32), len(prompt) + t,
-                cache, cfg, rope_len=cfg.block_size,
-            )
-    assert out.tokens == toks
+    assert out.tokens == _ref_sampled(params, cfg, prompt, 6, 11, 1.0, 5)
 
 
 def test_eos_retires_slot_early_without_stalling_batch():

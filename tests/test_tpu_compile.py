@@ -774,9 +774,34 @@ def test_afmoe_programs_update_the_pool_of_two_ring_lengths_in_place(topo):
     assert programs["prefill"].memory_analysis().temp_size_in_bytes < 2.0e9
 
 
+def _computations(text: str) -> dict:
+    """``{name: body}`` of a compiled module's computations."""
+    parts = re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
+    return {re.match(r"(?:ENTRY )?(%[\w.\-]+)", p).group(1): p
+            for p in parts[1:]}
+
+
+def _runs(comps: dict, name: str) -> set:
+    """``name`` and every computation it calls as a fusion or applies as a
+    reduction, transitively: what runs when it runs, but for the branches
+    of a ``conditional`` in it."""
+    seen, todo = set(), [name]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += re.findall(r"(?:calls|to_apply)=(%[\w.\-]+)", comps[c])
+    return seen
+
+
+def _op_kinds(body: str) -> set:
+    return set(re.findall(r" = [^=]*?\b([a-z\-]+)\(", body))
+
+
 def test_sampler_is_scoped(topo):
     """The engine's jitted sampler (a narrow vocabulary: the sort over
-    12,000 takes the compiler 23 s and the scope does not depend on it)."""
+    12,000 takes the compiler 23 s and the scope does not depend on it),
+    and what of it a batch that asks nothing executes."""
     from differential_transformer_replication_tpu.serving.engine import (
         _build_step_fns,
     )
@@ -784,14 +809,35 @@ def test_sampler_is_scoped(topo):
     slots, V = 8, 512
     sample = _build_step_fns(ModelConfig(model="diff", vocab_size=V), 512)[2]
     text = compile_for(
-        topo, sample, sds((slots, 8), jnp.int32), sds((slots, V), jnp.float32),
+        topo, sample, sds((slots, 9), jnp.int32), sds((slots, V), jnp.float32),
         sds((slots, V), jnp.bool_), sds((slots, V), jnp.int32))
     assert text.startswith("HloModule jit__sample")
     assert "sampler" in scopes_in(text)
-    # the sort and the draw each sit in a branch a greedy batch skips
-    assert len(re.findall(r" conditional\(", text)) == 2
-    entry = text[text.index("ENTRY "):]
-    assert " sort(" not in entry and " sort(" in text
+    # the body sits in a branch a batch that asks nothing skips; inside it
+    # the sort, the draw and the echo each in a branch of its own
+    assert len(re.findall(r" conditional\(", text)) == 4
+    comps = _computations(text)
+    (entry,) = (n for n, body in comps.items() if body.startswith("ENTRY "))
+    (arms,) = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}",
+                         comps[entry])
+    plain, full = arms.split(", ")  # a cond's false branch comes first
+    assert " conditional(" in comps[full]
+    # what a plain batch executes, the ENTRY computation and the plain
+    # arm: no vocabulary-wide sort, exponential, logarithm or PRNG round,
+    # no pass of the pipeline; over the logits the two reductions alone
+    runs = _runs(comps, entry) | _runs(comps, plain)
+    kinds = set().union(*(_op_kinds(comps[c]) for c in runs))
+    assert not kinds & {"sort", "exponential", "log", "xor", "divide",
+                        "shift-right-logical", "rng-bit-generator",
+                        "gather", "custom-call"}
+    assert {"sort", "exponential", "log", "xor"} <= set().union(
+        *(_op_kinds(body) for body in comps.values()))
+    over_logits = [c for c in runs
+                   if re.search(rf"f32\[{slots},{V}\][^ ]* parameter\(",
+                                comps[c]) and c not in (entry, plain)]
+    assert sorted(k for c in over_logits for k in _op_kinds(comps[c])
+                  if k in ("reduce", "is-finite", "iota")) == [
+                      "iota", "is-finite", "reduce", "reduce"]
 
 
 @pytest.mark.slow
