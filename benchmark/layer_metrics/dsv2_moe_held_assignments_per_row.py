@@ -1,0 +1,14 @@
+"""Held (row, expert) assignments a live row and expert layer of the
+deepseek_v2 family, from the engine's ``decode`` spans
+(``lib/deepseek_v2_sizes.py:group_load``). None for a program whose spans
+carry no ``moe.rows_in_held_group``."""
+
+from lib import deepseek_v2_sizes
+
+
+def read(run):
+    load = deepseek_v2_sizes.group_load(run)
+    if load is None or not load["rows"]:
+        return None
+    layers = deepseek_v2_sizes.sizes(run.cell.config["model"])["moe"]
+    return load["held"] / (load["rows"] * layers)

@@ -18,7 +18,8 @@ import dataclasses
 from dataclasses import dataclass, field
 from typing import Any, Optional, Tuple
 
-MODEL_KINDS = ("control", "diff", "ndiff", "jamba", "kimi_linear", "afmoe")
+MODEL_KINDS = ("control", "diff", "ndiff", "jamba", "kimi_linear", "afmoe",
+               "deepseek_v2")
 
 # Fields only the ``jamba`` family reads. Another family given one of them
 # at a value other than its default is refused by name: a field that is
@@ -48,8 +49,22 @@ AFMOE_FIELDS = (
     "experts_per_token", "moe_hidden", "first_dense_layers",
     "routed_scaling", "held_experts",
 )
+# Fields only the ``deepseek_v2`` family reads (the MLA sizes and the
+# experts' fields it shares with ``kimi_linear``, ``rope_theta`` with
+# ``afmoe``), refused the same way.
+DEEPSEEK_V2_FIELDS = (
+    "ffn_hidden", "norm_eps", "q_lora_rank", "kv_lora_rank",
+    "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "rope_theta",
+    "rope_scaling", "num_experts", "experts_per_token", "moe_hidden",
+    "first_dense_layers", "routed_scaling", "held_experts", "n_group",
+    "topk_group", "n_shared_experts",
+)
 FAMILY_FIELDS = {"jamba": JAMBA_FIELDS, "kimi_linear": KIMI_LINEAR_FIELDS,
-                 "afmoe": AFMOE_FIELDS}
+                 "afmoe": AFMOE_FIELDS, "deepseek_v2": DEEPSEEK_V2_FIELDS}
+# the keys of a YaRN ``rope_scaling`` block, as the published config.json
+# spells them (``type`` beside them says "yarn")
+YARN_KEYS = ("factor", "beta_fast", "beta_slow", "mscale", "mscale_all_dim",
+             "original_max_position_embeddings")
 # ``layer_types`` as the published config.json spells them, and the mixer
 # kind ``ModelConfig.layer_kinds`` gives each
 AFMOE_LAYER_TYPES = {"sliding_attention": "window", "full_attention": "full"}
@@ -232,6 +247,29 @@ class ModelConfig:
     sliding_window: int = 0
     sliding_ring: int = 0
     rope_theta: float = 10000.0
+    # -- the ``deepseek_v2`` family's fields (DEEPSEEK_V2_FIELDS;
+    # models/deepseek_v2.py) ------------------------------------------------
+    # MLA in every layer, as ``kimi_linear``'s layer sizes it
+    # (kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim), with
+    # a LOW-RANK query (x W_qa, RMSNorm, W_qb; ``q_lora_rank`` wide) and a
+    # rotary part: the qk_rope_head_dim dimensions of every query head and
+    # of the shared key part turn at the token's position (``rope_theta``,
+    # dimension 2i with 2i + 1), the key part before it enters the latent
+    # ring. ``rope_scaling`` is the published YaRN block whole (a dict in
+    # a configuration file, kept as a sorted tuple of pairs: the config is
+    # a jit key), read through :meth:`yarn`; empty = plain frequencies.
+    q_lora_rank: int = 0
+    rope_scaling: Tuple[Tuple[str, Any], ...] = ()
+    # Experts: a softmax router over ``num_experts`` in ``n_group`` groups
+    # of equal size (a group is a device of the expert-parallel stage): a
+    # token keeps the ``topk_group`` groups whose best expert scores
+    # highest and the ``experts_per_token`` best experts inside them,
+    # weighted by ``routed_scaling`` times their probability (not
+    # renormalised); ``n_shared_experts`` shared experts are ONE MLP of
+    # n_shared_experts * moe_hidden, added unscaled.
+    n_group: int = 1
+    topk_group: int = 1
+    n_shared_experts: int = 1
 
     def __post_init__(self):
         if self.model not in MODEL_KINDS:
@@ -240,10 +278,13 @@ class ModelConfig:
                      "layer_types"):
             # a configuration file gives lists; the config is a jit key
             object.__setattr__(self, name, tuple(getattr(self, name)))
+        object.__setattr__(self, "rope_scaling", tuple(sorted(
+            dict(self.rope_scaling).items())))
         self._check_family_fields()
         self._check_jamba_fields()
         self._check_kimi_linear_fields()
         self._check_afmoe_fields()
+        self._check_deepseek_v2_fields()
         self._check_expert_fields()
         if self.attention_impl not in ("xla", "pallas"):
             raise ValueError(
@@ -325,8 +366,8 @@ class ModelConfig:
 
     def _check_expert_fields(self):
         """The fields of a family whose later layers hold routed experts
-        (kimi_linear, afmoe)."""
-        if self.model not in ("kimi_linear", "afmoe"):
+        (kimi_linear, afmoe, deepseek_v2)."""
+        if self.model not in ("kimi_linear", "afmoe", "deepseek_v2"):
             return
         for name in ("moe_hidden", "experts_per_token"):
             if getattr(self, name) < 1:
@@ -394,6 +435,54 @@ class ModelConfig:
                     "ends where the full layers' ring does"
                 )
 
+    def _check_deepseek_v2_fields(self):
+        if self.model != "deepseek_v2":
+            return
+        for name in ("attention_impl", "ffn_impl", "decode_attention_impl"):
+            if getattr(self, name) != "xla":
+                raise ValueError(
+                    f"the deepseek_v2 family takes {name}='xla' only, got "
+                    f"{getattr(self, name)!r}: the kernels that 'pallas' "
+                    "selects (ops/flash.py, ops/fused_ffn.py, "
+                    "ops/decode_attention.py) know neither a latent cache "
+                    "nor experts. The option chooses nothing for this "
+                    "family: its decode step reads the latent ring through "
+                    "its own kernel whatever it says (ops/mla.py, "
+                    "mla_latent_decode_fwd)"
+                )
+        if self.dropout:
+            raise ValueError("the deepseek_v2 family has no dropout")
+        for name in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                     "qk_rope_head_dim", "v_head_dim", "n_shared_experts"):
+            if getattr(self, name) < 1:
+                raise ValueError(f"{name} must be >= 1, got {getattr(self, name)}")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError(
+                f"qk_rope_head_dim ({self.qk_rope_head_dim}) must be even: "
+                "the rotation turns dimension 2i with 2i + 1")
+        if self.rope_scaling:
+            block = dict(self.rope_scaling)
+            kind = block.pop("type", "yarn")
+            if kind != "yarn" or sorted(block) != sorted(YARN_KEYS):
+                raise ValueError(
+                    "rope_scaling must be a YaRN block (type 'yarn' with "
+                    f"{', '.join(YARN_KEYS)}), got {dict(self.rope_scaling)}")
+        if self.first_dense_layers < self.n_layer:
+            size, (lo, hi) = self.expert_group_size, self.held_expert_range
+            if (self.n_group < 1 or self.num_experts % self.n_group
+                    or not 1 <= self.topk_group <= self.n_group
+                    or self.experts_per_token > self.topk_group * size):
+                raise ValueError(
+                    f"num_experts ({self.num_experts}) must divide into "
+                    f"n_group ({self.n_group}) groups, of which a token keeps "
+                    f"topk_group ({self.topk_group}) that hold its "
+                    f"experts_per_token ({self.experts_per_token})")
+            if lo % size or hi % size:
+                raise ValueError(
+                    f"held_experts {self.held_experts} must be whole routing "
+                    f"groups of {size} experts: a group is what one device "
+                    "of the expert-parallel stage holds")
+
     def _check_jamba_fields(self):
         if self.model != "jamba":
             return
@@ -443,7 +532,8 @@ class ModelConfig:
 
     @property
     def resolved_norm_eps(self) -> float:
-        """eps of the jamba, kimi_linear and afmoe families' RMSNorm."""
+        """eps of the jamba, kimi_linear, afmoe and deepseek_v2 families'
+        RMSNorm."""
         return self.norm_eps or 1e-6
 
     @property
@@ -463,8 +553,11 @@ class ModelConfig:
         carry no position at all, so a rolled ring would turn them into
         sliding-window layers without a word. afmoe's sliding layers roll
         inside that bound, in rings of their own length
-        (:meth:`ring_len`)."""
-        return self.model in ("diff", "jamba", "kimi_linear", "afmoe")
+        (:meth:`ring_len`). deepseek_v2's layers do carry positions, but
+        they see EVERY earlier one: a rolled ring of latents would make
+        them sliding-window layers, which the model is not."""
+        return self.model in ("diff", "jamba", "kimi_linear", "afmoe",
+                              "deepseek_v2")
 
     def ring_len(self, kind: str) -> int:
         """Positions the ring of a layer of mixer ``kind`` holds a slot
@@ -496,6 +589,23 @@ class ModelConfig:
         lo, hi = self.held_experts
         return (lo, hi) if hi else (0, self.num_experts)
 
+    @property
+    def expert_group_size(self) -> int:
+        """Experts a routing group (``num_experts / n_group``)."""
+        return self.num_experts // max(1, self.n_group)
+
+    @property
+    def mla_rotary(self) -> bool:
+        """Whether an MLA layer turns its queries' and its shared key
+        part's ``qk_rope_head_dim`` dimensions at the token's position
+        (deepseek_v2); kimi_linear's MLA carries no position."""
+        return self.model == "deepseek_v2"
+
+    @property
+    def yarn(self) -> Optional[dict]:
+        """The YaRN block of ``rope_scaling`` as a dict, or None."""
+        return dict(self.rope_scaling) or None
+
     def mlp_kinds(self) -> Tuple[str, ...]:
         """``"dense"`` or ``"moe"`` for every layer, 0-based: a model with
         experts holds them in the layers past the first
@@ -508,8 +618,12 @@ class ModelConfig:
     def layer_kinds(self) -> Tuple[str, ...]:
         """The mixer of every layer, 0-based: ``"attention"``, ``"mamba"``
         (jamba), ``"kda"`` or ``"mla"`` (kimi_linear), ``"window"`` or
-        ``"full"`` (afmoe). The reference families attend in every
-        layer."""
+        ``"full"`` (afmoe), ``"latent"`` (deepseek_v2: MLA with a rotary
+        key part, whose ring is read in blocks as far as it is live,
+        where ``"mla"`` reads it whole). The reference families attend in
+        every layer."""
+        if self.model == "deepseek_v2":
+            return ("latent",) * self.n_layer
         if self.model == "afmoe":
             return tuple(AFMOE_LAYER_TYPES[t] for t in self.layer_types)
         if self.model == "kimi_linear":
@@ -534,7 +648,8 @@ class ModelConfig:
         """
         if self.head_dim:
             return self.head_dim
-        if self.model in ("control", "jamba", "kimi_linear", "afmoe"):
+        if self.model in ("control", "jamba", "kimi_linear", "afmoe",
+                          "deepseek_v2"):
             return self.n_embd // self.n_head
         return self.n_embd // (self.n_head * 2)
 
@@ -542,7 +657,8 @@ class ModelConfig:
     def value_size(self) -> int:
         """Per-head value width: doubled for differential variants
         (diff_transformer.py:30, Ndiff_transformer.py:59)."""
-        if self.model in ("control", "jamba", "kimi_linear", "afmoe"):
+        if self.model in ("control", "jamba", "kimi_linear", "afmoe",
+                          "deepseek_v2"):
             return self.head_size
         return self.head_size * 2
 

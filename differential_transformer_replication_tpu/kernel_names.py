@@ -22,6 +22,8 @@ substrings, so the names keep to these rules:
 - the delta-rule kernel starts ``kda_`` (``pallas`` there, ``kda`` here);
 - the experts' grouped product starts ``moe_`` (``pallas`` there, ``moe``
   here);
+- the latent ring's decode read starts ``mla_`` (``pallas`` there, ``mla``
+  here);
 - no name holds a needle of another family.
 
 Standard library only, at the top of the package: ``ops/`` (which
@@ -57,6 +59,13 @@ DECODE_ATTENTION = "decode_dattn_fwd"
 # blocks of every K/V head a grid step, grouped query heads)
 RING_GQA_DECODE = "ring_gqa_decode_fwd"
 
+# ops/mla.py (the deepseek_v2 family's decode step: a row's live blocks of
+# the ring of latents, each read once for all heads, absorbed queries)
+MLA_LATENT_DECODE = "mla_latent_decode_fwd"
+# (a prefill chunk's: a head's queries of the whole chunk over the ring's
+# blocks, each widened to that head's keys and values on the chip)
+MLA_CHUNK_WIDENED = "mla_chunk_widened_fwd"
+
 # ops/kv_write.py (one call a cache leaf: K, V and the int8 scale planes)
 KV_ROW_WRITE = "kv_row_write"
 
@@ -74,6 +83,7 @@ KDA_STATE_UPDATE = "kda_state_update"
 MOE_GROUPED_MATMUL = "moe_grouped_matmul"
 
 RING = (RING_GQA_DECODE,)
+MLA = (MLA_LATENT_DECODE, MLA_CHUNK_WIDENED)
 FLASH = (
     FLASH_FWD, FLASH_FWD_TILED, FLASH_FWD_CHUNK, FLASH_FWD_TM,
     FLASH_FWD_TM_PACKED, FLASH_BWD_DQ, FLASH_BWD_DKV, FLASH_BWD_DQ_TILED,
@@ -99,6 +109,7 @@ FAMILIES = {
     "kda": KDA,
     "moe": MOE,
     "ring_attention": RING,
+    "mla": MLA,
 }
 ALL = (FLASH + FUSED_FFN + FUSED_NORM + DECODE + KV_WRITE + SSM + KDA + MOE
-       + RING)
+       + RING + MLA)

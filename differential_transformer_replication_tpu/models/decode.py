@@ -54,6 +54,7 @@ from differential_transformer_replication_tpu.config import ModelConfig
 from differential_transformer_replication_tpu.models.generate import sample_token
 from differential_transformer_replication_tpu.models import (
     afmoe,
+    deepseek_v2,
     common,
     jamba,
     kimi_linear,
@@ -109,7 +110,8 @@ KV_CACHE_BATCH_AXIS = {"k": 1, "v": 0, "k_scale": 1, "v_scale": 0,
 STATE_LEAVES = ("ssm", "conv", "kda")
 # the families whose layers are of several kinds (:func:`_hybrid_chunk`),
 # each with the module that holds its ``embed``
-HYBRID = {"jamba": jamba, "kimi_linear": kimi_linear, "afmoe": afmoe}
+HYBRID = {"jamba": jamba, "kimi_linear": kimi_linear, "afmoe": afmoe,
+          "deepseek_v2": deepseek_v2}
 # Ring positions that a prefill chunk's blocked attention
 # (:func:`_attend_ring_blocked`) reads at a time
 ATTEND_KEY_BLOCK = 1024
@@ -247,7 +249,8 @@ def init_cache(cfg: ModelConfig, batch_size: int) -> list:
     in ssm_state_dtype, conv (B, K-1, Di)}``, zeros being a sequence's
     start. The ``kimi_linear`` family's: a KDA layer ``{kda (B, H, d, d)
     float32, conv (B, K-1, 3 H d)}``, an MLA layer ``{latent (B, 1, M,
-    rank + rope)}``, the ring of what it caches a position. The ``afmoe``
+    rank + rope)}``, the ring of what it caches a position (the
+    ``deepseek_v2`` family's every layer likewise). The ``afmoe``
     family's rings are of two lengths in one slot (``cfg.ring_len``): a
     full layer's ``block_size`` long, a sliding layer's
     ``sliding_ring``."""
@@ -265,7 +268,7 @@ def init_cache(cfg: ModelConfig, batch_size: int) -> list:
             conv, state = kimi_linear.kda_zero_state(cfg, batch_size)
             cache.append({"kda": state, "conv": conv})
             continue
-        if kind == "mla":
+        if kind in ("mla", "latent"):
             cache.append({"latent": jnp.zeros(
                 (batch_size, 1, M, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
                 jnp.dtype(cfg.compute_dtype))})
@@ -544,8 +547,11 @@ def forward_chunk(
             raise ValueError(
                 f"chunk [{pos}, {pos + L}) exceeds block_size {M}: the "
                 f"{cfg.model} family's "
-                f"{'full ' if 'full' in cfg.layer_kinds() else ''}attention "
-                "layers carry no position, so a rolled ring would silently become "
+                + ("layers see every earlier position"
+                   if "latent" in cfg.layer_kinds() else
+                   f"{'full ' if 'full' in cfg.layer_kinds() else ''}"
+                   "attention layers carry no position")
+                + ", so a rolled ring would silently become "
                 "sliding-window attention"
             )
         if ("window" in cfg.layer_kinds() and L > cfg.ring_slack
@@ -631,8 +637,10 @@ def forward_chunk(
 
 #: The attention kinds that read a ring in blocks and mask each block from
 #: ``_Ring.window`` (:func:`_attend_ring_blocked`, ``ring_decode_attention``;
-#: ``models/afmoe.py``'s gated attention): their rings get no ``visible``.
-BLOCKED_KINDS = ("window", "full")
+#: ``models/afmoe.py``'s gated attention) or from the positions
+#: (``"latent"``, the deepseek_v2 family's MLA: ``ops/mla.py``'s blocked
+#: reads): their rings get no ``visible``.
+BLOCKED_KINDS = ("window", "full", "latent")
 
 
 class _Ring(NamedTuple):
@@ -752,14 +760,20 @@ def _mixer_chunk(x, blk: dict, layer_cache: dict, cfg: ModelConfig, pos,
         with jax.named_scope("mla"):
             h = jamba.norm(x, blk["ln1"], cfg)
             with jax.named_scope("mla_latent_write"):
-                rows = kimi_linear.mla_latent(h, blk["mla"], cfg)
+                rows = kimi_linear.mla_latent(
+                    h, blk["mla"], cfg,
+                    pos + jnp.arange(x.shape[1]) if cfg.mla_rotary else None)
                 latent = jax.lax.dynamic_update_slice(
                     layer_cache["latent"],
                     rows[:, None].astype(layer_cache["latent"].dtype),
                     (0, 0, ring.at, 0))
-            with jax.named_scope("mla_attend"):
-                a = kimi_linear.mla_attend(h, blk["mla"], latent[:, 0],
-                                           ring.visible)
+            if kind in BLOCKED_KINDS:  # the widened form, in blocks
+                a = kimi_linear.mla_chunk_attend(h, blk["mla"], cfg,
+                                                 latent[:, 0], pos)
+            else:
+                with jax.named_scope("mla_attend"):
+                    a = kimi_linear.mla_attend(h, blk["mla"], cfg,
+                                               latent[:, 0], ring.visible)
         return a, {"latent": latent}
     with jax.named_scope("attn_norm"):
         h = jamba.norm(x, blk["ln1"], cfg)
@@ -860,15 +874,20 @@ def _mixer_step(x, blk: dict, layer_cache: dict, cfg: ModelConfig, live,
         with jax.named_scope("mla"):
             h = jamba.norm(x, blk["ln1"], cfg)
             with jax.named_scope("mla_latent_write"):
-                rows = kimi_linear.mla_latent(h, blk["mla"], cfg)
+                rows = kimi_linear.mla_latent(
+                    h, blk["mla"], cfg, pos if cfg.mla_rotary else None)
                 layer_cache = _write_ring(
                     layer_cache,
                     {"latent": rows[:, None].astype(
                         layer_cache["latent"].dtype)}, ring.at)
-            with jax.named_scope("mla_attend"):
-                a = kimi_linear.mla_attend(
-                    h[:, None], blk["mla"], layer_cache["latent"][:, 0],
-                    ring.visible)[:, 0]
+            if kind in BLOCKED_KINDS:  # the absorbed form, the live blocks
+                a = kimi_linear.mla_step_attend(
+                    h, blk["mla"], cfg, layer_cache["latent"], pos, live)
+            else:
+                with jax.named_scope("mla_attend"):
+                    a = kimi_linear.mla_attend(
+                        h[:, None], blk["mla"], cfg,
+                        layer_cache["latent"][:, 0], ring.visible)[:, 0]
         return a, layer_cache
     gated = kind in BLOCKED_KINDS
     with jax.named_scope("attn_norm"):
@@ -906,7 +925,9 @@ def _hybrid_decode(params: dict, tokens: jnp.ndarray, pos, cache: list,
     (3,) int32, summed over the expert layers: the (row, expert)
     assignments that fell on held experts, the largest count on one
     expert, and the held experts that got a row at all (whose weights the
-    step had to read); None for a family without experts."""
+    step had to read); a router limited to groups (deepseek_v2) adds a
+    fourth, the live rows that kept a group this share holds, which alone
+    can meet a held expert. None for a family without experts."""
     B = tokens.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     live = jnp.ones((B,), bool) if active is None else active
@@ -930,8 +951,10 @@ def _hybrid_decode(params: dict, tokens: jnp.ndarray, pos, cache: list,
         new_cache.append(layer_cache)
         x, load = _mlp(x + a, blk, cfg, live)
         if load is not None:
+            # a router limited to groups adds the rows that kept a held one
+            load, *reached = load if isinstance(load, tuple) else (load,)
             loads.append(jnp.stack([jnp.sum(load), jnp.max(load),
-                                    jnp.sum(load > 0)]))
+                                    jnp.sum(load > 0), *reached]))
     with jax.named_scope("lm_head"):
         return (jamba.lm_head(params, x, cfg), new_cache,
                 sum(loads) if loads else None)
@@ -1545,7 +1568,8 @@ def generate_cached(
         raise ValueError(
             f"prompt ({T0}) + max_new_tokens ({max_new_tokens}) exceeds "
             f"block_size ({M}): the {cfg.model} family's cache cannot roll "
-            "(its attention layers, afmoe's full ones, carry no position)"
+            "(its attention layers, afmoe's full ones, carry no position; "
+            "deepseek_v2's see every earlier one)"
         )
     if cfg.model == "diff" and T0 + max_new_tokens > M:
         raise ValueError(

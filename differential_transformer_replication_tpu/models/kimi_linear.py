@@ -66,8 +66,18 @@ from differential_transformer_replication_tpu.models.jamba import (
 )
 from differential_transformer_replication_tpu.ops import kda as kda_ops
 from differential_transformer_replication_tpu.ops import moe as moe_ops
-from differential_transformer_replication_tpu.ops.mla import attend_latent
+from differential_transformer_replication_tpu.ops.mla import (
+    absorb_queries,
+    attend_latent,
+    chunk_attention,
+    latent_decode_attention,
+)
 from differential_transformer_replication_tpu.ops.norms import rms_norm
+from differential_transformer_replication_tpu.ops.rope import (
+    apply_rope_pairs_at,
+    yarn_frequencies,
+    yarn_mscale,
+)
 from differential_transformer_replication_tpu.ops.ssm import causal_conv
 
 USES_ROPE = False  # embed, ffn, lm_head and norm are jamba's: the same blocks
@@ -205,25 +215,119 @@ def kda_zero_state(cfg: ModelConfig, batch: int, compute_dtype=None):
 
 
 # -- the MLA mixer -------------------------------------------------------------
+# ONE mixer for this family and ``deepseek_v2`` (models/deepseek_v2.py).
+# What differs follows from the layer's leaves and the configuration: a
+# low-rank query where the leaves hold ``wq_a`` (else the full-rank
+# ``wq``); the rotary part turned where the caller gives positions
+# (``cfg.mla_rotary``: this family's MLA carries none); the softmax scale (:func:`mla_scale`). The ring is read one of three ways
+# (ops/mla.py): whole under a mask (:func:`mla_attend`), a chunk's blocks
+# in the widened form (:func:`mla_chunk_attend`) or a step's live blocks in
+# the absorbed form (:func:`mla_step_attend`); models/decode.py picks by
+# the layer's kind.
 
 
-def mla_latent(h: jnp.ndarray, p: dict, cfg: ModelConfig) -> jnp.ndarray:
+def _rotate(x: jnp.ndarray, pos, cfg: ModelConfig) -> jnp.ndarray:
+    """The rotary part ``x`` (.., rope) at ``pos`` (broadcasting against
+    ``x``'s leading axes), under the configuration's YaRN block."""
+    freqs, mult = yarn_frequencies(cfg.qk_rope_head_dim, cfg.rope_theta,
+                                   cfg.yarn)
+    return apply_rope_pairs_at(x, pos, freqs, mult)
+
+
+def mla_scale(cfg: ModelConfig) -> Optional[float]:
+    """What the scores are multiplied by: ``(nope + rope) ** -0.5 m^2``
+    with YaRN's ``m = 0.1 mscale_all_dim ln(factor) + 1``; None without
+    such a block (the reads then divide by ``sqrt(nope + rope)``)."""
+    block = cfg.yarn
+    if not block or not block["mscale_all_dim"]:
+        return None
+    m = yarn_mscale(block["factor"], block["mscale_all_dim"])
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def mla_queries(h: jnp.ndarray, p: dict, cfg: ModelConfig,
+                pos=None) -> jnp.ndarray:
+    """``h`` (.., E) -> the heads' queries (.., H, nope + rope): ``x W_q``,
+    or through the low-rank ``RMSNorm(x W_qa) W_qb``; with ``pos``
+    (broadcasting against ``h``'s leading axes) the last ``rope``
+    dimensions of every head are turned there."""
+    if "wq_a" in p:  # graftlint: disable=GL104 (a dict's keys are static)
+        c = rms_norm(h @ p["wq_a"].astype(h.dtype),
+                     p["q_norm"].astype(jnp.float32), cfg.resolved_norm_eps)
+        q = jnp.einsum("...r,rhd->...hd", c, p["wq_b"].astype(h.dtype))
+    else:
+        q = jnp.einsum("...e,ehd->...hd", h, p["wq"].astype(h.dtype))
+    if pos is None:
+        return q
+    nope = cfg.qk_nope_head_dim
+    turned = _rotate(q[..., nope:], jnp.asarray(pos)[..., None], cfg)
+    return jnp.concatenate([q[..., :nope], turned], axis=-1)
+
+
+def mla_latent(h: jnp.ndarray, p: dict, cfg: ModelConfig,
+               pos=None) -> jnp.ndarray:
     """``h`` (.., E) -> what the cache holds a position, (.., rank +
-    rope): the normed latent ``c`` beside the shared key part ``k_r``."""
+    rope): the normed latent ``c`` beside the shared key part ``k_r``,
+    turned at ``pos`` where positions are given."""
     rank = cfg.kv_lora_rank
     kv = h @ p["wkv_a"].astype(h.dtype)
     c = rms_norm(kv[..., :rank], p["kv_norm"].astype(jnp.float32),
                  cfg.resolved_norm_eps)
-    return jnp.concatenate([c.astype(h.dtype), kv[..., rank:]], axis=-1)
+    k_r = kv[..., rank:] if pos is None else _rotate(kv[..., rank:], pos, cfg)
+    return jnp.concatenate([c.astype(h.dtype), k_r], axis=-1)
 
 
-def mla_attend(h: jnp.ndarray, p: dict, latent: jnp.ndarray,
-               visible: jnp.ndarray) -> jnp.ndarray:
+def mla_attend(h: jnp.ndarray, p: dict, cfg: ModelConfig,
+               latent: jnp.ndarray, visible: jnp.ndarray) -> jnp.ndarray:
     """The queries of ``h`` (B, L, E) over ``latent`` (B, M, rank + rope)
-    where ``visible`` says so, through the output projection."""
-    q = jnp.einsum("...e,ehd->...hd", h, p["wq"].astype(h.dtype))
-    heads = attend_latent(q, latent.astype(h.dtype), p["wkv_b"], visible)
+    where ``visible`` says so, the ring whole in the absorbed form,
+    through the output projection (a layer of kind ``"mla"``: no
+    position)."""
+    q = mla_queries(h, p, cfg)
+    heads = attend_latent(q, latent.astype(h.dtype), p["wkv_b"], visible,
+                          mla_scale(cfg))
     return heads @ p["out"]["w"].astype(h.dtype)
+
+
+def _scale(cfg: ModelConfig) -> float:
+    return mla_scale(cfg) or (
+        cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+
+
+def mla_chunk_attend(h: jnp.ndarray, p: dict, cfg: ModelConfig,
+                     latent: jnp.ndarray, pos) -> jnp.ndarray:
+    """A chunk ``h`` (B, L, E) whose first token stands at ``pos``, over
+    the ring ``latent`` (B, M, rank + rope) that holds the chunk's own
+    latents: the WIDENED form, the ring in blocks as far as it is written
+    (``ops/mla.py:chunk_attention``)."""
+    at = pos + jnp.arange(h.shape[1]) if cfg.mla_rotary else None
+    with jax.named_scope("mla_q"):
+        q = mla_queries(h, p, cfg, at)
+    with jax.named_scope("mla_attend"):
+        heads = chunk_attention(q, latent, p["wkv_b"], pos, _scale(cfg))
+    with jax.named_scope("mla_out"):
+        return heads @ p["out"]["w"].astype(h.dtype)
+
+
+def mla_step_attend(h: jnp.ndarray, p: dict, cfg: ModelConfig,
+                    latent: jnp.ndarray, pos: jnp.ndarray,
+                    live: jnp.ndarray) -> jnp.ndarray:
+    """One token a slot, ``h`` (B, E) at positions ``pos`` (B,), over the
+    pool's rings ``latent`` (B, 1, M, rank + rope) that hold the tokens'
+    own latents: the ABSORBED form over each row's live blocks
+    (``ops/mla.py:latent_decode_attention``); ``W_kvb`` is multiplied into
+    the queries before the read and into the mixed latents after it."""
+    rank, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    w = p["wkv_b"].astype(h.dtype)
+    with jax.named_scope("mla_q"):
+        q = mla_queries(h, p, cfg, pos if cfg.mla_rotary else None)
+        qq = absorb_queries(q, w, cfg.qk_rope_head_dim)
+    with jax.named_scope("mla_attend"):
+        mixed = latent_decode_attention(qq, latent, pos, live, rank,
+                                        _scale(cfg))
+    with jax.named_scope("mla_out"):
+        heads = jnp.einsum("bhr,rhv->bhv", mixed, w[..., nope:])
+        return heads.reshape(h.shape[0], -1) @ p["out"]["w"].astype(h.dtype)
 
 
 # -- the experts ---------------------------------------------------------------
@@ -236,28 +340,42 @@ def moe_mlp(h: jnp.ndarray, p: dict, cfg: ModelConfig,
     experts' weighted sum beside the shared expert's output, and the
     assignments that fell on each held expert from the rows that are
     ``live`` (.., bool; None = all). The ``afmoe`` family's layer is this
-    one too (models/afmoe.py)."""
+    one too (models/afmoe.py), and ``deepseek_v2``'s: a router without a
+    correction bias among its leaves is the softmax router limited to
+    groups (``ops/moe.py:route_grouped``), and the load then comes as
+    ``(load, reached)``, ``reached`` () int32 the live rows that kept a
+    group this share holds."""
     with jax.named_scope("moe"):
         rows = h.reshape(-1, h.shape[-1])
+        flat = None if live is None else live.reshape(-1)
+        lo, hi = cfg.held_expert_range
         with jax.named_scope("moe_router"):
-            chosen, weights = moe_ops.route(
-                rows, p["router"]["w"], p["router"]["b"],
-                cfg.experts_per_token, cfg.routed_scaling)
+            if "b" in p["router"]:  # graftlint: disable=GL104 (static keys)
+                kept = None
+                chosen, weights = moe_ops.route(
+                    rows, p["router"]["w"], p["router"]["b"],
+                    cfg.experts_per_token, cfg.routed_scaling)
+            else:
+                chosen, weights, kept = moe_ops.route_grouped(
+                    rows, p["router"]["w"], cfg.experts_per_token,
+                    cfg.routed_scaling, cfg.n_group, cfg.topk_group)
         with jax.named_scope("moe_experts"):
-            y, load = moe_ops.experts(
-                rows, chosen, weights, p["experts"],
-                cfg.held_expert_range[0],
-                None if live is None else live.reshape(-1))
+            y, load = moe_ops.experts(rows, chosen, weights, p["experts"],
+                                      lo, flat)
         with jax.named_scope("moe_shared"):
             y = y + gated_mlp(rows, p["shared"])
+        if kept is not None:
+            size = cfg.expert_group_size
+            mine = jnp.any(kept[:, lo // size:hi // size], axis=-1)
+            load = (load, jnp.sum(mine if flat is None else mine & flat,
+                                  dtype=jnp.int32))
         return y.reshape(h.shape), load
 
 
 def moe(x: jnp.ndarray, blk: dict, cfg: ModelConfig,
         live: Optional[jnp.ndarray] = None):
     """The block's second half on the residual ``x`` (.., E) with a
-    layer of experts: returns ``(x + y, load (G,) int32)``
-    (:func:`moe_mlp`)."""
+    layer of experts: returns ``(x + y, load)`` (:func:`moe_mlp`)."""
     with jax.named_scope("ffn_norm"):
         h = norm(x, blk["ln2"], cfg)
     y, load = moe_mlp(h, blk["moe"], cfg, live)
@@ -293,7 +411,8 @@ def block_forward(
             h = norm(x, blk["ln1"], cfg)
             T = x.shape[1]
             with jax.named_scope("mla_attend"):
-                a = mla_attend(h, blk["mla"], mla_latent(h, blk["mla"], cfg),
+                a = mla_attend(h, blk["mla"], cfg,
+                               mla_latent(h, blk["mla"], cfg),
                                jnp.tril(jnp.ones((T, T), bool)))
     if "moe" in blk:  # graftlint: disable=GL104 (a dict's keys are static)
         return moe(x + a, blk, cfg)[0]

@@ -3,8 +3,9 @@
 The reference selects models by commenting code blocks in and out
 (train.py:205-230); here it is a first-class dispatch on
 ``ModelConfig.model`` covering the same three families, and ``jamba``
-(models/jamba.py), ``kimi_linear`` (models/kimi_linear.py) and ``afmoe``
-(models/afmoe.py), whose layers are of several kinds.
+(models/jamba.py), ``kimi_linear`` (models/kimi_linear.py), ``afmoe``
+(models/afmoe.py) and ``deepseek_v2`` (models/deepseek_v2.py), whose
+layers are of several kinds.
 """
 
 from __future__ import annotations
@@ -18,6 +19,7 @@ from differential_transformer_replication_tpu.config import ModelConfig
 from differential_transformer_replication_tpu.models import (
     afmoe,
     control,
+    deepseek_v2,
     diff,
     jamba,
     kimi_linear,
@@ -25,7 +27,8 @@ from differential_transformer_replication_tpu.models import (
 )
 
 _MODULES = {"control": control, "diff": diff, "ndiff": ndiff,
-            "jamba": jamba, "kimi_linear": kimi_linear, "afmoe": afmoe}
+            "jamba": jamba, "kimi_linear": kimi_linear, "afmoe": afmoe,
+            "deepseek_v2": deepseek_v2}
 
 
 def init_model(key: jax.Array, cfg: ModelConfig) -> dict:

@@ -63,6 +63,7 @@ KERNEL_BUCKETS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("kda", _NAMES.KDA),
     ("moe", _NAMES.MOE),
     ("ring_attention", _NAMES.RING),
+    ("mla", _NAMES.MLA),
     ("fused_ffn", _NAMES.FUSED_FFN + _NAMES.FUSED_NORM),
     ("flash_attention", _NAMES.FLASH),
     ("collectives", ("all-reduce", "all-gather", "reduce-scatter",

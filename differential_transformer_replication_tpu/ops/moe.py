@@ -2,7 +2,8 @@
 
 :func:`route` ranks ALL ``num_experts`` experts a token (a sigmoid router
 with a correction bias that only moves the ranking, weights renormalised
-over the chosen and scaled). :func:`experts` adds the terms of the HELD
+over the chosen and scaled); :func:`route_grouped` is the softmax router
+that keeps a token inside a few groups of experts. :func:`experts` adds the terms of the HELD
 experts ``[lo, hi)`` only: the (token, expert) assignments are sorted by
 expert and the held ones multiplied in groups, a group a held expert; no
 token is dropped and there is no capacity. An assignment that fell on an
@@ -53,6 +54,33 @@ def route(h: jnp.ndarray, w: jnp.ndarray, bias: jnp.ndarray, k: int,
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scaling
     return chosen.astype(jnp.int32), weights
+
+
+def route_grouped(h: jnp.ndarray, w: jnp.ndarray, k: int, scaling: float,
+                  n_group: int, topk_group: int):
+    """:func:`route`'s sibling for a router limited to groups (the
+    deepseek_v2 family: ``topk_method: group_limited_greedy``, a group the
+    experts of one device). ``h`` (T, E) -> ``(experts (T, k) int32,
+    weights (T, k) float32, kept (T, n_group) bool)``: the scores are a
+    SOFTMAX over all experts; expert ``e`` lies in group ``e // (N /
+    n_group)``; a group scores as its best expert, a token keeps the
+    ``topk_group`` best groups and the ``k`` best experts inside them
+    (ties go to the lower index, as ``top_k`` breaks them), each weighted
+    ``scaling`` times its probability: nothing is renormalised. Float32
+    under the highest precision, for :func:`route`'s reason."""
+    f32 = jnp.float32
+    scores = jax.nn.softmax(jnp.dot(h.astype(f32), w.astype(f32),
+                                    precision=_HIGHEST), axis=-1)
+    T, N = scores.shape
+    best = jnp.max(scores.reshape(T, n_group, N // n_group), axis=-1)
+    _, groups = jax.lax.top_k(best, topk_group)
+    kept = jnp.zeros((T, n_group), bool).at[
+        jnp.arange(T)[:, None], groups].set(True)
+    # a probability is positive: an expert outside the kept groups, at -1,
+    # ranks behind every expert inside them
+    inside = jnp.repeat(kept, N // n_group, axis=-1)
+    picked, chosen = jax.lax.top_k(jnp.where(inside, scores, -1.0), k)
+    return chosen.astype(jnp.int32), picked * scaling, kept
 
 
 def _row_tile(assignments: int, groups: int) -> int:

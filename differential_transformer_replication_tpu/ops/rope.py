@@ -32,7 +32,11 @@ the same cast back, and ``q . k`` is the same sum in another order.
 
 from __future__ import annotations
 
+import math
+from typing import Optional
+
 import jax.numpy as jnp
+import numpy as np
 
 
 def rope_cos_sin(head_dim: int, max_seq_len: int, theta: float = 10000.0):
@@ -137,3 +141,70 @@ def apply_rope_half(x: jnp.ndarray, pos: jnp.ndarray,
     freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = jnp.asarray(pos, jnp.float32)[..., None] * freqs
     return _turn_halves(x, jnp.cos(angles), jnp.sin(angles))
+
+
+# -- YaRN (the deepseek_v2 family's rotary key part) ---------------------------
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature term ``0.1 mscale ln(factor) + 1`` (1
+    for a factor of at most 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(d: int, theta: float, beta_fast: float,
+                          beta_slow: float, original_max: int):
+    """``(low, high)``: the frequency indices between which YaRN blends
+    the scaled and the plain frequency. ``d(r) = d ln(original_max / (2 pi
+    r)) / (2 ln theta)`` is the index whose wave turns ``r`` times over
+    the trained length; ``low = floor(d(beta_fast))``, ``high =
+    ceil(d(beta_slow))``, both held to ``[0, d - 1]``."""
+    at = lambda r: d * math.log(original_max / (2 * math.pi * r)) / (  # noqa: E731
+        2 * math.log(theta))
+    return (max(math.floor(at(beta_fast)), 0),
+            min(math.ceil(at(beta_slow)), d - 1))
+
+
+def yarn_frequencies(d: int, theta: float, scaling: Optional[dict]):
+    """``(f (d/2,) float32 NumPy, cos/sin multiplier)``: the angle of pair
+    ``i`` at position ``t`` is ``t f_i``. Without ``scaling`` the plain
+    ``theta ** (-2i/d)`` and 1. With a YaRN block (``factor``,
+    ``beta_fast``, ``beta_slow``, ``mscale``, ``mscale_all_dim``,
+    ``original_max_position_embeddings``) a pair below ``low`` keeps its
+    plain frequency (its wave turns often inside the trained length), one
+    above ``high`` is slowed by ``factor``, and those between blend
+    linearly: ``f_i = (1 - g_i) plain_i / factor + g_i plain_i``, ``g_i =
+    1 - clip((i - low) / (high - low), 0, 1)``. The multiplier is
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``.
+    Static arithmetic on the sizes: constants of the program."""
+    plain = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+    if not scaling:
+        return plain.astype(np.float32), 1.0
+    low, high = yarn_correction_range(
+        d, theta, scaling["beta_fast"], scaling["beta_slow"],
+        scaling["original_max_position_embeddings"])
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    f = ramp * plain / scaling["factor"] + (1.0 - ramp) * plain
+    mult = (yarn_mscale(scaling["factor"], scaling["mscale"])
+            / yarn_mscale(scaling["factor"], scaling["mscale_all_dim"]))
+    return f.astype(np.float32), mult
+
+
+def apply_rope_pairs_at(x: jnp.ndarray, pos: jnp.ndarray, freqs,
+                        mult: float = 1.0) -> jnp.ndarray:
+    """Rotate ``x`` (..., d), whose features stand in the PUBLISHED order
+    (dimension 2i turns with 2i + 1), at the absolute positions ``pos``,
+    which broadcast against ``x``'s leading axes, by the angles ``pos *
+    freqs`` (:func:`yarn_frequencies`); cos and sin times ``mult``. The
+    result stands in :func:`half_split`'s order (pair i at ``(i, i +
+    d/2)``): the pair is moved once, by a transpose, and the rotation
+    turns contiguous halves (the module docstring says what a stride of
+    two along the lanes costs the chip). A query and a key that both went
+    through here meet dimension for dimension, so ``q . k`` is the
+    published sum in another order; nothing else may read the result
+    feature by feature. The angles come from the positions themselves, in
+    float32, so no table bounds a sequence."""
+    angles = jnp.asarray(pos, jnp.float32)[..., None] * jnp.asarray(freqs)
+    return _turn_halves(half_split(x), jnp.cos(angles) * mult,
+                        jnp.sin(angles) * mult)

@@ -186,6 +186,14 @@ _STAT_SPEC = {
         "layer, pos + 1 a full one; models/decode.py live_kv). 0 for "
         "the other families.",
     ),
+    "decode_live_latent": (
+        "serving_decode_live_latent_positions_total",
+        "Ring positions that hold a live latent for the rows of decode "
+        "steps, pos + 1 a row, summed over the layers (a family whose "
+        "every layer is MLA over a ring of latents that the step reads "
+        "live: ops/mla.py latent_decode_attention). 0 for the other "
+        "families.",
+    ),
     "completed": (
         "serving_requests_completed_total",
         "Requests finished normally (eos or length).",
@@ -217,6 +225,13 @@ _STAT_SPEC = {
         "this replica holds, over the expert layers (families with routed "
         "experts; the rest of a row's experts_per_token lie on the chips "
         "that hold the other experts).",
+    ),
+    "moe_rows_in_held_group": (
+        "serving_moe_rows_in_held_group_total",
+        "(row, expert layer) pairs of decode steps whose router kept a "
+        "routing group this replica holds (a router limited to groups: "
+        "only such a row can meet a held expert; topk_group / n_group of "
+        "the rows under even routing). 0 for a router without groups.",
     ),
     "page_shed": (
         "serving_requests_page_shed_total",
@@ -753,6 +768,41 @@ def _refuse_for_two_ring_lengths(cfg: ModelConfig,
         )
 
 
+def _refuse_for_latent_ring(cfg: ModelConfig,
+                            serving: ServingConfig) -> None:
+    """The engine features that the ``deepseek_v2`` family's pool does not
+    have yet, each refused under its own reason. Its state is a ring of
+    latents a layer, addressed by position like a K/V ring (no recurrent
+    state: :func:`_refuse_for_recurrent_state`'s reason does not hold),
+    and all of one length (:func:`_refuse_for_two_ring_lengths`'s neither);
+    what is missing is code, named here."""
+    lacks = "the {} family keeps a ring of latents a slot and layer, and {}"
+    asked = (
+        ("the host tier (host_tier_bytes) stashes and restores a slot as "
+         "the pages of a page table, which this pool does not have (no "
+         "paging over latents yet)", serving.host_tier_bytes > 0),
+        ("speculation (spec_mode) verifies several rows a slot in one "
+         "step: the hybrid decode loop advances one row a slot, and the "
+         "live-latent read (ops/mla.py latent_decode_attention) takes one "
+         "query position a slot", serving.spec_enabled()),
+        ("paging (kv_page_size > 0; with it the prefix cache) maps K and V "
+         "leaves through a page table: serving/pages.py and the paged "
+         "decode programs know no `latent` leaf, and the live-latent read "
+         "takes a slot's ring whole, not pages", serving.paged()),
+    )
+    for what, on in asked:
+        if on:
+            raise ValueError(lacks.format(cfg.model, what))
+    if cfg.kv_cache_dtype == "int8":
+        raise ValueError(
+            f"kv_cache_dtype='int8' is not available for the {cfg.model} "
+            "family: int8 latents do not exist yet (quantize_kv scales a "
+            "K/V head; a latent is key and value of every head at once, "
+            "and its rotary part would need a scale of its own), and the "
+            "live-latent read takes float latents"
+        )
+
+
 # fold_in salt distinguishing a draft position's ACCEPT-draw key from
 # its token key: the t-th token's decisions stay a pure function of
 # (request seed, t) — never of slot, batch composition, or how many
@@ -1091,6 +1141,11 @@ class ServingEngine:
         self._window_layers = cfg.layer_kinds().count("window")
         if self._window_layers:
             _refuse_for_two_ring_lengths(cfg, self.serving)
+        # every layer keeps a ring of latents that the decode step reads
+        # live (deepseek_v2): the decode span says how many it holds
+        self._latent_layers = cfg.layer_kinds().count("latent")
+        if self._latent_layers:
+            _refuse_for_latent_ring(cfg, self.serving)
         # the hybrid families' prefill program takes a chunk padded to
         # the ladder's next shape (forward_chunk ``valid``): a prompt's
         # tail is one program, not one a binary digit of its length
@@ -1590,6 +1645,11 @@ class ServingEngine:
                        "table cannot roll with a KV cache (models/decode.py)"
                        if self.cfg.model == "diff" else
                        f"and the {self.cfg.model} family's cache cannot "
+                       "roll: its layers see every earlier position, which "
+                       "a rolled ring of latents no longer holds "
+                       "(models/decode.py)"
+                       if self._latent_layers else
+                       f"and the {self.cfg.model} family's cache cannot "
                        "roll: its attention layers (afmoe's full ones) "
                        "carry no position (models/decode.py)")
                 )
@@ -1895,6 +1955,11 @@ class ServingEngine:
                 # from the positions just built, no device read
                 decode_args["kv"] = live_kv(pos, mask,
                                             self.cfg.sliding_window)
+            if self._latent_layers:
+                # the latents the rows hold live, pos + 1 a row: what the
+                # step's attention reads a layer, from the positions
+                decode_args["latent_live"] = int(
+                    (pos[mask].astype(np.int64) + 1).sum())
             load = ()
             if self.cfg.num_experts:
                 # filled in below, once the tokens' read has waited for
@@ -1929,11 +1994,14 @@ class ServingEngine:
                     # twelve bytes that are there, no second wait
                     with self.tracer.span("load_read",
                                           iteration=iteration):
-                        held, top, hit = (
+                        held, top, hit, *reached = (
                             int(v) for v in np.asarray(load[0]))
                     expert_load.update(held=held, max_expert=top,
                                        experts_hit=hit)
                     self.stats.inc("moe_held", held)
+                    if reached:  # a router limited to groups
+                        expert_load["rows_in_held_group"] = reached[0]
+                        self.stats.inc("moe_rows_in_held_group", reached[0])
             bad = [s for s in active if not ok[s.index]]
             if bad:
                 raise EngineCrashError(
@@ -1955,6 +2023,10 @@ class ServingEngine:
                         self._window_layers * kv["live_window"]
                         + (self.cfg.n_layer - self._window_layers)
                         * kv["live_full"])
+                if self._latent_layers:
+                    self.stats.inc(
+                        "decode_live_latent",
+                        self._latent_layers * decode_args["latent_live"])
                 for s in active:
                     self._emit(
                         s, int(sampled[s.index]), now, finished,

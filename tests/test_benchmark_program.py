@@ -37,6 +37,13 @@ _afmoe_cases = _load(BENCH_DIR / "tests" / "test_benchmark_afmoe.py",
                      "benchmark_afmoe_cases")
 globals().update({name: fn for name, fn in vars(_afmoe_cases).items()
                   if name.startswith("test_") or name == "planted_afmoe"})
+# PR 40: the deepseek_v2 configuration's cases likewise
+_deepseek_v2_cases = _load(
+    BENCH_DIR / "tests" / "test_benchmark_deepseek_v2.py",
+    "benchmark_deepseek_v2_cases")
+globals().update({name: fn for name, fn in vars(_deepseek_v2_cases).items()
+                  if name.startswith("test_")
+                  or name == "planted_deepseek_v2"})
 
 from lib import (  # noqa: E402
     harness,

@@ -180,7 +180,8 @@ def test_latent_attention_is_attention_over_per_head_keys_and_values(params):
     cfg = toy()
     latent = kimi_linear.mla_latent(h, p, cfg)
     assert latent.shape == (2, 40, 32 + 8)
-    got = kimi_linear.mla_attend(h, p, latent, jnp.tril(jnp.ones((40, 40), bool)))
+    got = kimi_linear.mla_attend(h, p, cfg, latent,
+                                 jnp.tril(jnp.ones((40, 40), bool)))
     s = reference.sizes(TOY)
     want = jnp.stack([reference._mla(hb, p, s, None) for hb in h])
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)

@@ -51,7 +51,7 @@ dattn = importlib.import_module(
 KERNEL_MODULES = (
     "ops.flash", "ops.fused_ffn", "ops.fused_norm_residual",
     "ops.decode_attention", "ops.kv_write", "ops.ssm", "ops.kda", "ops.moe",
-    "ops.ring_attention",
+    "ops.ring_attention", "ops.mla",
 )
 
 # the recipe's widths (8L/768d, T=512, vocab 12000); the batch is cut, a
@@ -772,6 +772,74 @@ def test_afmoe_programs_update_the_pool_of_two_ring_lengths_in_place(topo):
         kernel_names.MOE_GROUPED_MATMUL}
     assert programs["decode"].memory_analysis().temp_size_in_bytes < 1.0e9
     assert programs["prefill"].memory_analysis().temp_size_in_bytes < 2.0e9
+
+
+# -- the deepseek_v2 family at the published widths (DeepSeek-V2, a share) ----
+
+DEEPSEEK_V2 = dict(
+    model="deepseek_v2", vocab_size=12800, n_embd=5120, n_head=128,
+    n_layer=5, block_size=8192, ffn_hidden=12288, q_lora_rank=1536,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, rope_theta=10000.0,
+    rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                  "beta_slow": 1, "mscale": 0.707, "mscale_all_dim": 0.707,
+                  "original_max_position_embeddings": 4096},
+    num_experts=160, experts_per_token=6, moe_hidden=1536, n_group=8,
+    topk_group=3, n_shared_experts=2, routed_scaling=16.0,
+    held_experts=[0, 20], param_dtype="bfloat16")
+
+
+def test_deepseek_v2_programs_update_the_latent_pool_in_place(topo):
+    """What the chip's compiler makes of the deepseek_v2 family's two
+    programs at the serve cell's own size (64 slots of five rings of
+    8,192 latents of 576 values, the five layers of the share at
+    published widths, 128 heads): every cache leaf is aliased input to
+    output; the decode program names its kernels (the latent's row write,
+    the live-latent read, the experts' grouped product) and its scopes;
+    and the temporaries stay far under the pool (3.02 GB): the decode step
+    holds no score over slots x ring (268 MB a layer in float32 if it
+    did), and a prefill chunk's scores and widened keys stay on the
+    chip (``mla_chunk_widened_fwd``)."""
+    from differential_transformer_replication_tpu.models import init_model
+    from differential_transformer_replication_tpu.models.decode import init_cache
+    from differential_transformer_replication_tpu.serving import engine
+
+    cfg, slots = ModelConfig(**DEEPSEEK_V2), 64
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = place(jax.eval_shape(lambda k: init_model(k, cfg),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_cache(cfg, slots)))
+    ints, scalar = place(sds((slots,), jnp.int32)), place(sds((), jnp.int32))
+    prefill, decode = engine._build_step_fns(cfg, cfg.block_size)[:2]
+    programs = {
+        "decode": decode.lower(params, ints, ints,
+                               place(sds((slots,), jnp.bool_)), cache).compile(),
+        "prefill": prefill.lower(params, cache, scalar,
+                                 place(sds((1, 1024), jnp.int32)), scalar,
+                                 scalar).compile(),
+    }
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(cache))
+    assert pool_bytes == 64 * 8192 * 5760
+    for name, compiled in programs.items():
+        assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes, name
+    text = programs["decode"].as_text()
+    assert text.startswith("HloModule jit__decode")
+    assert assert_kernels_named(text, "_decode") == {
+        kernel_names.KV_ROW_WRITE, kernel_names.MOE_GROUPED_MATMUL,
+        kernel_names.MLA_LATENT_DECODE}
+    assert {"mla", "mla_q", "mla_latent_write", "mla_attend", "mla_out",
+            "moe", "moe_router", "moe_experts", "moe_shared", "ffn_norm",
+            "ffn", "lm_head", "kv_merge"} <= scopes_in(text)
+    assert {"mla", "mla_q", "mla_latent_write", "mla_attend", "mla_out",
+            "moe_experts"} <= scopes_in(programs["prefill"].as_text())
+    assert assert_kernels_named(programs["prefill"].as_text(), "_prefill") == {
+        kernel_names.MOE_GROUPED_MATMUL, kernel_names.MLA_CHUNK_WIDENED}
+    assert programs["decode"].memory_analysis().temp_size_in_bytes < 0.1e9
+    assert programs["prefill"].memory_analysis().temp_size_in_bytes < 1.0e9
 
 
 def _computations(text: str) -> dict:
