@@ -24,12 +24,23 @@ techniques:
   batched length-1 ``forward_chunk``. Sequences retire on EOS or
   max-tokens without stalling the rest of the batch; the freed slot is
   refilled on the next iteration.
+- **The late read**: sampled tokens pass from step to step ON THE
+  DEVICE (the sampler's packed output of step k is an operand of step
+  k + 1; a finished prompt's first token is written over its row), the
+  next decode step is dispatched BEFORE the last one's tokens are read,
+  and the host reads them an iteration late, in one blocking read of
+  work the device finished while the host was dispatching
+  (:meth:`ServingEngine.step`). Where a step is built from what the
+  last token did to the host's state (a row with an FSM mask or a
+  penalty, the speculative path, quality telemetry) the same loop reads
+  first; no option selects either order.
 
 Everything device-side is shape-static, so continuous batching costs no
 recompilation as requests come and go:
 
 - the decode step is one jitted call over the FULL pool — per-slot
-  positions/tokens/active-mask are runtime arrays (inactive rows compute
+  positions/tokens/active-mask are runtime values of ONE packed int32
+  operand and of the device's record of sampled rows (inactive rows compute
   garbage and write nothing: the mask goes into the cache write, and the
   step's cache traffic is the rows it writes, in place in the pool);
 - prefill chunks come from a power-of-two ladder, so at most
@@ -68,6 +79,7 @@ from __future__ import annotations
 import math
 import sys
 import time
+from dataclasses import dataclass, field
 from functools import lru_cache
 from typing import List, Optional, Sequence
 
@@ -233,6 +245,26 @@ _STAT_SPEC = {
         "only such a row can meet a held expert; topk_group / n_group of "
         "the rows under even routing). 0 for a router without groups.",
     ),
+    # the late read (ServingEngine.step): how often the loop dispatched a
+    # decode step before it had read the one before, how often it had
+    # to read first, and what the late read cost in rows
+    "lookahead_steps": (
+        "serving_lookahead_steps_total",
+        "Decode steps dispatched before the tokens of the step before "
+        "were read (the host read them an iteration late).",
+    ),
+    "lookahead_drains": (
+        "serving_lookahead_drains_total",
+        "Iterations that read everything in flight before building their "
+        "decode step (a row with an FSM mask or a penalty, the "
+        "speculative path, quality telemetry, a profile capture).",
+    ),
+    "lookahead_dropped_rows": (
+        "serving_lookahead_dropped_rows_total",
+        "Rows of a dispatched step whose request had ended by the time "
+        "the step was read (an EOS or stop sequence read late, a cancel, "
+        "a deadline): the token is dropped, nothing of it is delivered.",
+    ),
     "page_shed": (
         "serving_requests_page_shed_total",
         "Requests shed at admission because the KV page pool could "
@@ -328,6 +360,26 @@ class EngineCrashError(RuntimeError):
     retriable = True
 
 
+@dataclass
+class _InFlight:
+    """One iteration's dispatched work whose outputs the host has not
+    read: the first tokens of the prompts that finished there, each its
+    (slot, request id, the one-row sampler call's packed output), and the
+    decode step, its rows as (slot, request id), the pool-wide sampler
+    call's packed output, the expert load where the family has experts
+    and the ``moe`` dict of its ``decode`` span, filled when read. A row
+    is named by slot AND request: one whose slot no longer holds that
+    request when the record is read has ended meanwhile, and its token is
+    dropped (``ServingEngine._deliver``)."""
+
+    iteration: int
+    firsts: list = field(default_factory=list)
+    rows: list = field(default_factory=list)
+    out: object = None
+    load: object = None
+    moe: Optional[dict] = None
+
+
 # What a row asks of the sampler program beyond an argmax and the
 # finiteness check, one bit each in column 8 of its packed operand
 # (``_build_step_fns._sample``): the host sets them for the rows a call
@@ -367,14 +419,16 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
     """
     if page_size > 0:
 
-        def _decode_paged(params, tokens, pos, cache, page_tables,
+        def _decode_paged(params, rows, last_out, cache, page_tables,
                           write_pages):
             """One batched length-1 step over the whole slot pool
             THROUGH the page tables (models/decode.py
-            ``forward_decode_pool``, as ``_decode`` below): inactive
+            ``forward_decode_pool``, as ``_decode`` below, the rows'
+            operand and the merge of their tokens the same): inactive
             rows' writes are redirected to the trash page by
             ``write_pages`` (the contiguous path gives them no write
             target instead)."""
+            tokens, pos, _ = _merge_rows(rows, last_out)
             logits, new_cache = forward_decode_pool(
                 params, tokens, pos, cache, cfg, rope_len=rope_len,
                 page_tables=page_tables, write_pages=write_pages,
@@ -429,7 +483,17 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
                 out.append(layer)
             return out
 
-    def _decode(params, tokens, pos, active, cache):
+    def _merge_rows(rows, last_out):
+        """(tokens, positions, active mask) of a step from its ONE packed
+        (B, 4) int32 host operand (``pack_decode_rows``) and the sampler's
+        packed output of the step before, still on the device: a row takes
+        its token from column 0 of ``last_out`` unless the host names it
+        ``from_host`` (it alone knows the token: a resumed, imported or
+        replayed slot, a row after a speculative block)."""
+        tokens = jnp.where(rows[:, 3] != 0, rows[:, 0], last_out[:, 0])
+        return tokens, rows[:, 1], rows[:, 2] != 0
+
+    def _decode(params, rows, last_out, cache):
         """One batched length-1 step over the WHOLE slot pool:
         models/decode.py ``forward_decode_pool``, the one L = 1 entry
         point (a row's Q/K/V a length-1 forward_chunk's under vmap,
@@ -438,7 +502,11 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
         ``decode_attention_impl: pallas``, the fused kernel over every
         row in one (B*H,)-grid call per layer) is bound there, not here.
 
-        tokens/pos/active: (B,) runtime arrays. Inactive rows run the
+        ``rows`` is the step's packed host operand, ``last_out`` the
+        device's own record of the last sampled tokens (``_merge_rows``):
+        ONE program whether the host read those tokens before this call
+        or reads them after it. tokens/pos/active: (B,) runtime arrays
+        taken from them. Inactive rows run the
         projections, the FFN and the head on garbage inputs (static
         shapes are the point) and their logits mean nothing; the write
         takes ``active`` as its mask, so a mid-prefill or free slot's
@@ -451,6 +519,7 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
         routed experts (``num_experts``) returns a third item, the step's
         expert load (models/decode.py:``_hybrid_decode``).
         """
+        tokens, pos, active = _merge_rows(rows, last_out)
         logits, new_cache, *load = forward_decode_pool(
             params, tokens, pos, cache, cfg, rope_len=rope_len, active=active)
         return (logits.astype(jnp.float32), new_cache, *load)
@@ -678,7 +747,7 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
         )
     return (
         jax.jit(_prefill, donate_argnums=(1,)),
-        jax.jit(_decode, donate_argnums=(4,)),
+        jax.jit(_decode, donate_argnums=(3,)),
         jax.jit(_sample),
         None,
         None,
@@ -691,6 +760,44 @@ def _build_step_fns(cfg: ModelConfig, rope_len: int,
 # runtime scalar and the leaves are told apart by name, so it compiles once
 # a pool shape.
 _reset_state_fn = jax.jit(reset_slot_state, donate_argnums=(0,))
+
+
+@jax.jit
+def _set_row_fn(last_out, slot, row):
+    """A completed prompt's first token (the one-row sampler call's packed
+    output) written into the device's record of the last sampled rows, so
+    the row joins the next decode step without the host having seen the
+    token. NOT donated: the read of the step in flight may still hold the
+    old record. ``slot`` is a runtime scalar: one compile a pool shape."""
+    return last_out.at[slot].set(row[0])
+
+
+def pack_decode_rows(tokens, pos, active, from_host=None) -> np.ndarray:
+    """The decode step's ONE packed (B, 4) int32 host operand: token |
+    position | active | from_host a row (``_build_step_fns._merge_rows``
+    takes it apart on the device). ``from_host`` defaults to every row:
+    the tokens given are the ones the step runs."""
+    tokens = np.asarray(tokens)
+    rows = np.zeros((tokens.shape[0], 4), np.int32)
+    rows[:, 0] = tokens
+    rows[:, 1] = np.asarray(pos)
+    rows[:, 2] = np.asarray(active)
+    rows[:, 3] = 1 if from_host is None else np.asarray(from_host)
+    return rows
+
+
+def _prng_key_words(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)``'s two uint32 words, minted on the
+    host: the default generator pads a 32-bit seed with zeros (``(0,
+    seed mod 2**32)``, tests/test_serving.py holds it word for word), so
+    a submission costs no program on the chip and no read of one, which
+    would queue behind the step in flight. Another generator, 64-bit
+    integers or a seed offset take JAX's own path."""
+    if (jax.config.jax_default_prng_impl == "threefry2x32"
+            and not jax.config.jax_enable_x64
+            and not jax.config.jax_random_seed_offset):
+        return np.array([0, int(seed) & 0xFFFFFFFF], np.uint32)
+    return np.asarray(jax.random.PRNGKey(seed), np.uint32)
 
 
 def _refuse_for_recurrent_state(cfg: ModelConfig,
@@ -1273,6 +1380,15 @@ class ServingEngine:
                              self.serving.kv_page_size)
             if self._paged else init_cache(cfg, self._rows)
         )
+        # The late read (:meth:`step`): the device's own record of the
+        # last sampled rows (the newest decode step's packed sampler
+        # output, a completed prompt's first token written over its
+        # row), which the next step takes its tokens from; the work
+        # dispatched an iteration ago whose outputs the host has not
+        # read; the first tokens dispatched since.
+        self._last_out = self._no_rows_sampled()
+        self._inflight: Optional[_InFlight] = None
+        self._firsts: list = []
         self.scheduler = Scheduler(
             self.serving,
             on_retire=(
@@ -1722,9 +1838,7 @@ class ServingEngine:
             raise
         if ckey is not None:
             self._constraints[rid] = (ckey, cfsm)
-        self._base_keys[rid] = np.asarray(
-            jax.random.PRNGKey(req.params.seed), np.uint32
-        )
+        self._base_keys[rid] = _prng_key_words(req.params.seed)
         return rid
 
     def cancel(self, request_id: int) -> bool:
@@ -1756,7 +1870,20 @@ class ServingEngine:
     # -- one engine iteration -----------------------------------------
 
     def has_work(self) -> bool:
-        return self.scheduler.has_work()
+        """Requests queued or in slots, or a dispatched step whose tokens
+        the host has not read: a :meth:`step` with nothing left to
+        dispatch reads it, so the last token of the last request is
+        delivered without another arrival."""
+        return self.scheduler.has_work() or self._inflight is not None
+
+    def _no_rows_sampled(self):
+        """The device's record of the last sampled rows before any step
+        ran: zeros of the pool-wide sampler call's packed output shape,
+        so the decode program sees ONE operand shape from its first call
+        (no row reads it: a row is ``from_host`` or was written)."""
+        return jnp.zeros(
+            (self._rows, 3 + 2 * self._lp_k + (3 if self._quality else 0)),
+            jnp.int32)
 
     def queue_len(self) -> int:
         """Requests waiting for a slot (admission-queue depth)."""
@@ -1764,9 +1891,29 @@ class ServingEngine:
 
     def step(self) -> List[RequestOutput]:
         """Deadline shed -> admit -> prefill (budgeted) -> batched
-        decode. Returns the requests that finished THIS iteration
-        (including ones retired with ``finish_reason == "deadline"``)."""
-        if not self.scheduler.has_work():
+        decode, READ AN ITERATION LATE. Returns the requests that
+        finished THIS iteration (including ones retired with
+        ``finish_reason == "deadline"``).
+
+        The loop's order: (1) schedule and dispatch the admitted
+        prompts' chunks, a completed prompt's first token sampled and
+        written into the device's record of sampled rows without a read;
+        (2) dispatch the decode step for the rows the host KNOWS are live,
+        their tokens taken on the device from that record; (3) only then
+        read what the iteration BEFORE dispatched (its first tokens,
+        emitted at once, then its step's packed rows and its expert
+        load: :meth:`_read`), work the device finished while the host was
+        dispatching; (4) emit and finish from that read. A step needs the host to have seen the
+        last tokens first (``_reads_first``: a live row with an FSM mask
+        or a penalty, the speculative path, quality telemetry, a profile
+        capture): there the same loop reads what is in flight before it
+        builds the step and reads the step right after its dispatch, as
+        every iteration did before. A row that ended on a token the host
+        could not foresee (EOS, a stop sequence, a cancel, a deadline)
+        has one step too many in flight: its token is dropped when read
+        (``_deliver``), its K/V write lies in a slot or a page of its own
+        that the next occupant overwrites in device order."""
+        if not self.has_work():
             out, self._finished_prior = self._finished_prior, []
             return out
         iteration = self.stats["iterations"]
@@ -1852,6 +1999,10 @@ class ServingEngine:
                     # would RESET the cursor to the FSM's start state
                     slot.constraint = ent[1]
                     slot.fsm_state = snap["fsm_state"]
+                # the host alone knows the last token: the next step
+                # takes it from the host's operand
+                slot.dispatched = len(slot.generated)
+                slot.token_on_device = False
                 slot.state = ACTIVE
                 self._resume.pop(slot.request.request_id, None)
             self._resumed = []
@@ -1860,14 +2011,20 @@ class ServingEngine:
             with self.tracer.span(
                 "prefill", iteration=iteration, chunks=len(chunks)
             ):
-                self._run_prefill(chunks, finished, iteration)
+                self._run_prefill(chunks, iteration)
 
         if faults.serve_corrupt_at(iteration):
             self._corrupt_one_slot()
         if self._pages is not None and faults.prefix_corrupt_at(iteration):
             self._corrupt_cached_prefix()
 
-        active = self.scheduler.active_slots()
+        active = self._live_rows()
+        cause = self._reads_first(active, capturing)
+        if cause and (self._inflight is not None or self._firsts):
+            # this step is built from what the last tokens did to the
+            # host's state: read what is in flight before building it
+            self._drain(iteration)
+            active = self._live_rows()
         if self._constraints:
             if faults.constrain_dead_end_at(iteration):
                 # chaos hook: poison the first constrained ACTIVE
@@ -1902,138 +2059,43 @@ class ServingEngine:
                     else "constraint_dead_end",
                 ))
             if swept:
-                active = self.scheduler.active_slots()
+                active = self._live_rows()
         proposals = {}
         if active and self._spec_k:
             proposals = self._collect_proposals(active, iteration)
         if active and proposals:
             self._decode_spec(active, proposals, iteration, finished)
-        elif active:
+        else:
             # the plain L=1 step — also the spec engine's k=0 ladder
             # rung, taken whenever no slot has a proposal this
             # iteration (drafter dry, all slots near their windows,
             # or a rebuilt drafter falling back)
-            with self.tracer.span("decode_inputs", iteration=iteration):
-                B = self._rows
-                tokens = np.zeros((B,), np.int32)
-                pos = np.zeros((B,), np.int32)
-                mask = np.zeros((B,), bool)
-                for s in active:
-                    tokens[s.index] = s.generated[-1]
-                    pos[s.index] = s.prompt_len + len(s.generated) - 1
-                    mask[s.index] = True
-                if self._pages is not None:
-                    # page tables + per-row write pages ride the one
-                    # jitted step as runtime int32 arrays; inactive
-                    # rows write the trash page (the contiguous path
-                    # gives them no write target)
-                    M = self.cfg.block_size
-                    ps = self.serving.kv_page_size
-                    tables = self._pages.tables()
-                    write_pages = np.zeros((B,), np.int32)
-                    for s in active:
-                        write_pages[s.index] = tables[
-                            s.index, (pos[s.index] % M) // ps
-                        ]
-            # the decode step is one batched op over every active slot;
-            # its span carries the trace ids it advanced so a stitched
-            # timeline shows which requests shared each iteration
-            decode_args = {"iteration": iteration, "active": len(active)}
-            if self._own_ring_attend:
-                # what the step's attention reads of the pool: the rule
-                # the program runs on this mask, read on the host
-                decode_args["attend_rows"] = attend_rows(mask)
-            if self._tracing:
-                tids = [
-                    s.trace.trace_id for s in active
-                    if s.trace is not None
-                ]
-                if tids:
-                    decode_args["trace_ids"] = tids
-            if self._window_layers:
-                # what the rows hold of their rings of either length:
-                # from the positions just built, no device read
-                decode_args["kv"] = live_kv(pos, mask,
-                                            self.cfg.sliding_window)
-            if self._latent_layers:
-                # the latents the rows hold live, pos + 1 a row: what the
-                # step's attention reads a layer, from the positions
-                decode_args["latent_live"] = int(
-                    (pos[mask].astype(np.int64) + 1).sum())
-            load = ()
-            if self.cfg.num_experts:
-                # filled in below, once the tokens' read has waited for
-                # the step: the span keeps the dict it was handed
-                decode_args["moe"] = expert_load = {}
-            operands = ((tokens, pos, mask) if self._pages is None
-                        else (tokens, pos, tables, write_pages))
-            with self.tracer.span("decode", **decode_args):
-                # the host's share of the step, taken apart: the
-                # operands' transfers, one each, then the call, which
-                # returns before the device is done
-                with self.tracer.span(
-                    "decode_h2d", **self._h2d_args(iteration, operands)
-                ):
-                    operands = [jnp.asarray(a) for a in operands]
-                with self.tracer.span("decode_dispatch",
-                                      iteration=iteration):
-                    if self._pages is not None:
-                        logits, self.cache = self._decode_fn(
-                            self.params, *operands[:2], self.cache,
-                            *operands[2:],
-                        )
-                    else:
-                        logits, self.cache, *load = self._decode_fn(
-                            self.params, *operands, self.cache,
-                        )
-            with self.tracer.span("sample", iteration=iteration):
-                sampled, ok, packed = self._sample_all_slots(
-                    logits, iteration)
-                if load:
-                    # the step has finished (its tokens were just read):
-                    # twelve bytes that are there, no second wait
-                    with self.tracer.span("load_read",
-                                          iteration=iteration):
-                        held, top, hit, *reached = (
-                            int(v) for v in np.asarray(load[0]))
-                    expert_load.update(held=held, max_expert=top,
-                                       experts_hit=hit)
-                    self.stats.inc("moe_held", held)
-                    if reached:  # a router limited to groups
-                        expert_load["rows_in_held_group"] = reached[0]
-                        self.stats.inc("moe_rows_in_held_group", reached[0])
-            bad = [s for s in active if not ok[s.index]]
-            if bad:
-                raise EngineCrashError(
-                    f"non-finite logits decoding slot(s) "
-                    f"{[s.index for s in bad]} (request(s) "
-                    f"{[s.request.request_id for s in bad]}): corrupt "
-                    "slot pool or numerically diverged params"
-                )
-            with self.tracer.span("emit", iteration=iteration):
-                now = time.perf_counter()
-                self.stats.inc("decode_tokens", len(active))
-                if self._own_ring_attend:
-                    self.stats.inc("decode_attend_rows",
-                                   decode_args["attend_rows"])
-                if self._window_layers:
-                    kv = decode_args["kv"]
-                    self.stats.inc(
-                        "decode_live_kv",
-                        self._window_layers * kv["live_window"]
-                        + (self.cfg.n_layer - self._window_layers)
-                        * kv["live_full"])
-                if self._latent_layers:
-                    self.stats.inc(
-                        "decode_live_latent",
-                        self._latent_layers * decode_args["latent_live"])
-                for s in active:
-                    self._emit(
-                        s, int(sampled[s.index]), now, finished,
-                        lp=self._lp_echo(s, packed[s.index]),
-                        q=(self._quality_echo(packed[s.index])
-                           if self._quality else None),
-                    )
+            logits = None
+            rec = None
+            if active:
+                logits, rec = self._dispatch_decode(active, iteration, cause)
+            elif self._firsts:
+                rec = _InFlight(iteration)
+            if rec is not None:
+                rec.firsts, self._firsts = self._firsts, []
+            # what this iteration reads: under a cause its own work (the
+            # next step is built from these tokens), else the work of
+            # the iteration before, which the device finished while the
+            # host was dispatching; this iteration's stays in flight
+            if cause:
+                reads = rec
+            else:
+                reads, self._inflight = self._inflight, rec
+            if logits is not None or reads is not None:
+                with self.tracer.span("sample", iteration=iteration):
+                    if logits is not None:
+                        rec.out = self._last_out = self._sample_dispatch(
+                            [(s.index, s) for s in active], self._rows,
+                            logits, iteration, "decode")
+                    if reads is not None:
+                        out = self._read(reads, iteration, finished)
+            if reads is not None:
+                self._deliver(reads, out, finished, iteration)
 
         # the iteration's book-keeping; it lies AFTER the iteration's
         # last stamped span, so it carries no ``iteration`` (readers
@@ -2051,16 +2113,17 @@ class ServingEngine:
         self._finished_prior = []
         return finished
 
-    def _run_prefill(self, chunks, finished: List[RequestOutput],
-                     iteration: int) -> None:
+    def _run_prefill(self, chunks, iteration: int) -> None:
         """Execute one iteration's planned prefill chunks (see
         :meth:`Scheduler.plan`); extracted so the step's tracer span
         brackets exactly the prefill device work. Inside it, per chunk:
         ``prefill_call`` (the chunk's transfer and the dispatch of its
         program, which returns before the device is done) and, on the
-        chunk that completes a prompt, ``first_token`` (the sampler
-        call, whose read of the token BLOCKS on the device before the
-        next request's chunk can be dispatched, and the emit)."""
+        chunk that completes a prompt, ``first_token`` (the one-row
+        sampler call and the write of its row into the device's record
+        of sampled rows, both dispatched and neither read: the row
+        joins this iteration's decode step, the host reads the token
+        with the next read, :meth:`_deliver`)."""
         for slot, start, size in chunks:
             if start == slot.cached_len:
                 # first chunk actually RUN = the request finally got a
@@ -2112,20 +2175,12 @@ class ServingEngine:
             # prompt complete: the chunk's last-position logits give
             # the first generated token (generate_cached's contract)
             with self.tracer.span("first_token", iteration=iteration):
-                tok, ok, packed = self._sample_rows(
-                    [slot], logits[None], iteration)
-                if not ok[0]:
-                    raise EngineCrashError(
-                        f"non-finite logits prefilling slot {slot.index} "
-                        f"(request {slot.request.request_id}): corrupt "
-                        "slot pool or numerically diverged params"
-                    )
-                self._emit(
-                    slot, int(tok[0]), time.perf_counter(), finished,
-                    lp=self._lp_echo(slot, packed[0]),
-                    q=(self._quality_echo(packed[0])
-                       if self._quality else None),
-                )
+                out = self._sample_dispatch(
+                    [(0, slot)], 1, logits[None], iteration, "prefill")
+                self._last_out = _set_row_fn(
+                    self._last_out, np.int32(slot.index), out)
+            slot.state = ACTIVE
+            self._firsts.append((slot, slot.request.request_id, out))
 
     def _reset_slot_state(self, slot: Slot, iteration: int) -> None:
         """Zero the recurrent state the slot's last sequence left behind,
@@ -2136,6 +2191,273 @@ class ServingEngine:
         with self.tracer.span("state_reset", iteration=iteration, slots=1):
             self.cache = _reset_state_fn(self.cache, np.int32(slot.index))
         self.stats.inc("state_resets")
+
+    # -- the late read --------------------------------------------------
+
+    def _live_rows(self) -> List[Slot]:
+        """The rows the host KNOWS the next decode step runs: the ACTIVE
+        slots but those whose last token is already dispatched (a row that
+        reaches ``max_new_tokens`` with the token in flight is known
+        beforehand and left out). With everything read that is every
+        ACTIVE slot."""
+        return [s for s in self.scheduler.active_slots()
+                if s.dispatched < s.request.params.max_new_tokens]
+
+    def _reads_first(self, rows: List[Slot], capturing: bool) -> str:
+        """Why this iteration's decode step needs the host to have read
+        every token before it is built, or "" where it does not and the
+        read may come an iteration late. Decided an iteration from what
+        the rows ask and the engine's own state, by no option: a live row
+        with an FSM mask or a penalty (the cursor and the histogram
+        advance on the host, ``_emit``), the speculative path (proposals
+        are drafted from the tokens read), quality telemetry (the
+        sampler's previous-token column), a profile capture that closes
+        on this iteration."""
+        if capturing:
+            return "profile"
+        if self._quality:
+            return "quality"
+        if self._spec_k:
+            return "spec"
+        for s in rows:
+            asks = self._row_asks(s)
+            if asks & ASK_MASK:
+                return "mask"
+            if asks & ASK_PENALTY:
+                return "penalty"
+        return ""
+
+    def _owns(self, slot: Slot, rid: int) -> bool:
+        """Whether the slot still holds the request a dispatched row was
+        built for (request ids are never reused)."""
+        return (slot.state != FREE and slot.request is not None
+                and slot.request.request_id == rid)
+
+    def _drain(self, iteration: Optional[int] = None) -> None:
+        """Read and deliver EVERYTHING in flight, now: before a step that
+        is built from the host's state (:meth:`_reads_first`) and before
+        anything that reads a slot's host state whole
+        (``export_slot_state``, ``_preempt_slot``, ``close``). Outside a
+        step what finishes waits in ``_finished_prior`` for the next
+        :meth:`step` or :meth:`take_finished`."""
+        if self._inflight is None and not self._firsts:
+            return
+        if iteration is None:
+            iteration = self.stats["iterations"]
+        rec = self._inflight or _InFlight(iteration)
+        rec.firsts = rec.firsts + self._firsts
+        self._inflight, self._firsts = None, []
+        with self.tracer.span("sample", iteration=iteration):
+            out = self._read(rec, iteration, self._finished_prior)
+        self._deliver(rec, out, self._finished_prior, iteration)
+
+    def _dispatch_decode(self, active: List[Slot], iteration: int,
+                         cause: str):
+        """Build and dispatch the L = 1 step for ``active``; returns its
+        logits (on the device) and its in-flight record. The rows' host
+        operand is ONE packed int32 array (``pack_decode_rows``'s
+        layout); a row's token rides in it only where the host alone
+        knows it (``Slot.token_on_device`` false), else the program takes
+        it from ``_last_out``; positions count the DISPATCHED tokens."""
+        with self.tracer.span("decode_inputs", iteration=iteration):
+            B = self._rows
+            rows = np.zeros((B, 4), np.int32)
+            for s in active:
+                row = rows[s.index]
+                if not s.token_on_device:
+                    row[0], row[3] = s.generated[-1], 1
+                row[1] = s.prompt_len + s.dispatched - 1
+                row[2] = 1
+            pos, mask = rows[:, 1], rows[:, 2].astype(bool)
+            operands = (rows,)
+            if self._pages is not None:
+                # page tables + per-row write pages ride the one
+                # jitted step as runtime int32 arrays; inactive
+                # rows write the trash page (the contiguous path
+                # gives them no write target)
+                M = self.cfg.block_size
+                ps = self.serving.kv_page_size
+                tables = self._pages.tables()
+                write_pages = np.zeros((B,), np.int32)
+                for s in active:
+                    write_pages[s.index] = tables[
+                        s.index, (pos[s.index] % M) // ps
+                    ]
+                operands = (rows, tables, write_pages)
+        # the decode step is one batched op over every active slot;
+        # its span carries the trace ids it advanced so a stitched
+        # timeline shows which requests shared each iteration
+        decode_args = {"iteration": iteration, "active": len(active),
+                       # 1: dispatched before the step before was read
+                       "lookahead": int(not cause)}
+        if cause:
+            decode_args["drain"] = cause
+            self.stats.inc("lookahead_drains")
+        else:
+            self.stats.inc("lookahead_steps")
+            # rows of the step in flight (this iteration reads it) whose
+            # request has ended since: their tokens will be dropped
+            decode_args["inflight_dropped"] = (
+                0 if self._inflight is None else sum(
+                    1 for s, rid in self._inflight.rows
+                    if not self._owns(s, rid)))
+        if self._own_ring_attend:
+            # what the step's attention reads of the pool: the rule
+            # the program runs on this mask, read on the host
+            decode_args["attend_rows"] = attend_rows(mask)
+            self.stats.inc("decode_attend_rows", decode_args["attend_rows"])
+        if self._tracing:
+            tids = [
+                s.trace.trace_id for s in active
+                if s.trace is not None
+            ]
+            if tids:
+                decode_args["trace_ids"] = tids
+        if self._window_layers:
+            # what the rows hold of their rings of either length:
+            # from the positions just built, no device read
+            kv = decode_args["kv"] = live_kv(pos, mask,
+                                             self.cfg.sliding_window)
+            self.stats.inc(
+                "decode_live_kv",
+                self._window_layers * kv["live_window"]
+                + (self.cfg.n_layer - self._window_layers)
+                * kv["live_full"])
+        if self._latent_layers:
+            # the latents the rows hold live, pos + 1 a row: what the
+            # step's attention reads a layer, from the positions
+            decode_args["latent_live"] = int(
+                (pos[mask].astype(np.int64) + 1).sum())
+            self.stats.inc(
+                "decode_live_latent",
+                self._latent_layers * decode_args["latent_live"])
+        rec = _InFlight(
+            iteration,
+            rows=[(s, s.request.request_id) for s in active])
+        if self.cfg.num_experts:
+            # filled in when the step's expert load is read (this
+            # iteration or the next): the span keeps the dict it was
+            # handed
+            decode_args["moe"] = rec.moe = {}
+        with self.tracer.span("decode", **decode_args):
+            # the host's share of the step, taken apart: the
+            # operands' transfers, one each, then the call, which
+            # returns before the device is done
+            with self.tracer.span(
+                "decode_h2d", **self._h2d_args(iteration, operands)
+            ):
+                operands = [jnp.asarray(a) for a in operands]
+            with self.tracer.span("decode_dispatch",
+                                  iteration=iteration):
+                logits, self.cache, *load = self._decode_fn(
+                    self.params, operands[0], self._last_out,
+                    self.cache, *operands[1:],
+                )
+        if load:
+            rec.load = load[0]
+        return logits, rec
+
+    def _read(self, rec: _InFlight, iteration: int,
+              finished: List[RequestOutput]):
+        """The blocking read of an iteration, inside the caller's
+        ``sample`` span, in the order the device made the work: the first
+        tokens of the prompts that finished in the record's iteration
+        (``token_read`` with ``path: prefill``), EMITTED at once, because
+        their programs ended a whole decode step before the step's own
+        rows are there and a first token should not wait that step out;
+        then the step's packed rows (``token_read`` with ``path:
+        decode``: the wait for the device, the copy back, the thread's
+        wake-up) and its expert load (``load_read``). The spans are
+        stamped with the iteration the HOST is in; ``of_iteration`` is
+        the one the work was dispatched in. Returns the step's packed
+        rows (None for a record without a step) for :meth:`_deliver`."""
+        args = {"iteration": iteration, "of_iteration": rec.iteration}
+        if rec.firsts:
+            with self.tracer.span("token_read", path="prefill", **args):
+                firsts = [np.asarray(o)[0] for _, _, o in rec.firsts]
+            self._deliver_firsts(rec, firsts, finished, iteration)
+        if rec.out is None:
+            return None
+        with self.tracer.span("token_read", path="decode", **args):
+            out = np.asarray(rec.out)
+        if rec.load is not None:
+            # the step has finished (its tokens were just read):
+            # twelve bytes that are there, no second wait
+            with self.tracer.span("load_read", moe=rec.moe, **args):
+                held, top, hit, *reached = (
+                    int(v) for v in np.asarray(rec.load))
+                rec.moe.update(held=held, max_expert=top, experts_hit=hit)
+                self.stats.inc("moe_held", held)
+                if reached:  # a router limited to groups
+                    rec.moe["rows_in_held_group"] = reached[0]
+                    self.stats.inc("moe_rows_in_held_group", reached[0])
+        return out
+
+    def _emit_row(self, slot: Slot, row: np.ndarray, now: float,
+                  finished: List[RequestOutput]) -> None:
+        """One packed sampler row (``_build_step_fns._sample``'s output
+        contract) emitted for its slot."""
+        self._emit(
+            slot, int(row[0]), now, finished,
+            lp=self._lp_echo(slot, row),
+            q=(self._quality_echo(row) if self._quality else None),
+        )
+
+    def _deliver_firsts(self, rec: _InFlight, firsts,
+                        finished: List[RequestOutput],
+                        iteration: int) -> None:
+        """Emit the first tokens a read brought back. A non-finite one of
+        a request that still holds its slot raises
+        :class:`EngineCrashError` as its turn comes (what finished before
+        it is in ``take_finished``); one whose request ended meanwhile (a
+        cancel, a deadline) is dropped and counted."""
+        dropped = 0
+        with self.tracer.span("emit", iteration=iteration):
+            now = time.perf_counter()
+            for (slot, rid, _), row in zip(rec.firsts, firsts):
+                if not self._owns(slot, rid):
+                    dropped += 1
+                    continue
+                if not row[1]:
+                    raise EngineCrashError(
+                        f"non-finite logits prefilling slot {slot.index} "
+                        f"(request {rid}): corrupt "
+                        "slot pool or numerically diverged params"
+                    )
+                self._emit_row(slot, row, now, finished)
+        if dropped:
+            self.stats.inc("lookahead_dropped_rows", dropped)
+
+    def _deliver(self, rec: _InFlight, out,
+                 finished: List[RequestOutput], iteration: int) -> None:
+        """Emit a read decode step's token a row (``out`` None: the
+        record held first tokens only). A non-finite row of a request
+        that still holds its slot raises :class:`EngineCrashError` naming
+        slot and request BEFORE any token of the step is emitted. A row
+        whose request ended between its dispatch and this read (an EOS or
+        a stop sequence read late, its own first token's among them, a
+        cancel, a deadline) is dropped and counted: nothing of it
+        reaches a caller."""
+        if out is None:
+            return
+        live = [s for s, rid in rec.rows if self._owns(s, rid)]
+        bad = [s for s in live if not out[s.index, 1]]
+        if bad:
+            raise EngineCrashError(
+                f"non-finite logits decoding slot(s) "
+                f"{[s.index for s in bad]} (request(s) "
+                f"{[s.request.request_id for s in bad]}): corrupt "
+                "slot pool or numerically diverged params"
+            )
+        with self.tracer.span("emit", iteration=iteration):
+            now = time.perf_counter()
+            if live:
+                self.stats.inc("decode_tokens", len(live))
+            for slot in live:
+                self._emit_row(slot, out[slot.index], now, finished)
+        if len(live) < len(rec.rows):
+            self.stats.inc("lookahead_dropped_rows",
+                           len(rec.rows) - len(live))
 
     # -- speculative decoding (serving/spec.py) ------------------------
 
@@ -2399,6 +2721,10 @@ class ServingEngine:
                     )
                     if s.state == FREE:
                         break  # EOS/stop/length retired the slot mid-block
+                # the block's tokens were read as they were made: the
+                # host alone knows the last one
+                s.dispatched = len(s.generated)
+                s.token_on_device = False
             self.stats.inc("decode_tokens", emitted)
 
     def spec_stats(self) -> Optional[dict]:
@@ -2642,7 +2968,7 @@ class ServingEngine:
     def run(self) -> List[RequestOutput]:
         """Drain the queue; returns every output, in completion order."""
         outs: List[RequestOutput] = []
-        while self.scheduler.has_work():
+        while self.has_work():
             outs.extend(self.step())
         return outs
 
@@ -2670,7 +2996,14 @@ class ServingEngine:
     def close(self) -> None:
         """Release host-side resources: drain the device-profile
         sampler (its queued parse must land before the process exits).
-        Idempotent; called by EngineRunner's shutdown paths."""
+        Idempotent; called by EngineRunner's shutdown paths. What is
+        still in flight is read first, so no program outlives the
+        engine (nobody is left to deliver its tokens to: a crash in it
+        is the shutdown's, not a caller's)."""
+        try:
+            self._drain()
+        except Exception:
+            self._inflight, self._firsts = None, []
         if self._device_prof is not None:
             self._device_prof.close()
 
@@ -2848,7 +3181,12 @@ class ServingEngine:
         decode state to the tier, free its pages, and REQUEUE it with
         its ORIGINAL submit_time so anti-starvation aging keeps
         accruing. The later swap-in (:meth:`_try_resume`) is bit-exact
-        — no recompute, no recompile."""
+        — no recompute, no recompile. The snapshot is of the slot's host
+        state whole, so what is in flight is read first; a victim that
+        read ends is not preempted (its pages are free already)."""
+        self._drain()
+        if slot.state != ACTIVE:
+            return
         rid = slot.request.request_id
         ps = self.serving.kv_page_size
         # pages actually written so far: after emitting g tokens the
@@ -3004,6 +3342,9 @@ class ServingEngine:
                 "live migration needs the paged KV layout "
                 "(ServingConfig.kv_page_size > 0) — fall back to replay"
             )
+        # the wire image is the slot's host state whole: read what is in
+        # flight first (the tokens may also end the request)
+        self._drain()
         slot = self._slot_for(request_id)
         if slot is None or slot.state != ACTIVE or not slot.generated:
             raise MigrateExportError(
@@ -3210,7 +3551,9 @@ class ServingEngine:
         cacheable = (
             slot.prompt_len > 0
             and slot.filled == slot.prompt_len
-            and slot.prompt_len + len(slot.generated)
+            # what the device wrote counts DISPATCHED tokens: a row that
+            # ended with a step in flight has one position more
+            and slot.prompt_len + max(slot.dispatched, len(slot.generated))
             <= self.cfg.block_size
         )
         self._pages.release(slot.index, prompt, cacheable)
@@ -3427,7 +3770,8 @@ class ServingEngine:
             # 0, serving/migrate.py) samples token t with the key the
             # DEAD attempt would have used at global position
             # key_offset + t — bit-identical streams across failover
-            ints[i, 0] = p.key_offset + len(s.generated)
+            # and the request's tokens DISPATCHED so far, read or not
+            ints[i, 0] = p.key_offset + s.dispatched
             ints[i, 1] = p.top_k or 0
             ints[i, 2:4].view(np.uint32)[:] = (
                 self._base_keys[s.request.request_id]
@@ -3460,17 +3804,19 @@ class ServingEngine:
                     cm[i] = self._slot_counts(s)
         return ints, am, cm
 
-    def _sample(self, rows, B: int, logits, iteration: int, path: str):
-        """One sampler call over a (row index, slot) assignment of B
-        rows, the host's share of it in three spans inside the caller's
-        ``sample`` / ``first_token``: ``sample_operands`` (the packed
-        rows, a mask or histogram where a row needs one),
-        ``sample_dispatch`` (their transfers and the call, which
-        returns before the device is done) and ``token_read`` (the
-        BLOCKING read: the wait for the device, the copy back, the
-        thread's wake-up). ``path`` says which caller: ``decode`` or
-        ``prefill``. Returns (tokens, finite-ok, packed echo rows) — the
-        packed layout is _build_step_fns._sample's output contract."""
+    def _sample_dispatch(self, rows, B: int, logits, iteration: int,
+                         path: str):
+        """Dispatch one sampler call over a (row index, slot) assignment
+        of B rows, the host's share of it in two spans inside the
+        caller's ``sample`` / ``first_token``: ``sample_operands`` (the
+        packed rows, a mask or histogram where a row needs one) and
+        ``sample_dispatch`` (their transfers and the call, which returns
+        before the device is done). ``path`` says which caller:
+        ``decode`` or ``prefill``. Returns the packed output ON THE
+        DEVICE (the layout is _build_step_fns._sample's output contract);
+        :meth:`_read` brings it back, this iteration or the next. Each
+        named row has one more token dispatched, which the caller keeps
+        in the device's record of sampled rows (``_last_out``)."""
         args = {"iteration": iteration, "path": path}
         use = self._sampler_use(rows) if self._tracing else {}
         with self.tracer.span("sample_operands", rows=B,
@@ -3485,25 +3831,10 @@ class ServingEngine:
             out = self._sample_fn(
                 jnp.asarray(ints), logits, allowed, counts
             )
-        with self.tracer.span("token_read", **args):
-            out = np.asarray(out)
-        return out[:, 0], out[:, 1].astype(bool), out
-
-    def _sample_rows(self, slots: List[Slot], logits, iteration: int):
-        """Sample one token for each given slot from (n, V) logits
-        through the logit pipeline (a completed prompt's first
-        token)."""
-        return self._sample(list(enumerate(slots)), len(slots), logits,
-                            iteration, "prefill")
-
-    def _sample_all_slots(self, logits, iteration: int):
-        """Full-pool variant with inert defaults on non-active rows, so
-        the decode-path sampler always sees the same (B, V) shape; only
-        ACTIVE rows mean anything (inactive rows compute garbage by
-        design)."""
-        return self._sample(
-            [(s.index, s) for s in self.scheduler.active_slots()],
-            self._rows, logits, iteration, "decode")
+        for _, s in rows:
+            s.dispatched += 1
+            s.token_on_device = True
+        return out
 
     def _lp_echo(self, s: Slot, row: np.ndarray):
         """Decode one sampler echo row into the (chosen logprob,
@@ -3863,6 +4194,10 @@ class ServingEngine:
                 self._q_acc.pop(rid, None)
         preserved = list(self.scheduler.queue)
         self._resumed = []
+        # what was in flight belongs to requests failed above, and its
+        # device state is as untrusted as the pool: dropped, not read
+        self._inflight, self._firsts = None, []
+        self._last_out = self._no_rows_sampled()
         if self._tier is not None:
             # host-cached prefixes are as untrusted as the device pool
             # they were captured from (a poisoned page demotes with a

@@ -118,6 +118,15 @@ class Slot:
     # token's logprob and top-N (id, logprob) pairs per emitted token
     token_logprobs: Optional[list] = None
     top_logprobs: Optional[list] = None
+    # the late read (serving/engine.py:step): tokens whose sampler call
+    # has been DISPATCHED for this request, read or not (positions and
+    # the key chain count from it; ``len(generated)`` is what the host
+    # has read), and whether the newest of them sits in the engine's
+    # device buffer of sampled rows, so the next step takes it from
+    # there and not from the host (False for a resumed or imported slot
+    # and after a speculative block: the host alone knows the token)
+    dispatched: int = 0
+    token_on_device: bool = False
 
     @property
     def prompt_len(self) -> int:
@@ -144,6 +153,8 @@ class Slot:
         self.penalty_counts = None
         self.token_logprobs = None
         self.top_logprobs = None
+        self.dispatched = 0
+        self.token_on_device = False
 
 
 def _pow2_chunk(n: int, cap: int) -> int:
@@ -399,6 +410,8 @@ class Scheduler:
             slot.penalty_counts = None
             slot.token_logprobs = None
             slot.top_logprobs = None
+            slot.dispatched = 0
+            slot.token_on_device = False
             slot.submit_time = t_submit
             slot.deadline = deadline
             slot.trace = trace

@@ -603,12 +603,14 @@ def test_engine_span_names_args_and_the_iteration_rule(served):
         assert all(_inside(s, p) for s in calls + firsts)
         assert all({"iteration", "size"} == set(c[3]) for c in calls)
         assert all({"iteration"} == set(f[3]) for f in firsts)
-        # the sampler call of a completed prompt, taken apart
+        # the sampler call of a completed prompt, taken apart: dispatched
+        # and not read there (ISSUE 41: the token is read with the next
+        # read of the decode path, inside `sample`)
         for f in firsts:
             parts = [c for n in SUB_SPANS - {"decode_h2d", "decode_dispatch"}
                      for c in by[n] if _inside(c, f)]
             assert sorted(c[0] for c in parts) == [
-                "sample_dispatch", "sample_operands", "token_read"]
+                "sample_dispatch", "sample_operands"]
             assert all(c[3]["path"] == "prefill"
                        and c[3]["iteration"] == args["iteration"]
                        for c in parts)
@@ -620,10 +622,12 @@ def test_engine_span_names_args_and_the_iteration_rule(served):
         map(len, PROMPTS))
     assert len(by["first_token"]) == len(PROMPTS)
     # every stamped span lies between its iteration's schedule start and
-    # the end of its emit (or of its prefill, where nothing decoded yet)
+    # the end of its emit (or of its prefill, where nothing decoded yet,
+    # or of its sample, where a step was dispatched and nothing was in
+    # flight to read and emit: the first step of the late read)
     sched = {s[3]["iteration"]: s for s in by["schedule"]}
     last = {}
-    for s in by["emit"] + by["prefill"]:
+    for s in by["emit"] + by["prefill"] + by["sample"]:
         it = s[3]["iteration"]
         last[it] = max(last.get(it, 0.0), s[2])
     for name in STAMPED | SUB_SPANS:
@@ -710,8 +714,11 @@ def test_tracing_off_sends_every_site_to_the_noop_tracer(monkeypatch):
             assert set(args) == {"iteration", "chunks"}
         if name == "decode":
             # `attend_rows` (PR 33) is one number, which the engine's
-            # counter `decode_attend_rows` takes whether traced or not
-            assert set(args) == {"iteration", "active", "attend_rows"}
+            # counter `decode_attend_rows` takes whether traced or not;
+            # `lookahead` and `inflight_dropped` (ISSUE 41) are the loop's
+            # own state, counted in `engine.stats` either way
+            assert set(args) == {"iteration", "active", "attend_rows",
+                                 "lookahead", "inflight_dropped"}
         # ISSUE 38: the bytes and the counts of use cost a loop each
         if name == "decode_h2d":
             assert set(args) == {"iteration"}
@@ -752,27 +759,45 @@ def test_sub_spans_lie_inside_their_parents_and_cover_them(served):
             "decode_h2d", "decode_dispatch"]
         assert all(c[3]["iteration"] == d[3]["iteration"] for c in parts)
         h2d = next(c for c in parts if c[0] == "decode_h2d")
-        # tokens, pos (int32) and mask (bool) of the pool's rows
+        # ONE packed int32 array (ISSUE 41): token, position, active and
+        # from_host a row of the pool
         rows = 4  # the toy's slots
         assert set(h2d[3]) == {"iteration", "arrays", "bytes"}
-        assert (h2d[3]["arrays"], h2d[3]["bytes"]) == (3, rows * (4 + 4 + 1))
+        assert (h2d[3]["arrays"], h2d[3]["bytes"]) == (1, rows * 4 * 4)
+    dispatched, read_late = 0, 0
     for smp in by["sample"]:
         parts = _children(by, smp, SUB_SPANS)
-        assert [c[0] for c in sorted(parts, key=lambda c: c[1])] == [
-            "sample_operands", "sample_dispatch", "token_read"]
-        assert all(c[3]["iteration"] == smp[3]["iteration"]
-                   and c[3]["path"] == "decode" for c in parts)
+        # the step's sampler call, then the ONE read: of the iteration
+        # before (`of_iteration`), which the first step has none of and
+        # the last iteration, with nothing left to dispatch, is all of
+        names = [c[0] for c in sorted(parts, key=lambda c: c[1])]
+        reads = [c for c in parts if c[0] == "token_read"]
+        assert names[:len(names) - len(reads)] in (
+            ["sample_operands", "sample_dispatch"], []), names
+        # the first tokens of the iteration before are read (and emitted)
+        # ahead of its step's rows: `path` tells the two reads apart
+        assert [c[3]["path"] for c in reads] in (
+            [], ["decode"], ["prefill"], ["prefill", "decode"]), names
+        assert all(c[3]["iteration"] == smp[3]["iteration"] for c in parts)
+        assert all(c[3]["path"] == "decode" for c in parts
+                   if c[0] != "token_read")
+        dispatched += "sample_dispatch" in names
+        for c in reads:
+            assert c[3]["of_iteration"] < c[3]["iteration"]
+            read_late += c[3]["path"] == "decode"
+    assert read_late >= dispatched - 1 > 0
     # every sub-span has a parent of the right name
     for name, parents in (("decode_h2d", ("decode",)),
                           ("decode_dispatch", ("decode",)),
                           ("sample_operands", ("sample", "first_token")),
                           ("sample_dispatch", ("sample", "first_token")),
-                          ("token_read", ("sample", "first_token"))):
+                          ("token_read", ("sample",))):
         for c in by[name]:
             held = [p for n in parents for p in by[n] if _inside(c, p)]
             assert len(held) == 1, (name, c)
             assert held[0][3]["iteration"] == c[3]["iteration"]
-            if "decode" not in parents:  # the sampler's say which call
+            if "decode" not in parents and name != "token_read":
+                # the sampler's dispatch says which call it is
                 assert c[3]["path"] == (
                     "prefill" if held[0][0] == "first_token" else "decode")
     assert _cover(by, "decode", ("decode_h2d", "decode_dispatch")) >= 0.9
@@ -872,6 +897,66 @@ def test_the_toy_engines_plain_calls_are_what_the_reader_counts():
     assert calls == len(MIXED) + NEW_TOKENS - 1
     assert harness._reader_for("sampler_plain_calls_pct")(run) == (
         pytest.approx(100.0 / calls))
+
+
+def test_lookahead_reader_by_hand_and_with_nothing_to_read(monkeypatch,
+                                                           tmp_path):
+    """ISSUE 41: ``decode_lookahead_iters_pct`` is the share of the
+    window's ``decode`` spans whose ``lookahead`` is 1, over those that
+    carry the argument; a program from before the late read (the
+    hand-built run as it stands), a run without a record and a span after
+    the window give it nothing."""
+    read = harness._reader_for("decode_lookahead_iters_pct")
+    run = _host_share_cases.hand_built_run(monkeypatch, tmp_path)
+    steps = sum(1 for n, *_ in run.spans.spans if n == "decode")
+    assert steps == 2 and read(run) is None
+    # the first step had to read first, the second was dispatched ahead
+    depth = iter((0, 1))
+    run.spans.spans = [
+        (n, a, b, dict(args, lookahead=next(depth)) if n == "decode"
+         else args) for n, a, b, args in run.spans.spans]
+    run.spans.spans.append(("decode", 1.5, 1.6, {"lookahead": 1}))
+    got = read(run)
+    assert got == pytest.approx(50.0) and isinstance(got, float)
+    run.spans = None
+    assert read(run) is None
+    entry = next(m for m in harness.load_benchmark()["per_layer"]
+                 if m["name"] == "decode_lookahead_iters_pct")
+    assert entry["workloads"] == list(_host_share_cases.SERVE_CELLS) + [
+        DSV2_CELL]
+    decl = harness.load_json("layer_metrics",
+                             "decode_lookahead_iters_pct.json")
+    assert (decl["unit"], decl["layer"], decl["moves"]) == (
+        entry["unit"], entry["layer"], entry["moves"]) == (
+            "%", "engine loop", "itl_mean_ms")
+    assert (entry["source"], entry["better"]) == ("program_counter", "higher")
+
+
+@pytest.mark.parametrize("traffic", ["greedy", "mixed"])
+def test_the_toy_engines_lookahead_is_what_the_reader_counts(traffic):
+    """The engine's own record through the reader: greedy rows that ask
+    nothing take the late read in every decode step (100, no row dropped);
+    with a penalised row among them (MIXED) every step it is live in
+    reads first (0)."""
+    rec = SpanRecorder()
+    if traffic == "greedy":
+        _drive(rec, params=[SamplingParams(max_new_tokens=NEW_TOKENS,
+                                           temperature=0.0)] * len(MIXED))
+    else:
+        _drive(rec)
+    run = harness.Run(harness.find_cell(harness.load_benchmark(),
+                                        _host_share_cases.SERVE_CELLS[0]),
+                      harness.Env([], None), spans=rec, planes=None)
+    run.values["measured_window"] = (0.0, float("inf"))
+    steps = [s[3] for s in _by_name(rec)["decode"]]
+    assert len(steps) == NEW_TOKENS - 1
+    want = 100.0 if traffic == "greedy" else 0.0
+    assert harness._reader_for("decode_lookahead_iters_pct")(run) == (
+        pytest.approx(want))
+    if traffic == "greedy":
+        assert all(s["inflight_dropped"] == 0 for s in steps)
+    else:
+        assert {s["drain"] for s in steps} == {"penalty"}
 
 
 def test_served_tokens_and_stats_do_not_depend_on_the_tracer():

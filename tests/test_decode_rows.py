@@ -62,9 +62,19 @@ from differential_transformer_replication_tpu.models.decode import (
 from differential_transformer_replication_tpu.serving.engine import (
     ServingEngine,
     _build_step_fns,
+    pack_decode_rows,
 )
 
 FAMILIES = ["control", "diff", "ndiff"]
+
+
+def _step(decode, params, tokens, pos, active, pool):
+    """The engine's decode program on tokens the host gives: every row
+    of its one packed operand says ``from_host``, so the device's record
+    of sampled rows (zeros here) is read by none."""
+    rows = jnp.asarray(pack_decode_rows(tokens, pos, active))
+    return decode(params, rows, jnp.zeros((rows.shape[0], 1), jnp.int32),
+                  pool)
 
 
 def _pallas_calls(jaxpr, out):
@@ -93,11 +103,13 @@ def test_no_ffn_or_norm_kernel_of_the_decode_program_has_a_row_grid(family):
     )
     params = jax.eval_shape(lambda: init_model(jax.random.PRNGKey(0), cfg))
     pool = jax.eval_shape(lambda: init_cache(cfg, rows))
-    ids = jax.ShapeDtypeStruct((rows,), jnp.int32)
-    active = jax.ShapeDtypeStruct((rows,), jnp.bool_)
+    # the engine's operands: the rows' one packed int32 array and the
+    # device's record of the last sampled rows
+    packed = jax.ShapeDtypeStruct((rows, 4), jnp.int32)
+    sampled = jax.ShapeDtypeStruct((rows, 13), jnp.int32)
     decode = _build_step_fns(cfg, cfg.block_size)[1]
     calls = _pallas_calls(
-        jax.make_jaxpr(decode)(params, ids, ids, active, pool).jaxpr, [])
+        jax.make_jaxpr(decode)(params, packed, sampled, pool).jaxpr, [])
 
     ffn = [c for c in calls if c[0] == FUSED_FFN_FWD]
     # one row tile (pick_block(256, 256)), the hidden width in 6 tiles of
@@ -160,8 +172,8 @@ def test_decode_step_matches_a_length_1_chunk_a_row(family, ffn, dtype):
     pos = jnp.asarray(POS, jnp.int32)
     before = _random_pool(cfg, SLOTS, 5)  # the step donates the one it gets
     decode = _build_step_fns(cfg, cfg.block_size)[1]
-    logits, pool = decode(params, tokens, pos, jnp.asarray(ACTIVE),
-                          _random_pool(cfg, SLOTS, 5))
+    logits, pool = _step(decode, params, tokens, pos, jnp.asarray(ACTIVE),
+                         _random_pool(cfg, SLOTS, 5))
     chunk = jax.jit(lambda p, t, at, row: forward_chunk(
         p, t, at, row, cfg, rope_len=cfg.block_size))
     for b in range(SLOTS):
@@ -218,8 +230,9 @@ def test_exact_verify_sub_step_is_a_plain_step_bit_for_bit(ffn, dtype):
         return jnp.concatenate([column, jnp.full((1,), fill, column.dtype)])
 
     for step in range(depth):
-        logits, pool = decode(
-            params, padded(tokens[:, step], 0), padded(pos[:, step], 0),
+        logits, pool = _step(
+            decode, params, padded(tokens[:, step], 0),
+            padded(pos[:, step], 0),
             padded(jnp.asarray(valid[:, step]), False), pool)
         np.testing.assert_array_equal(np.asarray(spec_logits[:, step]),
                                       np.asarray(logits[:SLOTS]))
@@ -462,8 +475,8 @@ def test_the_own_ring_attend_stops_at_the_highest_active_row(family, store,
     assert attend_rows(active) == read
     assert int(jax.jit(attend_rows)(jnp.asarray(active))) == read
 
-    logits, pool = decode(params, tokens, pos, jnp.asarray(active),
-                          _random_pool(cfg, POOL_ROWS, 23))
+    logits, pool = _step(decode, params, tokens, pos, jnp.asarray(active),
+                         _random_pool(cfg, POOL_ROWS, 23))
     want_logits, want_pool = whole(tokens, pos,
                                    _random_pool(cfg, POOL_ROWS, 23))
     assert decode._cache_size() == 1
@@ -487,8 +500,8 @@ def test_the_own_ring_attend_stops_at_the_highest_active_row(family, store,
             np.testing.assert_array_equal(take(have[key], idle),
                                           take(kept[key], idle), key)
     if read < POOL_ROWS:
-        other, _ = decode(params, tokens, pos, jnp.asarray(active),
-                          _random_pool(cfg, POOL_ROWS, 29))
+        other, _ = _step(decode, params, tokens, pos, jnp.asarray(active),
+                         _random_pool(cfg, POOL_ROWS, 29))
         np.testing.assert_array_equal(np.asarray(other)[read:],
                                       np.asarray(logits)[read:])
         assert not np.array_equal(np.asarray(want_logits)[read:],

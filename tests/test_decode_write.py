@@ -29,6 +29,7 @@ from differential_transformer_replication_tpu.ops.kv_write import (
 )
 from differential_transformer_replication_tpu.serving.engine import (
     _build_step_fns,
+    pack_decode_rows,
 )
 
 SLOTS = 4
@@ -40,6 +41,15 @@ MASKS = {
     "mixed": [True, False, True, False],
 }
 POS = [5, 9, 31, 0]
+
+
+def _step(decode, params, tokens, pos, active, pool):
+    """The engine's decode program on tokens the host gives: every row
+    of its one packed operand says ``from_host``, so the device's record
+    of sampled rows (zeros here) is read by none."""
+    rows = jnp.asarray(pack_decode_rows(tokens, pos, active))
+    return decode(params, rows, jnp.zeros((rows.shape[0], 1), jnp.int32),
+                  pool)
 
 
 def _cfg(family, kv, impl):
@@ -124,7 +134,7 @@ def test_decode_step_leaves_the_pool_the_old_masked_merge_left(
 
     decode = _build_step_fns(cfg, cfg.block_size)[1]
     given = _random_pool(cfg, 5)
-    logits, pool = decode(params, tokens, pos, active, given)
+    logits, pool = _step(decode, params, tokens, pos, active, given)
     for got, want in zip(pool, want_pool):
         assert sorted(got) == sorted(want)
         for key in want:
@@ -138,7 +148,7 @@ def test_decode_step_leaves_the_pool_the_old_masked_merge_left(
     # the argument was donated, and nothing reads it afterwards: the
     # next step runs on the pool that came back
     assert all(leaf.is_deleted() for c in given for leaf in c.values())
-    again, pool2 = decode(params, tokens, pos + 1, active, pool)
+    again, pool2 = _step(decode, params, tokens, pos + 1, active, pool)
     assert np.isfinite(np.asarray(again)[on]).all()
     assert all(leaf.is_deleted() for c in pool for leaf in c.values())
     assert len(pool2) == cfg.n_layer

@@ -352,8 +352,19 @@ def test_nan_logits_on_an_active_row_raise_through_the_plain_arm(row):
 
     eng._decode_fn = corrupt
     if row == "active":
-        with pytest.raises(EngineCrashError, match="non-finite"):
-            eng.step()
+        # raised where the corrupted step's row is READ (an iteration
+        # after its dispatch), naming slot and request, and nothing of
+        # that step delivered: the request holds its first token and
+        # the token of the sound step that was in flight, no third
+        rid = slot.request.request_id
+        with pytest.raises(
+            EngineCrashError,
+            match=rf"decoding slot\(s\) \[{slot.index}\] "
+                  rf"\(request\(s\) \[{rid}\]\)",
+        ):
+            eng.run()
+        assert slot.generated == _ref_greedy(params, cfg, prompt, 2)
+        assert eng.take_finished() == []
     else:
         (out,) = eng.run()
         assert out.tokens == _ref_greedy(params, cfg, prompt, 5)
@@ -973,3 +984,244 @@ def test_serve_bench_smoke():
         "shutting_down", "other",
     }
     assert all(v == 0 for v in line["errors"].values())
+
+
+# -- the late read (ISSUE 41): the host reads a sampled token one iteration
+# -- after the program that made it was dispatched ----------------------------
+
+
+def _reads_first_always(eng):
+    """The engine's own predicate patched so that every iteration reads
+    what is in flight before it builds its step (the order of every
+    iteration before the late read): no option of the engine does that."""
+    eng._reads_first = lambda rows, capturing: "test"
+    return eng
+
+
+def _jamba():
+    """A toy of the hybrid family with a recurrent state a slot, with the
+    reference's weights (tests/test_jamba.py: with a fixed 0.02 a toy only
+    repeats its last token and a state read across requests goes unseen)."""
+    bench = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import reference_jamba
+
+    toy = dict(model="jamba", vocab_size=61, n_embd=32, n_head=2, kv_heads=1,
+               n_layer=2, block_size=32, ffn_hidden=48, norm_eps=1e-6,
+               tie_embeddings=True, attn_layer_period=2, attn_layer_offset=1,
+               mamba_d_state=8, mamba_d_conv=4, mamba_expand=2,
+               mamba_dt_rank=4, compute_dtype="float32",
+               param_dtype="float32")
+    return ModelConfig(**toy), reference_jamba.make_params(7, toy)
+
+
+@lru_cache(maxsize=None)
+def _family(kind):
+    return _jamba() if kind == "jamba" else _setup(kind)
+
+
+def _pool(pool, **kw):
+    return ServingConfig(**dict(
+        dict(num_slots=3, prefill_chunk=4, prefill_budget=8), **kw,
+        **({"kv_page_size": 8} if pool == "paged" else {})))
+
+
+# a family with a recurrent state refuses paging by name
+_DEPTH_CASES = [("diff", "contiguous"), ("diff", "paged"),
+                ("control", "contiguous"), ("control", "paged"),
+                ("jamba", "contiguous")]
+
+
+def _streams(outs):
+    return [(o.tokens, o.finish_reason, o.token_logprobs, o.top_logprobs)
+            for o in outs]
+
+
+@pytest.mark.parametrize("sampling", ["greedy", "tempered"])
+@pytest.mark.parametrize("family,pool", _DEPTH_CASES)
+def test_both_depths_serve_identical_streams(family, pool, sampling):
+    """The same requests through the late read and through a read before
+    every step give identical tokens, finish reasons and log
+    probabilities: more requests than slots (slots are reused), answers
+    of 1 and 2 tokens (a row that ends by length with its token in flight
+    is known beforehand and left out of the next step), log probabilities
+    on some; with a temperature the key chain follows the tokens
+    DISPATCHED. The compiled programs of one depth serve the other."""
+    cfg, params = _family(family)
+    prompts = _prompts([5, 9, 3, 12, 7, 4], cfg.vocab_size, seed=41)
+    asks = [SamplingParams(
+        max_new_tokens=n, logprobs=lp, seed=100 + i,
+        **(dict(temperature=0.0) if sampling == "greedy"
+           else dict(temperature=0.9, top_k=7)))
+        for i, (n, lp) in enumerate(
+            [(7, 0), (1, 2), (9, 0), (2, 0), (6, 2), (8, 0)])]
+    late = ServingEngine(params, cfg, _pool(pool))
+    first = _reads_first_always(ServingEngine(params, cfg, _pool(pool)))
+    got = late.generate(prompts, params=asks)
+    # the programs are shared by every engine of this configuration (and
+    # by other tests' pools of other sizes): reading first adds none
+    compiled = late.compile_stats()
+    want = first.generate(prompts, params=asks)
+    assert first.compile_stats() == compiled
+    assert _streams(got) == _streams(want)
+    assert [len(o.tokens) for o in got] == [7, 1, 9, 2, 6, 8]
+    if sampling == "greedy" and family != "jamba":
+        for p, o in zip(prompts, got):
+            assert o.tokens == _ref_greedy(params, cfg, p, len(o.tokens))
+    assert late.stats["lookahead_steps"] > 0
+    assert late.stats["lookahead_drains"] == 0
+    assert first.stats["lookahead_steps"] == 0
+    assert first.stats["lookahead_drains"] > 0
+    for eng in (late, first):
+        assert eng.stats["lookahead_dropped_rows"] == 0
+        assert eng.stats["decode_tokens"] == sum(
+            a.max_new_tokens - 1 for a in asks)
+        assert not eng.has_work()
+        assert all(s.state == FREE for s in eng.scheduler.slots)
+    if pool == "paged":
+        st = late.page_stats()
+        assert st["free"] + st["cached"] == st["total"]
+
+
+@pytest.mark.parametrize("ends_on", ["eos", "stop_sequence"])
+@pytest.mark.parametrize("family,pool", [
+    ("control", "contiguous"), ("control", "paged"), ("jamba", "contiguous")])
+def test_a_row_that_ends_with_a_step_in_flight(family, pool, ends_on):
+    """A row that ends on a token the host could not foresee has one step
+    too many in flight: nothing is emitted after the ending token, the
+    slot (and its pages) is freed once, the row is counted as dropped,
+    and the next occupant of the slot, admitted from the queue, serves
+    what it serves alone (it reads none of the ended row's K/V or state)."""
+    cfg, params = _family(family)
+    a_p, b_p, c_p = _prompts([6, 9, 5], cfg.vocab_size, seed=43)
+    kw = dict(max_new_tokens=9, temperature=0.0)
+
+    def alone(prompt):
+        (out,) = ServingEngine(params, cfg, _pool(pool)).generate(
+            [prompt], **kw)
+        return out.tokens
+
+    a_ref, b_ref, c_ref = alone(a_p), alone(b_p), alone(c_p)
+    # end A on its third token (the first occurrence of that token or of
+    # that pair of tokens decides where it really ends)
+    if ends_on == "eos":
+        ending = dict(eos_token_id=a_ref[2])
+        cut = a_ref.index(a_ref[2]) + 1
+    else:
+        ending = dict(stop=[tuple(a_ref[1:3])])
+        cut = next(i + 2 for i in range(len(a_ref) - 1)
+                   if a_ref[i:i + 2] == a_ref[1:3])
+    eng = ServingEngine(params, cfg, _pool(pool, num_slots=2))
+    a = eng.submit(a_p, **kw, **ending)
+    b = eng.submit(b_p, **kw)
+    c = eng.submit(c_p, **kw)  # waits for the first slot that frees: A's
+    outs = {o.request_id: o for o in eng.run()}
+    assert outs[a].tokens == a_ref[:cut]
+    assert outs[a].finish_reason == ends_on
+    assert outs[b].tokens == b_ref and outs[c].tokens == c_ref
+    assert eng.stats["lookahead_dropped_rows"] >= 1
+    assert eng.stats["completed"] == 3
+    assert not eng.has_work()
+    assert all(s.state == FREE for s in eng.scheduler.slots)
+    if family == "jamba":
+        assert eng.stats["state_resets"] == 3
+    if pool == "paged":
+        st = eng.page_stats()
+        assert st["free"] + st["cached"] == st["total"]
+
+
+@pytest.mark.parametrize("asker", ["masked", "penalized"])
+def test_an_asking_row_drains_and_the_batch_returns_to_the_late_read(asker):
+    """A constrained or penalised row joining a greedy batch makes every
+    step it is live in read first (its FSM cursor or histogram advances on
+    the host, token by token), by cause in the ``decode`` span; when it
+    leaves the batch returns to the late read. Both requests serve what
+    they serve alone."""
+    cfg, params = _setup("control")
+    vocab = _LETTERS
+    long_p, ask_p = _prompts([7, 5], cfg.vocab_size, seed=44)
+    long_kw = dict(max_new_tokens=20, temperature=0.0)
+    ask_kw = dict(max_new_tokens=6, temperature=0.0, **(
+        dict(regex="[a-f]{8,12}") if asker == "masked"
+        else dict(repetition_penalty=1.7, presence_penalty=0.4)))
+
+    def alone(prompt, kw):
+        (out,) = ServingEngine(params, cfg, ServingConfig(num_slots=2),
+                               vocab=vocab).generate([prompt], **kw)
+        return out.tokens
+
+    long_ref, ask_ref = alone(long_p, long_kw), alone(ask_p, ask_kw)
+    rec = _SpanArgs()
+    eng = ServingEngine(params, cfg, ServingConfig(num_slots=2),
+                        vocab=vocab, tracer=rec)
+    # the programs are shared by every engine of this configuration: the
+    # engines above compiled this pool's shapes
+    compiled = eng.compile_stats()
+    long_id = eng.submit(long_p, **long_kw)
+    for _ in range(4):
+        eng.step()
+    ask_id = eng.submit(ask_p, **ask_kw)
+    outs = {o.request_id: o for o in eng.run()}
+    assert outs[long_id].tokens == long_ref
+    assert outs[ask_id].tokens == ask_ref
+    steps = [args for name, args in rec.spans if name == "decode"]
+    depth = [s["lookahead"] for s in steps]
+    cause = {"masked": "mask", "penalized": "penalty"}[asker]
+    assert [s.get("drain") for s in steps if not s["lookahead"]] == (
+        [cause] * depth.count(0))
+    # late, then reading first while the asking row is live, then late
+    first, last = depth.index(0), len(depth) - depth[::-1].index(0)
+    assert 0 < first and last < len(depth)
+    assert set(depth[first:last]) == {0}
+    assert depth.count(0) == ask_kw["max_new_tokens"] - 1
+    assert eng.stats["lookahead_drains"] == depth.count(0)
+    assert eng.stats["lookahead_steps"] == depth.count(1)
+    assert eng.stats["lookahead_dropped_rows"] == 0
+    assert all(s.get("inflight_dropped", 0) == 0 for s in steps)
+    assert eng.compile_stats() == compiled
+
+
+def test_spans_are_stamped_with_the_host_s_iteration_and_the_read_says_whose():
+    """Every span carries the iteration the HOST is in when it runs (an
+    iteration is taken from its first to its last stamped span); the late
+    read carries the iteration its work was dispatched in as
+    ``of_iteration``, one less; ``first_token`` holds the sampler's
+    dispatch and no read."""
+    cfg, params = _setup("control")
+    rec = _SpanArgs()
+    eng = ServingEngine(params, cfg, ServingConfig(num_slots=2), tracer=rec)
+    eng.generate(_prompts([5, 8], cfg.vocab_size, seed=45),
+                 max_new_tokens=5, temperature=0.0)
+    names = [name for name, _ in rec.spans]
+    reads = [args for name, args in rec.spans if name == "token_read"]
+    assert reads and all(
+        r["of_iteration"] == r["iteration"] - 1 for r in reads)
+    # a read's first tokens come first (their programs ended a decode
+    # step before the step's rows are there), one read for both prompts
+    assert [r["path"] for r in reads[:2]] == ["prefill", "decode"]
+    assert {r["path"] for r in reads[2:]} == {"decode"}
+    stamped = [args["iteration"] for name, args in rec.spans
+               if "iteration" in args]
+    assert stamped == sorted(stamped)
+    # the first token is dispatched, not read, inside `first_token`
+    at = names.index("first_token")
+    assert names[at + 1:at + 3] == ["sample_operands", "sample_dispatch"]
+    assert names[at + 3] != "token_read"
+
+
+@pytest.mark.parametrize("seed", [0, 1, 41, 2**31 - 1, 2**31, 2**32 - 1,
+                                  2**32 + 5, 123456789012])
+def test_submit_mints_the_key_jax_would_without_a_program(seed):
+    """``submit`` mints a request's key on the host (a program on the chip
+    and a blocking read of it would queue behind the step in flight on
+    every arrival): word for word ``jax.random.PRNGKey(seed)``."""
+    from differential_transformer_replication_tpu.serving import engine as E
+
+    want = np.asarray(jax.random.PRNGKey(seed))
+    got = E._prng_key_words(seed)
+    assert got.dtype == np.uint32 and got.tolist() == want.tolist()
+    cfg, params = _setup("control")
+    eng = ServingEngine(params, cfg, ServingConfig(num_slots=1))
+    rid = eng.submit([1, 2, 3], max_new_tokens=2, seed=seed)
+    assert eng._base_keys[rid].tolist() == want.tolist()

@@ -468,18 +468,31 @@ def test_outputs_finished_before_mid_step_crash_survive():
     """A request that finishes EARLY in a step whose decode then
     crashes is already retired from the scheduler — invisible to both
     the lost-list and the preserved queue. take_finished() must hand it
-    back, or its caller hangs forever (code-review regression)."""
+    back, or its caller hangs forever (code-review regression). Since
+    the late read (ISSUE 41) the error is raised where the corrupted
+    step's rows are READ, an iteration after the one that corrupted the
+    slot: what is pinned is the slot, the request, and that nothing of
+    that step was delivered while the prompt that finished before it
+    was."""
     cfg, params = _setup("control")
     eng = ServingEngine(params, cfg, _serving(num_slots=2))
     p_long, p_short = _prompts([5, 4], cfg.vocab_size, seed=40)
     rid_b = eng.submit(p_long, max_new_tokens=16, temperature=0.0)
     eng.step()  # B prefills + goes ACTIVE
+    (slot_b,) = eng.scheduler.active_slots()
     faults.arm(f"serve_corrupt@{eng.stats['iterations']}")
-    # A finishes during next step's PREFILL phase (single token); the
-    # corruption then poisons ACTIVE B and the decode raises
+    # A's one token is sampled in the next step's PREFILL phase; the
+    # corruption then poisons ACTIVE B and its decode step is dispatched
     rid_a = eng.submit(p_short, max_new_tokens=1, temperature=0.0)
-    with pytest.raises(EngineCrashError):
-        eng.step()
+    with pytest.raises(
+        EngineCrashError,
+        match=rf"decoding slot\(s\) \[{slot_b.index}\] "
+              rf"\(request\(s\) \[{rid_b}\]\)",
+    ):
+        eng.run()
+    # B holds what was sampled before the corruption (its first token
+    # and the sound step dispatched with it), nothing of the step after
+    assert slot_b.generated == _ref_greedy(params, cfg, p_long, 2)
     outs = eng.take_finished()
     assert [o.request_id for o in outs] == [rid_a]
     assert outs[0].finish_reason == "length"
@@ -980,3 +993,127 @@ def test_serve_bench_http_smoke_reports_error_breakdown():
         "queue_full", "engine_crash", "deadline", "timeout",
         "shutting_down", "other",
     }
+
+
+# -- the late read (ISSUE 41): what reaches the engine from outside while a
+# -- decode step is in flight ---------------------------------------------------
+
+
+def _with_a_step_in_flight(eng, rid, n=3):
+    """Step until request ``rid`` has been read ``n`` tokens and a step
+    that holds its row is dispatched and not read."""
+    for _ in range(200):
+        slot = eng._slot_for(rid)
+        if (slot is not None and len(slot.generated) >= n
+                and eng._inflight is not None
+                and any(r == rid for _, r in eng._inflight.rows)):
+            assert slot.dispatched == len(slot.generated) + 1
+            return slot
+        eng.step()
+    raise AssertionError(f"request {rid} never had a step in flight")
+
+
+@pytest.mark.parametrize("event", ["cancel", "deadline", "preempt_resume",
+                                   "export_import", "reset_after_crash"])
+def test_what_reaches_the_engine_with_a_step_in_flight(event):
+    """A cancel, a deadline, a preemption and its resume, an export and
+    its import on a peer, and a crash reset, each arriving while a decode
+    step that holds the request's row is dispatched and not yet read:
+    the row's in-flight token is dropped (cancel, deadline, reset) or
+    read first (what snapshots the slot's host state whole), no token
+    reaches a caller twice or after the end, the neighbour serves what
+    it serves alone, and every slot and page comes back once."""
+    cfg, params = _setup("control")
+    a_p, b_p = _prompts([6, 9], cfg.vocab_size, seed=61)
+    a_ref = _ref_greedy(params, cfg, a_p, 12)
+    b_ref = _ref_greedy(params, cfg, b_p, 12)
+    kw = dict(max_new_tokens=12, temperature=0.0)
+    paged = dict(kv_page_size=8, kv_pool_pages=12)
+    serving = {
+        "cancel": _serving(num_slots=2),
+        "deadline": _serving(num_slots=2),
+        "preempt_resume": _serving(num_slots=2, host_tier_bytes=1 << 30,
+                                   **paged),
+        "export_import": _serving(num_slots=2, **paged),
+        "reset_after_crash": _serving(num_slots=1),
+    }[event]
+    eng = ServingEngine(params, cfg, serving)
+    a = eng.submit(a_p, **kw)
+    b = eng.submit(b_p, **kw)
+    slot = _with_a_step_in_flight(eng, a)
+    read = list(slot.generated)
+    assert read == a_ref[:len(read)]
+    compiled = eng.compile_stats()  # shared with other tests' pools
+    outs = {}
+
+    if event == "cancel":
+        assert eng.cancel(a) is True
+        assert slot.state == FREE and eng._inflight is not None
+        outs = {o.request_id: o for o in eng.run()}
+        assert a not in outs
+        assert eng.stats["lookahead_dropped_rows"] == 1
+    elif event == "deadline":
+        slot.deadline = time.perf_counter() - 1.0
+        outs = {o.request_id: o for o in eng.run()}
+        assert outs[a].finish_reason == "deadline"
+        assert outs[a].tokens == read  # nothing after what was read
+        assert eng.stats["lookahead_dropped_rows"] == 1
+    elif event == "preempt_resume":
+        eng._preempt_slot(slot)  # what the scheduler's hook calls
+        assert eng.stats["preemptions"] == 1 and slot.state == FREE
+        # the step in flight was read first: the snapshot holds its token
+        assert eng._resume[a]["generated"] == a_ref[:len(read) + 1]
+        outs = {o.request_id: o for o in eng.run()}
+        assert eng.stats["resumes"] == 1
+        assert outs[a].tokens == a_ref
+        assert eng.stats["lookahead_dropped_rows"] == 0
+    elif event == "export_import":
+        dst = ServingEngine(params, cfg, serving)
+        blob = eng.export_slot_state(a)
+        assert len(slot.generated) == len(read) + 1  # read first
+        assert eng.release_migrated(a) is True
+        new = dst.import_state(blob)
+        (moved,) = dst.run()
+        assert moved.request_id == new and moved.tokens == a_ref
+        outs = {o.request_id: o for o in eng.run()}
+        assert a not in outs
+    else:  # reset_after_crash: B still waits in the queue (one slot)
+        assert eng.reset_after_crash() == [a]
+        assert eng._inflight is None and not eng._firsts
+        assert eng.take_finished() == []
+        outs = {o.request_id: o for o in eng.run()}
+        assert a not in outs
+        assert eng.stats["engine_restarts"] == 1
+
+    assert outs[b].tokens == b_ref and outs[b].finish_reason == "length"
+    assert not eng.has_work() and eng._inflight is None
+    assert all(s.state == FREE for s in eng.scheduler.slots)
+    # nothing compiled for it; the paged programs of a swap or a copy
+    # apart, which these events run for the first time
+    assert eng.compile_stats()["decode"] == compiled["decode"]
+    if eng.page_stats() is not None:
+        st = eng.page_stats()
+        assert st["free"] + st["cached"] == st["total"]
+
+
+def test_a_read_outstanding_is_work_and_a_step_with_nothing_to_dispatch_reads_it():
+    """``has_work()`` stays true while a dispatched step is unread, with
+    no request left in the scheduler, and the ``step`` that finds nothing
+    to dispatch reads it: the last token of the last request is delivered
+    without another arrival, and a cancelled request's is dropped."""
+    cfg, params = _setup("control")
+    prompt = _prompts([5], cfg.vocab_size, seed=62)[0]
+    eng = ServingEngine(params, cfg, _serving())
+    rid = eng.submit(prompt, max_new_tokens=3, temperature=0.0)
+    done = []
+    while not done:
+        # its last token dispatched, the slot waits for the read alone
+        done = eng.step()
+        assert eng.has_work() == (not done)
+    assert done[0].tokens == _ref_greedy(params, cfg, prompt, 3)
+    rid = eng.submit(prompt, max_new_tokens=8, temperature=0.0)
+    _with_a_step_in_flight(eng, rid, n=2)
+    assert eng.cancel(rid) is True
+    assert not eng.scheduler.has_work() and eng.has_work()
+    assert eng.step() == [] and not eng.has_work()
+    assert eng.stats["lookahead_dropped_rows"] == 1

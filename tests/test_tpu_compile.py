@@ -435,6 +435,16 @@ def test_decode_program_keeps_its_name_and_names_its_kernels(topo, impl):
             "kv_merge"} <= scopes_in(text)
 
 
+def _lower_decode(decode, place, params, slots, cache):
+    """The engine's decode program lowered with the operands the engine
+    hands it: ONE packed (slots, 4) int32 host operand (token | position |
+    active | from_host a row) and the device's record of the last sampled
+    rows, the sampler's packed output (13 columns at the default echo
+    width), which the program takes the tokens from."""
+    return decode.lower(params, place(sds((slots, 4), jnp.int32)),
+                        place(sds((slots, 13), jnp.int32)), cache)
+
+
 def _compile_decode(topo, impl, slots, kv="auto"):
     """The engine's decode program of the diff recipe's widths, two
     layers deep, compiled for the described chip: (compiled, the
@@ -454,10 +464,9 @@ def _compile_decode(topo, impl, slots, kv="auto"):
     params = place(jax.eval_shape(lambda k: init_model(k, cfg),
                                   jax.random.PRNGKey(0)))
     cache = place(jax.eval_shape(lambda: init_cache(cfg, slots)))
-    ints = place(sds((slots,), jnp.int32))
     decode = _build_step_fns(cfg, cfg.block_size)[1]
-    return decode.lower(params, ints, ints, place(sds((slots,), jnp.bool_)),
-                        cache).compile(), cache
+    return (_lower_decode(decode, place, params, slots, cache).compile(),
+            cache)
 
 
 _HLO_DTYPES = {"bfloat16": "bf16", "int8": "s8", "float32": "f32"}
@@ -541,6 +550,47 @@ def test_decode_program_updates_the_pool_in_place(topo, impl, kv):
     assert not offenders, offenders
 
 
+def test_decode_program_takes_its_tokens_on_the_device(topo, monkeypatch):
+    """ISSUE 41: the decode program with the merged token operand lowers
+    for the chip at the chat cell's pool (256 slots): beside the weights
+    and the pool it takes exactly two operands, the ONE packed int32 host
+    operand (token | position | active | from_host a row) and the device's
+    record of the last sampled rows, and the pool is still updated in
+    place. And the engine keeps that ONE compiled program over iterations
+    at both depths: the same operand shapes whether the record is the
+    step before's output, a first token written over a row, or the zeros
+    of a fresh engine (a tiny engine on the CPU; the depth is forced by
+    patching the engine's own predicate)."""
+    compiled, cache = _compile_decode(topo, "xla", slots=256)
+    head = compiled.as_text().split("\n", 1)[0]
+    layout = head.split("entry_computation_layout={(", 1)[1].split(")->")[0]
+    ints = re.findall(r"s32\[([\d,]*)\]", layout)
+    assert sorted(ints) == ["256,13", "256,4"], ints
+    assert "pred[" not in layout  # the active mask rides the packed operand
+    leaves = jax.tree_util.tree_leaves(cache)
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in leaves)
+
+    from differential_transformer_replication_tpu.config import ServingConfig
+    from differential_transformer_replication_tpu.models import init_model
+    from differential_transformer_replication_tpu.serving.engine import (
+        ServingEngine,
+    )
+
+    monkeypatch.undo()  # the engine below RUNS, on the CPU: interpret mode
+    cfg = ModelConfig(model="control", vocab_size=67, n_embd=32, n_head=2,
+                      n_layer=1, block_size=32, compute_dtype="float32")
+    eng = ServingEngine(init_model(jax.random.PRNGKey(0), cfg), cfg,
+                        ServingConfig(num_slots=3))
+    late = eng._reads_first
+    for depth in (late, lambda rows, capturing: "test", late):
+        eng._reads_first = depth
+        eng.generate([list(range(n, 2 * n)) for n in (4, 7)],
+                     max_new_tokens=5, temperature=0.0)
+    assert eng.stats["lookahead_steps"] and eng.stats["lookahead_drains"]
+    assert eng.compile_stats()["decode"] == 1
+
+
 # -- the jamba family at the published widths (AI21-Jamba2-3B) ----------------
 
 JAMBA = dict(model="jamba", vocab_size=65536, n_embd=2560, n_head=20,
@@ -581,11 +631,10 @@ def _compile_jamba(topo, slots, n_layer=4):
     params = place(jax.eval_shape(lambda k: init_model(k, cfg),
                                   jax.random.PRNGKey(0)))
     cache = place(jax.eval_shape(lambda: init_cache(cfg, slots)))
-    ints, scalar = place(sds((slots,), jnp.int32)), place(sds((), jnp.int32))
+    scalar = place(sds((), jnp.int32))
     prefill, decode = engine._build_step_fns(cfg, cfg.block_size)[:2]
     return (
-        decode.lower(params, ints, ints, place(sds((slots,), jnp.bool_)),
-                     cache).compile(),
+        _lower_decode(decode, place, params, slots, cache).compile(),
         # the engine always gives this family `valid` (a padded tail)
         prefill.lower(params, cache, scalar, place(sds((1, 64), jnp.int32)),
                       scalar, scalar).compile(),
@@ -675,11 +724,11 @@ def test_kimi_linear_programs_update_the_pool_in_place(topo):
     params = place(jax.eval_shape(lambda k: init_model(k, cfg),
                                   jax.random.PRNGKey(0)))
     cache = place(jax.eval_shape(lambda: init_cache(cfg, slots)))
-    ints, scalar = place(sds((slots,), jnp.int32)), place(sds((), jnp.int32))
+    scalar = place(sds((), jnp.int32))
     prefill, decode = engine._build_step_fns(cfg, cfg.block_size)[:2]
     programs = {
-        "decode": decode.lower(params, ints, ints,
-                               place(sds((slots,), jnp.bool_)), cache).compile(),
+        "decode": _lower_decode(decode, place, params, slots,
+                                cache).compile(),
         "prefill": prefill.lower(params, cache, scalar,
                                  place(sds((1, 256), jnp.int32)), scalar,
                                  scalar).compile(),
@@ -744,11 +793,11 @@ def test_afmoe_programs_update_the_pool_of_two_ring_lengths_in_place(topo):
     params = place(jax.eval_shape(lambda k: init_model(k, cfg),
                                   jax.random.PRNGKey(0)))
     cache = place(jax.eval_shape(lambda: init_cache(cfg, slots)))
-    ints, scalar = place(sds((slots,), jnp.int32)), place(sds((), jnp.int32))
+    scalar = place(sds((), jnp.int32))
     prefill, decode = engine._build_step_fns(cfg, cfg.block_size)[:2]
     programs = {
-        "decode": decode.lower(params, ints, ints,
-                               place(sds((slots,), jnp.bool_)), cache).compile(),
+        "decode": _lower_decode(decode, place, params, slots,
+                                cache).compile(),
         "prefill": prefill.lower(params, cache, scalar,
                                  place(sds((1, 1024), jnp.int32)), scalar,
                                  scalar).compile(),
@@ -812,11 +861,11 @@ def test_deepseek_v2_programs_update_the_latent_pool_in_place(topo):
     params = place(jax.eval_shape(lambda k: init_model(k, cfg),
                                   jax.random.PRNGKey(0)))
     cache = place(jax.eval_shape(lambda: init_cache(cfg, slots)))
-    ints, scalar = place(sds((slots,), jnp.int32)), place(sds((), jnp.int32))
+    scalar = place(sds((), jnp.int32))
     prefill, decode = engine._build_step_fns(cfg, cfg.block_size)[:2]
     programs = {
-        "decode": decode.lower(params, ints, ints,
-                               place(sds((slots,), jnp.bool_)), cache).compile(),
+        "decode": _lower_decode(decode, place, params, slots,
+                                cache).compile(),
         "prefill": prefill.lower(params, cache, scalar,
                                  place(sds((1, 1024), jnp.int32)), scalar,
                                  scalar).compile(),
