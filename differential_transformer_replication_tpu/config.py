@@ -19,7 +19,7 @@ from dataclasses import dataclass, field
 from typing import Any, Optional, Tuple
 
 MODEL_KINDS = ("control", "diff", "ndiff", "jamba", "kimi_linear", "afmoe",
-               "deepseek_v2")
+               "deepseek_v2", "nemotron_h")
 
 # Fields only the ``jamba`` family reads. Another family given one of them
 # at a value other than its default is refused by name: a field that is
@@ -59,8 +59,30 @@ DEEPSEEK_V2_FIELDS = (
     "first_dense_layers", "routed_scaling", "held_experts", "n_group",
     "topk_group", "n_shared_experts",
 )
+# Fields only the ``nemotron_h`` family reads (the K/V heads, the norm's
+# eps, the convolution's taps and the state's dtype it shares with
+# ``jamba``, the experts' fields with ``kimi_linear``), refused the same
+# way. The last two are the published multi-token-prediction module's,
+# which this family refuses by name (:meth:`_check_nemotron_h_fields`).
+NEMOTRON_H_FIELDS = (
+    "kv_heads", "norm_eps", "mamba_d_conv", "ssm_state_dtype",
+    "hybrid_override_pattern", "mamba_num_heads", "mamba_head_dim",
+    "n_groups", "ssm_state_size", "chunk_size", "moe_latent_size",
+    "moe_shared_hidden", "mlp_act", "num_experts", "experts_per_token",
+    "moe_hidden", "routed_scaling", "held_experts",
+    "num_nextn_predict_layers", "mtp_hybrid_override_pattern",
+)
 FAMILY_FIELDS = {"jamba": JAMBA_FIELDS, "kimi_linear": KIMI_LINEAR_FIELDS,
-                 "afmoe": AFMOE_FIELDS, "deepseek_v2": DEEPSEEK_V2_FIELDS}
+                 "afmoe": AFMOE_FIELDS, "deepseek_v2": DEEPSEEK_V2_FIELDS,
+                 "nemotron_h": NEMOTRON_H_FIELDS}
+# ``hybrid_override_pattern`` as the published config.json spells it, a
+# letter a layer, and the mixer kind ``ModelConfig.layer_kinds`` gives each:
+# a Mamba-2 mixer, grouped-query attention without positions (read in
+# blocks as afmoe's full layers are), or no mixer at all (the layer is its
+# expert feed-forward part alone)
+NEMOTRON_H_LAYERS = {"M": "mamba2", "*": "full", "E": "none"}
+# the mixer kinds that keep a recurrent state a slot and no ring
+RECURRENT_KINDS = ("mamba", "kda", "mamba2")
 # the keys of a YaRN ``rope_scaling`` block, as the published config.json
 # spells them (``type`` beside them says "yarn")
 YARN_KEYS = ("factor", "beta_fast", "beta_slow", "mscale", "mscale_all_dim",
@@ -270,6 +292,42 @@ class ModelConfig:
     n_group: int = 1
     topk_group: int = 1
     n_shared_experts: int = 1
+    # -- the ``nemotron_h`` family's fields (NEMOTRON_H_FIELDS;
+    # models/nemotron_h.py) -------------------------------------------------
+    # The published ``hybrid_override_pattern``, a letter a layer: ``M`` a
+    # Mamba-2 (SSD) mixer, ``*`` grouped-query attention without positions,
+    # ``E`` an expert feed-forward part. A layer is ONE of the three, under
+    # one norm: a mixer layer has no feed-forward part and an ``E`` layer
+    # no mixer and no cache.
+    hybrid_override_pattern: str = ""
+    # Mamba-2: ``mamba_num_heads`` heads of ``mamba_head_dim`` channels,
+    # each head with ONE decay and a state of (head_dim, ssm_state_size)
+    # float32; ``B`` and ``C`` are shared by the heads of a group
+    # (``n_groups`` of them); the convolution (``mamba_d_conv`` taps) runs
+    # over x, B and C together; the gated RMSNorm norms a group's
+    # channels; a prompt is scanned in chunks of ``chunk_size`` tokens as
+    # matrix products (ops/ssd.py).
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 64
+    n_groups: int = 1
+    ssm_state_size: int = 128
+    chunk_size: int = 128
+    # Experts in a latent: one projection a layer enters ``moe_latent_size``
+    # (0 = the experts read the hidden state), the routed experts are
+    # ``moe_latent_size -> moe_hidden -> moe_latent_size``, one projection
+    # leaves it; the shared expert, ``moe_shared_hidden`` wide, reads the
+    # hidden state. ``mlp_act`` names the experts' activation as the
+    # published config does: ``"silu"`` is the gated ``silu(gate) * up`` of
+    # the other families (their only value), ``"relu2"`` the UNGATED
+    # ``relu(up) ** 2`` of this one (its only value: the experts' leaves
+    # hold ``up``, not ``gate_up``, and ``ops/moe.py`` follows the leaves).
+    moe_latent_size: int = 0
+    moe_shared_hidden: int = 0
+    mlp_act: str = "silu"
+    # The published multi-token-prediction module (a draft head beside the
+    # language model). Left out: any value but the default is refused.
+    num_nextn_predict_layers: int = 0
+    mtp_hybrid_override_pattern: str = ""
 
     def __post_init__(self):
         if self.model not in MODEL_KINDS:
@@ -285,6 +343,7 @@ class ModelConfig:
         self._check_kimi_linear_fields()
         self._check_afmoe_fields()
         self._check_deepseek_v2_fields()
+        self._check_nemotron_h_fields()
         self._check_expert_fields()
         if self.attention_impl not in ("xla", "pallas"):
             raise ValueError(
@@ -366,8 +425,10 @@ class ModelConfig:
 
     def _check_expert_fields(self):
         """The fields of a family whose later layers hold routed experts
-        (kimi_linear, afmoe, deepseek_v2)."""
-        if self.model not in ("kimi_linear", "afmoe", "deepseek_v2"):
+        (kimi_linear, afmoe, deepseek_v2) or whose ``E`` layers do
+        (nemotron_h)."""
+        if self.model not in ("kimi_linear", "afmoe", "deepseek_v2",
+                              "nemotron_h"):
             return
         for name in ("moe_hidden", "experts_per_token"):
             if getattr(self, name) < 1:
@@ -377,7 +438,9 @@ class ModelConfig:
                 f"first_dense_layers ({self.first_dense_layers}) must lie in "
                 f"[0, n_layer = {self.n_layer}]"
             )
-        if self.first_dense_layers < self.n_layer:
+        if ("E" in self.hybrid_override_pattern
+                if self.model == "nemotron_h"
+                else self.first_dense_layers < self.n_layer):
             lo, hi = self.held_expert_range
             if self.num_experts < self.experts_per_token:
                 raise ValueError(
@@ -483,6 +546,78 @@ class ModelConfig:
                     f"groups of {size} experts: a group is what one device "
                     "of the expert-parallel stage holds")
 
+    def _check_nemotron_h_fields(self):
+        if self.model != "nemotron_h":
+            return
+        for name in ("attention_impl", "ffn_impl", "decode_attention_impl"):
+            if getattr(self, name) != "xla":
+                raise ValueError(
+                    f"the nemotron_h family takes {name}='xla' only, got "
+                    f"{getattr(self, name)!r}: the kernels that 'pallas' "
+                    "selects (ops/flash.py, ops/fused_ffn.py, "
+                    "ops/decode_attention.py) know neither grouped K/V "
+                    "heads, a layer without a mixer nor experts. The "
+                    "option chooses nothing for this family: its decode "
+                    "step reads the attention layers' rings through "
+                    "ring_gqa_decode_fwd (ops/ring_attention.py) and "
+                    "advances the Mamba-2 states through "
+                    "ssm_ssd_state_update (ops/ssd.py) whatever it says"
+                )
+        if self.num_nextn_predict_layers or self.mtp_hybrid_override_pattern:
+            raise ValueError(
+                "the nemotron_h family leaves the multi-token-prediction "
+                "module out (num_nextn_predict_layers, "
+                "mtp_hybrid_override_pattern): it is a draft head beside "
+                "the language model whose published config gives its layer "
+                "pattern and not how it joins the next token's embedding "
+                "to the hidden state, and serving it is speculative "
+                "decoding over a recurrent state, which needs a snapshot "
+                "of that state that the engine does not take"
+            )
+        if self.ssm_state_dtype != "float32":
+            raise ValueError(
+                "ssm_state_dtype must be 'float32' (no narrower recurrent "
+                f"state is tested or measured), got {self.ssm_state_dtype!r}"
+            )
+        if self.dropout:
+            raise ValueError("the nemotron_h family has no dropout")
+        pattern = self.hybrid_override_pattern
+        if len(pattern) != self.n_layer or any(
+                c not in NEMOTRON_H_LAYERS for c in pattern):
+            raise ValueError(
+                f"hybrid_override_pattern must name each of the "
+                f"{self.n_layer} layers as one of "
+                f"{sorted(NEMOTRON_H_LAYERS)} (a Mamba-2 mixer, attention, "
+                f"an expert feed-forward part), got {pattern!r}"
+            )
+        if self.n_embd % self.n_head or self.n_head % self.n_kv_head:
+            raise ValueError(
+                f"n_embd ({self.n_embd}) must divide by n_head "
+                f"({self.n_head}) and n_head by kv_heads ({self.n_kv_head})"
+            )
+        for name in ("mamba_num_heads", "mamba_head_dim", "n_groups",
+                     "ssm_state_size", "chunk_size"):
+            if getattr(self, name) < 1:
+                raise ValueError(f"{name} must be >= 1, got {getattr(self, name)}")
+        if self.mamba_num_heads % self.n_groups:
+            raise ValueError(
+                f"mamba_num_heads ({self.mamba_num_heads}) must divide into "
+                f"n_groups ({self.n_groups}) groups of heads that share B "
+                "and C")
+        if self.mamba_d_conv < 2:
+            raise ValueError("mamba_d_conv must be >= 2 (a carried window)")
+        if "E" in pattern and self.mlp_act != "relu2":
+            raise ValueError(
+                "the nemotron_h family's experts are the published ungated "
+                f"relu2 (mlp_act='relu2'), got {self.mlp_act!r}: the gated "
+                "silu form is the other families'")
+        if "E" in pattern and (self.moe_latent_size < 0
+                               or self.moe_shared_hidden < 1):
+            raise ValueError(
+                "an expert layer needs moe_shared_hidden >= 1 (the shared "
+                "expert's width) and moe_latent_size >= 0, got "
+                f"{self.moe_shared_hidden} and {self.moe_latent_size}")
+
     def _check_jamba_fields(self):
         if self.model != "jamba":
             return
@@ -532,8 +667,8 @@ class ModelConfig:
 
     @property
     def resolved_norm_eps(self) -> float:
-        """eps of the jamba, kimi_linear, afmoe and deepseek_v2 families'
-        RMSNorm."""
+        """eps of the jamba, kimi_linear, afmoe, deepseek_v2 and nemotron_h
+        families' RMSNorm."""
         return self.norm_eps or 1e-6
 
     @property
@@ -544,6 +679,17 @@ class ModelConfig:
     @property
     def dt_rank(self) -> int:
         return self.mamba_dt_rank or -(-self.n_embd // 16)
+
+    @property
+    def ssd_inner(self) -> int:
+        """Channels of a Mamba-2 mixer: heads times a head's width."""
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def ssd_conv_channels(self) -> int:
+        """What a Mamba-2 mixer's convolution runs over: x beside every
+        group's B and C."""
+        return self.ssd_inner + 2 * self.n_groups * self.ssm_state_size
 
     @property
     def cannot_roll(self) -> bool:
@@ -557,7 +703,7 @@ class ModelConfig:
         they see EVERY earlier one: a rolled ring of latents would make
         them sliding-window layers, which the model is not."""
         return self.model in ("diff", "jamba", "kimi_linear", "afmoe",
-                              "deepseek_v2")
+                              "deepseek_v2", "nemotron_h")
 
     def ring_len(self, kind: str) -> int:
         """Positions the ring of a layer of mixer ``kind`` holds a slot
@@ -609,7 +755,12 @@ class ModelConfig:
     def mlp_kinds(self) -> Tuple[str, ...]:
         """``"dense"`` or ``"moe"`` for every layer, 0-based: a model with
         experts holds them in the layers past the first
-        ``first_dense_layers``."""
+        ``first_dense_layers``. A ``nemotron_h`` layer has a feed-forward
+        part only where its pattern says ``E``: ``"moe"`` there and
+        ``"none"`` in a mixer layer."""
+        if self.model == "nemotron_h":
+            return tuple("moe" if c == "E" else "none"
+                         for c in self.hybrid_override_pattern)
         if not self.num_experts:
             return ("dense",) * self.n_layer
         return tuple("dense" if i < self.first_dense_layers else "moe"
@@ -620,8 +771,13 @@ class ModelConfig:
         (jamba), ``"kda"`` or ``"mla"`` (kimi_linear), ``"window"`` or
         ``"full"`` (afmoe), ``"latent"`` (deepseek_v2: MLA with a rotary
         key part, whose ring is read in blocks as far as it is live,
-        where ``"mla"`` reads it whole). The reference families attend in
-        every layer."""
+        where ``"mla"`` reads it whole); ``"mamba2"``, ``"full"`` or
+        ``"none"`` (nemotron_h: a layer that is an expert feed-forward part
+        alone has no mixer and keeps no cache). The reference families
+        attend in every layer."""
+        if self.model == "nemotron_h":
+            return tuple(NEMOTRON_H_LAYERS[c]
+                         for c in self.hybrid_override_pattern)
         if self.model == "deepseek_v2":
             return ("latent",) * self.n_layer
         if self.model == "afmoe":
@@ -649,7 +805,7 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         if self.model in ("control", "jamba", "kimi_linear", "afmoe",
-                          "deepseek_v2"):
+                          "deepseek_v2", "nemotron_h"):
             return self.n_embd // self.n_head
         return self.n_embd // (self.n_head * 2)
 
@@ -658,7 +814,7 @@ class ModelConfig:
         """Per-head value width: doubled for differential variants
         (diff_transformer.py:30, Ndiff_transformer.py:59)."""
         if self.model in ("control", "jamba", "kimi_linear", "afmoe",
-                          "deepseek_v2"):
+                          "deepseek_v2", "nemotron_h"):
             return self.head_size
         return self.head_size * 2
 

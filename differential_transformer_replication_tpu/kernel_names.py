@@ -74,6 +74,11 @@ KV_ROW_WRITE = "kv_row_write"
 SSM_SCAN_FWD = "ssm_scan_fwd"
 SSM_STATE_UPDATE = "ssm_state_update"
 
+# ops/ssd.py (the nemotron_h family's Mamba-2 mixers: the decode step's
+# one-token update of the active slots' states, a (slot, group of heads) a
+# grid step, in place in the pool)
+SSD_STATE_UPDATE = "ssm_ssd_state_update"
+
 # ops/kda.py (the kimi_linear family's KDA mixers: the decode step's
 # one-token update of the active slots' states, in place in the pool)
 KDA_STATE_UPDATE = "kda_state_update"
@@ -93,7 +98,7 @@ FUSED_FFN = (FUSED_FFN_FWD, FUSED_FFN_BWD)
 FUSED_NORM = (FUSED_ADD_NORM_FWD, FUSED_ADD_NORM_BWD)
 DECODE = (DECODE_ATTENTION,)
 KV_WRITE = (KV_ROW_WRITE,)
-SSM = (SSM_SCAN_FWD, SSM_STATE_UPDATE)
+SSM = (SSM_SCAN_FWD, SSM_STATE_UPDATE, SSD_STATE_UPDATE)
 KDA = (KDA_STATE_UPDATE,)
 MOE = (MOE_GROUPED_MATMUL,)
 

@@ -50,7 +50,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from differential_transformer_replication_tpu.config import ModelConfig
+from differential_transformer_replication_tpu.config import (
+    RECURRENT_KINDS,
+    ModelConfig,
+)
 from differential_transformer_replication_tpu.models.generate import sample_token
 from differential_transformer_replication_tpu.models import (
     afmoe,
@@ -58,6 +61,7 @@ from differential_transformer_replication_tpu.models import (
     common,
     jamba,
     kimi_linear,
+    nemotron_h,
 )
 from differential_transformer_replication_tpu.ops import (
     apply_rope,
@@ -101,17 +105,27 @@ def _uses_rope(cfg: ModelConfig) -> bool:
 # Pool-batch axis of each cache leaf: K (and its scales) carry the
 # stream axis first, V does not. The single source of truth for every
 # per-slot slice/scatter/merge over the cache pytree (serving/engine.py).
-# ``ssm`` and ``conv`` are a Mamba layer's leaves (the jamba family),
-# ``kda`` and ``conv`` a KDA layer's (kimi_linear): a slot's recurrent
-# state, not a ring over positions. ``latent`` is an MLA layer's ring of
+# ``ssm`` and ``conv`` are a Mamba layer's leaves (the jamba family's
+# Mamba-1 and the nemotron_h family's Mamba-2 mixers), ``kda`` and ``conv``
+# a KDA layer's (kimi_linear): a slot's recurrent state, not a ring over
+# positions. A layer without a mixer (a nemotron_h ``E`` layer) holds no
+# leaf at all: its entry in the cache is an empty dict, which every walk
+# over ``layer.items()`` passes by. ``latent`` is an MLA layer's ring of
 # latents (B, 1, M, rank + rope): one "head" that every query head reads.
 KV_CACHE_BATCH_AXIS = {"k": 1, "v": 0, "k_scale": 1, "v_scale": 0,
                        "ssm": 0, "conv": 0, "kda": 0, "latent": 0}
 STATE_LEAVES = ("ssm", "conv", "kda")
+# a block's leaf that holds its token mixer; a block with none of them is a
+# feed-forward part alone (a nemotron_h ``E`` layer) and keeps no cache
+MIXER_LEAVES = ("attn", "mamba", "mamba2", "kda", "mla")
+
+
+def _has_mixer(blk: dict) -> bool:
+    return any(leaf in blk for leaf in MIXER_LEAVES)
 # the families whose layers are of several kinds (:func:`_hybrid_chunk`),
 # each with the module that holds its ``embed``
 HYBRID = {"jamba": jamba, "kimi_linear": kimi_linear, "afmoe": afmoe,
-          "deepseek_v2": deepseek_v2}
+          "deepseek_v2": deepseek_v2, "nemotron_h": nemotron_h}
 # Ring positions that a prefill chunk's blocked attention
 # (:func:`_attend_ring_blocked`) reads at a time
 ATTEND_KEY_BLOCK = 1024
@@ -119,10 +133,10 @@ ATTEND_KEY_BLOCK = 1024
 
 def has_recurrent_state(cfg: ModelConfig) -> bool:
     """Whether a sequence's cache holds state that every token overwrites
-    (a Mamba or a KDA layer's): such a slot has to be zeroed before a new
-    sequence enters it, where a ring is simply masked by positions. Told
-    by what the layers keep, not by the family's name."""
-    return any(kind in ("mamba", "kda") for kind in cfg.layer_kinds())
+    (a Mamba, a Mamba-2 or a KDA layer's): such a slot has to be zeroed
+    before a new sequence enters it, where a ring is simply masked by
+    positions. Told by what the layers keep, not by the family's name."""
+    return any(kind in RECURRENT_KINDS for kind in cfg.layer_kinds())
 
 
 def kv_store_dtype(cfg: ModelConfig) -> str:
@@ -253,15 +267,23 @@ def init_cache(cfg: ModelConfig, batch_size: int) -> list:
     ``deepseek_v2`` family's every layer likewise). The ``afmoe``
     family's rings are of two lengths in one slot (``cfg.ring_len``): a
     full layer's ``block_size`` long, a sliding layer's
-    ``sliding_ring``."""
+    ``sliding_ring``. The ``nemotron_h`` family's layers are ONE of three
+    things: a Mamba-2 layer holds ``{ssm (B, N, heads x P) float32, conv
+    (B, K-1, Di + 2 n_groups N)}``, an attention layer K/V rings with
+    ``kv_heads`` heads, and an expert layer, which has no mixer, ``{}``:
+    nothing to keep, to reset or to write."""
     S = _n_streams(cfg)
     H, d, dv = cfg.n_kv_head, cfg.head_size, cfg.value_size
     store = kv_store_dtype(cfg)
     cache = []
     for kind in cfg.layer_kinds():
         M = cfg.ring_len(kind)
-        if kind == "mamba":
-            conv, ssm = jamba.zero_state(cfg, batch_size)
+        if kind == "none":
+            cache.append({})
+            continue
+        if kind in ("mamba", "mamba2"):
+            family = jamba if kind == "mamba" else nemotron_h
+            conv, ssm = family.zero_state(cfg, batch_size)
             cache.append({"ssm": ssm, "conv": conv})
             continue
         if kind == "kda":
@@ -661,7 +683,7 @@ def _rings(cfg: ModelConfig, one) -> dict:
     (a family's rings are all read in blocks or none is)."""
     by_len, out = {}, {}
     for kind in cfg.layer_kinds():
-        if kind in ("mamba", "kda") or kind in out:
+        if kind in RECURRENT_KINDS or kind == "none" or kind in out:
             continue
         M = cfg.ring_len(kind)
         if M not in by_len:
@@ -749,6 +771,13 @@ def _mixer_chunk(x, blk: dict, layer_cache: dict, cfg: ModelConfig, pos,
                 h, blk["mamba"], cfg, layer_cache["conv"],
                 layer_cache["ssm"], valid)
         return a, {"ssm": ssm, "conv": conv}
+    if "mamba2" in blk:
+        with jax.named_scope("ssm"):
+            h = jamba.norm(x, blk["ln1"], cfg)
+            a, conv, ssm = nemotron_h.mixer_chunk(
+                h, blk["mamba2"], cfg, layer_cache["conv"],
+                layer_cache["ssm"], valid)
+        return a, {"ssm": ssm, "conv": conv}
     if "kda" in blk:
         with jax.named_scope("kda"):
             h = jamba.norm(x, blk["ln1"], cfg)
@@ -778,9 +807,15 @@ def _mixer_chunk(x, blk: dict, layer_cache: dict, cfg: ModelConfig, pos,
     with jax.named_scope("attn_norm"):
         h = jamba.norm(x, blk["ln1"], cfg)
     if kind in BLOCKED_KINDS:
+        # afmoe's attention is gated and normed a head (its leaves hold
+        # ``wg``); nemotron_h's is jamba's plain grouped-query one
+        gated = "wg" in blk["attn"]
         with jax.named_scope("attn"):
-            q, k, v, g = afmoe.qkvg(h, blk["attn"], cfg,
-                                    pos + jnp.arange(x.shape[1]), kind)
+            if gated:
+                q, k, v, g = afmoe.qkvg(h, blk["attn"], cfg,
+                                        pos + jnp.arange(x.shape[1]), kind)
+            else:
+                q, k, v = jamba.qkv(h, blk["attn"])
             with jax.named_scope("kv_write"):
                 write = (_write_chunk_wrapping if kind == "window"
                          else _write_chunk)
@@ -789,6 +824,8 @@ def _mixer_chunk(x, blk: dict, layer_cache: dict, cfg: ModelConfig, pos,
                 o = _attend_ring_blocked(
                     q, layer_cache["k"][0].astype(q.dtype),
                     layer_cache["v"].astype(q.dtype), pos, ring.window)
+            if not gated:
+                return o @ blk["attn"]["out"]["w"].astype(q.dtype), layer_cache
             a = afmoe.gate_out(o, g, blk["attn"])
         with jax.named_scope("attn_norm"):
             return jamba.norm(a, blk["ln1_post"], cfg), layer_cache
@@ -804,7 +841,11 @@ def _mixer_chunk(x, blk: dict, layer_cache: dict, cfg: ModelConfig, pos,
 
 def _mlp(x, blk: dict, cfg: ModelConfig, live=None):
     """A layer's MLP of either kind on the residual ``x``: ``(x + y, the
-    held experts' load or None)``."""
+    held experts' load or None)``; a layer that is a mixer alone (no
+    ``ffn`` and no ``moe`` leaf: a nemotron_h ``M`` or ``*`` layer) has no
+    second half and hands ``x`` back."""
+    if "ffn" not in blk and "moe" not in blk:
+        return x, None
     if "ln2_post" in blk:  # afmoe: either kind between two norms
         return afmoe.mlp(x, blk, cfg, live)
     if "moe" in blk:
@@ -842,10 +883,12 @@ def _hybrid_chunk(params: dict, tokens: jnp.ndarray, pos, cache: list,
     new_cache = []
     for blk, layer_cache, kind in zip(params["blocks"], cache,
                                       cfg.layer_kinds()):
-        a, layer_cache = _mixer_chunk(x, blk, layer_cache, cfg, pos, kind,
-                                      rings.get(kind), valid)
+        if _has_mixer(blk):  # else the layer's empty entry goes on as it is
+            a, layer_cache = _mixer_chunk(x, blk, layer_cache, cfg, pos,
+                                          kind, rings.get(kind), valid)
+            x = x + a
         new_cache.append(layer_cache)
-        x, _ = _mlp(x + a, blk, cfg)
+        x, _ = _mlp(x, blk, cfg)
     if valid is not None:
         x = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
     with jax.named_scope("lm_head"):
@@ -861,6 +904,13 @@ def _mixer_step(x, blk: dict, layer_cache: dict, cfg: ModelConfig, live,
             h = jamba.norm(x, blk["ln1"], cfg)
             a, conv, ssm = jamba.mixer_step(
                 h, blk["mamba"], cfg, layer_cache["conv"],
+                layer_cache["ssm"], live)
+        return a, {"ssm": ssm, "conv": conv}
+    if "mamba2" in blk:
+        with jax.named_scope("ssm"):
+            h = jamba.norm(x, blk["ln1"], cfg)
+            a, conv, ssm = nemotron_h.mixer_step(
+                h, blk["mamba2"], cfg, layer_cache["conv"],
                 layer_cache["ssm"], live)
         return a, {"ssm": ssm, "conv": conv}
     if "kda" in blk:
@@ -889,7 +939,7 @@ def _mixer_step(x, blk: dict, layer_cache: dict, cfg: ModelConfig, live,
                         h[:, None], blk["mla"], cfg,
                         layer_cache["latent"][:, 0], ring.visible)[:, 0]
         return a, layer_cache
-    gated = kind in BLOCKED_KINDS
+    gated = "wg" in blk["attn"]  # afmoe's; nemotron_h's is jamba's plain one
     with jax.named_scope("attn_norm"):
         h = jamba.norm(x, blk["ln1"], cfg)
     with jax.named_scope("attn"):
@@ -901,13 +951,15 @@ def _mixer_step(x, blk: dict, layer_cache: dict, cfg: ModelConfig, live,
             layer_cache = _write_ring(
                 layer_cache, _store_rows(layer_cache, k[None], v), ring.at)
         k_c, v_c = _dequant_layer(layer_cache, q.dtype)
-        if not gated:
+        if kind not in BLOCKED_KINDS:
             a = jamba.attend(q[:, None], k_c[0], v_c, ring.visible)[:, 0] @ blk[
                 "attn"]["out"]["w"].astype(q.dtype)
             return a, layer_cache
         with jax.named_scope("attn_" + kind):
             # a row's live ring blocks alone, not every slot's ring whole
             o = ring_decode_attention(q, k_c[0], v_c, pos, live, ring.window)
+        if not gated:
+            return o @ blk["attn"]["out"]["w"].astype(q.dtype), layer_cache
         a = afmoe.gate_out(o, g, blk["attn"])
     with jax.named_scope("attn_norm"):
         return jamba.norm(a, blk["ln1_post"], cfg), layer_cache
@@ -921,7 +973,11 @@ def _hybrid_decode(params: dict, tokens: jnp.ndarray, pos, cache: list,
     and read the pool (afmoe's a row's live ring blocks alone:
     ``ops/ring_attention.py``); the recurrent layers advance the active
     slots' states (``ops/ssm.py``, ``ops/kda.py``). A row that is not ``active``
-    leaves every leaf of its slot as it is and meets no expert. ``load``
+    leaves every leaf of its slot as it is and meets no expert. A layer
+    is what its leaves say: one without a mixer leaf (a nemotron_h ``E``
+    layer) runs no mixer and hands its empty cache entry on, one without
+    an ``ffn`` or ``moe`` leaf (its ``M`` and ``*`` layers) has no second
+    half. ``load``
     (3,) int32, summed over the expert layers: the (row, expert)
     assignments that fell on held experts, the largest count on one
     expert, and the held experts that got a row at all (whose weights the
@@ -946,10 +1002,12 @@ def _hybrid_decode(params: dict, tokens: jnp.ndarray, pos, cache: list,
     new_cache, loads = [], []
     for blk, layer_cache, kind in zip(params["blocks"], cache,
                                       cfg.layer_kinds()):
-        a, layer_cache = _mixer_step(x, blk, layer_cache, cfg, live, pos,
-                                     kind, rings.get(kind))
+        if _has_mixer(blk):
+            a, layer_cache = _mixer_step(x, blk, layer_cache, cfg, live,
+                                         pos, kind, rings.get(kind))
+            x = x + a
         new_cache.append(layer_cache)
-        x, load = _mlp(x + a, blk, cfg, live)
+        x, load = _mlp(x, blk, cfg, live)
         if load is not None:
             # a router limited to groups adds the rows that kept a held one
             load, *reached = load if isinstance(load, tuple) else (load,)
@@ -977,10 +1035,11 @@ def live_kv(pos: np.ndarray, active: np.ndarray, window: int) -> dict:
 
 
 def reset_slot_state(cache: list, slot) -> list:
-    """``cache`` with slot ``slot``'s recurrent state (every Mamba or KDA
-    layer's ``STATE_LEAVES``) zeroed, in place under a jit that donates
-    the pool; rings are left as they are (positions mask them). ``slot``
-    is a runtime scalar."""
+    """``cache`` with slot ``slot``'s recurrent state (every Mamba,
+    Mamba-2 or KDA layer's ``STATE_LEAVES``) zeroed, in place under a jit
+    that donates the pool; rings are left as they are (positions mask
+    them) and a layer without a mixer has nothing to zero. ``slot`` is a
+    runtime scalar."""
     return [
         {key: (jax.lax.dynamic_update_slice_in_dim(
                    leaf, jnp.zeros((1,) + leaf.shape[1:], leaf.dtype),

@@ -333,6 +333,12 @@ def mla_step_attend(h: jnp.ndarray, p: dict, cfg: ModelConfig,
 # -- the experts ---------------------------------------------------------------
 
 
+def relu2_mlp(h: jnp.ndarray, p: dict) -> jnp.ndarray:
+    """``W_down relu(W_up h)^2``, no gate and no bias."""
+    u = jax.nn.relu(h @ p["up"]["w"].astype(h.dtype))
+    return (u * u) @ p["down"]["w"].astype(h.dtype)
+
+
 def moe_mlp(h: jnp.ndarray, p: dict, cfg: ModelConfig,
             live: Optional[jnp.ndarray] = None):
     """A layer of experts on the normed rows ``h`` (.., E), ``p`` the
@@ -344,7 +350,13 @@ def moe_mlp(h: jnp.ndarray, p: dict, cfg: ModelConfig,
     correction bias among its leaves is the softmax router limited to
     groups (``ops/moe.py:route_grouped``), and the load then comes as
     ``(load, reached)``, ``reached`` () int32 the live rows that kept a
-    group this share holds."""
+    group this share holds. The ``nemotron_h`` family's is this one as
+    well: where the leaves hold ``latent_in`` and ``latent_out`` the
+    routed experts read ``h W_in`` and their weighted sum leaves through
+    ``W_out`` (the router and the shared expert read ``h``), and a shared
+    expert without a ``gate`` leaf is the ungated ``W_down relu(W_up
+    h)^2`` (:func:`relu2_mlp`), as the routed ones are where their leaves
+    hold ``up`` (``ops/moe.py:experts``)."""
     with jax.named_scope("moe"):
         rows = h.reshape(-1, h.shape[-1])
         flat = None if live is None else live.reshape(-1)
@@ -359,11 +371,19 @@ def moe_mlp(h: jnp.ndarray, p: dict, cfg: ModelConfig,
                 chosen, weights, kept = moe_ops.route_grouped(
                     rows, p["router"]["w"], cfg.experts_per_token,
                     cfg.routed_scaling, cfg.n_group, cfg.topk_group)
+        into = rows
+        if "latent_in" in p:  # graftlint: disable=GL104 (static keys)
+            with jax.named_scope("moe_latent"):
+                into = rows @ p["latent_in"].astype(rows.dtype)
         with jax.named_scope("moe_experts"):
-            y, load = moe_ops.experts(rows, chosen, weights, p["experts"],
+            y, load = moe_ops.experts(into, chosen, weights, p["experts"],
                                       lo, flat)
+        if "latent_out" in p:  # graftlint: disable=GL104 (static keys)
+            with jax.named_scope("moe_latent"):
+                y = y @ p["latent_out"].astype(rows.dtype)
         with jax.named_scope("moe_shared"):
-            y = y + gated_mlp(rows, p["shared"])
+            shared = gated_mlp if "gate" in p["shared"] else relu2_mlp
+            y = y + shared(rows, p["shared"])
         if kept is not None:
             size = cfg.expert_group_size
             mine = jnp.any(kept[:, lo // size:hi // size], axis=-1)

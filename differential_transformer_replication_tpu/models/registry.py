@@ -4,8 +4,8 @@ The reference selects models by commenting code blocks in and out
 (train.py:205-230); here it is a first-class dispatch on
 ``ModelConfig.model`` covering the same three families, and ``jamba``
 (models/jamba.py), ``kimi_linear`` (models/kimi_linear.py), ``afmoe``
-(models/afmoe.py) and ``deepseek_v2`` (models/deepseek_v2.py), whose
-layers are of several kinds.
+(models/afmoe.py), ``deepseek_v2`` (models/deepseek_v2.py) and
+``nemotron_h`` (models/nemotron_h.py), whose layers are of several kinds.
 """
 
 from __future__ import annotations
@@ -24,11 +24,12 @@ from differential_transformer_replication_tpu.models import (
     jamba,
     kimi_linear,
     ndiff,
+    nemotron_h,
 )
 
 _MODULES = {"control": control, "diff": diff, "ndiff": ndiff,
             "jamba": jamba, "kimi_linear": kimi_linear, "afmoe": afmoe,
-            "deepseek_v2": deepseek_v2}
+            "deepseek_v2": deepseek_v2, "nemotron_h": nemotron_h}
 
 
 def init_model(key: jax.Array, cfg: ModelConfig) -> dict:

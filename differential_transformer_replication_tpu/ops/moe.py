@@ -16,7 +16,9 @@ PADDED grouping: every group starts on a whole tile of ``tm`` rows, so a
 row tile belongs to one expert and a grid step is one plain matrix product
 of its rows with that expert's weights (the tile's expert rides as scalar
 prefetch and picks the weight block). The weights are what a decode step
-pays for (7 MB an expert, 3-4 rows each): consecutive tiles of one expert
+pays for (7 MB an expert and 3-4 rows each in the kimi_linear cell, 19 MB
+in deepseek_v2's, 11 MB in a latent in nemotron_h's): consecutive tiles of
+one expert
 keep its block, an expert nobody chose is never fetched, and the tiles
 behind the last group point at the last block and cost no traffic. ``tm``
 follows the rows an expert can expect, 16 in a decode step; XLA's own
@@ -152,13 +154,18 @@ def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, tile_group: jnp.ndarray,
 def experts(h: jnp.ndarray, chosen: jnp.ndarray, weights: jnp.ndarray,
             p: dict, lo: int, live: Optional[jnp.ndarray] = None):
     """``h`` (T, E); ``chosen``, ``weights`` (T, k) from :func:`route`;
-    ``p`` the held experts' weights ``gate_up`` (G, E, 2 F), ``down``
-    (G, F, E) for the experts ``lo .. lo + G - 1``; ``live`` (T,) bool,
+    ``p`` the held experts' weights for the experts ``lo .. lo + G - 1``:
+    ``gate_up`` (G, E, 2 F) and ``down`` (G, F, E), an expert
+    ``W_down(silu(gate) * up)``, or ``up`` (G, E, F) in ``gate_up``'s
+    place, an UNGATED expert ``W_down relu(W_up h)^2`` (the nemotron_h
+    family's, where ``E`` is the latent's width); ``live`` (T,) bool,
     the rows that are a sequence's (None = all). Returns ``(y (T, E) in
     h's dtype, load (G,) int32)``: the held experts' weighted sum a row,
     and how many assignments fell on each held expert."""
     T, k = chosen.shape
-    G, E, F2 = p["gate_up"].shape
+    gated = "gate_up" in p
+    w_in = p["gate_up"] if gated else p["up"]
+    G, E, F2 = w_in.shape
     tm = _row_tile(T * k, G)
     local = chosen - lo
     held = (local >= 0) & (local < G)
@@ -185,9 +192,11 @@ def experts(h: jnp.ndarray, chosen: jnp.ndarray, weights: jnp.ndarray,
         jnp.searchsorted(ends, jnp.arange(tiles) * tm, side="right"),
         G - 1).astype(jnp.int32)
     used = (ends[-1:] // tm).astype(jnp.int32)
-    gu = grouped_matmul(rows, p["gate_up"].astype(h.dtype), tile_group, used,
-                        tm)
-    act = jax.nn.silu(gu[:, :F2 // 2]) * gu[:, F2 // 2:]
+    gu = grouped_matmul(rows, w_in.astype(h.dtype), tile_group, used, tm)
+    if gated:
+        act = jax.nn.silu(gu[:, :F2 // 2]) * gu[:, F2 // 2:]
+    else:
+        act = jnp.square(jax.nn.relu(gu))
     out = grouped_matmul(act, p["down"].astype(h.dtype), tile_group, used,
                          tm, jnp.float32)
     # a row no tile computed is no product of anything: select, not scale

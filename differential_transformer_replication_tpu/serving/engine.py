@@ -229,7 +229,21 @@ _STAT_SPEC = {
     "state_resets": (
         "serving_state_resets_total",
         "Slots whose recurrent state was zeroed on admission (families "
-        "with Mamba or KDA layers; a K/V ring needs none).",
+        "with Mamba, Mamba-2 or KDA layers; a K/V ring needs none).",
+    ),
+    "decode_live_state": (
+        "serving_decode_live_state_bytes_total",
+        "Bytes of recurrent state that decode steps moved: the active "
+        "rows times a slot's state leaves (every Mamba, Mamba-2 or KDA "
+        "layer's state, read and written once a step by the update "
+        "kernels, which touch the active slots alone). 0 for a family of "
+        "rings.",
+    ),
+    "moe_experts_hit": (
+        "serving_moe_experts_hit_total",
+        "Held experts that got at least one row in a decode step, summed "
+        "over the expert layers and the steps: the experts whose weights "
+        "the grouped product had to fetch (families with routed experts).",
     ),
     "moe_held": (
         "serving_moe_held_assignments_total",
@@ -804,18 +818,26 @@ def _refuse_for_recurrent_state(cfg: ModelConfig,
                                 serving: ServingConfig) -> None:
     """The engine features that address a sequence's state BY POSITION,
     each refused by name for a family whose layers hold a recurrent state
-    (``jamba``'s Mamba layers, ``kimi_linear``'s KDA layers): a K/V ring
-    can be cut, shared, rolled back or shipped at any position, a
-    recurrent state is overwritten every token and what it was at an
-    earlier position is gone."""
-    lacks = ("the {} family keeps a recurrent state a Mamba or KDA layer, "
+    (``jamba``'s Mamba layers, ``kimi_linear``'s KDA layers,
+    ``nemotron_h``'s Mamba-2 layers): a K/V ring can be cut, shared,
+    rolled back or shipped at any position, a recurrent state is
+    overwritten every token and what it was at an earlier position is
+    gone. The message names the mixers the configuration has."""
+    mixers = " or ".join(
+        name for kind, name in (("mamba", "Mamba"), ("mamba2", "Mamba-2"),
+                                ("kda", "KDA"))
+        if kind in cfg.layer_kinds())
+    lacks = ("the {} family keeps a recurrent state a " + mixers + " layer, "
              "and {} needs a snapshot of that state at a position, which "
              "the engine does not take")
+    drafts = ("; the published multi-token-prediction module, which this "
+              "family leaves out, would be its draft head"
+              if cfg.model == "nemotron_h" else "")
     asked = (
         ("the host tier (host_tier_bytes; preemption and resume)",
          serving.host_tier_bytes > 0),
-        ("speculation (spec_mode; rejected drafts roll the cache back)",
-         serving.spec_enabled()),
+        ("speculation (spec_mode; rejected drafts roll the cache back"
+         + drafts + ")", serving.spec_enabled()),
         ("paging (kv_page_size > 0; with it the prefix cache, whose hits "
          "resume a sequence at the shared prefix's end)",
          serving.paged()),
@@ -828,7 +850,7 @@ def _refuse_for_recurrent_state(cfg: ModelConfig,
             f"kv_cache_dtype='int8' is not available for the {cfg.model} "
             "family: its attention layers' decode path reads float rings "
             "(grouped-query K/V, or MLA's latents), and a quantized "
-            "recurrent state does not exist yet (the state is float32)"
+            + mixers + " state does not exist yet (the state is float32)"
         )
 
 
@@ -1380,6 +1402,12 @@ class ServingEngine:
                              self.serving.kv_page_size)
             if self._paged else init_cache(cfg, self._rows)
         )
+        # bytes of recurrent state a slot holds, over the layers (0 for a
+        # family of rings): what a decode step moves a live row, one way
+        self._state_bytes_per_slot = sum(
+            leaf.nbytes // leaf.shape[0] for layer in self.cache
+            for key, leaf in layer.items()
+            if key in STATE_LEAVES and key != "conv")
         # The late read (:meth:`step`): the device's own record of the
         # last sampled rows (the newest decode step's packed sampler
         # output, a completed prompt's first token written over its
@@ -1483,7 +1511,7 @@ class ServingEngine:
         self.registry.gauge(
             "serving_state_pool_bytes",
             "HBM bytes of the pool's state that is no K/V ring of "
-            "block_size positions: every Mamba or KDA layer's recurrent "
+            "block_size positions: every Mamba, Mamba-2 or KDA layer's recurrent "
             "state and convolution window, every MLA layer's ring of "
             "latents, and, where a slot holds K/V rings of two lengths "
             "(afmoe), the rings of both; 0 for a family of K/V rings of "
@@ -2323,6 +2351,13 @@ class ServingEngine:
                 self._window_layers * kv["live_window"]
                 + (self.cfg.n_layer - self._window_layers)
                 * kv["live_full"])
+        if self._state_bytes_per_slot:
+            # the recurrent state the step's update kernels move: the
+            # active rows' states alone, read and written once
+            decode_args["live_state_bytes"] = (
+                int(mask.sum()) * self._state_bytes_per_slot)
+            self.stats.inc("decode_live_state",
+                           decode_args["live_state_bytes"])
         if self._latent_layers:
             # the latents the rows hold live, pos + 1 a row: what the
             # step's attention reads a layer, from the positions
@@ -2388,6 +2423,7 @@ class ServingEngine:
                     int(v) for v in np.asarray(rec.load))
                 rec.moe.update(held=held, max_expert=top, experts_hit=hit)
                 self.stats.inc("moe_held", held)
+                self.stats.inc("moe_experts_hit", hit)
                 if reached:  # a router limited to groups
                     rec.moe["rows_in_held_group"] = reached[0]
                     self.stats.inc("moe_rows_in_held_group", reached[0])
@@ -3314,7 +3350,7 @@ class ServingEngine:
             raise MigrateExportError(
                 f"live migration is not available for the {self.cfg.model} "
                 "family: the wire image ships K/V pages by position, and a "
-                "Mamba or KDA layer's recurrent state has no page to ship "
+                "layer's recurrent state has no page to ship "
                 "(it needs a snapshot of the state) — fall back to replay"
             )
         if self._window_layers:

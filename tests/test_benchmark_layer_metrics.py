@@ -59,6 +59,8 @@ globals().update({name: fn for name, fn in vars(_host_share_cases).items()
 # (`pytest benchmark/tests`) the old one fails until a `benchmark` PR
 # updates it (PERF.md section 7).
 DSV2_CELL = "serve-deepseek-v2-5l-ep8-code-chat"
+# PR 42 added a sixth, to the same lists
+NEMOTRON_CELL = "serve-nemotron3-super-11l-ep4-agent-turns"
 _SILENT = ("sampler_logprobs_ms_per_iter", "sampler_pipeline_ms_per_iter")
 
 
@@ -69,7 +71,7 @@ def test_host_share_metrics_are_declared_for_the_four_serve_cells(metric):
     entry = next(m for m in harness.load_benchmark()["per_layer"]
                  if m["name"] == metric)
     assert entry["workloads"] == cells + (
-        [] if metric in _SILENT else [DSV2_CELL])
+        [] if metric in _SILENT else [DSV2_CELL, NEMOTRON_CELL])
     decl = harness.load_json("layer_metrics", metric + ".json")
     assert (decl["unit"], decl["layer"], decl["moves"]) == (
         entry["unit"], entry["layer"], entry["moves"])
@@ -165,7 +167,7 @@ def test_name_falls_in_its_bucket_under_both_readers(family, name):
 
 
 def test_table_is_whole_and_the_metrics_needles_are_disjoint():
-    assert len(kernel_names.ALL) == len(set(kernel_names.ALL)) == 25
+    assert len(kernel_names.ALL) == len(set(kernel_names.ALL)) == 26
     assert sorted(kernel_names.ALL) == sorted(n for _, n in NAMES)
     sets = {
         "attn": ["flash"],  # every needle-reader of the flash family
@@ -183,6 +185,24 @@ def test_table_is_whole_and_the_metrics_needles_are_disjoint():
     assert all("_dattn_" in n for n in kernel_names.DECODE)
     assert not any("_dattn_" in n for n in kernel_names.ALL
                    if n not in kernel_names.DECODE)
+
+
+@pytest.mark.parametrize("metric, kernel", [
+    ("ssm_state_update_roofline", kernel_names.SSM_STATE_UPDATE),
+    ("ssm_scan_roofline", kernel_names.SSM_SCAN_FWD),
+    ("kda_state_update_roofline", kernel_names.KDA_STATE_UPDATE),
+])
+def test_an_older_state_kernel_s_needles_do_not_catch_the_mamba2_update(
+        metric, kernel):
+    """PR 42's `ssm_ssd_state_update` starts `ssm_` (its family's rule) and
+    holds none of the accepted readers' needles: the jamba cell's rooflines
+    go on reading jamba's kernels alone, and the nemotron_h cell reads its
+    own by scope (`ssm_state`), not by name."""
+    needles = _needles(metric)
+    assert any(x in kernel for x in needles)
+    assert not any(x in kernel_names.SSD_STATE_UPDATE for x in needles)
+    assert kernel_names.SSD_STATE_UPDATE.startswith("ssm_")
+    assert kernel_names.SSD_STATE_UPDATE in kernel_names.FAMILIES["ssm"]
 
 
 # -- a hand-made trace ----------------------------------------------------------
@@ -876,7 +896,7 @@ def test_plain_calls_reader_by_hand_and_with_nothing_to_read(monkeypatch,
     entry = next(m for m in harness.load_benchmark()["per_layer"]
                  if m["name"] == "sampler_plain_calls_pct")
     assert entry["workloads"] == list(_host_share_cases.SERVE_CELLS) + [
-        DSV2_CELL]
+        DSV2_CELL, NEMOTRON_CELL]
     decl = harness.load_json("layer_metrics", "sampler_plain_calls_pct.json")
     assert (decl["unit"], decl["layer"], decl["moves"]) == (
         entry["unit"], entry["layer"], entry["moves"]) == (
@@ -923,7 +943,7 @@ def test_lookahead_reader_by_hand_and_with_nothing_to_read(monkeypatch,
     entry = next(m for m in harness.load_benchmark()["per_layer"]
                  if m["name"] == "decode_lookahead_iters_pct")
     assert entry["workloads"] == list(_host_share_cases.SERVE_CELLS) + [
-        DSV2_CELL]
+        DSV2_CELL, NEMOTRON_CELL]
     decl = harness.load_json("layer_metrics",
                              "decode_lookahead_iters_pct.json")
     assert (decl["unit"], decl["layer"], decl["moves"]) == (

@@ -51,7 +51,7 @@ dattn = importlib.import_module(
 KERNEL_MODULES = (
     "ops.flash", "ops.fused_ffn", "ops.fused_norm_residual",
     "ops.decode_attention", "ops.kv_write", "ops.ssm", "ops.kda", "ops.moe",
-    "ops.ring_attention", "ops.mla",
+    "ops.ring_attention", "ops.mla", "ops.ssd",
 )
 
 # the recipe's widths (8L/768d, T=512, vocab 12000); the batch is cut, a
@@ -913,6 +913,79 @@ def _runs(comps: dict, name: str) -> set:
 
 def _op_kinds(body: str) -> set:
     return set(re.findall(r" = [^=]*?\b([a-z\-]+)\(", body))
+
+
+# -- the nemotron_h family at the published widths (Nemotron-3-Super, a share) -
+
+NEMOTRON_H = dict(
+    model="nemotron_h", vocab_size=32768, n_embd=4096, n_head=32, kv_heads=2,
+    n_layer=11, block_size=8192, norm_eps=1e-5,
+    hybrid_override_pattern="MEMEMEM*EME", mamba_num_heads=128,
+    mamba_head_dim=64, n_groups=8, ssm_state_size=128, chunk_size=128,
+    mamba_d_conv=4, num_experts=512, experts_per_token=22, moe_hidden=2688,
+    moe_latent_size=1024, moe_shared_hidden=5376, mlp_act="relu2",
+    routed_scaling=5.0, held_experts=[0, 128], param_dtype="bfloat16")
+
+
+def test_nemotron_h_programs_update_the_state_pool_in_place(topo):
+    """What the chip's compiler makes of the nemotron_h family's two
+    programs at the serve cell's own size (64 slots; five Mamba-2 states of
+    4.19 MB and one K/V ring of 8,192 positions a slot, five expert layers
+    that keep nothing; the eleven layers of the share at published widths):
+    every cache leaf is aliased input to output; the decode program names
+    its kernels (the state update, the row write, the live-block ring
+    read, the experts' grouped product) and its scopes; and the
+    temporaries of both stay far under the pool (1.9 GB) and under what
+    the chip has left beside pool and weights (4.5 GB): no state and no
+    ring is copied, and a chunk's scan holds a sub-chunk's decay maps, not
+    a state a token."""
+    from differential_transformer_replication_tpu.models import init_model
+    from differential_transformer_replication_tpu.models.decode import init_cache
+    from differential_transformer_replication_tpu.serving import engine
+
+    cfg, slots = ModelConfig(**NEMOTRON_H), 64
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = place(jax.eval_shape(lambda k: init_model(k, cfg),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_cache(cfg, slots)))
+    assert [sorted(layer) for layer in cache] == [
+        {"M": ["conv", "ssm"], "*": ["k", "v"], "E": []}[c]
+        for c in cfg.hybrid_override_pattern]
+    scalar = place(sds((), jnp.int32))
+    prefill, decode = engine._build_step_fns(cfg, cfg.block_size)[:2]
+    programs = {
+        "decode": _lower_decode(decode, place, params, slots,
+                                cache).compile(),
+        "prefill": prefill.lower(params, cache, scalar,
+                                 place(sds((1, 1024), jnp.int32)), scalar,
+                                 scalar).compile(),
+    }
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(cache))
+    assert pool_bytes == 64 * (5 * (128 * 8192 * 4 + 3 * 10240 * 2)
+                               + 2 * 2 * 8192 * 128 * 2)
+    for name, compiled in programs.items():
+        assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes, name
+    text = programs["decode"].as_text()
+    assert text.startswith("HloModule jit__decode")
+    assert assert_kernels_named(text, "_decode") == {
+        kernel_names.SSD_STATE_UPDATE, kernel_names.KV_ROW_WRITE,
+        kernel_names.MOE_GROUPED_MATMUL, kernel_names.RING_GQA_DECODE}
+    assert {"ssm", "ssm_conv", "ssm_state", "attn_norm", "attn", "attn_full",
+            "kv_write", "moe", "moe_router", "moe_latent", "moe_experts",
+            "moe_shared", "ffn_norm", "lm_head"} <= scopes_in(text)
+    assert {"ssm", "ssm_conv", "ssm_scan", "attn", "attn_full", "kv_write",
+            "moe_latent", "moe_experts"} <= scopes_in(
+                programs["prefill"].as_text())
+    assert assert_kernels_named(programs["prefill"].as_text(), "_prefill") == {
+        kernel_names.MOE_GROUPED_MATMUL}
+    for name, compiled in programs.items():
+        print(name, compiled.memory_analysis())
+    assert programs["decode"].memory_analysis().temp_size_in_bytes < 0.5e9
+    assert programs["prefill"].memory_analysis().temp_size_in_bytes < 2.0e9
 
 
 def test_sampler_is_scoped(topo):
