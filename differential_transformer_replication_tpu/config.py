@@ -157,6 +157,9 @@ class ModelConfig:
     # (jax.checkpoint): trades ~1/3 more FLOPs for O(n_layer) less
     # activation memory — the standard TPU lever for bigger micro-batches
     # or longer contexts (no reference analog; it keeps all activations).
+    # It is also how a run trades back the two (M, 4E) pre-activations
+    # the fused FFN's forward saves for its backward (ops/fused_ffn.py):
+    # under remat they live for one block, not for the whole backward.
     remat: bool = False
     # What jax.checkpoint may SAVE per block when remat is on — the
     # per-layer-group recompute policy (models/common.py REMAT_POLICIES):
@@ -165,10 +168,9 @@ class ModelConfig:
     #   "dots"       save matmul outputs (checkpoint_policies.dots_
     #                saveable): skips recomputing the MXU-bound work,
     #                recomputes only the cheap elementwise/norm chain —
-    #                the sweet spot once the FFN epilogue is fused
-    #                (fused kernels make the recompute side cheaper, so
-    #                the policy trade-off moved; sweep with
-    #                tools/ffn_sweep.py --remat-policies),
+    #                (the fused FFN's two pre-activations come out of
+    #                a kernel, not a dot, so they are replayed with it;
+    #                sweep with tools/ffn_sweep.py --remat-policies),
     #   "dots_no_batch"  dots_with_no_batch_dims_saveable (Flax's
     #                default "save the small stuff" policy),
     #   "nothing"    nothing_saveable, explicit,

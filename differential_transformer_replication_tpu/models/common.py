@@ -150,10 +150,12 @@ def apply_block_ffn(
     On the fused path the first three HBM round-trips collapse into two
     kernels: ``fused_add_norm`` produces the carried residual AND the
     normalized FFN input in one pass over the tile, and ``fused_swiglu``
-    runs the gate/xform/SiLU/product chain without materializing the
-    (M, 4E) pre-activations. The down-proj + residual stay XLA: the
-    row-parallel matmul is MXU-bound and XLA fuses the add into its
-    epilogue.
+    runs the gate/xform/SiLU/product chain in one kernel. With no
+    gradient (serving, evaluation) the (M, 4E) pre-activations never
+    reach HBM; under one the forward saves both for its backward, which
+    ``cfg.remat`` holds to one block at a time. The down-proj + residual
+    stay XLA: the row-parallel matmul is MXU-bound and XLA fuses the add
+    into its epilogue.
     """
     rate = cfg.dropout
     if use_fused_ffn(cfg, mesh):

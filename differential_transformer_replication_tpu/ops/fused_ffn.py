@@ -23,13 +23,23 @@ kernel, which owns the pre-LN at every block boundary — a standalone
 LN never precedes the FFN without a residual add in front, so there
 is deliberately no LN-in-front variant here).
 
-Backward is a custom VJP around ONE Pallas kernel that recomputes the
-pre-activations tile-by-tile (flash-style: matmul recompute is cheaper
-than an (M, 4E) x2 HBM round-trip of saved activations), produces the
-gate/xform pre-activation cotangents, and accumulates the fp32 weight
-and bias gradients in-kernel across the row grid. The two remaining
-contractions (``dg @ Wg^T + dt @ Wx^T``) run as plain XLA ops on those
-outputs — they are MXU-bound matmuls XLA already schedules well.
+Two forwards share that kernel body. The primal (serving, evaluation,
+generation: every call with no gradient) writes the hidden and nothing
+else. The forward that runs under a gradient also writes the two
+pre-activations it already holds in its fp32 accumulators, rounded once
+to the activation dtype, as the backward's residuals: on a v5e the
+(M, 4E) x2 round-trip costs about a third of what recomputing the two
+products did (PERF.md, PR 43), because the kernels are bound by the MXU
+the recompute competed for. ``ModelConfig.remat`` is how a run trades
+the two residuals back: under it they live for one block only.
+
+Backward is a custom VJP around ONE Pallas kernel that reads the saved
+pre-activations, produces the gate/xform pre-activation cotangents in
+their place (aliased), and accumulates the fp32 weight and bias
+gradients in-kernel across the row grid: the two products it owns and
+no other. The two remaining contractions (``dg @ Wg^T + dt @ Wx^T``)
+run as plain XLA ops on those outputs — they are MXU-bound matmuls XLA
+already schedules well.
 
 Interpret-mode fallback on CPU (like ops/flash.py), so the tier-1 CPU
 suite exercises the real kernel code paths.
@@ -76,42 +86,51 @@ def _pre_acts(xn, wg_ref, bg_ref, wx_ref, bx_ref):
 
 
 def _ffn_fwd_kernel(*refs):
-    x_ref, wg_ref, bg_ref, wx_ref, bx_ref, outh_ref = refs
+    """``refs`` ends in the hidden tile alone (the primal) or in the
+    hidden and both pre-activation tiles (the gradient's forward): the
+    hidden is computed from the fp32 accumulators either way."""
+    x_ref, wg_ref, bg_ref, wx_ref, bx_ref, outh_ref, *res_refs = refs
     xn = x_ref[...]
     g, t = _pre_acts(xn, wg_ref, bg_ref, wx_ref, bx_ref)
     outh_ref[...] = (g * jax.nn.sigmoid(g) * t).astype(outh_ref.dtype)
+    if res_refs:
+        g_ref, t_ref = res_refs
+        g_ref[...] = g.astype(g_ref.dtype)
+        t_ref[...] = t.astype(t_ref.dtype)
 
 
-def _specs(E, F, bm, bf):
-    """(in_specs sans gh, shared index maps) for both kernels. Grid is
-    (F//bf, M//bm) — j (hidden tile) OUTER, i (row tile) inner."""
+def _specs(E, bm, bf):
+    """(x, weight, bias, hidden) block specs, shared by both kernels.
+    Grid is (F//bf, M//bm) — j (hidden tile) OUTER, i (row tile) inner."""
     x_spec = pl.BlockSpec((bm, E), lambda j, i: (i, 0), memory_space=pltpu.VMEM)
     w_spec = pl.BlockSpec((E, bf), lambda j, i: (0, j), memory_space=pltpu.VMEM)
     b_spec = pl.BlockSpec((1, bf), lambda j, i: (0, j), memory_space=pltpu.VMEM)
     h_spec = pl.BlockSpec((bm, bf), lambda j, i: (i, j), memory_space=pltpu.VMEM)
-    in_specs = [x_spec, w_spec, b_spec, w_spec, b_spec]
-    return in_specs, x_spec, w_spec, b_spec, h_spec
+    return x_spec, w_spec, b_spec, h_spec
 
 
-def _fwd_call(x2, wg, bg2, wx, bx2, *, block_m, block_f, interpret):
+def _fwd_call(x2, wg, bg2, wx, bx2, *, block_m, block_f, interpret,
+              residuals=False):
+    """``h``, or ``(h, g, t)`` with ``residuals``: the pre-activations in
+    ``h``'s dtype, tiles and index map."""
     M, E = x2.shape
     F = wg.shape[1]
     bm = pick_block(block_m, M)
     bf = pick_block(block_f, F)
-    in_specs, *_, h_spec = _specs(E, F, bm, bf)
-    inputs = (x2, wg, bg2, wx, bx2)
+    x_spec, w_spec, b_spec, h_spec = _specs(E, bm, bf)
+    h_shape = jax.ShapeDtypeStruct((M, F), x2.dtype)
     return pl.pallas_call(
         _ffn_fwd_kernel,
         grid=(F // bf, M // bm),
-        in_specs=in_specs,
-        out_shape=jax.ShapeDtypeStruct((M, F), x2.dtype),
-        out_specs=h_spec,
+        in_specs=[x_spec, w_spec, b_spec, w_spec, b_spec],
+        out_shape=[h_shape] * 3 if residuals else h_shape,
+        out_specs=[h_spec] * 3 if residuals else h_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         name=kernel_names.FUSED_FFN_FWD,
         interpret=interpret,
-    )(*inputs)
+    )(x2, wg, bg2, wx, bx2)
 
 
 # ---------------------------------------------------------------------------
@@ -120,15 +139,16 @@ def _fwd_call(x2, wg, bg2, wx, bx2, *, block_m, block_f, interpret):
 
 
 def _ffn_bwd_kernel(*refs):
-    """Recompute g/t for one tile pair, emit the pre-activation
-    cotangents (dg, dt — consumed by the XLA ``@ W^T`` contractions for
-    dx), and accumulate fp32 dWg/dbg/dWx/dbx across the row grid while
-    the weight column blocks are resident."""
-    (x_ref, wg_ref, bg_ref, wx_ref, bx_ref, gh_ref,
+    """Read the forward's saved g/t for one tile pair, emit the
+    pre-activation cotangents over them (dg, dt — consumed by the XLA
+    ``@ W^T`` contractions for dx), and accumulate fp32 dWg/dbg/dWx/dbx
+    across the row grid while their column blocks are resident."""
+    (x_ref, g_ref, t_ref, gh_ref,
      dg_ref, dt_ref, dwg_ref, dbg_ref, dwx_ref, dbx_ref) = refs
     xn = x_ref[...]
     i = pl.program_id(1)
-    g, t = _pre_acts(xn, wg_ref, bg_ref, wx_ref, bx_ref)
+    g = g_ref[...].astype(jnp.float32)
+    t = t_ref[...].astype(jnp.float32)
     sg = jax.nn.sigmoid(g)
     silu = g * sg
     gh = gh_ref[...].astype(jnp.float32)
@@ -164,19 +184,58 @@ def _ffn_bwd_kernel(*refs):
         dbx_ref[...] += pbx
 
 
-def _bwd_call(x2, wg, bg2, wx, bx2, gh, *, block_m, block_f, interpret):
+_SCOPED_VMEM_DEFAULT = 16 << 20  # what Mosaic grants a v5e kernel unasked
+_BWD_VMEM_BUDGET = 64 << 20      # half of a v5e's VMEM
+
+
+def _bwd_vmem(E, bm, bf, itemsize):
+    """(x's, the weight gradients', the whole) VMEM footprint of the
+    backward, as Mosaic asked for it over sixteen (E, dtype, tile) cases
+    compiled for a v5e (PERF.md, PR 43) and a tenth: the x block in two
+    buffers and turned for ``x^T dg``; dWg and dWx with their bias rows
+    in two buffers and one product in flight; g, t, gh, dg, dt in two
+    buffers and three fp32 (bm, bf) temporaries."""
+    x = 4 * bm * E * itemsize
+    grads = 5 * (E + 8) * bf * 4
+    rest = 10 * bm * bf * itemsize + 3 * bm * bf * 4
+    return x, grads, int(1.1 * (x + grads + rest))
+
+
+def _bwd_tiles(E, itemsize):
+    """(block_m, block_f) of the backward, from the widths alone. With no
+    weight block in its VMEM the kernel affords a far larger tile than
+    the forward's: a wider one re-reads ``x`` fewer times (``F / bf``
+    passes), a taller one re-writes the resident weight-gradient blocks
+    fewer times. 1024 x 1024 is the recipe's (E 768, bf16; sweep and cell
+    runs on a v5e, PERF.md, PR 43); a wider ``E`` or float32 halves the
+    side whose own block weighs more until the footprint fits the
+    budget. ``pick_block`` then cuts both to divisors of the shape."""
+    bm = bf = 1024
+    while max(bm, bf) > 128:
+        x, grads, whole = _bwd_vmem(E, bm, bf, itemsize)
+        if whole <= _BWD_VMEM_BUDGET:
+            break
+        if grads > x and bf > 128:
+            bf //= 2
+        else:
+            bm //= 2
+    return bm, bf
+
+
+def _bwd_call(x2, g, t, gh, *, interpret):
     M, E = x2.shape
-    F = wg.shape[1]
+    F = g.shape[1]
+    itemsize = x2.dtype.itemsize
+    block_m, block_f = _bwd_tiles(E, itemsize)
     bm = pick_block(block_m, M)
     bf = pick_block(block_f, F)
-    in_specs, x_spec, w_spec, b_spec, h_spec = _specs(E, F, bm, bf)
-    in_specs = in_specs + [h_spec]
-    inputs = (x2, wg, bg2, wx, bx2, gh)
-    dwb_spec = pl.BlockSpec((1, bf), lambda j, i: (0, j), memory_space=pltpu.VMEM)
+    x_spec, w_spec, b_spec, h_spec = _specs(E, bm, bf)
+    # a limit, not an allocation; never under what a kernel gets unasked
+    limit = max(_bwd_vmem(E, bm, bf, itemsize)[2], _SCOPED_VMEM_DEFAULT)
     return pl.pallas_call(
         _ffn_bwd_kernel,
         grid=(F // bf, M // bm),
-        in_specs=in_specs,
+        in_specs=[x_spec, h_spec, h_spec, h_spec],
         out_shape=[
             jax.ShapeDtypeStruct((M, F), x2.dtype),       # dg
             jax.ShapeDtypeStruct((M, F), x2.dtype),       # dt
@@ -185,13 +244,17 @@ def _bwd_call(x2, wg, bg2, wx, bx2, gh, *, block_m, block_f, interpret):
             jax.ShapeDtypeStruct((E, F), jnp.float32),    # dWx
             jax.ShapeDtypeStruct((1, F), jnp.float32),    # dbx
         ],
-        out_specs=[h_spec, h_spec, w_spec, dwb_spec, w_spec, dwb_spec],
+        out_specs=[h_spec, h_spec, w_spec, b_spec, w_spec, b_spec],
+        # dg over g, dt over t: a tile is read before it is written, so
+        # the backward holds no second pair of (M, F) arrays
+        input_output_aliases={1: 0, 2: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=limit,
         ),
         name=kernel_names.FUSED_FFN_BWD,
         interpret=interpret,
-    )(*inputs)
+    )(x2, g, t, gh)
 
 
 def _dxn(dg, dt, wg, wx):
@@ -221,19 +284,21 @@ def _swiglu2(x2, wg, bg2, wx, bx2, block_m, block_f, interpret):
 
 
 def _swiglu2_fwd(x2, wg, bg2, wx, bx2, block_m, block_f, interpret):
-    h = _swiglu2(x2, wg, bg2, wx, bx2, block_m, block_f, interpret)
-    return h, (x2, wg, bg2, wx, bx2)
+    h, g, t = _fwd_call(
+        x2, wg, bg2, wx, bx2,
+        block_m=block_m, block_f=block_f, interpret=interpret,
+        residuals=True,
+    )
+    return h, (x2, wg, wx, g, t)
 
 
 def _swiglu2_bwd(block_m, block_f, interpret, res, gh):
-    x2, wg, bg2, wx, bx2 = res
-    dg, dt, dwg, dbg, dwx, dbx = _bwd_call(
-        x2, wg, bg2, wx, bx2, gh,
-        block_m=block_m, block_f=block_f, interpret=interpret,
-    )
+    x2, wg, wx, g, t = res
+    dg, dt, dwg, dbg, dwx, dbx = _bwd_call(x2, g, t, gh, interpret=interpret)
     dx = _dxn(dg, dt, wg, wx)
-    return (dx, dwg.astype(wg.dtype), dbg.astype(bg2.dtype),
-            dwx.astype(wx.dtype), dbx.astype(bx2.dtype))
+    # a bias arrives in its weight's dtype (fused_swiglu casts all four)
+    return (dx, dwg.astype(wg.dtype), dbg.astype(wg.dtype),
+            dwx.astype(wx.dtype), dbx.astype(wx.dtype))
 
 
 _swiglu2.defvjp(_swiglu2_fwd, _swiglu2_bwd)

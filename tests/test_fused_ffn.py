@@ -224,6 +224,106 @@ class TestFusedSwiGLU:
         )
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its inner jaxprs included."""
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
+
+
+def _pallas_calls(fn, *args):
+    """name -> the pallas_call equations of ``fn``'s jaxpr."""
+    calls = {}
+    for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        if e.primitive.name == "pallas_call":
+            calls.setdefault(e.params["name"], []).append(e)
+    return calls
+
+
+def _tanh_loss(x, wg, bg, wx, bx):
+    return jnp.sum(
+        jnp.tanh(fused_swiglu(x, wg, bg, wx, bx).astype(jnp.float32))
+    )
+
+
+@pytest.mark.parametrize("case", [
+    "fwd_rule_h_bitwise-fp32", "fwd_rule_h_bitwise-bf16",
+    "primal_is_one_call_one_output", "bwd_kernel_owns_two_products",
+    "remat_grads_equal",
+])
+def test_saved_pre_activations(case):
+    """The forward under a gradient hands g and t to the backward; the
+    forward without one pays nothing for them (PR 43)."""
+    from differential_transformer_replication_tpu import kernel_names
+    from differential_transformer_replication_tpu.ops import fused_ffn
+
+    if case.startswith("fwd_rule_h_bitwise"):
+        dtype = jnp.float32 if case.endswith("fp32") else jnp.bfloat16
+        x, _, _, wg, bg, wx, bx = _ffn_inputs(dtype)
+        x2 = x.reshape(-1, x.shape[-1])
+        args = (x2, wg.astype(dtype), bg.astype(dtype).reshape(1, -1),
+                wx.astype(dtype), bx.astype(dtype).reshape(1, -1),
+                8, 64, True)
+        h, (_, _, _, g, t) = fused_ffn._swiglu2_fwd(*args)
+        np.testing.assert_array_equal(
+            np.asarray(h, np.float32),
+            np.asarray(fused_ffn._swiglu2(*args), np.float32),
+        )
+        # the residuals: the pre-activations, in the activation's dtype
+        assert g.dtype == t.dtype == dtype and g.shape == t.shape == h.shape
+        for got, w, b in ((g, wg, bg), (t, wx, bx)):
+            want = x2.astype(jnp.float32) @ w.astype(dtype).astype(
+                jnp.float32) + b.astype(dtype).astype(jnp.float32)
+            _close(got, want, TOLS[dtype])
+        return
+    x, _, _, wg, bg, wx, bx = _ffn_inputs(jnp.float32)
+    if case == "primal_is_one_call_one_output":
+        # serving, evaluation and generation do not pay for the residuals
+        calls = _pallas_calls(fused_swiglu, x, wg, bg, wx, bx)
+        assert list(calls) == [kernel_names.FUSED_FFN_FWD]
+        (call,) = calls[kernel_names.FUSED_FFN_FWD]
+        assert len(call.outvars) == 1
+    elif case == "bwd_kernel_owns_two_products":
+        calls = _pallas_calls(
+            jax.grad(_tanh_loss, argnums=tuple(range(5))), x, wg, bg, wx, bx
+        )
+        (fwd,) = calls[kernel_names.FUSED_FFN_FWD]
+        (bwd,) = calls[kernel_names.FUSED_FFN_BWD]
+        assert len(fwd.outvars) == 3  # h, g, t
+        # x, g, t, gh in: no weight reaches the backward kernel, and it
+        # multiplies x with dg and with dt and nothing else
+        assert [v.aval.shape for v in bwd.invars] == [
+            (24, 32), (24, 128), (24, 128), (24, 128)]
+        dots = [e for e in _eqns(bwd.params["jaxpr"])
+                if e.primitive.name == "dot_general"]
+        assert len(dots) == 2
+        # dg over g, dt over t
+        assert tuple(bwd.params["input_output_aliases"]) == ((1, 0), (2, 1))
+    else:
+        assert case == "remat_grads_equal"
+        # under remat the forward rule runs inside the backward's replay:
+        # the residuals live for one block, the gradients are the same
+        g0 = jax.grad(_tanh_loss, argnums=tuple(range(5)))(x, wg, bg, wx, bx)
+        g1 = jax.grad(jax.checkpoint(_tanh_loss), argnums=tuple(range(5)))(
+            x, wg, bg, wx, bx)
+        for a, b in zip(g0, g1):
+            _close(b, a, GRAD_TOLS[jnp.float32])
+        cfg = ModelConfig(model="control", **TINY).replace(ffn_impl="pallas")
+        params = init_model(jax.random.PRNGKey(0), cfg)
+        idx = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 61)
+
+        def loss(p, c):
+            return model_forward(p, idx, c, targets=jnp.roll(idx, -1, -1))[1]
+
+        g0 = jax.grad(loss)(params, cfg)
+        g1 = jax.grad(loss)(params, cfg.replace(remat=True))
+        for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
+            np.testing.assert_allclose(
+                np.asarray(b), np.asarray(a), rtol=1e-5, atol=1e-6
+            )
+
+
 class TestModelParity:
     """ffn_impl='pallas' vs 'xla' through the full forward/backward for
     every family — the switch must be numerically invisible."""
@@ -665,7 +765,10 @@ class TestToolGates:
         assert r.returncode == 0, r.stderr[-2000:]
         lines = [json.loads(l) for l in r.stdout.splitlines() if l.strip()]
         cases = {d["case"] for d in lines}
-        assert cases == {"ffn_chain", "remat_step"}, cases
+        assert cases == {"ffn_kernel", "ffn_chain", "remat_step"}, cases
+        assert {"fwd", "fwd_res", "bwd"} == {
+            d["kernel"] for d in lines if d["case"] == "ffn_kernel"
+        }
         assert not any("failed" in d for d in lines), lines
         # both impls timed, so before/after deltas are diffable
         assert {"xla", "pallas"} <= {

@@ -249,6 +249,39 @@ def test_fused_ffn_and_add_norm_compile(topo):
     assert {"ffn_norm", "ffn"} <= scopes_in(text)
 
 
+def test_fused_ffn_gradient_compiles_at_the_cells_rows(topo):
+    """The SwiGLU kernels under a gradient at the row count the train
+    cells run (64 x 512 = 32,768): the backward takes its tiles and its
+    VMEM limit from the shape (ops/fused_ffn.py:_bwd_tiles), and it
+    writes dg and dt over the saved pre-activations, so the program
+    holds no second pair of (M, 4E) arrays."""
+    from differential_transformer_replication_tpu.ops.fused_ffn import (
+        fused_swiglu,
+    )
+
+    rows, F = 64, 4 * E
+
+    def loss(x, wg, bg, wx, bx):
+        return fused_swiglu(x, wg, bg, wx, bx).astype(jnp.float32).sum()
+
+    sharding = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for shape, dtype in (((rows, T, E), jnp.bfloat16),
+                                 ((E, F), jnp.float32), ((F,), jnp.float32),
+                                 ((E, F), jnp.float32), ((F,), jnp.float32))]
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    # one forward, one backward (autodiff wraps the instruction's name)
+    names = sorted(assert_kernels_named(compiled.as_text(), "loss"))
+    assert len(names) == 2, names
+    assert kernel_names.FUSED_FFN_BWD in names[1], names
+    assert kernel_names.FUSED_FFN_FWD in names[0], names
+    # live at once: h's cotangent, g/dg and t/dt, and nothing else of
+    # that size (a backward that did not alias would hold five)
+    hidden = rows * T * F * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * hidden
+
+
 # decode attention at the recipe's width over a 32-slot pool; pages of 8
 # are what the verify skill and serve_bench --smoke use, 1 is the least
 DECODE_CASES = [

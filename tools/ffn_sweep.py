@@ -1,19 +1,26 @@
 """Kernel-level sweep for the fused FFN/norm path — flash_sweep.py's
 sibling for ops/fused_ffn.py + ops/fused_norm_residual.py.
 
-Three sweep axes, each printed as one JSON line per case:
+Four sweep axes, each printed as one JSON line per case:
 
   - impl: the fused Pallas chain vs the reference XLA composition
     (layer_norm + swiglu), fwd and fwd+grad, at several (rows, width)
     shapes — the kernel-level win the ffn_impl switch buys,
-  - tiles: (block_m, block_f) candidates for the fused SwiGLU kernel,
+  - tiles: (block_m, block_f) candidates for the fused SwiGLU forward,
+  - kernels (--kernels): the three SwiGLU kernels alone — the primal
+    forward, the gradient's forward (which also writes the two
+    pre-activations) and the backward that reads them — per forward
+    tile (--tiles) and backward tile (--bwd-tiles); what
+    ops/fused_ffn.py:_bwd_tiles was chosen from,
   - remat policies: full train-step timings per ModelConfig.remat_policy
-    (--remat-policies), because the fused kernels changed the
-    recompute-vs-save trade-off the policy controls.
+    (--remat-policies): under remat the FFN's two saved pre-activations
+    live for one block and the gradient's forward runs twice.
 
 Timed regions close with ``jax.block_until_ready``, like flash_sweep.py.
 
     python tools/ffn_sweep.py [--steps 10] [--tiles 256,512 ...]
+    python tools/ffn_sweep.py --kernels --rows 32768 \
+        --bwd-tiles 256,512 256,1024 512,1024
     python tools/ffn_sweep.py --remat-policies none,dots --steps 5
     python tools/ffn_sweep.py --smoke     # tier-1 CI gate: tiny shapes,
                                           # interpret-mode kernels, ~seconds
@@ -26,6 +33,7 @@ import json
 import sys
 import time
 from pathlib import Path
+from unittest import mock
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
@@ -92,9 +100,61 @@ def bench_ffn_case(M, E, impl, tiles, steps, mode, dtype):
     return (time.perf_counter() - t0) / steps
 
 
+def bench_kernel_case(M, E, kernel, tiles, steps, dtype):
+    """One SwiGLU kernel alone at (rows, width): ``fwd`` (the primal),
+    ``fwd_res`` (the gradient's forward) under forward ``tiles``, or
+    ``bwd`` under backward ``tiles`` (None: the library's own choice).
+    The backward's g/t are donated and its dg/dt fed back, as the
+    custom VJP's aliasing has it. Returns seconds/call."""
+    from differential_transformer_replication_tpu.ops import fused_ffn
+
+    F = 4 * E
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    x = jax.random.normal(ks[0], (M, E), dtype)
+    interpret = fused_ffn.auto_interpret()
+    if kernel == "bwd":
+        bwd = jax.jit(
+            lambda x, g, t, gh: fused_ffn._bwd_call(
+                x, g, t, gh, interpret=interpret
+            ),
+            donate_argnums=(1, 2),
+        )
+        g, t, gh = (jax.random.normal(k, (M, F), dtype) for k in ks[1:])
+
+        def call(out):
+            return bwd(x, out[0], out[1], gh)
+
+        # the tiles are read when the first call traces the kernel
+        choice = fused_ffn._bwd_tiles if tiles is None else lambda *_: tiles
+        with mock.patch.object(fused_ffn, "_bwd_tiles", choice):
+            out = call((g, t))
+    else:
+        wg, wx = (jax.random.normal(k, (E, F), dtype) * 0.02 for k in ks[1:3])
+        b = jnp.zeros((1, F), dtype)
+        bm, bf = tiles or (
+            fused_ffn._DEFAULT_BLOCK_M, fused_ffn._DEFAULT_BLOCK_F
+        )
+        fwd = jax.jit(lambda x, wg, wx: fused_ffn._fwd_call(
+            x, wg, b, wx, b, block_m=bm, block_f=bf, interpret=interpret,
+            residuals=kernel == "fwd_res",
+        ))
+
+        def call(_):
+            return fwd(x, wg, wx)
+
+        out = call(None)
+    jax.block_until_ready(out)  # compiled + warm
+    t0 = time.perf_counter()
+    for _ in range(steps):
+        out = call(out)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / steps
+
+
 def bench_remat_case(policy, ffn_impl, steps, args):
-    """Full train-step seconds/step under remat with one save policy —
-    the knob the fused kernels re-opened (cheaper FFN recompute)."""
+    """Full train-step seconds/step under remat with one save policy:
+    ``off`` keeps every block's residuals (the FFN's two saved
+    pre-activations among them), a policy replays the block's forward."""
     from differential_transformer_replication_tpu.config import (
         ModelConfig,
         TrainConfig,
@@ -139,6 +199,15 @@ def main():
         help="fused-kernel tile configs as block_m,block_f "
              "(default: library default only)",
     )
+    p.add_argument(
+        "--kernels", action="store_true",
+        help="time the three SwiGLU kernels alone instead of the chain",
+    )
+    p.add_argument(
+        "--bwd-tiles", nargs="*", default=None,
+        help="backward-kernel tile configs as block_m,block_f for "
+             "--kernels (default: the library's choice only)",
+    )
     p.add_argument("--rows", default="4096,16384",
                    help="M = B*T row counts for the kernel-level sweep")
     p.add_argument("--width", type=int, default=768, help="E (hidden = 4E)")
@@ -180,16 +249,45 @@ def main():
         args.vocab_size, args.block_size, args.micro_batch = 64, 16, 2
         if args.remat_policies is None:
             args.remat_policies = "off,none,dots"
+        args.kernels, args.bwd_tiles = True, ["8,64"]
 
     dtype = jnp.dtype(args.dtype)
     failed = 0
 
-    configs = [None]
-    if args.tiles:
-        configs += [tuple(int(v) for v in t.split(",")) for t in args.tiles]
+    def parse(tiles):
+        return [None] + [
+            tuple(int(v) for v in t.split(",")) for t in tiles or []
+        ]
 
+    configs = parse(args.tiles)
+    kernel_cases = []
+    if args.kernels:
+        kernel_cases = [
+            (k, t) for k in ("fwd", "fwd_res") for t in configs
+        ] + [("bwd", t) for t in parse(args.bwd_tiles)]
+    # --kernels replaces the chain sweep (the smoke gate runs both)
+    chain_modes = args.modes.split(",")
+    if args.kernels and not args.smoke:
+        chain_modes = []
     for M in (int(s) for s in args.rows.split(",")):
-        for mode in args.modes.split(","):
+        for kernel, tiles in kernel_cases:
+            try:
+                dt = bench_kernel_case(
+                    M, args.width, kernel, tiles, args.steps, dtype
+                )
+                print(json.dumps({
+                    "case": "ffn_kernel", "rows": M, "width": args.width,
+                    "kernel": kernel, "tiles": tiles,
+                    "ms": round(dt * 1e3, 3),
+                }), flush=True)
+            except Exception as e:  # noqa: BLE001
+                failed += 1
+                print(json.dumps({
+                    "case": "ffn_kernel", "rows": M, "kernel": kernel,
+                    "tiles": tiles, "failed":
+                    f"{type(e).__name__}: {str(e)[:160]}",
+                }), flush=True)
+        for mode in chain_modes:
             for impl in args.impls.split(","):
                 for tiles in configs if impl == "pallas" else [None]:
                     try:
