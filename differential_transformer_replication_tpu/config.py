@@ -406,8 +406,13 @@ class ModelConfig:
             if getattr(self, name) != "xla":
                 raise ValueError(
                     f"the kimi_linear family runs {name}='xla' only, got "
-                    f"{getattr(self, name)!r}: the Pallas attention and FFN "
-                    "kernels know neither a latent cache nor experts"
+                    f"{getattr(self, name)!r}: the kernels that 'pallas' "
+                    "selects (ops/flash.py, ops/fused_ffn.py, "
+                    "ops/decode_attention.py) know neither a latent cache "
+                    "nor experts. The option chooses nothing for this "
+                    "family: its MLA layers read the latent ring through "
+                    "their own kernels whatever it says (ops/mla.py, "
+                    "mla_latent_decode_fwd and mla_chunk_widened_fwd)"
                 )
         if self.dropout:
             raise ValueError("the kimi_linear family has no dropout")
@@ -770,13 +775,14 @@ class ModelConfig:
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """The mixer of every layer, 0-based: ``"attention"``, ``"mamba"``
-        (jamba), ``"kda"`` or ``"mla"`` (kimi_linear), ``"window"`` or
-        ``"full"`` (afmoe), ``"latent"`` (deepseek_v2: MLA with a rotary
-        key part, whose ring is read in blocks as far as it is live,
-        where ``"mla"`` reads it whole); ``"mamba2"``, ``"full"`` or
-        ``"none"`` (nemotron_h: a layer that is an expert feed-forward part
-        alone has no mixer and keeps no cache). The reference families
-        attend in every layer."""
+        (jamba), ``"kda"`` or ``"latent"`` (kimi_linear), ``"window"`` or
+        ``"full"`` (afmoe), ``"latent"`` (deepseek_v2); ``"mamba2"``,
+        ``"full"`` or ``"none"`` (nemotron_h: a layer that is an expert
+        feed-forward part alone has no mixer and keeps no cache). The
+        reference families attend in every layer. ``"latent"`` is MLA over
+        a ring of latents, read in blocks as far as it is live
+        (ops/mla.py) whichever family keeps it: whether its key part is
+        rotated is ``mla_rotary``'s to say, not the kind's."""
         if self.model == "nemotron_h":
             return tuple(NEMOTRON_H_LAYERS[c]
                          for c in self.hybrid_override_pattern)
@@ -785,7 +791,7 @@ class ModelConfig:
         if self.model == "afmoe":
             return tuple(AFMOE_LAYER_TYPES[t] for t in self.layer_types)
         if self.model == "kimi_linear":
-            return tuple("kda" if i in self.kda_layers else "mla"
+            return tuple("kda" if i in self.kda_layers else "latent"
                          for i in range(1, self.n_layer + 1))
         if self.model != "jamba":
             return ("attention",) * self.n_layer

@@ -290,7 +290,7 @@ def init_cache(cfg: ModelConfig, batch_size: int) -> list:
             conv, state = kimi_linear.kda_zero_state(cfg, batch_size)
             cache.append({"kda": state, "conv": conv})
             continue
-        if kind in ("mla", "latent"):
+        if kind == "latent":
             cache.append({"latent": jnp.zeros(
                 (batch_size, 1, M, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
                 jnp.dtype(cfg.compute_dtype))})
@@ -569,7 +569,7 @@ def forward_chunk(
             raise ValueError(
                 f"chunk [{pos}, {pos + L}) exceeds block_size {M}: the "
                 f"{cfg.model} family's "
-                + ("layers see every earlier position"
+                + ("MLA layers see every earlier position"
                    if "latent" in cfg.layer_kinds() else
                    f"{'full ' if 'full' in cfg.layer_kinds() else ''}"
                    "attention layers carry no position")
@@ -660,8 +660,9 @@ def forward_chunk(
 #: The attention kinds that read a ring in blocks and mask each block from
 #: ``_Ring.window`` (:func:`_attend_ring_blocked`, ``ring_decode_attention``;
 #: ``models/afmoe.py``'s gated attention) or from the positions
-#: (``"latent"``, the deepseek_v2 family's MLA: ``ops/mla.py``'s blocked
-#: reads): their rings get no ``visible``.
+#: (``"latent"``, MLA over a ring of latents, the deepseek_v2 family's
+#: and kimi_linear's alike: ``ops/mla.py``'s blocked reads): their rings
+#: get no ``visible``, which is left to jamba's ``"attention"`` kind.
 BLOCKED_KINDS = ("window", "full", "latent")
 
 
@@ -796,13 +797,9 @@ def _mixer_chunk(x, blk: dict, layer_cache: dict, cfg: ModelConfig, pos,
                     layer_cache["latent"],
                     rows[:, None].astype(layer_cache["latent"].dtype),
                     (0, 0, ring.at, 0))
-            if kind in BLOCKED_KINDS:  # the widened form, in blocks
-                a = kimi_linear.mla_chunk_attend(h, blk["mla"], cfg,
-                                                 latent[:, 0], pos)
-            else:
-                with jax.named_scope("mla_attend"):
-                    a = kimi_linear.mla_attend(h, blk["mla"], cfg,
-                                               latent[:, 0], ring.visible)
+            # the widened form, the ring in blocks as far as it is written
+            a = kimi_linear.mla_chunk_attend(h, blk["mla"], cfg,
+                                             latent[:, 0], pos)
         return a, {"latent": latent}
     with jax.named_scope("attn_norm"):
         h = jamba.norm(x, blk["ln1"], cfg)
@@ -930,14 +927,9 @@ def _mixer_step(x, blk: dict, layer_cache: dict, cfg: ModelConfig, live,
                     layer_cache,
                     {"latent": rows[:, None].astype(
                         layer_cache["latent"].dtype)}, ring.at)
-            if kind in BLOCKED_KINDS:  # the absorbed form, the live blocks
-                a = kimi_linear.mla_step_attend(
-                    h, blk["mla"], cfg, layer_cache["latent"], pos, live)
-            else:
-                with jax.named_scope("mla_attend"):
-                    a = kimi_linear.mla_attend(
-                        h[:, None], blk["mla"], cfg,
-                        layer_cache["latent"][:, 0], ring.visible)[:, 0]
+            # the absorbed form, a row's live blocks alone
+            a = kimi_linear.mla_step_attend(
+                h, blk["mla"], cfg, layer_cache["latent"], pos, live)
         return a, layer_cache
     gated = "wg" in blk["attn"]  # afmoe's; nemotron_h's is jamba's plain one
     with jax.named_scope("attn_norm"):
@@ -970,8 +962,10 @@ def _hybrid_decode(params: dict, tokens: jnp.ndarray, pos, cache: list,
     """The hybrid families' decode step over the whole slot pool, one
     batch: ``((B, V) logits, updated cache, expert load)``. The attention
     layers write their row into the ring in place (``ops/kv_write.py``)
-    and read the pool (afmoe's a row's live ring blocks alone:
-    ``ops/ring_attention.py``); the recurrent layers advance the active
+    and read the pool (afmoe's and nemotron_h's a row's live ring blocks
+    alone, ``ops/ring_attention.py``, an MLA layer's a row's live latent
+    blocks, ``ops/mla.py``; jamba's every ring whole under a mask); the
+    recurrent layers advance the active
     slots' states (``ops/ssm.py``, ``ops/kda.py``). A row that is not ``active``
     leaves every leaf of its slot as it is and meets no expert. A layer
     is what its leaves say: one without a mixer leaf (a nemotron_h ``E``
@@ -989,10 +983,10 @@ def _hybrid_decode(params: dict, tokens: jnp.ndarray, pos, cache: list,
     live = jnp.ones((B,), bool) if active is None else active
 
     def ring(M: int, W: int, blocked: bool) -> _Ring:
-        # a ring that is not read in blocks cannot roll (jamba's and
-        # kimi_linear's: pos < M always), so slot m holds a live key iff
-        # m <= pos; the blocked kinds' rows find what they see in
-        # ops/ring_attention.py
+        # a ring that is not read in blocks cannot roll (jamba's: pos < M
+        # always), so slot m holds a live key iff m <= pos; the blocked
+        # kinds' rows find what they see in ops/ring_attention.py and
+        # ops/mla.py
         visible = (None if blocked else
                    jnp.arange(M)[None, None, :] <= pos[:, None, None])
         return _Ring(_write_targets(pos, active, M), visible, W)

@@ -219,11 +219,13 @@ def kda_zero_state(cfg: ModelConfig, batch: int, compute_dtype=None):
 # What differs follows from the layer's leaves and the configuration: a
 # low-rank query where the leaves hold ``wq_a`` (else the full-rank
 # ``wq``); the rotary part turned where the caller gives positions
-# (``cfg.mla_rotary``: this family's MLA carries none); the softmax scale (:func:`mla_scale`). The ring is read one of three ways
-# (ops/mla.py): whole under a mask (:func:`mla_attend`), a chunk's blocks
-# in the widened form (:func:`mla_chunk_attend`) or a step's live blocks in
-# the absorbed form (:func:`mla_step_attend`); models/decode.py picks by
-# the layer's kind.
+# (``cfg.mla_rotary``: this family's MLA carries none); the softmax scale
+# (:func:`mla_scale`). A ring of latents (a layer of kind ``"latent"``) has
+# ONE chunk read and ONE decode read, whichever family keeps it (ops/mla.py;
+# models/decode.py calls them): a chunk's blocks in the widened form
+# (:func:`mla_chunk_attend`) and a step's live blocks in the absorbed form
+# (:func:`mla_step_attend`). :func:`mla_attend`, the latents whole under a
+# mask, is this family's full forward's and the tests' reference.
 
 
 def _rotate(x: jnp.ndarray, pos, cfg: ModelConfig) -> jnp.ndarray:
@@ -280,9 +282,10 @@ def mla_latent(h: jnp.ndarray, p: dict, cfg: ModelConfig,
 def mla_attend(h: jnp.ndarray, p: dict, cfg: ModelConfig,
                latent: jnp.ndarray, visible: jnp.ndarray) -> jnp.ndarray:
     """The queries of ``h`` (B, L, E) over ``latent`` (B, M, rank + rope)
-    where ``visible`` says so, the ring whole in the absorbed form,
-    through the output projection (a layer of kind ``"mla"``: no
-    position)."""
+    where ``visible`` says so, the latents whole in the absorbed form,
+    through the output projection, with no position (this family's full
+    forward over a whole sequence, and what the tests hold the two serving
+    reads against; neither serving program calls it)."""
     q = mla_queries(h, p, cfg)
     heads = attend_latent(q, latent.astype(h.dtype), p["wkv_b"], visible,
                           mla_scale(cfg))

@@ -16,15 +16,20 @@ the cache, on every read.
 
 Three reads of the ring of latents:
 
-- :func:`attend_latent`, absorbed, the ring whole under a visibility mask
-  (kimi_linear's chunks and steps; float32 scores ``(B, H, L, M)``);
+- :func:`attend_latent`, absorbed, the latents whole under a visibility
+  mask, float32 scores ``(B, H, L, M)``: kimi_linear's full forward over a
+  whole sequence and the tests' reference. NEITHER serving program calls
+  it: over a pool of rings it reads every slot's ring whole, dead
+  positions and free slots too (3.88 ms of the kimi cell's decode step,
+  a hundred times its live latents: ledger, PR 43);
 - :func:`latent_decode_attention`, absorbed, one token a slot of the pool,
   a Pallas kernel (``mla_latent_decode_fwd``) that reads a row's LIVE
   latent blocks alone, once for all heads, with an online float32 softmax:
   no score leaves the chip, and a step costs the live positions rounded
-  up to blocks, not slots x ring (the deepseek_v2 family's decode step);
-- :func:`chunk_attention`, a prefill chunk's, a Pallas kernel
-  (``mla_chunk_widened_fwd``): absorbed, a (query, key) pair costs ``2 H
+  up to blocks, not slots x ring (THE decode read of a layer of kind
+  ``"latent"``: every deepseek_v2 layer, kimi_linear's MLA layers);
+- :func:`chunk_attention`, a prefill chunk's of the same layers, a Pallas
+  kernel (``mla_chunk_widened_fwd``): absorbed, a (query, key) pair costs ``2 H
   (2 rank + rope)`` operations, 278 k at 128 heads and a rank of 512;
   WIDENED, keys and values a head made from the latents (``W_kvb``) for
   the positions the chunk sees, ``2 H (nope + rope + v)``, 82 k, plus the

@@ -201,10 +201,10 @@ _STAT_SPEC = {
     "decode_live_latent": (
         "serving_decode_live_latent_positions_total",
         "Ring positions that hold a live latent for the rows of decode "
-        "steps, pos + 1 a row, summed over the layers (a family whose "
-        "every layer is MLA over a ring of latents that the step reads "
-        "live: ops/mla.py latent_decode_attention). 0 for the other "
-        "families.",
+        "steps, pos + 1 a row, summed over the layers that are MLA over "
+        "a ring of latents, which the step reads live (every deepseek_v2 "
+        "layer, kimi_linear's MLA layers: ops/mla.py "
+        "latent_decode_attention). 0 for the other families.",
     ),
     "completed": (
         "serving_requests_completed_total",
@@ -899,13 +899,17 @@ def _refuse_for_two_ring_lengths(cfg: ModelConfig,
 
 def _refuse_for_latent_ring(cfg: ModelConfig,
                             serving: ServingConfig) -> None:
-    """The engine features that the ``deepseek_v2`` family's pool does not
-    have yet, each refused under its own reason. Its state is a ring of
-    latents a layer, addressed by position like a K/V ring (no recurrent
-    state: :func:`_refuse_for_recurrent_state`'s reason does not hold),
-    and all of one length (:func:`_refuse_for_two_ring_lengths`'s neither);
-    what is missing is code, named here."""
-    lacks = "the {} family keeps a ring of latents a slot and layer, and {}"
+    """The engine features that a pool with rings of latents does not
+    have yet (``deepseek_v2``: every layer; ``kimi_linear``: its MLA
+    layers), each refused under its own reason. Such a ring is addressed
+    by position like a K/V ring and all rings are of one length
+    (:func:`_refuse_for_two_ring_lengths`'s reason does not hold); what is
+    missing is code, named here. ``kimi_linear``'s KDA layers keep a
+    recurrent state besides, so :func:`_refuse_for_recurrent_state` has
+    refused each of these for it before this is asked; a configuration of
+    MLA layers alone meets these reasons."""
+    lacks = ("the {} family keeps a ring of latents a slot and MLA layer, "
+             "and {}")
     asked = (
         ("the host tier (host_tier_bytes) stashes and restores a slot as "
          "the pages of a page table, which this pool does not have (no "
@@ -927,8 +931,8 @@ def _refuse_for_latent_ring(cfg: ModelConfig,
             f"kv_cache_dtype='int8' is not available for the {cfg.model} "
             "family: int8 latents do not exist yet (quantize_kv scales a "
             "K/V head; a latent is key and value of every head at once, "
-            "and its rotary part would need a scale of its own), and the "
-            "live-latent read takes float latents"
+            "and its shared key part would need a scale of its own), and "
+            "the live-latent read takes float latents"
         )
 
 
@@ -1270,8 +1274,9 @@ class ServingEngine:
         self._window_layers = cfg.layer_kinds().count("window")
         if self._window_layers:
             _refuse_for_two_ring_lengths(cfg, self.serving)
-        # every layer keeps a ring of latents that the decode step reads
-        # live (deepseek_v2): the decode span says how many it holds
+        # the layers that keep a ring of latents, which the decode step
+        # reads live (every deepseek_v2 layer, kimi_linear's MLA layers):
+        # the decode span says how many latents the rows hold
         self._latent_layers = cfg.layer_kinds().count("latent")
         if self._latent_layers:
             _refuse_for_latent_ring(cfg, self.serving)
@@ -1789,8 +1794,8 @@ class ServingEngine:
                        "table cannot roll with a KV cache (models/decode.py)"
                        if self.cfg.model == "diff" else
                        f"and the {self.cfg.model} family's cache cannot "
-                       "roll: its layers see every earlier position, which "
-                       "a rolled ring of latents no longer holds "
+                       "roll: its MLA layers see every earlier position, "
+                       "which a rolled ring of latents no longer holds "
                        "(models/decode.py)"
                        if self._latent_layers else
                        f"and the {self.cfg.model} family's cache cannot "

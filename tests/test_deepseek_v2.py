@@ -20,6 +20,7 @@ import math
 import re
 import sys
 from pathlib import Path
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -317,34 +318,63 @@ def test_generate_cached_runs_the_family(params):
 
 # -- the three reads of the ring of latents -------------------------------------
 
+class _Dims(NamedTuple):
+    """A layer of kind ``"latent"``: its widths, its softmax scale, the
+    gain its random widening is drawn at and what float32 in another
+    order of products may differ by."""
+    H: int
+    rank: int
+    rope: int
+    nope: int
+    vd: int
+    scale: float
+    gain: float
+    tol: float
 
-def _latent_case(M, width, rank, H, nope, vd, B, seed):
+
+TOY_DIMS = _Dims(4, 16, 8, 8, 8, 0.3, 1.0, 2e-5)
+#: kimi_linear's MLA layer as published (32 heads over a ring of 4,096
+#: latents of 512 + 64 values, no rotation), which the two blocked reads
+#: serve beside deepseek_v2's; the widening at unit gain, as a trained one
+#: (scores of order 1, not 20); a score sums 576 products, a row up to
+#: 4,096 weights
+KIMI_DIMS = _Dims(32, 512, 64, 128, 128, 192 ** -0.5, 512 ** -0.5, 2e-4)
+
+
+def _latent_case(M, dims, B, seed):
+    """``(latents (B, M, rank + rope), W_kvb, queries (B, 1, H, .))``."""
     rng = np.random.default_rng(seed)
     arr = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
-    return (arr(B, M, width), arr(rank, H, nope + vd),
-            arr(B, 1, H, nope + width - rank))
+    return (arr(B, M, dims.rank + dims.rope),
+            arr(dims.rank, dims.H, dims.nope + dims.vd) * dims.gain,
+            arr(B, 1, dims.H, dims.nope + dims.rope))
 
 
-@pytest.mark.parametrize("M, pos, live", [
-    (64, [0, 7, 31, 63], [1, 1, 1, 1]),
-    (64, [5, 40, 63, 12], [0, 1, 0, 1]),
-    (64, [5, 30, 9, 50], [0, 0, 0, 0]),
-    (128, [0, 127, 64, 3], [1, 1, 0, 1]),
-    (2048, [0, 511, 512, 1500, 2047], [1, 1, 1, 1, 1]),
-    (2048, [2000, 100, 1600, 900, 40], [0, 1, 1, 0, 1]),
+@pytest.mark.parametrize("M, pos, live, dims", [
+    (64, [0, 7, 31, 63], [1, 1, 1, 1], TOY_DIMS),
+    (64, [5, 40, 63, 12], [0, 1, 0, 1], TOY_DIMS),
+    (64, [5, 30, 9, 50], [0, 0, 0, 0], TOY_DIMS),
+    (128, [0, 127, 64, 3], [1, 1, 0, 1], TOY_DIMS),
+    (2048, [0, 511, 512, 1500, 2047], [1, 1, 1, 1, 1], TOY_DIMS),
+    (2048, [2000, 100, 1600, 900, 40], [0, 1, 1, 0, 1], TOY_DIMS),
+    (4096, [0, 511, 512, 4095], [1, 1, 1, 1], KIMI_DIMS),
+    (4096, [3000, 100, 1600, 40, 352], [0, 1, 1, 0, 1], KIMI_DIMS),
+    (4096, [5, 3000, 9], [0, 0, 0], KIMI_DIMS),
 ], ids=["toy_all_live", "toy_some_live", "toy_none_live", "ring_on_lanes",
-        "blocks_all_live", "blocks_some_live"])
-def test_latent_decode_kernel_reads_a_row_s_live_blocks_alone(M, pos, live):
+        "blocks_all_live", "blocks_some_live", "kimi_all_live",
+        "kimi_some_live", "kimi_none_live"])
+def test_latent_decode_kernel_reads_a_row_s_live_blocks_alone(M, pos, live,
+                                                              dims):
     """``ops/mla.py:latent_decode_attention`` (interpret mode; a ring of
-    128 is read as the chip holds it, ring on the lanes) against the whole
-    ring under a mask (``attend_latent``): absorbed on both sides. A row
-    that is not live comes out as zeros whatever its ring holds, and the
-    blocks a row is told to read are those that hold a visible latent."""
-    B, H, rank, ropew, nope, vd = len(pos), 4, 16, 8, 8, 8
-    latent, w, q = _latent_case(M, rank + ropew, rank, H, nope, vd, B,
-                                M + len(pos))
+    128 is read as the chip holds it, ring on the lanes, and so is kimi's
+    of 4,096 x 576) against the whole ring under a mask
+    (``attend_latent``): absorbed on both sides. A row that is not live
+    comes out as zeros whatever its ring holds, and the blocks a row is
+    told to read are those that hold a visible latent."""
+    H, rank, ropew, nope, vd, scale, _, tol = dims
+    B = len(pos)
+    latent, w, q = _latent_case(M, dims, B, M + len(pos))
     pos, live = np.asarray(pos, np.int32), np.asarray(live, bool)
-    scale = 0.3
     visible = jnp.arange(M)[None, None, :] <= jnp.asarray(pos)[:, None, None]
     want = mla.attend_latent(q, latent, w, visible, scale)[:, 0]
     qq = mla.absorb_queries(q[:, 0], w, ropew)
@@ -353,7 +383,7 @@ def test_latent_decode_kernel_reads_a_row_s_live_blocks_alone(M, pos, live):
                           jnp.asarray(live))
     got = jnp.einsum("bhr,rhv->bhv", mixed, w[..., nope:]).reshape(B, -1)
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
-                               atol=2e-5, rtol=2e-5)
+                               atol=tol, rtol=tol)
     assert np.all(np.asarray(mixed)[~live] == 0)
     KB = mla.key_block(M)
     count = mla.live_blocks(pos, live, M)
@@ -362,25 +392,39 @@ def test_latent_decode_kernel_reads_a_row_s_live_blocks_alone(M, pos, live):
         mla.live_blocks(jnp.asarray(pos), jnp.asarray(live), M), count)
 
 
-@pytest.mark.parametrize("M, L, pos", [
-    (64, 8, 0), (64, 8, 24), (64, 16, 48), (64, 3, 5), (2048, 8, 0),
-    (2048, 16, 505), (2048, 64, 1984),
+@pytest.mark.parametrize("M, L, pos, valid, dims", [
+    (64, 8, 0, None, TOY_DIMS), (64, 8, 24, None, TOY_DIMS),
+    (64, 16, 48, None, TOY_DIMS), (64, 3, 5, None, TOY_DIMS),
+    (2048, 8, 0, None, TOY_DIMS), (2048, 16, 505, None, TOY_DIMS),
+    (2048, 64, 1984, None, TOY_DIMS), (2048, 32, 500, 21, TOY_DIMS),
+    (4096, 16, 0, None, KIMI_DIMS), (4096, 16, 505, None, KIMI_DIMS),
+    (4096, 32, 1000, 21, KIMI_DIMS),
 ], ids=["ring_start", "mid_ring", "ring_end", "a_chunk_of_three",
-        "first_block", "over_a_block_s_edge", "last_block"])
-def test_widened_chunk_read_is_the_absorbed_one(M, L, pos):
+        "first_block", "over_a_block_s_edge", "last_block", "a_padded_tail",
+        "kimi_first_block", "kimi_over_a_block_s_edge",
+        "kimi_a_padded_tail"])
+def test_widened_chunk_read_is_the_absorbed_one(M, L, pos, valid, dims):
     """``chunk_attention`` (interpret mode: keys and values a head, the
     ring in blocks up to the chunk's end) against ``attend_latent``
     (absorbed, the ring whole): the same attention, another order of
-    products."""
-    H, rank, ropew, nope, vd = 4, 16, 8, 8, 8
-    latent, w, _ = _latent_case(M, rank + ropew, rank, H, nope, vd, 2, M + pos)
+    products. With ``valid`` the chunk's rows from there on are padding
+    (a prompt's tail at a compiled shape, ``forward_chunk``'s ``valid``):
+    the latents they wrote lie past the sequence's end, and whatever those
+    are, the real rows come out the same, bit for bit."""
+    H, rank, ropew, nope, vd, scale, _, tol = dims
+    B = 2 if dims is TOY_DIMS else 1  # interpret mode: 256 grid steps a row
+    latent, w, _ = _latent_case(M, dims, B, M + pos)
     q = jnp.asarray(np.random.default_rng(L).normal(
-        size=(2, L, H, nope + ropew)), jnp.float32)
+        size=(B, L, H, nope + ropew)), jnp.float32)
     visible = decode._ring_visible(pos, L, M, M)
-    want = mla.attend_latent(q, latent, w, visible, 0.3)
-    got = jax.jit(lambda at: mla.chunk_attention(q, latent, w, at, 0.3))(
-        jnp.int32(pos))
-    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    want = mla.attend_latent(q, latent, w, visible, scale)
+    read = jax.jit(lambda ring, at: mla.chunk_attention(q, ring, w, at, scale))
+    got = read(latent, jnp.int32(pos))
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+    if valid is not None:
+        other = latent.at[:, pos + valid:pos + L].set(7.0)
+        assert np.array_equal(np.asarray(read(other, jnp.int32(pos)))[:, :valid],
+                              np.asarray(got)[:, :valid])
 
 
 # -- the router -------------------------------------------------------------------

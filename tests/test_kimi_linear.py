@@ -95,8 +95,11 @@ def test_layout_matches_the_reference():
     made = reference.make_params(1, TOY)
     assert jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), shapes) == \
         jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), made)
-    assert list(zip(toy().layer_kinds(), toy().mlp_kinds())) == \
-        reference.layer_kinds(TOY)
+    # the reference names a mixer by its leaf; the program's kind of an
+    # ``mla`` leaf is ``"latent"``, the ring it keeps
+    assert list(zip(toy().layer_kinds(), toy().mlp_kinds())) == [
+        ({"mla": "latent"}.get(mixer, mixer), mlp)
+        for mixer, mlp in reference.layer_kinds(TOY)]
 
 
 def test_the_family_is_served_not_trained(params, tokens):
@@ -397,6 +400,56 @@ def test_engine_serves_the_reference_s_greedy_tokens(params, num_slots):
     assert float(got) == pool  # no K/V ring here: the whole pool is state
 
 
+def test_the_decode_span_counts_the_latents_the_mla_layer_reads_live(params):
+    """The MLA layer is of kind ``"latent"``: the decode step reads a
+    row's live latent blocks (``ops/mla.py:latent_decode_attention``), and
+    the engine says what the rows hold, ``pos + 1`` a live row, in the
+    decode span's ``latent_live`` and, once a layer of that kind (one
+    here), in ``serving_decode_live_latent_positions_total``."""
+    cfg = toy()
+    assert cfg.layer_kinds().count("latent") == 1
+    spans = _Spans()
+    eng = _engine(params, cfg, tracer=spans, num_slots=3)
+    prompt = _prompts(1, seed=4)[0]
+    eng.generate([prompt], max_new_tokens=6, temperature=0.0)
+    steps = [a for n, a in spans.spans if n == "decode"]
+    # one row: the step that feeds the token at position pos holds pos + 1
+    assert [a["active"] for a in steps] == [1] * len(steps) and len(steps) >= 5
+    assert [a["latent_live"] for a in steps] == [
+        len(prompt) + 1 + t for t in range(len(steps))]
+    assert eng.stats["decode_live_latent"] == sum(
+        a["latent_live"] for a in steps) > 0
+    # several rows at once: the sum over the live rows, free slots nothing
+    before = len(steps)
+    eng.generate(_prompts(2, seed=5), max_new_tokens=4, temperature=0.0)
+    steps = [a for n, a in spans.spans if n == "decode"]
+    assert len(steps) > before and all(
+        a["active"] <= a["latent_live"] <= 192 * a["active"] for a in steps)
+    assert eng.stats["decode_live_latent"] == sum(
+        a["latent_live"] for a in steps)
+    text = eng.registry.render()
+    got = re.search(r"^serving_decode_live_latent_positions_total (\S+)$",
+                    text, re.M).group(1)
+    assert float(got) == eng.stats["decode_live_latent"]
+
+
+@pytest.mark.parametrize("serving, named", [
+    (dict(kv_page_size=16), "paging"),
+    (dict(spec_mode="ngram"), "speculation"),
+    (dict(kv_cache_dtype="int8"), "kv_cache_dtype='int8'"),
+    (dict(kv_page_size=16, host_tier_bytes=1 << 20), "host tier"),
+])
+def test_mla_layers_alone_refuse_by_name_what_a_ring_of_latents_lacks(
+        params, serving, named):
+    """Without a KDA layer no recurrent state refuses first: what answers
+    is the ring of latents, as for deepseek_v2, and names this family."""
+    cfg = toy(kda_layers=[], full_attn_layers=[1, 2, 3, 4, 5])
+    with pytest.raises(ValueError) as e:
+        _engine(params, cfg, **serving)
+    assert named in str(e.value) and "kimi_linear" in str(e.value)
+    assert "latent" in str(e.value) and "recurrent" not in str(e.value)
+
+
 def test_a_reused_slot_serves_what_a_fresh_engine_serves(params):
     cfg = toy()
     first, second = _prompts(2, seed=11)
@@ -511,7 +564,7 @@ def test_kimi_linear_refuses_what_it_does_not_run_by_name(field, value):
 
 def test_published_lists_put_mla_at_layer_4_and_experts_after_layer_1():
     cfg = ModelConfig(**PUBLISHED)
-    assert cfg.layer_kinds() == ("kda", "kda", "kda", "mla", "kda")
+    assert cfg.layer_kinds() == ("kda", "kda", "kda", "latent", "kda")
     assert cfg.mlp_kinds() == ("dense", "moe", "moe", "moe", "moe")
     assert cfg.held_expert_range == (0, 128)
     assert ModelConfig(**dict(PUBLISHED, held_experts=[0, 0])

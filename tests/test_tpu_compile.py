@@ -738,13 +738,18 @@ def test_kimi_linear_programs_update_the_pool_in_place(topo):
     """What the chip's compiler makes of the kimi_linear family's three
     programs at the serve cell's own size (256 slots, the five layers of
     the share at published widths): every cache leaf is aliased input to
-    output in all three; the decode program names its three kernels (the
-    state update, the latent's write, the experts' grouped product) and
-    all of its scopes, and its temporaries stay far under the latent ring
-    (1.2 GB) and a KDA layer's state pool (0.54 GB): neither is copied,
-    selected over or laid out anew. The chip lays the ring of latents
-    (4096 x 576) out with the positions on the lanes, which is what
-    ``ops/kv_write.py:position_on_lanes`` says of it."""
+    output in all three; the decode program names its four kernels (the
+    state update, the latent's write, the live-latent read, the experts'
+    grouped product), the prefill program the widened chunk read, and
+    both all of their scopes. The decode program's temporaries stay under
+    0.1 GB, far under the latent ring (1.21 GB) and a KDA layer's state
+    pool (0.54 GB): neither is copied, selected over or laid out anew, and
+    no score over slots x ring exists (134 MB in float32 if it did). The
+    chip lays the ring of latents (4096 x 576) out with the positions on
+    the lanes, which is what ``ops/kv_write.py:position_on_lanes`` says of
+    it, the row write writes and the live-latent read takes (handed the
+    row-major leaf that read made the compiler copy the pool both ways:
+    ``ops/mla.py:latent_decode_attention``)."""
     from differential_transformer_replication_tpu.models import init_model
     from differential_transformer_replication_tpu.models.decode import init_cache
     from differential_transformer_replication_tpu.serving import engine
@@ -777,16 +782,17 @@ def test_kimi_linear_programs_update_the_pool_in_place(topo):
     assert text.startswith("HloModule jit__decode")
     assert assert_kernels_named(text, "_decode") == {
         kernel_names.KDA_STATE_UPDATE, kernel_names.KV_ROW_WRITE,
-        kernel_names.MOE_GROUPED_MATMUL}
-    assert {"kda", "kda_conv", "kda_state", "mla", "mla_latent_write",
-            "mla_attend", "moe", "moe_router", "moe_experts", "moe_shared",
-            "ffn_norm", "ffn", "lm_head", "kv_merge"} <= scopes_in(text)
-    assert {"kda", "kda_conv", "kda_chunk", "mla", "mla_latent_write",
-            "mla_attend", "moe_experts"} <= scopes_in(
-                programs["prefill"].as_text())
+        kernel_names.MLA_LATENT_DECODE, kernel_names.MOE_GROUPED_MATMUL}
+    assert {"kda", "kda_conv", "kda_state", "mla", "mla_q",
+            "mla_latent_write", "mla_attend", "mla_out", "moe", "moe_router",
+            "moe_experts", "moe_shared", "ffn_norm", "ffn", "lm_head",
+            "kv_merge"} <= scopes_in(text)
+    assert {"kda", "kda_conv", "kda_chunk", "mla", "mla_q",
+            "mla_latent_write", "mla_attend", "mla_out",
+            "moe_experts"} <= scopes_in(programs["prefill"].as_text())
     assert assert_kernels_named(programs["prefill"].as_text(), "_prefill") == {
-        kernel_names.MOE_GROUPED_MATMUL}
-    assert programs["decode"].memory_analysis().temp_size_in_bytes < 0.4e9
+        kernel_names.MOE_GROUPED_MATMUL, kernel_names.MLA_CHUNK_WIDENED}
+    assert programs["decode"].memory_analysis().temp_size_in_bytes < 0.1e9
     assert programs["prefill"].memory_analysis().temp_size_in_bytes < 0.4e9
 
 
