@@ -282,6 +282,8 @@ class _Mod:
     top_defs: Dict[str, _Func] = field(default_factory=dict)
     funcs: List[_Func] = field(default_factory=list)
     classes: Dict[str, Dict[str, _Func]] = field(default_factory=dict)
+    # module-level name -> the functions its assigned value mentions
+    tables: Dict[str, List[_Func]] = field(default_factory=dict)
 
 
 def _dotted(node: ast.AST) -> Optional[str]:
@@ -740,7 +742,15 @@ def _build_graph(mods: Dict[str, _Mod]) -> _Graph:
                 return
             owner = self.stack[-1]
             if owner is None:
-                return  # module-level function vars: top_defs covers defs
+                # a module-level table of functions: what it mentions may
+                # run wherever the table is read (EdgeVisitor.visit_Name)
+                self.mod.tables[node.targets[0].id] = [f for f in (
+                    lambda_funcs.get(id(n)) if isinstance(n, ast.Lambda) else
+                    _resolve_call_any(None, self.mod, _dotted(n) or "", mods)
+                    for n in ast.walk(node.value)
+                    if isinstance(n, (ast.Lambda, ast.Name, ast.Attribute))
+                ) if f is not None]
+                return
             owner.var_targets.setdefault(
                 node.targets[0].id, []
             ).extend(self._classify(node.value, owner, 0))
@@ -843,6 +853,11 @@ def _build_graph(mods: Dict[str, _Mod]) -> _Graph:
 
         visit_FunctionDef = visit_AsyncFunctionDef = visit_Lambda = _push
 
+        def visit_Name(self, node: ast.Name):
+            # dispatch through a module-level table (``KINDS[kind].step``)
+            for t in self.mod.tables.get(node.id, ()):
+                g.add_edge(self.stack[-1], t)
+
         def _axes(self, tl: str, node: ast.Call) -> Set[str]:
             if tl == "pmap":
                 for kw in node.keywords:
@@ -935,12 +950,11 @@ def _build_graph(mods: Dict[str, _Mod]) -> _Graph:
                             pending.param_calls.setdefault(
                                 pw, []
                             ).append(owner)
-            # a lambda passed as ANY call argument runs inside the
-            # callee's dynamic extent; approximate with a caller edge
+            # a lambda (or a ``partial``) passed as ANY call argument runs
+            # inside the callee's dynamic extent; approximate with a caller edge
             for a in list(node.args) + [k.value for k in node.keywords]:
-                if isinstance(a, ast.Lambda):
-                    f = lambda_funcs.get(id(a))
-                    if f is not None:
+                if isinstance(a, (ast.Lambda, ast.Call)):
+                    for f in funcs_from_expr(a, owner, self.mod):
                         g.add_edge(owner, f)
             self.generic_visit(node)
 

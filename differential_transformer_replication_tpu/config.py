@@ -81,8 +81,6 @@ FAMILY_FIELDS = {"jamba": JAMBA_FIELDS, "kimi_linear": KIMI_LINEAR_FIELDS,
 # blocks as afmoe's full layers are), or no mixer at all (the layer is its
 # expert feed-forward part alone)
 NEMOTRON_H_LAYERS = {"M": "mamba2", "*": "full", "E": "none"}
-# the mixer kinds that keep a recurrent state a slot and no ring
-RECURRENT_KINDS = ("mamba", "kda", "mamba2")
 # the keys of a YaRN ``rope_scaling`` block, as the published config.json
 # spells them (``type`` beside them says "yarn")
 YARN_KEYS = ("factor", "beta_fast", "beta_slow", "mscale", "mscale_all_dim",

@@ -148,6 +148,23 @@ def gate_out(o: jnp.ndarray, g: jnp.ndarray, p: dict) -> jnp.ndarray:
     return o @ p["out"]["w"].astype(o.dtype)
 
 
+def ring_qkv(h: jnp.ndarray, p: dict, cfg: ModelConfig, pos, kind: str):
+    """:func:`qkvg` for the ring chunk and step of models/decode.py:
+    ``pos`` is a chunk's first position (``h`` (B, L, E)) or a position a
+    row (``h`` (B, E))."""
+    at = pos + jnp.arange(h.shape[1]) if h.ndim == 3 else pos
+    return qkvg(h, p, cfg, at, kind)
+
+
+def ring_out(o: jnp.ndarray, g: jnp.ndarray, blk: dict,
+             cfg: ModelConfig) -> jnp.ndarray:
+    """The ring's read ``o`` gated, projected and normed again."""
+    with jax.named_scope("attn"):
+        a = gate_out(o, g, blk["attn"])
+    with jax.named_scope("attn_norm"):
+        return norm(a, blk["ln1_post"], cfg)
+
+
 def band(T: int, window: int) -> jnp.ndarray:
     """(T, T) bool: query i sees key j iff ``j <= i`` and ``i - j <
     window``."""

@@ -43,17 +43,15 @@ at the input instead. Generation is eval-mode: no dropout anywhere.
 from __future__ import annotations
 
 import math
+from dataclasses import dataclass, field
 from functools import partial
-from typing import NamedTuple, Optional, Tuple
+from typing import Callable, Mapping, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from differential_transformer_replication_tpu.config import (
-    RECURRENT_KINDS,
-    ModelConfig,
-)
+from differential_transformer_replication_tpu.config import ModelConfig
 from differential_transformer_replication_tpu.models.generate import sample_token
 from differential_transformer_replication_tpu.models import (
     afmoe,
@@ -102,30 +100,15 @@ def _uses_rope(cfg: ModelConfig) -> bool:
     return cfg.model in ("control", "ndiff")
 
 
-# Pool-batch axis of each cache leaf: K (and its scales) carry the
-# stream axis first, V does not. The single source of truth for every
-# per-slot slice/scatter/merge over the cache pytree (serving/engine.py).
-# ``ssm`` and ``conv`` are a Mamba layer's leaves (the jamba family's
-# Mamba-1 and the nemotron_h family's Mamba-2 mixers), ``kda`` and ``conv``
-# a KDA layer's (kimi_linear): a slot's recurrent state, not a ring over
-# positions. A layer without a mixer (a nemotron_h ``E`` layer) holds no
-# leaf at all: its entry in the cache is an empty dict, which every walk
-# over ``layer.items()`` passes by. ``latent`` is an MLA layer's ring of
-# latents (B, 1, M, rank + rope): one "head" that every query head reads.
-KV_CACHE_BATCH_AXIS = {"k": 1, "v": 0, "k_scale": 1, "v_scale": 0,
-                       "ssm": 0, "conv": 0, "kda": 0, "latent": 0}
-STATE_LEAVES = ("ssm", "conv", "kda")
-# a block's leaf that holds its token mixer; a block with none of them is a
-# feed-forward part alone (a nemotron_h ``E`` layer) and keeps no cache
-MIXER_LEAVES = ("attn", "mamba", "mamba2", "kda", "mla")
-
-
-def _has_mixer(blk: dict) -> bool:
-    return any(leaf in blk for leaf in MIXER_LEAVES)
-# the families whose layers are of several kinds (:func:`_hybrid_chunk`),
+# the families whose layers are of several kinds (:func:`_hybrid_walk`),
 # each with the module that holds its ``embed``
 HYBRID = {"jamba": jamba, "kimi_linear": kimi_linear, "afmoe": afmoe,
           "deepseek_v2": deepseek_v2, "nemotron_h": nemotron_h}
+# a family's flavour of attention over a K/V ring, ``(ring_qkv, ring_out)``
+# as its module hands them (nemotron_h's ``*`` layers attend as jamba's do)
+RING_ATTENTION = {"jamba": (jamba.ring_qkv, jamba.ring_out),
+                  "afmoe": (afmoe.ring_qkv, afmoe.ring_out),
+                  "nemotron_h": (jamba.ring_qkv, jamba.ring_out)}
 # Ring positions that a prefill chunk's blocked attention
 # (:func:`_attend_ring_blocked`) reads at a time
 ATTEND_KEY_BLOCK = 1024
@@ -136,7 +119,7 @@ def has_recurrent_state(cfg: ModelConfig) -> bool:
     (a Mamba, a Mamba-2 or a KDA layer's): such a slot has to be zeroed
     before a new sequence enters it, where a ring is simply masked by
     positions. Told by what the layers keep, not by the family's name."""
-    return any(kind in RECURRENT_KINDS for kind in cfg.layer_kinds())
+    return any(KINDS[kind].recurrent for kind in cfg.layer_kinds())
 
 
 def kv_store_dtype(cfg: ModelConfig) -> str:
@@ -247,69 +230,32 @@ def quality_vector(lp: jnp.ndarray, proc: jnp.ndarray,
 
 
 def init_cache(cfg: ModelConfig, batch_size: int) -> list:
-    """Per-layer K/V buffers sized to ``block_size``, HEAD-MAJOR so the
-    per-(slot, head) ring is contiguous — the fused decode kernel's
-    native layout (ops/decode_attention.py) and an equivalent einsum for
-    the XLA chunk path: K is per-stream (S, B, H, M, d); V is shared
-    across streams (B, H, M, dv).
+    """The pool of ``batch_size`` slots: for every layer what its kind's
+    record keeps (``KINDS``), each ring ``cfg.ring_len(kind)`` long: K/V
+    rings (:func:`_kv_zeros`; the reference families' are ``block_size``
+    long), rings of latents, recurrent states (zeros being a sequence's
+    start) or, for a layer without a mixer, ``{}``."""
+    return [KINDS[kind].zeros(cfg, batch_size, cfg.ring_len(kind))
+            for kind in cfg.layer_kinds()]
 
-    ``cfg.kv_cache_dtype == "int8"`` stores symmetric per-head-scale
-    int8 values plus fp32 scales (``k_scale`` (S, B, H, M) / ``v_scale``
-    (B, H, M)) — about half the bf16 bytes per slot; otherwise the
-    resolved float dtype (:func:`kv_store_dtype`).
 
-    The ``jamba`` family's layers are of two kinds: an attention layer
-    gets rings with ``kv_heads`` heads, a Mamba layer ``{ssm (B, N, Di)
-    in ssm_state_dtype, conv (B, K-1, Di)}``, zeros being a sequence's
-    start. The ``kimi_linear`` family's: a KDA layer ``{kda (B, H, d, d)
-    float32, conv (B, K-1, 3 H d)}``, an MLA layer ``{latent (B, 1, M,
-    rank + rope)}``, the ring of what it caches a position (the
-    ``deepseek_v2`` family's every layer likewise). The ``afmoe``
-    family's rings are of two lengths in one slot (``cfg.ring_len``): a
-    full layer's ``block_size`` long, a sliding layer's
-    ``sliding_ring``. The ``nemotron_h`` family's layers are ONE of three
-    things: a Mamba-2 layer holds ``{ssm (B, N, heads x P) float32, conv
-    (B, K-1, Di + 2 n_groups N)}``, an attention layer K/V rings with
-    ``kv_heads`` heads, and an expert layer, which has no mixer, ``{}``:
-    nothing to keep, to reset or to write."""
-    S = _n_streams(cfg)
-    H, d, dv = cfg.n_kv_head, cfg.head_size, cfg.value_size
+def _kv_zeros(cfg: ModelConfig, rows: int, M: int) -> dict:
+    """K/V rings of ``M`` positions, HEAD-MAJOR so the per-(slot, head)
+    ring is contiguous — the fused decode kernel's native layout
+    (ops/decode_attention.py) and an equivalent einsum for the XLA chunk
+    path: K is per-stream (S, B, H, M, d); V is shared across streams
+    (B, H, M, dv). ``cfg.kv_cache_dtype == "int8"`` stores symmetric
+    per-head-scale int8 values plus fp32 scales (``k_scale`` (S, B, H, M)
+    / ``v_scale`` (B, H, M)) — about half the bf16 bytes per slot;
+    otherwise the resolved float dtype (:func:`kv_store_dtype`)."""
+    S, H = _n_streams(cfg), cfg.n_kv_head
     store = kv_store_dtype(cfg)
-    cache = []
-    for kind in cfg.layer_kinds():
-        M = cfg.ring_len(kind)
-        if kind == "none":
-            cache.append({})
-            continue
-        if kind in ("mamba", "mamba2"):
-            family = jamba if kind == "mamba" else nemotron_h
-            conv, ssm = family.zero_state(cfg, batch_size)
-            cache.append({"ssm": ssm, "conv": conv})
-            continue
-        if kind == "kda":
-            conv, state = kimi_linear.kda_zero_state(cfg, batch_size)
-            cache.append({"kda": state, "conv": conv})
-            continue
-        if kind == "latent":
-            cache.append({"latent": jnp.zeros(
-                (batch_size, 1, M, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
-                jnp.dtype(cfg.compute_dtype))})
-            continue
-        if store == "int8":
-            layer = {
-                "k": jnp.zeros((S, batch_size, H, M, d), jnp.int8),
-                "v": jnp.zeros((batch_size, H, M, dv), jnp.int8),
-                "k_scale": jnp.zeros((S, batch_size, H, M), jnp.float32),
-                "v_scale": jnp.zeros((batch_size, H, M), jnp.float32),
-            }
-        else:
-            dt = jnp.dtype(store)
-            layer = {
-                "k": jnp.zeros((S, batch_size, H, M, d), dt),
-                "v": jnp.zeros((batch_size, H, M, dv), dt),
-            }
-        cache.append(layer)
-    return cache
+    layer = {"k": jnp.zeros((S, rows, H, M, cfg.head_size), jnp.dtype(store)),
+             "v": jnp.zeros((rows, H, M, cfg.value_size), jnp.dtype(store))}
+    if store == "int8":
+        layer.update(k_scale=jnp.zeros((S, rows, H, M), jnp.float32),
+                     v_scale=jnp.zeros((rows, H, M), jnp.float32))
+    return layer
 
 
 def _dequant_layer(layer_cache: dict, dtype):
@@ -615,8 +561,8 @@ def forward_chunk(
         return _hybrid_chunk(params, tokens, pos, cache, cfg, window, valid)
     if valid is not None:
         raise ValueError(
-            f"forward_chunk(valid=...) pads a chunk of the jamba, "
-            f"kimi_linear and afmoe families only; the {cfg.model!r} family "
+            f"forward_chunk(valid=...) pads a chunk of the "
+            f"{', '.join(HYBRID)} families only; the {cfg.model!r} family "
             "runs whole chunks"
         )
     x, cos, sin = _embed_chunk(params, tokens, pos, cfg, rope_len)
@@ -638,32 +584,56 @@ def forward_chunk(
 
 
 # ---------------------------------------------------------------------------
-# The hybrid families (models/jamba.py, models/kimi_linear.py,
-# models/afmoe.py): layers of several kinds in one stack, ONE loop over
-# (mixer kind, MLP kind) a layer for a prefill chunk and one for the decode
-# step. An attention layer keeps K/V rings like the other families'
-# (``kv_heads`` heads, each shared by a group of query heads) and an MLA
-# layer a ring of latents; a Mamba or a KDA layer keeps a recurrent state a
-# slot, which a prefill chunk carries on from where the last chunk left it
-# and a decode step overwrites for the active slots, in place in the
-# donated pool. Nothing masks a state by position: a slot that takes a new
-# sequence has to be zeroed first (:func:`reset_slot_state`;
-# serving/engine.py does so on admission). A layer's kinds are read off
-# its leaves, but an ``afmoe`` layer's attention kind, which
-# ``cfg.layer_kinds`` tells. A slot's rings may be of several lengths
-# (``cfg.ring_len``: afmoe's sliding layers keep a shorter ring than its
-# full layers, and roll in it); where a row's K/V lands and what a row
-# sees is worked out once a ring length (:class:`_Ring`), not once a layer.
+# The layer kinds. ``config.py`` says which kind each layer of a
+# configuration is and how long its ring (``layer_kinds``, ``ring_len``,
+# ``ring_window``); ONE table, ``KINDS``, says what a kind DOES, and nothing
+# else in the package says it. A record (:class:`LayerKind`) holds
+#
+# - ``leaves``, ``zeros``: what a layer keeps in a cache slot, each leaf's
+#   name with its pool axis, and the leaves themselves from ``(cfg, rows,
+#   ring length)`` as :func:`init_cache` builds them;
+# - ``state``: the leaf every token overwrites through the step's update
+#   kernel. Such a kind is ``recurrent``: nothing masks a state by position,
+#   so a slot that takes a new sequence is zeroed first
+#   (:func:`reset_slot_state`; serving/engine.py does so on admission);
+# - ``blocks``: how its ring is read (None: it keeps none; False: whole
+#   under a mask; True: in blocks as far as it is live); ``rolls``: under
+#   multi-token chunks; ``latents``: a position holds one latent, no K/V;
+# - ``chunk(h, blk, layer_cache, cfg, pos, ring, valid)`` and ``step(h, blk,
+#   layer_cache, cfg, live, pos, ring)``, each ``-> (a, layer_cache)``: its
+#   mixer over a prefill chunk and a decode step, on the layer's input normed
+#   under ``scope`` (a named scope the per-layer metrics read), with the
+#   weights in the block's leaf ``params``;
+# - ``refuses``: for the host tier, speculation, paging and int8 storage
+#   either nothing or the message serving/engine.py raises (``str.format``
+#   over ``model``, ``mixers``, ``ring``, ``block``), in the table's order.
+#
+# A new kind is one record beside its family's module. A K/V ring is served
+# in its family's flavour (``RING_ATTENTION``). A slot's rings may be of
+# several lengths; where a row's K/V lands and what a row sees is worked out
+# once a length (:class:`_Ring`). The reference families' layers are of the
+# kind ``"attention"`` too: their walk (:func:`forward_chunk`'s loop,
+# :func:`_decode_step`) shares the record's leaves, not its chunk and step.
 # ---------------------------------------------------------------------------
 
+@dataclass(frozen=True)
+class LayerKind:
+    params: Optional[str] = None
+    leaves: Mapping[str, int] = field(default_factory=dict)
+    zeros: Callable = lambda cfg, rows, M: {}
+    state: Optional[str] = None
+    name: str = ""  # what the refusals call a recurrent kind's layer
+    blocks: Optional[bool] = None
+    rolls: bool = False
+    latents: bool = False
+    scope: str = "attn_norm"
+    chunk: Optional[Callable] = None
+    step: Optional[Callable] = None
+    refuses: Mapping[str, str] = field(default_factory=dict)
 
-#: The attention kinds that read a ring in blocks and mask each block from
-#: ``_Ring.window`` (:func:`_attend_ring_blocked`, ``ring_decode_attention``;
-#: ``models/afmoe.py``'s gated attention) or from the positions
-#: (``"latent"``, MLA over a ring of latents, the deepseek_v2 family's
-#: and kimi_linear's alike: ``ops/mla.py``'s blocked reads): their rings
-#: get no ``visible``, which is left to jamba's ``"attention"`` kind.
-BLOCKED_KINDS = ("window", "full", "latent")
+    @property
+    def recurrent(self) -> bool:
+        return self.state is not None
 
 
 class _Ring(NamedTuple):
@@ -671,7 +641,7 @@ class _Ring(NamedTuple):
     length: ``at``, where their K/V goes (a chunk: the first row's ring
     position; a step: a target a row, -1 for none); ``visible``, what each
     row sees of the ring after the write ((L, M) a chunk, (B, 1, M) a
-    step; None for the ``BLOCKED_KINDS``); ``window``, the positions a
+    step; None for a kind read in blocks); ``window``, the positions a
     row sees, itself among them."""
     at: jnp.ndarray
     visible: Optional[jnp.ndarray]
@@ -684,11 +654,11 @@ def _rings(cfg: ModelConfig, one) -> dict:
     (a family's rings are all read in blocks or none is)."""
     by_len, out = {}, {}
     for kind in cfg.layer_kinds():
-        if kind in RECURRENT_KINDS or kind == "none" or kind in out:
+        if KINDS[kind].blocks is None or kind in out:
             continue
         M = cfg.ring_len(kind)
         if M not in by_len:
-            by_len[M] = one(M, cfg.ring_window(kind), kind in BLOCKED_KINDS)
+            by_len[M] = one(M, cfg.ring_window(kind), KINDS[kind].blocks)
         out[kind] = by_len[M]
     return out
 
@@ -761,79 +731,223 @@ def _attend_ring_blocked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out.transpose(0, 3, 1, 2, 4).reshape(B, L, H * d)
 
 
-def _mixer_chunk(x, blk: dict, layer_cache: dict, cfg: ModelConfig, pos,
-                 kind: str, ring: Optional[_Ring], valid):
-    """A layer's mixer over a chunk ``x`` (B, L, E): ``(its output, the
-    layer's cache after the chunk)``."""
-    if "mamba" in blk:
-        with jax.named_scope("ssm"):
-            h = jamba.norm(x, blk["ln1"], cfg)
-            a, conv, ssm = jamba.mixer_chunk(
-                h, blk["mamba"], cfg, layer_cache["conv"],
-                layer_cache["ssm"], valid)
-        return a, {"ssm": ssm, "conv": conv}
-    if "mamba2" in blk:
-        with jax.named_scope("ssm"):
-            h = jamba.norm(x, blk["ln1"], cfg)
-            a, conv, ssm = nemotron_h.mixer_chunk(
-                h, blk["mamba2"], cfg, layer_cache["conv"],
-                layer_cache["ssm"], valid)
-        return a, {"ssm": ssm, "conv": conv}
-    if "kda" in blk:
-        with jax.named_scope("kda"):
-            h = jamba.norm(x, blk["ln1"], cfg)
-            a, conv, state = kimi_linear.kda_chunk(
-                h, blk["kda"], cfg, layer_cache["conv"], layer_cache["kda"],
-                valid)
-        return a, {"kda": state, "conv": conv}
-    if "mla" in blk:
-        with jax.named_scope("mla"):
-            h = jamba.norm(x, blk["ln1"], cfg)
-            with jax.named_scope("mla_latent_write"):
-                rows = kimi_linear.mla_latent(
-                    h, blk["mla"], cfg,
-                    pos + jnp.arange(x.shape[1]) if cfg.mla_rotary else None)
-                latent = jax.lax.dynamic_update_slice(
-                    layer_cache["latent"],
-                    rows[:, None].astype(layer_cache["latent"].dtype),
-                    (0, 0, ring.at, 0))
-            # the widened form, the ring in blocks as far as it is written
-            a = kimi_linear.mla_chunk_attend(h, blk["mla"], cfg,
-                                             latent[:, 0], pos)
-        return a, {"latent": latent}
-    with jax.named_scope("attn_norm"):
-        h = jamba.norm(x, blk["ln1"], cfg)
-    if kind in BLOCKED_KINDS:
-        # afmoe's attention is gated and normed a head (its leaves hold
-        # ``wg``); nemotron_h's is jamba's plain grouped-query one
-        gated = "wg" in blk["attn"]
-        with jax.named_scope("attn"):
-            if gated:
-                q, k, v, g = afmoe.qkvg(h, blk["attn"], cfg,
-                                        pos + jnp.arange(x.shape[1]), kind)
-            else:
-                q, k, v = jamba.qkv(h, blk["attn"])
-            with jax.named_scope("kv_write"):
-                write = (_write_chunk_wrapping if kind == "window"
-                         else _write_chunk)
-                layer_cache = write(layer_cache, k[None], v, ring.at)
+def _latent_chunk(h, blk, layer_cache, cfg, pos, ring, valid):
+    with jax.named_scope("mla"):
+        with jax.named_scope("mla_latent_write"):
+            rows = kimi_linear.mla_latent(
+                h, blk["mla"], cfg,
+                pos + jnp.arange(h.shape[1]) if cfg.mla_rotary else None)
+            latent = jax.lax.dynamic_update_slice(
+                layer_cache["latent"],
+                rows[:, None].astype(layer_cache["latent"].dtype),
+                (0, 0, ring.at, 0))
+        # the widened form, the ring in blocks as far as it is written
+        a = kimi_linear.mla_chunk_attend(h, blk["mla"], cfg, latent[:, 0],
+                                         pos)
+    return a, {"latent": latent}
+
+
+def _latent_step(h, blk, layer_cache, cfg, live, pos, ring):
+    with jax.named_scope("mla"):
+        with jax.named_scope("mla_latent_write"):
+            rows = kimi_linear.mla_latent(
+                h, blk["mla"], cfg, pos if cfg.mla_rotary else None)
+            layer_cache = _write_ring(
+                layer_cache,
+                {"latent": rows[:, None].astype(
+                    layer_cache["latent"].dtype)}, ring.at)
+        # the absorbed form, a row's live blocks alone
+        a = kimi_linear.mla_step_attend(
+            h, blk["mla"], cfg, layer_cache["latent"], pos, live)
+    return a, layer_cache
+
+
+def _ring_chunk(h, blk, layer_cache, cfg, pos, ring, valid, *, kind, write):
+    ring_qkv, ring_out = RING_ATTENTION[cfg.model]
+    with jax.named_scope("attn"):
+        q, k, v, rest = ring_qkv(h, blk["attn"], cfg, pos, kind)
+        with jax.named_scope("kv_write"):
+            layer_cache = write(layer_cache, k[None], v, ring.at)
+        if ring.visible is None:  # in blocks, as far as the ring is written
             with jax.named_scope("attn_" + kind):
                 o = _attend_ring_blocked(
                     q, layer_cache["k"][0].astype(q.dtype),
                     layer_cache["v"].astype(q.dtype), pos, ring.window)
-            if not gated:
-                return o @ blk["attn"]["out"]["w"].astype(q.dtype), layer_cache
-            a = afmoe.gate_out(o, g, blk["attn"])
-        with jax.named_scope("attn_norm"):
-            return jamba.norm(a, blk["ln1_post"], cfg), layer_cache
+        else:
+            k_c, v_c = _dequant_layer(layer_cache, q.dtype)
+            o = jamba.attend(q, k_c[0], v_c, ring.visible)
+    return ring_out(o, rest, blk, cfg), layer_cache
+
+
+def _ring_step(h, blk, layer_cache, cfg, live, pos, ring, *, kind):
+    ring_qkv, ring_out = RING_ATTENTION[cfg.model]
     with jax.named_scope("attn"):
-        q, k, v = jamba.qkv(h, blk["attn"])
+        q, k, v, rest = ring_qkv(h, blk["attn"], cfg, pos, kind)
         with jax.named_scope("kv_write"):
-            layer_cache = _write_chunk(layer_cache, k[None], v, ring.at)
+            layer_cache = _write_ring(
+                layer_cache, _store_rows(layer_cache, k[None], v), ring.at)
         k_c, v_c = _dequant_layer(layer_cache, q.dtype)
-        a = jamba.attend(q, k_c[0], v_c, ring.visible) @ blk["attn"][
-            "out"]["w"].astype(q.dtype)
-    return a, layer_cache
+        if ring.visible is None:  # a row's live ring blocks alone
+            with jax.named_scope("attn_" + kind):
+                o = ring_decode_attention(q, k_c[0], v_c, pos, live,
+                                          ring.window)
+        else:  # every slot's ring whole
+            o = jamba.attend(q[:, None], k_c[0], v_c, ring.visible)[:, 0]
+    return ring_out(o, rest, blk, cfg), layer_cache
+
+
+def _recurrent(params: str, state: str, mixer_chunk, mixer_step, zero_state,
+               **record) -> LayerKind:
+    """A kind that keeps ``state`` (its scope's name too) beside a
+    convolution's window: the family's mixer carries both on from where the
+    last chunk left them, as far as ``valid`` goes, and a step overwrites
+    them for the ``live`` slots; ``zero_state`` is a sequence's start."""
+
+    def run(fn, h, blk, layer_cache, cfg, until):
+        with jax.named_scope(state):
+            a, conv, new = fn(h, blk[params], cfg, layer_cache["conv"],
+                              layer_cache[state], until)
+        return a, {state: new, "conv": conv}
+
+    return LayerKind(
+        params=params, state=state, scope=state, leaves={state: 0, "conv": 0},
+        zeros=lambda cfg, rows, M: dict(
+            zip(("conv", state), zero_state(cfg, rows))),
+        chunk=lambda h, blk, c, cfg, pos, ring, valid: run(
+            mixer_chunk, h, blk, c, cfg, valid),
+        step=lambda h, blk, c, cfg, live, pos, ring: run(
+            mixer_step, h, blk, c, cfg, live), **record)
+
+
+def _kv_ring(kind: str, blocks: bool, write: Callable = _write_chunk,
+             **record) -> LayerKind:
+    """A K/V ring in the family's own flavour (``RING_ATTENTION``);
+    ``write`` puts a chunk into it."""
+    return LayerKind(
+        params="attn", blocks=blocks, zeros=_kv_zeros,
+        leaves={"k": 1, "v": 0, "k_scale": 1, "v_scale": 0},
+        chunk=partial(_ring_chunk, kind=kind, write=write),
+        step=partial(_ring_step, kind=kind), **record)
+
+
+def _refusals(lead: str, tail: str, int8: str, **asked: str) -> dict:
+    return dict({feature: lead + what + tail
+                 for feature, what in asked.items()},
+                int8="kv_cache_dtype='int8' is not available for the "
+                     "{model} family: " + int8)
+
+
+def _no_snapshot(drafts: str = "") -> dict:
+    """A K/V ring can be cut, shared, rolled back or shipped at any
+    position; a recurrent state is overwritten every token and what it was
+    earlier is gone. ``{mixers}``: the configuration's recurrent kinds."""
+    return _refusals(
+        "the {model} family keeps a recurrent state a {mixers} layer, and ",
+        " needs a snapshot of that state at a position, which the engine "
+        "does not take",
+        int8="its attention layers' decode path reads float rings "
+             "(grouped-query K/V, or MLA's latents), and a quantized "
+             "{mixers} state does not exist yet (the state is float32)",
+        host_tier="the host tier (host_tier_bytes; preemption and resume)",
+        spec="speculation (spec_mode; rejected drafts roll the cache back"
+             + drafts + ")",
+        paging="paging (kv_page_size > 0; with it the prefix cache, whose "
+               "hits resume a sequence at the shared prefix's end)")
+
+
+# serving/pages.py keeps one page table a slot for every layer, and a page,
+# a rolled-back draft or a stashed slot means the same ring positions in
+# every layer: not where the sliding rings are shorter, and roll
+_TWO_RING_LENGTHS = _refusals(
+    "the {model} family keeps rings of two lengths a slot (a sliding "
+    "layer's of {ring}, a full layer's of {block}), and ", "",
+    int8="its prefill writes a chunk into a rolling ring by a select over "
+         "the float ring, and its grouped-query decode path reads float "
+         "rings",
+    host_tier="the host tier (host_tier_bytes) stashes and restores a slot "
+              "as pages of one page table",
+    spec="speculation (spec_mode) verifies several rows a slot in one "
+         "step, whose writes into a rolled sliding ring would evict keys "
+         "that the step's earlier rows still see, and whose rejected rows "
+         "cannot be rolled back there",
+    paging="paging (kv_page_size > 0; with it the prefix cache) maps every "
+           "layer's ring through ONE page table a slot, block_size long")
+
+# a ring of latents is addressed by position like a K/V ring and all rings
+# are of one length; what is missing is code, named here
+_LATENT_RING = _refusals(
+    "the {model} family keeps a ring of latents a slot and MLA layer, and ",
+    "",
+    int8="int8 latents do not exist yet (quantize_kv scales a K/V head; a "
+         "latent is key and value of every head at once, and its shared "
+         "key part would need a scale of its own), and the live-latent "
+         "read takes float latents",
+    host_tier="the host tier (host_tier_bytes) stashes and restores a slot "
+              "as the pages of a page table, which this pool does not have "
+              "(no paging over latents yet)",
+    spec="speculation (spec_mode) verifies several rows a slot in one "
+         "step: the hybrid decode loop advances one row a slot, and the "
+         "live-latent read (ops/mla.py latent_decode_attention) takes one "
+         "query position a slot",
+    paging="paging (kv_page_size > 0; with it the prefix cache) maps K and "
+           "V leaves through a page table: serving/pages.py and the paged "
+           "decode programs know no `latent` leaf, and the live-latent "
+           "read takes a slot's ring whole, not pages")
+
+
+KINDS = {
+    # grouped-query attention over the whole ring under a mask (jamba's; the
+    # ring cannot roll); the reference families keep the same leaves
+    "attention": _kv_ring("attention", blocks=False),
+    # a Mamba-1 mixer (jamba): ssm (B, N, Di) in ``ssm_state_dtype`` and the
+    # convolution's last inputs, conv (B, K-1, Di)
+    "mamba": _recurrent(
+        "mamba", "ssm", jamba.mixer_chunk, jamba.mixer_step,
+        jamba.zero_state, name="Mamba", refuses=_no_snapshot()),
+    # a Mamba-2 (SSD) mixer (nemotron_h): ssm (B, N, heads x P) float32, conv
+    # (B, K-1, Di + 2 n_groups N) over x, B and C together
+    "mamba2": _recurrent(
+        "mamba2", "ssm", nemotron_h.mixer_chunk, nemotron_h.mixer_step,
+        nemotron_h.zero_state, name="Mamba-2", refuses=_no_snapshot(
+            "; the published multi-token-prediction module, which this "
+            "family leaves out, would be its draft head")),
+    # a KDA delta-rule mixer (kimi_linear): kda (B, H, d, d) float32, conv
+    # (B, K-1, 3 H d) over q, k and v
+    "kda": _recurrent(
+        "kda", "kda", kimi_linear.kda_chunk, kimi_linear.kda_step,
+        kimi_linear.kda_zero_state, name="KDA", refuses=_no_snapshot()),
+    # sliding-window attention (afmoe): a ring of ``sliding_ring`` positions
+    # that rolls, shorter than the slot's full rings
+    "window": _kv_ring("window", True, _write_chunk_wrapping, rolls=True,
+                       refuses=_TWO_RING_LENGTHS),
+    # attention over every earlier position, without one of its own (afmoe's
+    # full layers, nemotron_h's ``*`` layers)
+    "full": _kv_ring("full", blocks=True),
+    # MLA over a ring of latents (kimi_linear's MLA layers, every deepseek_v2
+    # layer): ONE "head" a position, rank + rope wide, that every head reads
+    "latent": LayerKind(
+        params="mla", blocks=True, latents=True, scope="mla",
+        refuses=_LATENT_RING, chunk=_latent_chunk, step=_latent_step,
+        leaves={"latent": 0},
+        zeros=lambda cfg, rows, M: {"latent": jnp.zeros(
+            (rows, 1, M, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+            jnp.dtype(cfg.compute_dtype))}),
+    # no mixer: the layer is its feed-forward part alone (a nemotron_h ``E``
+    # layer); its cache entry is ``{}``, which a walk over its items passes by
+    "none": LayerKind(),
+}
+
+# Each cache leaf's pool axis (K and its scales carry the stream axis first):
+# the single source of truth for every per-slot slice/scatter/merge over the
+# cache (serving/engine.py), and the page axis; the recurrent kinds' leaves;
+# the block's leaves that hold a mixer.
+KV_CACHE_BATCH_AXIS = {leaf: axis for record in KINDS.values()
+                       for leaf, axis in record.leaves.items()}
+STATE_LEAVES = tuple(dict.fromkeys(
+    leaf for record in KINDS.values() if record.recurrent
+    for leaf in record.leaves))
+MIXER_LEAVES = tuple(dict.fromkeys(
+    record.params for record in KINDS.values() if record.params))
 
 
 def _mlp(x, blk: dict, cfg: ModelConfig, live=None):
@@ -850,9 +964,40 @@ def _mlp(x, blk: dict, cfg: ModelConfig, live=None):
     return jamba.ffn(x, blk, cfg), None
 
 
-def _embed(params: dict, tokens: jnp.ndarray, cfg: ModelConfig):
-    """The family's own ``embed`` (afmoe scales the token table)."""
-    return HYBRID[cfg.model].embed(params, tokens, cfg)
+def _hybrid_walk(params: dict, tokens: jnp.ndarray, cache: list,
+                 cfg: ModelConfig, rings: dict, mixer, live=None, valid=None):
+    """The hybrid families' stack over a chunk or a step: embed; a layer:
+    the pre-norm, ``mixer(record, h, blk, layer_cache, ring)`` (the
+    record's ``chunk`` or ``step`` at the caller's positions), the
+    residual, the MLP; the head, for a padded chunk on the row of its last
+    real token (``valid``). A layer without a mixer hands its empty cache
+    entry on. A step (``live`` given) gathers the experts' load. Returns
+    ``(logits, the cache after, the loads' sum or None)``."""
+    x = HYBRID[cfg.model].embed(params, tokens, cfg)  # afmoe's is scaled
+    new_cache, loads = [], []
+    for blk, layer_cache, kind in zip(params["blocks"], cache,
+                                      cfg.layer_kinds()):
+        record = KINDS[kind]
+        if record.params:
+            with jax.named_scope(record.scope):
+                h = jamba.norm(x, blk["ln1"], cfg)
+            a, layer_cache = mixer(record, h, blk, layer_cache,
+                                   rings.get(kind))
+            x = x + a
+        new_cache.append(layer_cache)
+        x, load = _mlp(x, blk, cfg, live)
+        if load is not None and live is not None:
+            # a router limited to groups adds the rows that kept a held one
+            load, *reached = load if isinstance(load, tuple) else (load,)
+            loads.append(jnp.stack([jnp.sum(load), jnp.max(load),
+                                    jnp.sum(load > 0), *reached]))
+    if valid is not None:
+        x = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
+    with jax.named_scope("lm_head"):
+        # the loads' sum has always stood under this scope: the device
+        # time the per-layer metrics count as the head's holds it
+        return (jamba.lm_head(params, x, cfg), new_cache,
+                sum(loads) if loads else None)
 
 
 def _hybrid_chunk(params: dict, tokens: jnp.ndarray, pos, cache: list,
@@ -876,85 +1021,10 @@ def _hybrid_chunk(params: dict, tokens: jnp.ndarray, pos, cache: list,
         jax.lax.rem(jnp.asarray(pos, jnp.int32), M),
         None if blocked else _ring_visible(pos, L, M, int(window) or W),
         int(window) or W))
-    x = _embed(params, tokens, cfg)
-    new_cache = []
-    for blk, layer_cache, kind in zip(params["blocks"], cache,
-                                      cfg.layer_kinds()):
-        if _has_mixer(blk):  # else the layer's empty entry goes on as it is
-            a, layer_cache = _mixer_chunk(x, blk, layer_cache, cfg, pos,
-                                          kind, rings.get(kind), valid)
-            x = x + a
-        new_cache.append(layer_cache)
-        x, _ = _mlp(x, blk, cfg)
-    if valid is not None:
-        x = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
-    with jax.named_scope("lm_head"):
-        return jamba.lm_head(params, x, cfg), new_cache
-
-
-def _mixer_step(x, blk: dict, layer_cache: dict, cfg: ModelConfig, live,
-                pos, kind: str, ring: Optional[_Ring]):
-    """A layer's mixer for one token a slot, ``x`` (B, E): ``(its output,
-    the layer's cache after the step)``."""
-    if "mamba" in blk:
-        with jax.named_scope("ssm"):
-            h = jamba.norm(x, blk["ln1"], cfg)
-            a, conv, ssm = jamba.mixer_step(
-                h, blk["mamba"], cfg, layer_cache["conv"],
-                layer_cache["ssm"], live)
-        return a, {"ssm": ssm, "conv": conv}
-    if "mamba2" in blk:
-        with jax.named_scope("ssm"):
-            h = jamba.norm(x, blk["ln1"], cfg)
-            a, conv, ssm = nemotron_h.mixer_step(
-                h, blk["mamba2"], cfg, layer_cache["conv"],
-                layer_cache["ssm"], live)
-        return a, {"ssm": ssm, "conv": conv}
-    if "kda" in blk:
-        with jax.named_scope("kda"):
-            h = jamba.norm(x, blk["ln1"], cfg)
-            a, conv, state = kimi_linear.kda_step(
-                h, blk["kda"], cfg, layer_cache["conv"], layer_cache["kda"],
-                live)
-        return a, {"kda": state, "conv": conv}
-    if "mla" in blk:
-        with jax.named_scope("mla"):
-            h = jamba.norm(x, blk["ln1"], cfg)
-            with jax.named_scope("mla_latent_write"):
-                rows = kimi_linear.mla_latent(
-                    h, blk["mla"], cfg, pos if cfg.mla_rotary else None)
-                layer_cache = _write_ring(
-                    layer_cache,
-                    {"latent": rows[:, None].astype(
-                        layer_cache["latent"].dtype)}, ring.at)
-            # the absorbed form, a row's live blocks alone
-            a = kimi_linear.mla_step_attend(
-                h, blk["mla"], cfg, layer_cache["latent"], pos, live)
-        return a, layer_cache
-    gated = "wg" in blk["attn"]  # afmoe's; nemotron_h's is jamba's plain one
-    with jax.named_scope("attn_norm"):
-        h = jamba.norm(x, blk["ln1"], cfg)
-    with jax.named_scope("attn"):
-        if gated:
-            q, k, v, g = afmoe.qkvg(h, blk["attn"], cfg, pos, kind)
-        else:
-            q, k, v = jamba.qkv(h, blk["attn"])
-        with jax.named_scope("kv_write"):
-            layer_cache = _write_ring(
-                layer_cache, _store_rows(layer_cache, k[None], v), ring.at)
-        k_c, v_c = _dequant_layer(layer_cache, q.dtype)
-        if kind not in BLOCKED_KINDS:
-            a = jamba.attend(q[:, None], k_c[0], v_c, ring.visible)[:, 0] @ blk[
-                "attn"]["out"]["w"].astype(q.dtype)
-            return a, layer_cache
-        with jax.named_scope("attn_" + kind):
-            # a row's live ring blocks alone, not every slot's ring whole
-            o = ring_decode_attention(q, k_c[0], v_c, pos, live, ring.window)
-        if not gated:
-            return o @ blk["attn"]["out"]["w"].astype(q.dtype), layer_cache
-        a = afmoe.gate_out(o, g, blk["attn"])
-    with jax.named_scope("attn_norm"):
-        return jamba.norm(a, blk["ln1_post"], cfg), layer_cache
+    return _hybrid_walk(
+        params, tokens, cache, cfg, rings,
+        lambda record, h, blk, layer_cache, ring: record.chunk(
+            h, blk, layer_cache, cfg, pos, ring, valid), valid=valid)[:2]
 
 
 def _hybrid_decode(params: dict, tokens: jnp.ndarray, pos, cache: list,
@@ -962,22 +1032,18 @@ def _hybrid_decode(params: dict, tokens: jnp.ndarray, pos, cache: list,
     """The hybrid families' decode step over the whole slot pool, one
     batch: ``((B, V) logits, updated cache, expert load)``. The attention
     layers write their row into the ring in place (``ops/kv_write.py``)
-    and read the pool (afmoe's and nemotron_h's a row's live ring blocks
-    alone, ``ops/ring_attention.py``, an MLA layer's a row's live latent
-    blocks, ``ops/mla.py``; jamba's every ring whole under a mask); the
-    recurrent layers advance the active
-    slots' states (``ops/ssm.py``, ``ops/kda.py``). A row that is not ``active``
-    leaves every leaf of its slot as it is and meets no expert. A layer
-    is what its leaves say: one without a mixer leaf (a nemotron_h ``E``
-    layer) runs no mixer and hands its empty cache entry on, one without
-    an ``ffn`` or ``moe`` leaf (its ``M`` and ``*`` layers) has no second
-    half. ``load``
-    (3,) int32, summed over the expert layers: the (row, expert)
-    assignments that fell on held experts, the largest count on one
-    expert, and the held experts that got a row at all (whose weights the
-    step had to read); a router limited to groups (deepseek_v2) adds a
-    fourth, the live rows that kept a group this share holds, which alone
-    can meet a held expert. None for a family without experts."""
+    and read the pool (a row's live ring or latent blocks alone,
+    ``ops/ring_attention.py``, ``ops/mla.py``; jamba's every ring whole
+    under a mask); the recurrent layers advance the active slots' states
+    (``ops/ssm.py``, ``ops/ssd.py``, ``ops/kda.py``). A row that is not
+    ``active`` leaves every leaf of its slot as it is and meets no
+    expert. ``load`` (3,) int32, summed over the expert layers:
+    the (row, expert) assignments that fell on held experts, the largest
+    count on one expert, and the held experts that got a row at all (whose
+    weights the step had to read); a router limited to groups
+    (deepseek_v2) adds a fourth, the live rows that kept a group this
+    share holds, which alone can meet a held expert. None for a family
+    without experts."""
     B = tokens.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     live = jnp.ones((B,), bool) if active is None else active
@@ -991,25 +1057,10 @@ def _hybrid_decode(params: dict, tokens: jnp.ndarray, pos, cache: list,
                    jnp.arange(M)[None, None, :] <= pos[:, None, None])
         return _Ring(_write_targets(pos, active, M), visible, W)
 
-    rings = _rings(cfg, ring)
-    x = _embed(params, tokens, cfg)  # (B, E)
-    new_cache, loads = [], []
-    for blk, layer_cache, kind in zip(params["blocks"], cache,
-                                      cfg.layer_kinds()):
-        if _has_mixer(blk):
-            a, layer_cache = _mixer_step(x, blk, layer_cache, cfg, live,
-                                         pos, kind, rings.get(kind))
-            x = x + a
-        new_cache.append(layer_cache)
-        x, load = _mlp(x, blk, cfg, live)
-        if load is not None:
-            # a router limited to groups adds the rows that kept a held one
-            load, *reached = load if isinstance(load, tuple) else (load,)
-            loads.append(jnp.stack([jnp.sum(load), jnp.max(load),
-                                    jnp.sum(load > 0), *reached]))
-    with jax.named_scope("lm_head"):
-        return (jamba.lm_head(params, x, cfg), new_cache,
-                sum(loads) if loads else None)
+    return _hybrid_walk(
+        params, tokens, cache, cfg, _rings(cfg, ring),
+        lambda record, h, blk, layer_cache, ring: record.step(
+            h, blk, layer_cache, cfg, live, pos, ring), live=live)
 
 
 def live_kv(pos: np.ndarray, active: np.ndarray, window: int) -> dict:
@@ -1029,8 +1080,8 @@ def live_kv(pos: np.ndarray, active: np.ndarray, window: int) -> dict:
 
 
 def reset_slot_state(cache: list, slot) -> list:
-    """``cache`` with slot ``slot``'s recurrent state (every Mamba,
-    Mamba-2 or KDA layer's ``STATE_LEAVES``) zeroed, in place under a jit
+    """``cache`` with slot ``slot``'s recurrent state (the leaves of every
+    recurrent kind's layers, ``STATE_LEAVES``) zeroed, in place under a jit
     that donates the pool; rings are left as they are (positions mask
     them) and a layer without a mixer has nothing to zero. ``slot`` is a
     runtime scalar."""

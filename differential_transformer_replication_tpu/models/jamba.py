@@ -239,6 +239,20 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out.reshape(B, L, H * d)
 
 
+def ring_qkv(h: jnp.ndarray, p: dict, cfg: ModelConfig, pos, kind: str):
+    """What the ring chunk and step of models/decode.py take from a
+    family: q, k, v and what its ``ring_out`` needs besides (here nothing
+    is normed or rotated, whatever the position and the kind)."""
+    del cfg, pos, kind
+    return (*qkv(h, p), None)
+
+
+def ring_out(o: jnp.ndarray, _, blk: dict, cfg: ModelConfig) -> jnp.ndarray:
+    """The joined heads ``o`` through the output projection."""
+    with jax.named_scope("attn"):
+        return o @ blk["attn"]["out"]["w"].astype(o.dtype)
+
+
 def _attn_full(h: jnp.ndarray, p: dict) -> jnp.ndarray:
     T = h.shape[1]
     q, k, v = qkv(h, p)

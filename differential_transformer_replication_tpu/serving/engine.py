@@ -92,6 +92,7 @@ from differential_transformer_replication_tpu.config import (
     ServingConfig,
 )
 from differential_transformer_replication_tpu.models.decode import (
+    KINDS,
     KV_CACHE_BATCH_AXIS,
     apply_logit_pipeline,
     attend_rows,
@@ -106,7 +107,6 @@ from differential_transformer_replication_tpu.models.decode import (
     init_cache,
     init_cache_paged,
     reset_slot_state,
-    STATE_LEAVES,
     kv_store_dtype,
     own_ring_attend,
     quality_vector,
@@ -814,126 +814,36 @@ def _prng_key_words(seed: int) -> np.ndarray:
     return np.asarray(jax.random.PRNGKey(seed), np.uint32)
 
 
-def _refuse_for_recurrent_state(cfg: ModelConfig,
-                                serving: ServingConfig) -> None:
-    """The engine features that address a sequence's state BY POSITION,
-    each refused by name for a family whose layers hold a recurrent state
-    (``jamba``'s Mamba layers, ``kimi_linear``'s KDA layers,
-    ``nemotron_h``'s Mamba-2 layers): a K/V ring can be cut, shared,
-    rolled back or shipped at any position, a recurrent state is
-    overwritten every token and what it was at an earlier position is
-    gone. The message names the mixers the configuration has."""
-    mixers = " or ".join(
-        name for kind, name in (("mamba", "Mamba"), ("mamba2", "Mamba-2"),
-                                ("kda", "KDA"))
-        if kind in cfg.layer_kinds())
-    lacks = ("the {} family keeps a recurrent state a " + mixers + " layer, "
-             "and {} needs a snapshot of that state at a position, which "
-             "the engine does not take")
-    drafts = ("; the published multi-token-prediction module, which this "
-              "family leaves out, would be its draft head"
-              if cfg.model == "nemotron_h" else "")
-    asked = (
-        ("the host tier (host_tier_bytes; preemption and resume)",
-         serving.host_tier_bytes > 0),
-        ("speculation (spec_mode; rejected drafts roll the cache back"
-         + drafts + ")", serving.spec_enabled()),
-        ("paging (kv_page_size > 0; with it the prefix cache, whose hits "
-         "resume a sequence at the shared prefix's end)",
-         serving.paged()),
-    )
-    for what, on in asked:
-        if on:
-            raise ValueError(lacks.format(cfg.model, what))
-    if cfg.kv_cache_dtype == "int8":
-        raise ValueError(
-            f"kv_cache_dtype='int8' is not available for the {cfg.model} "
-            "family: its attention layers' decode path reads float rings "
-            "(grouped-query K/V, or MLA's latents), and a quantized "
-            + mixers + " state does not exist yet (the state is float32)"
-        )
-
-
-def _refuse_for_two_ring_lengths(cfg: ModelConfig,
-                                 serving: ServingConfig) -> None:
-    """The engine features that assume ONE ring length a slot, each
-    refused by name for a family whose slot holds rings of two (``afmoe``:
-    a sliding layer's ring is shorter than a full layer's and rolls):
-    ``serving/pages.py`` keeps one page table a slot for every layer, and
-    a page, a rolled-back draft or a stashed slot means the same ring
-    positions in every layer."""
-    lacks = ("the {} family keeps rings of two lengths a slot (a sliding "
-             "layer's of {}, a full layer's of {}), and {}")
-    asked = (
-        ("the host tier (host_tier_bytes) stashes and restores a slot as "
-         "pages of one page table", serving.host_tier_bytes > 0),
-        ("speculation (spec_mode) verifies several rows a slot in one "
-         "step, whose writes into a rolled sliding ring would evict keys "
-         "that the step's earlier rows still see, and whose rejected rows "
-         "cannot be rolled back there", serving.spec_enabled()),
-        ("paging (kv_page_size > 0; with it the prefix cache) maps every "
-         "layer's ring through ONE page table a slot, block_size long",
-         serving.paged()),
-    )
-    for what, on in asked:
-        if on:
-            raise ValueError(lacks.format(
-                cfg.model, cfg.ring_len("window"), cfg.block_size, what))
-    if cfg.kv_cache_dtype == "int8":
-        raise ValueError(
-            f"kv_cache_dtype='int8' is not available for the {cfg.model} "
-            "family: its prefill writes a chunk into a rolling ring by a "
-            "select over the float ring, and its grouped-query decode path "
-            "reads float rings"
-        )
-    if serving.prefill_chunk > cfg.ring_slack:
-        raise ValueError(
-            f"prefill_chunk ({serving.prefill_chunk}) exceeds what the "
-            f"{cfg.model} family's sliding rings hold past their window "
-            f"(sliding_ring {cfg.ring_len('window')} - sliding_window "
-            f"{cfg.sliding_window} = {cfg.ring_slack}): a longer chunk "
-            "written at a rolled position would evict keys that its "
-            "earlier rows still see (models/decode.py)"
-        )
-
-
-def _refuse_for_latent_ring(cfg: ModelConfig,
+def _refuse_for_layer_kinds(cfg: ModelConfig,
                             serving: ServingConfig) -> None:
-    """The engine features that a pool with rings of latents does not
-    have yet (``deepseek_v2``: every layer; ``kimi_linear``: its MLA
-    layers), each refused under its own reason. Such a ring is addressed
-    by position like a K/V ring and all rings are of one length
-    (:func:`_refuse_for_two_ring_lengths`'s reason does not hold); what is
-    missing is code, named here. ``kimi_linear``'s KDA layers keep a
-    recurrent state besides, so :func:`_refuse_for_recurrent_state` has
-    refused each of these for it before this is asked; a configuration of
-    MLA layers alone meets these reasons."""
-    lacks = ("the {} family keeps a ring of latents a slot and MLA layer, "
-             "and {}")
-    asked = (
-        ("the host tier (host_tier_bytes) stashes and restores a slot as "
-         "the pages of a page table, which this pool does not have (no "
-         "paging over latents yet)", serving.host_tier_bytes > 0),
-        ("speculation (spec_mode) verifies several rows a slot in one "
-         "step: the hybrid decode loop advances one row a slot, and the "
-         "live-latent read (ops/mla.py latent_decode_attention) takes one "
-         "query position a slot", serving.spec_enabled()),
-        ("paging (kv_page_size > 0; with it the prefix cache) maps K and V "
-         "leaves through a page table: serving/pages.py and the paged "
-         "decode programs know no `latent` leaf, and the live-latent read "
-         "takes a slot's ring whole, not pages", serving.paged()),
-    )
-    for what, on in asked:
-        if on:
-            raise ValueError(lacks.format(cfg.model, what))
-    if cfg.kv_cache_dtype == "int8":
-        raise ValueError(
-            f"kv_cache_dtype='int8' is not available for the {cfg.model} "
-            "family: int8 latents do not exist yet (quantize_kv scales a "
-            "K/V head; a latent is key and value of every head at once, "
-            "and its shared key part would need a scale of its own), and "
-            "the live-latent read takes float latents"
-        )
+    """The engine features that a kind of ``cfg``'s layers cannot serve,
+    each refused under that kind's own reason (models/decode.py ``KINDS``:
+    ``refuses``, in the table's order: a recurrent state before rings of
+    two lengths before a ring of latents). A ring that rolls under
+    multi-token chunks also bounds ``prefill_chunk``."""
+    asked = {"host_tier": serving.host_tier_bytes > 0,
+             "spec": serving.spec_enabled(), "paging": serving.paged(),
+             "int8": cfg.kv_cache_dtype == "int8"}
+    here = {k: r for k, r in KINDS.items() if k in cfg.layer_kinds()}
+    mixers = " or ".join(r.name for r in here.values() if r.recurrent)
+    for kind, record in here.items():
+        for feature, on in asked.items():
+            if on and feature in record.refuses:
+                raise ValueError(record.refuses[feature].format(
+                    model=cfg.model, mixers=mixers, ring=cfg.ring_len(kind),
+                    block=cfg.block_size))
+        if record.rolls and serving.prefill_chunk > cfg.ring_slack:
+            raise ValueError(
+                f"prefill_chunk ({serving.prefill_chunk}) exceeds what the "
+                f"{cfg.model} family's sliding rings hold past their window "
+                f"(sliding_ring {cfg.ring_len(kind)} - sliding_window "
+                f"{cfg.sliding_window} = {cfg.ring_slack}): a longer chunk "
+                "written at a rolled position would evict keys that its "
+                "earlier rows still see (models/decode.py)")
+
+
+# the name benchmark/configs/nemotron3-super-11l-ep4.json knows it by
+_refuse_for_recurrent_state = _refuse_for_layer_kinds
 
 
 # fold_in salt distinguishing a draft position's ACCEPT-draw key from
@@ -1262,24 +1172,20 @@ class ServingEngine:
         if self.serving.kv_cache_dtype:
             cfg = cfg.replace(kv_cache_dtype=self.serving.kv_cache_dtype)
         self.cfg = cfg
+        # what a kind of this model's layers cannot serve refuses here
+        _refuse_for_layer_kinds(cfg, self.serving)
+        records = [KINDS[kind] for kind in cfg.layer_kinds()]
         # a sequence's state is overwritten every token in some layers:
-        # a slot is zeroed on admission (_run_prefill), and what needs the
-        # state at a position refuses here
+        # a slot is zeroed on admission (_run_prefill)
         self._recurrent = has_recurrent_state(cfg)
-        if self._recurrent:
-            _refuse_for_recurrent_state(cfg, self.serving)
-        # a slot holds rings of two lengths (afmoe's sliding and full
-        # layers): what assumes one refuses here, and the decode span
-        # says what the rows hold of each (models/decode.py live_kv)
-        self._window_layers = cfg.layer_kinds().count("window")
-        if self._window_layers:
-            _refuse_for_two_ring_lengths(cfg, self.serving)
+        # the layers whose ring rolls (afmoe's sliding layers, in a slot of
+        # rings of two lengths): the decode span says what the rows hold of
+        # each length (models/decode.py live_kv)
+        self._window_layers = sum(r.rolls for r in records)
         # the layers that keep a ring of latents, which the decode step
         # reads live (every deepseek_v2 layer, kimi_linear's MLA layers):
         # the decode span says how many latents the rows hold
-        self._latent_layers = cfg.layer_kinds().count("latent")
-        if self._latent_layers:
-            _refuse_for_latent_ring(cfg, self.serving)
+        self._latent_layers = sum(r.latents for r in records)
         # the hybrid families' prefill program takes a chunk padded to
         # the ladder's next shape (forward_chunk ``valid``): a prompt's
         # tail is one program, not one a binary digit of its length
@@ -1410,9 +1316,8 @@ class ServingEngine:
         # bytes of recurrent state a slot holds, over the layers (0 for a
         # family of rings): what a decode step moves a live row, one way
         self._state_bytes_per_slot = sum(
-            leaf.nbytes // leaf.shape[0] for layer in self.cache
-            for key, leaf in layer.items()
-            if key in STATE_LEAVES and key != "conv")
+            layer[r.state].nbytes // layer[r.state].shape[0]
+            for layer, r in zip(self.cache, records) if r.recurrent)
         # The late read (:meth:`step`): the device's own record of the
         # last sampled rows (the newest decode step's packed sampler
         # output, a completed prompt's first token written over its
@@ -1522,10 +1427,9 @@ class ServingEngine:
             "(afmoe), the rings of both; 0 for a family of K/V rings of "
             "one length.",
         ).set(
-            sum(leaf.nbytes for layer in self.cache
-                for key, leaf in layer.items()
-                if key in STATE_LEAVES or key == "latent"
-                or self._window_layers)
+            sum(leaf.nbytes for layer, r in zip(self.cache, records)
+                for leaf in layer.values()
+                if r.recurrent or r.latents or self._window_layers)
         )
         self.registry.gauge(
             "serving_kv_cache_bytes_per_slot",

@@ -666,6 +666,26 @@ class TestJitRegionDiscovery:
         f = next(x for x in res.active if x.rule == "GL101")
         assert f.path.endswith("impl.py")
 
+    @pytest.mark.parametrize("entry", [
+        "helper", "functools.partial(helper, k=1)", "make()"])
+    def test_callee_reached_through_a_module_level_table(self, tmp_path,
+                                                         entry):
+        """Dispatch through a table of functions (models/decode.py
+        ``KINDS[kind].step``): whoever reads the table may run what it
+        mentions, a ``partial`` handed on as a call argument included."""
+        res = lint_src(tmp_path, JIT_HEADER + (
+            "import functools\n"
+            "def helper(x, k=0):\n"
+            "    return x.item()\n"
+            "def make():\n"
+            "    return dict(step=functools.partial(helper, k=1))\n"
+            f"TABLE = {{'a': {entry}}}\n"
+            "@jax.jit\n"
+            "def f(x):\n"
+            "    return TABLE['a'](x)\n"
+        ))
+        assert "GL101" in active_ids(res)
+
     def test_unreached_helper_is_host_code(self, tmp_path):
         res = lint_src(tmp_path, JIT_HEADER + (
             "def helper(x):\n"
