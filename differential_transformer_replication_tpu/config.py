@@ -19,7 +19,7 @@ from dataclasses import dataclass, field
 from typing import Any, Optional, Tuple
 
 MODEL_KINDS = ("control", "diff", "ndiff", "jamba", "kimi_linear", "afmoe",
-               "deepseek_v2", "nemotron_h")
+               "deepseek_v2", "nemotron_h", "lfm2")
 
 # Fields only the ``jamba`` family reads. Another family given one of them
 # at a value other than its default is refused by name: a field that is
@@ -72,9 +72,19 @@ NEMOTRON_H_FIELDS = (
     "moe_hidden", "routed_scaling", "held_experts",
     "num_nextn_predict_layers", "mtp_hybrid_override_pattern",
 )
+# Fields only the ``lfm2`` family reads (the MLP's width, the K/V heads, the
+# norm's eps and the tied head it shares with ``jamba``, ``head_dim``,
+# ``layer_types`` and ``rope_theta`` with ``afmoe``, the experts' fields with
+# ``kimi_linear``), refused the same way.
+LFM2_FIELDS = (
+    "ffn_hidden", "kv_heads", "norm_eps", "tie_embeddings", "head_dim",
+    "layer_types", "rope_theta", "conv_taps", "router_eps", "num_experts",
+    "experts_per_token", "moe_hidden", "first_dense_layers",
+    "routed_scaling", "held_experts",
+)
 FAMILY_FIELDS = {"jamba": JAMBA_FIELDS, "kimi_linear": KIMI_LINEAR_FIELDS,
                  "afmoe": AFMOE_FIELDS, "deepseek_v2": DEEPSEEK_V2_FIELDS,
-                 "nemotron_h": NEMOTRON_H_FIELDS}
+                 "nemotron_h": NEMOTRON_H_FIELDS, "lfm2": LFM2_FIELDS}
 # ``hybrid_override_pattern`` as the published config.json spells it, a
 # letter a layer, and the mixer kind ``ModelConfig.layer_kinds`` gives each:
 # a Mamba-2 mixer, grouped-query attention without positions (read in
@@ -88,6 +98,11 @@ YARN_KEYS = ("factor", "beta_fast", "beta_slow", "mscale", "mscale_all_dim",
 # ``layer_types`` as the published config.json spells them, and the mixer
 # kind ``ModelConfig.layer_kinds`` gives each
 AFMOE_LAYER_TYPES = {"sliding_attention": "window", "full_attention": "full"}
+# the ``lfm2`` family's ``layer_types``: a gated short convolution whose
+# whole cache is its window, or grouped-query attention over every earlier
+# position (rotary, unlike afmoe's full layers: models/lfm2.py says so, the
+# kind does not)
+LFM2_LAYER_TYPES = {"conv": "shortconv", "full_attention": "full"}
 
 
 @dataclass(frozen=True)
@@ -328,6 +343,17 @@ class ModelConfig:
     # language model). Left out: any value but the default is refused.
     num_nextn_predict_layers: int = 0
     mtp_hybrid_override_pattern: str = ""
+    # -- the ``lfm2`` family's fields (LFM2_FIELDS; models/lfm2.py) ----------
+    # ``layer_types`` names a layer ``"conv"`` (a gated short convolution of
+    # ``conv_taps`` taps a channel, the published ``conv_L_cache``, over
+    # n_embd channels: a slot keeps its last ``conv_taps - 1`` gated inputs
+    # and nothing else) or ``"full_attention"`` (grouped-query attention,
+    # q and k normed a head and THEN rotated at ``rope_theta``, every
+    # earlier position visible). The experts' router divides the chosen
+    # scores by their sum plus ``router_eps`` (0 for the other families,
+    # whose published routers add nothing).
+    conv_taps: int = 3
+    router_eps: float = 0.0
 
     def __post_init__(self):
         if self.model not in MODEL_KINDS:
@@ -344,6 +370,7 @@ class ModelConfig:
         self._check_afmoe_fields()
         self._check_deepseek_v2_fields()
         self._check_nemotron_h_fields()
+        self._check_lfm2_fields()
         self._check_expert_fields()
         if self.attention_impl not in ("xla", "pallas"):
             raise ValueError(
@@ -430,10 +457,10 @@ class ModelConfig:
 
     def _check_expert_fields(self):
         """The fields of a family whose later layers hold routed experts
-        (kimi_linear, afmoe, deepseek_v2) or whose ``E`` layers do
+        (kimi_linear, afmoe, deepseek_v2, lfm2) or whose ``E`` layers do
         (nemotron_h)."""
         if self.model not in ("kimi_linear", "afmoe", "deepseek_v2",
-                              "nemotron_h"):
+                              "nemotron_h", "lfm2"):
             return
         for name in ("moe_hidden", "experts_per_token"):
             if getattr(self, name) < 1:
@@ -623,6 +650,48 @@ class ModelConfig:
                 "expert's width) and moe_latent_size >= 0, got "
                 f"{self.moe_shared_hidden} and {self.moe_latent_size}")
 
+    def _check_lfm2_fields(self):
+        if self.model != "lfm2":
+            return
+        for name in ("attention_impl", "ffn_impl", "decode_attention_impl"):
+            if getattr(self, name) != "xla":
+                raise ValueError(
+                    f"the lfm2 family takes {name}='xla' only, got "
+                    f"{getattr(self, name)!r}: the kernels that 'pallas' "
+                    "selects (ops/flash.py, ops/fused_ffn.py, "
+                    "ops/decode_attention.py) know neither grouped K/V "
+                    "heads, normed heads, a convolution's window nor "
+                    "experts. The option chooses nothing for this family: "
+                    "its decode step reads the attention layers' rings "
+                    "through ring_gqa_decode_fwd (ops/ring_attention.py) "
+                    "whatever it says, and its convolution is three "
+                    "elementwise taps"
+                )
+        if self.dropout:
+            raise ValueError("the lfm2 family has no dropout")
+        if len(self.layer_types) != self.n_layer or any(
+                t not in LFM2_LAYER_TYPES for t in self.layer_types):
+            raise ValueError(
+                f"layer_types must name each of the {self.n_layer} layers "
+                f"as one of {sorted(LFM2_LAYER_TYPES)}, got "
+                f"{self.layer_types}"
+            )
+        if self.n_head % self.n_kv_head:
+            raise ValueError(
+                f"n_head ({self.n_head}) must divide by kv_heads "
+                f"({self.n_kv_head})"
+            )
+        if self.head_size % 2:
+            raise ValueError(
+                f"head_dim ({self.head_size}) must be even: the attention "
+                "layers rotate dimension i with i + head_dim / 2"
+            )
+        if self.conv_taps < 2:
+            raise ValueError("conv_taps must be >= 2 (a carried window)")
+        if self.router_eps < 0:
+            raise ValueError(
+                f"router_eps must be >= 0, got {self.router_eps}")
+
     def _check_jamba_fields(self):
         if self.model != "jamba":
             return
@@ -672,8 +741,8 @@ class ModelConfig:
 
     @property
     def resolved_norm_eps(self) -> float:
-        """eps of the jamba, kimi_linear, afmoe, deepseek_v2 and nemotron_h
-        families' RMSNorm."""
+        """eps of the jamba, kimi_linear, afmoe, deepseek_v2, nemotron_h and
+        lfm2 families' RMSNorm."""
         return self.norm_eps or 1e-6
 
     @property
@@ -706,9 +775,17 @@ class ModelConfig:
         inside that bound, in rings of their own length
         (:meth:`ring_len`). deepseek_v2's layers do carry positions, but
         they see EVERY earlier one: a rolled ring of latents would make
-        them sliding-window layers, which the model is not."""
+        them sliding-window layers, which the model is not; lfm2's
+        attention layers likewise (:attr:`full_layers_rotate`)."""
         return self.model in ("diff", "jamba", "kimi_linear", "afmoe",
-                              "deepseek_v2", "nemotron_h")
+                              "deepseek_v2", "nemotron_h", "lfm2")
+
+    @property
+    def full_layers_rotate(self) -> bool:
+        """Whether the ``"full"`` layers carry positions (lfm2's rotate q
+        and k; afmoe's and nemotron_h's carry none). Either way they see
+        every earlier position and their ring cannot roll."""
+        return self.model == "lfm2"
 
     def ring_len(self, kind: str) -> int:
         """Positions the ring of a layer of mixer ``kind`` holds a slot
@@ -776,7 +853,8 @@ class ModelConfig:
         (jamba), ``"kda"`` or ``"latent"`` (kimi_linear), ``"window"`` or
         ``"full"`` (afmoe), ``"latent"`` (deepseek_v2); ``"mamba2"``,
         ``"full"`` or ``"none"`` (nemotron_h: a layer that is an expert
-        feed-forward part alone has no mixer and keeps no cache). The
+        feed-forward part alone has no mixer and keeps no cache);
+        ``"shortconv"`` or ``"full"`` (lfm2). The
         reference families attend in every layer. ``"latent"`` is MLA over
         a ring of latents, read in blocks as far as it is live
         (ops/mla.py) whichever family keeps it: whether its key part is
@@ -788,6 +866,8 @@ class ModelConfig:
             return ("latent",) * self.n_layer
         if self.model == "afmoe":
             return tuple(AFMOE_LAYER_TYPES[t] for t in self.layer_types)
+        if self.model == "lfm2":
+            return tuple(LFM2_LAYER_TYPES[t] for t in self.layer_types)
         if self.model == "kimi_linear":
             return tuple("kda" if i in self.kda_layers else "latent"
                          for i in range(1, self.n_layer + 1))
@@ -811,7 +891,7 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         if self.model in ("control", "jamba", "kimi_linear", "afmoe",
-                          "deepseek_v2", "nemotron_h"):
+                          "deepseek_v2", "nemotron_h", "lfm2"):
             return self.n_embd // self.n_head
         return self.n_embd // (self.n_head * 2)
 
@@ -820,7 +900,7 @@ class ModelConfig:
         """Per-head value width: doubled for differential variants
         (diff_transformer.py:30, Ndiff_transformer.py:59)."""
         if self.model in ("control", "jamba", "kimi_linear", "afmoe",
-                          "deepseek_v2", "nemotron_h"):
+                          "deepseek_v2", "nemotron_h", "lfm2"):
             return self.head_size
         return self.head_size * 2
 

@@ -128,7 +128,9 @@ def qkvg(h: jnp.ndarray, p: dict, cfg: ModelConfig, pos: jnp.ndarray,
     """``h`` (.., E) -> q (.., H, d), k and v (.., KV, d), each head of q
     and k RMS-normed and, in a ``"window"`` layer, rotated at ``pos``
     (which broadcasts against ``h``'s leading axes), and the output gate's
-    input g (.., H d)."""
+    input g (.., H d). That the ``"full"`` layers carry no position is THIS
+    family's choice, not the kind's: the lfm2 family's full layers norm a
+    head and then rotate it (``models/lfm2.py:normed_rotated_qkv``)."""
     q, k, v = qkv(h, p)
     f32, eps = jnp.float32, cfg.resolved_norm_eps
     q = rms_norm(q, p["q_norm"].astype(f32), eps)

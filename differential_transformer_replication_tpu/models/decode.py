@@ -59,6 +59,7 @@ from differential_transformer_replication_tpu.models import (
     common,
     jamba,
     kimi_linear,
+    lfm2,
     nemotron_h,
 )
 from differential_transformer_replication_tpu.ops import (
@@ -103,12 +104,15 @@ def _uses_rope(cfg: ModelConfig) -> bool:
 # the families whose layers are of several kinds (:func:`_hybrid_walk`),
 # each with the module that holds its ``embed``
 HYBRID = {"jamba": jamba, "kimi_linear": kimi_linear, "afmoe": afmoe,
-          "deepseek_v2": deepseek_v2, "nemotron_h": nemotron_h}
+          "deepseek_v2": deepseek_v2, "nemotron_h": nemotron_h, "lfm2": lfm2}
 # a family's flavour of attention over a K/V ring, ``(ring_qkv, ring_out)``
-# as its module hands them (nemotron_h's ``*`` layers attend as jamba's do)
+# as its module hands them (nemotron_h's ``*`` layers attend as jamba's do;
+# lfm2's ``"full"`` layers norm a head and then rotate it, afmoe's do not
+# rotate, and nothing follows their read but jamba's output projection)
 RING_ATTENTION = {"jamba": (jamba.ring_qkv, jamba.ring_out),
                   "afmoe": (afmoe.ring_qkv, afmoe.ring_out),
-                  "nemotron_h": (jamba.ring_qkv, jamba.ring_out)}
+                  "nemotron_h": (jamba.ring_qkv, jamba.ring_out),
+                  "lfm2": (lfm2.ring_qkv, jamba.ring_out)}
 # Ring positions that a prefill chunk's blocked attention
 # (:func:`_attend_ring_blocked`) reads at a time
 ATTEND_KEY_BLOCK = 1024
@@ -116,7 +120,8 @@ ATTEND_KEY_BLOCK = 1024
 
 def has_recurrent_state(cfg: ModelConfig) -> bool:
     """Whether a sequence's cache holds state that every token overwrites
-    (a Mamba, a Mamba-2 or a KDA layer's): such a slot has to be zeroed
+    (a Mamba, a Mamba-2 or a KDA layer's, or an lfm2 convolution's window
+    alone): such a slot has to be zeroed
     before a new sequence enters it, where a ring is simply masked by
     positions. Told by what the layers keep, not by the family's name."""
     return any(KINDS[kind].recurrent for kind in cfg.layer_kinds())
@@ -517,6 +522,8 @@ def forward_chunk(
                 f"{cfg.model} family's "
                 + ("MLA layers see every earlier position"
                    if "latent" in cfg.layer_kinds() else
+                   "attention layers see every earlier position"
+                   if cfg.full_layers_rotate else
                    f"{'full ' if 'full' in cfg.layer_kinds() else ''}"
                    "attention layers carry no position")
                 + ", so a rolled ring would silently become "
@@ -592,9 +599,10 @@ def forward_chunk(
 # - ``leaves``, ``zeros``: what a layer keeps in a cache slot, each leaf's
 #   name with its pool axis, and the leaves themselves from ``(cfg, rows,
 #   ring length)`` as :func:`init_cache` builds them;
-# - ``state``: the leaf every token overwrites through the step's update
-#   kernel. Such a kind is ``recurrent``: nothing masks a state by position,
-#   so a slot that takes a new sequence is zeroed first
+# - ``state``: the leaf every token overwrites (through the step's update
+#   kernel, or, where the kind keeps a convolution's window alone, the
+#   window itself). Such a kind is ``recurrent``: nothing masks a state by
+#   position, so a slot that takes a new sequence is zeroed first
 #   (:func:`reset_slot_state`; serving/engine.py does so on admission);
 # - ``blocks``: how its ring is read (None: it keeps none; False: whole
 #   under a mask; True: in blocks as far as it is live); ``rolls``: under
@@ -799,20 +807,24 @@ def _ring_step(h, blk, layer_cache, cfg, live, pos, ring, *, kind):
 def _recurrent(params: str, state: str, mixer_chunk, mixer_step, zero_state,
                **record) -> LayerKind:
     """A kind that keeps ``state`` (its scope's name too) beside a
-    convolution's window: the family's mixer carries both on from where the
-    last chunk left them, as far as ``valid`` goes, and a step overwrites
-    them for the ``live`` slots; ``zero_state`` is a sequence's start."""
+    convolution's window ``conv``, or, with ``state="conv"``, the window
+    ALONE (lfm2's short convolution, whose window is all that a token
+    overwrites): the family's mixer ``(h, p, cfg, conv[, state], until) ->
+    (a, conv[, state])`` carries the leaves on from where the last chunk
+    left them, as far as ``valid`` goes, and a step overwrites them for the
+    ``live`` slots; ``zero_state`` is a sequence's start, in that order."""
+    kept = tuple(dict.fromkeys(("conv", state)))
 
     def run(fn, h, blk, layer_cache, cfg, until):
         with jax.named_scope(state):
-            a, conv, new = fn(h, blk[params], cfg, layer_cache["conv"],
-                              layer_cache[state], until)
-        return a, {state: new, "conv": conv}
+            a, *new = fn(h, blk[params], cfg,
+                         *(layer_cache[leaf] for leaf in kept), until)
+        return a, dict(zip(kept, new))
 
     return LayerKind(
-        params=params, state=state, scope=state, leaves={state: 0, "conv": 0},
-        zeros=lambda cfg, rows, M: dict(
-            zip(("conv", state), zero_state(cfg, rows))),
+        params=params, state=state, scope=state,
+        leaves=dict.fromkeys((state, "conv"), 0),
+        zeros=lambda cfg, rows, M: dict(zip(kept, zero_state(cfg, rows))),
         chunk=lambda h, blk, c, cfg, pos, ring, valid: run(
             mixer_chunk, h, blk, c, cfg, valid),
         step=lambda h, blk, c, cfg, live, pos, ring: run(
@@ -837,17 +849,18 @@ def _refusals(lead: str, tail: str, int8: str, **asked: str) -> dict:
                      "{model} family: " + int8)
 
 
-def _no_snapshot(drafts: str = "") -> dict:
+def _no_snapshot(drafts: str = "", kept: str = "float32") -> dict:
     """A K/V ring can be cut, shared, rolled back or shipped at any
     position; a recurrent state is overwritten every token and what it was
-    earlier is gone. ``{mixers}``: the configuration's recurrent kinds."""
+    earlier is gone. ``{mixers}``: the configuration's recurrent kinds;
+    ``kept``: what the state is stored as."""
     return _refusals(
         "the {model} family keeps a recurrent state a {mixers} layer, and ",
         " needs a snapshot of that state at a position, which the engine "
         "does not take",
         int8="its attention layers' decode path reads float rings "
              "(grouped-query K/V, or MLA's latents), and a quantized "
-             "{mixers} state does not exist yet (the state is float32)",
+             "{mixers} state does not exist yet (the state is " + kept + ")",
         host_tier="the host tier (host_tier_bytes; preemption and resume)",
         spec="speculation (spec_mode; rejected drafts roll the cache back"
              + drafts + ")",
@@ -916,12 +929,21 @@ KINDS = {
     "kda": _recurrent(
         "kda", "kda", kimi_linear.kda_chunk, kimi_linear.kda_step,
         kimi_linear.kda_zero_state, name="KDA", refuses=_no_snapshot()),
+    # a gated short convolution (lfm2): conv (B, K-1, E), its last gated
+    # inputs in the compute dtype, is the layer's WHOLE cache: no state
+    # stands beside the window, and the window is what every token overwrites
+    "shortconv": _recurrent(
+        "conv", "conv", lfm2.conv_chunk, lfm2.conv_step, lfm2.zero_window,
+        name="short-convolution", refuses=_no_snapshot(
+            kept="the convolution's window of conv_taps - 1 gated inputs in "
+                 "the compute dtype")),
     # sliding-window attention (afmoe): a ring of ``sliding_ring`` positions
     # that rolls, shorter than the slot's full rings
     "window": _kv_ring("window", True, _write_chunk_wrapping, rolls=True,
                        refuses=_TWO_RING_LENGTHS),
-    # attention over every earlier position, without one of its own (afmoe's
-    # full layers, nemotron_h's ``*`` layers)
+    # attention over every earlier position (afmoe's full layers and
+    # nemotron_h's ``*`` layers, without a position of their own; lfm2's,
+    # rotated: ``RING_ATTENTION`` says which)
     "full": _kv_ring("full", blocks=True),
     # MLA over a ring of latents (kimi_linear's MLA layers, every deepseek_v2
     # layer): ONE "head" a position, rank + rope wide, that every head reads
@@ -939,8 +961,9 @@ KINDS = {
 
 # Each cache leaf's pool axis (K and its scales carry the stream axis first):
 # the single source of truth for every per-slot slice/scatter/merge over the
-# cache (serving/engine.py), and the page axis; the recurrent kinds' leaves;
-# the block's leaves that hold a mixer.
+# cache (serving/engine.py), and the page axis; the recurrent kinds' leaves
+# (a state beside a window, or a window alone); the block's leaves that hold
+# a mixer.
 KV_CACHE_BATCH_AXIS = {leaf: axis for record in KINDS.values()
                        for leaf, axis in record.leaves.items()}
 STATE_LEAVES = tuple(dict.fromkeys(

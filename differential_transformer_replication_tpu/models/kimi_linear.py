@@ -359,7 +359,10 @@ def moe_mlp(h: jnp.ndarray, p: dict, cfg: ModelConfig,
     ``W_out`` (the router and the shared expert read ``h``), and a shared
     expert without a ``gate`` leaf is the ungated ``W_down relu(W_up
     h)^2`` (:func:`relu2_mlp`), as the routed ones are where their leaves
-    hold ``up`` (``ops/moe.py:experts``)."""
+    hold ``up`` (``ops/moe.py:experts``). The ``lfm2`` family's layer has
+    NO shared expert: where the leaves hold no ``shared`` nothing is added
+    to the routed experts' sum (and its router adds ``cfg.router_eps`` to
+    the chosen scores' sum, 0 for the others)."""
     with jax.named_scope("moe"):
         rows = h.reshape(-1, h.shape[-1])
         flat = None if live is None else live.reshape(-1)
@@ -369,7 +372,8 @@ def moe_mlp(h: jnp.ndarray, p: dict, cfg: ModelConfig,
                 kept = None
                 chosen, weights = moe_ops.route(
                     rows, p["router"]["w"], p["router"]["b"],
-                    cfg.experts_per_token, cfg.routed_scaling)
+                    cfg.experts_per_token, cfg.routed_scaling,
+                    cfg.router_eps)
             else:
                 chosen, weights, kept = moe_ops.route_grouped(
                     rows, p["router"]["w"], cfg.experts_per_token,
@@ -384,9 +388,10 @@ def moe_mlp(h: jnp.ndarray, p: dict, cfg: ModelConfig,
         if "latent_out" in p:  # graftlint: disable=GL104 (static keys)
             with jax.named_scope("moe_latent"):
                 y = y @ p["latent_out"].astype(rows.dtype)
-        with jax.named_scope("moe_shared"):
-            shared = gated_mlp if "gate" in p["shared"] else relu2_mlp
-            y = y + shared(rows, p["shared"])
+        if "shared" in p:  # graftlint: disable=GL104 (static keys)
+            with jax.named_scope("moe_shared"):
+                shared = gated_mlp if "gate" in p["shared"] else relu2_mlp
+                y = y + shared(rows, p["shared"])
         if kept is not None:
             size = cfg.expert_group_size
             mine = jnp.any(kept[:, lo // size:hi // size], axis=-1)

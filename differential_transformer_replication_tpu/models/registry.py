@@ -4,8 +4,9 @@ The reference selects models by commenting code blocks in and out
 (train.py:205-230); here it is a first-class dispatch on
 ``ModelConfig.model`` covering the same three families, and ``jamba``
 (models/jamba.py), ``kimi_linear`` (models/kimi_linear.py), ``afmoe``
-(models/afmoe.py), ``deepseek_v2`` (models/deepseek_v2.py) and
-``nemotron_h`` (models/nemotron_h.py), whose layers are of several kinds.
+(models/afmoe.py), ``deepseek_v2`` (models/deepseek_v2.py),
+``nemotron_h`` (models/nemotron_h.py) and ``lfm2`` (models/lfm2.py), whose
+layers are of several kinds.
 """
 
 from __future__ import annotations
@@ -23,13 +24,15 @@ from differential_transformer_replication_tpu.models import (
     diff,
     jamba,
     kimi_linear,
+    lfm2,
     ndiff,
     nemotron_h,
 )
 
 _MODULES = {"control": control, "diff": diff, "ndiff": ndiff,
             "jamba": jamba, "kimi_linear": kimi_linear, "afmoe": afmoe,
-            "deepseek_v2": deepseek_v2, "nemotron_h": nemotron_h}
+            "deepseek_v2": deepseek_v2, "nemotron_h": nemotron_h,
+            "lfm2": lfm2}
 
 
 def init_model(key: jax.Array, cfg: ModelConfig) -> dict:

@@ -17,11 +17,14 @@ row tile belongs to one expert and a grid step is one plain matrix product
 of its rows with that expert's weights (the tile's expert rides as scalar
 prefetch and picks the weight block). The weights are what a decode step
 pays for (7 MB an expert and 3-4 rows each in the kimi_linear cell, 19 MB
-in deepseek_v2's, 11 MB in a latent in nemotron_h's): consecutive tiles of
-one expert
-keep its block, an expert nobody chose is never fetched, and the tiles
-behind the last group point at the last block and cost no traffic. ``tm``
-follows the rows an expert can expect, 16 in a decode step; XLA's own
+in deepseek_v2's, 11 MB in a latent in nemotron_h's, 18.9 MB in lfm2's):
+consecutive tiles of one expert keep its block, an expert nobody chose is
+never fetched, and the tiles behind the last group point at the last block
+and cost no traffic. Where the program holds EVERY expert (lfm2's cell: 64
+of 64) no assignment is sorted behind the groups, the load sums to rows x
+``experts_per_token``, and a decode step over many rows reads nearly every
+expert of a layer. ``tm`` follows the rows an expert can expect, 16 in a
+decode step (64 at a prompt chunk of 1,024 on 64 held experts); XLA's own
 grouped product (``jax.lax.ragged_dot``) tiles 512 rows whatever the
 groups hold and took 3.0 times as long at the decode step's shapes (my
 chip run, PR 32).
@@ -45,17 +48,23 @@ _WEIGHT_BLOCK_BYTES = 5 << 20  # an expert's (K, tn) block; two are in flight
 
 
 def route(h: jnp.ndarray, w: jnp.ndarray, bias: jnp.ndarray, k: int,
-          scaling: float) -> Tuple[jnp.ndarray, jnp.ndarray]:
+          scaling: float, eps: float = 0.0
+          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``h`` (T, E) -> ``(experts (T, k) int32, weights (T, k) float32)``.
     The scores are float32 under the highest precision: the ranking
-    decides which weights a token meets, and a rounded score flips it."""
+    decides which weights a token meets, and a rounded score flips it.
+    ``eps`` (static) is what a published router adds to the chosen scores'
+    sum before it divides (the lfm2 family's 1e-6; the others' add
+    nothing, and at 0 nothing is added here)."""
     f32 = jnp.float32
     scores = jax.nn.sigmoid(jnp.dot(h.astype(f32), w.astype(f32),
                                     precision=_HIGHEST))
     _, chosen = jax.lax.top_k(scores + bias.astype(f32), k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scaling
-    return chosen.astype(jnp.int32), weights
+    total = jnp.sum(picked, axis=-1, keepdims=True)
+    if eps:
+        total = total + eps
+    return chosen.astype(jnp.int32), picked / total * scaling
 
 
 def route_grouped(h: jnp.ndarray, w: jnp.ndarray, k: int, scaling: float,

@@ -25,6 +25,16 @@ query head), and the blocks a row holds ride as scalar prefetch:
   zeros.
 
 What a step reads is then the live positions rounded up to whole blocks.
+
+The rings are taken AS THE CHIP LAYS THEM OUT. A pool leaf's layout follows
+its shape (``ops/kv_write.py:position_on_lanes``): heads of 128 (afmoe,
+nemotron_h) lie row-major, a block is ``(KV, block, d)``; heads of 64 (lfm2)
+put the ring on the lanes, and the kernel then reads the transposed view, a
+block ``(KV, d, block)``, with the two products' contractions turned to
+match. Handed the row-major leaf there, the compiler copied the K and the V
+pool into that layout and back every step (2.15 GB of temporaries beside a
+pool of 1.08 GB, my described-v5e compile, PR 47), as
+``ops/mla.py:latent_decode_attention`` found for its latents (PR 40).
 """
 
 from __future__ import annotations
@@ -40,7 +50,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from differential_transformer_replication_tpu import kernel_names
 from differential_transformer_replication_tpu.ops.flash import auto_interpret
-from differential_transformer_replication_tpu.ops.kv_write import slot_owners
+from differential_transformer_replication_tpu.ops.kv_write import (
+    position_on_lanes,
+    slot_owners,
+)
 from differential_transformer_replication_tpu.ops.streams import NEG_INF
 
 RING_BLOCK = 512  # ring positions a grid step reads (of every K/V head)
@@ -68,10 +81,13 @@ def row_blocks(pos, live, M: int, window: int):
 
 
 def _kernel(row_ref, lo_ref, n_ref, live_ref, pos_ref, q_ref, k_ref, v_ref,
-            o_ref, m_scr, l_scr, acc_scr, *, ring: int, window: int):
+            o_ref, m_scr, l_scr, acc_scr, *, ring: int, window: int,
+            on_lanes: bool):
     del row_ref, n_ref  # the index maps' alone
     KV, G, d = q_ref.shape[1:]
-    KB = k_ref.shape[2]
+    # a K/V block: (KV, KB, d), or (KV, d, KB) with the ring on the lanes
+    KB = k_ref.shape[3 if on_lanes else 2]
+    over_d, over_ring = (0, 1) if on_lanes else (1, 0)
     b, j = pl.program_id(0), pl.program_id(1)
     last = pl.num_programs(1) - 1
     pos, first, count = pos_ref[b], lo_ref[b], live_ref[b]
@@ -95,7 +111,7 @@ def _kernel(row_ref, lo_ref, n_ref, live_ref, pos_ref, q_ref, k_ref, v_ref,
         for h in range(KV):  # a K/V head's query heads: one small product
             rows = slice(h * G, (h + 1) * G)
             s = jax.lax.dot_general(
-                q_ref[0, h], k_ref[0, h], (((1,), (1,)), ((), ())),
+                q_ref[0, h], k_ref[0, h], (((1,), (over_d,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # (G, KB)
             s = jnp.where(visible, s, NEG_INF)
             top = m_scr[rows]
@@ -105,7 +121,8 @@ def _kernel(row_ref, lo_ref, n_ref, live_ref, pos_ref, q_ref, k_ref, v_ref,
             l_scr[rows] = l_scr[rows] * keep + jnp.sum(p, axis=-1,
                                                        keepdims=True)
             acc_scr[rows] = acc_scr[rows] * keep + jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[0, h], (((1,), (0,)), ((), ())),
+                p.astype(v_ref.dtype), v_ref[0, h],
+                (((1,), (over_ring,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_scr[rows] = new_top
 
@@ -144,21 +161,29 @@ def ring_decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    jnp.where(owner < slots, final[owner], first[owner]))
     n = jnp.where(live, count, 1)
 
+    on_lanes = position_on_lanes(M, d)
+    if on_lanes:  # the chip's own layout of the leaves, as row-major views
+        k, v = jnp.swapaxes(k, -1, -2), jnp.swapaxes(v, -1, -2)
+
     def ring_index(b, j, row_ref, lo_ref, n_ref, *_):
-        return row_ref[b], 0, lo_ref[b] + jnp.minimum(j, n_ref[b] - 1), 0
+        row = row_ref[b]
+        block = lo_ref[b] + jnp.minimum(j, n_ref[b] - 1)
+        return (row, 0, 0, block) if on_lanes else (row, 0, block, 0)
+
+    ring_block_shape = (1, KV, d, KB) if on_lanes else (1, KV, KB, d)
 
     def own(b, j, *_):
         return b, 0, 0, 0
 
     out = pl.pallas_call(
-        functools.partial(_kernel, ring=M, window=window),
+        functools.partial(_kernel, ring=M, window=window, on_lanes=on_lanes),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(B, M // KB),
             in_specs=[
                 pl.BlockSpec((1, KV, Gp, d), own),
-                pl.BlockSpec((1, KV, KB, d), ring_index),
-                pl.BlockSpec((1, KV, KB, d), ring_index),
+                pl.BlockSpec(ring_block_shape, ring_index),
+                pl.BlockSpec(ring_block_shape, ring_index),
             ],
             out_specs=pl.BlockSpec((1, KV, Gp, d), own),
             scratch_shapes=[

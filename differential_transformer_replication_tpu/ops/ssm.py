@@ -46,7 +46,8 @@ _SCAN_TIME_BLOCK = 128
 def causal_conv(u: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
                 window: jnp.ndarray, valid=None):
     """Causal depthwise convolution of ``u`` (B, L, Di) with ``w`` (K, Di)
-    taps a channel and bias ``b`` (Di,), continuing a sequence whose last
+    taps a channel and bias ``b`` (Di,; None: the convolution has none,
+    the lfm2 family's), continuing a sequence whose last
     K-1 inputs are ``window`` (B, K-1, Di; zeros at a sequence's start):
     ``out[t] = b + sum_k w[k] * x[t + k - (K-1)]``. Returns ``(out, the
     new window)``, float32 and ``window``'s dtype. With ``valid`` (a
@@ -57,8 +58,11 @@ def causal_conv(u: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
     full = jnp.concatenate([window.astype(jnp.float32),
                             u.astype(jnp.float32)], axis=1)
     wf = w.astype(jnp.float32)
-    out = b.astype(jnp.float32) + sum(
-        full[:, k:k + L] * wf[k] for k in range(K))
+    if b is None:
+        out = sum(full[:, k:k + L] * wf[k] for k in range(K))
+    else:
+        out = b.astype(jnp.float32) + sum(
+            full[:, k:k + L] * wf[k] for k in range(K))
     if valid is None:
         return out, full[:, L:].astype(window.dtype)
     last = jax.lax.dynamic_slice_in_dim(full, valid, K - 1, axis=1)

@@ -229,15 +229,17 @@ _STAT_SPEC = {
     "state_resets": (
         "serving_state_resets_total",
         "Slots whose recurrent state was zeroed on admission (families "
-        "with Mamba, Mamba-2 or KDA layers; a K/V ring needs none).",
+        "with Mamba, Mamba-2 or KDA layers, or short convolutions whose "
+        "window is the whole state; a K/V ring needs none).",
     ),
     "decode_live_state": (
         "serving_decode_live_state_bytes_total",
         "Bytes of recurrent state that decode steps moved: the active "
         "rows times a slot's state leaves (every Mamba, Mamba-2 or KDA "
         "layer's state, read and written once a step by the update "
-        "kernels, which touch the active slots alone). 0 for a family of "
-        "rings.",
+        "kernels, which touch the active slots alone; a short "
+        "convolution's window where that is all the layer keeps). 0 for a "
+        "family of rings.",
     ),
     "moe_experts_hit": (
         "serving_moe_experts_hit_total",
@@ -1702,6 +1704,11 @@ class ServingEngine:
                        "which a rolled ring of latents no longer holds "
                        "(models/decode.py)"
                        if self._latent_layers else
+                       f"and the {self.cfg.model} family's cache cannot "
+                       "roll: its attention layers see every earlier "
+                       "position, which a rolled ring no longer holds "
+                       "(models/decode.py)"
+                       if self.cfg.full_layers_rotate else
                        f"and the {self.cfg.model} family's cache cannot "
                        "roll: its attention layers (afmoe's full ones) "
                        "carry no position (models/decode.py)")

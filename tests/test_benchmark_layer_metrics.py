@@ -61,6 +61,8 @@ globals().update({name: fn for name, fn in vars(_host_share_cases).items()
 DSV2_CELL = "serve-deepseek-v2-5l-ep8-code-chat"
 # PR 42 added a sixth, to the same lists
 NEMOTRON_CELL = "serve-nemotron3-super-11l-ep4-agent-turns"
+# PR 47 a seventh
+LFM2_CELL = "serve-lfm2-24b-a2b-5l-extract-rag"
 _SILENT = ("sampler_logprobs_ms_per_iter", "sampler_pipeline_ms_per_iter")
 
 
@@ -71,7 +73,7 @@ def test_host_share_metrics_are_declared_for_the_four_serve_cells(metric):
     entry = next(m for m in harness.load_benchmark()["per_layer"]
                  if m["name"] == metric)
     assert entry["workloads"] == cells + (
-        [] if metric in _SILENT else [DSV2_CELL, NEMOTRON_CELL])
+        [] if metric in _SILENT else [DSV2_CELL, NEMOTRON_CELL, LFM2_CELL])
     decl = harness.load_json("layer_metrics", metric + ".json")
     assert (decl["unit"], decl["layer"], decl["moves"]) == (
         entry["unit"], entry["layer"], entry["moves"])
@@ -896,7 +898,7 @@ def test_plain_calls_reader_by_hand_and_with_nothing_to_read(monkeypatch,
     entry = next(m for m in harness.load_benchmark()["per_layer"]
                  if m["name"] == "sampler_plain_calls_pct")
     assert entry["workloads"] == list(_host_share_cases.SERVE_CELLS) + [
-        DSV2_CELL, NEMOTRON_CELL]
+        DSV2_CELL, NEMOTRON_CELL, LFM2_CELL]
     decl = harness.load_json("layer_metrics", "sampler_plain_calls_pct.json")
     assert (decl["unit"], decl["layer"], decl["moves"]) == (
         entry["unit"], entry["layer"], entry["moves"]) == (
@@ -943,7 +945,7 @@ def test_lookahead_reader_by_hand_and_with_nothing_to_read(monkeypatch,
     entry = next(m for m in harness.load_benchmark()["per_layer"]
                  if m["name"] == "decode_lookahead_iters_pct")
     assert entry["workloads"] == list(_host_share_cases.SERVE_CELLS) + [
-        DSV2_CELL, NEMOTRON_CELL]
+        DSV2_CELL, NEMOTRON_CELL, LFM2_CELL]
     decl = harness.load_json("layer_metrics",
                              "decode_lookahead_iters_pct.json")
     assert (decl["unit"], decl["layer"], decl["moves"]) == (

@@ -2,7 +2,7 @@
 kind keeps in a slot, what is read off the table, what the engine refuses
 for it, and the seam itself: a kind the package does not know, registered by
 the test alone, runs through the prefill chunk, the decode step, the reset
-and an engine without an edit elsewhere. Over the five hybrid families' toy
+and an engine without an edit elsewhere. Over the six hybrid families' toy
 configurations (tests/test_<family>.py ``TOY``, the weights of
 benchmark/reference_<family>.py).
 """
@@ -29,7 +29,8 @@ from differential_transformer_replication_tpu.serving.engine import (  # noqa: E
     ServingEngine,
 )
 
-FAMILIES = ("jamba", "kimi_linear", "afmoe", "deepseek_v2", "nemotron_h")
+FAMILIES = ("jamba", "kimi_linear", "afmoe", "deepseek_v2", "nemotron_h",
+            "lfm2")
 V = 223  # not the families' own 211: no cached program of theirs is met
 
 
@@ -67,6 +68,10 @@ SLOT = {
         "mamba2": {"ssm": ((3, 16, 64), F32), "conv": ((3, 3, 128), F32)},
         "full": {"k": ((1, 3, 1, 64, 16), F32), "v": ((3, 1, 64, 16), F32)},
         "none": {}},
+    # PR 47's record: a recurrent kind whose only leaf is its window
+    "lfm2": {
+        "shortconv": {"conv": ((3, 2, 64), F32)},
+        "full": {"k": ((1, 3, 2, 64, 64), F32), "v": ((3, 2, 64, 64), F32)}},
 }
 
 
@@ -107,13 +112,16 @@ def test_the_reference_families_keep_the_attention_record_s_rings(store,
 
 # -- (b) what is read off the table ----------------------------------------------------
 
-# literal copies of the parent's constants (models/decode.py, config.py)
+# literal copies of PR 45's constants (models/decode.py, config.py), with
+# what PR 47's record ``"shortconv"`` adds: its weights' leaf ``conv`` (its
+# cache leaf ``conv`` was a state leaf already) and itself among the
+# recurrent kinds
 PARENT = {
     "KV_CACHE_BATCH_AXIS": {"k": 1, "v": 0, "k_scale": 1, "v_scale": 0,
                             "ssm": 0, "conv": 0, "kda": 0, "latent": 0},
     "STATE_LEAVES": ("ssm", "conv", "kda"),
-    "MIXER_LEAVES": ("attn", "mamba", "mamba2", "kda", "mla"),
-    "RECURRENT_KINDS": ("mamba", "kda", "mamba2"),
+    "MIXER_LEAVES": ("attn", "mamba", "mamba2", "kda", "conv", "mla"),
+    "RECURRENT_KINDS": ("mamba", "kda", "mamba2", "shortconv"),
     "BLOCKED_KINDS": ("window", "full", "latent"),
 }
 
@@ -132,7 +140,8 @@ def test_what_is_derived_is_what_the_parent_spelled_out(name):
 
 def test_the_table_has_the_kinds_a_configuration_can_name():
     assert set(decode.KINDS) == {"attention", "mamba", "mamba2", "kda",
-                                 "latent", "window", "full", "none"}
+                                 "shortconv", "latent", "window", "full",
+                                 "none"}
     assert [k for k, r in decode.KINDS.items() if r.rolls] == ["window"]
     assert [k for k, r in decode.KINDS.items() if r.latents] == ["latent"]
 
@@ -208,9 +217,15 @@ LATENTS = {
 }
 # the first reason a family meets: a recurrent state before rings of two
 # lengths before a ring of latents (kimi_linear has KDA and MLA layers)
+# a window alone is refused as a state is, and says what it is stored as
+WINDOW_ALONE = dict(RECURRENT, int8=RECURRENT["int8"].replace(
+    "(the state is float32)",
+    "(the state is the convolution's window of conv_taps - 1 gated inputs "
+    "in the compute dtype)"))
 REASON = {"jamba": (RECURRENT, "Mamba"), "kimi_linear": (RECURRENT, "KDA"),
           "nemotron_h": (RECURRENT, "Mamba-2"), "afmoe": (TWO_LENGTHS, ""),
-          "deepseek_v2": (LATENTS, "")}
+          "deepseek_v2": (LATENTS, ""),
+          "lfm2": (WINDOW_ALONE, "short-convolution")}
 ASKING = {"host_tier": dict(kv_page_size=8, host_tier_bytes=1 << 20),
           "spec": dict(spec_mode="ngram"), "paging": dict(kv_page_size=8),
           "int8": dict(kv_cache_dtype="int8"),

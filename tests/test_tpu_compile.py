@@ -1027,6 +1027,176 @@ def test_nemotron_h_programs_update_the_state_pool_in_place(topo):
     assert programs["prefill"].memory_analysis().temp_size_in_bytes < 2.0e9
 
 
+# -- the lfm2 family at the published widths (LFM2-24B-A2B, one stage) --------
+
+LFM2 = dict(
+    model="lfm2", vocab_size=65536, n_embd=2048, n_head=32, kv_heads=8,
+    n_layer=5, block_size=8192, norm_eps=1e-5,
+    layer_types=["conv", "full_attention", "conv", "conv", "conv"],
+    first_dense_layers=1, ffn_hidden=11776, num_experts=64,
+    experts_per_token=4, moe_hidden=1536, routed_scaling=1.0,
+    held_experts=[0, 64], rope_theta=1e6, conv_taps=3, router_eps=1e-6,
+    tie_embeddings=True, param_dtype="bfloat16")
+
+
+def _ring_on_lanes(text: str, shape: str) -> bool:
+    """Whether the entry parameter of ``shape`` (``"64,8,8192,64"``) lies
+    with its ring axis minor-most (on the lanes), the features next."""
+    layout = re.search(
+        r"bf16\[" + shape + r"\]\{([\d,]+):[^}]*\} parameter\(", text).group(1)
+    order = [int(a) for a in layout.split(",")]
+    rank = len(shape.split(","))
+    return order[:2] == [rank - 2, rank - 1]
+
+
+def test_lfm2_programs_update_the_pool_in_place(topo):
+    """What the chip's compiler makes of the lfm2 family's two programs at
+    the serve cell's own size (64 slots; four windows of 8 KB and one K/V
+    ring of 8,192 positions of heads of 64 a slot; the five layers of the
+    stage at published widths, all 64 experts held): every cache leaf is
+    aliased input to output; the decode program names its kernels and its
+    scopes; the chip lays the ring of heads of 64 out WITH THE RING ON THE
+    LANES (``position_on_lanes(8192, 64)``), the row write and the ring
+    read both take it so, and the decode program's temporaries stay under
+    a hundredth of the pool: handed the row-major leaf, the read made the
+    compiler copy the K and the V pool there and back every step (2.15 GB
+    of temporaries, PR 47)."""
+    from differential_transformer_replication_tpu.models import init_model
+    from differential_transformer_replication_tpu.models.decode import init_cache
+    from differential_transformer_replication_tpu.ops.kv_write import (
+        position_on_lanes,
+    )
+    from differential_transformer_replication_tpu.serving import engine
+
+    cfg, slots = ModelConfig(**LFM2), 64
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = place(jax.eval_shape(lambda k: init_model(k, cfg),
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_cache(cfg, slots)))
+    assert [sorted(layer) for layer in cache] == [
+        ["conv"], ["k", "v"], ["conv"], ["conv"], ["conv"]]
+    scalar = place(sds((), jnp.int32))
+    prefill, decode = engine._build_step_fns(cfg, cfg.block_size)[:2]
+    programs = {
+        "decode": _lower_decode(decode, place, params, slots,
+                                cache).compile(),
+        "prefill": prefill.lower(params, cache, scalar,
+                                 place(sds((1, 1024), jnp.int32)), scalar,
+                                 scalar).compile(),
+    }
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(cache))
+    assert pool_bytes == 64 * (4 * 2 * 2048 * 2 + 2 * 8 * 8192 * 64 * 2)
+    for name, compiled in programs.items():
+        assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes, name
+    text = programs["decode"].as_text()
+    assert text.startswith("HloModule jit__decode")
+    assert assert_kernels_named(text, "_decode") == {
+        kernel_names.KV_ROW_WRITE, kernel_names.MOE_GROUPED_MATMUL,
+        kernel_names.RING_GQA_DECODE}
+    assert {"conv", "conv_taps", "attn_norm", "attn", "attn_full", "kv_write",
+            "moe", "moe_router", "moe_experts", "ffn_norm", "ffn",
+            "lm_head"} <= scopes_in(text)
+    assert not {"moe_shared", "moe_latent", "ssm"} & scopes_in(text)
+    assert {"conv", "conv_taps", "attn", "attn_full", "kv_write",
+            "moe_experts"} <= scopes_in(programs["prefill"].as_text())
+    assert assert_kernels_named(programs["prefill"].as_text(), "_prefill") == {
+        kernel_names.MOE_GROUPED_MATMUL}
+    # the layout the rule foretells is the one the compiler chose, in both
+    # programs (the pool is one buffer between them)
+    assert position_on_lanes(8192, 64)
+    for compiled in programs.values():
+        assert _ring_on_lanes(compiled.as_text(), "64,8,8192,64")
+        assert _ring_on_lanes(compiled.as_text(), "1,64,8,8192,64")
+    for name, compiled in programs.items():
+        print(name, compiled.memory_analysis())
+    assert programs["decode"].memory_analysis().temp_size_in_bytes < 0.02e9
+    assert programs["prefill"].memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+@pytest.mark.parametrize("d, lanes", [(64, True), (128, False)])
+def test_ring_read_and_row_write_agree_on_the_pools_layout(topo, d, lanes):
+    """The K/V write and the ring read at ``KV 8, G 4`` on a pool of 64
+    rings of 8,192 positions, one after the other on the donated pool: at
+    heads of 64 the ring lies on the lanes and at 128 row-major, and either
+    way nothing of a leaf's size is copied between the two kernels."""
+    from differential_transformer_replication_tpu.ops.kv_write import (
+        position_on_lanes,
+        write_rows,
+    )
+    from differential_transformer_replication_tpu.ops.ring_attention import (
+        ring_decode_attention,
+    )
+
+    B, KV, G, M = 64, 8, 4, 8192
+    assert position_on_lanes(M, d) is lanes
+
+    def step(k, v, k_row, v_row, q, pos, live):
+        targets = jnp.where(live, jax.lax.rem(pos, M), -1)
+        k = write_rows(k, k_row, targets, 0)
+        v = write_rows(v, v_row, targets, 0)
+        return ring_decode_attention(q, k, v, pos, live, M), k, v
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+            for s in (sds((B, KV, M, d)), sds((B, KV, M, d)),
+                      sds((B, KV, d)), sds((B, KV, d)), sds((B, KV * G, d)),
+                      sds((B,), jnp.int32), sds((B,), jnp.bool_))]
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    assert {kernel_names.KV_ROW_WRITE, kernel_names.RING_GQA_DECODE} <= set(
+        kernel_instruction_names(text))
+    leaf = B * KV * M * d * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 2 * leaf
+    assert memory.temp_size_in_bytes < leaf / 100
+    assert _ring_on_lanes(text, f"{B},{KV},{M},{d}") is lanes
+
+
+def test_short_convolution_step_compiles_at_published_widths(topo):
+    """The conv step over 64 slots of 2,048 channels: one projection to
+    three parts, the gates, the window's shift under the live mask and the
+    three taps, in XLA; the window is updated in place."""
+    from differential_transformer_replication_tpu.models import lfm2
+
+    cfg = ModelConfig(**LFM2)
+    p = {"in_proj": sds((2048, 6144)), "conv_w": sds((3, 2048)),
+         "out_proj": sds((2048, 2048))}
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    compiled = jax.jit(
+        lambda h, p, conv, live: lfm2.conv_step(h, p, cfg, conv, live),
+        donate_argnums=(2,)).lower(
+            *place((sds((64, 2048)), p, sds((64, 2, 2048)),
+                    sds((64,), jnp.bool_)))).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes == 64 * 2 * 2048 * 2
+    assert {"conv_taps"} <= scopes_in(compiled.as_text())
+
+
+@pytest.mark.parametrize("tokens", [64, 1024], ids=["256_assignments",
+                                                    "4096_assignments"])
+def test_grouped_product_compiles_with_every_expert_held(topo, tokens):
+    """``ops/moe.py:experts`` on 64 held experts of 2,048 x 3,072 and
+    1,536 x 2,048 with EVERY assignment held: a decode step over 64 slots
+    (256 assignments, tiles of 16 rows) and a prompt chunk of 1,024 (4,096
+    assignments, tiles of 64)."""
+    from differential_transformer_replication_tpu.ops import moe
+
+    assert moe._row_tile(tokens * 4, 64) == (16 if tokens == 64 else 64)
+    p = {"gate_up": sds((64, 2048, 3072)), "down": sds((64, 1536, 2048))}
+    text = compile_for(
+        topo, lambda h, chosen, w, p: moe.experts(h, chosen, w, p, 0),
+        sds((tokens, 2048)), sds((tokens, 4), jnp.int32),
+        sds((tokens, 4), jnp.float32), p)
+    assert kernel_instruction_names(text).count(
+        kernel_names.MOE_GROUPED_MATMUL) == 2
+
+
 def test_sampler_is_scoped(topo):
     """The engine's jitted sampler (a narrow vocabulary: the sort over
     12,000 takes the compiler 23 s and the scope does not depend on it),
